@@ -49,10 +49,10 @@ test: $(LIB)
 	python -m pytest tests/ -q
 
 lint:
-	python tools/graftlint.py mxnet_tpu tools bench.py \
+	python tools/graftlint.py mxnet_tpu tools bench.py chip_smoke.py \
 	    --baseline tools/graftlint_baseline.json --check-env-docs
 
-# xprof views over the newest BENCH / chip_watch artifacts in the repo
+# xprof views over the newest BENCH artifacts in the repo
 # root (compile registry, op-category FLOPs, HBM, device-time table)
 profile-report:
 	python tools/trace_report.py --profile-report

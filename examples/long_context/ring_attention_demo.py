@@ -27,9 +27,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import numpy as np
 
-import jax
-jax.config.update("jax_platforms", "cpu")
-
 from mxnet_tpu.parallel import make_mesh
 from mxnet_tpu.parallel.ring_attention import (make_ring_attention,
                                                reference_attention)

@@ -20,9 +20,6 @@ import numpy as np
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import mxnet_tpu as mx
 from mxnet_tpu.executor import make_graph_eval
 

@@ -12,7 +12,38 @@ The public API mirrors ``import mxnet as mx``:
 """
 from __future__ import annotations
 
-from .base import MXNetError
+import os as _os
+
+
+def _place_compile_cache():
+    """XLA's persistent compile cache, placed before anything compiles.
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins and JAX reads
+    it itself — nothing is set here. Otherwise the cache lives at a FIXED
+    path beside the package (the path is part of how a run finds the
+    cache again, so never a temp name, a pid or a time); JAX's own
+    thresholds then write every program that took a second or more to
+    compile, which is every train step and serve bucket.
+
+    The cache is for the chip. A process pinned to the CPU
+    (``JAX_PLATFORMS=cpu``) gets none: this jaxlib's CPU loader logs a
+    machine-mismatch error on every cache hit, and CPU executables that
+    travel with the checkout to another host may not run there.
+    Setting config options initialises no backend."""
+    if "JAX_COMPILATION_CACHE_DIR" in _os.environ \
+            or _os.environ.get("JAX_PLATFORMS") == "cpu":
+        return   # before importing jax: a CPU worker stays a light import
+    import jax
+
+    if jax.config.jax_platforms == "cpu":   # pinned in code, not by env
+        return
+    root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_dir",
+                      _os.path.join(root, ".jax_cache"))
+
+
+_place_compile_cache()
+
+from .base import MXNetError  # noqa: E402
 from .context import Context, cpu, gpu, tpu, current_context, num_devices
 from . import engine
 from . import ndarray

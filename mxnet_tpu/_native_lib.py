@@ -1,19 +1,23 @@
 """Loader for the native runtime library (C++ engine + recordio codec).
 
 Builds ``mxnet_tpu/_native/libmxtpu.so`` from ``src/native/*.cc`` on first
-use when a compiler is available (``make`` at repo root does the same);
-everything degrades gracefully to the pure-Python implementations if the
-library is missing. Set ``MXNET_TPU_NO_NATIVE=1`` to force pure Python.
+use (``make`` at repo root does the same), and REBUILDS it when it is older
+than any of its sources: the library is a generated file git ignores, so
+one found on disk may predate the checkout. Without a working compiler the
+pure-Python implementations serve, after one warning that carries the
+compiler's stderr. Set ``MXNET_TPU_NO_NATIVE=1`` to force pure Python.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
 from . import env as _env
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib = None
 _tried = False
@@ -23,19 +27,43 @@ _LIB_PATH = os.path.join(_REPO, "mxnet_tpu", "_native", "libmxtpu.so")
 _SRC_DIR = os.path.join(_REPO, "src", "native")
 
 
-def _build() -> bool:
-    srcs = [os.path.join(_SRC_DIR, f) for f in sorted(os.listdir(_SRC_DIR))
-            if f.endswith(".cc")] if os.path.isdir(_SRC_DIR) else []
-    if not srcs:
-        return False
+def _sources():
+    if not os.path.isdir(_SRC_DIR):
+        return []
+    return [os.path.join(_SRC_DIR, f) for f in sorted(os.listdir(_SRC_DIR))
+            if f.endswith(".cc")]
+
+
+def _stale(srcs) -> bool:
+    """True when the library is missing or older than any source."""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    return any(os.path.getmtime(src) > built for src in srcs)
+
+
+def _build(srcs) -> bool:
     os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+    # compile beside the target and rename into place: a concurrent
+    # process never loads a half-written library
+    tmp = "%s.%d.tmp" % (_LIB_PATH, os.getpid())
     cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
-           "-o", _LIB_PATH] + srcs
+           "-o", tmp] + srcs
     try:
         res = subprocess.run(cmd, capture_output=True, timeout=120)
-        return res.returncode == 0
-    except Exception:
-        return False
+        failure = None if res.returncode == 0 else "g++ exited %d:\n%s" % (
+            res.returncode, res.stderr.decode(errors="replace").strip())
+    except (OSError, subprocess.TimeoutExpired) as e:
+        failure = str(e)
+    if failure is None:
+        os.replace(tmp, _LIB_PATH)
+        return True
+    if os.path.exists(tmp):
+        os.remove(tmp)
+    _log.warning("native library build failed; the pure-Python engine and "
+                 "recordio codec serve instead. %s", failure)
+    return False
 
 
 def _configure(lib):
@@ -78,7 +106,10 @@ def get_lib():
         _tried = True
         if _env.get("MXNET_TPU_NO_NATIVE"):
             return None
-        if not os.path.exists(_LIB_PATH) and not _build():
+        srcs = _sources()
+        if srcs and _stale(srcs) and not _build(srcs):
+            return None
+        if not os.path.exists(_LIB_PATH):
             return None
         try:
             _lib = _configure(ctypes.CDLL(_LIB_PATH))

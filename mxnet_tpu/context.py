@@ -61,17 +61,14 @@ class Context:
         import jax
 
         if self.device_type in ("cpu", "cpu_pinned"):
-            try:
-                devs = [d for d in jax.local_devices(backend="cpu")]
-            except RuntimeError:
-                # cpu backend unavailable under some plugins: fall back to
-                # default platform devices (functionally equivalent for tests)
-                devs = jax.local_devices()
+            devs = jax.local_devices(backend="cpu")
         else:
             devs = _accelerator_devices()
             if not devs:
-                # graceful degradation like the reference's CPU fallback
-                devs = jax.local_devices()
+                raise MXNetError(
+                    "%s: this process has no accelerator (jax found only "
+                    "platform %r); use mx.cpu() to run on the host"
+                    % (self, jax.local_devices()[0].platform))
         if self.device_id >= len(devs):
             raise MXNetError(
                 "%s: device_id out of range (%d devices visible)" % (self, len(devs)))
@@ -117,8 +114,5 @@ def num_devices(device_type: str = "tpu") -> int:
     import jax
 
     if device_type == "cpu":
-        try:
-            return len(jax.devices("cpu"))
-        except RuntimeError:
-            return 1
+        return len(jax.devices("cpu"))
     return len(_accelerator_devices())

@@ -288,8 +288,9 @@ declare("MXNET_TPU_BENCH_THREADS", int, 0,
         "Decode pool size for the end-to-end tier (default: host CPU "
         "count).", section=_B)
 declare("MXNET_TPU_BENCH_TIMEOUT", int, 2400,
-        "Seconds the bench orchestrator gives the accelerator child "
-        "before falling back to CPU.", section=_B)
+        "Seconds a named bench mode (`serve`, `fleet`, `multichip`, ...) "
+        "gives the CPU-mesh child it spawns; each mode has its own "
+        "default. The default mode spawns no child.", section=_B)
 declare("MXNET_TPU_BENCH_BATCH", int, 0,
         "Override the per-device batch size of the device-resident bench "
         "tier (default: the model recipe's batch).", section=_B)
@@ -297,19 +298,11 @@ declare("MXNET_TPU_BENCH_STEPS", int, 0,
         "Override the measured step count per bench tier (default: the "
         "recipe's step budget).", section=_B)
 declare("MXNET_TPU_BENCH_DTYPE", str, "",
-        "Compute dtype for the bench model (default `bfloat16` on TPU — "
-        "MXU native — and `float32` elsewhere).", section=_B)
+        "Compute dtype for the bench model (default `bfloat16`, MXU "
+        "native).", section=_B)
 declare("MXNET_TPU_BENCH_TRACE", str, "",
         "Directory to capture a jax profiler trace of the measured bench "
         "window into (empty: no trace).", section=_B)
-declare("MXNET_TPU_BENCH_INNER", bool, False,
-        "Set by the bench orchestrator in the child it spawns; marks the "
-        "process that actually measures (the parent only supervises the "
-        "timeout/CPU fallback). Not meant to be set by hand.", section=_B)
-declare("MXNET_TPU_BENCH_FORCE_EXPERIMENTS", bool, False,
-        "Run the accelerator-only MFU experiment grid even off-TPU "
-        "(produces `valid:false` rows; for exercising the harness).",
-        section=_B)
 declare("MXNET_TPU_STRICT_FEED_GATE", bool, False,
         "Make the feed-the-chip test enforce the absolute host-feed-rate "
         "bar (nightly boxes); unset, the bar is reported but only the "

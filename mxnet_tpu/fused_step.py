@@ -588,11 +588,28 @@ class FusedTrainStep:
                     grp.append(ns)
                 new_st.append(tuple(grp))
             if grad_shardings is not None:
-                # keep the updated params on their (fsdp) shardings so
-                # GSPMD never gathers them just to re-scatter on entry
-                # to the next step
-                new_p = [jax.lax.with_sharding_constraint(p, s)
-                         for p, s in zip(new_p, param_shardings)]
+                # every piece of carried state leaves the step on the
+                # sharding it entered with. Updated params stay on their
+                # (fsdp) shardings so GSPMD never gathers them just to
+                # re-scatter on entry to the next step; and an output
+                # GSPMD is left free to place (BN stats, optimizer
+                # state) can come back sharded differently, which
+                # changes the NEXT call's input shardings and makes jit
+                # compile the whole step a second time — silently, since
+                # step.fused_recompiles only sees shapes
+                pin = jax.lax.with_sharding_constraint
+                new_p = [pin(p, s) for p, s in zip(new_p, param_shardings)]
+                aux_out = [pin(a, rep) for a in aux_out]
+                for gi, (_, _, positions) in enumerate(specs):
+                    # same rule as place_like_param: a state leaf shaped
+                    # like its weight shares the weight's sharding
+                    new_st[gi] = tuple(
+                        jax.tree_util.tree_map(
+                            lambda leaf, pos=pos: pin(
+                                leaf, param_shardings[pos]
+                                if leaf.shape == new_p[pos].shape
+                                else rep), ns)
+                        for pos, ns in zip(positions, new_st[gi]))
             new_accs = accs
             labels = [o_vals[p] for p in label_pos]
             if fold:
@@ -728,6 +745,10 @@ class FusedInfer:
                        if i not in d_set]
         self._top_k = int(top_k)
         self._mesh = mesh
+        # off-mesh placement target: the executor's OWN device, not the
+        # process default — on a TPU host a module bound to mx.cpu()
+        # must not have its request rows land on the chip
+        self._device = ex._ctx.jax_device()
         self._tp = 1
         if mesh is not None and "tp" in mesh.axis_names:
             self._tp = int(mesh.shape["tp"])
@@ -884,8 +905,8 @@ class FusedInfer:
                         continue
                     arg_i = self._p_idx[pos]
                     sh = self._param_sharding(arg_i)
-                    val = (jax.device_put(host, sh) if sh is not None
-                           else jax.device_put(host))
+                    val = jax.device_put(
+                        host, sh if sh is not None else self._device)
                     new_params[pos] = val
                     # write-through so a later full re-pack (or a
                     # host-side get_params) sees the streamed values
@@ -946,7 +967,7 @@ class FusedInfer:
                 if sh is not None:
                     placed.append(jax.device_put(a, sh))
                 elif isinstance(a, _np.ndarray):
-                    placed.append(jax.device_put(a))
+                    placed.append(jax.device_put(a, self._device))
                 else:
                     placed.append(a)
         return placed

@@ -57,16 +57,16 @@ def _replicated_zero(like):
     return z
 
 
-def _device_ids(x):
-    """frozenset of device ids ``x`` is committed to, or None when it
-    carries no sharding (uncommitted / not a jax array)."""
+def _device_set(x):
+    """frozenset of the devices ``x`` is committed to, or None when it
+    carries no sharding (uncommitted / not a jax array). Devices, not
+    their ids: a host with a TPU has a ``cpu:0`` AND a ``tpu:0``, and
+    labels from a host iterator must not look colocated with
+    predictions on the chip."""
     sharding = getattr(x, "sharding", None)
     if sharding is None:
         return None
-    try:
-        return frozenset(d.id for d in sharding.device_set)
-    except Exception:
-        return None
+    return frozenset(sharding.device_set)
 
 
 def check_label_shapes(labels, preds):
@@ -124,12 +124,12 @@ class EvalMetric:
         # executor shards preds over the mesh while labels sit on one
         # device — that batch takes the eager numpy path instead
         # (get() still folds in whatever the accumulator already holds)
-        sets = {_device_ids(a._data) for a in labels + preds}
+        sets = {_device_set(a._data) for a in labels + preds}
         sets.discard(None)
         if len(sets) > 1:
             return False
         if self._device_acc is not None and sets \
-                and _device_ids(self._device_acc[0]) not in (
+                and _device_set(self._device_acc[0]) not in (
                     None, next(iter(sets))):
             return False
         import jax

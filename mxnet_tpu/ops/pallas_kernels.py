@@ -11,8 +11,11 @@ tile-aligned — plus the availability plumbing shared by future kernels
 Gradients route through ``jax.custom_vjp``: the backward matmuls are plain
 XLA (already MXU-optimal); only the fused forward is hand-written.
 
-On CPU the kernels run in interpreter mode so the whole path is testable
-without hardware.
+Every kernel goes through :func:`pallas_call`, which decides interpreter
+vs Mosaic per LOWERING: a computation lowered for the CPU gets the Pallas
+interpreter (so the whole path is testable without hardware), one lowered
+for a TPU gets the compiled kernel, and nothing in the process can flip
+that.
 """
 from __future__ import annotations
 
@@ -24,8 +27,9 @@ import numpy as np
 from .. import env as _env
 
 __all__ = ["fused_linear", "flash_attention", "pallas_available",
-           "conv2d", "conv_dgrad", "conv_wgrad", "conv_backward_applicable",
-           "fused_norm_act", "norm_act_applicable"]
+           "pallas_call", "conv2d", "conv_dgrad", "conv_wgrad",
+           "conv_backward_applicable", "fused_norm_act",
+           "norm_act_applicable"]
 
 # float32 MXU-friendly tiles (sublane 8, lane 128)
 TILE_M = 128
@@ -46,11 +50,22 @@ def pallas_available() -> bool:
         return False
 
 
-@functools.lru_cache(None)
-def _interpret_mode() -> bool:
+def pallas_call(kernel, *operands, **kw):
+    """``pl.pallas_call(kernel, **kw)(*operands)`` with interpret mode
+    chosen by the platform the enclosing computation is LOWERED for
+    (``jax.lax.platform_dependent``): the interpreter on ``cpu``, the
+    Mosaic-compiled kernel everywhere else. A process-wide "what is the
+    default backend" answer is wrong as soon as one process holds two
+    backends, which every process on a TPU host does."""
     import jax
+    from jax.experimental import pallas as pl
 
-    return jax.default_backend() == "cpu"
+    def lowered(interpret):
+        return lambda *ops: pl.pallas_call(kernel, interpret=interpret,
+                                           **kw)(*ops)
+
+    return jax.lax.platform_dependent(*operands, cpu=lowered(True),
+                                      default=lowered(False))
 
 
 def _linear_call(x, w_t, bias, act: str):
@@ -84,8 +99,8 @@ def _linear_call(x, w_t, bias, act: str):
                 acc = jax.nn.sigmoid(acc)
             o_ref[:] = acc
 
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        kernel, x, w_t, bias,
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE_M, TILE_K), lambda i, j, kk: (i, kk)),
@@ -94,8 +109,7 @@ def _linear_call(x, w_t, bias, act: str):
         ],
         out_specs=pl.BlockSpec((TILE_M, TILE_N), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=_interpret_mode(),
-    )(x, w_t, bias)
+    )
 
 
 def fused_linear(x, weight, bias=None, act: str = "none") -> Optional[object]:
@@ -214,8 +228,8 @@ def _flash_call(q, k, v, scale: float, causal: bool):
         def _():
             o_ref[0] = acc_ref[:] / l_ref[:, :1]
 
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        kernel, q, k, v,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, BLOCK_Q, d), lambda b, iq, ik: (b, iq, 0)),
@@ -229,8 +243,7 @@ def _flash_call(q, k, v, scale: float, causal: bool):
             pltpu.VMEM((BLOCK_Q, 128), jnp.float32),   # running normalizer
             pltpu.VMEM((BLOCK_Q, d), jnp.float32),     # weighted accumulator
         ],
-        interpret=_interpret_mode(),
-    )(q, k, v)
+    )
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -354,15 +367,14 @@ def _matmul(a, b, tiles, transpose_a=False):
     a_spec = (pl.BlockSpec((tk, tm), lambda i, j, kk: (kk, i))
               if transpose_a
               else pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)))
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        kernel, a, b,
         grid=grid,
         in_specs=[a_spec,
                   pl.BlockSpec((tk, tn), lambda i, j, kk: (kk, j))],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=_interpret_mode(),
-    )(a, b)
+    )
 
 
 def _patches(x, kh, kw, stride):
@@ -564,10 +576,12 @@ def norm_act_applicable(shape, dtype, block_rows=NORM_BLOCK_ROWS) -> bool:
         return False
     import jax.numpy as jnp
 
-    if len(shape) < 2 or block_rows <= 0 or block_rows % 8:
-        return False
-    if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
-                                jnp.dtype(jnp.bfloat16)):
+    # the row tile must fill whole sublane tiles: 8 rows of f32, 16 of
+    # bf16 (which packs two rows per 32-bit sublane)
+    sublane = {jnp.dtype(jnp.float32): 8,
+               jnp.dtype(jnp.bfloat16): 16}.get(jnp.dtype(dtype))
+    if sublane is None or len(shape) < 2 or block_rows <= 0 \
+            or block_rows % sublane:
         return False
     c = shape[-1]
     rows = 1
@@ -591,8 +605,8 @@ def _norm_act_fwd_call(x2, scale, shift, act, block_rows):
             y = jnp.maximum(y, 0.0)
         o_ref[:] = y.astype(o_ref.dtype)
 
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        kernel, x2, scale, shift,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, _NORM_BLOCK_C),
@@ -603,8 +617,7 @@ def _norm_act_fwd_call(x2, scale, shift, act, block_rows):
         out_specs=pl.BlockSpec((block_rows, _NORM_BLOCK_C),
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), x2.dtype),
-        interpret=_interpret_mode(),
-    )(x2, scale, shift)
+    )
 
 
 def _norm_act_bwd_call(x2, scale, shift, g2, act, block_rows):
@@ -635,8 +648,8 @@ def _norm_act_bwd_call(x2, scale, shift, g2, act, block_rows):
         dsc_ref[:] += jnp.sum(ge * x, axis=0, keepdims=True)
         dsh_ref[:] += jnp.sum(ge, axis=0, keepdims=True)
 
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        kernel, x2, scale, shift, g2,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, _NORM_BLOCK_C),
@@ -657,8 +670,7 @@ def _norm_act_bwd_call(x2, scale, shift, g2, act, block_rows):
             jax.ShapeDtypeStruct((1, c), jnp.float32),
             jax.ShapeDtypeStruct((1, c), jnp.float32),
         ],
-        interpret=_interpret_mode(),
-    )(x2, scale, shift, g2)
+    )
 
 
 def fused_norm_act(x, scale, shift, act: str = "none",

@@ -1,18 +1,12 @@
-"""shard_map across jax versions: new ``jax.shard_map`` (check_vma) vs
-old ``jax.experimental.shard_map`` (check_rep)."""
+"""``jax.shard_map`` as every caller here uses it: replication checking
+(``check_vma``) off."""
 from __future__ import annotations
 
 __all__ = ["shard_map"]
 
 
-def shard_map(f, mesh, in_specs, out_specs, check=False):
-    try:
-        import jax
+def shard_map(f, mesh, in_specs, out_specs):
+    import jax
 
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

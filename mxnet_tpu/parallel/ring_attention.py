@@ -147,19 +147,13 @@ def make_ring_attention(mesh, axis_name: str = "sp", causal: bool = False,
     import jax
     from jax.sharding import PartitionSpec as P
 
+    from ._compat import shard_map
+
     fn = ring_attention if impl == "ring" else ulysses_attention
     spec = P(None, axis_name, None, None)
     body = functools.partial(fn, axis_name=axis_name, causal=causal)
-    try:
-        from jax import shard_map
-
-        smapped = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check_vma=False)
-    except (ImportError, TypeError):  # older jax
-        from jax.experimental.shard_map import shard_map as shard_map_old
-
-        smapped = shard_map_old(body, mesh=mesh, in_specs=(spec, spec, spec),
-                                out_specs=spec, check_rep=False)
+    smapped = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec)
 
     @jax.jit
     def attn(q, k, v):

@@ -93,7 +93,11 @@ def fsdp_param_spec(shape, mesh, axis: str = "fsdp"):
     size = int(mesh.shape[axis])
     if size <= 1 or not shape or shape[0] % size != 0:
         return P()
-    return P(*((axis,) + (None,) * (len(shape) - 1)))
+    # no trailing Nones: P(axis) is the spelling XLA hands back on a
+    # step's outputs, and jit keys its cache on the spelling — a param
+    # that enters as P(axis, None) and returns as P(axis) recompiles
+    # the fused step once, on the second batch
+    return P(axis)
 
 
 def batch_shard_extent(mesh) -> int:

@@ -16,6 +16,7 @@ import numpy as np
 
 from .base import MXNetError
 from .ndarray import NDArray
+from .ops.pallas_kernels import pallas_call
 
 __all__ = ["Rtc"]
 
@@ -66,15 +67,14 @@ class Rtc:
             raise MXNetError("Rtc '%s': kernel failed to compile: %s"
                              % (name, e))
         self._kernel = scope["__kernel__"]
-        interpret = jax.default_backend() == "cpu"
 
         def call(*in_arrays):
-            return pl.pallas_call(
-                self._kernel,
+            # interpreter when lowered for the CPU, Mosaic on a TPU:
+            # decided by where the pushed NDArrays live, per call
+            return pallas_call(
+                self._kernel, *in_arrays,
                 out_shape=tuple(jax.ShapeDtypeStruct(s, d)
-                                for s, d in self._out_shapes),
-                interpret=interpret,
-            )(*in_arrays)
+                                for s, d in self._out_shapes))
 
         self._call = jax.jit(call)
 

@@ -1212,12 +1212,10 @@ class InferenceServer:
         Refuses (naming the knob) when ``tp`` does not divide the
         device count — silently dropping devices would serve a
         different capacity than the operator asked for."""
-        import jax
-
         from .parallel.sharding import make_mesh
 
         devices = (list(mesh.devices.flat) if mesh is not None
-                   else jax.devices()[:1])
+                   else [c.jax_device() for c in group.contexts])
         n = len(devices)
         if n % tp != 0:
             raise MXNetError(

@@ -44,6 +44,7 @@ compiles and zero extra dispatches (regression-tested against
 """
 from __future__ import annotations
 
+import logging
 import re
 import threading
 import time
@@ -53,10 +54,13 @@ from . import env as _env
 from . import telemetry as _tel
 from .base import MXNetError
 
+_log = logging.getLogger(__name__)
+
 __all__ = [
     "enabled", "enable", "disable", "reset", "jit", "record_compile",
     "records", "summary", "last_retrace_cause", "hlo_op_breakdown",
-    "analyze", "chip_peak_tflops", "chip_hbm_gbps", "hbm_stats",
+    "analyze", "chip_peaks", "chip_peak_tflops", "chip_hbm_gbps",
+    "CHIP_PEAKS", "hbm_stats",
     "HbmWatermark", "preflight_check", "device_memory_limit",
     "CompileRecord", "CATEGORIES",
 ]
@@ -229,14 +233,11 @@ def _cost_dict(compiled) -> dict:
     return dict(c) if c else {}
 
 
-def _device_count(compiled) -> Optional[int]:
+def _device_count(compiled) -> int:
     """Devices the executable was SPMD-partitioned over (1 for an
     unsharded step; the dp mesh size for the sharded fused step) — the
     compile-registry witness that GSPMD actually partitioned a site."""
-    try:
-        return len(compiled.runtime_executable().local_devices())
-    except Exception:
-        return None
+    return len(compiled.runtime_executable().local_devices())
 
 
 def _memory_dict(compiled) -> Optional[dict]:
@@ -396,9 +397,18 @@ class _InstrumentedJit:
             return self._jit(*args)
         try:
             return compiled(*args)
-        except TypeError:
+        except TypeError as e:
             # the AOT input check is stricter than jit dispatch (e.g. a
-            # committed-device mismatch); fall back rather than fail
+            # committed-device mismatch). The plain jit still serves the
+            # call, but it COMPILES THE SITE A SECOND TIME and the
+            # registry's record no longer describes what runs — so this
+            # is counted and logged, never silent
+            _tel.inc("compile.aot_fallback")
+            _log.warning(
+                "xprof: %s: the measured AOT executable rejected its "
+                "arguments (%s); dispatching through jax.jit instead, "
+                "which compiles this site again", self._site,
+                str(e).splitlines()[0])
             with self._lock:
                 self._cache[sig] = _FALLBACK
             return self._jit(*args)
@@ -413,12 +423,10 @@ class _InstrumentedJit:
         rec = record_compile(self._site, compiled,
                              time.perf_counter() - t0, signature=sig)
         if _env.get("MXNET_TPU_XPROF_PREFLIGHT") and rec.peak_bytes:
-            try:
-                devs = compiled.runtime_executable().local_devices()
-            except Exception:
-                devs = None
-            preflight_check(rec.peak_bytes, devices=devs,
-                            what=self._site)
+            preflight_check(
+                rec.peak_bytes,
+                devices=compiled.runtime_executable().local_devices(),
+                what=self._site)
         self._cache[sig] = compiled
         return compiled
 
@@ -682,55 +690,63 @@ def hlo_op_breakdown(hlo_text: str) -> Dict[str, dict]:
 # analytic MFU / roofline classification
 # ---------------------------------------------------------------------------
 
-# bf16 peak TFLOP/s per chip (kept in sync with bench.CHIP_PEAK_TFLOPS)
-CHIP_PEAK_TFLOPS = {"v5 lite": 197, "v5litepod": 197, "v5e": 197,
-                    "v5p": 459, "v4": 275, "v6 lite": 918, "v6e": 918,
-                    "v3": 123, "v2": 45}
-# HBM bandwidth GB/s per chip (public TPU system specs)
-CHIP_HBM_GBPS = {"v5 lite": 819, "v5litepod": 819, "v5e": 819,
-                 "v5p": 2765, "v4": 1228, "v6 lite": 1640, "v6e": 1640,
-                 "v3": 900, "v2": 700}
+# The ONE peak table: published per-chip (bf16 TFLOP/s, HBM GB/s), keyed
+# by a fragment of jax's ``device_kind``. Source: Google Cloud TPU
+# documentation, the per-generation system-architecture pages ("TPU v5e":
+# 197 TFLOP/s bf16, 819 GB/s HBM; likewise v2/v3/v4/v5p/v6e). A v5e
+# reports ``device_kind == "TPU v5 lite"``.
+CHIP_PEAKS = {"v5 lite": (197, 819), "v5litepod": (197, 819),
+              "v5e": (197, 819), "v5p": (459, 2765), "v4": (275, 1228),
+              "v6 lite": (918, 1640), "v6e": (918, 1640),
+              "v3": (123, 900), "v2": (45, 700)}
 
 
-def _table_lookup(table, device_kind: Optional[str]):
-    if not device_kind:
+def chip_peaks(device_kind: Optional[str]):
+    """``(peak bf16 TFLOP/s, HBM GB/s)`` for a device kind. ``"cpu"`` is
+    the explicit "no peak" and returns None; any other kind missing from
+    :data:`CHIP_PEAKS` raises — a utilisation computed against a guessed
+    or absent peak is worse than no number."""
+    kind = (device_kind or "").lower()
+    if kind == "cpu":
         return None
-    kind = device_kind.lower()
-    for frag, val in sorted(table.items(), key=lambda kv: -len(kv[0])):
+    for frag in sorted(CHIP_PEAKS, key=len, reverse=True):
         if frag in kind:
-            return val
-    return None
+            return CHIP_PEAKS[frag]
+    raise MXNetError(
+        "no published peak for device_kind %r: add it to "
+        "xprof.CHIP_PEAKS with its source" % (device_kind,))
 
 
 def chip_peak_tflops(device_kind: Optional[str]):
-    return _table_lookup(CHIP_PEAK_TFLOPS, device_kind)
+    peaks = chip_peaks(device_kind)
+    return peaks[0] if peaks else None
 
 
 def chip_hbm_gbps(device_kind: Optional[str]):
-    return _table_lookup(CHIP_HBM_GBPS, device_kind)
+    peaks = chip_peaks(device_kind)
+    return peaks[1] if peaks else None
 
 
 def analyze(flops, bytes_accessed, step_time_s=None,
             device_kind: Optional[str] = None) -> dict:
     """Roofline analytics for one executable: arithmetic intensity,
     the chip's ridge point, compute- vs bandwidth-bound, and (given a
-    measured step time) achieved TFLOP/s + analytic MFU. Unknown chips
-    (CPU) report ``analytic_mfu_pct: 0.0`` and ``bound: "unknown"``
-    with the FLOP counts still attached."""
+    measured step time) achieved TFLOP/s + analytic MFU. On the CPU
+    there is no peak: ``bound`` is ``"unknown"`` and NO
+    ``analytic_mfu_pct`` field is emitted (never a 0.0 that reads as a
+    measurement); the FLOP counts are still attached. ``device_kind``
+    defaults to the first device's."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = None
-    peak = chip_peak_tflops(device_kind)
-    bw = chip_hbm_gbps(device_kind)
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    peak, bw = chip_peaks(device_kind) or (None, None)
     out = {"flops": flops, "bytes_accessed": bytes_accessed,
            "device_kind": device_kind,
            "peak_tflops": peak, "hbm_gbps": bw}
     ai = (float(flops) / float(bytes_accessed)
           if flops and bytes_accessed else None)
-    ridge = (peak * 1e12) / (bw * 1e9) if peak and bw else None
+    ridge = (peak * 1e12) / (bw * 1e9) if peak else None
     out["arithmetic_intensity"] = round(ai, 2) if ai else None
     out["ridge_intensity"] = round(ridge, 2) if ridge else None
     out["bound"] = (("compute" if ai >= ridge else "bandwidth")
@@ -738,8 +754,9 @@ def analyze(flops, bytes_accessed, step_time_s=None,
     if step_time_s and flops:
         achieved = float(flops) / float(step_time_s)
         out["achieved_tflops"] = round(achieved / 1e12, 3)
-        out["analytic_mfu_pct"] = (
-            round(100.0 * achieved / (peak * 1e12), 2) if peak else 0.0)
+        if peak:
+            out["analytic_mfu_pct"] = round(
+                100.0 * achieved / (peak * 1e12), 2)
     return out
 
 

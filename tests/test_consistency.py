@@ -1,11 +1,11 @@
 """Cross-dtype operator consistency (the reference's GPU-vs-CPU
 validation tier: tests/python/gpu/test_operator_gpu.py re-ran every op
 through check_consistency across ctx x dtype configs with per-dtype
-tolerances). Here the axes are dtype (fp16/fp32) and, when the session
-has an accelerator, backend — exercised per core op family.
+tolerances). Here the axis is dtype (fp16/fp32), exercised per core op
+family; the accelerator-vs-CPU axis needs the chip and lives in
+tools/tpu_consistency.py.
 """
 import numpy as np
-import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu.test_utils import check_consistency
@@ -61,18 +61,3 @@ def test_consistency_elementwise_reduce():
     data = mx.sym.Variable("data")
     net = mx.sym.sum(data=data, axis=1)
     check_consistency(net, _cfgs(data=(3, 4, 5)), grad_req="null")
-
-
-@pytest.mark.skipif(
-    __import__("jax").default_backend() == "cpu",
-    reason="needs an accelerator backend to compare against cpu")
-def test_consistency_cross_backend():
-    # the literal cuDNN-vs-CPU analogue: accelerator vs CPU backend
-    data = mx.sym.Variable("data")
-    net = mx.sym.Convolution(data=data, kernel=(3, 3), num_filter=4,
-                             name="conv")
-    net = mx.sym.Activation(data=net, act_type="relu")
-    check_consistency(net, [
-        {"ctx": mx.cpu(), "data": (2, 3, 8, 8)},
-        {"ctx": mx.tpu(0), "data": (2, 3, 8, 8)},
-    ])

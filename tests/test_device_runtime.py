@@ -1,0 +1,205 @@
+"""The device runtime's contract on a host that may hold two backends:
+nothing falls back to the CPU behind a ``tpu`` label, importing the package
+takes no chip, the compile cache can be placed from outside, kernels choose
+interpreter vs Mosaic per lowering, and generated files are rebuilt rather
+than trusted."""
+import json
+import logging
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import metric, telemetry, xprof
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import pallas_kernels as pk
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+_REPORT = (
+    "import json, sys\n"
+    "sys.path.insert(0, %r)\n"
+    "import mxnet_tpu, jax\n"
+    "from jax._src import xla_bridge\n"
+    "print(json.dumps({'dir': jax.config.jax_compilation_cache_dir,\n"
+    "                  'backends': sorted(xla_bridge._backends)}))\n" % REPO)
+
+
+def test_import_places_the_compile_cache_and_takes_no_chip(tmp_path):
+    """A fresh interpreter, started in another directory, with
+    JAX_PLATFORMS unset — on a TPU host that is the real default
+    configuration: the cache points at a fixed path beside the package
+    (no pid, time or temp name: it equals a constant, so it is the same
+    in every process) and importing initialised no backend."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR",
+                        "PYTHONPATH")}
+    r = subprocess.run([sys.executable, "-c", _REPORT], cwd=str(tmp_path),
+                       capture_output=True, text=True, timeout=120, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    rep = json.loads(r.stdout.splitlines()[-1])
+    assert rep["dir"] == os.path.join(REPO, ".jax_cache")
+    assert rep["backends"] == [], "import initialised %s" % rep
+
+
+def test_compile_cache_is_left_alone_when_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it (at import) and the
+    package sets nothing. A CPU-pinned process gets no cache at all."""
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    mx._place_compile_cache()
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    mx._place_compile_cache()
+    assert calls == []
+    # pinned in code rather than by the environment (as conftest does)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert jax.config.jax_platforms == "cpu"
+    mx._place_compile_cache()
+    assert calls == []
+
+
+def test_tpu_context_raises_without_an_accelerator():
+    with pytest.raises(MXNetError, match="no accelerator.*'cpu'"):
+        mx.tpu(0).jax_device()
+    with pytest.raises(MXNetError, match="no accelerator"):
+        mx.nd.zeros((2, 2), ctx=mx.gpu(0))
+    assert mx.cpu(0).jax_device().platform == "cpu"
+    assert mx.num_devices("tpu") == 0
+
+
+def test_metric_colocation_compares_devices_not_ids():
+    """cpu:0 and tpu:0 share id 0; labels from a host iterator must not
+    look colocated with predictions on the chip."""
+    class Dev:
+        def __init__(self, platform):
+            self.platform, self.id = platform, 0
+
+    class Arr:
+        def __init__(self, dev):
+            self.sharding = type("S", (), {"device_set": {dev}})()
+
+    host, chip = Dev("cpu"), Dev("tpu")
+    assert metric._device_set(Arr(host)) != metric._device_set(Arr(chip))
+    assert metric._device_set(Arr(chip)) == metric._device_set(Arr(chip))
+    assert metric._device_set(np.zeros(3)) is None
+
+
+def _lowered_text(fn, args, platform):
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+@pytest.mark.parametrize("name", ["fused_norm_act", "fused_linear", "rtc"])
+def test_kernels_lower_to_mosaic_for_tpu_and_the_interpreter_for_cpu(name):
+    """The interpret decision is taken per lowering: the same traced call
+    becomes a Mosaic custom call when lowered for a TPU and interpreter
+    HLO when lowered for the CPU — no process-wide answer to flip."""
+    x = jnp.ones((128, 128), jnp.float32)
+    v = jnp.ones((128,), jnp.float32)
+    if name == "fused_norm_act":
+        fn, args = (lambda x, s, b: pk.fused_norm_act(x, s, b, act="relu"),
+                    (x, v, v))
+    elif name == "fused_linear":
+        fn, args = (lambda x, w, b: pk.fused_linear(x, w, b)), (x, x, v)
+    else:
+        def body(x_ref, o_ref):
+            o_ref[:] = x_ref[:] * 2.0
+        fn, args = (lambda x: pk.pallas_call(
+            body, x, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))), (x,)
+    tpu = _lowered_text(fn, args, "tpu")
+    cpu = _lowered_text(fn, args, "cpu")
+    assert "tpu_custom_call" in tpu
+    assert "tpu_custom_call" not in cpu
+    # and the CPU lowering really computes
+    out = jax.jit(fn)(*args)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_bf16_norm_act_needs_sixteen_row_tiles():
+    assert pk.norm_act_applicable((64, 128), jnp.float32, 8)
+    assert not pk.norm_act_applicable((64, 128), jnp.bfloat16, 8)
+    assert pk.norm_act_applicable((64, 128), jnp.bfloat16, 16)
+
+
+def test_native_library_older_than_its_sources_is_rebuilt(caplog,
+                                                          monkeypatch):
+    import mxnet_tpu._native_lib as nl
+
+    if nl.get_lib() is None:
+        pytest.skip("no compiler / native library on this host")
+    srcs = nl._sources()
+    assert srcs and not nl._stale(srcs)
+    os.utime(nl._LIB_PATH, (1, 1))          # older than any checkout
+    assert nl._stale(srcs)
+    monkeypatch.setattr(nl, "_lib", None)
+    monkeypatch.setattr(nl, "_tried", False)
+    assert nl.get_lib() is not None
+    assert not nl._stale(srcs), "get_lib() loaded the stale library"
+
+    # a failed build is said once, at warning level, with the reason
+    monkeypatch.setattr(nl, "_lib", None)
+    monkeypatch.setattr(nl, "_tried", False)
+    monkeypatch.setattr(nl, "_stale", lambda srcs: True)
+    monkeypatch.setattr(
+        nl.subprocess, "run",
+        lambda *a, **k: subprocess.CompletedProcess(a, 1, b"", b"boom.cc:1"))
+    with caplog.at_level(logging.WARNING, logger=nl.__name__):
+        assert nl.get_lib() is None
+        assert nl.get_lib() is None
+    warned = [r for r in caplog.records if "build failed" in r.getMessage()]
+    assert len(warned) == 1 and "boom.cc:1" in warned[0].getMessage()
+
+
+def test_bench_default_mode_fails_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                       capture_output=True, text=True, timeout=120,
+                       cwd=REPO, env=env)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert r.stdout.strip() == "", "a metric line was printed: %s" % r.stdout
+    src = open(os.path.join(REPO, "bench.py")).read()
+    for gone in ("cpu-fallback", "bench-failed", "last_accelerator_result",
+                 "_accelerator_reachable", ".bench_cache.json"):
+        assert gone not in src, gone
+
+
+def test_xprof_aot_rejection_is_counted_and_logged(caplog):
+    """The AOT executable's input check is stricter than jit dispatch;
+    when it rejects a call the plain jit serves it, but never silently."""
+    xprof.enable()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        f = xprof.jit(lambda a: a * 2, site="t.aot_reject")
+
+        class Rejecting:
+            def __call__(self, *args):
+                raise TypeError("Argument types differ from the types "
+                                "for which this computation was compiled")
+
+        arg = jnp.ones((4,), jnp.float32)
+        sig = xprof.leaf_signature((arg,), None)
+        f._cache[sig] = Rejecting()
+        with caplog.at_level(logging.WARNING, logger=xprof.__name__):
+            out = f(arg)
+        assert np.allclose(np.asarray(out), 2.0)
+        assert telemetry.peek("compile.aot_fallback") == 1
+        assert any("t.aot_reject" in r.getMessage()
+                   and "compiles this site again" in r.getMessage()
+                   for r in caplog.records)
+    finally:
+        telemetry.reset()
+        telemetry.disable()
+        xprof.disable()
+        xprof.reset()

@@ -256,7 +256,7 @@ def test_fsdp_spec_helpers():
     mesh = make_mesh({"dp": 2, "fsdp": 4})
     assert mesh_axis_sizes(mesh) == {"dp": 2, "fsdp": 4}
     assert batch_spec(mesh, 0) == P(("dp", "fsdp"))
-    assert fsdp_param_spec((8, 4), mesh) == P("fsdp", None)
+    assert fsdp_param_spec((8, 4), mesh) == P("fsdp")
     assert fsdp_param_spec((6, 4), mesh) == P()      # 6 % 4 != 0
     assert fsdp_param_spec((), mesh) == P()          # scalar
     dp_only = make_mesh({"dp": 8})

@@ -250,7 +250,8 @@ def test_parse_error_is_reported_not_raised():
 def test_repo_tree_has_no_unbaselined_findings():
     findings = graftlint.analyze_paths(
         [os.path.join(ROOT, "mxnet_tpu"), os.path.join(ROOT, "tools"),
-         os.path.join(ROOT, "bench.py")], root=ROOT)
+         os.path.join(ROOT, "bench.py"),
+         os.path.join(ROOT, "chip_smoke.py")], root=ROOT)
     baseline = graftlint.load_baseline(
         os.path.join(ROOT, "tools", "graftlint_baseline.json"))
     new, _ = graftlint.partition(findings, baseline)

@@ -95,8 +95,9 @@ def test_repo_artifact_has_no_untagged_impossible_rows():
 
 
 def test_main_refuses_to_print_invalid_rows(monkeypatch, capsys):
-    """stdout is the .jsonl destination (chip_watch appends it): an
-    invalid measurement must go to stderr only."""
+    """stdout is the .jsonl destination (callers append it to
+    MFU_EXPERIMENTS.jsonl): an invalid measurement must go to stderr
+    only."""
     import mfu_experiments as mfu
 
     def fake_measure(variant, batch, image, num_classes, steps, dtype):
@@ -110,18 +111,3 @@ def test_main_refuses_to_print_invalid_rows(monkeypatch, capsys):
     cap = capsys.readouterr()
     assert cap.out.strip() == ""
     assert "REFUSING" in cap.err
-
-
-def test_chip_watch_scrubs_jsonl_stdout():
-    import chip_watch
-
-    good = json.dumps(_row())
-    bad = json.dumps(_row(mfu_pct=700.0))
-    tagged = json.dumps(dict(_row(mfu_pct=700.0), valid=False,
-                             invalid_reason="x"))
-    text = "\n".join([good, bad, tagged]) + "\n"
-    out = chip_watch._scrub_jsonl(text)
-    lines = [l for l in out.splitlines() if l.strip()]
-    assert good in lines
-    assert bad not in lines
-    assert tagged in lines

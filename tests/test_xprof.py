@@ -144,9 +144,26 @@ def test_analyze_roofline_classification():
     assert hi["analytic_mfu_pct"] > 0
     lo = xprof.analyze(1e9, 1e9, device_kind="v5e")
     assert lo["bound"] == "bandwidth"
-    cpu = xprof.analyze(1e9, 1e9, step_time_s=0.1)  # unknown chip
-    assert cpu["analytic_mfu_pct"] == 0.0
-    assert cpu["bound"] == "unknown"
+    # the CPU is an explicit "no peak": no MFU field at all, never a 0.0
+    # that reads as a measurement
+    cpu = xprof.analyze(1e9, 1e9, step_time_s=0.1)
+    assert "analytic_mfu_pct" not in cpu
+    assert cpu["peak_tflops"] is None and cpu["bound"] == "unknown"
+    assert cpu["achieved_tflops"] > 0
+
+
+def test_peak_lookup_one_table_unknown_accelerator_raises():
+    assert xprof.chip_peaks("TPU v5 lite") == (197, 819)
+    assert xprof.chip_peak_tflops("TPU v5 lite") == 197
+    assert xprof.chip_hbm_gbps("TPU v5e") == 819
+    assert xprof.chip_peaks("cpu") is None
+    for kind in ("TPU v9 mystery", "NVIDIA H100", "", None):
+        with pytest.raises(MXNetError, match="no published peak"):
+            xprof.chip_peaks(kind)
+    # bench.py holds no table of its own
+    import bench
+
+    assert not hasattr(bench, "CHIP_PEAK_TFLOPS")
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ headline metric:
 A metric regresses when it moves in the WRONG direction by more than
 its tolerance (relative); improvements always pass and never fail the
 gate. A missing artifact or one stamped ``"incomplete"`` reports
-INCOMPLETE — exit 0, so an unattended chip_watch window that produced
+INCOMPLETE — exit 0, so an unattended run that produced
 no artifact does not page anyone (``--strict`` upgrades INCOMPLETE to
 failure for interactive use).
 

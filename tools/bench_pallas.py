@@ -117,8 +117,6 @@ def bench_flash_attention():
 
 def main():
     import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     dev = jax.devices()[0]
     print(json.dumps({"device": getattr(dev, "device_kind", dev.platform)}))
     for name, shape, pallas_us, xla_us in (bench_fused_linear()

@@ -45,9 +45,9 @@ RESNET50_TRAIN_GFLOPS_PER_IMG = 4.089 * 3
 
 
 def _chip_peak(kind):
-    from bench import _chip_peak as peak
+    from mxnet_tpu.xprof import chip_peak_tflops
 
-    return peak(kind)
+    return chip_peak_tflops(kind)
 
 
 def validate(result):
@@ -187,21 +187,13 @@ def measure(variant, batch, image, num_classes, steps, dtype_name):
     # executable's true FLOP count (validate() turns it into the
     # analytic floor) and the measured executable is what we dispatch,
     # so the instrumentation never pays the compile twice
-    step_fn = jit_step
-    compile_time_s = None
-    flops_per_step = None
-    try:
-        from mxnet_tpu import xprof
+    from mxnet_tpu import xprof
 
-        tic_c = time.time()
-        compiled = jit_step.lower(params, data, aux, key).compile()
-        compile_time_s = time.time() - tic_c
-        rec = xprof.record_compile("mfu_experiments.%s" % variant,
-                                   compiled, compile_time_s)
-        flops_per_step = rec.flops
-        step_fn = compiled
-    except Exception:
-        pass
+    tic_c = time.time()
+    step_fn = jit_step.lower(params, data, aux, key).compile()
+    compile_time_s = time.time() - tic_c
+    flops_per_step = xprof.record_compile(
+        "mfu_experiments.%s" % variant, step_fn, compile_time_s).flops
 
     def _force(tree):
         # fetch a scalar: block_until_ready alone can under-synchronize
@@ -212,12 +204,7 @@ def measure(variant, batch, image, num_classes, steps, dtype_name):
         leaf = next(iter(tree.values())) if isinstance(tree, dict) else tree
         return float(np.asarray(leaf.sum()))
 
-    try:
-        outputs, params, aux = step_fn(params, data, aux, key)
-    except TypeError:
-        # the AOT input check is stricter than jit dispatch; fall back
-        step_fn = jit_step
-        outputs, params, aux = step_fn(params, data, aux, key)
+    outputs, params, aux = step_fn(params, data, aux, key)
     outputs, params, aux = step_fn(params, data, aux,
                                    jax.random.fold_in(key, 999))
     _force(params)
@@ -243,8 +230,7 @@ def measure(variant, batch, image, num_classes, steps, dtype_name):
         # lines without this field under-synchronized and are invalid
         "fence": "scalar_fetch",
     }
-    if compile_time_s is not None:
-        result["compile_time_s"] = round(compile_time_s, 3)
+    result["compile_time_s"] = round(compile_time_s, 3)
     if flops_per_step:
         result["flops_per_step"] = flops_per_step
     peak = _chip_peak(getattr(dev, "device_kind", "")) \
@@ -325,8 +311,6 @@ def main(argv=None):
         return
 
     import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     on_accel = jax.devices()[0].platform != "cpu"
     batch = args.batch or (256 if on_accel else 4)
     image = args.image or (224 if on_accel else 32)
@@ -340,7 +324,7 @@ def main(argv=None):
     for v in variants:
         r = measure(v, batch, image, num_classes, steps, dtype)
         if r.get("valid") is False:
-            # stdout is what chip_watch appends to MFU_EXPERIMENTS.jsonl;
+            # stdout is what callers append to MFU_EXPERIMENTS.jsonl;
             # a physically impossible measurement is evidence of a broken
             # fence, not of performance — refuse to record it
             sys.stderr.write(

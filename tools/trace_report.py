@@ -1163,10 +1163,9 @@ def _repo_root():
 
 def profile_report(top=10):
     """`make profile-report`: run the xprof views against the newest
-    BENCH / chip_watch artifacts in the repo root."""
+    BENCH artifacts in the repo root."""
     root = _repo_root()
-    candidates = [os.path.join(root, "BENCH_watch.json"),
-                  os.path.join(root, ".bench_cache.json")]
+    candidates = [os.path.join(root, "BENCH_watch.json")]
     import glob
 
     candidates += sorted(glob.glob(os.path.join(root, "BENCH_r*.json")),
@@ -1194,22 +1193,6 @@ def profile_report(top=10):
         if trees:
             out.append("distributed traces (FLEET_trace.json):\n")
             out.append(render_trace_summary(trees, top=3))
-    dev = os.path.join(root, "XPROF_DEVICE_TIME.json")
-    if os.path.exists(dev):
-        rows = load_bench_records(dev)
-        if rows:
-            last = rows[-1]
-            out.append("chip_watch device-time artifact "
-                       "(XPROF_DEVICE_TIME.json):\n")
-            cats = last.get("device_time_by_category") or {}
-            if cats:
-                t = [("category", "ms/step", "share")]
-                tot = sum(cats.values()) or 1.0
-                for c, ms in sorted(cats.items(), key=lambda kv: -kv[1]):
-                    t.append((c, "%.2f" % ms, "%.1f%%" % (100 * ms / tot)))
-                out.append("\n".join(_table(t)) + "\n")
-            if last.get("incomplete"):
-                out.append("  INCOMPLETE: %s\n" % last["incomplete"])
     return "\n".join(out)
 
 
@@ -1288,7 +1271,7 @@ def main(argv=None):
                         "model-health table + overhead verdict over a "
                         "NUMWATCH_health.json artifact (path optional)")
     p.add_argument("--profile-report", action="store_true",
-                   help="auto-discover the newest BENCH / chip_watch "
+                   help="auto-discover the newest BENCH "
                         "artifacts in the repo root and render the "
                         "bench view (used by `make profile-report`)")
     a = p.parse_args(argv)
