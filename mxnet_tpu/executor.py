@@ -114,7 +114,11 @@ def make_graph_eval(symbol, node_device=None, remat=False):
             aux_in = [aux_out[s] for s in slots]
             rng = jax.random.fold_in(key, n.uid) if key is not None else None
             octx = OpContext(is_train, rng)
-            outs, new_aux = n.op.apply(octx, ins, aux_in)
+            # the node's name on every op it lowers to (metadata only;
+            # an operator made outside the registry has its class's)
+            op_name = getattr(n.op, "op_name", type(n.op).__name__)
+            with jax.named_scope("%s:%s" % (op_name, n.name)):
+                outs, new_aux = n.op.apply(octx, ins, aux_in)
             for s, a in zip(slots, new_aux):
                 aux_out[s] = a
             env[n.uid] = list(outs)
@@ -400,8 +404,6 @@ class Executor:
                 # monitor flag shows up as a climbing jit_build count)
                 _tel.inc("executor.jit_build")
                 fwd_bwd_cache[k] = make_fwd_bwd(*k)
-            else:
-                _tel.inc("executor.jit_cache_hit")
             return fwd_bwd_cache[k]
 
         def make_fwd_bwd(want_internals, donate):
@@ -488,8 +490,6 @@ class Executor:
                 raise MXNetError("forward: unknown argument '%s'" % name)
             self.arg_dict[name][:] = arr
         _tel.inc("executor.forward")
-        if is_train:
-            _tel.inc("executor.forward_train")
         self._last_key = self._key()
         if is_train:
             # lazy: the fused fwd+bwd in backward() materializes outputs;

@@ -275,8 +275,30 @@ class FusedTrainStep:
         return {"params": params, "aux": aux, "updater_slots": slots}
 
     # ------------------------------------------------------------------
+    def jit_entries(self) -> int:
+        """Compiled entries the step's jits hold: what the jits
+        themselves count, so a step that compiled twice behind one shape
+        signature (``step.fused_recompiles`` sees shapes only) shows."""
+        return sum(fn._cache_size() for fn in self._jit_cache.values())
+
     def step(self, data_batch, eval_metric):
         """Run one training batch as one XLA dispatch."""
+        with _tel.span("step.marshal"):
+            do, const_vars, mutable_vars = self._marshal(data_batch)
+        get_engine().push(do, const_vars=const_vars,
+                          mutable_vars=mutable_vars, prop="fused_step")
+        self._module._params_dirty = True
+        _tel.inc("step.fused_steps")
+        if self._fold_leaves is None:
+            # unsupported metric: update host-side from the fused step's
+            # outputs — still one dispatch for fwd+bwd+update
+            eval_metric.update(data_batch.label, self._executor.outputs)
+
+    def _marshal(self, data_batch):
+        """Host work of one step that cannot trace: place the batch,
+        the optimizer's per-step plans, the key, the argument packs.
+        Returns the closure that dispatches it and the engine
+        variables it reads and writes."""
         import jax.numpy as jnp
 
         ex = self._executor
@@ -357,8 +379,9 @@ class FusedTrainStep:
               None if nw is None else nw.trace_key)
         fn = self._jit_cache.get(ck)
         if fn is None:
-            fn = self._build(specs, clip is not None, donate, fold, feed,
-                             watch=nw)
+            with _tel.span("step.build"):
+                fn = self._build(specs, clip is not None, donate, fold,
+                                 feed, watch=nw)
             self._jit_cache[ck] = fn
 
         with _san.intentional_transfer():
@@ -424,13 +447,13 @@ class FusedTrainStep:
         # RecompileDetector turns into an anomaly event
         sig = ck + (tuple((v.shape, str(v.dtype))
                           for v in p_vals + o_vals + aux_vals),)
-        if sig not in self._seen_sigs:
+        new_entry = sig not in self._seen_sigs
+        if new_entry:
             self._seen_sigs.add(sig)
             _tel.inc("step.fused_recompiles")
         if self._retrace_san is not None:
             self._retrace_san.check(len(self._seen_sigs))
 
-        module = self._module
         mut = [nd._var for nd in p_nds] \
             + [a._var for a in ex.aux_arrays] \
             + [s._var for grp in state_nds for member in grp
@@ -446,24 +469,38 @@ class FusedTrainStep:
                         accs, key)
             if aug_vals is not None:
                 args = args + (aug_vals,)
-            res = fn(*args)
-            if nw is not None:
-                new_p, outs, aux_out, new_st, new_accs, new_stats = res
-                nw.write_back(new_stats)
+            if new_entry:
+                # the first call of a new jit entry traces, lowers and
+                # compiles (or reads the cache) before it dispatches
+                with _tel.span("step.build"):
+                    with _tel.span("step.dispatch"):
+                        res = fn(*args)
             else:
-                new_p, outs, aux_out, new_st, new_accs = res
-            for nd, v in zip(p_nds, new_p):
-                nd._data = v
-            for nd, v in zip(ex.aux_arrays, aux_out):
-                nd._data = v
-            for grp, new_grp in zip(state_nds, new_st):
-                for member, new_member in zip(grp, new_grp):
-                    for snd, sv in zip(member, new_member):
-                        snd._data = sv
-            for leaf, acc in zip(leaves, new_accs):
-                leaf._device_acc = acc
-            ex._set_outputs(outs)
-            ex._train_pending = False
+                with _tel.span("step.dispatch"):
+                    res = fn(*args)
+            if _tel.enabled():
+                # after every dispatch, not only a build the signature
+                # announced: the entry that matters is the one it missed
+                _tel.set_gauge("step.fused_jit_entries",
+                               self.jit_entries())
+            with _tel.span("step.write_back"):
+                if nw is not None:
+                    new_p, outs, aux_out, new_st, new_accs, new_stats = res
+                    nw.write_back(new_stats)
+                else:
+                    new_p, outs, aux_out, new_st, new_accs = res
+                for nd, v in zip(p_nds, new_p):
+                    nd._data = v
+                for nd, v in zip(ex.aux_arrays, aux_out):
+                    nd._data = v
+                for grp, new_grp in zip(state_nds, new_st):
+                    for member, new_member in zip(grp, new_grp):
+                        for snd, sv in zip(member, new_member):
+                            snd._data = sv
+                for leaf, acc in zip(leaves, new_accs):
+                    leaf._device_acc = acc
+                ex._set_outputs(outs)
+                ex._train_pending = False
             if donate and _san.enabled("donation"):
                 # argnums (0, 2, 3, 5[, 6]): params, aux, opt states,
                 # accs, and the numwatch stats pack when armed
@@ -475,14 +512,7 @@ class FusedTrainStep:
                     + ([stats] if stats is not None else []))
             return list(new_p)
 
-        get_engine().push(_do, const_vars=[nd._var for nd in o_nds],
-                          mutable_vars=mut, prop="fused_step")
-        module._params_dirty = True
-        _tel.inc("step.fused_steps")
-        if not fold:
-            # unsupported metric: update host-side from the fused step's
-            # outputs — still one dispatch for fwd+bwd+update
-            eval_metric.update(data_batch.label, ex.outputs)
+        return _do, [nd._var for nd in o_nds], mut
 
     # ------------------------------------------------------------------
     def _build(self, specs, clipped, donate, fold, feed=None, watch=None):
@@ -566,27 +596,33 @@ class FusedTrainStep:
                     fl[i] = pv[pos]
                 return run_graph(fl, aux, key, True)
 
-            res, vjp = jax.vjp(f, list(p_vals))
+            # the scopes are names on the compiled ops (metadata only):
+            # a device trace tells the four phases apart by them
+            with jax.named_scope("fwd"):
+                res, vjp = jax.vjp(f, list(p_vals))
             outs, aux_out = res
-            heads = [jnp.ones_like(o)
-                     if jnp.issubdtype(o.dtype, jnp.inexact)
-                     else zero_cotangent(o) for o in outs]
-            cts = (heads, jax.tree_util.tree_map(zero_cotangent, aux_out))
-            grads, = vjp(cts)
-            if grad_shardings is not None:
-                grads = [jax.lax.with_sharding_constraint(g, s)
-                         for g, s in zip(grads, grad_shardings)]
+            with jax.named_scope("bwd"):
+                heads = [jnp.ones_like(o)
+                         if jnp.issubdtype(o.dtype, jnp.inexact)
+                         else zero_cotangent(o) for o in outs]
+                cts = (heads,
+                       jax.tree_util.tree_map(zero_cotangent, aux_out))
+                grads, = vjp(cts)
+                if grad_shardings is not None:
+                    grads = [jax.lax.with_sharding_constraint(g, s)
+                             for g, s in zip(grads, grad_shardings)]
             new_p = list(p_vals)
             new_st = []
-            for gi, (kind, n_states, positions) in enumerate(specs):
-                math_fn = math_fns[(kind, n_states)]
-                grp = []
-                for j, pos in enumerate(positions):
-                    nw, ns = math_fn(new_p[pos], grads[pos], st[gi][j],
-                                     sv_mats[gi][j])
-                    new_p[pos] = nw
-                    grp.append(ns)
-                new_st.append(tuple(grp))
+            with jax.named_scope("update"):
+                for gi, (kind, n_states, positions) in enumerate(specs):
+                    math_fn = math_fns[(kind, n_states)]
+                    grp = []
+                    for j, pos in enumerate(positions):
+                        nw, ns = math_fn(new_p[pos], grads[pos],
+                                         st[gi][j], sv_mats[gi][j])
+                        new_p[pos] = nw
+                        grp.append(ns)
+                    new_st.append(tuple(grp))
             if grad_shardings is not None:
                 # every piece of carried state leaves the step on the
                 # sharding it entered with. Updated params stay on their
@@ -614,12 +650,13 @@ class FusedTrainStep:
             labels = [o_vals[p] for p in label_pos]
             if fold:
                 new_accs = []
-                for leaf, (s, c) in zip(leaves, accs):
-                    for lab, pred in zip(labels, outs):
-                        ds, dc = leaf.device_fold(lab, pred)
-                        s = s + ds
-                        c = c + dc
-                    new_accs.append((s, c))
+                with jax.named_scope("metric"):
+                    for leaf, (s, c) in zip(leaves, accs):
+                        for lab, pred in zip(labels, outs):
+                            ds, dc = leaf.device_fold(lab, pred)
+                            s = s + ds
+                            c = c + dc
+                        new_accs.append((s, c))
                 new_accs = tuple(new_accs)
             new_p = tuple(new_p)
             new_st = tuple(new_st)

@@ -11,6 +11,7 @@ from typing import Dict, List, Optional
 from ..base import MXNetError
 from .. import metric as _metric
 from .. import ndarray as nd
+from .. import numwatch as _numwatch
 from .. import telemetry as _tel
 from .. import tracing as _tracing
 from ..analysis import sanitizers as _san
@@ -21,6 +22,8 @@ __all__ = ["BaseModule", "BatchEndParam"]
 
 BatchEndParam = namedtuple("BatchEndParam",
                            ["epoch", "nbatch", "eval_metric", "locals"])
+
+_EPOCH_OVER = object()   # what next() gives fit when the iterator ends
 
 
 def _as_list(obj):
@@ -250,16 +253,20 @@ class BaseModule:
         """The training loop (reference ``base_module.py:275`` fit)."""
         if num_epoch is None:
             raise MXNetError("num_epoch must be specified")
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
+        with _tel.span("fit.bind"):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
+        with _tel.span("fit.init_params"):
+            self.init_params(initializer=initializer,
+                             arg_params=arg_params, aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        with _tel.span("fit.init_optimizer"):
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
         if validation_metric is None:
             validation_metric = eval_metric
         eval_metric = _metric.create(eval_metric)
@@ -287,7 +294,8 @@ class BaseModule:
         # into ONE donated XLA dispatch per batch; None falls back to
         # the classic three-phase loop (dist kvstores, custom-update
         # optimizers, monitors, grad_req="add")
-        fused = self._fused_train_step(eval_metric)
+        with _tel.span("fit.fused_build"):
+            fused = self._fused_train_step(eval_metric)
 
         # MXNET_TPU_CKPT_DIR: preemption-safe full-state snapshots —
         # periodic saves every MXNET_TPU_CKPT_EVERY_N_STEPS, auto-resume
@@ -319,7 +327,6 @@ class BaseModule:
                     batch_end_callback, eval_batch_end_callback,
                     monitor, fused, ckpt, resume, begin_epoch, num_epoch,
                     numwatch=None):
-        from .. import numwatch as _numwatch
         for epoch in range(begin_epoch, num_epoch):
             if resume is not None and epoch < resume["epoch"]:
                 continue
@@ -332,10 +339,6 @@ class BaseModule:
             if not resuming:
                 eval_metric.reset()
                 train_data.reset()
-            # step latency is measured boundary-to-boundary so the data
-            # fetch (where input stalls accrue) is attributed to the
-            # step that waited on it, not lost between timers
-            t_last = time.perf_counter() if _tel.enabled() else 0.0
             nbatch = nbatch_base - 1
             # MXNET_TPU_SANITIZE=transfer (fused path only: the classic
             # loop updates metrics host-side by design): any implicit
@@ -344,52 +347,25 @@ class BaseModule:
             # intentional_transfer() windows
             guard = (_san.step_guard() if fused is not None
                      else _contextlib.nullcontext())
+            batches = iter(train_data)
             try:
                 with guard:
-                    for data_batch in train_data:
-                        nbatch += 1
-                        if monitor is not None:
-                            monitor.tic()
-                        if ckpt is not None:
-                            # SIGTERM inside this window defers to the
-                            # step boundary (donated packs are torn
-                            # mid-dispatch)
-                            ckpt.step_begin()
-                        if fused is not None:
-                            fused.step(data_batch, eval_metric)
-                        else:
-                            # device-feed batches (batch.aug) are
-                            # materialized eagerly inside
-                            # load_data_batch on this path
-                            self.forward_backward(data_batch)
-                            self.update()
-                            self.update_metric(eval_metric,
-                                               data_batch.label)
-                        if ckpt is not None:
-                            # packs whole again: periodic cadence save,
-                            # or the deferred preempt save + exit
-                            ckpt.step_end(epoch, nbatch)
-                        # numerics plane: one None check when disabled;
-                        # on the EVERY_N cadence a single small D2H
-                        # fetch of the stats pack plus guard actions
-                        nw_extra = _numwatch.after_step(numwatch)
-                        if monitor is not None:
-                            monitor.toc_print()
-                        if _tel.enabled():
-                            now = time.perf_counter()
-                            extra = {"epoch": epoch, "nbatch": nbatch}
-                            if nw_extra:
-                                extra.update(nw_extra)
-                            _tracing.record_step(
-                                (now - t_last) * 1e3, extra=extra)
-                            t_last = now
-                        if batch_end_callback is not None:
-                            params = BatchEndParam(
-                                epoch=epoch, nbatch=nbatch,
-                                eval_metric=eval_metric,
-                                locals=locals())
-                            for cb in _as_list(batch_end_callback):
-                                cb(params)
+                    while True:
+                        _tel.next_step()
+                        # one iteration, boundary to boundary: the data
+                        # fetch (where input stalls accrue) belongs to
+                        # the step that waited on it
+                        with _tel.span("fit.step") as step_span:
+                            with _tel.span("fit.next"):
+                                data_batch = next(batches, _EPOCH_OVER)
+                            if data_batch is _EPOCH_OVER:
+                                step_span.cancel()
+                                break
+                            nbatch += 1
+                            self._fit_step(
+                                data_batch, epoch, nbatch, eval_metric,
+                                fused, ckpt, numwatch, monitor,
+                                batch_end_callback, step_span)
             except Exception as e:
                 if _san.is_transfer_guard_error(e):
                     _san.record_trip("transfer")
@@ -419,6 +395,51 @@ class BaseModule:
                 for name, val in res:
                     self.logger.info("Epoch[%d] Validation-%s=%f",
                                      epoch, name, val)
+
+    def _fit_step(self, data_batch, epoch, nbatch, eval_metric, fused,
+                  ckpt, numwatch, monitor, batch_end_callback, step_span):
+        """One batch of ``fit``: the step, then what runs at its end."""
+        if monitor is not None:
+            monitor.tic()
+        if ckpt is not None:
+            # SIGTERM inside this window defers to the step boundary
+            # (donated packs are torn mid-dispatch)
+            ckpt.step_begin()
+        if fused is not None:
+            fused.step(data_batch, eval_metric)
+        else:
+            # device-feed batches (batch.aug) are materialized eagerly
+            # inside load_data_batch on this path
+            with _tel.span("fit.forward_backward"):
+                self.forward_backward(data_batch)
+            with _tel.span("fit.update"):
+                self.update()
+            with _tel.span("fit.update_metric"):
+                self.update_metric(eval_metric, data_batch.label)
+        with _tel.span("fit.callbacks"):
+            if ckpt is not None:
+                # packs whole again: periodic cadence save, or the
+                # deferred preempt save + exit
+                ckpt.step_end(epoch, nbatch)
+            # numerics plane: one None check when disabled; on the
+            # EVERY_N cadence a single small D2H fetch of the stats
+            # pack plus guard actions
+            nw_extra = _numwatch.after_step(numwatch)
+            if monitor is not None:
+                monitor.toc_print()
+            if batch_end_callback is not None:
+                params = BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                       eval_metric=eval_metric,
+                                       locals=locals())
+                for cb in _as_list(batch_end_callback):
+                    cb(params)
+            if _tel.enabled():
+                extra = {"epoch": epoch, "nbatch": nbatch}
+                if nw_extra:
+                    extra.update(nw_extra)
+                # the step's last act, so that the latency is the
+                # fit.step span's but for this record itself
+                _tracing.record_step(step_span.elapsed_ms(), extra=extra)
 
     def _fused_train_step(self, eval_metric):
         """Hook: an object with ``.step(data_batch, eval_metric)`` that

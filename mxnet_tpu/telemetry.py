@@ -15,9 +15,10 @@ registry those layers report through:
   reservoir of recent samples for percentiles. Memory is O(capacity)
   no matter how long the job runs.
 * **Spans** — host-side wall-time intervals (``with telemetry.span(n)``)
-  kept in a bounded ring; when an XLA trace capture is active they also
-  emit ``TraceAnnotation`` so host work lines up with device ops in the
-  same Perfetto view.
+  kept in a bounded ring with their thread, enclosing span and step, and
+  written as ``TraceAnnotation("mx:<n>")`` so that a profiler capture
+  (``jax.profiler.start_trace`` or ``mx.profiler.start``) shows host
+  work on the same timeline as the device ops.
 
 Overhead contract: telemetry is DISABLED by default; every recording
 helper starts with one module-level flag check and returns immediately,
@@ -38,7 +39,6 @@ JSONL schema.
 from __future__ import annotations
 
 import bisect
-import contextlib
 import copy
 import json
 import os
@@ -51,7 +51,8 @@ from . import env as _env
 from .base import MXNetError
 
 __all__ = ["enabled", "enable", "disable", "counter", "gauge", "histogram",
-           "inc", "set_gauge", "observe", "span", "snapshot", "reset",
+           "inc", "set_gauge", "observe", "span", "spans", "next_step",
+           "snapshot", "reset",
            "dump_jsonl", "write_chrome_trace", "Counter", "Gauge",
            "Histogram", "peek", "metrics_items", "merge_snapshots",
            "bucket_quantile", "sample_quantile", "DEFAULT_BUCKET_BOUNDS"]
@@ -72,14 +73,72 @@ _EPOCH = time.time() - time.perf_counter()
 _step_lock = threading.Lock()
 _step = 0
 
+# the step number ring entries carry: what the spans of one training
+# step share. Bumped by next_step() (the fit loop, once an iteration);
+# set-up's spans carry 0
+_span_step = 0
+_tls = threading.local()      # per-thread stack of open spans
+# what spans need of JAX, resolved once by _hook_jax() on the way to
+# being enabled (importing this module must not import jax.profiler)
+_TraceAnnotation = None
+_outermost_trace = None
+
+# JAX's own duration events -> the span each is recorded as (the event
+# arrives when the work ends, so the span is laid back from "now")
+_JAX_EVENT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_read",
+}
+
 
 def enabled() -> bool:
     return _ENABLED
 
 
+def _on_jax_duration(event, duration_secs, **_kw):
+    if not _ENABLED:
+        return
+    name = _JAX_EVENT_SPANS.get(event)
+    # a trace that ends inside another (the jitted helpers a traced
+    # function calls, thousands in one train step) is left out: the
+    # enclosing jax.trace covers its time, and the ring is bounded
+    if name is not None and (_outermost_trace is None
+                             or _outermost_trace()):
+        stack = getattr(_tls, "stack", None)
+        _spans.append((name, threading.get_ident(),
+                       time.perf_counter() - duration_secs, duration_secs,
+                       stack[-1].name if stack else None, _span_step))
+
+
+def _hook_jax():
+    """Once per process: JAX's ``TraceAnnotation`` for the spans, and a
+    listener through which every program JAX traces, lowers, builds or
+    reads from its persistent cache while telemetry is on leaves a
+    ``jax.*`` span, whichever call site asked for it."""
+    global _TraceAnnotation, _outermost_trace
+    with _reg_lock:
+        if _TraceAnnotation is not None:
+            return
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    from jax import monitoring
+    try:
+        from jax._src import core as _jax_core
+        _outermost_trace = _jax_core.trace_state_clean
+    except (ImportError, AttributeError):
+        pass                 # a JAX without it: every trace is recorded
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 def enable():
     global _ENABLED
+    _hook_jax()
     _ENABLED = True
+
+
+if _ENABLED:
+    _hook_jax()
 
 
 def disable():
@@ -283,42 +342,92 @@ def observe(name: str, v: float):
 
 
 # -- spans ---------------------------------------------------------------
-@contextlib.contextmanager
+class _NoSpan:
+    """What :func:`span` hands out while telemetry is off: one shared
+    object whose every method does nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def cancel(self):
+        pass
+
+    def elapsed_ms(self) -> float:
+        return 0.0
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("name", "t0", "parent", "step", "_ann", "_keep")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._keep = True
+
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self.parent = stack[-1].name if stack else None
+        self.step = _span_step
+        stack.append(self)
+        # outside a profiler capture this is a no-op of the runtime's
+        self._ann = _TraceAnnotation("mx:" + self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = time.perf_counter() - self.t0
+        self._ann.__exit__(None, None, None)
+        _tls.stack.pop()
+        if self._keep:
+            _spans.append((self.name, threading.get_ident(), self.t0, dur,
+                           self.parent, self.step))
+            observe("span.%s_ms" % self.name, dur * 1e3)
+        return False
+
+    def cancel(self):
+        """The interval turned out not to be one (the fit loop's
+        ``fit.step`` whose ``next()`` found the epoch over): leave no
+        ring entry and no histogram sample."""
+        self._keep = False
+
+    def elapsed_ms(self) -> float:
+        return (time.perf_counter() - self.t0) * 1e3
+
+
 def span(name: str):
-    """Host-side named interval. Recorded into the bounded span ring and
-    the ``span.<name>_ms`` histogram; while an XLA trace capture is
-    running it additionally nests a ``TraceAnnotation`` so the interval
-    shows up inside the device trace too."""
+    """Host-side named interval, ``with telemetry.span(name):``. Recorded
+    into the bounded span ring and the ``span.<name>_ms`` histogram, and
+    written as ``TraceAnnotation("mx:<name>")``, so that any profiler
+    capture shows it beside the device's work. Off, it returns one
+    shared object that does nothing."""
     if not _ENABLED:
-        yield
-        return
-    ann = None
-    try:
-        from . import profiler as _prof
+        return _NO_SPAN
+    return _Span(name)
 
-        if _prof.is_running():
-            import jax
 
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-    except Exception:
-        ann = None
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dur = time.perf_counter() - t0
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
-        _spans.append((name, threading.get_ident(), t0, dur))
-        observe("span.%s_ms" % name, dur * 1e3)
+def next_step():
+    """A new training step begins: spans opened from here on carry the
+    next step number."""
+    global _span_step
+    if _ENABLED:
+        _span_step += 1
 
 
 def spans():
-    """The buffered (name, tid, start_perf_counter, duration_s) tuples."""
+    """The buffered ``(name, tid, start, duration_s, parent, step)``
+    tuples, oldest first: start on ``time.perf_counter()``, ``parent``
+    the name of the span that enclosed this one on its thread (or
+    None), ``step`` the number :func:`next_step` had reached."""
     return list(_spans)
 
 
@@ -342,7 +451,7 @@ def write_chrome_trace(path: str, extra_events: Optional[list] = None):
              "args": {"name": "%s (pid %d)"
                       % (os.path.basename(sys.argv[0] or "python"),
                          pid)}}]
-    for tid in sorted({tid for _, tid, _, _ in spans}):
+    for tid in sorted({sp[1] for sp in spans}):
         meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                      "tid": tid,
                      "args": {"name": thread_names.get(
@@ -351,7 +460,7 @@ def write_chrome_trace(path: str, extra_events: Optional[list] = None):
         {"name": name, "ph": "X", "cat": "host",
          "pid": pid, "tid": tid,
          "ts": (t0 + _EPOCH) * 1e6, "dur": dur * 1e6}
-        for name, tid, t0, dur in spans]
+        for name, tid, t0, dur, _parent, _step_no in spans]
     if extra_events:
         events.extend(extra_events)
     with open(path, "w") as f:
@@ -544,9 +653,10 @@ def dump_jsonl(path: str, extra: Optional[dict] = None) -> dict:
 def reset():
     """Clear every metric, span, and the step counter (bench/test
     isolation). The enabled flag is left as-is."""
-    global _step
+    global _step, _span_step
     with _reg_lock:
         _metrics.clear()
     _spans.clear()
+    _span_step = 0
     with _step_lock:
         _step = 0

@@ -384,6 +384,14 @@ class _InstrumentedJit:
         # HLO regression gates lower() the raw jit; keep that working
         return self._jit.lower(*args, **kw)
 
+    def _cache_size(self) -> int:
+        """Executables built for this site, as ``jax.jit``'s own
+        ``_cache_size``: the measured AOT ones and what the plain jit
+        compiled for signatures that fell back to it."""
+        with self._lock:
+            aot = sum(c is not _FALLBACK for c in self._cache.values())
+        return aot + self._jit._cache_size()
+
     def __call__(self, *args):
         sig = leaf_signature(args, self._arg_names)
         with self._lock:

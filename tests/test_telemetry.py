@@ -84,10 +84,11 @@ def test_disabled_mode_records_nothing():
 def test_span_records_interval_and_histogram():
     with telemetry.span("work"):
         pass
-    (name, tid, _t0, dur) = telemetry.spans()[-1]
+    (name, tid, _t0, dur, parent, step) = telemetry.spans()[-1]
     assert name == "work"
     assert tid == threading.get_ident()
     assert dur >= 0.0
+    assert parent is None and step == 0
     snap = telemetry.snapshot()
     assert snap["span"]["work_ms"]["count"] == 1
 
