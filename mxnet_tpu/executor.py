@@ -24,6 +24,7 @@ not update moving stats).
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -53,6 +54,122 @@ def zero_cotangent(x):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
+class _ConvGroup:
+    """Sibling convolutions lowered as one (:func:`_plan_conv_groups`).
+    ``links[d]`` holds every member's node at link ``d`` of its chain, as
+    far as the merge reaches: the convolutions, then their BatchNorms,
+    then their Activations."""
+
+    __slots__ = ("links", "conv_op", "offsets", "channel_axis")
+
+    def __init__(self, links, channel_axis):
+        from .ops.nn import Convolution
+
+        self.links = links
+        self.channel_axis = channel_axis
+        self.offsets = [0]
+        for conv in links[0]:
+            self.offsets.append(self.offsets[-1] + conv.op.num_filter)
+        self.conv_op = Convolution(**dict(links[0][0].op.params,
+                                          num_filter=self.offsets[-1]))
+
+    def pays(self, data_shape):
+        """Whether the one wide convolution moves fewer bytes than its
+        members apart, reckoned from the shapes. Saved for certain: the
+        input is read by the forward pass and by the weight gradient, and
+        its gradient is written and read again for the sum, once instead
+        of once a member — 4 (k-1) passes over the input. At risk: up to
+        3 passes over the outputs, where the slices, their gradients'
+        concatenation and a BatchNorm pass come off the fusions they rode.
+        (On the v5e, PR 25: Inception-BN's ten groups, at least as wide in
+        as out, won 2.8 ms of a 50.6 ms step; ResNet-50's ``stage0_unit0``,
+        64 channels in and 64 + 256 out at 56x56, lost 1.9 of 100.2 ms.)"""
+        _, (out_shape,), _ = self.conv_op.infer_shape([tuple(data_shape)])
+        return 4 * (len(self.links[0]) - 1) * math.prod(data_shape) \
+            >= 3 * math.prod(out_shape)
+
+
+def _plan_conv_groups(op_nodes, out_index, node_device, seg_of):
+    """Find the sibling convolutions of a graph: two or more
+    ``Convolution`` nodes that read the same tensor with the same
+    geometry are one convolution of the summed output width — the same
+    multiply-adds, but the input is read once a pass instead of once a
+    member, and one input gradient is written instead of one a member to
+    be summed. XLA has no pass that joins them, so the lowering does.
+
+    The merge follows the members down their chains while they stay
+    alike: where every member's output feeds only a ``BatchNorm`` (equal
+    parameters, on the convolution's channel axis) those run as one over
+    the concatenated channels — per-channel statistics do not mix
+    channels — and where each of those feeds only an ``Activation`` of one
+    ``act_type``, that does too. Where the tails differ the merge ends at
+    the last common link. Decided from the graph alone: members share
+    device (``node_device``) and remat segment (``seg_of``), and every
+    other input of the merged nodes (weight, bias, gamma, beta) is there
+    before the first member's place in the order.
+
+    Returns ``{uid: _ConvGroup}`` for every node a group evaluates.
+    Whether a group is worth lowering as one is for the shapes to say,
+    when the program is traced (:meth:`_ConvGroup.pays`)."""
+    from .ops.nn import Activation, BatchNorm, Convolution
+
+    pos = {n.uid: i for i, n in enumerate(op_nodes)}
+    readers = {}
+    for n in op_nodes:
+        for slot, (src, i) in enumerate(n.inputs):
+            readers.setdefault((src.uid, i), []).append((n, slot))
+    for key in out_index:           # a graph output is read too
+        readers.setdefault(key, []).append((None, 0))
+
+    def place(n):
+        return (node_device(n) if node_device is not None else None,
+                seg_of.get(n.uid, 0))
+
+    def sole_reader(n):
+        """The node that alone reads ``n``'s output, as its data."""
+        users = readers.get((n.uid, 0), [])
+        if len(users) == 1 and users[0][1] == 0:
+            return users[0][0]
+        return None
+
+    def ready(link, first):
+        """Are the parameters of ``link`` there where ``first`` runs?"""
+        return all(pos.get(src.uid, -1) < pos[first.uid]
+                   for n in link for src, _ in n.inputs[1:])
+
+    siblings = {}
+    for n in op_nodes:
+        if type(n.op) is Convolution and n.op.num_group == 1:
+            key = (n.inputs[0][0].uid, n.inputs[0][1], n.op._norm_params(),
+                   n.op._is_nhwc(), n.op.no_bias, place(n))
+            siblings.setdefault(key, []).append(n)
+
+    group_of = {}
+    for convs in siblings.values():
+        first = convs[0]
+        if len(convs) < 2 or not ready(convs, first):
+            continue
+        ndim = len(first.op.kernel) + 2
+        caxis = ndim - 1 if first.op._is_nhwc() else 1
+        links = [convs]
+        for cls, alike in (
+                (BatchNorm, lambda op: (op.eps, op.momentum, op.fix_gamma,
+                                        op.use_global_stats)
+                 if op.axis % ndim == caxis else None),
+                (Activation, lambda op: op.act_type)):
+            nxt = [sole_reader(n) for n in links[-1]]
+            if any(n is None or type(n.op) is not cls for n in nxt):
+                break
+            kinds = {alike(n.op) for n in nxt}
+            if len(kinds) != 1 or None in kinds or not ready(nxt, first) \
+                    or {place(n) for n in nxt} != {place(first)}:
+                break
+            links.append(nxt)
+        group = _ConvGroup(links, caxis)
+        group_of.update((n.uid, group) for link in links for n in link)
+    return group_of
+
+
 def make_graph_eval(symbol, node_device=None, remat=False):
     """Build the pure graph-eval function for a symbol.
 
@@ -78,9 +195,17 @@ def make_graph_eval(symbol, node_device=None, remat=False):
     materialize every activation again at once.) Internals-mode calls
     fall back to the unsegmented path (monitoring wants every tensor
     live anyway).
-    """
-    import math
 
+    Sibling convolutions — Inception's parallel 1x1 branches, a ResNet
+    unit's first 1x1 beside its unstrided shortcut — are lowered as one
+    convolution of the summed width where that moves fewer bytes
+    (:func:`_plan_conv_groups` finds them here, from the graph;
+    :meth:`_ConvGroup.pays` decides from the shapes when a program is
+    traced; telemetry ``lower.conv_groups_merged`` /
+    ``lower.convs_merged`` count groups and members per traced program).
+    Parameter names, shapes and checkpoints are untouched.
+    ``want_internals=True`` lowers every node on its own, as before.
+    """
     import jax
 
     nodes = symbol._topo()
@@ -100,11 +225,89 @@ def make_graph_eval(symbol, node_device=None, remat=False):
                 slot += k
     n_aux = slot
     out_index = [(n.uid, i) for n, i in symbol._outputs]
+    op_nodes = [n for n in nodes if not n.is_variable]
+    segments = []
+    if remat:
+        # segmented remat (memonger / sqrt schedule)
+        n_seg = max(2, int(math.isqrt(len(op_nodes))))
+        seg_size = max(1, (len(op_nodes) + n_seg - 1) // n_seg)
+        segments = [op_nodes[i:i + seg_size]
+                    for i in range(0, len(op_nodes), seg_size)]
+    seg_of = {n.uid: si for si, seg in enumerate(segments) for n in seg}
+    group_of = _plan_conv_groups(op_nodes, out_index, node_device, seg_of)
+
+    def _eval_group(g, env, aux_out, is_train):
+        """One convolution (+ BatchNorm + Activation, as far as the plan
+        merged them) for all members of ``g``, sliced per member into env;
+        False, and nothing done, where the shapes say it does not pay.
+        The parameters stay the members' own: they are concatenated here,
+        inside the traced program, and each gets its gradient through the
+        concatenation's transpose."""
+        import jax.numpy as jnp
+
+        convs = g.links[0]
+        src, i = convs[0].inputs[0]
+        if not g.pays(env[src.uid][i].shape):
+            return False
+        _tel.inc("lower.conv_groups_merged")
+        _tel.inc("lower.convs_merged", len(convs))
+        dev = node_device(convs[0]) if node_device is not None else None
+
+        def put(x):
+            return x if dev is None else jax.device_put(x, dev)
+
+        def gather(link, slot):
+            return jnp.concatenate(
+                [put(env[n.inputs[slot][0].uid][n.inputs[slot][1]])
+                 for n in link])
+
+        def scope(link):
+            return jax.named_scope("%s:%s" % (
+                link[0].op.op_name, "+".join(n.name for n in link)))
+
+        def cut(y, axis):
+            return [jax.lax.slice_in_dim(y, lo, hi, axis=axis)
+                    for lo, hi in zip(g.offsets, g.offsets[1:])]
+
+        ins = [put(env[src.uid][i])] + [
+            gather(convs, slot) for slot in range(1, len(convs[0].inputs))]
+        octx = OpContext(is_train, None)    # none of the three ops draws
+        with scope(convs):
+            (y,), _ = g.conv_op.apply(octx, ins, [])
+        if len(g.links) > 1:
+            bns = g.links[1]
+            slots = [aux_slots[n.uid] for n in bns]
+            aux_in = [jnp.concatenate([aux_out[s[k]] for s in slots])
+                      for k in range(len(slots[0]))]
+            with scope(bns):
+                (y,), new_aux = bns[0].op.apply(
+                    octx, [y, gather(bns, 1), gather(bns, 2)], aux_in)
+            for k, a in enumerate(new_aux):
+                for s, piece in zip(slots, cut(a, 0)):
+                    aux_out[s[k]] = piece
+        if len(g.links) > 2:
+            acts = g.links[2]
+            with scope(acts):
+                (y,), _ = acts[0].op.apply(octx, [y], [])
+        for n, piece in zip(g.links[-1], cut(y, g.channel_axis)):
+            env[n.uid] = [piece]
+        return True
 
     def _eval_nodes(node_list, env, aux_out, key, is_train,
                     internals=None):
-        """Evaluate op nodes into env (uid -> outputs list) in place."""
+        """Evaluate op nodes into env (uid -> outputs list) in place.
+        With ``internals`` every node is lowered on its own (the monitor
+        wants each node's tensor); without, sibling convolutions are
+        lowered as one where that pays."""
+        merged = set()
         for n in node_list:
+            g = group_of.get(n.uid) if internals is None else None
+            if g is not None:
+                if n is g.links[0][0] and _eval_group(g, env, aux_out,
+                                                      is_train):
+                    merged.add(id(g))
+                if id(g) in merged:
+                    continue
             ins = [env[src.uid][i] for src, i in n.inputs]
             if node_device is not None:
                 dev = node_device(n)
@@ -134,8 +337,7 @@ def make_graph_eval(symbol, node_device=None, remat=False):
         for n in nodes:
             if n.is_variable:
                 env[n.uid] = [arg_list[arg_index[n.uid]]]
-        _eval_nodes([n for n in nodes if not n.is_variable], env, aux_out,
-                    key, is_train, internals)
+        _eval_nodes(op_nodes, env, aux_out, key, is_train, internals)
         outputs = [env[uid][i] for uid, i in out_index]
         if want_internals:
             return outputs, aux_out, internals
@@ -144,22 +346,10 @@ def make_graph_eval(symbol, node_device=None, remat=False):
     if not remat:
         return eval_graph, n_aux
 
-    # ---- segmented remat (memonger / sqrt schedule) -------------------
-    op_nodes = [n for n in nodes if not n.is_variable]
-    n_seg = max(2, int(math.isqrt(len(op_nodes))))
-    seg_size = max(1, (len(op_nodes) + n_seg - 1) // n_seg)
-    segments = [op_nodes[i:i + seg_size]
-                for i in range(0, len(op_nodes), seg_size)]
-
     # static plan: which (uid, out_idx) values cross each segment
-    # boundary (consumed by a later segment or by the graph outputs)
-    seg_of = {}
-    for si, seg in enumerate(segments):
-        for n in seg:
-            seg_of[n.uid] = si
-    # for each segment: values it must emit = those it produces that a
-    # later segment or the graph outputs consume. Variables are never
-    # segment outputs — they sit in the caller's store for the duration.
+    # boundary. A segment must emit the values it produces that a later
+    # segment or the graph outputs consume. Variables are never segment
+    # outputs — they sit in the caller's store for the duration.
     consumed_later = [set() for _ in segments]
     for si, seg in enumerate(segments):
         for n in seg:

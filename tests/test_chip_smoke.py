@@ -38,8 +38,11 @@ def trained():
         ctx = [mx.cpu(0)]
         yield (chip_smoke.train_phase(_toy_net(), ctx, CHW, batch=16,
                                       steps=4, fused=False, lr=LR),
+               # 32 steps: the serve phase wants a decisive model, and at
+               # 16 or 24 the smallest top-2 margin hangs on bf16 rounding
+               # order (0.06-0.21 there, 0.93-0.97 here)
                chip_smoke.train_phase(_toy_net(), ctx, CHW, batch=16,
-                                      steps=16, fused=True, lr=LR))
+                                      steps=32, fused=True, lr=LR))
     finally:
         mp.undo()
 
