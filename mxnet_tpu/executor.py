@@ -24,6 +24,7 @@ not update moving stats).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
@@ -371,6 +372,17 @@ def make_graph_eval(symbol, node_device=None, remat=False):
         out_keys = sorted(consumed_later[si], key=lambda k: (k[0], k[1]))
         plans.append((seg, in_keys, out_keys))
 
+    # an op may declare results that are cheap to keep and dear to
+    # recompute (``Operator.remat_keep_names``): those stay, all else
+    # inside a segment is recomputed; a graph whose ops declare none
+    # checkpoints as before
+    keep = sorted({name for n in op_nodes
+                   for name in getattr(n.op, "remat_keep_names", ())})
+    checkpoint = functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(*keep)) \
+        if keep else jax.checkpoint
+
     def eval_graph_remat(arg_list, aux_list, key, is_train,
                          want_internals=False):
         if want_internals:
@@ -395,8 +407,7 @@ def make_graph_eval(symbol, node_device=None, remat=False):
                 return [env[uid][i] for uid, i in _out], aux_out
 
             in_vals = [store[k] for k in in_keys]
-            out_vals, aux_state = jax.checkpoint(seg_fn)(in_vals,
-                                                         aux_state)
+            out_vals, aux_state = checkpoint(seg_fn)(in_vals, aux_state)
             store.update(zip(out_keys, out_vals))
         outputs = [store[(uid, i)] for uid, i in out_index]
         return outputs, aux_state
@@ -529,8 +540,18 @@ class Executor:
             else:
                 cdtype = self._compute_dtype
         # label args keep full precision (bf16 cannot represent class ids
-        # >= 256 exactly); everything else float casts to compute dtype
-        cast_arg = [cdtype is not None and n not in self._label_names
+        # >= 256 exactly), and so do the variables an op declares it must
+        # read uncast (token ids into Embedding, a router's float32
+        # weights); everything else float casts to compute dtype
+        uncast = set(self._label_names)
+        for n in self._symbol._topo():
+            keep = () if n.is_variable \
+                else getattr(n.op, "full_precision_args", ())
+            if keep:
+                for slot, (src, _) in zip(n.op.list_arguments(), n.inputs):
+                    if slot in keep and src.is_variable:
+                        uncast.add(src.name)
+        cast_arg = [cdtype is not None and n not in uncast
                     for n in self.arg_names]
 
         def cast_in(args):

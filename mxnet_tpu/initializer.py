@@ -22,6 +22,11 @@ class Initializer:
     def __call__(self, name: str, arr: NDArray):
         if name.startswith("upsampling"):
             self._init_bilinear(name, arr)
+        elif name.endswith("_A_log") or name.endswith("_dt_bias") \
+                or (name.endswith("_D") and len(arr.shape) == 1):
+            # ``SSMScan``'s per-head vectors, by its argument names;
+            # before "bias": dt_bias is one
+            self._init_ssm(name, arr)
         elif name.endswith("bias"):
             self._init_bias(name, arr)
         elif name.endswith("gamma"):
@@ -33,7 +38,8 @@ class Initializer:
         elif name.endswith("parameters"):
             # fused-RNN flat parameter blob (cuDNN-style)
             self._init_weight(name, arr)
-        elif name.endswith("moving_mean") or name.endswith("moving_avg"):
+        elif name.endswith("moving_mean") or name.endswith("moving_avg") \
+                or name.endswith("expert_rows"):
             self._init_zero(name, arr)
         elif name.endswith("state") or name.endswith("state_cell") \
                 or name.endswith("init_h") or name.endswith("init_c"):
@@ -58,6 +64,21 @@ class Initializer:
 
     def _init_beta(self, _, arr):
         arr[:] = 0.0
+
+    def _init_ssm(self, name, arr):
+        """A state-space scan's per-head parameters (``SSMScan``), spread
+        over the heads as Mamba-2 draws them: ``A = -exp(A_log)`` from 1
+        to 16, a step ``softplus(dt_bias)`` from 1e-3 to 0.1
+        (log-spaced), skip ``D`` = 1."""
+        n = max(arr.shape[0] - 1, 1)
+        at = np.arange(arr.shape[0], dtype=np.float64) / n
+        if name.endswith("_A_log"):
+            arr[:] = np.log(1.0 + 15.0 * at).astype(np.float32)
+        elif name.endswith("_dt_bias"):
+            dt = np.exp(np.log(1e-3) + at * (np.log(0.1) - np.log(1e-3)))
+            arr[:] = (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+        else:
+            arr[:] = 1.0
 
     def _init_bilinear(self, _, arr):
         shape = arr.shape

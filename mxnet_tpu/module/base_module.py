@@ -242,6 +242,10 @@ class BaseModule:
             outputs = [out[0:out.shape[0] - pad] for out in self.get_outputs()]
             yield outputs, nbatch, eval_batch
 
+    def publish_aux_counters(self):
+        """Telemetry from what auxiliary states count on the device, read
+        at a fence (``Module.publish_aux_counters``); nothing by default."""
+
     def fit(self, train_data, eval_data=None, eval_metric="acc",
             epoch_end_callback=None, batch_end_callback=None, kvstore="local",
             optimizer="sgd", optimizer_params=(("learning_rate", 0.01),),
@@ -296,6 +300,7 @@ class BaseModule:
         # optimizers, monitors, grad_req="add")
         with _tel.span("fit.fused_build"):
             fused = self._fused_train_step(eval_metric)
+        self.publish_aux_counters()   # the baseline of what the device counts
 
         # MXNET_TPU_CKPT_DIR: preemption-safe full-state snapshots —
         # periodic saves every MXNET_TPU_CKPT_EVERY_N_STEPS, auto-resume
@@ -385,6 +390,7 @@ class BaseModule:
             self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
                              time.time() - tic)
             arg_params_, aux_params_ = self.get_params()
+            self.publish_aux_counters()
             if epoch_end_callback is not None:
                 for cb in _as_list(epoch_end_callback):
                     cb(epoch, self.symbol, arg_params_, aux_params_)

@@ -243,16 +243,33 @@ class DataParallelExecutorGroup:
         if shared_group is not None:
             shared_aux = dict(zip(shared_group.aux_names,
                                   shared_group.executor.aux_arrays))
-        for name, shape in zip(self.aux_names, aux_shapes):
+        # auxiliary states are float32 (BatchNorm's statistics) unless
+        # their op says otherwise (a routed-expert layer's row counts)
+        aux_types = self.symbol.infer_type()[2]
+        for name, shape, dtype in zip(self.aux_names, aux_shapes, aux_types):
             if name in shared_aux and shared_aux[name].shape == shape:
                 aux.append(shared_aux[name])
             else:
-                aux.append(self._place(np.zeros(shape, dtype=np.float32), None))
+                aux.append(self._place(np.zeros(shape, dtype=dtype), None))
 
         self.executor = Executor(self.symbol, self.contexts[0], args,
                                  grads or None, self.grad_req, aux,
                                  label_names=self.label_names)
         self.execs = [self.executor]  # reference exposes per-device list
+
+    def release_grad_buffers(self):
+        """Move the bound gradient arrays off the accelerator: they stay
+        valid zeros of the right shape on the host, where a reader or a
+        later classic ``backward`` (which assigns fresh device arrays)
+        finds them. For a step that computes its gradients inside one
+        program and never writes them here."""
+        import jax
+        import jax.numpy as jnp
+
+        host = jax.devices("cpu")[0]
+        for g in self.executor.grad_arrays:
+            if g is not None and host not in g._data.devices():
+                g._data = jnp.zeros(g.shape, g._data.dtype, device=host)
 
     def _batch_axis_of(self, name: str) -> int:
         for d in self.data_shapes + self.label_shapes:
@@ -290,7 +307,8 @@ class DataParallelExecutorGroup:
                                                                   name)
         for name, arr in (aux_params or {}).items():
             if name in self.executor.aux_dict:
-                self.executor.aux_dict[name]._data = _placed_copy(arr)
+                bound = self.executor.aux_dict[name]
+                bound._data = _placed_copy(arr).astype(bound.dtype)
 
     def get_params(self, arg_params: Dict[str, NDArray],
                    aux_params: Dict[str, NDArray]):

@@ -166,7 +166,12 @@ class Module(BaseModule):
             self._arg_params = {n: nd.zeros(arg_shape_map[n])
                                 for n in self._param_names}
         if self._aux_params is None:
-            self._aux_params = {n: nd.zeros(aux_shape_map[n])
+            # in the dtype they are bound in (an op may count in int32):
+            # a float32 copy set over them would make step 2 another
+            # program than step 1
+            bound = self._exec_group.executor.aux_dict
+            self._aux_params = {n: nd.zeros(aux_shape_map[n],
+                                            dtype=bound[n].dtype)
                                 for n in self._aux_names}
 
         for name, arr in self._arg_params.items():
@@ -293,10 +298,48 @@ class Module(BaseModule):
                 "fused train step active: forward+backward+update%s "
                 "compiled into one donated XLA dispatch per batch",
                 "+metric" if fused._fold_leaves is not None else "")
+            # the fused step never materialises a gradient: the buffers
+            # bound for them (a float32 copy of every parameter) leave
+            # the device for the host, and their memory is the step's
+            self._exec_group.release_grad_buffers()
         return fused
 
     def get_outputs(self, merge_multi_context=True):
         return self._exec_group.get_outputs()
+
+    def publish_aux_counters(self):
+        """Turn auxiliary states that COUNT on the device (a routed-expert
+        layer's rows per expert) into telemetry. The step never fetches
+        them; this does, so call it at a fence only: ``fit`` does after
+        set-up (the baseline) and at each epoch's end, a harness at the
+        ends of its window. Counters get the increment since the last
+        call, summed over the layers; a gauge its largest value over the
+        layers. A no-op with telemetry off or nothing that counts."""
+        from .. import telemetry as _tel
+
+        if not _tel.enabled() or not self.binded:
+            return
+        aux = self._exec_group.executor.aux_dict
+        last = self.__dict__.setdefault("_aux_counter_last", {})
+        gauges = {}
+        for node in self._symbol._topo():
+            read = None if node.is_variable \
+                else getattr(node.op, "aux_counters", None)
+            if read is None:
+                continue
+            name = "%s_%s" % (node.name, node.op.list_auxiliary_states()[0])
+            now = aux[name].asnumpy()  # graft: host-sync
+            before = last.get(name)
+            last[name] = now
+            if before is None:
+                continue                     # the baseline
+            counters, layer_gauges = read(before, now)
+            for key, n in counters.items():
+                _tel.inc(key, n)
+            for key, v in layer_gauges.items():
+                gauges[key] = max(v, gauges.get(key, v))
+        for key, v in gauges.items():
+            _tel.set_gauge(key, v)
 
     def get_input_grads(self, merge_multi_context=True):
         return self._exec_group.get_input_grads()

@@ -255,7 +255,18 @@ class NDArray:
                     # device buffer (asnumpy), the borrow pins that
                     # buffer against donation (the fused step then
                     # silently holds two copies of the state)
-                    v = jnp.array(val, dtype=self.dtype)
+                    # ... and where this array lives, not on JAX's default
+                    # device: a host copy of the parameters written from
+                    # ``asnumpy()`` (``Module.get_params``) would move
+                    # onto the accelerator, a second copy of the model
+                    # (left to JAX where that IS the default device: the
+                    # array stays uncommitted, free to follow a mesh)
+                    import jax
+
+                    devs = self._data.devices()
+                    dev = next(iter(devs)) if len(devs) == 1 else None
+                    v = jnp.array(val, dtype=self.dtype, device=None
+                                  if dev == jax.devices()[0] else dev)
                 else:
                     v = jnp.asarray(val, dtype=self.dtype)
                 if v.shape != self.shape:
@@ -263,7 +274,7 @@ class NDArray:
                 self._data = v
             else:
                 self._data = self._data.at[key].set(
-                    val if np.isscalar(val) else jnp.asarray(val, dtype=self.dtype))
+                    jnp.asarray(val, dtype=self.dtype))
         get_engine().push(_do, const_vars=reads, mutable_vars=[self._var])
 
     # -- arithmetic --------------------------------------------------------

@@ -11,6 +11,8 @@ from . import tensor  # noqa: F401
 from . import seq     # noqa: F401
 from . import vision  # noqa: F401
 from . import ctc     # noqa: F401
+from . import attention  # noqa: F401
+from . import moe     # noqa: F401
 # plugin ops that register symbols (caffe bridge); imported here so the
 # creators exist before symbol-module generation
 from ..plugins import caffe_op as _caffe_op  # noqa: F401,E402

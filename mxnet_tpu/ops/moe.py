@@ -1,0 +1,297 @@
+"""Routed-expert feed-forward: one chip's share of a sparse expert layer.
+
+Beyond the reference (2016 MXNet predates sparse experts). The op is told
+which experts it holds (``first_held .. first_held + num_held`` of
+``num_experts``), routes over ALL of them at the published router width,
+and computes the part of the layer's result that its own experts give;
+rows routed to an absent expert add nothing here (in an expert-parallel
+layout the chip that holds that expert adds them; on one chip the layer
+runs without its exchange). What every chip computes alike, a shared
+expert, is not this op's: it is plain ``FullyConnected`` nodes beside it.
+
+**No capacity, no dropped row.** The (row, expert) pairs that land here
+are sorted by expert and laid out in blocks of ``block`` rows, each
+block one expert's (:func:`plan`); a group's last block is padded, with
+weight 0. The layout's length is static, ``rows x min(top_k, num_held)``
+plus one block an expert, but the products run in a loop over the blocks
+really filled (a dynamic trip count), each block's results scattered back
+weighted onto its rows, so work follows the rows really routed:
+:func:`grouped_experts`, forward and backward loops written out because a
+loop of unknown length has no reverse-mode autodiff. An imbalanced router
+makes one expert's group long, never short of a row.
+
+Router scores (sigmoid, no softmax), selection and combine weights are
+float32 at ``highest`` matmul precision whatever the compute dtype: the
+choice of experts is a discontinuous function of the scores. The expert
+products take inputs in the compute dtype and accumulate in float32.
+
+**The selection bias is a state, not a weight.** ``select_bias`` (float32,
+``num_experts``) is added to the scores for the choice only and no gradient
+reaches it; the family balances its experts by moving it after every
+training step against each expert's load: ``b_e += bias_update_rate *
+sign(mean load - load_e)``, the loads counted over the step's rows and over
+ALL experts, held or not (the router is whole on every chip; in an
+expert-parallel layout the counts would be summed over the chips first, and
+on one chip's share they are its own rows'). So it is an auxiliary state
+like BatchNorm's moving statistics: it moves only where ``is_train``, by the
+step that read it, and with ``bias_update_rate`` 0 (the default) it never
+moves.
+
+The auxiliary state ``expert_rows`` (int32, ``num_experts + 1``) adds up,
+on the device, the rows routed to each expert (all of them, held or not)
+and in its last slot the rows that landed here but were not computed (0
+by construction; counted so that a change that drops shows). Nothing is
+fetched per step: :meth:`RoutedExperts.aux_counters` turns two readings
+into telemetry when the module asks at a fence
+(``Module.publish_aux_counters``).
+"""
+from __future__ import annotations
+
+from ..base import MXNetError
+from .registry import Operator, Param, REQUIRED, register_op
+
+BLOCK_ROWS = 512
+REMAT_KEEP = "routed_experts_out"
+
+
+def route(x, router, select_bias, top_k, scale):
+    """``x [S, h]`` -> (expert ids ``[S, k]`` int32, combine weights
+    ``[S, k]`` float32): scores ``sigmoid(x W_r)``; the ``top_k`` largest
+    of ``scores + select_bias``; weights the chosen scores over their sum,
+    times ``scale``."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(f32), router.astype(f32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, eid = jax.lax.top_k(scores + select_bias.astype(f32), top_k)
+    chosen = jnp.take_along_axis(scores, eid, axis=1)
+    wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + 1e-20) * scale
+    return eid.astype(jnp.int32), wts
+
+
+def balance_step(bias, load, rate):
+    """The family's balancing without an auxiliary loss: each expert's
+    selection bias moves by ``rate`` against its load, ``load [E]`` the rows
+    each expert drew."""
+    import jax.numpy as jnp
+
+    load = load.astype(jnp.float32)
+    return bias.astype(jnp.float32) \
+        + rate * jnp.sign(jnp.mean(load) - load)
+
+
+def plan(eid, wts, first_held, num_held, block):
+    """Lay the pairs that land on the held experts out in blocks.
+
+    Returns ``(rows [L], weights [L], slot [S, k], block_expert [L /
+    block], nblocks, dropped)``: slot i of the layout computes row
+    ``rows[i]`` through the expert of its block, and that row adds the
+    result times ``weights[i]``; padding slots read row 0 with weight 0.
+    ``slot[r, j]`` is where pair (r, j) sits in the layout, ``L`` for a
+    pair that does not land here. ``nblocks`` (traced) is how many blocks
+    are filled, ``dropped`` the pairs that landed here and got no slot."""
+    import jax.numpy as jnp
+
+    s, k = eid.shape
+    pairs = s * k
+    length = -(-(s * min(k, num_held) + num_held * (block - 1)) // block) \
+        * block
+    local = eid.reshape(-1) - first_held
+    here = (local >= 0) & (local < num_held)
+    key = jnp.where(here, local, num_held)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((num_held + 1,), jnp.int32).at[key].add(1)[:num_held]
+    padded = (counts + block - 1) // block * block
+    pend = jnp.cumsum(padded)
+    pstart = pend - padded
+    sstart = jnp.cumsum(counts) - counts
+    pos = jnp.arange(length, dtype=jnp.int32)
+    e_of = jnp.searchsorted(pend, pos, side="right").astype(jnp.int32)
+    e_c = jnp.minimum(e_of, num_held - 1)
+    j = pos - pstart[e_c]
+    valid = (e_of < num_held) & (j < counts[e_c])
+    pair = order[jnp.clip(sstart[e_c] + j, 0, pairs - 1)]
+    rows = jnp.where(valid, pair // k, 0).astype(jnp.int32)
+    weights = jnp.where(valid, wts.reshape(-1)[pair], 0.0)
+    # a pair's slot: its place among its expert's pairs, from the sort
+    rank = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    key_c = jnp.minimum(key, num_held - 1)
+    slot = jnp.where(here, pstart[key_c] + rank - sstart[key_c], length)
+    dropped = jnp.sum(here.astype(jnp.int32)) \
+        - jnp.sum(valid.astype(jnp.int32))
+    return (rows, weights, slot.reshape(s, k).astype(jnp.int32),
+            e_c[::block], pend[-1] // block, dropped)
+
+
+def _expert_block(xb, w_up_e, cd):
+    import jax.numpy as jnp
+
+    h1 = jnp.dot(xb, w_up_e, preferred_element_type=jnp.float32)
+    r = jnp.maximum(h1, 0.0)
+    return r, (r * r).astype(cd)
+
+
+def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
+                    nblocks):
+    """``y[r] = sum_j wts[r, j] * W_down[e] relu(W_up[e] x[r])^2`` over the
+    pairs (r, j) that land here: ``x [S, h]``, ``w_up [held, h, f]``,
+    ``w_down [held, f, h]``, ``wts [S, k]``, the layout of :func:`plan`
+    (``weights`` is ``wts`` by slot). One block a loop step, ``nblocks``
+    steps, each adding its rows' weighted results onto the float32 result
+    (a padding slot adds zero to row 0). Differentiable in ``x``, the
+    expert weights and ``wts`` (whose gradient comes back by ``slot``)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    cd = x.dtype
+    block = rows.shape[0] // block_expert.shape[0]
+
+    def take(b, rows, weights, block_expert):
+        r = jax.lax.dynamic_slice(rows, (b * block,), (block,))
+        w = jax.lax.dynamic_slice(weights, (b * block,), (block,))
+        return block_expert[b], r, w
+
+    def forward(x, w_up, w_down, wts, rows, weights, slot, block_expert,
+                nblocks):
+        def body(b, out):
+            e, r, w = take(b, rows, weights, block_expert)
+            _, a = _expert_block(x[r], w_up[e], cd)
+            o = jnp.dot(a, w_down[e], preferred_element_type=f32)
+            return out.at[r].add(o * w[:, None])
+
+        return jax.lax.fori_loop(0, nblocks, body,
+                                 jnp.zeros(x.shape, f32)).astype(cd)
+
+    # the layout rides as explicit (integer, gradient-free) arguments: a
+    # custom_vjp may not close over traced values
+    f = jax.custom_vjp(forward)
+
+    def f_fwd(*args):
+        return forward(*args), args
+
+    def f_bwd(res, dy):
+        x, w_up, w_down, wts, rows, weights, slot, block_expert, nblocks = res
+
+        def body(b, carry):
+            dx, dwu, dwd, dwt = carry
+            e, r, w = take(b, rows, weights, block_expert)
+            xb, dyb = x[r], dy[r]
+            relu, a = _expert_block(xb, w_up[e], cd)
+            # d(result)/d(a), before the slot's weight
+            da = jnp.dot(dyb, w_down[e].T, preferred_element_type=f32)
+            dwt = jax.lax.dynamic_update_slice(
+                dwt, jnp.sum(a.astype(f32) * da, axis=1), (b * block,))
+            dyw = (dyb.astype(f32) * w[:, None]).astype(cd)
+            dwd = dwd.at[e].add(jnp.dot(a.T, dyw, preferred_element_type=f32))
+            dh = (da * w[:, None] * 2.0 * relu).astype(cd)
+            dwu = dwu.at[e].add(jnp.dot(xb.T, dh, preferred_element_type=f32))
+            dxb = jnp.dot(dh, w_up[e].T, preferred_element_type=f32)
+            return dx.at[r].add(dxb), dwu, dwd, dwt
+
+        dx, dwu, dwd, dwt = jax.lax.fori_loop(
+            0, nblocks, body,
+            (jnp.zeros(x.shape, f32),
+             jnp.zeros(w_up.shape, f32), jnp.zeros(w_down.shape, f32),
+             jnp.zeros(weights.shape, f32)))
+        dwts = jnp.take(dwt, slot, mode="fill", fill_value=0)
+        return (dx.astype(cd), dwu.astype(w_up.dtype),
+                dwd.astype(w_down.dtype), dwts, None, None, None, None, None)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(x, w_up, w_down, wts, rows, weights, slot, block_expert,
+             nblocks)
+
+
+@register_op("RoutedExperts")
+class RoutedExperts(Operator):
+    """The held experts' part of a routed-expert layer (see the module's
+    docstring). Experts are ``W_down relu(W_up x)^2``, no gate, no bias."""
+
+    name_hint = "routedexperts"
+    PARAMS = {
+        "num_experts": Param(int, REQUIRED, "experts the router scores"),
+        "num_held": Param(int, REQUIRED, "experts whose weights are here"),
+        "first_held": Param(int, 0, "index of the first held expert"),
+        "top_k": Param(int, REQUIRED),
+        "scale": Param(float, 1.0, "routed scaling factor"),
+        "num_hidden": Param(int, REQUIRED, "an expert's inner width"),
+        "bias_update_rate": Param(float, 0.0, "what a training step moves "
+                                  "each expert's selection bias by, "
+                                  "against its load"),
+    }
+    # arguments that reach the op in their own dtype under mixed precision
+    full_precision_args = ("router_weight",)
+    remat_keep_names = (REMAT_KEEP,)
+
+    def list_arguments(self):
+        return ["data", "router_weight", "up_weight", "down_weight"]
+
+    def list_auxiliary_states(self):
+        return ["expert_rows", "select_bias"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            raise MXNetError("RoutedExperts: data shape unknown")
+        e, held = self.num_experts, self.num_held
+        if not (0 <= self.first_held and self.first_held + held <= e
+                and 0 < held and self.top_k <= e):
+            raise MXNetError("RoutedExperts: experts %d..%d of %d, top_k %d"
+                             % (self.first_held, self.first_held + held, e,
+                                self.top_k))
+        h, f = data[1], self.num_hidden
+        return ([data, (h, e), (held, h, f), (held, f, h)], [data],
+                [(e + 1,), (e,)])
+
+    def infer_type(self, in_types, out_types=None):
+        import numpy as np
+
+        ins, outs, _ = super().infer_type(in_types, out_types)
+        return ins, outs, [np.dtype(np.int32), np.dtype(np.float32)]
+
+    def apply(self, ctx, inputs, aux):
+        import jax.numpy as jnp
+
+        x, router, w_up, w_down = inputs
+        counted, bias = aux
+        e = self.num_experts
+        eid, wts = route(x, router, bias, self.top_k, self.scale)
+        block = min(BLOCK_ROWS, max(8, x.shape[0] // 8))
+        import jax
+        from jax.ad_checkpoint import checkpoint_name
+
+        rows, weights, slot, block_expert, nblocks, dropped = plan(
+            eid, wts, self.first_held, self.num_held, block)
+        y = grouped_experts(x, w_up, w_down, wts,
+                            rows, jax.lax.stop_gradient(weights), slot,
+                            block_expert, nblocks)
+        # under MXNET_BACKWARD_DO_MIRROR the result is kept (one
+        # activation) so that the recomputed forward skips the loop
+        y = checkpoint_name(y, REMAT_KEEP)
+        load = jnp.zeros((e,), jnp.int32).at[eid.reshape(-1)].add(1)
+        if ctx.is_train and self.bias_update_rate:
+            bias = balance_step(bias, load, self.bias_update_rate)
+        seen = jnp.concatenate([load, dropped[None].astype(jnp.int32)])
+        return [y], [counted.astype(jnp.int32) + seen, bias]
+
+    def aux_counters(self, before, after):
+        """Telemetry from two host readings of ``expert_rows``:
+        ``{counter: increment}``, ``{gauge: value}``."""
+        import numpy as np
+
+        d = (np.asarray(after, np.int64) - np.asarray(before, np.int64)) \
+            % (1 << 32)                      # int32 on the device wraps
+        lo, e = self.first_held, self.num_experts
+        held = d[lo:lo + self.num_held]
+        counters = {"moe.rows_total": int(d[:e].sum()),
+                    "moe.rows_here": int(held.sum()),
+                    "moe.dropped_rows": int(d[e])}
+        gauges = {}
+        if held.sum():
+            gauges["moe.expert_load_max_over_mean"] = \
+                float(held.max() / held.mean())
+        return counters, gauges

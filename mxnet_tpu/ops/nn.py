@@ -75,7 +75,8 @@ class FullyConnected(Operator):
 @register_op("Activation")
 class Activation(Operator):
     name_hint = "activation"
-    PARAMS = {"act_type": Param(str, REQUIRED, "relu/sigmoid/tanh/softrelu")}
+    PARAMS = {"act_type": Param(str, REQUIRED,
+                                "relu/sigmoid/tanh/softrelu/silu/relu2")}
 
     def apply(self, ctx, inputs, aux):
         jnp = _jnp()
@@ -89,6 +90,11 @@ class Activation(Operator):
             out = jnp.tanh(x)
         elif act == "softrelu":
             out = _jax().nn.softplus(x)
+        elif act == "silu":
+            out = _jax().nn.silu(x)
+        elif act == "relu2":
+            # squared ReLU (So et al., Primer, arXiv:2109.08668)
+            out = jnp.square(jnp.maximum(x, 0))
         else:
             raise MXNetError("unknown act_type %s" % act)
         return [out], []
@@ -750,6 +756,8 @@ class Embedding(Operator):
         "input_dim": Param(int, REQUIRED),
         "output_dim": Param(int, REQUIRED),
     }
+    # ids above 255 do not survive a cast to bfloat16
+    full_precision_args = ("data",)
 
     def list_arguments(self):
         return ["data", "weight"]
@@ -781,6 +789,53 @@ class Embedding(Operator):
 # ---------------------------------------------------------------------------
 # Normalization ops
 # ---------------------------------------------------------------------------
+@register_op("RMSNorm")
+class RMSNorm(Operator):
+    """Root-mean-square normalisation over the last axis (Zhang and
+    Sennrich, arXiv:1910.07467): ``x * rsqrt(mean(x^2) + eps) * gamma``,
+    statistics in float32 whatever the compute dtype.
+
+    ``num_groups`` > 1 normalises each of that many equal slices of the
+    last axis on its own (one learned ``gamma`` over the whole width).
+    ``gated=True`` takes a second input ``gate`` and normalises
+    ``x * silu(gate)``: the gated norm that closes a Mamba-2 mixer (Dao
+    and Gu, arXiv:2405.21060)."""
+
+    name_hint = "rmsnorm"
+    PARAMS = {
+        "eps": Param(float, 1e-5),
+        "num_groups": Param(int, 1),
+        "gated": Param(bool, False),
+    }
+
+    def list_arguments(self):
+        return ["data", "gamma", "gate"] if self.gated \
+            else ["data", "gamma"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            raise MXNetError("RMSNorm: data shape unknown")
+        if data[-1] % self.num_groups:
+            raise MXNetError("RMSNorm: width %d is not %d equal groups"
+                             % (data[-1], self.num_groups))
+        shapes = [data, (data[-1],)] + ([data] if self.gated else [])
+        return shapes, [data], []
+
+    def apply(self, ctx, inputs, aux):
+        jax, jnp = _jax(), _jnp()
+        x, gamma = inputs[0], inputs[1]
+        y = x.astype(jnp.float32)
+        if self.gated:
+            y = y * jax.nn.silu(inputs[2].astype(jnp.float32))
+        g = self.num_groups
+        yg = y.reshape(y.shape[:-1] + (g, y.shape[-1] // g))
+        yg = yg * jax.lax.rsqrt(
+            jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + self.eps)
+        y = yg.reshape(y.shape) * gamma.astype(jnp.float32)
+        return [y.astype(x.dtype)], []
+
+
 @register_op("LRN")
 class LRN(Operator):
     """Cross-channel local response normalization (reference lrn-inl.h)."""

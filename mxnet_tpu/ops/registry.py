@@ -78,6 +78,16 @@ class Operator:
     # subclasses override
     PARAMS: Dict[str, Param] = {}
     name_hint = "op"
+    # names of arguments that must reach ``apply`` in their own dtype
+    # under mixed precision (MXNET_COMPUTE_DTYPE): integer ids that a
+    # float cast would corrupt, float32 quantities a rounding would move
+    # discontinuously. The executor's cast rule skips the variables that
+    # feed them.
+    full_precision_args: Sequence[str] = ()
+    # names (``jax.ad_checkpoint.checkpoint_name``) of results ``apply``
+    # marks that the segmented recomputation (MXNET_BACKWARD_DO_MIRROR,
+    # ``executor.make_graph_eval``) keeps instead of computing again
+    remat_keep_names: Sequence[str] = ()
 
     def __init__(self, **kwargs):
         unknown = [k for k in kwargs if k not in self.PARAMS]

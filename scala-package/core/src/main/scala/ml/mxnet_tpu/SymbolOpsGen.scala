@@ -218,6 +218,48 @@ object SymbolOpsGen {
     mk("Cast", name, ps.toSeq, ins.toSeq)
   }
 
+  def CausalAttention(query: Symbol,
+      numHeads: Int,
+      numKvHeads: Int,
+      headDim: Int,
+      seqLen: Int,
+      name: String = "",
+      key: Symbol = null,
+      value: Symbol = null,
+      rotary: Boolean = true,
+      ropeTheta: Float = 10000.0f): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("rotary", rotary.toString))
+    ps += (("rope_theta", ropeTheta.toString))
+    ps += (("num_heads", numHeads.toString))
+    ps += (("num_kv_heads", numKvHeads.toString))
+    ps += (("head_dim", headDim.toString))
+    ps += (("seq_len", seqLen.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("query", query))
+    if (key != null) ins += (("key", key))
+    if (value != null) ins += (("value", value))
+    mk("CausalAttention", name, ps.toSeq, ins.toSeq)
+  }
+
+  def CausalConv1D(data: Symbol,
+      kernel: Int,
+      seqLen: Int,
+      name: String = "",
+      weight: Symbol = null,
+      bias: Symbol = null,
+      noBias: Boolean = false): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("no_bias", noBias.toString))
+    ps += (("kernel", kernel.toString))
+    ps += (("seq_len", seqLen.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("data", data))
+    if (weight != null) ins += (("weight", weight))
+    if (bias != null) ins += (("bias", bias))
+    mk("CausalConv1D", name, ps.toSeq, ins.toSeq)
+  }
+
   def ceil(data: Symbol,
       name: String = ""): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
@@ -668,6 +710,22 @@ object SymbolOpsGen {
     mk("Reshape", name, ps.toSeq, ins.toSeq)
   }
 
+  def RMSNorm(data: Symbol,
+      name: String = "",
+      gamma: Symbol = null,
+      eps: Float = 1e-05f,
+      numGroups: Int = 1,
+      gated: Boolean = false): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("eps", eps.toString))
+    ps += (("num_groups", numGroups.toString))
+    ps += (("gated", gated.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("data", data))
+    if (gamma != null) ins += (("gamma", gamma))
+    mk("RMSNorm", name, ps.toSeq, ins.toSeq)
+  }
+
   def RNN(data: Symbol,
       stateSize: Int,
       numLayers: Int,
@@ -714,6 +772,34 @@ object SymbolOpsGen {
     val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
     ins += (("data", data))
     mk("round", name, ps.toSeq, ins.toSeq)
+  }
+
+  def RoutedExperts(data: Symbol,
+      numExperts: Int,
+      numHeld: Int,
+      topK: Int,
+      numHidden: Int,
+      name: String = "",
+      routerWeight: Symbol = null,
+      upWeight: Symbol = null,
+      downWeight: Symbol = null,
+      firstHeld: Int = 0,
+      scale: Float = 1.0f,
+      biasUpdateRate: Float = 0.0f): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("first_held", firstHeld.toString))
+    ps += (("scale", scale.toString))
+    ps += (("bias_update_rate", biasUpdateRate.toString))
+    ps += (("num_experts", numExperts.toString))
+    ps += (("num_held", numHeld.toString))
+    ps += (("top_k", topK.toString))
+    ps += (("num_hidden", numHidden.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("data", data))
+    if (routerWeight != null) ins += (("router_weight", routerWeight))
+    if (upWeight != null) ins += (("up_weight", upWeight))
+    if (downWeight != null) ins += (("down_weight", downWeight))
+    mk("RoutedExperts", name, ps.toSeq, ins.toSeq)
   }
 
   def rsqrt(data: Symbol,
@@ -882,6 +968,34 @@ object SymbolOpsGen {
     val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
     ins += (("data", data))
     mk("square", name, ps.toSeq, ins.toSeq)
+  }
+
+  def SSMScan(data: Symbol,
+      numHeads: Int,
+      headDim: Int,
+      numGroups: Int,
+      stateSize: Int,
+      seqLen: Int,
+      name: String = "",
+      dt: Symbol = null,
+      ALog: Symbol = null,
+      D: Symbol = null,
+      dtBias: Symbol = null,
+      chunk: Int = 128): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("chunk", chunk.toString))
+    ps += (("num_heads", numHeads.toString))
+    ps += (("head_dim", headDim.toString))
+    ps += (("num_groups", numGroups.toString))
+    ps += (("state_size", stateSize.toString))
+    ps += (("seq_len", seqLen.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("data", data))
+    if (dt != null) ins += (("dt", dt))
+    if (ALog != null) ins += (("A_log", ALog))
+    if (D != null) ins += (("D", D))
+    if (dtBias != null) ins += (("dt_bias", dtBias))
+    mk("SSMScan", name, ps.toSeq, ins.toSeq)
   }
 
   def sum(data: Symbol,

@@ -291,3 +291,22 @@ def test_copyto_defensive_on_unverifiable_aliasing(monkeypatch):
     assert dst._data is not src._data
     np.testing.assert_array_equal(dst.asnumpy(),
                                   np.arange(4, dtype=np.float32))
+
+
+def test_full_write_from_numpy_stays_on_the_arrays_device():
+    """``a[:] = numpy`` keeps ``a`` where it lives: it used to land on
+    JAX's default device, so on a machine whose default is the
+    accelerator the module's host copies of the parameters moved there
+    at every ``get_params``."""
+    import jax
+
+    cpus = jax.devices("cpu")
+    if len(cpus) < 4:
+        pytest.skip("needs several host devices")
+    a = mx.nd.zeros((4, 3), ctx=mx.cpu(3))
+    a[:] = np.arange(12, dtype=np.float32).reshape(4, 3)
+    assert a._data.devices() == {cpus[3]}
+    np.testing.assert_array_equal(a.asnumpy(),
+                                  np.arange(12).reshape(4, 3))
+    a[:] = mx.nd.ones((4, 3), ctx=mx.cpu(0))       # an NDArray brings its own
+    assert a.asnumpy().sum() == 12

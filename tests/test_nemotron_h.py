@@ -1,0 +1,700 @@
+"""The language-model operators (RMSNorm, squared ReLU, CausalConv1D,
+SSMScan, CausalAttention, RoutedExperts) against plain ``jax.numpy``, and
+``models.get_nemotron_h`` through ``Module.fit`` on the fused step against
+the benchmark's float32 reference. Toy widths, seeded."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import symbol as sym
+from mxnet_tpu import telemetry
+from mxnet_tpu.models import get_nemotron_h
+from mxnet_tpu.ops import moe as moe_ops
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark.reference import nemotron_h as ref  # noqa: E402
+
+TOY = dict(pattern="MEMEMEM*E", hidden=32, vocab=96, experts_total=16,
+           experts_held=4, first_expert=4, seq_len=24, mamba_heads=4,
+           mamba_head_dim=8, ssm_groups=2, ssm_state=8, chunk=16,
+           attn_heads=4, kv_heads=2, head_dim=8, top_k=3, expert_hidden=16,
+           shared_hidden=24)
+
+
+def aux_states(net, shapes, given=None):
+    """The auxiliary states of ``net`` in their op's shapes and dtypes:
+    zeros, or ``given`` by the end of a state's name."""
+    out = []
+    for name, shape, dtype in zip(net.list_auxiliary_states(),
+                                  net.infer_shape(**shapes)[2],
+                                  net.infer_type()[2]):
+        value = next((v for k, v in (given or {}).items()
+                      if name.endswith(k)), np.zeros(shape))
+        out.append(mx.nd.array(np.asarray(value), dtype=dtype))
+    return out
+
+
+def run_op(net, inputs, head, aux=None):
+    """Outputs and input gradients of a one-output symbol, through bind /
+    forward / backward."""
+    args = {k: mx.nd.array(v, dtype=v.dtype) for k, v in inputs.items()}
+    grads = {k: mx.nd.zeros(v.shape) for k, v in inputs.items()
+             if np.issubdtype(v.dtype, np.floating)}
+    ex = net.bind(mx.cpu(), args, args_grad=grads,
+                  aux_states=aux_states(
+                      net, {k: v.shape for k, v in inputs.items()}, aux))
+    ex.forward(is_train=True)
+    ex.backward([mx.nd.array(head)])
+    return ex.outputs[0].asnumpy(), {k: g.asnumpy() for k, g in grads.items()}
+
+
+def close(a, b, tol=2e-5):
+    scale = max(float(np.abs(b).max()), 1e-6)
+    assert float(np.abs(np.asarray(a) - np.asarray(b)).max()) <= tol * scale, \
+        (float(np.abs(np.asarray(a) - np.asarray(b)).max()), scale)
+
+
+def against(fn, net, inputs, seed=0, tol=2e-5, aux=None):
+    """``net`` (a Symbol over ``inputs``) against the plain ``fn(**inputs)``:
+    the output, and every float input's gradient under a random head."""
+    want = fn(**{k: jnp.asarray(v) for k, v in inputs.items()})
+    head = np.random.default_rng(seed).standard_normal(want.shape).astype(
+        np.float32)
+    floats = [k for k, v in inputs.items()
+              if np.issubdtype(v.dtype, np.floating)]
+    want_g = jax.grad(lambda fl: jnp.sum(fn(**{
+        **{k: jnp.asarray(v) for k, v in inputs.items()}, **fl}) * head))(
+            {k: jnp.asarray(inputs[k]) for k in floats})
+    got, got_g = run_op(net, inputs, head, aux)
+    close(got, want, tol)
+    for k in floats:
+        close(got_g[k], want_g[k], tol)
+
+
+def rng_inputs(seed, **shapes):
+    rng = np.random.default_rng(seed)
+    return {k: rng.standard_normal(s).astype(np.float32)
+            for k, s in shapes.items()}
+
+
+# ---------------------------------------------------------------------------
+# normalisation, activation, convolution
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("groups,gated", [(1, False), (4, True)])
+def test_rmsnorm_against_plain(groups, gated):
+    shapes = dict(data=(6, 16), gamma=(16,))
+    if gated:
+        shapes["gate"] = (6, 16)
+    inputs = rng_inputs(1, **shapes)
+
+    def plain(data, gamma, gate=None):
+        y = data * jax.nn.silu(gate) if gate is not None else data
+        yg = y.reshape(6, groups, 16 // groups)
+        yg = yg / jnp.sqrt(jnp.mean(yg * yg, -1, keepdims=True) + 1e-5)
+        return yg.reshape(6, 16) * gamma
+
+    v = {k: sym.Variable(k) for k in inputs}
+    against(plain, sym.RMSNorm(eps=1e-5, num_groups=groups, gated=gated, **v),
+            inputs)
+
+
+def test_rmsnorm_shape_and_type_inference():
+    net = sym.RMSNorm(data=sym.Variable("data"), num_groups=2, name="n")
+    arg, out, _ = net.infer_shape(data=(5, 8))
+    assert arg == [(5, 8), (8,)] and out == [(5, 8)]
+    with pytest.raises(mx.MXNetError):
+        sym.RMSNorm(data=sym.Variable("data"), num_groups=3).infer_shape(
+            data=(5, 8))
+    types, _, _ = net.infer_type(data=np.float32)
+    assert all(t == np.float32 for t in types)
+
+
+@pytest.mark.parametrize("act,plain", [
+    ("relu2", lambda x: jnp.square(jnp.maximum(x, 0))),
+    ("silu", jax.nn.silu)])
+def test_activation_types(act, plain):
+    inputs = rng_inputs(2, data=(5, 7))
+    against(lambda data: plain(data),
+            sym.Activation(data=sym.Variable("data"), act_type=act), inputs)
+
+
+def test_causal_conv1d_against_plain():
+    t, c, k = 10, 6, 4
+    inputs = rng_inputs(3, data=(2 * t, c), weight=(c, k), bias=(c,))
+
+    def plain(data, weight, bias):
+        x = data.reshape(2, t, c)
+        rows = []
+        for pos in range(t):
+            acc = bias
+            for i in range(k):
+                src = pos - (k - 1) + i
+                if src >= 0:
+                    acc = acc + weight[:, i] * x[:, src]
+            rows.append(acc)
+        return jnp.stack(rows, axis=1).reshape(2 * t, c)
+
+    v = {n: sym.Variable(n) for n in inputs}
+    against(plain, sym.CausalConv1D(kernel=k, seq_len=t, **v), inputs)
+    with pytest.raises(mx.MXNetError):
+        sym.CausalConv1D(data=sym.Variable("data"), kernel=k,
+                         seq_len=7).infer_shape(data=(20, c))
+
+
+# ---------------------------------------------------------------------------
+# the chunked scan against the step-by-step recurrence
+# ---------------------------------------------------------------------------
+def plain_scan(data, dt, A_log, D, dt_bias, t, h, p, g, n):
+    b = data.shape[0] // t
+    di, gn = h * p, g * n
+    x = data[:, :di].reshape(b, t, h, p)
+    bm = jnp.repeat(data[:, di:di + gn].reshape(b, t, g, n), h // g, axis=2)
+    cm = jnp.repeat(data[:, di + gn:].reshape(b, t, g, n), h // g, axis=2)
+    step = jax.nn.softplus(dt.reshape(b, t, h) + dt_bias)
+    a = -jnp.exp(A_log)
+    s = jnp.zeros((b, h, p, n))
+    ys = []
+    for i in range(t):
+        s = jnp.exp(step[:, i] * a)[..., None, None] * s \
+            + (step[:, i, :, None] * x[:, i])[..., None] * bm[:, i, :, None]
+        ys.append(jnp.einsum("bhpn,bhn->bhp", s, cm[:, i]) + D[:, None]
+                  * x[:, i])
+    return jnp.stack(ys, axis=1).reshape(b * t, di)
+
+
+@pytest.mark.parametrize("t", [8, 12, 24], ids=["1chunk", "1.5chunks",
+                                                "3chunks"])
+def test_ssm_scan_against_recurrence(t):
+    h, p, g, n, chunk = 4, 3, 2, 5, 8
+    inputs = rng_inputs(4, data=(2 * t, h * p + 2 * g * n), dt=(2 * t, h),
+                        A_log=(h,), D=(h,), dt_bias=(h,))
+    inputs["dt_bias"] -= 2.0          # steps of about 0.1
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.SSMScan(num_heads=h, head_dim=p, num_groups=g, state_size=n,
+                      chunk=chunk, seq_len=t, **v)
+    against(lambda **kw: plain_scan(t=t, h=h, p=p, g=g, n=n, **kw), net,
+            inputs, tol=5e-5)
+
+
+def test_ssm_scan_shapes():
+    net = sym.SSMScan(data=sym.Variable("data"), dt=sym.Variable("dt"),
+                      num_heads=4, head_dim=3, num_groups=2, state_size=5,
+                      seq_len=6, name="s")
+    arg, out, _ = net.infer_shape(data=(12, 32))
+    assert arg == [(12, 32), (12, 4), (4,), (4,), (4,)] and out == [(12, 12)]
+    assert net.list_arguments() == ["data", "dt", "s_A_log", "s_D",
+                                    "s_dt_bias"]
+    with pytest.raises(mx.MXNetError):
+        net.infer_shape(data=(12, 30))
+
+
+# ---------------------------------------------------------------------------
+# attention
+# ---------------------------------------------------------------------------
+def plain_attention(query, key, value, t, hq, hkv, d, rotary):
+    b = query.shape[0] // t
+    q = query.reshape(b, t, hq, d)
+    k = jnp.repeat(key.reshape(b, t, hkv, d), hq // hkv, axis=2)
+    v = jnp.repeat(value.reshape(b, t, hkv, d), hq // hkv, axis=2)
+    if rotary:
+        q, k = ref._rope(q, 10000.0), ref._rope(k, 10000.0)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+    return out.reshape(b * t, hq * d)
+
+
+@pytest.mark.parametrize("rotary", [True, False])
+def test_attention_grouped_heads_against_masked_softmax(rotary, monkeypatch):
+    from mxnet_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "BLOCK_Q", 8)    # three blocks, one short
+    t, hq, hkv, d = 20, 4, 2, 8
+    inputs = rng_inputs(5, query=(2 * t, hq * d), key=(2 * t, hkv * d),
+                        value=(2 * t, hkv * d))
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.CausalAttention(num_heads=hq, num_kv_heads=hkv, head_dim=d,
+                              seq_len=t, rotary=rotary, **v)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        against(lambda **kw: plain_attention(t=t, hq=hq, hkv=hkv, d=d,
+                                             rotary=rotary, **kw), net,
+                inputs, tol=5e-5)
+        assert telemetry.peek("lower.attention_kernel.xla_blockwise") >= 1
+    finally:
+        telemetry.disable()
+
+
+def test_attention_ungrouped_heads_whole_blocks():
+    """As many key/value heads as query heads, a sequence of whole query
+    blocks and a head narrower than the lanes splash wants: the blockwise
+    lowering serves it."""
+    t, h, d = 128, 2, 8
+    inputs = rng_inputs(6, query=(t, h * d), key=(t, h * d),
+                        value=(t, h * d))
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.CausalAttention(num_heads=h, num_kv_heads=h, head_dim=d,
+                              seq_len=t, **v)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        against(lambda **kw: plain_attention(t=t, hq=h, hkv=h, d=d,
+                                             rotary=True, **kw), net, inputs,
+                tol=5e-5)
+        assert telemetry.peek("lower.attention_kernel.xla_blockwise") >= 1
+        assert not telemetry.peek("lower.attention_kernel.pallas_splash")
+    finally:
+        telemetry.disable()
+
+
+def test_attention_shape_inference():
+    net = sym.CausalAttention(query=sym.Variable("q"), key=sym.Variable("k"),
+                              value=sym.Variable("v"), num_heads=4,
+                              num_kv_heads=2, head_dim=8, seq_len=5)
+    arg, out, _ = net.infer_shape(q=(10, 32))
+    assert arg == [(10, 32), (10, 16), (10, 16)] and out == [(10, 32)]
+    with pytest.raises(mx.MXNetError):
+        sym.CausalAttention(query=sym.Variable("q"), key=sym.Variable("k"),
+                            value=sym.Variable("v"), num_heads=4,
+                            num_kv_heads=3, head_dim=8,
+                            seq_len=5).infer_shape(q=(10, 32))
+
+
+# ---------------------------------------------------------------------------
+# routed experts
+# ---------------------------------------------------------------------------
+def plain_experts(data, router_weight, up_weight, down_weight, select_bias,
+                  first, top_k, scale):
+    scores = jax.nn.sigmoid(data @ router_weight)
+    _, eid = jax.lax.top_k(scores + select_bias, top_k)
+    chosen = jnp.take_along_axis(scores, eid, axis=1)
+    wts = chosen / chosen.sum(1, keepdims=True) * scale
+    out = jnp.zeros_like(data)
+    for j in range(up_weight.shape[0]):
+        gate = jnp.sum(jnp.where(eid == first + j, wts, 0.0), axis=1)
+        out = out + gate[:, None] * (jnp.square(jnp.maximum(
+            data @ up_weight[j], 0.0)) @ down_weight[j])
+    return out
+
+
+def skewed_expert_inputs(seed, rows=64, h=12, e=8, held=3, f=10, hot=2):
+    """A router that sends well over half the rows to expert ``hot``: the
+    op's inputs and its selection bias (a state)."""
+    inputs = rng_inputs(seed, data=(rows, h), router_weight=(h, e),
+                        up_weight=(held, h, f), down_weight=(held, f, h))
+    bias = np.zeros(e, np.float32)
+    bias[hot] = 5.0
+    return inputs, bias
+
+
+def test_routed_experts_against_loop_under_a_skewed_router():
+    first, top_k, e, held = 1, 2, 8, 3
+    inputs, bias = skewed_expert_inputs(7)
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.RoutedExperts(num_experts=e, num_held=held, first_held=first,
+                            top_k=top_k, scale=2.5, num_hidden=10, **v)
+    assert [n.split("_", 1)[1] for n in net.list_auxiliary_states()] == [
+        "expert_rows", "select_bias"]
+    against(lambda **kw: plain_experts(select_bias=jnp.asarray(bias),
+                                       first=first, top_k=top_k, scale=2.5,
+                                       **kw), net, inputs, tol=5e-5,
+            aux={"select_bias": bias})
+    # every row went to the hot expert: no capacity would hold them
+    eid, wts = moe_ops.route(jnp.asarray(inputs["data"]),
+                             jnp.asarray(inputs["router_weight"]),
+                             jnp.asarray(bias), top_k, 2.5)
+    assert int((np.asarray(eid) == 2).any(axis=1).sum()) > 32
+    rows, weights, slot, block_expert, nblocks, dropped = moe_ops.plan(
+        eid, wts, first, held, 8)
+    assert int(dropped) == 0
+    here = (np.asarray(eid) >= first) & (np.asarray(eid) < first + held)
+    assert int((np.asarray(weights) != 0).sum()) == int(here.sum())
+    assert int(nblocks) * 8 >= int(here.sum())
+    # every pair that lands here has a slot of its own, the others none
+    slot = np.asarray(slot)
+    assert (slot[here] < len(np.asarray(rows))).all()
+    assert len(set(slot[here].tolist())) == int(here.sum())
+    assert (slot[~here] == len(np.asarray(rows))).all()
+    assert (np.asarray(rows)[slot[here]] == np.nonzero(here)[0]).all()
+
+
+def test_routed_experts_counts_rows_on_the_device():
+    inputs, bias = skewed_expert_inputs(8)
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.RoutedExperts(num_experts=8, num_held=3, first_held=1,
+                            top_k=2, scale=1.0, num_hidden=10, name="x", **v)
+    shapes = {k: a.shape for k, a in inputs.items()}
+    assert net.infer_shape(**shapes)[2] == [(9,), (8,)]
+    assert net.infer_type()[2] == [np.dtype(np.int32), np.dtype(np.float32)]
+    ex = net.bind(mx.cpu(), {k: mx.nd.array(a) for k, a in inputs.items()},
+                  aux_states=aux_states(net, shapes, {"select_bias": bias}))
+    before = ex.aux_arrays[0].asnumpy()
+    for _ in range(2):
+        ex.forward(is_train=True)
+        ex.backward([mx.nd.ones((64, 12))])
+    after = ex.aux_arrays[0].asnumpy()
+    assert after.dtype == np.int32
+    # no bias_update_rate: the selection bias stays as it was handed over
+    assert (ex.aux_arrays[1].asnumpy() == bias).all()
+    op = net._outputs[0][0].op
+    counters, gauges = op.aux_counters(before, after)
+    assert counters["moe.rows_total"] == 2 * 64 * 2
+    assert counters["moe.dropped_rows"] == 0
+    assert 0 < counters["moe.rows_here"] <= counters["moe.rows_total"]
+    assert gauges["moe.expert_load_max_over_mean"] > 1.5
+    # the device's int32 wraps; the increment does not
+    c2, _ = op.aux_counters(np.full(9, 2 ** 31 - 3, np.int32),
+                            np.full(9, -(2 ** 31) + 4, np.int32))
+    assert c2["moe.dropped_rows"] == 7
+
+
+def test_a_training_step_moves_the_selection_bias_against_the_load():
+    """``bias_update_rate``: after each training step every expert's bias
+    has moved by the rate, down where it drew more rows than the mean and
+    up where fewer, by the counts of the step that read it; a forward pass
+    outside training moves nothing; the plain reference's ``balance_step``
+    says the same."""
+    inputs, bias = skewed_expert_inputs(8)
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.RoutedExperts(num_experts=8, num_held=3, first_held=1,
+                            top_k=2, scale=1.0, num_hidden=10,
+                            bias_update_rate=0.25, name="x", **v)
+    shapes = {k: a.shape for k, a in inputs.items()}
+    ex = net.bind(mx.cpu(), {k: mx.nd.array(a) for k, a in inputs.items()},
+                  aux_states=aux_states(net, shapes, {"select_bias": bias}))
+    ex.forward(is_train=False)
+    assert (ex.aux_arrays[1].asnumpy() == bias).all()
+    want = jnp.asarray(bias)
+    for _ in range(3):
+        rows_before = ex.aux_arrays[0].asnumpy()
+        ex.forward(is_train=True)
+        ex.backward([mx.nd.ones((64, 12))])
+        load = (ex.aux_arrays[0].asnumpy() - rows_before)[:8]
+        assert load.sum() == 64 * 2
+        want = ref.balance_step(want, jnp.asarray(load, jnp.float32), 0.25)
+        np.testing.assert_allclose(ex.aux_arrays[1].asnumpy(), want)
+        moved = ex.aux_arrays[1].asnumpy() - bias
+    # the hot expert drew every row at first: its bias has only fallen
+    assert moved[2] == pytest.approx(-0.75)
+    assert ex.aux_arrays[1].dtype == np.float32
+
+
+def test_balanced_start_evens_a_skewed_router():
+    """``reference.balanced_bias``: the family's rule run on one batch's
+    scores until it settles loads every expert within a few rows of the
+    mean, from a router that sent most rows to a few."""
+    rng = np.random.default_rng(3)
+    logits = rng.standard_normal((512, 16)) + 2.0 * rng.standard_normal(16)
+    scores = jax.nn.sigmoid(jnp.asarray(logits, jnp.float32))
+    rates = jnp.asarray(np.geomspace(0.1, 0.001, 200), jnp.float32)
+    zero = jnp.zeros(16, jnp.float32)
+    before = ref.loads(jax.lax.top_k(scores, 3)[1], 16)
+    bias = ref.balanced_bias(scores, zero, 3, rates)
+    after = ref.loads(jax.lax.top_k(scores + bias, 3)[1], 16)
+    mean = 512 * 3 / 16
+    assert float(before.max()) > 2.5 * mean
+    assert float(after.sum()) == 512 * 3
+    assert float(jnp.abs(after - mean).max()) <= 0.1 * mean
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Over all 16 shares of an expert layer, the routed parts plus the
+    shared expert counted once equal the uncut reference layer."""
+    args = dict(TOY, pattern="E", experts_held=16, first_expert=0)
+    shapes = ref.param_shapes(args)
+    params = ref.init_params(args, jax.random.PRNGKey(9))
+    x = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (48, TOY["hidden"])).astype(np.float32))
+    c = ref.config(args)
+    whole, _, _ = ref._experts(params, "layer0_", x, c, *ref._ROUND[None],
+                               None)
+    inputs = {k[len("layer0_experts_"):]: np.asarray(params[k])
+              for k in shapes if "experts_" in k and not k.endswith(ref.STATE)}
+    total = np.asarray(ref.shared_part(params, "layer0_", x,
+                                       *ref._ROUND[None]))
+    v = {k: sym.Variable(k) for k in ["data"] + list(inputs)}
+    for share in range(16):
+        net = sym.RoutedExperts(num_experts=16, num_held=1, first_held=share,
+                                top_k=3, scale=2.5, num_hidden=16, **v)
+        mine = dict(inputs, data=np.asarray(x))
+        mine["up_weight"] = inputs["up_weight"][share:share + 1]
+        mine["down_weight"] = inputs["down_weight"][share:share + 1]
+        ex = net.bind(mx.cpu(), {k: mx.nd.array(a) for k, a in mine.items()},
+                      aux_states=aux_states(
+                          net, {k: a.shape for k, a in mine.items()}))
+        total = total + ex.forward(is_train=False)[0].asnumpy()
+    close(total, np.asarray(whole), 5e-5)
+
+
+# ---------------------------------------------------------------------------
+# token ids under mixed precision
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("id_dtype", [np.int32, np.float32])
+def test_token_ids_reach_embedding_intact_under_bfloat16(id_dtype):
+    vocab, dim = 16384, 4
+    net = sym.Embedding(data=sym.Variable("data"), input_dim=vocab,
+                        output_dim=dim, name="embed")
+    table = np.arange(vocab, dtype=np.float32)[:, None] \
+        * np.ones((1, dim), np.float32)
+    ids = np.array([[300, 16383, 255, 257]], id_dtype)
+    ex = net.bind(mx.cpu(), {"data": mx.nd.array(ids, dtype=id_dtype),
+                             "embed_weight": mx.nd.array(table)},
+                  compute_dtype="bfloat16")
+    out = ex.forward(is_train=False)[0].asnumpy()
+    # the table's rows are rounded to bfloat16, the ids are not: 300 and
+    # 16383 pick their own rows (as bfloat16 they would read 300 -> 300,
+    # 16383 -> 16384: out of range)
+    want = np.asarray(jnp.asarray(table[[300, 16383, 255, 257]],
+                                  jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(out[0], want)
+
+
+# ---------------------------------------------------------------------------
+# the model through Module.fit against the benchmark's reference
+# ---------------------------------------------------------------------------
+class Ring:
+    """The DataIter protocol ``fit`` uses, over fixed int32 batches."""
+
+    def __init__(self, batches):
+        self.batches = batches
+        self.batch_size = batches[0][0].shape[0]
+        self.provide_data = [mx.io.DataDesc("data", batches[0][0].shape)]
+        self.provide_label = [mx.io.DataDesc("softmax_label",
+                                             batches[0][1].shape)]
+        self.k = 0
+
+    def reset(self):
+        self.k = 0
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return self.next()
+
+    def next(self):
+        if self.k >= len(self.batches):
+            raise StopIteration
+        ids, lab = self.batches[self.k]
+        self.k += 1
+        return mx.io.DataBatch([mx.nd.array(ids, dtype=np.int32)],
+                               [mx.nd.array(lab, dtype=np.int32)], pad=0)
+
+
+def toy_batches(n, batch=2, seed=11):
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        ids = rng.integers(0, TOY["vocab"], (batch, TOY["seq_len"] + 1))
+        out.append((ids[:, :-1].astype(np.int32),
+                    ids[:, 1:].astype(np.int32)))
+    return out
+
+
+RECIPE = {"learning_rate": 0.01, "wd": 0.01, "beta1": 0.9, "beta2": 0.95,
+          "epsilon": 1e-8, "rescale_grad": 1.0}
+
+
+def fit_toy(monkeypatch, batches, compute_dtype=None, mirror=True,
+            aux_given=True, toy=TOY):
+    monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
+    if mirror:
+        monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    if compute_dtype:
+        monkeypatch.setenv("MXNET_COMPUTE_DTYPE", compute_dtype)
+    params0 = {k: np.asarray(v) for k, v in ref.init_params(
+        toy, jax.random.PRNGKey(5)).items()}
+    mod = mx.mod.Module(get_nemotron_h(**toy), context=mx.cpu(0))
+    # the selection biases are states: the reference's one dictionary of
+    # everything from the seed is handed over in two
+    states = {k: mx.nd.array(v) for k, v in params0.items()
+              if k.endswith(ref.STATE)}
+    states.update({n: mx.nd.zeros((toy["experts_total"] + 1,),
+                                  dtype=np.int32)
+                   for n in mod.symbol.list_auxiliary_states()
+                   if n.endswith("expert_rows")})
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        mod.fit(Ring(batches), eval_metric="ce", optimizer="adam",
+                optimizer_params=dict(RECIPE), initializer=None,
+                arg_params={k: mx.nd.array(v) for k, v in params0.items()
+                            if not k.endswith(ref.STATE)},
+                aux_params=states if aux_given else None,
+                num_epoch=1)
+        counters = {k: telemetry.peek(k) for k in (
+            "step.dispatches", "step.fused_steps", "step.fused_fallback",
+            "moe.rows_here", "moe.rows_total", "moe.dropped_rows",
+            "lower.scan_kernel.xla_chunked",
+            "lower.attention_kernel.xla_blockwise")}
+        counters["load"] = telemetry.peek("moe.expert_load_max_over_mean",
+                                          "gauge")
+        counters["jit_entries"] = telemetry.peek("step.fused_jit_entries",
+                                                 "gauge")
+    finally:
+        telemetry.disable()
+    return mod, params0, counters
+
+
+@pytest.mark.parametrize("bias_update_rate", [0.0, 0.05])
+def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch,
+                                                         bias_update_rate):
+    """Three Adam steps through ``Module.fit`` against the benchmark's
+    reference, the selection biases left alone and moved against the loads
+    after every step (then step 2 routes by what step 1 counted)."""
+    batches = toy_batches(3)
+    toy = dict(TOY, bias_update_rate=bias_update_rate)
+    mod, params0, counters = fit_toy(monkeypatch, batches, toy=toy)
+    assert mod._fused_step_active
+    assert counters["step.dispatches"] == 3
+    assert counters["step.fused_steps"] == 3
+    assert not counters["step.fused_fallback"]
+    tokens = 2 * TOY["seq_len"]
+    assert counters["moe.rows_total"] == 3 * 4 * tokens * TOY["top_k"]
+    assert 0 < counters["moe.rows_here"] < counters["moe.rows_total"]
+    assert counters["moe.dropped_rows"] == 0
+    assert counters["load"] >= 1.0
+    assert counters["lower.scan_kernel.xla_chunked"] >= 4
+    want = ref.follow(toy, RECIPE, params0,
+                      [(jnp.asarray(i), jnp.asarray(l)) for i, l in batches],
+                      rows=np.arange(16).reshape(2, 8))
+    got = {k: v.asnumpy() for part in mod.get_params()
+           for k, v in part.items()}
+    delta = ref.leaf_norms({k: jnp.asarray(got[k] - params0[k])
+                            for k in params0})
+    for k, bias in want["states"].items():
+        # float32 on both sides: the same rows counted, the same biases
+        np.testing.assert_allclose(got[k], bias, atol=1e-7)
+        assert (np.abs(bias).max() > 0) == bool(bias_update_rate)
+    worst = max(abs(float(delta[k]) - want["delta_norms"][k])
+                / max(want["delta_norms"][k], 1e-3) for k in delta)
+    assert worst < 2e-3, worst
+    # every held expert's slice trained
+    for k, n in want["delta_norms"].items():
+        if "[" in k:
+            assert float(delta[k]) > 0.5 * n > 0
+
+
+@pytest.mark.parametrize("aux_given", [True, False])
+def test_one_program_serves_every_step(monkeypatch, aux_given):
+    """The experts' row counts stay int32 from bind on, whether ``fit``
+    is handed auxiliary states or makes them: a float32 copy set over
+    them made step 2 another program than step 1."""
+    mod, _, counters = fit_toy(monkeypatch, toy_batches(3),
+                               aux_given=aux_given)
+    assert counters["jit_entries"] == 1
+    assert counters["moe.dropped_rows"] == 0 and counters["moe.rows_here"] > 0
+    for name, a in mod._exec_group.executor.aux_dict.items():
+        assert a.dtype == (np.int32 if name.endswith("expert_rows")
+                           else np.float32)
+
+
+def test_model_loss_follows_the_reference(monkeypatch):
+    """Step by step: the metric's mean cross-entropy after each step."""
+    batches = toy_batches(3, seed=12)
+    losses = []
+
+    class Watch(Ring):
+        def next(self):
+            if 0 < self.k < len(self.batches):
+                losses.append(self.metric.get()[1] * self.k)
+            return super().next()
+
+    monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
+    params0 = {k: np.asarray(v) for k, v in ref.init_params(
+        TOY, jax.random.PRNGKey(6)).items()}
+    mod = mx.mod.Module(get_nemotron_h(**TOY), context=mx.cpu(0))
+    it = Watch(batches)
+    it.metric = mx.metric.create("ce")
+    mod.fit(it, eval_metric=it.metric, optimizer="adam",
+            optimizer_params=dict(RECIPE), initializer=None,
+            arg_params={k: mx.nd.array(v) for k, v in params0.items()
+                        if not k.endswith(ref.STATE)},
+            num_epoch=1, allow_missing=True)
+    sums = losses + [it.metric.get()[1] * 3]
+    per_step = np.diff([0.0] + sums)
+    want = ref.follow(TOY, RECIPE, params0,
+                      [(jnp.asarray(i), jnp.asarray(l)) for i, l in batches],
+                      rows=np.arange(8).reshape(2, 4))
+    np.testing.assert_allclose(per_step, want["losses"], rtol=2e-4)
+
+
+def test_model_trains_in_bfloat16_with_int32_ids(monkeypatch):
+    batches = toy_batches(6, seed=13)
+    mod, params0, counters = fit_toy(monkeypatch, batches,
+                                     compute_dtype="bfloat16")
+    assert counters["step.dispatches"] == 6
+    assert not counters["step.fused_fallback"]
+    assert counters["moe.dropped_rows"] == 0
+    ex = mod._exec_group.executor
+    assert ex.arg_dict["data"].dtype == np.int32
+    got, _ = mod.get_params()
+    assert all(np.isfinite(v.asnumpy()).all() for v in got.values())
+    # the router and the scan's parameters stayed float32 inside the step
+    assert got["layer1_experts_router_weight"].dtype == np.float32
+
+
+def test_reference_imports_nothing_of_the_program():
+    src = open(ref.__file__).read()
+    assert "mxnet_tpu" not in src.replace(
+        "mxnet_tpu/optimizer.py", "").replace("``mxnet_tpu``", "") \
+        or "import mxnet_tpu" not in src
+    assert "import mxnet_tpu" not in src and "from mxnet_tpu" not in src
+
+
+def test_attention_takes_the_splash_kernel_at_whole_lane_heads():
+    """A head of 128 and whole blocks: JAX's multi-query Pallas kernel,
+    one call a key/value head (the interpreter here), forward and
+    gradients against the masked softmax."""
+    t, hq, hkv, d = 256, 4, 2, 128
+    inputs = rng_inputs(14, query=(t, hq * d), key=(t, hkv * d),
+                        value=(t, hkv * d))
+    v = {k: sym.Variable(k) for k in inputs}
+    net = sym.CausalAttention(num_heads=hq, num_kv_heads=hkv, head_dim=d,
+                              seq_len=t, **v)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        against(lambda **kw: plain_attention(t=t, hq=hq, hkv=hkv, d=d,
+                                             rotary=True, **kw), net, inputs,
+                tol=2e-4)
+        assert telemetry.peek("lower.attention_kernel.pallas_splash") >= 1
+        assert not telemetry.peek("lower.attention_kernel.xla_blockwise")
+    finally:
+        telemetry.disable()
+
+
+def test_model_with_whole_lane_heads_fits_under_recomputation(monkeypatch):
+    """A head of 128: the splash kernel inside the fused step, traced twice
+    (forward and the recomputed segment) from one cached kernel object."""
+    monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    monkeypatch.setenv("MXNET_COMPUTE_DTYPE", "bfloat16")
+    net = get_nemotron_h(pattern="M*E", hidden=64, vocab=256,
+                         experts_total=8, experts_held=4, seq_len=128,
+                         mamba_heads=4, mamba_head_dim=8, ssm_groups=2,
+                         ssm_state=8, chunk=32, attn_heads=2, kv_heads=1,
+                         head_dim=128, top_k=2, expert_hidden=16,
+                         shared_hidden=32)
+    ids = np.random.default_rng(3).integers(0, 256, (4, 128)).astype(np.int32)
+    batches = [(ids[i:i + 1], np.roll(ids[i:i + 1], -1, 1)) for i in range(4)]
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        mod = mx.mod.Module(net, context=mx.cpu(0))
+        mod.fit(Ring(batches), eval_metric="ce", optimizer="adam",
+                optimizer_params={"learning_rate": 1e-3, "rescale_grad": 1.0},
+                initializer=mx.init.Xavier(), num_epoch=1)
+        assert telemetry.peek("lower.attention_kernel.pallas_splash") >= 1
+        assert telemetry.peek("step.dispatches") == 4
+        assert not telemetry.peek("step.fused_fallback")
+    finally:
+        telemetry.disable()
+    got, _ = mod.get_params()
+    assert all(np.isfinite(v.asnumpy()).all() for v in got.values())
