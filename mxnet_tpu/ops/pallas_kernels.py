@@ -29,7 +29,8 @@ from .. import env as _env
 __all__ = ["fused_linear", "flash_attention", "pallas_available",
            "pallas_call", "conv2d", "conv_dgrad", "conv_wgrad",
            "conv_backward_applicable", "fused_norm_act",
-           "norm_act_applicable"]
+           "norm_act_applicable", "ssd_chunk_applicable",
+           "ssd_chunk_forward", "ssd_chunk_backward"]
 
 # float32 MXU-friendly tiles (sublane 8, lane 128)
 TILE_M = 128
@@ -714,3 +715,388 @@ def fused_norm_act(x, scale, shift, act: str = "none",
     f.defvjp(f_fwd, f_bwd)
     out = f(x, sc, sh)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Chunked selective state-space scan (``ops/seq.py`` ``SSMScan``)
+# ---------------------------------------------------------------------------
+#
+# One grid step is one chunk of one group of one sequence, the chunk axis
+# innermost and sequential. It sees the chunk's ``B``, ``C`` ``[L, N]`` and
+# the group's ``x`` ``[L, heads * P]``, forms ``C B^T`` once, and for each
+# head builds the masked decay matrix in VMEM. What crosses chunks (the
+# state, or in the backward kernel its gradient) is a float32 VMEM scratch
+# ``[N, heads * P]``: a head's state transposed, the heads side by side
+# along the lanes. Heads narrower than 128 lanes are handled in units of
+# whole 128-lane tiles: a product with a unit's ``x`` costs the MXU what a
+# product with one head's would, and a lane select keeps each head's half.
+#
+# Precision (both kernels): decays, cumulative sums, the carried state,
+# its gradient and every accumulator are float32; matrix products take
+# their inputs in the compute dtype (``x.dtype``) and accumulate in
+# float32; outputs are rounded to the compute dtype once.
+
+_SSD_MASKED = -1e30     # exp() of it is 0: a decay above the diagonal
+
+
+def _ssd_units(heads, p):
+    """Heads a 128-lane unit holds, its width in lanes, units a group."""
+    side = max(1, 128 // p)
+    return side, side * p, heads // side
+
+
+def ssd_chunk_applicable(dims, chunk, dtype) -> bool:
+    """Whether the chunk kernels take ``dims = (H, P, G, N)``: whole
+    tiles, that is a chunk and a state of whole 128-lane rows, heads that
+    fill 128 lanes alone or side by side, ``B`` and ``C`` starting at a
+    whole state's width inside ``x|B|C``, and a compute dtype the MXU
+    takes."""
+    h, p, g, n = dims
+    side, width, _ = _ssd_units(h // g, p)
+    return (chunk % 128 == 0 and n % 128 == 0 and (h * p) % n == 0
+            and width % 128 == 0 and (h // g) % side == 0
+            and str(dtype) in ("bfloat16", "float32") and pallas_available())
+
+
+def _ssd_small(dt, a_head, g, chunk):
+    """The per-position scalars a chunk needs, by (sequence, group, chunk)
+    with the positions along the lanes ``[.., heads, L]``: the step and
+    the cumulative decay inside the chunk. (A kernel turns them, ``[L,
+    heads]``, where it wants a position a sublane; kept that way in HBM
+    they would be padded sixteenfold.)
+
+    The cumulative sum is a float32 sum made on the MXU: the float32
+    addends cut exactly into three bfloat16 pieces, each times a triangle
+    of ones (exact products, float32 accumulation); it reads as close to a
+    float64 sum as ``jnp.cumsum`` does, and XLA's ``reduce-window`` took
+    0.19 ms a pass over these 2 MB. The pieces are cut with
+    ``lax.reduce_precision``: a convert to bfloat16 and back is an
+    identity to XLA on the TPU, and the sum was then a bfloat16 one."""
+    import jax
+    import jax.numpy as jnp
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    b, t, h = dt.shape
+    hg = h // g
+    dts = dt.reshape(b, t // chunk, chunk, g, hg).transpose(0, 3, 1, 4, 2)
+    upto = jnp.triu(jnp.ones((chunk, chunk), bf16))         # [s, t]: s <= t
+    cum, rest = 0.0, dts * a_head.reshape(1, g, 1, hg, 1)
+    for _ in range(3):
+        piece = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                         mantissa_bits=7)
+        cum = cum + jnp.einsum("bgchs,st->bgcht", piece.astype(bf16), upto,
+                               preferred_element_type=f32)
+        rest = rest - piece
+    return dts, cum
+
+
+def _ssd_chunk_tools(hg, p, chunk):
+    """What the two kernels' bodies share: the three products, a unit's
+    lane masks, the causal mask and a head's decay matrix."""
+    import jax
+    import jax.numpy as jnp
+
+    side, width, units = _ssd_units(hg, p)
+    f32 = jnp.float32
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=f32)
+
+    nn = lambda a, b: dot(a, b, ((1,), (0,)))      # noqa: E731
+    nt = lambda a, b: dot(a, b, ((1,), (1,)))      # noqa: E731
+    tn = lambda a, b: dot(a, b, ((0,), (0,)))      # noqa: E731
+
+    def lanes():
+        return jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+    def spread(cols, unit):
+        """``cols [rows, heads]`` -> ``[rows, width]``: each head of the
+        unit's value across that head's lanes."""
+        h0 = unit * side
+        out = cols[:, h0:h0 + 1]
+        for k in range(1, side):
+            out = jnp.where(lanes() >= k * p, cols[:, h0 + k:h0 + k + 1], out)
+        return out
+
+    def head_lanes(k):
+        lane = lanes()
+        return (lane >= k * p) & (lane < (k + 1) * p)
+
+    def causal(turned=False):
+        row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        return row <= col if turned else row >= col
+
+    def decay(cum, cumr, h, mask, turned=False):
+        """Head h's ``exp(cum_t - cum_s)`` where ``mask`` (s <= t), else 0:
+        t down and s across, or ``turned``, s down and t across."""
+        seg = cum[:, h:h + 1] - cumr[h:h + 1, :]
+        return jnp.exp(jnp.where(mask, -seg if turned else seg,
+                                 _SSD_MASKED))
+
+    return (side, width, units, nn, nt, tn, spread, head_lanes, causal,
+            decay)
+
+
+def _ssd_specs(dims, chunk, order):
+    """Block specs by (sequence, group, chunk); ``order`` maps the grid's
+    chunk index to the chunk (the backward kernel runs them reversed).
+    ``wide`` is a group's heads in ``x|B|C`` or in an array ``[B, T, H *
+    P]``; ``b_in`` / ``c_in`` are the group's ``B`` and ``C`` inside
+    ``x|B|C``, ``state`` the group's in an array ``[B, T, G * N]``,
+    ``small`` the group's per-position scalars (:func:`_ssd_small`)."""
+    from jax.experimental import pallas as pl
+
+    h, p, g, n = dims
+    hg = h // g
+
+    def columns(width, first):
+        return pl.BlockSpec((None, chunk, width),
+                            lambda bi, gi, ci: (bi, order(ci), first + gi))
+
+    small = pl.BlockSpec((None, None, None, hg, chunk),
+                         lambda bi, gi, ci: (bi, gi, order(ci), 0, 0))
+    skip = pl.BlockSpec((None, 1, hg * p), lambda bi, gi, ci: (gi, 0, 0))
+    starts = pl.BlockSpec((None, None, None, n, hg * p),
+                          lambda bi, gi, ci: (bi, order(ci), gi, 0, 0))
+    return (columns(hg * p, 0), columns(n, h * p // n),
+            columns(n, (h * p + g * n) // n), columns(n, 0), small, skip,
+            starts)
+
+
+def _ssd_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _ssd_chunk_forward(xbc, dt, a_head, d_skip, *, dims, chunk, with_states):
+    """``y = scan(x, dt, a_head, B, C) + D x`` for sequences of whole
+    chunks, one kernel call (see the section's comment): ``xbc [B, T, H*P
+    + 2*G*N]`` holds ``x | B | C`` (``dims = (H, P, G, N)``) and is read
+    where it lies, through three block specs. Returns ``y [B, T, H*P]`` in
+    ``xbc.dtype`` and, ``with_states``, the float32 state at each chunk's
+    start as the backward kernel reads it, ``[B, T/chunk, G, N, (H/G) *
+    P]`` (else ``None``). Scratch: the carried state, float32 ``[N, (H/G)
+    * P]``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    (b, t, _), (h, p, g, n) = xbc.shape, dims
+    hg, nc, cd = h // g, t // chunk, xbc.dtype
+    side, width, units, nn, nt, tn, spread, head_lanes, causal, decay = \
+        _ssd_chunk_tools(hg, p, chunk)
+
+    def kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, y_ref, *rest):
+        st_ref = rest[-1]
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            st_ref[...] = jnp.zeros_like(st_ref)
+
+        if with_states:
+            rest[0][...] = st_ref[...]
+        bm, cm = b_ref[...], c_ref[...]
+        cb = nt(cm, bm)                                    # [t, s]
+        from_start = nn(cm, st_ref[...].astype(cd))        # [L, hg * p]
+        cumr = cum_ref[...]
+        dts, cum = dt_ref[...].T, cumr.T
+        last = cum[chunk - 1:chunk, :]
+        ecum, elast = jnp.exp(cum), jnp.exp(last)
+        to_end = jnp.exp(last - cum) * dts
+        lower = causal()
+        # unrolled: as a ``lax.fori_loop`` over the units both kernels ran
+        # at half the speed
+        for unit in range(units):
+            sl = slice(unit * width, (unit + 1) * width)
+            xf = x_ref[:, sl].astype(f32)
+            xdt = (xf * spread(dts, unit)).astype(cd)
+            y = None
+            for k in range(side):
+                m = (cb * decay(cum, cumr, unit * side + k, lower)).astype(cd)
+                part = nn(m, xdt)
+                y = part if y is None else jnp.where(head_lanes(k), part, y)
+            y = y + spread(ecum, unit) * from_start[:, sl] \
+                + d_ref[:, sl] * xf
+            y_ref[:, sl] = y.astype(y_ref.dtype)
+            xw = (xf * spread(to_end, unit)).astype(cd)
+            st_ref[:, sl] = spread(elast, unit) * st_ref[:, sl] + tn(bm, xw)
+
+    dts, cum = _ssd_small(dt, a_head, g, chunk)
+    wide, b_in, c_in, _, row, skip, starts = _ssd_specs(
+        dims, chunk, lambda ci: ci)
+    out_shape = [jax.ShapeDtypeStruct((b, t, h * p), cd)]
+    out_specs = [wide]
+    if with_states:
+        out_shape.append(jax.ShapeDtypeStruct((b, nc, g, n, hg * p), f32))
+        out_specs.append(starts)
+    out = pallas_call(
+        kernel, xbc, xbc, xbc, dts, cum,
+        jnp.repeat(d_skip, p).reshape(g, 1, hg * p),
+        grid=(b, g, nc),
+        in_specs=[wide, b_in, c_in, row, row, skip],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((n, hg * p), f32)],
+        compiler_params=_ssd_params(), name="ssd_chunk_forward")
+    return out[0], out[1] if with_states else None
+
+
+def _ssd_chunk_backward(xbc, dt, a_head, d_skip, starts, dy, *, dims, chunk):
+    """The mirror of :func:`_ssd_chunk_forward` over the chunks reversed:
+    each chunk's matrices are formed again in VMEM from the inputs and the
+    chunk-start state, and the state's gradient rides a float32 scratch
+    ``[N, (H/G) * P]``. Returns what ``seq.ssd_chunked_grad`` returns
+    (see there for the decay's gradient): ``dx [B, T, H*P]``, ``dB``, ``dC
+    [B, T, G*N]`` in ``xbc.dtype`` and, in float32 ``[B, T, H]``, the
+    gradient of each position's cumulative decay and of its step where
+    the step scales ``x``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    (b, t, _), (h, p, g, n) = xbc.shape, dims
+    hg, nc, cd = h // g, t // chunk, xbc.dtype
+    side, width, units, nn, nt, tn, spread, head_lanes, causal, decay = \
+        _ssd_chunk_tools(hg, p, chunk)
+
+    def kernel(x_ref, dy_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, st_ref,
+               dx_ref, db_ref, dc_ref, dcum_ref, dstep_ref, ds_ref):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            ds_ref[...] = jnp.zeros_like(ds_ref)
+
+        bm, cm = b_ref[...], c_ref[...]
+        cb = nt(cm, bm)                                    # [t, s]
+        st0, ds_end = st_ref[...].astype(cd), ds_ref[...].astype(cd)
+        from_start = nn(cm, st0)                           # [L, hg * p]
+        from_end = nn(bm, ds_end)
+        cumr = cum_ref[...]
+        dts, cum = dt_ref[...].T, cumr.T
+        last = cum[chunk - 1:chunk, :]
+        ecum, elast = jnp.exp(cum), jnp.exp(last)
+        wexp = jnp.exp(last - cum)
+        head_col = jax.lax.broadcasted_iota(jnp.int32, (1, hg), 1)
+        head_row = jax.lax.broadcasted_iota(jnp.int32, (hg, 1), 0)
+        at_last = jax.lax.broadcasted_iota(
+            jnp.int32, (chunk, 1), 0) == chunk - 1
+        lower, upper = causal(), causal(True)
+        cb_t = nt(bm, cm)                                  # [s, t]
+        dcb = jnp.zeros((chunk, chunk), f32)
+        dc = jnp.zeros((chunk, n), f32)
+        db = jnp.zeros((chunk, n), f32)
+        at_t = jnp.zeros((chunk, hg), f32)     # d cum, by the row it is in
+        at_s = jnp.zeros((hg, chunk), f32)     # ... by the column, negative
+        dstep = jnp.zeros((chunk, hg), f32)
+
+        def by_head(v, k):
+            return jnp.sum(jnp.where(head_lanes(k), v, 0.0), axis=1,
+                           keepdims=True)
+
+        for unit in range(units):
+            sl = slice(unit * width, (unit + 1) * width)
+            xu, gu = x_ref[:, sl], dy_ref[:, sl]
+            xf, gf = xu.astype(f32), gu.astype(f32)
+            dte, ee, we = (spread(v, unit) for v in (dts, ecum, wexp))
+            xdt = (xf * dte).astype(cd)
+            dxs = None
+            for k in range(side):
+                hd = unit * side + k
+                dk = decay(cum, cumr, hd, lower)
+                mf = cb * dk
+                mine = head_lanes(k)
+                dm = nt(gu if side == 1 else
+                        jnp.where(mine, gu, jnp.zeros_like(gu)), xdt)
+                dcb = dcb + dm * dk
+                # the decay's gradient: one matrix, by rows and by columns
+                e = dm * mf
+                at_t = jnp.where(head_col == hd,
+                                 jnp.sum(e, axis=1, keepdims=True), at_t)
+                at_s = jnp.where(head_row == hd,
+                                 jnp.sum(e, axis=0, keepdims=True), at_s)
+                # m transposed, formed where it is used: measured faster
+                # than turning m (0.23 ms a layer's backward pass)
+                dxk = nn((cb_t * decay(cum, cumr, hd, upper, True)
+                          ).astype(cd), gu)
+                dxs = dxk if dxs is None else jnp.where(mine, dxk, dxs)
+            end_u = from_end[:, sl]
+            dxs = dxs + we * end_u
+            dx_ref[:, sl] = (dte * dxs + d_ref[:, sl] * gf).astype(
+                dx_ref.dtype)
+            got = gf * ee * from_start[:, sl]      # <dy, y from the start>
+            gave = xf * (we * dte) * end_u         # <x to the end, dS_end>
+            xdx = xf * dxs
+            # what the decay carries over the whole chunk: the state,
+            # <dS_end, S_start>, and each position's own part of dS_end
+            over = jnp.sum(ds_ref[:, sl] * st_ref[:, sl], axis=0,
+                           keepdims=True)
+            all_gave = jnp.sum(gave, axis=0, keepdims=True)
+            for k in range(side):
+                hd = unit * side + k
+                mid = by_head(got - gave, k)
+                ends = by_head(all_gave, k) \
+                    + elast[:, hd:hd + 1] * by_head(over, k)
+                at_t = at_t + jnp.where(
+                    head_col == hd, jnp.where(at_last, mid + ends, mid), 0.0)
+                dstep = jnp.where(head_col == hd, by_head(xdx, k), dstep)
+            dyw = (gf * ee).astype(cd)
+            xw = (xf * (we * dte)).astype(cd)
+            dc = dc + nt(dyw, st0[:, sl])
+            db = db + nt(xw, ds_end[:, sl])
+            ds_ref[:, sl] = spread(elast, unit) * ds_ref[:, sl] + tn(cm, dyw)
+        dcb = dcb.astype(cd)
+        dc_ref[...] = (dc + nn(dcb, bm)).astype(dc_ref.dtype)
+        db_ref[...] = (db + tn(dcb, cm)).astype(db_ref.dtype)
+        dcum_ref[...] = at_t.T - at_s
+        dstep_ref[...] = dstep.T
+
+    dts, cum = _ssd_small(dt, a_head, g, chunk)
+    wide, b_in, c_in, state, row, skip, at_start = _ssd_specs(
+        dims, chunk, lambda ci: nc - 1 - ci)
+    small = jax.ShapeDtypeStruct((b, g, nc, hg, chunk), f32)
+    dx, db, dc, dcum, dstep = pallas_call(
+        kernel, xbc, dy, xbc, xbc, dts, cum,
+        jnp.repeat(d_skip, p).reshape(g, 1, hg * p), starts,
+        grid=(b, g, nc),
+        in_specs=[wide, wide, b_in, c_in, row, row, skip, at_start],
+        out_specs=[wide, state, state, row, row],
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * p), cd),
+                   jax.ShapeDtypeStruct((b, t, g * n), cd),
+                   jax.ShapeDtypeStruct((b, t, g * n), cd), small, small],
+        scratch_shapes=[pltpu.VMEM((n, hg * p), f32)],
+        compiler_params=_ssd_params(), name="ssd_chunk_backward")
+
+    def by_position(v):
+        return v.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
+
+    return dx, db, dc, by_position(dcum), by_position(dstep)
+
+
+@functools.lru_cache(None)
+def _ssd_jitted():
+    """The two kernels' callers as ``jax.jit`` functions, made once: the
+    layers of a model share shapes, so a step traces and lowers each
+    kernel (an unrolled body, and the interpreter's twin beside it) once
+    and calls it a layer, where bare calls traced it a layer and pass: 9
+    s of an 8,192-token model's set-up on the chip's host."""
+    import jax
+
+    return (jax.jit(_ssd_chunk_forward,
+                    static_argnames=("dims", "chunk", "with_states")),
+            jax.jit(_ssd_chunk_backward, static_argnames=("dims", "chunk")))
+
+
+def ssd_chunk_forward(*args, **static):
+    """:func:`_ssd_chunk_forward` through its shared ``jax.jit``."""
+    return _ssd_jitted()[0](*args, **static)
+
+
+def ssd_chunk_backward(*args, **static):
+    """:func:`_ssd_chunk_backward` through its shared ``jax.jit``."""
+    return _ssd_jitted()[1](*args, **static)

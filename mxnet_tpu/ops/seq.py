@@ -363,47 +363,58 @@ class CausalConv1D(Operator):
         return [out.reshape(rows, c).astype(x.dtype)], []
 
 
-def ssd_chunked(x, dt, a_head, b_mat, c_mat, chunk):
-    """One sequence of the selective state-space recurrence
-    ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t`` (per
-    head; S is ``[P, N]``), computed by chunks of ``chunk`` positions:
-    inside a chunk the quadratic form (a masked ``[chunk, chunk]`` decay
-    matrix times ``C B^T``), between chunks the carried state. Decays,
-    cumulative sums and the carried state are float32; the matrix
-    products take their inputs in ``x.dtype`` and accumulate in float32.
-
-    ``x [T, H, P]``, ``dt [T, H]`` (after softplus, float32), ``a_head
-    [H]`` (negative, float32), ``b_mat``/``c_mat [T, G, N]``; head h reads
-    group ``h // (H/G)``. Returns ``y [T, H, P]`` float32. Nothing here is
-    ``[T, T]`` and no per-position state exists, so what autodiff keeps is
-    linear in T; callers wrap it in ``jax.checkpoint`` so that only the
-    inputs outlive the forward pass."""
-    jax, jnp = _jax(), _jnp()
+def _ssd_chunks(x, dt, a_head, b_mat, c_mat, chunk):
+    """What both passes of the XLA body share: the inputs cut into chunks
+    ``[nc, L, ...]``, the cumulative decay inside each chunk, the masked
+    decay matrix and the quadratic form's matrix ``(C B^T) * decay``."""
+    jnp = _jnp()
     f32 = jnp.float32
-    t_real, h, p = x.shape
+    h, p = x.shape[1:]
     g, n = b_mat.shape[1:]
     hg = h // g
-    cd = x.dtype
-    pad = -t_real % chunk
-    if pad:
-        # dt = 0 past the end: decay 1, no input, outputs cut off below
-        x, dt, b_mat, c_mat = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
-                               for v in (x, dt, b_mat, c_mat))
     nc = x.shape[0] // chunk
     xs = x.reshape(nc, chunk, g, hg, p)
     bs = b_mat.reshape(nc, chunk, g, n)
     cs_ = c_mat.reshape(nc, chunk, g, n)
     dts = dt.reshape(nc, chunk, g, hg)
     cum = jnp.cumsum(dts * a_head.reshape(g, hg), axis=1)   # [nc, L, g, hg]
-    # inside the chunk: y_t += sum_{s<=t} exp(cum_t - cum_s) (C_t.B_s) dt_s x_s
     cb = jnp.einsum("ctgn,csgn->cgts", cs_, bs, preferred_element_type=f32)
     cum_h = cum.transpose(0, 2, 3, 1)                       # [nc, g, hg, L]
     seg = cum_h[..., :, None] - cum_h[..., None, :]         # [.., t, s]
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
-    m = cb[:, :, None] * decay * dts.transpose(0, 2, 3, 1)[..., None, :]
-    y = jnp.einsum("cghts,csghp->ctghp", m.astype(cd), xs,
-                   preferred_element_type=f32)
+    # the step rides on x, so one matrix serves y = m (dt x) and its mirror
+    xdt = (xs.astype(f32) * dts[..., None]).astype(x.dtype)
+    m = (cb[:, :, None] * decay).astype(x.dtype)
+    return xs, bs, cs_, dts, cum, decay, cb, xdt, m
+
+
+def ssd_chunked(x, dt, a_head, d_skip, b_mat, c_mat, chunk):
+    """One sequence of the selective state-space recurrence
+    ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D
+    x_t`` (per head; S is ``[P, N]``), computed by chunks of ``chunk``
+    positions: inside a chunk the quadratic form (a masked ``[chunk,
+    chunk]`` decay matrix times ``C B^T``), between chunks the carried
+    state. Decays, cumulative sums and the carried state are float32; the
+    matrix products take their inputs in ``x.dtype`` and accumulate in
+    float32; ``y`` is rounded to ``x.dtype`` once, after the skip.
+
+    ``x [T, H, P]`` (T whole chunks), ``dt [T, H]`` (after softplus,
+    float32), ``a_head [H]`` (negative, float32), ``d_skip [H]``,
+    ``b_mat``/``c_mat [T, G, N]``; head h reads group ``h // (H/G)``.
+    Returns ``y [T, H, P]`` and the float32 state at each chunk's start
+    ``[T/chunk, G, H/G, P, N]``: all that :func:`ssd_chunked_grad` needs
+    beside the inputs. Nothing here is ``[T, T]`` and no per-position
+    state exists."""
+    jax, jnp = _jax(), _jnp()
+    f32 = jnp.float32
+    t, h, p = x.shape
+    g, n = b_mat.shape[1:]
+    cd = x.dtype
+    xs, bs, cs_, dts, cum, _, _, xdt, m = _ssd_chunks(x, dt, a_head, b_mat,
+                                                      c_mat, chunk)
+    # inside the chunk: y_t += sum_{s<=t} exp(cum_t - cum_s) (C_t.B_s) dt_s x_s
+    y = jnp.einsum("cghts,csghp->ctghp", m, xdt, preferred_element_type=f32)
     # each chunk's own contribution to the state at its end
     to_end = jnp.exp(cum[:, -1:] - cum) * dts               # [nc, L, g, hg]
     xw = (xs.astype(f32) * to_end[..., None]).astype(cd)
@@ -415,11 +426,161 @@ def ssd_chunked(x, dt, a_head, b_mat, c_mat, chunk):
         d, st = inp
         return d[..., None, None] * s + st, s               # emits the START
 
-    _, starts = jax.lax.scan(carry, jnp.zeros((g, hg, p, n), f32),
+    _, starts = jax.lax.scan(carry, jnp.zeros((g, h // g, p, n), f32),
                              (jnp.exp(cum[:, -1]), states))
     y = y + jnp.einsum("ctgn,cghpn->ctghp", cs_, starts.astype(cd),
                        preferred_element_type=f32) * jnp.exp(cum)[..., None]
-    return y.reshape(nc * chunk, h, p)[:t_real]
+    y = y.reshape(t, h, p) + d_skip[:, None] * x.astype(f32)
+    return y.astype(cd), starts
+
+
+def ssd_chunked_grad(x, dt, a_head, d_skip, b_mat, c_mat, starts, dy, chunk):
+    """The backward pass of :func:`ssd_chunked`, written out. The chunk's
+    matrices are formed again from the inputs, the state's gradient ``[P,
+    N]`` is carried in float32 from the last chunk to the first, and every
+    product mirrors one of the forward pass (inputs in ``x.dtype``,
+    float32 accumulation). Returns ``dx, dB, dC`` in ``x.dtype`` and, in
+    float32 ``[T, H]``, the gradient of each position's cumulative decay
+    and of its step where the step scales ``x``
+    (:func:`_ssd_step_grads` makes ``d dt`` and ``dA`` of the two).
+
+    The decay's gradient inside a chunk is ONE float32 matrix ``dM * M``
+    summed along its rows (at t) and along its columns (at s, negative):
+    the same number on both sides, so what cancels cancels exactly. (The
+    shorter ``<dy_t, y_t> - <x_t, dx_t>`` forms the two sides from
+    differently rounded products, and the difference drifts along the
+    sequence.)"""
+    jax, jnp = _jax(), _jnp()
+    f32 = jnp.float32
+    t, h, p = x.shape
+    g, n = b_mat.shape[1:]
+    cd = x.dtype
+    xs, bs, cs_, dts, cum, decay, cb, xdt, m = _ssd_chunks(
+        x, dt, a_head, b_mat, c_mat, chunk)
+    gs = dy.reshape(xs.shape)
+    gf, xf = gs.astype(f32), xs.astype(f32)
+    ecum = jnp.exp(cum)
+    wexp = jnp.exp(cum[:, -1:] - cum)
+    # the state's gradient: what reaches a chunk's END from the chunks after
+    dyw = (gf * ecum[..., None]).astype(cd)
+    local = jnp.einsum("ctghp,ctgn->cghpn", dyw, cs_,
+                       preferred_element_type=f32)
+
+    def carry(ds, inp):
+        d, dl = inp
+        return d[..., None, None] * ds + dl, ds
+
+    _, ds_end = jax.lax.scan(carry, jnp.zeros(starts.shape[1:], f32),
+                             (jnp.exp(cum[:, -1]), local), reverse=True)
+    # the decay carries the whole state over a chunk: <dS_end, S_start>
+    at_end = jnp.exp(cum[:, -1]) * jnp.sum(ds_end * starts, axis=(-2, -1))
+    ds_end, st0 = ds_end.astype(cd), starts.astype(cd)
+    from_start = jnp.einsum("ctgn,cghpn->ctghp", cs_, st0,
+                            preferred_element_type=f32)
+    from_end = jnp.einsum("csgn,cghpn->csghp", bs, ds_end,
+                          preferred_element_type=f32)
+    dxs = jnp.einsum("cghts,ctghp->csghp", m, gs,
+                     preferred_element_type=f32) + from_end * wexp[..., None]
+    dm = jnp.einsum("ctghp,csghp->cghts", gs, xdt,
+                    preferred_element_type=f32)
+    dcb = jnp.sum(dm * decay, axis=2).astype(cd)            # [nc, g, t, s]
+    xw = (xf * (wexp * dts)[..., None]).astype(cd)
+    dc = jnp.einsum("cgts,csgn->ctgn", dcb, bs, preferred_element_type=f32) \
+        + jnp.einsum("ctghp,cghpn->ctgn", dyw, st0,
+                     preferred_element_type=f32)
+    db = jnp.einsum("cgts,ctgn->csgn", dcb, cs_, preferred_element_type=f32) \
+        + jnp.einsum("csghp,cghpn->csgn", xw, ds_end,
+                     preferred_element_type=f32)
+    e = dm * (cb[:, :, None] * decay)                       # [nc, g, hg, t, s]
+    to_end = jnp.sum(xf * from_end, axis=-1) * wexp * dts   # [nc, L, g, hg]
+    dcum = (jnp.sum(e, axis=-1) - jnp.sum(e, axis=-2)).transpose(0, 3, 1, 2) \
+        + jnp.sum(gf * from_start, axis=-1) * ecum - to_end
+    dcum = dcum.at[:, -1].add(jnp.sum(to_end, axis=1) + at_end)
+    dx = dts[..., None] * dxs + d_skip.reshape(g, h // g, 1) * gf
+    return (dx.reshape(t, h, p).astype(cd), db.reshape(t, g, n).astype(cd),
+            dc.reshape(t, g, n).astype(cd), dcum.reshape(t, h),
+            jnp.sum(xf * dxs, axis=-1).reshape(t, h))
+
+
+def _ssd_step_grads(dt, a_head, dcum, dstep, chunk):
+    """``d dt [B, T, H]`` and ``dA [H]`` from the backward pass's two
+    sums: a step enters the cumulative decay of every later position of
+    its chunk, and scales ``x``."""
+    jax, jnp = _jax(), _jnp()
+    b, t, h = dt.shape
+    da = jax.lax.cumsum(dcum.reshape(b, t // chunk, chunk, h), axis=2,
+                        reverse=True).reshape(b, t, h)
+    return dstep + da * a_head, jnp.sum(da * dt, axis=(0, 1))
+
+
+def ssd_scan(xbc, dt, a_head, d_skip, dims, chunk, kernel):
+    """:func:`ssd_chunked` over sequences as ONE differentiable function
+    with both passes written out. ``xbc [B, T, H*P + 2*G*N]`` holds ``x |
+    B | C`` side by side as ``SSMScan`` gets them (``dims = (H, P, G,
+    N)``; T whole chunks), ``dt [B, T, H]``; returns ``y [B, T, H*P]``.
+    ``kernel`` picks the body: the Pallas chunk kernel
+    (``pallas_kernels.ssd_chunk_forward`` / ``_backward``: the decay
+    matrices and the carried state stay in VMEM, and the three parts are
+    read where they lie) or the same algorithm in ``jax.numpy``, one
+    sequence at a time. The residuals are the inputs and the float32
+    chunk-start states."""
+    jax, jnp = _jax(), _jnp()
+    from . import pallas_kernels
+
+    h, p, g, n = dims
+    di, gn = h * p, g * n
+
+    def parts(xbc):
+        lead = xbc.shape[:-1]
+        return (xbc[..., :di].reshape(lead + (h, p)),
+                xbc[..., di:di + gn].reshape(lead + (g, n)),
+                xbc[..., di + gn:].reshape(lead + (g, n)))
+
+    def run(with_states, xbc, dt, a_head, d_skip):
+        if kernel:
+            return pallas_kernels.ssd_chunk_forward(
+                xbc, dt, a_head, d_skip, dims=dims, chunk=chunk,
+                with_states=with_states)
+
+        def one(v):
+            x, b_mat, c_mat = parts(v[0])
+            y, starts = ssd_chunked(x, v[1], a_head, d_skip, b_mat, c_mat,
+                                    chunk)
+            return y.reshape(-1, di), starts
+
+        return jax.lax.map(one, (xbc, dt))
+
+    @jax.custom_vjp
+    def f(*args):
+        return run(False, *args)[0]
+
+    def f_fwd(*args):
+        y, starts = run(True, *args)
+        return y, args + (starts,)
+
+    def f_bwd(res, dy):
+        xbc, dt, a_head, d_skip, starts = res
+        if kernel:
+            dx, db, dc, dcum, dstep = pallas_kernels.ssd_chunk_backward(
+                *res, dy, dims=dims, chunk=chunk)
+        else:
+            def one(v):
+                x, b_mat, c_mat = parts(v[0])
+                dx, db, dc, dcum, dstep = ssd_chunked_grad(
+                    x, v[1], a_head, d_skip, b_mat, c_mat, v[2],
+                    v[3].reshape(x.shape), chunk)
+                return (dx.reshape(-1, di), db.reshape(-1, gn),
+                        dc.reshape(-1, gn), dcum, dstep)
+
+            dx, db, dc, dcum, dstep = jax.lax.map(one, (xbc, dt, starts, dy))
+        ddt, da = _ssd_step_grads(dt, a_head, dcum, dstep, chunk)
+        dd = jnp.sum((dy.astype(jnp.float32)
+                      * xbc[..., :di].astype(jnp.float32)).reshape(-1, h, p),
+                     axis=(0, 2))
+        return jnp.concatenate([dx, db, dc], axis=-1), ddt, da, dd
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(xbc, dt, a_head, d_skip)
 
 
 @register_op("SSMScan")
@@ -430,9 +591,19 @@ class SSMScan(Operator):
     of P), ``B`` and ``C`` (G groups of N), as the mixer's convolution
     leaves them; ``dt`` is ``[rows, H]`` before its bias and softplus.
     ``y = scan(x, softplus(dt + dt_bias), -exp(A_log), B, C) + D * x``.
-    The backward pass is autodiff of the chunked form under
-    ``jax.checkpoint``, one sequence at a time: memory linear in
-    ``seq_len``, inputs the only residuals."""
+
+    Both passes are written out (:func:`ssd_scan`): the backward pass
+    forms each chunk's matrices again and keeps only the inputs and the
+    float32 chunk-start states, so memory is linear in ``seq_len`` and
+    under segment recomputation the scan runs forward, forward, backward.
+    The body is chosen from the shapes when the node is traced, as
+    ``CausalAttention`` chooses: one Pallas chunk kernel where ``chunk``,
+    ``state_size`` and the heads are whole tiles
+    (``lower.scan_kernel.pallas_chunked``), else the same algorithm in
+    ``jax.numpy`` (``lower.scan_kernel.xla_chunked``). In both, decays,
+    cumulative sums, the carried state, its gradient and every accumulator
+    are float32; the matrix products take their inputs in the compute
+    dtype."""
 
     name_hint = "ssmscan"
     PARAMS = {
@@ -470,27 +641,26 @@ class SSMScan(Operator):
     def apply(self, ctx, inputs, aux):
         jax, jnp = _jax(), _jnp()
         from .. import telemetry as _tel
+        from . import pallas_kernels
 
-        _tel.inc("lower.scan_kernel.xla_chunked")
         xbc, dt, a_log, d_skip, dt_bias = inputs
         f32 = jnp.float32
-        di, gn = self._widths()
         t = self.seq_len
         b = xbc.shape[0] // t
-        h, p, g, n = (self.num_heads, self.head_dim, self.num_groups,
-                      self.state_size)
-        x = xbc[:, :di].reshape(b, t, h, p)
-        b_mat = xbc[:, di:di + gn].reshape(b, t, g, n)
-        c_mat = xbc[:, di + gn:].reshape(b, t, g, n)
+        dims = (self.num_heads, self.head_dim, self.num_groups,
+                self.state_size)
+        xbc = xbc.reshape(b, t, -1)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-        a_head = -jnp.exp(a_log.astype(f32))
-        chunk = self.chunk
-
-        @jax.checkpoint
-        def one(x, dt, b_mat, c_mat, a_head):
-            return ssd_chunked(x, dt, a_head, b_mat, c_mat, chunk)
-
-        y = jax.lax.map(lambda v: one(*v, a_head),
-                        (x, dt.reshape(b, t, h), b_mat, c_mat))
-        y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
-        return [y.reshape(b * t, di).astype(xbc.dtype)], []
+        dt = dt.reshape(b, t, -1)
+        pad = -t % self.chunk
+        if pad:
+            # dt = 0 past the end: decay 1, no input, outputs cut off below
+            xbc, dt = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                       for v in (xbc, dt))
+        kernel = pallas_kernels.ssd_chunk_applicable(dims, self.chunk,
+                                                     xbc.dtype)
+        _tel.inc("lower.scan_kernel.pallas_chunked" if kernel
+                 else "lower.scan_kernel.xla_chunked")
+        y = ssd_scan(xbc, dt, -jnp.exp(a_log.astype(f32)),
+                     d_skip.astype(f32), dims, self.chunk, kernel)
+        return [y[:, :t].reshape(b * t, -1)], []

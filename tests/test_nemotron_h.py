@@ -150,6 +150,7 @@ def test_causal_conv1d_against_plain():
 # the chunked scan against the step-by-step recurrence
 # ---------------------------------------------------------------------------
 def plain_scan(data, dt, A_log, D, dt_bias, t, h, p, g, n):
+    """The recurrence, a position at a time."""
     b = data.shape[0] // t
     di, gn = h * p, g * n
     x = data[:, :di].reshape(b, t, h, p)
@@ -157,28 +158,254 @@ def plain_scan(data, dt, A_log, D, dt_bias, t, h, p, g, n):
     cm = jnp.repeat(data[:, di + gn:].reshape(b, t, g, n), h // g, axis=2)
     step = jax.nn.softplus(dt.reshape(b, t, h) + dt_bias)
     a = -jnp.exp(A_log)
-    s = jnp.zeros((b, h, p, n))
-    ys = []
-    for i in range(t):
-        s = jnp.exp(step[:, i] * a)[..., None, None] * s \
-            + (step[:, i, :, None] * x[:, i])[..., None] * bm[:, i, :, None]
-        ys.append(jnp.einsum("bhpn,bhn->bhp", s, cm[:, i]) + D[:, None]
-                  * x[:, i])
-    return jnp.stack(ys, axis=1).reshape(b * t, di)
+
+    def one(s, v):
+        x_i, step_i, b_i, c_i = v
+        s = jnp.exp(step_i * a)[..., None, None] * s \
+            + (step_i[..., None] * x_i)[..., None] * b_i[:, :, None]
+        return s, jnp.einsum("bhpn,bhn->bhp", s, c_i) + D[:, None] * x_i
+
+    _, ys = jax.lax.scan(one, jnp.zeros((b, h, p, n)),
+                         tuple(v.swapaxes(0, 1) for v in (x, step, bm, cm)))
+    return ys.swapaxes(0, 1).reshape(b * t, di)
 
 
-@pytest.mark.parametrize("t", [8, 12, 24], ids=["1chunk", "1.5chunks",
-                                                "3chunks"])
-def test_ssm_scan_against_recurrence(t):
-    h, p, g, n, chunk = 4, 3, 2, 5, 8
-    inputs = rng_inputs(4, data=(2 * t, h * p + 2 * g * n), dt=(2 * t, h),
+def scan_net(t, h, p, g, n, chunk):
+    names = ("data", "dt", "A_log", "D", "dt_bias")
+    return sym.SSMScan(num_heads=h, head_dim=p, num_groups=g, state_size=n,
+                       chunk=chunk, seq_len=t,
+                       **{k: sym.Variable(k) for k in names})
+
+
+def scan_inputs(seed, rows, h, p, g, n, dt_shift=-2.0, a_shift=0.0):
+    inputs = rng_inputs(seed, data=(rows, h * p + 2 * g * n), dt=(rows, h),
                         A_log=(h,), D=(h,), dt_bias=(h,))
-    inputs["dt_bias"] -= 2.0          # steps of about 0.1
-    v = {k: sym.Variable(k) for k in inputs}
-    net = sym.SSMScan(num_heads=h, head_dim=p, num_groups=g, state_size=n,
-                      chunk=chunk, seq_len=t, **v)
-    against(lambda **kw: plain_scan(t=t, h=h, p=p, g=g, n=n, **kw), net,
-            inputs, tol=5e-5)
+    inputs["dt_bias"] += dt_shift     # -2: steps of about 0.1
+    inputs["A_log"] += a_shift
+    return inputs
+
+
+def scan_counters():
+    return tuple(telemetry.peek("lower.scan_kernel." + k) or 0
+                 for k in ("pallas_chunked", "xla_chunked"))
+
+
+# whole tiles: the Pallas chunk kernel (the interpreter here); anything
+# else: the same written passes in jax.numpy
+TOY_SCAN = dict(h=4, p=3, g=2, n=5, chunk=8)
+TILE_SCAN = dict(h=8, p=64, g=2, n=128, chunk=128)
+
+
+@pytest.mark.parametrize("shape,t,body", [
+    (TOY_SCAN, 8, "xla"), (TOY_SCAN, 12, "xla"), (TOY_SCAN, 24, "xla"),
+    (TILE_SCAN, 384, "pallas"), (TILE_SCAN, 512, "pallas"),
+    (TILE_SCAN, 200, "pallas"), (dict(TILE_SCAN, p=128, h=4), 256, "pallas"),
+    (dict(TILE_SCAN, n=64), 128, "xla"), (dict(TILE_SCAN, chunk=64), 128,
+                                          "xla"),
+], ids=["1chunk", "1.5chunks", "3chunks", "kernel-3chunks", "kernel-4chunks",
+        "kernel-padded-tail", "kernel-head-of-128", "state-of-64-is-xla",
+        "chunk-of-64-is-xla"])
+def test_ssm_scan_against_recurrence(shape, t, body):
+    """Both bodies, two sequences: the output and the gradients of all
+    five arguments against ``jax.grad`` of the step-by-step recurrence in
+    float32, and the counter that says which body the shapes chose."""
+    h, p, g, n = (shape[k] for k in "hpgn")
+    # at a chunk of 128 the steps are a model's (about 0.05, A in -2..0):
+    # the cumulative decay inside a chunk is a float32 sum, so steps that
+    # drive it to the hundreds cost the CHUNKED form digits the
+    # position-by-position recurrence keeps
+    inputs = scan_inputs(4, 2 * t, h, p, g, n) if shape["chunk"] < 64 \
+        else scan_inputs(4, 2 * t, h, p, g, n, dt_shift=-3.0, a_shift=-1.0)
+    net = scan_net(t, h, p, g, n, shape["chunk"])
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        against(lambda **kw: plain_scan(t=t, h=h, p=p, g=g, n=n, **kw), net,
+                inputs, tol=5e-5)
+        assert scan_counters() == ((1, 0) if body == "pallas" else (0, 1))
+    finally:
+        telemetry.disable()
+
+
+def chunked_scan_rounding_state(inputs, t, h, p, g, n, chunk, state_dtype):
+    """The recurrence in float32, a chunk at a time, the state carried to
+    the next chunk rounded to ``state_dtype``: what a kernel whose scratch
+    is bfloat16 would compute, nothing else rounded."""
+    step = jax.nn.softplus(inputs["dt"].reshape(t, h) + inputs["dt_bias"])
+    decay = jnp.exp(step * -jnp.exp(inputs["A_log"]))
+    di, gn = h * p, g * n
+    x = inputs["data"][:, :di].reshape(t, h, p)
+    bm = jnp.repeat(inputs["data"][:, di:di + gn].reshape(t, g, n), h // g, 1)
+    cm = jnp.repeat(inputs["data"][:, di + gn:].reshape(t, g, n), h // g, 1)
+
+    def one(s, v):
+        d, dx, b, c = v
+        s = d[:, None, None] * s + dx[..., None] * b[:, None]
+        return s, jnp.einsum("hpn,hn->hp", s, c)
+
+    s, ys = jnp.zeros((h, p, n)), []
+    for i in range(0, t, chunk):
+        sl = slice(i, i + chunk)
+        s, y = jax.lax.scan(one, s, (decay[sl], step[sl, :, None] * x[sl],
+                                     bm[sl], cm[sl]))
+        s = s.astype(state_dtype).astype(jnp.float32)
+        ys.append(y)
+    return (jnp.concatenate(ys) + inputs["D"][:, None] * x).reshape(t, di)
+
+
+@pytest.mark.parametrize("shape,t", [(dict(TOY_SCAN, p=8, n=8), 8 * 48),
+                                     (dict(TILE_SCAN, h=2, g=1), 128 * 32)],
+                         ids=["xla", "pallas"])
+def test_ssm_scan_carries_a_float32_state_under_bfloat16(shape, t):
+    """bfloat16 inputs, many chunks, next to no decay, a first chunk that
+    leaves a state a thousand times what each later chunk adds: rounded to
+    bfloat16 at a chunk's end, the state swallows every later addition
+    (each under half a unit in its last place) and drifts from the
+    float32 recurrence by a part in a thousand a chunk. The tolerance sits
+    between that and the rounding of the matrix products' inputs. The
+    mirror holds the gradient's carried state: a last chunk whose head
+    gradient is a thousand times the others'."""
+    from mxnet_tpu.executor import make_graph_eval
+
+    h, p, g, n, chunk = (shape[k] for k in ("h", "p", "g", "n", "chunk"))
+    inputs = scan_inputs(6, t, h, p, g, n, dt_shift=-4.0, a_shift=-12.0)
+    inputs["data"] = np.abs(inputs["data"])     # one sign: the state grows
+    inputs["data"][:chunk, :h * p] *= 1000.0
+    inputs["D"] *= 0.0
+    inputs = {k: np.asarray(jnp.asarray(v, jnp.bfloat16 if k in (
+        "data", "dt") else jnp.float32)) for k, v in inputs.items()}
+    as_f32 = {k: jnp.asarray(v, jnp.float32) for k, v in inputs.items()}
+    want = chunked_scan_rounding_state(as_f32, t, h, p, g, n, chunk,
+                                       jnp.float32)
+    rounded = chunked_scan_rounding_state(as_f32, t, h, p, g, n, chunk,
+                                          jnp.bfloat16)
+    net = scan_net(t, h, p, g, n, chunk)
+    eval_graph, _ = make_graph_eval(net)
+    names = net.list_arguments()
+
+    def gap(a, b):
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+    got, vjp = jax.vjp(lambda data: eval_graph(
+        [data if k == "data" else jnp.asarray(inputs[k]) for k in names], [],
+        None, True)[0][0], jnp.asarray(inputs["data"]))
+    assert got.dtype == jnp.bfloat16
+    assert gap(got, want) < 4e-3 < 8e-3 < gap(rounded, want), \
+        (gap(got, want), gap(rounded, want))
+    head = np.ones(want.shape, np.float32)
+    head[-chunk:] *= 1000.0
+    want_dx = jax.grad(lambda d: jnp.sum(head * chunked_scan_rounding_state(
+        {**as_f32, "data": d}, t, h, p, g, n, chunk, jnp.float32)))(
+            as_f32["data"])[:-chunk, :h * p]
+    dx = vjp(jnp.asarray(head, got.dtype))[0][:-chunk, :h * p]
+    assert gap(dx, want_dx) < 4e-3, gap(dx, want_dx)
+
+
+@pytest.mark.parametrize("shape,t", [(dict(TOY_SCAN, p=8, n=8), 8 * 64),
+                                     (dict(TILE_SCAN, h=2, g=1), 128 * 32)],
+                         ids=["xla", "pallas"])
+def test_ssm_scan_step_gradients_hold_along_a_long_sequence(shape, t):
+    """bfloat16, many chunks: the gradients of ``A_log`` and ``dt_bias``
+    sum the decay's gradient over every position. Written as ``<dy_t, y_t>
+    - <x_t, dx_t>`` summed from the sequence's end, that gradient is a
+    difference of two differently rounded products and drifts (it read
+    2-3 times the true value here, and 82% off on the chip at 8,192
+    positions); as one matrix by rows and columns inside each chunk it
+    stays with float32 autodiff of the recurrence."""
+    from mxnet_tpu.executor import make_graph_eval
+
+    h, p, g, n, chunk = (shape[k] for k in ("h", "p", "g", "n", "chunk"))
+    inputs = scan_inputs(8, t, h, p, g, n, dt_shift=-3.0, a_shift=-1.0)
+    inputs["data"][:, h * p:] *= 0.3
+    inputs = {k: jnp.asarray(v, jnp.bfloat16 if k in ("data", "dt")
+                             else jnp.float32) for k, v in inputs.items()}
+    head = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (t, h * p)), jnp.bfloat16)
+    names = scan_net(t, h, p, g, n, chunk).list_arguments()
+    eval_graph, _ = make_graph_eval(scan_net(t, h, p, g, n, chunk))
+    got = jax.grad(lambda fl: jnp.sum(eval_graph(
+        [fl[k] for k in names], [], None, True)[0][0].astype(jnp.float32)
+        * head.astype(jnp.float32)))(inputs)
+    want = jax.jit(jax.grad(lambda fl: jnp.sum(plain_scan(
+        t=t, h=h, p=p, g=g, n=n, **fl) * head.astype(jnp.float32))))(
+            {k: v.astype(jnp.float32) for k, v in inputs.items()})
+    for k in ("A_log", "dt_bias", "D"):
+        close(got[k], want[k], tol=2e-2)
+
+
+def _sub_jaxprs(jaxpr):
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _sub_jaxprs(sub)
+
+
+def test_ssm_scan_scratch_and_carries_are_float32():
+    """What crosses chunks, by dtype, in both bodies' traced passes: the
+    kernels' VMEM scratch, the ``lax.scan`` carries of the XLA body and
+    the chunk-start states kept for the backward pass."""
+    from mxnet_tpu.ops import pallas_kernels, seq
+
+    dims, chunk, t = (4, 64, 2, 128), 128, 256
+    h, p, g, n = dims
+    bf16 = jnp.bfloat16
+    args = (jnp.ones((1, t, h * p + 2 * g * n), bf16), jnp.ones((1, t, h)),
+            -jnp.ones((h,)), jnp.ones((h,)))
+    for kernel in (True, False):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(seq.ssd_scan(
+            *a, dims, chunk, kernel).astype(jnp.float32)),
+            argnums=range(4)))(*args)
+        scratch, carries = [], []
+        for sub in _sub_jaxprs(jaxpr.jaxpr):
+            for eqn in sub.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    scratch += [a.dtype for a in
+                                eqn.params["grid_mapping"].scratch_avals]
+                if eqn.primitive.name == "scan" and eqn.params["num_carry"]:
+                    nc, k = eqn.params["num_consts"], eqn.params["num_carry"]
+                    carries += [v.aval.dtype for v in eqn.invars[nc:nc + k]
+                                if v.aval.ndim == 4]
+        held = scratch if kernel else carries
+        # the interpreter's and Mosaic's branch each hold both kernels
+        assert len(held) == (4 if kernel else 2), (kernel, held)
+        assert all(d == jnp.float32 for d in held), (kernel, held)
+    _, starts = pallas_kernels.ssd_chunk_forward(*args, dims=dims,
+                                                 chunk=chunk,
+                                                 with_states=True)
+    assert starts.dtype == jnp.float32
+    x, b_mat = jnp.ones((t, h, p), bf16), jnp.ones((t, g, n), bf16)
+    assert seq.ssd_chunked(x, args[1][0], args[2], args[3], b_mat, b_mat,
+                           chunk)[1].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("build,shapes", [
+    (lambda: mx.models.get_lenet(num_classes=10), dict(data=(2, 1, 28, 28))),
+    (lambda: mx.models.get_resnet([1, 1], [8, 32, 64], num_classes=4,
+                                  small_input=True), dict(data=(2, 3, 8, 8))),
+    (lambda: mx.models.lstm_unroll(2, 5, 50, 16, 16, 50),
+     dict(data=(4, 5), softmax_label=(4, 5),
+          **{"l%d_init_%s" % (i, s): (4, 16) for i in (0, 1) for s in "hc"})),
+], ids=["lenet", "resnet", "lstm"])
+def test_nets_without_a_scan_count_no_scan_kernel(build, shapes):
+    from mxnet_tpu.executor import make_graph_eval
+
+    net = build()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        eval_graph, _ = make_graph_eval(net)
+        arg_shapes, _, aux_shapes = net.infer_shape(**shapes)
+        jax.eval_shape(
+            lambda a, x: eval_graph(a, x, None, True),
+            [jax.ShapeDtypeStruct(s, jnp.float32) for s in arg_shapes],
+            [jax.ShapeDtypeStruct(s, jnp.float32) for s in aux_shapes])
+        assert scan_counters() == (0, 0)
+    finally:
+        telemetry.disable()
 
 
 def test_ssm_scan_shapes():
@@ -698,3 +925,83 @@ def test_model_with_whole_lane_heads_fits_under_recomputation(monkeypatch):
         telemetry.disable()
     got, _ = mod.get_params()
     assert all(np.isfinite(v.asnumpy()).all() for v in got.values())
+
+
+# ---------------------------------------------------------------------------
+# how often the scan runs in a training step
+# ---------------------------------------------------------------------------
+KERNEL_TOY = dict(pattern="MEM", hidden=64, vocab=256, experts_total=8,
+                  experts_held=4, seq_len=256, mamba_heads=4,
+                  mamba_head_dim=64, ssm_groups=2, ssm_state=128, chunk=128,
+                  attn_heads=2, kv_heads=1, head_dim=8, top_k=2,
+                  expert_hidden=16, shared_hidden=32)
+
+
+def traced_fused_step(monkeypatch, toy):
+    """The fused train step of ``toy`` as ``fit`` builds it under
+    ``MXNET_BACKWARD_DO_MIRROR``, traced (``jax.stages.Traced``)."""
+    monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    traced = []
+    real_jit = jax.jit
+
+    def spy(fn, **kw):
+        jfn = real_jit(fn, **kw)
+        if getattr(fn, "__name__", "") != "step":
+            return jfn
+
+        class Spy:
+            def __call__(self, *args):
+                traced.append(jfn.trace(*args))
+                return jfn(*args)
+
+            def _cache_size(self):
+                return jfn._cache_size()
+        return Spy()
+
+    monkeypatch.setattr(jax, "jit", spy)
+    rng = np.random.default_rng(3)
+    ids = rng.integers(0, toy["vocab"], (1, toy["seq_len"] + 1))
+    mod = mx.mod.Module(get_nemotron_h(**toy), context=mx.cpu(0))
+    mod.fit(Ring([(ids[:, :-1].astype(np.int32), ids[:, 1:].astype(np.int32))]),
+            eval_metric="ce", optimizer="adam",
+            optimizer_params={"learning_rate": 1e-3, "rescale_grad": 1.0},
+            initializer=mx.init.Xavier(), num_epoch=1)
+    monkeypatch.undo()
+    assert len(traced) == 1
+    return traced[0]
+
+
+@pytest.mark.parametrize("body", ["pallas", "xla"])
+def test_the_scan_runs_forward_twice_and_backward_once_a_step(monkeypatch,
+                                                              body):
+    """Under segment recomputation each scan node runs its forward body
+    twice in the step (the forward pass, and the recomputed one that keeps
+    the chunk-start states) and its written backward once: no third
+    forward, as autodiff under an inner ``jax.checkpoint`` ran. Counted
+    as calls of the kernels' (jitted, so shared by the layers) callers in
+    the step lowered for the TPU, and for the XLA body
+    as the ``lax.scan``s that carry one float32 state ``[G, H/G, P, N]``
+    across the chunks (forward) or its gradient (reversed)."""
+    import re
+
+    toy = KERNEL_TOY if body == "pallas" else dict(KERNEL_TOY, ssm_state=16)
+    nodes = toy["pattern"].count("M")
+    traced = traced_fused_step(monkeypatch, toy)
+    if body == "pallas":
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        assert 'kernel_name = "ssd_chunk_forward"' in text
+        ran = [len(re.findall(r"call @_ssd_chunk_%s(_\d+)?\(" % k, text))
+               for k in ("forward", "backward")]
+    else:
+        state = (toy["ssm_groups"], toy["mamba_heads"] // toy["ssm_groups"],
+                 toy["mamba_head_dim"], toy["ssm_state"])
+        ran = [0, 0]
+        for sub in _sub_jaxprs(traced.jaxpr.jaxpr):
+            for eqn in sub.eqns:
+                if eqn.primitive.name == "scan" and \
+                        eqn.params["num_carry"] == 1:
+                    carry = eqn.invars[eqn.params["num_consts"]].aval
+                    if carry.shape == state and carry.dtype == jnp.float32:
+                        ran[bool(eqn.params["reverse"])] += 1
+    assert ran == [2 * nodes, nodes]
