@@ -49,73 +49,8 @@ test: $(LIB)
 	python -m pytest tests/ -q
 
 lint:
-	python tools/graftlint.py mxnet_tpu tools bench.py chip_smoke.py \
+	python tools/graftlint.py mxnet_tpu tools chip_smoke.py \
 	    --baseline tools/graftlint_baseline.json --check-env-docs
-
-# xprof views over the newest BENCH artifacts in the repo
-# root (compile registry, op-category FLOPs, HBM, device-time table)
-profile-report:
-	python tools/trace_report.py --profile-report
-
-# dp-scaling smoke on 8 simulated devices: the sharded fused step
-# (device_sync kvstore) measured at dp=1,2,4,8 -> MULTICHIP_scaling.json
-multichip:
-	python bench.py multichip
-
-# FSDP tier on the same 8 simulated devices, mesh factored
-# dp=2 x fsdp=4: per-device params+opt-state byte ratio, one-dispatch
-# proof, exact-parity witness -> merged under the "fsdp" key of
-# MULTICHIP_scaling.json
-fsdp-bench:
-	python bench.py multichip --fsdp
-
-# continuous-batching serving tier: open-loop Poisson load swept until
-# the tail-latency SLO breaks -> SERVE_bench.json (goodput, p50/p99,
-# batch occupancy, zero-retrace proof)
-serve-bench:
-	python bench.py serve
-
-# tensor-parallel serving tier on the same 8 simulated devices, group
-# factored dp=4 x tp=2: per-device param byte ratio, the preflight
-# bigger-than-one-chip proof, in-graph collectives inside the one
-# dispatch, and the delta-aware weight stream -> merged under the
-# "tp" key of SERVE_bench.json
-tp-serve-bench:
-	python bench.py serve --tp
-
-# closed-loop kernel/config search: candidates compiled through the
-# xprof registry, pruned or timed, fenced rows into
-# MFU_EXPERIMENTS.jsonl, winners into .autotune_cache.json
-# -> AUTOTUNE_search.json (read it with trace_report --view tune)
-autotune:
-	python bench.py autotune
-
-# fault-tolerant serving fleet: goodput vs replica count, a replica
-# killed mid-load (zero client-visible errors, measured recovery
-# window), rolling param-swap purity with torn_swap armed
-# -> FLEET_bench.json (read it with trace_report --view fleet)
-fleet-bench:
-	python bench.py fleet
-
-# socket transport: the fleet bench's network tier — zero-copy frame
-# codec vs pickle, socket-vs-pipe p99 overhead, chaos over TCP
-# (net_drop/net_partition/net_reorder armed, zero client errors), and
-# the 2-process netfeed epoch -> the "socket" record in
-# FLEET_bench.json (read it with trace_report --view wire)
-net-bench:
-	python bench.py fleet --smoke
-	python tools/trace_report.py --view wire
-
-# distributed-tracing smoke: the fleet bench (smoke profile) with the
-# tracer armed must produce a loadable merged chrome trace holding at
-# least one kept span tree -> FLEET_trace.json (read it with
-# trace_report --view waterfall, or load it in Perfetto)
-trace-smoke:
-	MXNET_TPU_DTRACE=1 python bench.py fleet --smoke
-	python -c "import json; d=json.load(open('FLEET_trace.json')); \
-	evs=[e for e in d['traceEvents'] if e.get('cat')=='dtrace']; \
-	assert evs, 'no dtrace events in FLEET_trace.json'; \
-	print('FLEET_trace.json ok: %d dtrace events' % len(evs))"
 
 # preemption-safety suite: crash-safe writes, torn-file detection,
 # bit-identical kill-at-step-k resume, elastic dp rejoin, SIGTERM grace
@@ -128,22 +63,12 @@ ckpt-test:
 numwatch-test:
 	python -m pytest tests/test_numwatch.py -q
 
-# perf-regression gate: current bench artifacts (SERVE / FLEET / OBS /
-# MULTICHIP, plus the BENCH_r* trajectory) vs tools/bench_baselines.json.
-# Exit 1 names the regressed metric, artifact, and measured delta;
-# missing artifacts are INCOMPLETE (exit 0) -> BENCH_GATE.json
-bench-gate:
-	python tools/bench_gate.py
-
-# observability gate: lint the new surface, run the obswatch + gate
-# test files, then the regression gate itself, recording the verdict
-# into PROGRESS.jsonl so the growth log carries pass/fail history
+# observability gate: lint the surface, then the obswatch and
+# telemetry test files
 obs-gate: lint
-	python -m pytest tests/test_obswatch.py tests/test_bench_gate.py \
-	    tests/test_telemetry.py -q
-	python tools/bench_gate.py --progress PROGRESS.jsonl
+	python -m pytest tests/test_obswatch.py tests/test_telemetry.py -q
 
 clean:
 	rm -rf mxnet_tpu/_native perl-package/blib
 
-.PHONY: all predict perl test lint profile-report multichip fsdp-bench serve-bench tp-serve-bench fleet-bench net-bench trace-smoke ckpt-test numwatch-test bench-gate obs-gate clean
+.PHONY: all predict perl test lint ckpt-test numwatch-test obs-gate clean

@@ -13,7 +13,8 @@ Four rule families:
     ``float()``/``int()``/``bool()`` or Python truthiness on a value
     produced by a jnp/jax call — must carry an explicit
     ``# graft: host-sync`` annotation. A silent sync in the step loop
-    is the dispatch-gap class that capped MFU at 15.8% (BENCH_r05).
+    leaves the device idle between dispatches (the benchmark's
+    ``fit_host_gap_ms_per_step`` and idle share).
 
 ``donation``
     A name passed in a ``donate_argnums`` position of a jitted callable

@@ -1,20 +1,19 @@
 """Closed-loop kernel/config autotuner over the xprof compile registry.
 
-The measured-MFU loop so far has been human-driven: run
-tools/mfu_experiments.py variants on a chip window, read the roofline,
-edit a default. This module closes the loop. For a kernel *site* (a
-named decision point — ``conv_backward``, ``norm_act``, ``fused_step``)
-it enumerates a candidate space, compiles each candidate through the
-same ``lower().compile()`` path ``xprof.jit`` measures, reads the
-CompileRegistry's cost/memory analysis to prune candidates that are
-pre-flight OOM or roofline-hopeless *before spending device time*,
-times the survivors in-process, and writes every candidate — winners
-and losers, with prune reasons — to MFU_EXPERIMENTS.jsonl through
-tools/mfu_experiments's validate() fence so no physically impossible
-row ever lands. The winning config is persisted to a per-(site,
-aval-signature, chip) cache that ``ops/nn.py`` and ``fused_step``
-consult at *trace time*, so a tuned choice costs zero extra dispatches
-per training step.
+For a kernel *site* (a named decision point — ``conv_backward``,
+``norm_act``, ``fused_step``) it enumerates a candidate space, compiles
+each candidate through the same ``lower().compile()`` path ``xprof.jit``
+measures, reads the CompileRegistry's cost/memory analysis to prune
+candidates that are pre-flight OOM or roofline-hopeless *before spending
+device time*, times the survivors in-process, and hands every candidate
+— winners and losers, with prune reasons — to :func:`record`, which
+appends them to the caller's .jsonl behind the :func:`validate` fence so
+no physically impossible row ever lands. The winning config is persisted
+to a per-(site, aval-signature, chip) cache that ``ops/nn.py`` and
+``fused_step`` consult at *trace time*, so a tuned choice costs zero
+extra dispatches per training step. Nothing in the repo runs a search:
+:func:`run_smoke` is the entry, and no cell has timed its choices on the
+chip (ROADMAP Design 8).
 
 The search core (:func:`search`) takes injected ``compile_fn``/
 ``run_fn``/``clock`` so tests drive it off a fake registry with a fake
@@ -38,14 +37,9 @@ from .base import MXNetError
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CACHE_FILE = os.path.join(_ROOT, ".autotune_cache.json")
-DEFAULT_JSONL = os.path.join(_ROOT, "MFU_EXPERIMENTS.jsonl")
 
-# XLA flag candidates are part of the space but can only be measured by
-# process re-exec (flags bind at backend init) — the chip-window driver
-# for them is `tools/mfu_experiments.py --sweep-flags`. The in-process
-# search records them as pruned with that pointer instead of silently
-# narrowing the space.
-FLAG_SWEEP = ("--xla_tpu_enable_latency_hiding_scheduler=true",)
+# model FLOPs of one ResNet-50 training image at 224x224 (forward x 3)
+RESNET50_TRAIN_GFLOPS_PER_IMG = 4.089 * 3
 
 
 def enabled() -> bool:
@@ -169,37 +163,65 @@ def search(site: str, candidates: List[dict],
 # validate-fenced JSONL recording
 # ---------------------------------------------------------------------------
 
-_validate_fn = None
+def _chip_peak(kind):
+    """Peak TFLOP/s from xprof's one table; None for the CPU or a chip
+    the table does not hold (no floor can be computed then)."""
+    from .xprof import chip_peak_tflops
+
+    try:
+        return chip_peak_tflops(kind)
+    except MXNetError:
+        return None
 
 
-def _mfu_validate(row: dict) -> Optional[str]:
-    """tools/mfu_experiments.validate, loaded by path (tools/ is not a
-    package). Same gate bench.py and the retag tool use."""
-    global _validate_fn
-    if _validate_fn is None:
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "mfu_experiments",
-            os.path.join(_ROOT, "tools", "mfu_experiments.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        _validate_fn = mod.validate
-    return _validate_fn(row)
+def validate(result):
+    """Physical-plausibility gate for a measurement row. Returns None
+    when the row could be real, else a reason string.
+
+    Two invariants no correct measurement can break: model FLOP
+    utilization cannot exceed the chip's peak (mfu_pct <= 100), and a
+    step cannot finish faster than its FLOPs take at that peak. Rows
+    that break either measured dispatch latency, not training."""
+    mfu = result.get("mfu_pct")
+    if mfu is not None and mfu > 100.0:
+        return "mfu_pct %.1f exceeds 100%% of chip peak" % mfu
+    step_ms = result.get("step_time_ms")
+    peak = _chip_peak(result.get("chip", "")) if step_ms else None
+    if not peak:
+        return None
+    # rows measured through the xprof registry carry the compiled
+    # executable's true FLOP count: the tightest floor, valid for
+    # every geometry
+    flops = result.get("flops_per_step")
+    if flops:
+        floor_ms = flops / (peak * 1e9)
+        if step_ms < floor_ms:
+            return ("step_time_ms %.2f below executable FLOP floor "
+                    "%.2f ms (%.1f GFLOP/step at %.0f peak TFLOPS)"
+                    % (step_ms, floor_ms, flops / 1e9, peak))
+    # a 224px ResNet-50 row is also held to the model's own count
+    batch = result.get("batch")
+    if batch and result.get("image", 0) >= 224:
+        floor_ms = batch * RESNET50_TRAIN_GFLOPS_PER_IMG / peak
+        if step_ms < floor_ms:
+            return ("step_time_ms %.2f below analytic floor %.2f ms "
+                    "(batch %d ResNet-50 train at %.0f peak TFLOPS)"
+                    % (step_ms, floor_ms, batch, peak))
+    return None
 
 
-def record(rows: List[dict], path: Optional[str] = None,
+def record(rows: List[dict], path: str,
            chip: Optional[str] = None) -> dict:
-    """Append search rows to MFU_EXPERIMENTS.jsonl behind the
+    """Append search rows to the .jsonl at ``path`` behind the
     validate() fence: rows the gate rejects are REFUSED (returned with
     the reason), never written — the results file only ever gains
     ``valid: true`` rows."""
-    path = path or DEFAULT_JSONL
     written, refused = [], []
     for row in rows:
         row = dict(row)
         if chip and "chip" not in row:
             row["chip"] = chip
-        reason = _mfu_validate(row)
+        reason = validate(row)
         if reason:
             row["refused"] = reason
             refused.append(row)
@@ -478,13 +500,14 @@ def _conv_site(shape=(2, 128, 8, 8), wshape=(128, 128, 3, 3),
     return build
 
 
-def run_smoke(budget: Optional[float] = None,
-              jsonl_path: Optional[str] = None,
+def run_smoke(jsonl_path: str, budget: Optional[float] = None,
               cache_path: Optional[str] = None) -> dict:
-    """The bounded CPU-mesh search bench.py's ``autotune`` child runs:
+    """A bounded search, callable by itself (nothing else runs it):
     tune the ``norm_act`` row tile and the ``conv_backward`` kernel
-    choice on fixed smoke shapes, fence every row through validate(),
-    persist winners to the cache, and return the search summary."""
+    choice on fixed smoke shapes, append every row that passes
+    validate() to ``jsonl_path``, persist winners to the cache
+    (``cache_path``, default :data:`CACHE_FILE`, what
+    ``MXNET_TPU_AUTOTUNE=1`` reads), and return the search summary."""
     from . import xprof as _xprof
 
     budget = budget_s() if budget is None else budget
@@ -504,16 +527,6 @@ def run_smoke(budget: Optional[float] = None,
         result, rows = search(site, cands, compile_fn, run_fn,
                               budget_s=budget, limit_bytes=limit,
                               peak_tflops=peak)
-        # the XLA-flag dimension of the space is measured by re-exec
-        # (tools/mfu_experiments.py --sweep-flags); record it as pruned
-        # rather than silently dropping the dimension
-        for flag in FLAG_SWEEP:
-            rows.append({"experiment": "autotune:%s:flags" % site,
-                         "site": site, "candidate": "flags",
-                         "config": {"xla_flags": flag},
-                         "pruned": "xla flags bind at backend init; "
-                                   "measure via tools/mfu_experiments.py "
-                                   "--sweep-flags"})
         rec = record(rows, jsonl_path, chip=chip)
         summary["rows_written"] += rec["written"]
         summary["rows_refused"] += rec["refused"]

@@ -18,7 +18,7 @@ impossible by construction:
   and ``tests/test_graftlint.py`` fails tier-1 when the checked-in doc
   block differs from the registry.
 
-Writes (``os.environ[...] = ...`` for child processes, bench env
+Writes (``os.environ[...] = ...`` for child processes, harness env
 overrides) are intentionally out of scope: the registry governs how
 configuration is *consumed*, not how harnesses stage it.
 
@@ -274,35 +274,6 @@ declare("MXNET_TPU_WATCHDOG_INTERVAL", float, 5.0,
         "signal.",
         section="Runtime sanitizers")
 
-declare("MXNET_TPU_BENCH_INPUT", str, "",
-        "Opt-in `bench.py` end-to-end tier: set to `1` (synthetic "
-        "recordio) or a `.rec` path to also train from `ImageRecordIter` "
-        "and report `input_imgs_per_sec` / `e2e_imgs_per_sec` beside the "
-        "device-resident number.", section=_B)
-declare("MXNET_TPU_BENCH_CACHE", bool, False,
-        "Allow the cache-fed tier to decode a USER-supplied .rec into a "
-        "full on-disk uint8 cache (ImageNet scale: ~250 GB — hence the "
-        "explicit opt-in; the bench's synthetic rec never needs it).",
-        section=_B)
-declare("MXNET_TPU_BENCH_THREADS", int, 0,
-        "Decode pool size for the end-to-end tier (default: host CPU "
-        "count).", section=_B)
-declare("MXNET_TPU_BENCH_TIMEOUT", int, 2400,
-        "Seconds a named bench mode (`serve`, `fleet`, `multichip`, ...) "
-        "gives the CPU-mesh child it spawns; each mode has its own "
-        "default. The default mode spawns no child.", section=_B)
-declare("MXNET_TPU_BENCH_BATCH", int, 0,
-        "Override the per-device batch size of the device-resident bench "
-        "tier (default: the model recipe's batch).", section=_B)
-declare("MXNET_TPU_BENCH_STEPS", int, 0,
-        "Override the measured step count per bench tier (default: the "
-        "recipe's step budget).", section=_B)
-declare("MXNET_TPU_BENCH_DTYPE", str, "",
-        "Compute dtype for the bench model (default `bfloat16`, MXU "
-        "native).", section=_B)
-declare("MXNET_TPU_BENCH_TRACE", str, "",
-        "Directory to capture a jax profiler trace of the measured bench "
-        "window into (empty: no trace).", section=_B)
 declare("MXNET_TPU_STRICT_FEED_GATE", bool, False,
         "Make the feed-the-chip test enforce the absolute host-feed-rate "
         "bar (nightly boxes); unset, the bar is reported but only the "
@@ -329,7 +300,7 @@ declare("MXNET_TPU_TELEMETRY_FSYNC", bool, False,
 
 _T = "Tracing / flight recorder (all require telemetry enabled)"
 declare("MXNET_TPU_METRICS_PORT", str, "",
-        "Start the live metrics server on this port at `fit()`/bench "
+        "Start the live metrics server on this port at `fit()` "
         "entry: Prometheus text format at `/metrics` (every sample "
         "labeled `rank=\"N\"`), liveness JSON at `/healthz`. Port `0` "
         "binds an ephemeral port (tests). Unset: no server thread.",
@@ -354,7 +325,7 @@ declare("MXNET_TPU_TRACE_EVENT_COOLDOWN", int, 10,
         "bounding event spam from a persistently degraded run.",
         section=_T)
 declare("MXNET_TPU_FLIGHT_RECORDER", bool, False,
-        "Install the crash-dump hooks at `fit()`/bench entry: unhandled "
+        "Install the crash-dump hooks at `fit()` entry: unhandled "
         "exception, SIGTERM (dump then terminate normally) and SIGUSR1 "
         "(dump and keep running) write the last-N step records, "
         "all-thread stacks and a telemetry snapshot into the crash "
@@ -371,11 +342,11 @@ declare("MXNET_TPU_XPROF", bool, False,
         "registry (`mxnet_tpu.xprof`): compile wall-time, "
         "`cost_analysis` FLOPs/bytes, `memory_analysis` peak bytes and "
         "the HLO op-category breakdown land in `compile.*` telemetry "
-        "and BENCH records, and recompiles carry a retrace-cause diff "
+        "and `xprof.records()`, and recompiles carry a retrace-cause diff "
         "naming the changed argument avals. The wrapper dispatches "
         "through the AOT executable it measured, so instrumentation "
         "adds zero extra compiles or dispatches. `xprof.enable()` does "
-        "the same at runtime (bench does so itself).", section=_X)
+        "the same at runtime.", section=_X)
 declare("MXNET_TPU_XPROF_OPS", bool, True,
         "Parse each recorded executable's optimized HLO into the "
         "conv/dot/fusion/collective/transpose/elementwise FLOP+bytes "
@@ -642,7 +613,8 @@ declare("MXNET_TPU_PALLAS_CONV", bool, False,
 _AT = "Autotuning"
 declare("MXNET_TPU_AUTOTUNE", bool, False,
         "Consult the autotuner's best-config cache "
-        "(`.autotune_cache.json`, written by `bench.py autotune`) at "
+        "(`.autotune_cache.json`, written by "
+        "`mxnet_tpu.autotune.run_smoke(path)`, which you call yourself) at "
         "trace time: tuned kernel/tile choices apply to `ops/nn.py` and "
         "the fused step with zero extra dispatches. Off: every site "
         "keeps its measured default.", section=_AT)
@@ -657,7 +629,7 @@ declare("MXNET_TPU_OBSWATCH_INTERVAL_MS", float, 1000.0,
         "(`mxnet_tpu.obswatch.ObsWatch.start()`): every tick scrapes "
         "each replica's metrics+health, federates, and appends one "
         "rollup record to the time-series store. Manual `tick()` "
-        "callers (the bench) ignore it.", section=_OW)
+        "callers ignore it.", section=_OW)
 declare("MXNET_TPU_OBSWATCH_DIR", str, "",
         "Directory for the obswatch durable time-series store "
         "(JSONL ring segments + manifest). Empty: `.obswatch/` under "

@@ -34,10 +34,10 @@ replica, swap, rejoin — so an injected ``torn_swap`` window is never
 observable; autoscaling (optional) grows the fleet while replicas
 report a degraded SLO and shrinks it after a sustained healthy streak.
 
-Every claim above is provable under :mod:`mxnet_tpu.faults` injection —
-``bench.py fleet --smoke`` kills a replica mid-load and records the
-recovery timeline into ``FLEET_bench.json``; the chaos tests pin zero
-client-visible errors and zero mixed-version responses.
+Every claim above is provable under :mod:`mxnet_tpu.faults` injection:
+the chaos tests (``tests/test_fleet.py``) kill a replica mid-load and
+pin zero client-visible errors and zero mixed-version responses.
+Goodput and recovery time on the chip are not measured.
 
 >>> rng = __import__("random").Random(0)
 >>> d0 = backoff_delay_s(0, 0.01, rng)
@@ -937,7 +937,7 @@ class SocketReplica(Replica):
 
     def wire_stats(self) -> dict:
         """Per-peer transport rollup (frames/bytes/rtt/reconnects/
-        stalls) — the fleet bench embeds this for --view wire."""
+        stalls), the shape ``trace_report --view wire`` renders."""
         return {} if self._client is None else self._client.stats()
 
     def refresh_params(self, apply_fn=None, snapshot_dir=None,
@@ -1010,10 +1010,9 @@ def in_socket(factory_ref: str,
 def demo_server_factory():
     """A tiny deterministic MLP behind an ``InferenceServer`` — the
     spawn-resolvable factory (``"mxnet_tpu.fleet:demo_server_factory"``)
-    the fleet bench and the subprocess-replica tests build replicas
-    from. Params are seeded half-integers over integer inputs (the
-    serving tests' exact-arithmetic regime), so replica parity is
-    bit-exact."""
+    the subprocess- and socket-replica tests build replicas from.
+    Params are seeded half-integers over integer inputs (the serving
+    tests' exact-arithmetic regime), so replica parity is bit-exact."""
     import mxnet_tpu as mx
     from .module import Module
     from .serving import InferenceServer

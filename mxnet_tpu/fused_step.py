@@ -4,10 +4,11 @@ The classic fit() loop issues three host dispatches per batch —
 ``forward_backward`` (one fused fwd+bwd computation), ``update`` (one
 donated kernel per optimizer structure group) and ``update_metric``
 (a fold or an eager ``asnumpy`` sync) — and the gaps between them are
-pure host overhead on an accelerator (BENCH_r05: 15.8% model MFU vs
-30.7% XLA-reported MFU, i.e. roughly half the step was dispatch gaps
-and syncs). This module compiles the whole batch into a SINGLE
-``jax.jit`` call:
+pure host overhead on an accelerator (what the classic loop loses to
+them on the chip is not measured: no benchmark cell runs it, PERF.md
+section 7 row 3; the fused, resident-fed cells leave the device idle
+0.2-0.3% of a step, ledger PR 27). This module compiles the whole batch
+into a SINGLE ``jax.jit`` call:
 
     params', outputs, aux', opt_states', metric_acc' =
         step(params, data/labels, aux, opt_states, hyper_vec, acc, key)
@@ -45,7 +46,8 @@ counts ``step.fused_fallback[.reason]`` and warns once naming the
 reason.
 
 Telemetry: ``step.dispatches`` counts XLA computation launches per
-batch on both paths (the fused-vs-unfused delta BENCH_r06 reports);
+batch on both paths (the benchmark reads it as
+``fit_dispatches_per_step``: 1.0 fused);
 ``step.fused_recompiles`` counts fresh trace signatures (a shape-driven
 recompile storm trips the tracing RecompileDetector);
 ``step.fused_fallback`` counts requested-but-refused configurations.
@@ -905,7 +907,7 @@ class FusedInfer:
         Telemetry either way: ``infer.refresh_bytes`` (host bytes
         moved), ``infer.refresh_ms``, ``infer.refresh_changed`` /
         ``infer.refresh_skipped`` param counts — mirrored on
-        ``last_refresh_*`` attributes for the bench.
+        ``last_refresh_*`` attributes.
 
         ``torn_ms > 0`` (the ``torn_swap`` injected fault) makes the
         swap deliberately non-atomic: half the new pack lands, then a

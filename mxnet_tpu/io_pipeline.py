@@ -827,7 +827,7 @@ class RequestStager:
     skips the concat+pad entirely (``serve.stage_fastpath``).
 
     Telemetry: ``serve.h2d_bytes`` and ``serve.pad_rows`` so the
-    mean-occupancy number in ``SERVE_bench.json`` stays honest about
+    scheduler's mean-occupancy number stays honest about
     pad waste (the wall-time split lives in the scheduler's
     per-request ``serve.h2d_ms``).
     """

@@ -41,29 +41,19 @@ def _bottleneck(data, num_filter, stride, dim_match, name, layout="NCHW"):
 
 
 def get_resnet(units, filter_list, num_classes=1000, small_input=False,
-               layout="NCHW", stem_s2d=False):
+               layout="NCHW"):
     """Build a bottleneck ResNet.
 
     ``small_input`` (CIFAR-style) swaps the 7x7/2+maxpool stem for 3x3/1,
     letting the same code run 32x32 tests and 224x224 benchmarks.
 
     ``layout="NHWC"`` builds the whole tower channels-last (data shape
-    (N, H, W, C), BatchNorm axis -1) — the TPU-native layout candidate
-    measured by tools/mfu_experiments.py. Weights stay OIHW either way,
-    so checkpoints are layout-portable.
+    (N, H, W, C), BatchNorm axis -1); no cell has timed it against NCHW
+    on the chip. Weights stay OIHW either way, so checkpoints are
+    layout-portable.
     """
     data = sym.Variable("data")
-    if stem_s2d:
-        # space-to-depth stem (MLPerf-style): the caller feeds data
-        # already 2x2 depth-stacked — (N, 12, H/2, W/2) — and a 5x5/1
-        # conv replaces the 7x7/2; structurally equivalent FLOPs/output
-        # resolution for the throughput experiment
-        # (tools/mfu_experiments.py), not weight-exact with 7x7
-        body = _conv_bn_relu(data, filter_list[0], (5, 5), (1, 1), (2, 2),
-                             "stem", layout=layout)
-        body = sym.Pooling(data=body, kernel=(3, 3), stride=(2, 2),
-                           pad=(1, 1), pool_type="max", layout=layout)
-    elif small_input:
+    if small_input:
         body = _conv_bn_relu(data, filter_list[0], (3, 3), (1, 1), (1, 1),
                              "stem", layout=layout)
     else:

@@ -463,5 +463,5 @@ class _DemoFeed(DataIter):
 
 def demo_feed_factory() -> DataIter:
     """Spawn-resolvable factory (``"mxnet_tpu.netfeed:demo_feed_factory"``)
-    for the netfeed tests and the fleet bench's 2-process epoch."""
+    for the netfeed tests."""
     return _DemoFeed()

@@ -62,15 +62,16 @@ send lock.
 
 The network fault plane (:mod:`mxnet_tpu.faults`: ``net_drop``,
 ``net_partition``, ``net_reorder``, ``net_slow``) injects *inside*
-``WireConn.send_frame`` — below every consumer — so the fleet bench
-proves goodput survives loss, resets, and reordering with the same
+``WireConn.send_frame`` — below every consumer — so the chaos tests
+prove requests survive loss, resets, and reordering with the same
 seeded, counted machinery as the process-fault drills.
 
 Telemetry (all under ``wire.``): ``bytes_tx``/``bytes_rx``,
 ``frames_tx``/``frames_rx``, ``rtt_ms``, ``reconnects``,
 ``backpressure_stalls``/``backpressure_stall_ms``, ``pending``.
-``trace_report --view wire`` renders the per-peer rollup the fleet
-bench embeds in FLEET_bench.json.
+``trace_report --view wire`` renders a per-peer rollup of
+``fleet.SocketReplica.wire_stats()`` from a record handed to it; no
+tool writes such a record since PR 28.
 """
 from __future__ import annotations
 
@@ -808,7 +809,7 @@ class WireClient:
 
     # -- introspection ------------------------------------------------------
     def stats(self) -> dict:
-        """Per-peer rollup for the fleet bench / ``--view wire``:
+        """Per-peer rollup (``trace_report --view wire`` renders it):
         frames, bytes, rtt mean/p99, reconnects, backpressure stalls."""
         ftx = frx = btx = brx = stalls = 0
         for c in self._conns:

@@ -439,31 +439,6 @@ class NumWatch:
             "healthy snapshot (saved at step %s); rollback #%d",
             info.get("step"), self._rollbacks)
 
-    def tensor_rows(self):
-        """Per-tensor health dicts from the last fetch, forward order —
-        the NUMWATCH_health.json / ``trace_report --view numerics``
-        feed."""
-        if self._last_body is None:
-            return []
-        body = self._last_body
-        rows = []
-        for i, name in enumerate(self.names):
-            sz = self.sizes[i]
-            w_sq = float(body[i, W_SUMSQ])
-            u_sq = float(body[i, UPD_SUMSQ])
-            rows.append({
-                "name": name,
-                "grad_l2": round(
-                    math.sqrt(max(float(body[i, G_SUMSQ]), 0.0)), 6),
-                "grad_maxabs": round(float(body[i, G_MAXABS]), 6),
-                "nonfinite": int(body[i, G_NONFIN] + body[i, W_NONFIN]),
-                "zero_frac": round(float(body[i, G_ZERO]) / sz, 4),
-                "uw_ratio": (round(math.sqrt(u_sq / w_sq), 8)
-                             if w_sq > 0 else 0.0),
-                "first_bad": int(max(body[i, FB_PARAM],
-                                     body[i, FB_GRAD]))})
-        return rows
-
     # -- monitor facade feed ------------------------------------------------
     def monitor_rows(self, re_prog, step):
         """Serve the classic Monitor rows — ``(step, name, stat)`` with

@@ -603,7 +603,7 @@ class BurnRateMonitor:
 
 class ObsWatch:
     """Scrape -> federate -> persist -> alert, as one object. Drive it
-    manually with :meth:`tick` (the bench does) or let :meth:`start`
+    manually with :meth:`tick` or let :meth:`start`
     poll every ``MXNET_TPU_OBSWATCH_INTERVAL_MS``. On an alert's rising
     edge it stamps ``slo_burn_alert`` into the step trace (so
     :class:`~mxnet_tpu.tracing.FleetHealthDetector` raises a

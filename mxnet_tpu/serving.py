@@ -48,10 +48,9 @@ Shutdown contract: ``close()`` stops intake, DRAINS every queued
 request (each gets a result or an error — nothing hangs a caller), and
 joins the worker thread; the tests' thread/process leak gate holds.
 
-``bench.py serve`` drives this with an open-loop Poisson load sweep and
-writes ``SERVE_bench.json`` (requests/sec, goodput at SLO, p50/p99/p999
-latency, per-tier batch occupancy, the adaptive-wait trajectory and
-per-lane goodput under ``--lanes``).
+No benchmark cell drives this yet: requests/sec, goodput at SLO and
+tail latency on the chip are not measured (PERF.md section 7 rows 1-2
+name the cells that will).
 """
 from __future__ import annotations
 
@@ -996,7 +995,7 @@ class BatchScheduler:
 
     def controller_state(self) -> dict:
         """The adaptive control plane, as one JSON-able dict (merged
-        into /healthz and the bench record)."""
+        into /healthz and ``stats()``)."""
         return {"adaptive": self.adaptive,
                 "adaptive_wait_ms": round(
                     self._ctl.wait_ms if self.adaptive
@@ -1005,9 +1004,8 @@ class BatchScheduler:
                 "queue_depth": self._pending_rows + self._q.qsize()}
 
     def occupancy_snapshot(self) -> dict:
-        """Monotone counters for per-tier occupancy deltas in the
-        bench (mean occupancy between two snapshots =
-        ``Δocc_sum / Δbatches``)."""
+        """Monotone counters for occupancy deltas (mean occupancy
+        between two snapshots = ``Δocc_sum / Δbatches``)."""
         with self._lock:
             return {"batches": self._batches, "occ_sum": self._occ_sum,
                     "served": self._served}
@@ -1035,17 +1033,6 @@ class BatchScheduler:
                                        self._q.qsize()),
             "serve.request_ms": self._lat_hist.export(include_sample=True),
         }
-
-    def drain_depth_samples(self) -> List[int]:
-        """Pop and return the queue-depth samples recorded since the
-        last drain (the bench computes per-tier percentiles from
-        these)."""
-        out: List[int] = []
-        while True:
-            try:
-                out.append(self._depth_samples.popleft())
-            except IndexError:
-                return out
 
     def wait_trajectory(self) -> List[dict]:
         """The adaptive-wait trajectory: one sample per dispatched

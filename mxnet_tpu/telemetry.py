@@ -651,8 +651,8 @@ def dump_jsonl(path: str, extra: Optional[dict] = None) -> dict:
 
 
 def reset():
-    """Clear every metric, span, and the step counter (bench/test
-    isolation). The enabled flag is left as-is."""
+    """Clear every metric, span, and the step counter (test and
+    benchmark-harness isolation). The enabled flag is left as-is."""
     global _step, _span_step
     with _reg_lock:
         _metrics.clear()

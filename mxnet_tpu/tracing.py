@@ -24,7 +24,7 @@ pieces close that gap:
   dumps without stopping the run).
 * :class:`MetricsServer` — a stdlib ``http.server`` thread serving
   Prometheus text format at ``/metrics`` plus ``/healthz`` on
-  ``MXNET_TPU_METRICS_PORT``, so an operator (or the bench harness)
+  ``MXNET_TPU_METRICS_PORT``, so an operator (or an obswatch scraper)
   can scrape a live run without attaching to the process. Samples are
   labeled with the worker rank so ``dist_async`` workers are
   distinguishable on one dashboard.
@@ -549,7 +549,7 @@ class StepTrace:
     deltas accumulated during that step.
 
     ``record(latency_ms)`` is called once per training step (the fit
-    loop, ``bench.py``). The baseline for step 1's deltas is the
+    loop). The baseline for step 1's deltas is the
     counter state at construction, so a recorder created at fit() start
     attributes everything to steps."""
 
@@ -1061,7 +1061,7 @@ def record_step(latency_ms: float, extra: Optional[dict] = None):
 
 
 def maybe_init():
-    """Env-driven one-shot setup, called at fit()/bench entry: start
+    """Env-driven one-shot setup, called at fit() entry: start
     the metrics server when ``MXNET_TPU_METRICS_PORT`` is set, install
     the flight recorder when ``MXNET_TPU_FLIGHT_RECORDER=1``, start
     the deadlock watchdog when ``MXNET_TPU_SANITIZE`` includes

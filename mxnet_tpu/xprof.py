@@ -325,7 +325,7 @@ def record_compile(site: str, compiled, compile_time_s: float,
 
 
 def summary() -> dict:
-    """JSON-able registry summary for BENCH records / trace_report."""
+    """JSON-able registry summary (``tools/trace_report.py`` renders it)."""
     with _lock:
         sites = {}
         for site, st in _sites.items():

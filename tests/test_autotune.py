@@ -125,6 +125,45 @@ def test_search_inapplicable_candidate():
 # the validate() fence on JSONL writes
 # ---------------------------------------------------------------------------
 
+def _row(**over):
+    row = {"experiment": "baseline", "imgs_per_sec": 1000.0,
+           "step_time_ms": 256.0, "batch": 256, "image": 224,
+           "compute_dtype": "bfloat16", "chip": "TPU v5 lite",
+           "xla_flags": "", "mfu_pct": 50.0}
+    row.update(over)
+    return row
+
+
+def test_validate_accepts_plausible_row():
+    assert autotune.validate(_row()) is None
+
+
+def test_validate_rejects_impossible_mfu():
+    reason = autotune.validate(_row(mfu_pct=1095.3))
+    assert reason and "mfu_pct" in reason
+
+
+def test_validate_rejects_step_below_analytic_floor():
+    # batch 256 at 197 peak TFLOPS: floor = 256*12.267/197 ~= 16 ms;
+    # 1.46 ms is impossible even without an mfu_pct field on the row
+    reason = autotune.validate(_row(step_time_ms=1.46, mfu_pct=None))
+    assert reason and "floor" in reason
+
+
+def test_validate_skips_floor_for_unknown_chip():
+    # no peak known -> the floor cannot be computed; only the mfu bound
+    # applies
+    assert autotune.validate(_row(chip="mystery accelerator",
+                                  step_time_ms=0.01, mfu_pct=None)) is None
+
+
+def test_validate_skips_floor_for_small_images():
+    # the analytic constant is the 224x224 ResNet-50 cost; CPU smoke
+    # runs at 32x32 are not comparable
+    assert autotune.validate(_row(image=32, step_time_ms=0.01,
+                                  mfu_pct=None)) is None
+
+
 def test_record_refuses_physically_impossible_rows(tmp_path):
     path = str(tmp_path / "rows.jsonl")
     rows = [
@@ -195,7 +234,7 @@ def test_conv_kernel_enabled_via_cache(monkeypatch, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the smoke search end to end (the bench.py autotune child's body)
+# the smoke search end to end
 # ---------------------------------------------------------------------------
 
 def test_run_smoke_non_default_winner(tmp_path):

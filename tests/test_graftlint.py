@@ -250,7 +250,6 @@ def test_parse_error_is_reported_not_raised():
 def test_repo_tree_has_no_unbaselined_findings():
     findings = graftlint.analyze_paths(
         [os.path.join(ROOT, "mxnet_tpu"), os.path.join(ROOT, "tools"),
-         os.path.join(ROOT, "bench.py"),
          os.path.join(ROOT, "chip_smoke.py")], root=ROOT)
     baseline = graftlint.load_baseline(
         os.path.join(ROOT, "tools", "graftlint_baseline.json"))
@@ -282,10 +281,10 @@ def test_env_get_reads_declared_default_and_coerces(monkeypatch):
 
 
 def test_env_get_dynamic_default_override(monkeypatch):
-    monkeypatch.delenv("MXNET_TPU_BENCH_THREADS", raising=False)
-    assert env.get("MXNET_TPU_BENCH_THREADS", default=7) == 7
-    monkeypatch.setenv("MXNET_TPU_BENCH_THREADS", "2")
-    assert env.get("MXNET_TPU_BENCH_THREADS", default=7) == 2
+    monkeypatch.delenv("MXNET_TPU_DECODE_PROCS", raising=False)
+    assert env.get("MXNET_TPU_DECODE_PROCS", default=7) == 7
+    monkeypatch.setenv("MXNET_TPU_DECODE_PROCS", "2")
+    assert env.get("MXNET_TPU_DECODE_PROCS", default=7) == 2
 
 
 def test_env_undeclared_read_raises():

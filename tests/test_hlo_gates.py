@@ -54,7 +54,8 @@ def _cost(compiled):
 @pytest.fixture(scope="module")
 def train_lowering():
     """One bf16 ResNet-50 (CIFAR-scale) train-step compile shared by all
-    gates — the same build bench.py measures on chip."""
+    gates — the step `build_sgd_train_step` builds (no benchmark cell
+    runs it: ROADMAP Design 1)."""
     net = models.get_resnet50(num_classes=NUM_CLASSES, small_input=True)
     params, data, aux = _feeds(net, (BATCH, 3, IMAGE, IMAGE), NUM_CLASSES)
     step, _ = build_sgd_train_step(net, ["data"], ["softmax_label"],

@@ -45,6 +45,7 @@ print("SSH_WORKER_OK rank=" + str(rank) + " cwd=" + os.getcwd())
 def test_ssh_launcher_loopback(tmp_path):
     shim = tmp_path / "fake_ssh"
     log = tmp_path / "ssh_log.txt"
+    seen = tmp_path / "secret_seen.txt"
     shim.write_text(
         "#!/bin/sh\n"
         "# drop '-tt' and '-o opt' args, record host + command, run locally\n"
@@ -53,6 +54,11 @@ def test_ssh_launcher_loopback(tmp_path):
         "done\n"
         "host=\"$1\"; shift\n"
         "printf '%s\\t%s\\n' \"$host\" \"$*\" >> " + str(log) + "\n"
+        "# the staged secret as a worker host sees it while the job runs\n"
+        "f=$(printf '%s' \"$*\" | sed -n "
+        "'s/.*MXTPU_PS_SECRET_FILE=\\([^ ]*\\).*/\\1/p')\n"
+        "printf '%s\\t%s\\t%s\\n' \"$f\" \"$(stat -c %a \"$f\")\" "
+        "\"$(cat \"$f\")\" >> " + str(seen) + "\n"
         "exec /bin/sh -c \"$*\"\n")
     shim.chmod(shim.stat().st_mode | stat.S_IEXEC)
 
@@ -107,9 +113,23 @@ def test_ssh_launcher_loopback(tmp_path):
     for l in lines:
         assert "hunter2-cluster-token" not in l, "secret leaked to argv"
         assert "MXTPU_PS_SECRET_FILE=" in l.split("\t")[1]
-    # filename is unique per job (pid.time suffix) so overlapping jobs
-    # in one shared dir cannot clobber each other's secret
-    secrets = list(workdir.glob(".mxtpu_ps_secret.*"))
-    assert len(secrets) == 1, secrets
-    assert secrets[0].read_text() == "hunter2-cluster-token"
-    assert (secrets[0].stat().st_mode & 0o777) == 0o600
+    # while the job ran, both hosts saw ONE file in the job dir, named
+    # for this job alone (pid.time suffix: overlapping jobs in one
+    # shared dir cannot clobber each other's secret), mode 0600, holding
+    # the secret
+    rows = [l.split("\t") for l in seen.read_text().strip().splitlines()]
+    assert len(rows) == 2, rows
+    paths = {r[0] for r in rows}
+    assert len(paths) == 1, paths
+    (path,) = paths
+    assert os.path.dirname(path) == str(workdir)
+    name = os.path.basename(path).split(".")
+    assert name[:2] == ["", "mxtpu_ps_secret"], path
+    assert name[2] == str(proc.pid) and name[3].isdigit(), path
+    for _, mode, text in rows:
+        assert mode == "600"
+        assert text == "hunter2-cluster-token"
+    # and it does not outlive the job (PR 1): a later reader of the
+    # shared dir finds no HMAC key
+    assert not os.path.exists(path)
+    assert list(workdir.glob(".mxtpu_ps_secret.*")) == []

@@ -1,7 +1,6 @@
 """NHWC layout tier: channels-last Convolution/Pooling/BatchNorm must
 compute exactly what NCHW computes (weights are OIHW in both layouts,
-so parity is a transpose of data only). This is the correctness gate
-behind tools/mfu_experiments.py's layout experiment."""
+so parity is a transpose of data only)."""
 import numpy as np
 import pytest
 
@@ -63,6 +62,23 @@ def test_global_pool_nhwc():
                                rtol=1e-5)
 
 
+def _resnet_feeds(net, data_shape, rng, label):
+    """Every argument of ``net`` but the data: unit gammas, labels from
+    ``label(shape)``, small seeded weights (OIHW: good for both layouts)."""
+    arg_shapes, _, _ = net.infer_shape(data=data_shape)
+    feeds = {}
+    for name, shape in zip(net.list_arguments(), arg_shapes):
+        if name == "data":
+            continue
+        if name.endswith("gamma"):
+            feeds[name] = np.ones(shape, np.float32)
+        elif name == "softmax_label":
+            feeds[name] = label(shape).astype(np.float32)
+        else:
+            feeds[name] = (rng.randn(*shape) * 0.05).astype(np.float32)
+    return feeds
+
+
 def test_resnet50_nhwc_matches_nchw_forward():
     """Whole-tower equivalence on the flagship model (small input)."""
     rng = np.random.RandomState(2)
@@ -71,17 +87,8 @@ def test_resnet50_nhwc_matches_nchw_forward():
                                layout="NHWC")
 
     x = rng.rand(2, 3, 16, 16).astype(np.float32)
-    arg_shapes, _, aux_shapes = nchw.infer_shape(data=(2, 3, 16, 16))
-    feeds = {}
-    for name, shape in zip(nchw.list_arguments(), arg_shapes):
-        if name == "data":
-            continue
-        if name.endswith("gamma"):
-            feeds[name] = np.ones(shape, np.float32)
-        elif name == "softmax_label":
-            feeds[name] = np.zeros(shape, np.float32)
-        else:
-            feeds[name] = (rng.randn(*shape) * 0.05).astype(np.float32)
+    feeds = _resnet_feeds(nchw, x.shape, rng,
+                          label=lambda shape: np.zeros(shape))
 
     o1, _ = _run(nchw, dict(feeds, data=x))
     o2, _ = _run(nhwc, dict(
@@ -89,27 +96,32 @@ def test_resnet50_nhwc_matches_nchw_forward():
     np.testing.assert_allclose(o1, o2, rtol=1e-4, atol=1e-5)
 
 
-def test_mfu_experiments_harness_runs():
-    """The measurement harness executes every variant end to end (CPU
-    smoke scale); on-chip numbers come from running it on the TPU."""
-    import importlib.util
-    import os as _os
+def test_resnet50_nhwc_matches_nchw_train_step():
+    """The channels-last tower trains as the NCHW one does: one forward
+    and backward pass in training mode (batch statistics), same OIHW
+    weights, gives the same loss output and the same weight gradients
+    from the head down to the stem."""
+    rng = np.random.RandomState(4)
+    nchw = models.get_resnet50(num_classes=8, small_input=True)
+    nhwc = models.get_resnet50(num_classes=8, small_input=True,
+                               layout="NHWC")
 
-    spec = importlib.util.spec_from_file_location(
-        "mfu_experiments", _os.path.join(
-            _os.path.dirname(__file__), "..", "tools",
-            "mfu_experiments.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    results = mod.main(["--variant", "nhwc", "--batch", "2", "--image",
-                        "16", "--steps", "1"])
-    assert results and results[0]["experiment"] == "nhwc"
-    assert results[0]["imgs_per_sec"] > 0
-    # the combined channels-last + space-to-depth variant (round 4)
-    results = mod.main(["--variant", "nhwc_s2d", "--batch", "2",
-                        "--image", "16", "--steps", "1"])
-    assert results and results[0]["experiment"] == "nhwc_s2d"
-    assert results[0]["imgs_per_sec"] > 0
+    x = rng.rand(4, 3, 16, 16).astype(np.float32)
+    feeds = _resnet_feeds(nchw, x.shape, rng,
+                          label=lambda shape: rng.randint(0, 8, shape))
+
+    o1, ex1 = _run(nchw, dict(feeds, data=x), train=True)
+    o2, ex2 = _run(nhwc, dict(
+        feeds, data=np.ascontiguousarray(x.transpose(0, 2, 3, 1))),
+        train=True)
+    np.testing.assert_allclose(o1, o2, rtol=1e-3, atol=1e-4)
+    for name in ("fc1_weight", "stage1_unit1_b2_conv_weight",
+                 "stem_conv_weight"):
+        g1 = ex1.grad_dict[name].asnumpy()
+        g2 = ex2.grad_dict[name].asnumpy()
+        assert np.abs(g1).max() > 0
+        np.testing.assert_allclose(g1, g2, rtol=2e-2,
+                                   atol=1e-3 * np.abs(g1).max())
 
 
 def test_deconvolution_nhwc_matches_nchw():

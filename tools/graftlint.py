@@ -4,7 +4,7 @@
 Usage::
 
     python tools/graftlint.py mxnet_tpu/                 # lint, exit 1 on findings
-    python tools/graftlint.py mxnet_tpu tools bench.py \
+    python tools/graftlint.py mxnet_tpu tools chip_smoke.py \
         --baseline tools/graftlint_baseline.json          # gate on NEW findings
     python tools/graftlint.py --write-baseline --baseline B.json PATHS
     python tools/graftlint.py --write-env-docs            # regen docs/env_vars.md

@@ -1,9 +1,9 @@
 #!/bin/sh
 # Nightly gate runner (reference tests/nightly/test_all.sh): the
 # convergence / distributed / recovery tiers. The chip tier is not run
-# from here: chip_smoke.py, tools/tpu_consistency.py and bench.py each
-# need to be the one process that owns the TPU and exit non-zero
-# without it — run them through the chip tool.
+# from here: chip_smoke.py, tools/tpu_consistency.py and the benchmark
+# (python3 benchmark/run.py) each need to be the one process that owns
+# the TPU and exit non-zero without it — run them through the chip tool.
 #
 # Usage: sh tools/nightly.sh
 set -e

@@ -4,8 +4,8 @@ images/sec as a function of preprocess_threads.
 The reference decodes recordio with an OMP pool sized by
 preprocess_threads (src/io/iter_image_recordio.cc:188-196); this
 measures our thread-pool equivalent so the "can the pipeline feed the
-chip?" question has a number instead of a guess (round-2 verdict item:
-compute side ran 2,504 img/s while decode was single-threaded).
+chip?" question has a host-side number instead of a guess (what the
+chip consumes is the benchmark's `train_samples_per_s`).
 
 Usage:
   python tools/pipeline_bench.py [--rec PATH] [--threads 1,4,8]

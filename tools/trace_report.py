@@ -193,14 +193,15 @@ def render_ckpt(telemetry):
 
 
 # ---------------------------------------------------------------------------
-# xprof views (compile / ops / memory) over BENCH records
+# xprof views (compile / ops / memory) over record files
 # ---------------------------------------------------------------------------
 
 def load_bench_records(path):
-    """Dict records from a BENCH file (bench.py prints one JSON object
-    per line; BENCH_watch.json interleaves stage markers — any dict
-    line is kept, unparseable lines skipped). Pretty-printed artifacts
-    holding one object (SERVE_bench.json) load as a single record."""
+    """Dict records from a record file: one JSON object per line (any
+    dict line is kept, unparseable lines skipped), or one pretty-printed
+    object or list. The views `bench`, `serve`, `fleet` and `wire` read
+    records no tool writes since PR 28 (ROADMAP Design 4); tests feed
+    the renderers dicts."""
     recs = []
     with open(path) as f:
         body = f.read()
@@ -267,8 +268,8 @@ def _strike(s):
 
 
 def load_tune_rows(path):
-    """Autotuner rows from MFU_EXPERIMENTS.jsonl: the lines written by
-    mxnet_tpu/autotune.py (``experiment: autotune:<site>:<cand>``).
+    """Autotuner rows from the .jsonl `autotune.record()` appends to
+    (``experiment: autotune:<site>:<cand>``).
     Unparseable lines are skipped, same contract as load_records."""
     rows = []
     try:
@@ -296,12 +297,12 @@ def render_tune(rows):
     prune reason). Rows the validate() gate rejects render
     struck-through with the reason — never dropped."""
     if not rows:
-        return ("no autotune rows (run `python bench.py autotune "
-                "[--smoke]` to populate MFU_EXPERIMENTS.jsonl)\n")
+        return ("no autotune rows (`mxnet_tpu.autotune.run_smoke(path)` "
+                "writes them)\n")
     try:
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        from mfu_experiments import validate
-    except Exception:   # numpy-less box: trust the stored tags
+        sys.path.insert(0, _repo_root())
+        from mxnet_tpu.autotune import validate
+    except Exception:   # a box without the package: trust the stored tags
         def validate(row):
             return None
     out = []
@@ -448,8 +449,8 @@ def collective_fraction(rec):
 
 
 def latest_serve_record(recs):
-    """The newest serving-bench record (SERVE_bench.json lines carry no
-    xprof key, so they need their own selector)."""
+    """The newest serving record (such lines carry no xprof key, so
+    they need their own selector)."""
     for r in reversed(recs):
         if (r.get("metric") == "serve_goodput_rps"
                 or "latency_decomposition_ms" in r):
@@ -599,7 +600,7 @@ def render_serve(rec):
 
 
 def latest_fleet_record(recs):
-    """The newest fleet-bench record (FLEET_bench.json)."""
+    """The newest fleet record."""
     for r in reversed(recs):
         if r.get("metric") == "fleet_goodput_rps" or "chaos" in r:
             return r
@@ -657,7 +658,7 @@ def render_fleet(rec):
 
 
 def render_wire(rec):
-    """Wire view over a FLEET_bench.json socket record: the
+    """Wire view over a fleet record's socket part: the
     serialization-vs-pickle headline, the socket-vs-pipe overhead
     claim, a per-peer transport table (frames, bytes, rtt, reconnects,
     backpressure stalls), and the netfeed epoch. INCOMPLETE-safe: a
@@ -667,8 +668,7 @@ def render_wire(rec):
         return "wire: INCOMPLETE: %s\n" % rec["incomplete"]
     sock = rec.get("socket")
     if not sock:
-        return ("wire: no socket record in this FLEET bench "
-                "(run `make net-bench`)\n")
+        return "wire: no socket record in this fleet record\n"
     if sock.get("incomplete"):
         return "wire: INCOMPLETE: %s\n" % sock["incomplete"]
     out = ["wire: %.1f req/s over TCP  p99 %.2fx of pipe  chaos "
@@ -741,7 +741,7 @@ def render_wire(rec):
 
 
 def render_fleet_health(rec):
-    """Fleet-health view over an obswatch artifact (OBS_fleet.json):
+    """Fleet-health view over an obswatch artifact:
     the federated rollup table — one row per replica plus the fleet
     row — the federation-agreement numbers, and the SLO burn-rate
     verdict. INCOMPLETE-safe: a stamped-incomplete record renders its
@@ -842,8 +842,8 @@ def render_health_rows(rows, top=10):
 
 
 def render_numerics(rec):
-    """Numerics view over a NUMWATCH_health.json artifact: the per-
-    tensor health table (norm / max-abs / nonfinite / zero-frac /
+    """Numerics view over a numwatch artifact: the per-tensor
+    health table (norm / max-abs / nonfinite / zero-frac /
     update-to-weight ratio), the measured stats-on overhead and the
     one-dispatch proof, the guard counters, and the provenance verdict
     when something went nonfinite. INCOMPLETE-safe: a stamped-
@@ -1004,8 +1004,8 @@ def _dominant_span(spans):
 
 
 def render_trace_summary(trees, top=3):
-    """Top-``top`` slowest kept traces with their dominant span — the
-    profile-report teaser pointing at the full waterfall view."""
+    """Top-``top`` slowest kept traces with their dominant span — a
+    teaser pointing at the full waterfall view."""
     ranked = []
     for tid, spans in trees.items():
         by_id = {s["span"]: s for s in spans}
@@ -1131,7 +1131,7 @@ def render_bench_report(rec, top=10):
 
 
 def categorize_op(name):
-    """Map a profiler-trace op name (trace_top rows) onto the same
+    """Map a profiler-trace op name onto the same
     categories the HLO breakdown uses, so device time and analytic
     FLOPs line up in one table."""
     n = name.lower()
@@ -1159,41 +1159,6 @@ def categorize_op(name):
 
 def _repo_root():
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def profile_report(top=10):
-    """`make profile-report`: run the xprof views against the newest
-    BENCH artifacts in the repo root."""
-    root = _repo_root()
-    candidates = [os.path.join(root, "BENCH_watch.json")]
-    import glob
-
-    candidates += sorted(glob.glob(os.path.join(root, "BENCH_r*.json")),
-                         reverse=True)
-    out = []
-    rec = None
-    for path in candidates:
-        if not os.path.exists(path):
-            continue
-        rec = latest_xprof_record(load_bench_records(path))
-        if rec is not None:
-            out.append("bench artifact: %s\n" % os.path.basename(path))
-            break
-    if rec is None:
-        out.append("no BENCH artifact with an xprof summary found "
-                   "(run bench.py, or bench.py --smoke)\n")
-    else:
-        out.append(render_bench_report(rec, top=top))
-    tr_path = os.path.join(root, "FLEET_trace.json")
-    if os.path.exists(tr_path):
-        try:
-            trees = dtrace_trees(load_chrome_trace(tr_path))
-        except (OSError, ValueError):
-            trees = {}
-        if trees:
-            out.append("distributed traces (FLEET_trace.json):\n")
-            out.append(render_trace_summary(trees, top=3))
-    return "\n".join(out)
 
 
 def report_crash_dump(dump_dir, top=10):
@@ -1242,8 +1207,7 @@ def main(argv=None):
     p.add_argument("path", nargs="?",
                    help="step-trace .jsonl, BENCH .json, crash-dump "
                         "dir, or (--view waterfall) a trace id or "
-                        "chrome-trace path (optional with "
-                        "--profile-report)")
+                        "chrome-trace path")
     p.add_argument("--top", type=int, default=10,
                    help="slowest steps to show (default 10)")
     p.add_argument("--view", default="steps",
@@ -1252,32 +1216,22 @@ def main(argv=None):
                             "tune", "waterfall", "numerics"),
                    help="steps (default): slowest-step trace table; "
                         "compile/ops/memory/bench: xprof views over a "
-                        "BENCH record file; serve: latency decomposition "
-                        "+ load sweep over a SERVE_bench.json record; "
-                        "fleet: recovery window + swap purity over a "
-                        "FLEET_bench.json record; wire: socket-"
+                        "file of records that carry an xprof summary; "
+                        "serve: latency decomposition + load sweep over "
+                        "a serving record; fleet: recovery window + "
+                        "swap purity over a fleet record; wire: socket-"
                         "transport per-peer table + netfeed epoch over "
-                        "a FLEET_bench.json record (path optional); "
-                        "fleet-health: "
-                        "federated rollup table + burn-rate verdict "
-                        "over an obswatch artifact (path optional, "
-                        "defaults to OBS_fleet.json); tune: autotuner "
-                        "winners/losers per site from "
-                        "MFU_EXPERIMENTS.jsonl; waterfall: one kept "
-                        "distributed trace as an indented span tree "
-                        "(path = trace id, resolved against "
-                        "FLEET_trace.json in the repo root, or a "
-                        "chrome-trace file); numerics: per-tensor "
-                        "model-health table + overhead verdict over a "
-                        "NUMWATCH_health.json artifact (path optional)")
-    p.add_argument("--profile-report", action="store_true",
-                   help="auto-discover the newest BENCH "
-                        "artifacts in the repo root and render the "
-                        "bench view (used by `make profile-report`)")
+                        "a fleet record; fleet-health: federated rollup "
+                        "table + burn-rate verdict over an obswatch "
+                        "artifact; tune: autotuner winners/losers per "
+                        "site from the .jsonl autotune.record() wrote; "
+                        "waterfall: one kept distributed trace as an "
+                        "indented span tree (path = a chrome-trace "
+                        "file, or a trace id resolved against "
+                        "FLEET_trace.json in the repo root); numerics: "
+                        "per-tensor model-health table + overhead "
+                        "verdict over a numwatch artifact")
     a = p.parse_args(argv)
-    if a.profile_report:
-        sys.stdout.write(profile_report(top=a.top))
-        return 0
     if a.view == "waterfall":
         # positional: a trace id (or unique prefix) resolved against
         # FLEET_trace.json in the repo root, or a chrome-trace path
@@ -1288,8 +1242,9 @@ def main(argv=None):
         if path is None:
             path = os.path.join(_repo_root(), "FLEET_trace.json")
         if not os.path.exists(path):
-            sys.stdout.write("no chrome trace at %s (run `make "
-                             "trace-smoke`)\n" % path)
+            sys.stdout.write("no chrome trace at %s (write one with "
+                             "mxnet_tpu.dtrace.write_chrome_trace)\n"
+                             % path)
             return 1
         trees = dtrace_trees(load_chrome_trace(path))
         if not trees:
@@ -1308,53 +1263,8 @@ def main(argv=None):
                 s["dur"] for s in trees[t]))
         sys.stdout.write(render_waterfall(tid, trees[tid]))
         return 0
-    if a.view == "wire":
-        # path optional: defaults to the repo-root fleet bench record
-        path = a.path or os.path.join(_repo_root(), "FLEET_bench.json")
-        if not os.path.exists(path):
-            sys.stdout.write("no fleet bench record at %s (run `make "
-                             "net-bench`)\n" % path)
-            return 1
-        rec = latest_fleet_record(load_bench_records(path))
-        if rec is None:
-            sys.stdout.write("no fleet record in %s\n" % path)
-            return 1
-        sys.stdout.write(render_wire(rec))
-        return 0
-    if a.view == "fleet-health":
-        # path optional: defaults to the repo-root obswatch artifact
-        path = a.path or os.path.join(_repo_root(), "OBS_fleet.json")
-        if not os.path.exists(path):
-            sys.stdout.write("no obswatch artifact at %s (run `python "
-                             "bench.py fleet --smoke`)\n" % path)
-            return 1
-        try:
-            with open(path) as f:
-                rec = json.load(f)
-        except ValueError:
-            sys.stdout.write("fleet-health: INCOMPLETE: unreadable "
-                             "artifact %s\n" % path)
-            return 0
-        sys.stdout.write(render_fleet_health(rec))
-        return 0
-    if a.view == "numerics":
-        # path optional: defaults to the repo-root numwatch artifact
-        path = a.path or os.path.join(_repo_root(), "NUMWATCH_health.json")
-        if not os.path.exists(path):
-            sys.stdout.write("no numwatch artifact at %s (run `python "
-                             "bench.py numwatch`)\n" % path)
-            return 1
-        try:
-            with open(path) as f:
-                rec = json.load(f)
-        except ValueError:
-            sys.stdout.write("numerics: INCOMPLETE: unreadable "
-                             "artifact %s\n" % path)
-            return 0
-        sys.stdout.write(render_numerics(rec))
-        return 0
     if a.path is None:
-        p.error("path is required unless --profile-report is given")
+        p.error("path is required")
     if a.view == "tune":
         rows = load_tune_rows(a.path)
         sys.stdout.write(render_tune(rows))
@@ -1366,12 +1276,28 @@ def main(argv=None):
             return 1
         sys.stdout.write(render_serve(rec))
         return 0
-    if a.view == "fleet":
+    if a.view in ("fleet", "wire"):
         rec = latest_fleet_record(load_bench_records(a.path))
         if rec is None:
             sys.stdout.write("no fleet record in %s\n" % a.path)
             return 1
-        sys.stdout.write(render_fleet(rec))
+        fn = {"fleet": render_fleet, "wire": render_wire}
+        sys.stdout.write(fn[a.view](rec))
+        return 0
+    if a.view in ("fleet-health", "numerics"):
+        if not os.path.exists(a.path):
+            sys.stdout.write("no %s artifact at %s\n" % (a.view, a.path))
+            return 1
+        try:
+            with open(a.path) as f:
+                rec = json.load(f)
+        except ValueError:
+            sys.stdout.write("%s: INCOMPLETE: unreadable artifact %s\n"
+                             % (a.view, a.path))
+            return 0
+        fn = {"fleet-health": render_fleet_health,
+              "numerics": render_numerics}
+        sys.stdout.write(fn[a.view](rec))
         return 0
     if a.view != "steps":
         rec = latest_xprof_record(load_bench_records(a.path))
