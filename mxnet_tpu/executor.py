@@ -171,7 +171,96 @@ def _plan_conv_groups(op_nodes, out_index, node_device, seg_of):
     return group_of
 
 
-def make_graph_eval(symbol, node_device=None, remat=False):
+def _device_free_bytes():
+    """Bytes the local devices report free now (the least over them), or
+    None where one reports nothing: the CPU backend."""
+    import jax
+
+    free = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+            return None
+        free.append(stats["bytes_limit"] - stats["bytes_in_use"])
+    return min(free)
+
+
+def _plan_kept(plans, arg_index, arg_list, budget):
+    """What the segmented recomputation keeps of the program being
+    traced: ``{uid: {result: name}}``, the ``checkpoint_name`` under which
+    each chosen node's ``apply`` marks the value (``OpContext.keep``).
+
+    Ops say what may be kept (:meth:`Operator.remat_results`: a result,
+    the bytes it holds, the operations it costs to compute again), from
+    the shapes and dtypes this walk infers as binding does. A result
+    stated without a cost is kept always. The others are kept by
+    descending operations a byte while they fit ``budget`` bytes. The
+    last segment is not recomputed, so nothing of it is a candidate.
+
+    ``budget`` ``None`` takes it from what can be observed: the bytes the
+    device reports free now, less what the step itself will want there,
+    reckoned from the shapes with room to spare: its arguments twice
+    (their copies in the compute dtype, and their gradients), every value
+    that crosses a segment boundary, and the largest segment's values
+    twice (recomputed, and their gradients). Nothing where the device
+    reports nothing. (On the v5e, PR 29, a language model of 667 M
+    parameters at 8,192 tokens: 8.90 GB free, 5.59 reckoned where the
+    compiler's own count came to 5.46, so 3.31 GB of budget; every
+    candidate's 1.39 GB kept, and the step read 247 ms for 269.)
+
+    Counted once a traced program: ``remat.segments``,
+    ``remat.segments_recomputed``, ``remat.kept_results``, gauges
+    ``remat.kept_bytes`` and ``remat.budget_bytes``."""
+    from .ops.registry import Operator
+
+    def nbytes(shape, dtype):
+        return math.prod(shape) * np.dtype(dtype).itemsize
+
+    always, ranked, held, crossing = [], [], [0], 0
+    if any(type(n.op).remat_results is not Operator.remat_results
+           for seg, _, _ in plans[:-1] for n in seg):
+        shapes = {uid: [tuple(arg_list[i].shape)]
+                  for uid, i in arg_index.items()}
+        types = {uid: [arg_list[i].dtype] for uid, i in arg_index.items()}
+        for seg, _, out_keys in plans:
+            for n in seg:
+                in_shapes = [shapes[src.uid][i] for src, i in n.inputs]
+                in_types = [types[src.uid][i] for src, i in n.inputs]
+                shapes[n.uid] = n.op.infer_shape(in_shapes)[1]
+                types[n.uid] = n.op.infer_type(in_types)[1]
+                if seg is not plans[-1][0]:
+                    for result, size, ops in n.op.remat_results(
+                            in_shapes, in_types):
+                        (always if ops is None else ranked).append(
+                            (n, result, size, ops))
+            held.append(sum(nbytes(sh, t) for n in seg for sh, t in
+                            zip(shapes[n.uid], types[n.uid])))
+            crossing += sum(nbytes(shapes[uid][i], types[uid][i])
+                            for uid, i in out_keys)
+    if budget is None:
+        free = _device_free_bytes()
+        reserve = 2 * sum(nbytes(a.shape, a.dtype) for a in arg_list) \
+            + crossing + 2 * max(held)
+        budget = 0 if free is None else max(0, free - reserve)
+    kept, kept_bytes, left = {}, 0, budget
+    ranked.sort(key=lambda c: -c[3] / max(c[2], 1))
+    for n, result, size, ops in always + ranked:
+        if ops is not None:
+            if size > left:
+                continue
+            left -= size
+        kept.setdefault(n.uid, {})[result] = "%s:%s" % (n.name, result)
+        kept_bytes += size
+    _tel.inc("remat.segments", len(plans))
+    _tel.inc("remat.segments_recomputed", len(plans) - 1)
+    _tel.inc("remat.kept_results", sum(len(r) for r in kept.values()))
+    _tel.set_gauge("remat.kept_bytes", kept_bytes)
+    _tel.set_gauge("remat.budget_bytes", budget)
+    return kept
+
+
+def make_graph_eval(symbol, node_device=None, remat=False,
+                    remat_budget=None):
     """Build the pure graph-eval function for a symbol.
 
     Returns ``(eval_graph, n_aux)`` where
@@ -188,14 +277,19 @@ def make_graph_eval(symbol, node_device=None, remat=False):
 
     ``remat=True`` is the memonger design behind the reference's
     ``MXNET_BACKWARD_DO_MIRROR`` (``static_graph.cc:395-439``): the topo
-    order is split into ~sqrt(N) segments and each segment evaluates
+    order is split into ~sqrt(N) segments and each but the last evaluates
     under ``jax.checkpoint``, so the backward pass stores only segment
     BOUNDARY activations and recomputes inside each segment — sublinear
     activation memory for chain-like graphs. (Wrapping the whole
     function in one checkpoint would save nothing: the recompute would
-    materialize every activation again at once.) Internals-mode calls
-    fall back to the unsegmented path (monitoring wants every tensor
-    live anyway).
+    materialize every activation again at once.) The last segment's
+    backward starts where its forward ends, so it is evaluated plainly.
+    What else stays is decided by what it costs to compute again against
+    what it costs to hold, from the shapes, when a program is traced
+    (:func:`_plan_kept`, :meth:`Operator.remat_results`);
+    ``remat_budget`` states the bytes the ranked results may take in
+    place of what the device reports free. Internals-mode calls fall back
+    to the unsegmented path (monitoring wants every tensor live anyway).
 
     Sibling convolutions — Inception's parallel 1x1 branches, a ResNet
     unit's first 1x1 beside its unstrided shortcut — are lowered as one
@@ -295,11 +389,12 @@ def make_graph_eval(symbol, node_device=None, remat=False):
         return True
 
     def _eval_nodes(node_list, env, aux_out, key, is_train,
-                    internals=None):
+                    internals=None, kept=None):
         """Evaluate op nodes into env (uid -> outputs list) in place.
         With ``internals`` every node is lowered on its own (the monitor
         wants each node's tensor); without, sibling convolutions are
-        lowered as one where that pays."""
+        lowered as one where that pays. ``kept``: what the recomputation
+        plan keeps of each node (:func:`_plan_kept`)."""
         merged = set()
         for n in node_list:
             g = group_of.get(n.uid) if internals is None else None
@@ -317,7 +412,7 @@ def make_graph_eval(symbol, node_device=None, remat=False):
             slots = aux_slots.get(n.uid, [])
             aux_in = [aux_out[s] for s in slots]
             rng = jax.random.fold_in(key, n.uid) if key is not None else None
-            octx = OpContext(is_train, rng)
+            octx = OpContext(is_train, rng, kept and kept.get(n.uid))
             # the node's name on every op it lowers to (metadata only;
             # an operator made outside the registry has its class's)
             op_name = getattr(n.op, "op_name", type(n.op).__name__)
@@ -372,17 +467,6 @@ def make_graph_eval(symbol, node_device=None, remat=False):
         out_keys = sorted(consumed_later[si], key=lambda k: (k[0], k[1]))
         plans.append((seg, in_keys, out_keys))
 
-    # an op may declare results that are cheap to keep and dear to
-    # recompute (``Operator.remat_keep_names``): those stay, all else
-    # inside a segment is recomputed; a graph whose ops declare none
-    # checkpoints as before
-    keep = sorted({name for n in op_nodes
-                   for name in getattr(n.op, "remat_keep_names", ())})
-    checkpoint = functools.partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(*keep)) \
-        if keep else jax.checkpoint
-
     def eval_graph_remat(arg_list, aux_list, key, is_train,
                          want_internals=False):
         if want_internals:
@@ -393,6 +477,15 @@ def make_graph_eval(symbol, node_device=None, remat=False):
             if n.is_variable:
                 store[(n.uid, 0)] = arg_list[arg_index[n.uid]]
         aux_state = list(aux_list)
+        # what is dear to recompute and cheap to hold stays, by the
+        # shapes of this trace; all else inside a segment is recomputed.
+        # The last segment's backward starts where its forward ends:
+        # recomputing it would free nothing at the step's peak.
+        kept = _plan_kept(plans, arg_index, arg_list, remat_budget)
+        checkpoint = functools.partial(
+            jax.checkpoint,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *(name for r in kept.values() for name in r.values())))
         for seg, in_keys, out_keys in plans:
             def seg_fn(in_vals, aux_vals, _seg=seg, _in=in_keys,
                        _out=out_keys):
@@ -403,11 +496,13 @@ def make_graph_eval(symbol, node_device=None, remat=False):
                 for (uid, i), v in zip(_in, in_vals):
                     env.setdefault(uid, {})[i] = v
                 aux_out = list(aux_vals)
-                _eval_nodes(_seg, env, aux_out, key, is_train)
+                _eval_nodes(_seg, env, aux_out, key, is_train, kept=kept)
                 return [env[uid][i] for uid, i in _out], aux_out
 
             in_vals = [store[k] for k in in_keys]
-            out_vals, aux_state = checkpoint(seg_fn)(in_vals, aux_state)
+            if seg is not segments[-1]:
+                seg_fn = checkpoint(seg_fn)
+            out_vals, aux_state = seg_fn(in_vals, aux_state)
             store.update(zip(out_keys, out_vals))
         outputs = [store[(uid, i)] for uid, i in out_index]
         return outputs, aux_state

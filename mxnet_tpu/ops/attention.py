@@ -71,7 +71,7 @@ def rope(x, theta, scale=1.0):
 
 
 @functools.lru_cache(None)
-def _splash_kernel(t, group, block, interpret):
+def _splash_kernel(t, group, block, interpret, keep_name):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
 
@@ -87,21 +87,24 @@ def _splash_kernel(t, group, block, interpret):
     # or a cached kernel would carry one program's tracers into the next
     with jax.ensure_compile_time_eval():
         return kernel.make_splash_mqa_single_device(
-            mask, block_sizes=sizes, interpret=interpret)
+            mask, block_sizes=sizes, interpret=interpret,
+            residual_checkpoint_name=keep_name)
 
 
-def attend_splash(q, k, v):
+def attend_splash(q, k, v, keep_name=None):
     """``q [B, Hkv, G, T, D]`` (already scaled by 1/sqrt(D)), ``k, v [B,
     Hkv, T, D]`` -> ``[B, Hkv, G, T, D]``: one multi-query kernel call a
     (sequence, key/value head). The interpreter on ``cpu``, the Mosaic
-    kernel elsewhere (``pallas_kernels.pallas_call``'s rule)."""
+    kernel elsewhere (``pallas_kernels.pallas_call``'s rule). Under
+    ``keep_name`` the kernel marks its output and log-sum-exp, the
+    backward kernels' residuals, for a recomputation to keep."""
     import jax
 
     t, group = q.shape[3], q.shape[2]
     block = min(SPLASH_BLOCK, t)
 
     def run(interpret):
-        one = _splash_kernel(t, group, block, interpret)
+        one = _splash_kernel(t, group, block, interpret, keep_name)
         return lambda q, k, v: jax.vmap(jax.vmap(one))(q, k, v)
 
     return jax.lax.platform_dependent(q, k, v, cpu=run(True),
@@ -178,6 +181,15 @@ class CausalAttention(Operator):
         return (self.head_dim % 128 == 0 and t % 128 == 0
                 and t % min(SPLASH_BLOCK, t) == 0)
 
+    def remat_results(self, in_shapes, in_types):
+        """Kept always under recomputation: the kernel's output and, on
+        the splash path, its float32 log-sum-exp a (head, position), the
+        backward kernels' residuals: a step runs the forward kernel once.
+        Projections and rotary are recomputed."""
+        rows, width = in_shapes[0]
+        return [("attention", rows * width * np.dtype(in_types[0]).itemsize
+                 + 4 * rows * self.num_heads, None)]
+
     def apply(self, ctx, inputs, aux):
         import jax
 
@@ -206,7 +218,8 @@ class CausalAttention(Operator):
             _tel.inc("lower.attention_kernel.pallas_splash")
             qg = q.reshape(b, t, hkv, hq // hkv, d).transpose(0, 2, 3, 1, 4)
             out = attend_splash(qg, k.transpose(0, 2, 1, 3),
-                                v.transpose(0, 2, 1, 3))
+                                v.transpose(0, 2, 1, 3),
+                                ctx.kept.get("attention"))
             out = out.transpose(0, 3, 1, 2, 4)
             return [out.reshape(b * t, hq * d).astype(inputs[0].dtype)], []
         _tel.inc("lower.attention_kernel.xla_blockwise")
@@ -214,4 +227,4 @@ class CausalAttention(Operator):
         out = jax.lax.map(
             lambda x: attend_blockwise(x[0], x[1], x[2], scale),
             (qg, k, v))
-        return [out.reshape(b * t, hq * d)], []
+        return [ctx.keep(out.reshape(b * t, hq * d), "attention")], []

@@ -47,18 +47,33 @@ into telemetry when the module asks at a fence
 """
 from __future__ import annotations
 
+import functools
+
 from ..base import MXNetError
 from .registry import Operator, Param, REQUIRED, register_op
 
 BLOCK_ROWS = 512
-REMAT_KEEP = "routed_experts_out"
 
 
-def route(x, router, select_bias, top_k, scale):
+def block_rows(rows):
+    """Rows a block of the layout holds, for ``rows`` tokens."""
+    return min(BLOCK_ROWS, max(8, rows // 8))
+
+
+def layout_length(rows, top_k, num_held, block):
+    """Slots of the layout: every pair that can land here, and a last
+    block an expert that padding may fill."""
+    return -(-(rows * min(top_k, num_held) + num_held * (block - 1))
+             // block) * block
+
+
+def route(x, router, select_bias, top_k, scale, keep=lambda v: v):
     """``x [S, h]`` -> (expert ids ``[S, k]`` int32, combine weights
     ``[S, k]`` float32): scores ``sigmoid(x W_r)``; the ``top_k`` largest
     of ``scores + select_bias``; weights the chosen scores over their sum,
-    times ``scale``."""
+    times ``scale``. ``keep`` marks the ids where they are made: the
+    weights' gradient reads them, and must read the marked ones for a
+    recomputation to skip the top-k."""
     import jax
     import jax.numpy as jnp
 
@@ -66,9 +81,10 @@ def route(x, router, select_bias, top_k, scale):
     scores = jax.nn.sigmoid(jnp.dot(x.astype(f32), router.astype(f32),
                                     precision=jax.lax.Precision.HIGHEST))
     _, eid = jax.lax.top_k(scores + select_bias.astype(f32), top_k)
+    eid = keep(eid.astype(jnp.int32))
     chosen = jnp.take_along_axis(scores, eid, axis=1)
     wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + 1e-20) * scale
-    return eid.astype(jnp.int32), wts
+    return eid, wts
 
 
 def balance_step(bias, load, rate):
@@ -96,8 +112,7 @@ def plan(eid, wts, first_held, num_held, block):
 
     s, k = eid.shape
     pairs = s * k
-    length = -(-(s * min(k, num_held) + num_held * (block - 1)) // block) \
-        * block
+    length = layout_length(s, k, num_held, block)
     local = eid.reshape(-1) - first_held
     here = (local >= 0) & (local < num_held)
     key = jnp.where(here, local, num_held)
@@ -225,7 +240,6 @@ class RoutedExperts(Operator):
     }
     # arguments that reach the op in their own dtype under mixed precision
     full_precision_args = ("router_weight",)
-    remat_keep_names = (REMAT_KEEP,)
 
     def list_arguments(self):
         return ["data", "router_weight", "up_weight", "down_weight"]
@@ -253,25 +267,41 @@ class RoutedExperts(Operator):
         ins, outs, _ = super().infer_type(in_types, out_types)
         return ins, outs, [np.dtype(np.int32), np.dtype(np.float32)]
 
+    def remat_results(self, in_shapes, in_types):
+        """Kept always under recomputation: the result (one activation),
+        so that the recomputed forward skips the loop, and what ``route``
+        and ``plan`` return (integers and scalars a row), so that top-k,
+        the sort and the layout run once a step. Both are needed again
+        only as the backward pass's residuals."""
+        import numpy as np
+
+        rows, h = in_shapes[0]
+        block = block_rows(rows)
+        length = layout_length(rows, self.top_k, self.num_held, block)
+        # ids, weights and slots a pair; row and weight a slot; an expert
+        # a block; the count of blocks
+        routing = 4 * (3 * rows * self.top_k + 2 * length
+                       + length // block + 1)
+        return [("output", rows * h * np.dtype(in_types[0]).itemsize, None),
+                ("routing", routing, None)]
+
     def apply(self, ctx, inputs, aux):
+        import jax
         import jax.numpy as jnp
 
         x, router, w_up, w_down = inputs
         counted, bias = aux
         e = self.num_experts
-        eid, wts = route(x, router, bias, self.top_k, self.scale)
-        block = min(BLOCK_ROWS, max(8, x.shape[0] // 8))
-        import jax
-        from jax.ad_checkpoint import checkpoint_name
-
-        rows, weights, slot, block_expert, nblocks, dropped = plan(
-            eid, wts, self.first_held, self.num_held, block)
+        keep = functools.partial(ctx.keep, result="routing")
+        eid, wts = route(x, router, bias, self.top_k, self.scale, keep)
+        *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
+                                block_rows(x.shape[0]))
+        wts, rows, weights, slot, block_expert, nblocks = keep(
+            (wts, *layout))
         y = grouped_experts(x, w_up, w_down, wts,
                             rows, jax.lax.stop_gradient(weights), slot,
                             block_expert, nblocks)
-        # under MXNET_BACKWARD_DO_MIRROR the result is kept (one
-        # activation) so that the recomputed forward skips the loop
-        y = checkpoint_name(y, REMAT_KEEP)
+        y = ctx.keep(y, "output")
         load = jnp.zeros((e,), jnp.int32).at[eid.reshape(-1)].add(1)
         if ctx.is_train and self.bias_update_rate:
             bias = balance_step(bias, load, self.bias_update_rate)

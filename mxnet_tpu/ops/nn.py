@@ -53,6 +53,14 @@ class FullyConnected(Operator):
             shapes.append((self.num_hidden,))
         return shapes, [(n, self.num_hidden)], []
 
+    def remat_results(self, in_shapes, in_types):
+        """The product: ``2 x rows x K x N`` operations to run again
+        against ``rows x N`` values to hold."""
+        rows, k = in_shapes[0][0], int(np.prod(in_shapes[0][1:]))
+        return [("output",
+                 rows * self.num_hidden * np.dtype(in_types[0]).itemsize,
+                 2 * rows * k * self.num_hidden)]
+
     def apply(self, ctx, inputs, aux):
         # XLA is the measured fast path: the Pallas fused_linear kernel
         # benched 0.1-1.0x of the XLA dot on a v5e across 256..8192 sizes
@@ -66,7 +74,7 @@ class FullyConnected(Operator):
         out = jnp.dot(x, w.T)
         if not self.no_bias:
             out = out + inputs[2]
-        return [out], []
+        return [ctx.keep(out, "output")], []
 
 
 # ---------------------------------------------------------------------------

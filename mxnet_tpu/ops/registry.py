@@ -63,13 +63,32 @@ class Param:
 class OpContext:
     """Per-invocation context handed to ``apply`` (reference ``OpContext``,
     ``operator.h:44-62``): training mode flag and a PRNG key (the reference's
-    per-device ``Random<xpu>`` resource, ``include/mxnet/resource.h``)."""
+    per-device ``Random<xpu>`` resource, ``include/mxnet/resource.h``).
 
-    __slots__ = ("is_train", "rng")
+    ``kept`` is what the segmented recomputation (``executor.
+    make_graph_eval(remat=True)``) keeps of this node: ``{result: name}``
+    for the results of :meth:`Operator.remat_results` it chose, empty
+    everywhere else."""
 
-    def __init__(self, is_train: bool, rng=None):
+    __slots__ = ("is_train", "rng", "kept")
+
+    def __init__(self, is_train: bool, rng=None, kept=None):
         self.is_train = is_train
         self.rng = rng
+        self.kept = kept or {}
+
+    def keep(self, value, result: str):
+        """``value`` (an array or a tree of them) marked as kept where the
+        recomputation plan chose ``result`` of this node, else as it is:
+        outside recomputation nothing is added to the program."""
+        name = self.kept.get(result)
+        if name is None:
+            return value
+        import jax
+        from jax.ad_checkpoint import checkpoint_name
+
+        return jax.tree_util.tree_map(
+            lambda v: checkpoint_name(v, name), value)
 
 
 class Operator:
@@ -84,10 +103,6 @@ class Operator:
     # discontinuously. The executor's cast rule skips the variables that
     # feed them.
     full_precision_args: Sequence[str] = ()
-    # names (``jax.ad_checkpoint.checkpoint_name``) of results ``apply``
-    # marks that the segmented recomputation (MXNET_BACKWARD_DO_MIRROR,
-    # ``executor.make_graph_eval``) keeps instead of computing again
-    remat_keep_names: Sequence[str] = ()
 
     def __init__(self, **kwargs):
         unknown = [k for k in kwargs if k not in self.PARAMS]
@@ -151,6 +166,19 @@ class Operator:
                     [np.float32] * len(self.list_auxiliary_states()))
         return ([dtype] * len(in_types), [dtype] * self.num_outputs,
                 [np.float32] * len(self.list_auxiliary_states()))
+
+    def remat_results(self, in_shapes, in_types):
+        """What the segmented recomputation (``MXNET_BACKWARD_DO_MIRROR``,
+        ``executor.make_graph_eval``) may keep of this node instead of
+        computing it again: ``[(result, bytes held, operations)]`` from
+        the input shapes and dtypes. ``result`` is the label under which
+        ``apply`` hands the value to ``ctx.keep``; ``operations`` is what
+        computing it again costs, and the plan keeps by descending
+        operations a byte as far as the device's memory allows.
+        ``operations`` ``None``: dear to compute again and cheap to hold
+        whatever the shapes, so kept always. Everything an op does not
+        list is recomputed inside its segment."""
+        return []
 
     def apply(self, ctx: OpContext, inputs: Sequence[Any], aux: Sequence[Any]):
         """Pure function over jnp arrays -> (outputs, new_aux)."""

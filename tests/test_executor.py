@@ -196,6 +196,141 @@ def test_segmented_remat_matches_plain():
     assert txt.count("stablehlo.dot") > plain_txt.count("stablehlo.dot")
 
 
+def _remat_chain(head=3):
+    """Nine products of growing input width behind one another, a
+    BatchNorm, and a head of ``head`` classes: 21 op nodes, so four
+    segments, the last one BatchNorm, head and loss."""
+    net = mx.sym.Variable("data")
+    for i in range(9):
+        net = mx.sym.FullyConnected(net, num_hidden=16 + 8 * (i % 3),
+                                    name="rfc%d" % i)
+        net = mx.sym.Activation(net, act_type="tanh")
+    net = mx.sym.BatchNorm(net, name="rbn")
+    net = mx.sym.FullyConnected(net, num_hidden=head, name="rcls")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+def _remat_case(net, budget, batch=4):
+    """``(outputs, aux, gradients, lowered text, telemetry)`` of the
+    training pass of ``net`` under ``make_graph_eval(remat=budget is not
+    False, remat_budget=budget)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.executor import make_graph_eval
+
+    fn, _ = make_graph_eval(net) if budget is False else \
+        make_graph_eval(net, remat=True, remat_budget=budget)
+    arg_shapes, _, aux_shapes = net.infer_shape(data=(batch, 16))
+    rng = np.random.RandomState(0)
+    args = [rng.randn(*s).astype(np.float32) * 0.3 for s in arg_shapes]
+    lbl = net.list_arguments().index("softmax_label")
+    args[lbl] = rng.randint(0, 2, (batch,)).astype(np.float32)
+    aux = [np.ones(s, np.float32) if "var" in n else np.zeros(s, np.float32)
+           for n, s in zip(net.list_auxiliary_states(), aux_shapes)]
+    key = jax.random.PRNGKey(0)
+
+    def loss(a):
+        outs, aux_o = fn(a, aux, key, True)
+        return (sum(jnp.sum(o) for o in outs)
+                + sum(jnp.sum(x) for x in aux_o))
+
+    outs, aux_o = fn(args, aux, key, True)
+    grads = jax.grad(loss)(args)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        text = jax.jit(jax.grad(loss)).lower(args).as_text()
+        seen = {k: telemetry.peek(k) for k in (
+            "remat.segments", "remat.segments_recomputed",
+            "remat.kept_results")}
+        seen.update({k: telemetry.peek(k, "gauge") for k in (
+            "remat.kept_bytes", "remat.budget_bytes")})
+    finally:
+        telemetry.disable()
+    return outs, aux_o, grads, text, seen
+
+
+def _dots(text, dim=None):
+    """Products in a lowered program's text; with ``dim`` those that read
+    or write a tensor with a dimension of that length."""
+    import re
+
+    lines = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln]
+    if dim is None:
+        return len(lines)
+    return sum(bool(re.search(r"[<x]%dx" % dim, ln)) for ln in lines)
+
+
+def test_last_segment_is_not_recomputed():
+    """The last segment's backward starts where its forward ends, so it
+    runs under no ``jax.checkpoint``: its product appears three times in
+    the step (forward, two gradients), not four; every other product is
+    still run again."""
+    net = _remat_chain(head=3)
+    *_, plain, _ = _remat_case(net, False)
+    *_, remat, seen = _remat_case(net, 0)
+    assert _dots(plain) == 30 and _dots(plain, 3) == 3
+    assert _dots(remat, 3) == 3
+    assert _dots(remat) == 9 * 4 + 3
+    assert seen["remat.segments"] == 4
+    assert seen["remat.segments_recomputed"] == 3
+    assert seen["remat.kept_results"] == 0 and seen["remat.kept_bytes"] == 0
+
+
+@pytest.mark.parametrize("budget", [0, 768, 1408, 1 << 30])
+def test_recomputation_keeps_by_work_a_byte_inside_the_budget(budget):
+    """Products are kept by descending operations a byte (here: by the
+    width they read) while they fit the stated budget; what is kept is
+    not run again; a budget of 0 keeps nothing, a large one every product
+    outside the last segment; and whatever is kept, outputs, auxiliary
+    states and gradients are the plain path's."""
+    net = _remat_chain()
+    o1, a1, g1, _, _ = _remat_case(net, False)
+    o2, a2, g2, text, seen = _remat_case(net, budget)
+    for x, y in zip(o1 + a1, o2 + a2):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-6)
+    for x, y in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=1e-5, atol=1e-6)
+    # product i reads 16 (i = 0), else the width of product i - 1; each
+    # holds batch x its own width x 4 bytes
+    widths = [16 + 8 * (i % 3) for i in range(9)]
+    reads = [16] + widths[:-1]
+    left, kept = budget, 0
+    for i in sorted(range(9), key=lambda i: -reads[i]):
+        if 4 * widths[i] * 4 <= left:
+            left -= 4 * widths[i] * 4
+            kept += 1
+    assert seen["remat.kept_results"] == kept
+    assert seen["remat.kept_bytes"] == budget - left <= budget
+    assert seen["remat.budget_bytes"] == budget
+    assert kept == {0: 0, 1 << 30: 9}.get(budget, kept)
+    assert _dots(text) == (9 - kept) * 4 + kept * 3 + 3
+
+
+def test_recomputation_budget_is_what_the_device_reports_free(monkeypatch):
+    """Unstated, the budget is what the device reports free when the step
+    is traced less a reserve reckoned from the shapes, never under 0; the
+    CPU backend reports nothing and the budget is 0."""
+    from mxnet_tpu import executor
+
+    net = _remat_chain()
+    assert executor._device_free_bytes() is None
+    *_, seen = _remat_case(net, None)
+    assert seen["remat.budget_bytes"] == 0 == seen["remat.kept_results"]
+    budgets = []
+    for free in (1 << 30, (1 << 30) + 4096, 64):
+        monkeypatch.setattr(executor, "_device_free_bytes", lambda: free)
+        budgets.append(_remat_case(net, None)[-1]["remat.budget_bytes"])
+    # the reserve: arguments twice, boundaries, the largest segment twice
+    arg_shapes, _, _ = net.infer_shape(data=(4, 16))
+    args = 4 * sum(int(np.prod(s)) for s in arg_shapes)
+    assert (1 << 30) - budgets[0] > 2 * args
+    assert budgets[1] - budgets[0] == 4096 and budgets[2] == 0
+
+
 def test_monitor_installed_between_forward_and_backward():
     """Per-batch monitor semantics: whether to monitor is decided at
     emission time (backward / lazy outputs), so a callback installed

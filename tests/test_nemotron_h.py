@@ -2,6 +2,7 @@
 SSMScan, CausalAttention, RoutedExperts) against plain ``jax.numpy``, and
 ``models.get_nemotron_h`` through ``Module.fit`` on the fused step against
 the benchmark's float32 reference. Toy widths, seeded."""
+import functools
 import os
 import sys
 
@@ -1005,3 +1006,118 @@ def test_the_scan_runs_forward_twice_and_backward_once_a_step(monkeypatch,
                     if carry.shape == state and carry.dtype == jnp.float32:
                         ran[bool(eqn.params["reverse"])] += 1
     assert ran == [2 * nodes, nodes]
+
+
+# ---------------------------------------------------------------------------
+# what the recomputation keeps
+# ---------------------------------------------------------------------------
+def _count(jaxpr, pick):
+    return sum(bool(pick(eqn)) for sub in _sub_jaxprs(jaxpr)
+               for eqn in sub.eqns)
+
+
+def test_attention_kernel_and_routing_run_once_a_step(monkeypatch):
+    """Under segment recomputation the splash kernel's output and
+    log-sum-exp and the experts' routing are kept: the forward kernel is
+    called as often as each backward kernel (once a step; the parent's
+    plan called it twice), and ``top_k`` and the layout's ``sort`` appear
+    once a ``RoutedExperts`` node. Counted in the traced step, and as
+    calls of the kernel's jitted caller (forward, backward) in the step
+    lowered for the TPU."""
+    import re
+
+    toy = dict(KERNEL_TOY, pattern="M*E", seq_len=128, mamba_head_dim=8,
+               ssm_state=8, chunk=32, head_dim=128)
+    traced = traced_fused_step(monkeypatch, toy)
+    jaxpr = traced.jaxpr.jaxpr
+
+    def kernels(name):
+        return _count(jaxpr, lambda e: e.primitive.name == "pallas_call"
+                      and name in str(e.params.get("name")))
+
+    # one call a branch of ``platform_dependent`` (interpreter, Mosaic)
+    assert kernels("splash_mqa_fwd") == kernels("splash_mqa_dq") \
+        == kernels("splash_mqa_dkv") == 2
+    assert _count(jaxpr, lambda e: e.primitive.name == "top_k") == 1
+    assert _count(jaxpr, lambda e: e.primitive.name == "sort") == 1
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert 'kernel_name = "splash_mqa_fwd_residuals"' in text
+    assert len(re.findall(r"call @_splash_attention(_\d+)?\(", text)) == 2
+
+
+@functools.lru_cache(None)
+def _model_pass(budget, seed=7):
+    """Outputs, auxiliary states and the float arguments' gradients of one
+    training pass of the toy model under ``make_graph_eval``: plain
+    (``budget`` False) or recomputed with ``remat_budget=budget``; and the
+    ``remat.*`` telemetry of that trace."""
+    from mxnet_tpu.executor import make_graph_eval, zero_cotangent
+
+    net = get_nemotron_h(**TOY)
+    shape = (2, TOY["seq_len"])
+    fn, _ = make_graph_eval(net) if budget is False else \
+        make_graph_eval(net, remat=True, remat_budget=budget)
+    arg_shapes, _, aux_shapes = net.infer_shape(data=shape,
+                                                softmax_label=shape)
+    rng = np.random.default_rng(seed)
+    args = []
+    for name, s in zip(net.list_arguments(), arg_shapes):
+        if name in ("data", "softmax_label"):
+            args.append(rng.integers(0, TOY["vocab"], s).astype(np.int32))
+        elif name.endswith(("_A_log", "_dt_bias", "_D")):
+            args.append(rng.uniform(0.1, 0.5, s).astype(np.float32))
+        else:
+            args.append((rng.standard_normal(s) / np.sqrt(s[-1])).astype(
+                np.float32))
+    args = [jnp.asarray(a) for a in args]
+    aux = [jnp.zeros(s, t) for s, t in zip(aux_shapes, net.infer_type()[2])]
+    floats = [i for i, a in enumerate(args) if a.dtype == np.float32]
+    key = jax.random.PRNGKey(0)
+
+    def f(fl):
+        full = list(args)
+        for i, v in zip(floats, fl):
+            full[i] = v
+        return fn(full, aux, key, True)
+
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        (outs, aux_out), vjp = jax.vjp(f, [args[i] for i in floats])
+        grads, = vjp(([jnp.ones_like(o) for o in outs],
+                      [zero_cotangent(a) for a in aux_out]))
+        seen = {k: telemetry.peek(k) for k in (
+            "remat.segments", "remat.segments_recomputed",
+            "remat.kept_results")}
+        seen.update({k: telemetry.peek(k, "gauge") for k in (
+            "remat.kept_bytes", "remat.budget_bytes")})
+    finally:
+        telemetry.disable()
+    return outs + aux_out + grads, seen
+
+
+@pytest.mark.parametrize("budget", [0, 40000, 1 << 40])
+def test_recomputation_plan_keeps_by_budget_and_matches_plain(budget):
+    """The toy model's 85 nodes make nine segments, eight recomputed.
+    With a stated budget of 0 only what the ops keep always engages (the
+    result and the routing of each of four expert layers, the attention
+    kernel's result: 9); with a large one every product outside the last
+    segment as well (20); in between as many as fit, and the bytes kept
+    never pass the budget plus the always-kept. Whatever is kept, the
+    values are the plain path's."""
+    plain, _ = _model_pass(False)
+    floor = _model_pass(0)[1]
+    got, seen = _model_pass(budget)
+    for x, y in zip(plain, got):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=1e-5, atol=1e-6)
+    assert seen["remat.segments"] == 9
+    assert seen["remat.segments_recomputed"] == 8
+    assert floor["remat.kept_results"] == 9
+    assert seen["remat.budget_bytes"] == budget
+    assert seen["remat.kept_bytes"] <= budget + floor["remat.kept_bytes"]
+    assert seen["remat.kept_results"] == {0: 9, 1 << 40: 29}.get(
+        budget, seen["remat.kept_results"])
+    assert 9 <= seen["remat.kept_results"] <= 29
+    if budget == 40000:
+        assert 9 < seen["remat.kept_results"] < 29
