@@ -804,16 +804,23 @@ class RMSNorm(Operator):
     statistics in float32 whatever the compute dtype.
 
     ``num_groups`` > 1 normalises each of that many equal slices of the
-    last axis on its own (one learned ``gamma`` over the whole width).
-    ``gated=True`` takes a second input ``gate`` and normalises
-    ``x * silu(gate)``: the gated norm that closes a Mamba-2 mixer (Dao
-    and Gu, arXiv:2405.21060)."""
+    last axis on its own, under one learned ``gamma`` over the whole width
+    or, with ``shared_gamma``, ONE ``gamma`` of a slice's width that every
+    slice shares (a head's norm). ``gated=True`` takes a second input
+    ``gate``: by default it normalises ``x * silu(gate)``, the gated norm
+    that closes a Mamba-2 mixer (Dao and Gu, arXiv:2405.21060); with
+    ``gate_after`` it normalises ``x`` first and multiplies by
+    ``silu(gate)`` after, as a gated delta-rule mixer closes (Yang et al.,
+    arXiv:2412.06464)."""
 
     name_hint = "rmsnorm"
     PARAMS = {
         "eps": Param(float, 1e-5),
         "num_groups": Param(int, 1),
         "gated": Param(bool, False),
+        "gate_after": Param(bool, False, "norm(x) * silu(gate), not "
+                            "norm(x * silu(gate))"),
+        "shared_gamma": Param(bool, False, "one gamma of a group's width"),
     }
 
     def list_arguments(self):
@@ -827,20 +834,31 @@ class RMSNorm(Operator):
         if data[-1] % self.num_groups:
             raise MXNetError("RMSNorm: width %d is not %d equal groups"
                              % (data[-1], self.num_groups))
-        shapes = [data, (data[-1],)] + ([data] if self.gated else [])
+        if self.gate_after and not self.gated:
+            raise MXNetError("RMSNorm: gate_after without gated")
+        width = data[-1] // self.num_groups if self.shared_gamma \
+            else data[-1]
+        shapes = [data, (width,)] + ([data] if self.gated else [])
         return shapes, [data], []
 
     def apply(self, ctx, inputs, aux):
         jax, jnp = _jax(), _jnp()
         x, gamma = inputs[0], inputs[1]
         y = x.astype(jnp.float32)
-        if self.gated:
-            y = y * jax.nn.silu(inputs[2].astype(jnp.float32))
+        gate = jax.nn.silu(inputs[2].astype(jnp.float32)) if self.gated \
+            else None
+        if self.gated and not self.gate_after:
+            y = y * gate
         g = self.num_groups
         yg = y.reshape(y.shape[:-1] + (g, y.shape[-1] // g))
         yg = yg * jax.lax.rsqrt(
             jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + self.eps)
-        y = yg.reshape(y.shape) * gamma.astype(jnp.float32)
+        if self.shared_gamma:
+            y = (yg * gamma.astype(jnp.float32)).reshape(y.shape)
+        else:
+            y = yg.reshape(y.shape) * gamma.astype(jnp.float32)
+        if self.gate_after:
+            y = y * gate
         return [y.astype(x.dtype)], []
 
 

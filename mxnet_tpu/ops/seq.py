@@ -664,3 +664,174 @@ class SSMScan(Operator):
         y = ssd_scan(xbc, dt, -jnp.exp(a_log.astype(f32)),
                      d_skip.astype(f32), dims, self.chunk, kernel)
         return [y[:, :t].reshape(b * t, -1)], []
+
+
+# ---------------------------------------------------------------------------
+# linear attention by the gated delta rule, on the same chunked skeleton
+# (Yang, Kautz and Hatamizadeh, "Gated Delta Networks", arXiv:2412.06464)
+# ---------------------------------------------------------------------------
+def gated_delta_chunked(q, k, v, g, beta, chunk):
+    """One sequence of the gated delta rule ``S_t = a_t S_{t-1} + b_t k_t
+    (v_t - a_t S_{t-1}^T k_t)^T``, ``o_t = S_t^T q_t`` (per head; S is
+    ``[K, V]``, ``S_0 = 0``), by chunks of ``chunk`` positions. Where a
+    diagonal decay (:func:`ssd_chunked`) needs a masked matrix product, the
+    factors ``a_t (I - b_t k_t k_t^T)`` need the chunk's WY form: with
+    ``u_t = b_t (v_t - a_t S_{t-1}^T k_t)`` the state is ``S_t = g_t S_0 +
+    sum_{i<=t} (g_t/g_i) k_i u_i^T`` (``g_t`` the decay from the chunk's
+    start), and the ``u`` of a chunk solve ONE unit-lower-triangular
+    system, ``(I + A) U = B V - B G K S_0`` with ``A[t, i] = b_t (g_t/g_i)
+    k_t.k_i`` below the diagonal. So
+
+    1. all chunks at once: the system solved for its two right-hand sides
+       (``U0 = (I+A)^-1 B V``, ``W = (I+A)^-1 B G K``; forward substitution,
+       no power series: the powers of ``A`` overflow float32's mantissa long
+       before they cancel), and from them each chunk's map of the state,
+       ``S_end = M S_start + N``;
+    2. one short ``lax.scan`` over the chunks carries the ``[K, V]`` state
+       through those maps (a product a step; nothing else is sequential);
+    3. all chunks at once: ``O = G Q S_start + ((Q K^T) * decay)(U0 - W
+       S_start)``.
+
+    Everything is float32 and every product is taken at ``HIGHEST``
+    precision: the operations are few (12 MFLOP a chunk and head at 64 x
+    96 x 192) and the state, the decays and the solve do not bear a
+    rounding to bfloat16 (the gradient is autodiff's through these same
+    float32 operations, so what cancels in it cancels as here).
+
+    ``q``, ``k [T, H, K]`` (normalised, ``q`` scaled), ``v [T, H, V]``,
+    ``g [T, H]`` (log of the decay, <= 0), ``beta [T, H]``; T whole chunks.
+    Returns ``o [T, H, V]``."""
+    jax, jnp = _jax(), _jnp()
+    from jax.scipy.linalg import solve_triangular
+
+    hi = jax.lax.Precision.HIGHEST
+    t, h, dk = k.shape
+    dv = v.shape[-1]
+    nc = t // chunk
+
+    def cut(x):                         # [T, H, ...] -> [nc, H, L, ...]
+        x = x.reshape((nc, chunk) + x.shape[1:])
+        return jnp.moveaxis(x, 1, 2)
+
+    q, k, v, g, beta = (cut(x) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-1)                         # [nc, H, L]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    seg = gc[..., :, None] - gc[..., None, :]           # [.., t, i]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
+    kk = jnp.einsum("nhtd,nhid->nhti", k, k, precision=hi)
+    a_mat = jnp.where(jnp.tril(lower, -1),
+                      beta[..., None] * kk * decay, 0.0)
+    rhs = jnp.concatenate([beta[..., None] * v,
+                           (beta * jnp.exp(gc))[..., None] * k], axis=-1)
+    # (I + A) U = rhs: the unit diagonal is implied, A's own is not read
+    sol = solve_triangular(a_mat, rhs, lower=True, unit_diagonal=True)
+    u0, w = sol[..., :dv], sol[..., dv:]
+    k_end = k * jnp.exp(gc[..., -1:] - gc)[..., None]   # k_i g_L / g_i
+    m = jnp.exp(gc[..., -1])[..., None, None] * jnp.eye(dk, dtype=k.dtype) \
+        - jnp.einsum("nhld,nhle->nhde", k_end, w, precision=hi)
+    n = jnp.einsum("nhld,nhle->nhde", k_end, u0, precision=hi)
+
+    def carry(s, mn):
+        return jnp.einsum("hde,hev->hdv", mn[0], s, precision=hi) + mn[1], s
+
+    _, starts = jax.lax.scan(carry, jnp.zeros((h, dk, dv), k.dtype), (m, n))
+    u = u0 - jnp.einsum("nhld,nhdv->nhlv", w, starts, precision=hi)
+    qk = jnp.einsum("nhtd,nhid->nhti", q, k, precision=hi) * decay
+    o = jnp.exp(gc)[..., None] * jnp.einsum("nhld,nhdv->nhlv", q, starts,
+                                            precision=hi) \
+        + jnp.einsum("nhti,nhiv->nhtv", qk, u, precision=hi)
+    return jnp.moveaxis(o, 2, 1).reshape(t, h, dv)
+
+
+@register_op("GatedDeltaRule")
+class GatedDeltaRule(Operator):
+    """Linear attention by the gated delta rule over whole sequences,
+    chunked (:func:`gated_delta_chunked`), beside ``SSMScan``. Per position
+    and head, from the mixer's convolved and activated projections
+    ``query``, ``key`` ``[rows, H*K]``, ``value`` ``[rows, H*V]`` and the
+    two per-head gates ``a``, ``b`` ``[rows, H]`` before their
+    nonlinearities:
+
+    ``q = query / |query|_2 / sqrt(K)``, ``k = key / |key|_2``;
+    ``beta = sigmoid(b)``, doubled under ``neg_eigval`` (the factor ``I -
+    beta k k^T`` may then reflect: eigenvalues in (-1, 1));
+    ``alpha = exp(-exp(A_log) softplus(a + dt_bias))``;
+    ``S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T``,
+    ``o_t = S_t^T q_t``, ``S_0 = 0`` at each sequence's start.
+
+    The normalisation, the doubling and the decay are inside the op, in
+    float32 like the state and the solve, whatever the compute dtype; the
+    result is rounded to it once. Counts ``lower.delta_rule_kernel.
+    xla_chunked`` when traced (the one body there is)."""
+
+    name_hint = "gateddeltarule"
+    PARAMS = {
+        "num_heads": Param(int, REQUIRED),
+        "key_dim": Param(int, REQUIRED, "a head's query/key width"),
+        "value_dim": Param(int, REQUIRED, "a head's value width"),
+        "chunk": Param(int, 64),
+        "seq_len": Param(int, REQUIRED),
+        "neg_eigval": Param(bool, False, "beta in (0, 2), not (0, 1)"),
+    }
+    # the decay compounds over a sequence: its parameters stay float32
+    full_precision_args = ("A_log", "dt_bias")
+    NORM_EPS = 1e-6     # under the root of |x|^2, as the published kernels
+
+    def list_arguments(self):
+        return ["query", "key", "value", "a", "b", "A_log", "dt_bias"]
+
+    def infer_shape(self, in_shapes):
+        q = in_shapes[0]
+        if q is None:
+            raise MXNetError("GatedDeltaRule: query shape unknown")
+        h = self.num_heads
+        if q[1] != h * self.key_dim:
+            raise MXNetError("GatedDeltaRule: query width %d is not %d heads "
+                             "of %d" % (q[1], h, self.key_dim))
+        _sequences(q[0], self.seq_len, "GatedDeltaRule")
+        rows = q[0]
+        return ([q, q, (rows, h * self.value_dim), (rows, h), (rows, h),
+                 (h,), (h,)], [(rows, h * self.value_dim)], [])
+
+    def remat_results(self, in_shapes, in_types):
+        """Kept always under recomputation: the result, one activation of
+        the values' width."""
+        rows = in_shapes[0][0]
+        return [("output", rows * self.num_heads * self.value_dim
+                 * np.dtype(in_types[0]).itemsize, None)]
+
+    def apply(self, ctx, inputs, aux):
+        jax, jnp = _jax(), _jnp()
+        from .. import telemetry as _tel
+
+        f32 = jnp.float32
+        q, k, v, a, b, a_log, dt_bias = (x.astype(f32) for x in inputs)
+        t, h = self.seq_len, self.num_heads
+        n = q.shape[0] // t
+
+        def heads(x):
+            return x.reshape(n, t, h, -1)
+
+        def unit(x):
+            return x * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                + self.NORM_EPS)
+
+        q = unit(heads(q)) * (self.key_dim ** -0.5)
+        k, v = unit(heads(k)), heads(v)
+        g = (-jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)).reshape(n, t, h)
+        beta = jax.nn.sigmoid(b).reshape(n, t, h)
+        if self.neg_eigval:
+            beta = 2.0 * beta
+        pad = -t % self.chunk
+        if pad:
+            # past the end: decay 1, beta 0, no key: the state stands still
+            q, k, v, g, beta = (
+                jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                for x in (q, k, v, g, beta))
+        _tel.inc("lower.delta_rule_kernel.xla_chunked")
+        o = jax.lax.map(
+            lambda x: gated_delta_chunked(*x, chunk=self.chunk),
+            (q, k, v, g, beta))
+        o = o[:, :t].reshape(n * t, -1).astype(inputs[0].dtype)
+        return [ctx.keep(o, "output")], []

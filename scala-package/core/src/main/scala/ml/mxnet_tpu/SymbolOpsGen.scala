@@ -532,6 +532,38 @@ object SymbolOpsGen {
     mk("FullyConnected", name, ps.toSeq, ins.toSeq)
   }
 
+  def GatedDeltaRule(query: Symbol,
+      numHeads: Int,
+      keyDim: Int,
+      valueDim: Int,
+      seqLen: Int,
+      name: String = "",
+      key: Symbol = null,
+      value: Symbol = null,
+      a: Symbol = null,
+      b: Symbol = null,
+      ALog: Symbol = null,
+      dtBias: Symbol = null,
+      chunk: Int = 64,
+      negEigval: Boolean = false): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("chunk", chunk.toString))
+    ps += (("neg_eigval", negEigval.toString))
+    ps += (("num_heads", numHeads.toString))
+    ps += (("key_dim", keyDim.toString))
+    ps += (("value_dim", valueDim.toString))
+    ps += (("seq_len", seqLen.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("query", query))
+    if (key != null) ins += (("key", key))
+    if (value != null) ins += (("value", value))
+    if (a != null) ins += (("a", a))
+    if (b != null) ins += (("b", b))
+    if (ALog != null) ins += (("A_log", ALog))
+    if (dtBias != null) ins += (("dt_bias", dtBias))
+    mk("GatedDeltaRule", name, ps.toSeq, ins.toSeq)
+  }
+
   def IdentityAttachKLSparseReg(data: Symbol,
       name: String = "",
       sparsenessTarget: Float = 0.1f,
@@ -715,11 +747,15 @@ object SymbolOpsGen {
       gamma: Symbol = null,
       eps: Float = 1e-05f,
       numGroups: Int = 1,
-      gated: Boolean = false): Symbol = {
+      gated: Boolean = false,
+      gateAfter: Boolean = false,
+      sharedGamma: Boolean = false): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("eps", eps.toString))
     ps += (("num_groups", numGroups.toString))
     ps += (("gated", gated.toString))
+    ps += (("gate_after", gateAfter.toString))
+    ps += (("shared_gamma", sharedGamma.toString))
     val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
     ins += (("data", data))
     if (gamma != null) ins += (("gamma", gamma))
