@@ -19,6 +19,7 @@ that.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -30,7 +31,9 @@ __all__ = ["fused_linear", "flash_attention", "pallas_available",
            "pallas_call", "conv2d", "conv_dgrad", "conv_wgrad",
            "conv_backward_applicable", "fused_norm_act",
            "norm_act_applicable", "ssd_chunk_applicable",
-           "ssd_chunk_forward", "ssd_chunk_backward"]
+           "ssd_chunk_forward", "ssd_chunk_backward",
+           "delta_chunk_applicable", "delta_chunk_forward",
+           "delta_chunk_backward"]
 
 # float32 MXU-friendly tiles (sublane 8, lane 128)
 TILE_M = 128
@@ -1100,3 +1103,372 @@ def ssd_chunk_forward(*args, **static):
 def ssd_chunk_backward(*args, **static):
     """:func:`_ssd_chunk_backward` through its shared ``jax.jit``."""
     return _ssd_jitted()[1](*args, **static)
+
+
+# ---------------------------------------------------------------------------
+# Chunked gated delta rule (``ops/seq.py`` ``GatedDeltaRule``)
+# ---------------------------------------------------------------------------
+#
+# One grid step is one chunk of a few heads of one sequence, the chunk axis
+# innermost and sequential; see ``seq.gated_delta_chunked`` for the chunk's
+# WY form. The arrays are read head-major, ``[B, H, T, K]`` (a block's last
+# dimension is the array's own, so a head's width need not be whole 128-lane
+# tiles: 96 and 192 are not), the per-position scalars with the positions
+# along the lanes. Inside a step, all in VMEM and per head: the cumulative
+# log-decay and the masked decay matrix, ``K K^T`` and ``Q K^T``, ``A``, the
+# chunk's explicit ``T = (I + A)^-1``, ``u = T b (v - G K S)``, the output
+# ``G Q S + ((Q K^T) * decay) u`` and the state's update. Because the state
+# is at hand the system has ONE right-hand side. ``T`` comes from forward
+# substitution by columns (row j is final after j steps, and is taken off
+# the rows below it scaled by ``A``'s column j: L - 1 multiply-adds on the
+# VPU over the sublane tiles that still change), never from a power series;
+# the backward kernel reuses it transposed: with ``u = T R``, ``dR = T^T
+# du`` and ``dA = -tril(dR u^T, -1)``. What crosses chunks (the state, or in
+# the backward kernel its gradient) is a float32 VMEM scratch ``[heads, K,
+# V]``. Products that share their right operand are taken as one, their
+# left operands stacked by rows (``[Q; K] K^T``, ``[Q; K] S``, ``[dR; dO]
+# u^T``, ...): the MXU's weights are loaded once for 128 rows, not twice
+# for 64 (a twentieth of the backward kernel's time, none of the forward's:
+# at six passes a product the kernels are bound by the rows they stream).
+#
+# The differentiable function over them is ``seq.gated_delta_scan``. Its
+# residuals are the five float32 inputs and the float32 chunk-start states
+# ``[B, T/chunk, H, K, V]`` the forward kernel writes when asked; the
+# backward kernel forms everything else again. Under segment recomputation
+# the op so runs forward (no states), forward (with states), backward.
+#
+# Precision (both kernels): everything is float32, as in the XLA body. Every
+# ``dot`` takes float32 operands at ``Precision.HIGHEST`` (Mosaic's
+# ``contract_precision<fp32>``: six bfloat16 passes, never one) and
+# accumulates in float32; the cumulative sums, the decays, the
+# substitution, the carried state, its gradient and every accumulator are
+# float32. The decay's gradient is ONE float32 matrix ``E = dP * P + dA *
+# A`` inside the chunk, summed along its rows (at t) and its columns (at i,
+# negative). The caller rounds the output to the compute dtype once,
+# outside.
+
+# heads a grid step takes at most (three or five measure alike, one is a
+# tenth slower), and the float32 words it may hold by the count below: five
+# heads at 64 x (96 + 192) fit the 16 MB of scoped VMEM, fifteen do not
+_DELTA_HEADS = 5
+_DELTA_STEP_WORDS = 420_000
+
+
+def _delta_heads(heads, dk, dv, chunk):
+    """Heads a grid step takes: the most that divide ``heads`` evenly and
+    fit VMEM together, a head counted as its rows of q/k and v, four of the
+    chunk's square matrices and its state, each padded to whole lanes."""
+    def lanes(width):
+        return -(-width // 128) * 128
+
+    words = chunk * (lanes(dk) + lanes(dv) + 4 * lanes(chunk)) \
+        + dk * lanes(dv)
+    most = min(_DELTA_HEADS, max(1, _DELTA_STEP_WORDS // words))
+    return max(d for d in range(1, most + 1) if heads % d == 0)
+
+
+def delta_chunk_applicable(dims, chunk, dtype) -> bool:
+    """Whether the chunk kernels take ``dims = (H, K, V)``: float32
+    operands (the op computes in float32 whatever the compute dtype; a
+    bfloat16 operand is another result), a chunk of whole sublane tiles
+    that fits the lanes, and widths of whole sublanes up to one lane tile
+    of keys and two of values, so that a step's heads fit VMEM (a head's
+    width need NOT be whole lanes: a block's last dimension is the
+    array's)."""
+    _, dk, dv = dims
+    return (str(dtype) == "float32" and chunk % 8 == 0 and 8 <= chunk <= 128
+            and dk % 8 == 0 and dv % 8 == 0 and dk <= 128 and dv <= 256
+            and pallas_available())
+
+
+_DeltaChunk = collections.namedtuple(
+    "_DeltaChunk", "b_col e_col w_col e_last decay p kkd a t_inv qs ks z u")
+
+
+def _delta_chunk_tools(chunk):
+    """What the two kernels' bodies share: the products, the masks, a row
+    turned into a column, and the chunk's matrices from its inputs and
+    start states."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=f32)
+
+    nn = lambda a, b: dot(a, b, ((1,), (0,)))      # noqa: E731
+    nt = lambda a, b: dot(a, b, ((1,), (1,)))      # noqa: E731
+    tn = lambda a, b: dot(a, b, ((0,), (0,)))      # noqa: E731
+
+    def both(product, x, y, shared):
+        """``product(x, shared)``, ``product(y, shared)`` as one product of
+        ``[x; y]`` (both ``[chunk, .]``)."""
+        out = product(jnp.concatenate([x, y], axis=0), shared)
+        return out[:chunk], out[chunk:]
+
+    def tn_sum(x0, y0, x1, y1):
+        """``x0^T y0 + x1^T y1`` as one product over ``2 chunk`` rows."""
+        return tn(jnp.concatenate([x0, x1], axis=0),
+                  jnp.concatenate([y0, y1], axis=0))
+
+    def masks():
+        row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        return row >= col, row > col, row == col
+
+    def column(r, eye):
+        """``[1, L]`` -> ``[L, 1]``, exactly."""
+        return jnp.sum(jnp.where(eye, r, 0.0), axis=1, keepdims=True)
+
+    def row(c, eye):
+        """``[L, 1]`` -> ``[1, L]``, exactly."""
+        return jnp.sum(jnp.where(eye, c, 0.0), axis=0, keepdims=True)
+
+    def unit_lower_inverses(mats, eye):
+        """``(I + a)^-1`` for each strictly lower triangular ``a [L, L]`` of
+        ``mats`` by forward substitution: step j takes row j (final by
+        then) off the rows below it, by sublane tiles of 8 rows; a tile
+        whose rows are all above j + 1 has nothing left to change and is
+        skipped. The systems go in lockstep: one's chain of L - 1
+        dependent steps fills the others' waits (a head at a time, each
+        chain between its own products, cost a forward kernel 0.8 ms of
+        3.0 at the cell's shapes)."""
+        ident = jnp.where(eye, 1.0, 0.0).astype(f32)
+        xs = [[ident[r:r + 8] for r in range(0, chunk, 8)] for _ in mats]
+        for j in range(chunk - 1):
+            for a, tiles in zip(mats, xs):
+                done = tiles[j // 8][j % 8:j % 8 + 1, :]
+                for i in range((j + 1) // 8, chunk // 8):
+                    tiles[i] = tiles[i] - a[8 * i:8 * i + 8, j:j + 1] * done
+        return [jnp.concatenate(tiles, axis=0) for tiles in xs]
+
+    def parts(heads, masks3):
+        """A chunk's matrices for each head of ``heads`` (tuples ``q, k, v,
+        g_row, b_row, s``, ``s`` the start state): what the forward kernel
+        computes and the backward kernel forms again."""
+        lower, strict, eye = masks3
+        first = []
+        for q, k, _, g_row, b_row, _ in heads:
+            # the cumulative log-decay c as a column; exp(c_t - c_i), t >= i
+            c_col = jnp.sum(jnp.where(lower, g_row, 0.0), axis=1,
+                            keepdims=True)
+            decay = jnp.exp(jnp.where(lower, c_col - row(c_col, eye),
+                                      _SSD_MASKED))
+            b_col = column(b_row, eye)
+            last = c_col[chunk - 1:chunk, :]
+            e_col, w_col, e_last = (jnp.exp(c_col), jnp.exp(last - c_col),
+                                    jnp.exp(last))
+            qk, kk = both(nt, q, k, k)
+            p = qk * decay                                 # [t, i], t >= i
+            kkd = jnp.where(strict, kk * decay, 0.0)
+            first.append((b_col, e_col, w_col, e_last, decay, p, kkd,
+                          b_col * kkd))
+        inverses = unit_lower_inverses([f[-1] for f in first], eye)
+        out = []
+        for (q, k, v, _, _, s), f, t_inv in zip(heads, first, inverses):
+            b_col, e_col = f[0], f[1]
+            qs, ks = both(nn, q, k, s)
+            z = v - e_col * ks
+            out.append(_DeltaChunk(*f, t_inv, qs, ks, z,
+                                   nn(t_inv, b_col * z)))
+        return out
+
+    return nn, nt, tn, both, tn_sum, masks, column, row, parts
+
+
+def _delta_layout(q, v, chunk):
+    """The kernels' view of the op's arrays: heads before positions, the
+    scalars ``[B, H / hb, T / chunk, hb, chunk]``; and the block specs by
+    (sequence, head group, chunk) given the chunk order."""
+    from jax.experimental import pallas as pl
+
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    hb, nc = _delta_heads(h, dk, dv, chunk), t // chunk
+
+    def wide(x):
+        return x.transpose(0, 2, 1, 3)
+
+    def small(x):
+        return x.reshape(b, nc, chunk, h // hb, hb).transpose(0, 3, 1, 4, 2)
+
+    def specs(order):
+        def block(width):
+            return pl.BlockSpec((None, hb, chunk, width),
+                                lambda bi, hi, ci: (bi, hi, order(ci), 0))
+
+        scalars = pl.BlockSpec((None, None, None, hb, chunk),
+                               lambda bi, hi, ci: (bi, hi, order(ci), 0, 0))
+        states = pl.BlockSpec((None, None, hb, dk, dv),
+                              lambda bi, hi, ci: (bi, order(ci), hi, 0, 0))
+        return block(dk), block(dv), scalars, states
+
+    return (b, t, h, dk, dv, hb, nc), wide, small, specs
+
+
+def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
+    """The gated delta rule over sequences of whole chunks, one kernel call
+    (see the section's comment). ``q``, ``k [B, T, H, K]``, ``v [B, T, H,
+    V]``, ``g``, ``beta [B, T, H]``, all float32. Returns ``o [B, T, H,
+    V]`` and, ``with_states``, the float32 state at each chunk's start
+    ``[B, T/chunk, H, K, V]`` (else ``None``). Scratch: the carried state,
+    float32 ``[heads a step, K, V]``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    (b, t, h, dk, dv, hb, nc), wide, small, specs = _delta_layout(
+        q, v, chunk)
+    nn, _, tn, _, _, masks, _, _, parts = _delta_chunk_tools(chunk)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
+        st_ref = rest[-1]
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            st_ref[...] = jnp.zeros_like(st_ref)
+
+        masks3 = masks()
+        # unrolled over the step's heads
+        heads = [(q_ref[i], k_ref[i], v_ref[i], g_ref[i:i + 1, :],
+                  b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
+        for i, (head, c) in enumerate(zip(heads, parts(heads, masks3))):
+            kh, s = head[1], head[5]
+            if with_states:
+                rest[0][i] = s
+            o_ref[i] = c.e_col * c.qs + nn(c.p, c.u)
+            st_ref[i] = c.e_last * s + tn(kh * c.w_col, c.u)
+
+    keys, values, scalars, states = specs(lambda ci: ci)
+    out_shape = [jax.ShapeDtypeStruct((b, h, t, dv), f32)]
+    out_specs = [values]
+    if with_states:
+        out_shape.append(jax.ShapeDtypeStruct((b, nc, h, dk, dv), f32))
+        out_specs.append(states)
+    out = pallas_call(
+        kernel, wide(q), wide(k), wide(v), small(g), small(beta),
+        grid=(b, h // hb, nc),
+        in_specs=[keys, keys, values, scalars, scalars],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
+        compiler_params=_ssd_params(), name="delta_chunk_forward")
+    return out[0].transpose(0, 2, 1, 3), out[1] if with_states else None
+
+
+def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
+    """The mirror of :func:`_delta_chunk_forward` over the chunks reversed:
+    each chunk's matrices are formed again in VMEM from the inputs and the
+    chunk-start state, and the state's gradient rides a float32 scratch
+    ``[heads a step, K, V]``. ``do [B, T, H, V]``; returns ``dq``, ``dk``,
+    ``dv`` in the inputs' shapes and ``dg``, ``dbeta [B, T, H]``, all
+    float32."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    (b, t, h, dk, dv, hb, nc), wide, small, specs = _delta_layout(
+        q, v, chunk)
+    nn, nt, tn, both, tn_sum, masks, column, row, parts = \
+        _delta_chunk_tools(chunk)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
+               dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            ds_ref[...] = jnp.zeros_like(ds_ref)
+
+        masks3 = lower, strict, eye = masks()
+        at_last = jax.lax.broadcasted_iota(
+            jnp.int32, (chunk, 1), 0) == chunk - 1
+
+        def rows(x):
+            return jnp.sum(x, axis=1, keepdims=True)
+
+        # unrolled over the step's heads
+        heads = [(q_ref[i], k_ref[i], v_ref[i], g_ref[i:i + 1, :],
+                  b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
+        for i, (head, c) in enumerate(zip(heads, parts(heads, masks3))):
+            qh, kh, s, ds, dy = head[0], head[1], head[5], ds_ref[i], \
+                do_ref[i]
+            (b_col, e_col, w_col, e_last, decay, p, kkd, a, t_inv, qs, ks, z,
+             u) = c
+            # o = G Q S + P u, S_end = g_L S + (w k)^T u
+            du = tn(p, dy) + nn(kh * w_col, ds)
+            dr = tn(t_inv, du)                  # u = T R: dR = T^T du
+            da, dp = both(nt, dr, dy, u)        # dA = -dR u^T, below
+            da = -da
+            dz = b_col * dr                     # R = b (v - G K S)
+            dks = -e_col * dz
+            dyw = e_col * dy
+            uds = nt(u, ds)                     # d (w k)
+            g_kk = jnp.where(strict, da * b_col * decay, 0.0)
+            g_qk = dp * decay
+            at_q, at_k = both(nn, g_qk, g_kk, kh)
+            of_q, of_k = both(nt, dyw, dks, s)
+            dq_ref[i] = at_q + of_q
+            dk_ref[i] = at_k + tn_sum(g_kk, kh, g_qk, qh) + of_k \
+                + w_col * uds
+            dv_ref[i] = dz
+            db_ref[i:i + 1, :] = row(rows(dr * z) + rows(da * kkd), eye)
+            # the decay's gradient: ONE matrix by rows and by columns, then
+            # what the chunk's own scalings carry
+            e = dp * p + da * a
+            took = rows(dy * qs) * e_col + rows(dks * ks)   # d G, times G
+            gave = rows(uds * kh) * w_col                   # d w, times w
+            ends = jnp.sum(gave, axis=0, keepdims=True) \
+                + e_last * jnp.sum(rows(ds * s), axis=0, keepdims=True)
+            dc = rows(e) - column(jnp.sum(e, axis=0, keepdims=True), eye) \
+                + took - gave + jnp.where(at_last, ends, 0.0)
+            # g_j is in c_t for every t >= j of the chunk
+            dg_ref[i:i + 1, :] = jnp.sum(jnp.where(lower, dc, 0.0), axis=0,
+                                         keepdims=True)
+            ds_ref[i] = e_last * ds + tn_sum(kh, dks, qh, dyw)
+
+    keys, values, scalars, states = specs(lambda ci: nc - 1 - ci)
+    small_out = jax.ShapeDtypeStruct((b, h // hb, nc, hb, chunk), f32)
+    dq, dk_, dv_, dg, db = pallas_call(
+        kernel, wide(q), wide(k), wide(v), small(g), small(beta), starts,
+        wide(do),
+        grid=(b, h // hb, nc),
+        in_specs=[keys, keys, values, scalars, scalars, states, values],
+        out_specs=[keys, keys, values, scalars, scalars],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, dk), f32),
+                   jax.ShapeDtypeStruct((b, h, t, dk), f32),
+                   jax.ShapeDtypeStruct((b, h, t, dv), f32),
+                   small_out, small_out],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
+        compiler_params=_ssd_params(), name="delta_chunk_backward")
+
+    def by_position(x):
+        return x.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
+
+    return wide(dq), wide(dk_), wide(dv_), by_position(dg), by_position(db)
+
+
+@functools.lru_cache(None)
+def _delta_jitted():
+    """The two kernels' callers as ``jax.jit`` functions, made once, as
+    :func:`_ssd_jitted` and for its reason: a model's layers share shapes,
+    so a step traces and lowers each unrolled body once."""
+    import jax
+
+    return (jax.jit(_delta_chunk_forward,
+                    static_argnames=("chunk", "with_states")),
+            jax.jit(_delta_chunk_backward, static_argnames=("chunk",)))
+
+
+def delta_chunk_forward(*args, **static):
+    """:func:`_delta_chunk_forward` through its shared ``jax.jit``."""
+    return _delta_jitted()[0](*args, **static)
+
+
+def delta_chunk_backward(*args, **static):
+    """:func:`_delta_chunk_backward` through its shared ``jax.jit``."""
+    return _delta_jitted()[1](*args, **static)

@@ -700,7 +700,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
 
     ``q``, ``k [T, H, K]`` (normalised, ``q`` scaled), ``v [T, H, V]``,
     ``g [T, H]`` (log of the decay, <= 0), ``beta [T, H]``; T whole chunks.
-    Returns ``o [T, H, V]``."""
+    Returns ``o [T, H, V]`` and the state at each chunk's start ``[T/chunk,
+    H, K, V]``."""
     jax, jnp = _jax(), _jnp()
     from jax.scipy.linalg import solve_triangular
 
@@ -740,7 +741,42 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     o = jnp.exp(gc)[..., None] * jnp.einsum("nhld,nhdv->nhlv", q, starts,
                                             precision=hi) \
         + jnp.einsum("nhti,nhiv->nhtv", qk, u, precision=hi)
-    return jnp.moveaxis(o, 2, 1).reshape(t, h, dv)
+    return jnp.moveaxis(o, 2, 1).reshape(t, h, dv), starts
+
+
+def gated_delta_scan(q, k, v, g, beta, chunk, kernel):
+    """The gated delta rule over sequences of whole chunks as ONE
+    differentiable function, beside :func:`ssd_scan`: ``q``, ``k [B, T, H,
+    K]``, ``v [B, T, H, V]``, ``g``, ``beta [B, T, H]``, float32; returns
+    ``o [B, T, H, V]``. ``kernel`` picks the body: the Pallas chunk kernels
+    (``pallas_kernels.delta_chunk_forward`` / ``_backward``: the solve, the
+    chunk's matrices and the carried state stay in VMEM) with the backward
+    pass written out, its residuals the five inputs and the float32
+    chunk-start states ``[B, T/chunk, H, K, V]``; or
+    :func:`gated_delta_chunked` a sequence at a time under autodiff."""
+    jax = _jax()
+    from . import pallas_kernels
+
+    if not kernel:
+        return jax.lax.map(
+            lambda x: gated_delta_chunked(*x, chunk=chunk)[0],
+            (q, k, v, g, beta))
+
+    @jax.custom_vjp
+    def f(*args):
+        return pallas_kernels.delta_chunk_forward(
+            *args, chunk=chunk, with_states=False)[0]
+
+    def f_fwd(*args):
+        o, starts = pallas_kernels.delta_chunk_forward(
+            *args, chunk=chunk, with_states=True)
+        return o, args + (starts,)
+
+    def f_bwd(res, do):
+        return pallas_kernels.delta_chunk_backward(*res, do, chunk=chunk)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(q, k, v, g, beta)
 
 
 @register_op("GatedDeltaRule")
@@ -761,8 +797,22 @@ class GatedDeltaRule(Operator):
 
     The normalisation, the doubling and the decay are inside the op, in
     float32 like the state and the solve, whatever the compute dtype; the
-    result is rounded to it once. Counts ``lower.delta_rule_kernel.
-    xla_chunked`` when traced (the one body there is)."""
+    result is rounded to it once.
+
+    The recurrence's body (:func:`gated_delta_scan`) is chosen from the
+    shapes when the node is traced, as ``SSMScan`` and ``CausalAttention``
+    choose: one Pallas chunk kernel a pass where the chunk is whole sublane
+    tiles and a head's widths are whole sublanes up to 128 keys and 256
+    values (``lower.delta_rule_kernel.pallas_chunked``; 64 x 96 x 192 at 15
+    heads is such a shape), else the same chunked algorithm in
+    ``jax.numpy`` under autodiff (``lower.delta_rule_kernel.xla_chunked``).
+    In both, every operand and product is float32 (products at ``HIGHEST``:
+    six bfloat16 passes, never one), as are the decays, the solve, the
+    carried state, its gradient and every accumulator. The kernels' backward
+    pass is written out: it keeps the five float32 inputs and the float32
+    chunk-start states ``[B, T/chunk, H, K, V]`` and forms each chunk's
+    matrices again in VMEM, so under segment recomputation the op runs
+    forward, forward, backward."""
 
     name_hint = "gateddeltarule"
     PARAMS = {
@@ -803,6 +853,7 @@ class GatedDeltaRule(Operator):
     def apply(self, ctx, inputs, aux):
         jax, jnp = _jax(), _jnp()
         from .. import telemetry as _tel
+        from . import pallas_kernels
 
         f32 = jnp.float32
         q, k, v, a, b, a_log, dt_bias = (x.astype(f32) for x in inputs)
@@ -829,9 +880,10 @@ class GatedDeltaRule(Operator):
             q, k, v, g, beta = (
                 jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
                 for x in (q, k, v, g, beta))
-        _tel.inc("lower.delta_rule_kernel.xla_chunked")
-        o = jax.lax.map(
-            lambda x: gated_delta_chunked(*x, chunk=self.chunk),
-            (q, k, v, g, beta))
+        kernel = pallas_kernels.delta_chunk_applicable(
+            (h, self.key_dim, self.value_dim), self.chunk, q.dtype)
+        _tel.inc("lower.delta_rule_kernel.pallas_chunked" if kernel
+                 else "lower.delta_rule_kernel.xla_chunked")
+        o = gated_delta_scan(q, k, v, g, beta, self.chunk, kernel)
         o = o[:, :t].reshape(n * t, -1).astype(inputs[0].dtype)
         return [ctx.keep(o, "output")], []

@@ -26,6 +26,12 @@ TOY = dict(layer_types=["linear_attention", "linear_attention",
            hidden=32, vocab=96, heads=4, heads_held=2, first_head=0,
            head_dim=8, linear_key_dim=6, linear_value_dim=12, ffn_hidden=48,
            seq_len=24, chunk=16)
+# the delta rule at widths of whole sublanes: the Pallas chunk kernels (the
+# interpreter here); TOY's widths keep the XLA body
+SUBLANE = dict(TOY, linear_key_dim=8, linear_value_dim=16)
+BODIES = pytest.mark.parametrize(
+    "toy,body", [(TOY, "xla_chunked"), (SUBLANE, "pallas_chunked")],
+    ids=["xla", "pallas"])
 RECIPE = {"learning_rate": 0.001, "wd": 0.01, "beta1": 0.9, "beta2": 0.95,
           "epsilon": 1e-8, "rescale_grad": 1.0}
 
@@ -109,6 +115,7 @@ def toy_batches(n, batch=2, seed=11, toy=TOY):
 
 COUNTERS = ("step.dispatches", "step.fused_steps", "step.fused_fallback",
             "lower.delta_rule_kernel.xla_chunked",
+            "lower.delta_rule_kernel.pallas_chunked",
             "lower.attention_kernel.xla_blockwise", "remat.segments",
             "remat.segments_recomputed", "remat.kept_results")
 
@@ -137,18 +144,23 @@ def fit_toy(monkeypatch, batches, compute_dtype=None, toy=TOY, seed=5,
     return mod, params0, counters
 
 
-def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch):
+@BODIES
+def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch, toy,
+                                                         body):
     """Three Adam steps through ``Module.fit`` under recomputation against
     the benchmark's reference: the first gradient (Adam's first moment)
-    and the three-step change by leaf, one dispatch a step, one program."""
+    and the three-step change by leaf, one dispatch a step, one program;
+    with the delta rule's XLA body and with its chunk kernels."""
     batches = toy_batches(3)
-    mod, params0, counters = fit_toy(monkeypatch, batches)
+    mod, params0, counters = fit_toy(monkeypatch, batches, toy=toy)
     assert mod._fused_step_active
     assert counters["step.dispatches"] == 3
     assert counters["step.fused_steps"] == 3
     assert not counters["step.fused_fallback"]
     assert counters["jit_entries"] == 1
-    assert counters["lower.delta_rule_kernel.xla_chunked"] >= 3
+    other = {"xla_chunked", "pallas_chunked"} - {body}
+    assert counters["lower.delta_rule_kernel." + body] >= 3
+    assert not counters["lower.delta_rule_kernel." + other.pop()]
     assert counters["lower.attention_kernel.xla_blockwise"] >= 1
     # under recomputation the delta rule's result and attention's are kept
     assert counters["remat.segments_recomputed"] \
@@ -156,7 +168,7 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch):
     assert counters["remat.kept_results"] >= 3
     assert set(mod.get_params()[0]) == set(params0)
     assert not mod.get_params()[1]
-    want = ref.follow(TOY, RECIPE, params0,
+    want = ref.follow(toy, RECIPE, params0,
                       [(jnp.asarray(i), jnp.asarray(l)) for i, l in batches],
                       rows=np.arange(16).reshape(2, 8))
     got = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
@@ -170,7 +182,7 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch):
     assert all(n > 0 for n in want["delta_norms"].values())
     # the first gradient, from Adam's first moment after ONE step from a
     # zero state: m1 = (1 - b1) (g + wd w0)
-    mod, _, _ = fit_toy(monkeypatch, batches[:1])
+    mod, _, _ = fit_toy(monkeypatch, batches[:1], toy=toy)
     for i, name in enumerate(mod._param_names):
         m1 = mod._updater.states[i][0].asnumpy()
         g = m1 / (1.0 - RECIPE["beta1"]) - RECIPE["wd"] * params0[name]
@@ -179,7 +191,8 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch):
             <= 2e-3 * max(want["grad_norms"][name], 1e-3), name
 
 
-def test_model_loss_follows_the_reference(monkeypatch):
+@BODIES
+def test_model_loss_follows_the_reference(monkeypatch, toy, body):
     """Step by step: the metric's mean cross-entropy after each step."""
     batches = toy_batches(3, seed=12)
     losses = []
@@ -194,23 +207,26 @@ def test_model_loss_follows_the_reference(monkeypatch):
     it.metric = mx.metric.create("ce")
     monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
     params0 = {k: np.asarray(v) for k, v in ref.init_params(
-        TOY, jax.random.PRNGKey(6)).items()}
-    mod = mx.mod.Module(get_olmo_hybrid(**TOY), context=mx.cpu(0))
+        toy, jax.random.PRNGKey(6)).items()}
+    mod = mx.mod.Module(get_olmo_hybrid(**toy), context=mx.cpu(0))
     mod.fit(it, eval_metric=it.metric, optimizer="adam",
             optimizer_params=dict(RECIPE), initializer=None,
             arg_params={k: mx.nd.array(v) for k, v in params0.items()},
             num_epoch=1)
     per_step = np.diff([0.0] + losses + [it.metric.get()[1] * 3])
-    want = ref.follow(TOY, RECIPE, params0,
+    want = ref.follow(toy, RECIPE, params0,
                       [(jnp.asarray(i), jnp.asarray(l)) for i, l in batches],
                       rows=np.arange(8).reshape(2, 4))
     np.testing.assert_allclose(per_step, want["losses"], rtol=2e-4)
 
 
-def test_model_trains_in_bfloat16_with_float32_decays(monkeypatch):
+@BODIES
+def test_model_trains_in_bfloat16_with_float32_decays(monkeypatch, toy, body):
     batches = toy_batches(6, seed=13)
     mod, params0, counters = fit_toy(monkeypatch, batches,
-                                     compute_dtype="bfloat16")
+                                     compute_dtype="bfloat16", toy=toy)
+    # the op computes in float32 whatever the compute dtype: the same body
+    assert counters["lower.delta_rule_kernel." + body] >= 3
     assert counters["step.dispatches"] == 6
     assert not counters["step.fused_fallback"]
     assert counters["jit_entries"] == 1
@@ -260,8 +276,11 @@ def _share(params, kind, first, held, toy):
     return out
 
 
-@pytest.mark.parametrize("kind", ["linear_attention", "full_attention"])
-def test_head_shares_add_up_to_the_uncut_mixer(kind):
+@pytest.mark.parametrize("kind,toy", [
+    ("linear_attention", TOY), ("linear_attention", SUBLANE),
+    ("full_attention", TOY)],
+    ids=["linear_attention", "linear_attention-pallas", "full_attention"])
+def test_head_shares_add_up_to_the_uncut_mixer(kind, toy):
     """Two chips share a layer by heads: what each computes of ``Mixer(x)``
     from its half of the heads adds up to the uncut reference's. For the
     gated delta rule exactly, program and reference alike (a head sees only
@@ -270,7 +289,7 @@ def test_head_shares_add_up_to_the_uncut_mixer(kind):
     ``qk_ms``); with the mean square of the columns held, as the benchmark
     runs it without the exchange, the gap is printed. The feed-forward, the
     norms and the head are whole on every chip: nothing of them adds up."""
-    uncut = dict(TOY, layer_types=[kind], heads_held=4)
+    uncut = dict(toy, layer_types=[kind], heads_held=4)
     half = dict(uncut, heads_held=2)
     mix = {k: jnp.asarray(v) for k, v in ref.init_params(
         uncut, jax.random.PRNGKey(9)).items()
@@ -279,9 +298,9 @@ def test_head_shares_add_up_to_the_uncut_mixer(kind):
         for n in ("layer0_qnorm_gamma", "layer0_knorm_gamma"):
             mix[n] = 1.0 + 0.2 * jnp.asarray(rng_inputs(
                 3, g=mix[n].shape)["g"])
-    x = jnp.asarray(rng_inputs(7, x=(2 * TOY["seq_len"], TOY["hidden"]))["x"])
+    x = jnp.asarray(rng_inputs(7, x=(2 * toy["seq_len"], toy["hidden"]))["x"])
     whole = ref.mixer(mix, "layer0_", kind, x, uncut)
-    shares = [_share(mix, kind, first, 2, TOY) for first in (0, 2)]
+    shares = [_share(mix, kind, first, 2, toy) for first in (0, 2)]
     qk_ms = ref.qk_mean_squares(mix, "layer0_", x) \
         if kind == "full_attention" else None
     parts = [ref.mixer(s, "layer0_", kind, x, dict(half, first_head=f),
@@ -291,10 +310,10 @@ def test_head_shares_add_up_to_the_uncut_mixer(kind):
     # the program, each share built at the held heads' width
     build = model._linear_attention if kind == "linear_attention" \
         else model._full_attention
-    args = (TOY["seq_len"], 2, TOY["linear_key_dim"],
-            TOY["linear_value_dim"], 4, TOY["chunk"], True, TOY["hidden"],
+    args = (toy["seq_len"], 2, toy["linear_key_dim"],
+            toy["linear_value_dim"], 4, toy["chunk"], True, toy["hidden"],
             1e-6) if kind == "linear_attention" \
-        else (TOY["seq_len"], 2, TOY["head_dim"], TOY["hidden"], 1e-6)
+        else (toy["seq_len"], 2, toy["head_dim"], toy["hidden"], 1e-6)
     net = build(sym.Variable("x"), "layer0", *args)
     got = []
     for s in shares:

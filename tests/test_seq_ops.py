@@ -207,15 +207,28 @@ def plain_delta(query, key, value, a, b, A_log, dt_bias, t, h, neg_eigval):
     return o.reshape(n * t, -1)
 
 
+def delta_counters():
+    return tuple(telemetry.peek("lower.delta_rule_kernel." + k) or 0
+                 for k in ("pallas_chunked", "xla_chunked"))
+
+
+# widths of whole sublanes: the Pallas chunk kernels (the interpreter
+# here); anything else: the XLA body under autodiff
+TOY_DELTA = dict(h=3, dk=6, dv=10)
+SUBLANE_DELTA = dict(h=3, dk=8, dv=16)
+
+
+@pytest.mark.parametrize("body", ["xla", "pallas"])
 @pytest.mark.parametrize("t,chunk,neg_eigval", [
     (16, 16, True), (40, 16, True), (40, 16, False), (96, 32, True)],
     ids=["1chunk", "2.5chunks", "2.5chunks-beta-under-1", "3chunks"])
-def test_gated_delta_rule_against_recurrence(t, chunk, neg_eigval):
+def test_gated_delta_rule_against_recurrence(t, chunk, neg_eigval, body):
     """Two sequences, one of them not whole chunks: the output and the
     gradients of all seven arguments against ``jax.grad`` of the
-    recurrence, with ``b`` doubled and not; the traced node counts its
-    lowering."""
-    h, dk, dv = 3, 6, 10
+    recurrence, with ``b`` doubled and not, for both bodies; the traced
+    node counts the lowering its shapes chose, once."""
+    shape = SUBLANE_DELTA if body == "pallas" else TOY_DELTA
+    h, dk, dv = shape["h"], shape["dk"], shape["dv"]
     inputs = delta_inputs(1, 2 * t, h, dk, dv)
     telemetry.reset()
     telemetry.enable()
@@ -224,7 +237,7 @@ def test_gated_delta_rule_against_recurrence(t, chunk, neg_eigval):
                                          **kw),
                 delta_net(t, h, dk, dv, chunk, neg_eigval), inputs, seed=3,
                 tol=5e-5)
-        assert telemetry.peek("lower.delta_rule_kernel.xla_chunked") >= 1
+        assert delta_counters() == ((1, 0) if body == "pallas" else (0, 1))
     finally:
         telemetry.disable()
     # the doubling is inside the operator: the two forms differ
@@ -234,16 +247,18 @@ def test_gated_delta_rule_against_recurrence(t, chunk, neg_eigval):
     assert float(jnp.abs(doubled - plain).max()) > 1e-2
 
 
-def test_gated_delta_rule_gradients_hold_along_a_long_sequence():
+@pytest.mark.parametrize("dk,dv,body", [(6, 10, "xla"), (8, 16, "pallas")])
+def test_gated_delta_rule_gradients_hold_along_a_long_sequence(dk, dv, body):
     """2,048 positions of bfloat16 inputs at the chunk the model uses,
     decays near 1 so that the state lives through the whole sequence: the
     decay's parameters sum their gradient over every position (the
     gradient PR 27 found 82% off on the chip when it was formed as a
     difference of two bfloat16 products), and every argument's gradient
-    stays with float32 autodiff of the recurrence."""
+    stays with float32 autodiff of the recurrence. Both bodies, the
+    kernels' at the smallest widths their rule admits."""
     from mxnet_tpu.executor import make_graph_eval
 
-    t, h, dk, dv, chunk = 2048, 2, 8, 16, 64
+    t, h, chunk = 2048, 2, 64
     f32 = delta_inputs(5, t, h, dk, dv, a_shift=-2.0)
     inputs = {k: jnp.asarray(v, jnp.float32 if k in ("A_log", "dt_bias")
                              else jnp.bfloat16) for k, v in f32.items()}
@@ -256,7 +271,13 @@ def test_gated_delta_rule_gradients_hold_along_a_long_sequence():
     def run(fl):
         return eval_graph([fl[k] for k in names], [], None, True)[0][0]
 
-    got_o = run(inputs)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        got_o = run(inputs)
+        assert delta_counters() == ((1, 0) if body == "pallas" else (0, 1))
+    finally:
+        telemetry.disable()
     assert got_o.dtype == jnp.bfloat16
     got = jax.jit(jax.grad(lambda fl: jnp.sum(
         run(fl).astype(jnp.float32) * head)))(inputs)
@@ -273,6 +294,93 @@ def test_gated_delta_rule_gradients_hold_along_a_long_sequence():
         close(got[k], want[k], 2e-3)
     for k in ("query", "key", "value", "a", "b"):
         close(got[k].astype(jnp.float32), want[k], 2e-2)
+
+
+@pytest.mark.parametrize("dims,chunk,dtype,takes", [
+    ((15, 96, 192), 64, "float32", True),       # the cell's own shapes
+    ((2, 8, 16), 64, "float32", True),
+    ((15, 96, 192), 64, "bfloat16", False),     # another result
+    ((15, 96, 192), 64, "float16", False),
+    ((15, 96, 192), 60, "float32", False),      # not whole sublane tiles
+    ((15, 96, 192), 256, "float32", False),     # wider than the lanes
+    ((4, 128, 256), 128, "float32", True),      # the widest
+    ((4, 136, 256), 64, "float32", False),      # keys past one lane tile
+    ((4, 128, 264), 64, "float32", False),      # values past two
+    ((3, 6, 10), 16, "float32", False)],        # toy widths
+    ids=["cell", "smallest", "bfloat16", "float16", "chunk60", "chunk256",
+         "widest", "keys136", "values264", "toy"])
+def test_delta_chunk_applicable(dims, chunk, dtype, takes):
+    from mxnet_tpu.ops import pallas_kernels
+
+    assert pallas_kernels.delta_chunk_applicable(
+        dims, chunk, jnp.dtype(dtype)) is takes
+
+
+def delta_scan_args(seed, b, t, h, dk, dv):
+    """What ``GatedDeltaRule.apply`` hands the scan: unit keys, scaled unit
+    queries, log-decays <= 0, beta in (0, 2); float32."""
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    return (unit(normal(b, t, h, dk)) * dk ** -0.5, unit(normal(b, t, h, dk)),
+            normal(b, t, h, dv), -jax.nn.softplus(normal(b, t, h) - 2.0),
+            2.0 * jax.nn.sigmoid(normal(b, t, h)))
+
+
+def test_gated_delta_scan_traces_two_kernels_and_no_scan():
+    """``jax.grad`` through the kernel body holds exactly the forward and
+    the backward ``pallas_call`` (each twice: the interpreter's and
+    Mosaic's branch), every VMEM scratch float32, and neither a ``scan``
+    nor a ``triangular_solve``; the XLA body holds both and no kernel."""
+    from test_nemotron_h import _sub_jaxprs
+
+    from mxnet_tpu.ops import seq
+
+    args = delta_scan_args(0, 1, 128, 2, 8, 16)
+    for kernel in (True, False):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+            seq.gated_delta_scan(*a, 64, kernel)), argnums=range(5)))(*args)
+        names, scratch, prims = [], [], set()
+        for sub in _sub_jaxprs(jaxpr.jaxpr):
+            for eqn in sub.eqns:
+                prims.add(eqn.primitive.name)
+                if eqn.primitive.name == "pallas_call":
+                    names.append(eqn.params["name"])
+                    scratch += [a.dtype for a in
+                                eqn.params["grid_mapping"].scratch_avals]
+        if kernel:
+            assert sorted(names) == ["delta_chunk_backward"] * 2 \
+                + ["delta_chunk_forward"] * 2, names
+            assert len(scratch) == 4 and all(
+                d == jnp.float32 for d in scratch), scratch
+            assert not prims & {"scan", "triangular_solve", "while"}, prims
+        else:
+            assert not names and {"scan", "triangular_solve"} <= prims
+
+
+def test_delta_chunk_forward_returns_the_chunk_start_states():
+    """The residual the backward kernel reads: float32 ``[B, T/chunk, H,
+    K, V]``, equal to the XLA body's carried states, beside an equal
+    output; without them the kernel returns ``None``."""
+    from mxnet_tpu.ops import pallas_kernels, seq
+
+    args = delta_scan_args(1, 2, 192, 3, 8, 16)
+    o, starts = pallas_kernels.delta_chunk_forward(*args, chunk=64,
+                                                   with_states=True)
+    assert starts.dtype == jnp.float32 and starts.shape == (2, 3, 3, 8, 16)
+    assert not np.asarray(starts[:, 0]).any()
+    for i in range(2):
+        want_o, want = seq.gated_delta_chunked(*(a[i] for a in args),
+                                               chunk=64)
+        close(starts[i], want, 5e-6)
+        close(o[i], want_o, 5e-6)
+    assert pallas_kernels.delta_chunk_forward(
+        *args, chunk=64, with_states=False)[1] is None
 
 
 def test_gated_delta_rule_shapes():
