@@ -48,24 +48,28 @@ SPLASH_BLOCK = 512
 _NEG = -1e30
 
 
-def rope(x, theta, scale=1.0):
-    """Rotary position embedding over the whole head (Su et al.,
-    arXiv:2104.09864), the half-split convention of the published
-    modelling code: ``x [T, ..., D]``, position = index along axis 0. The
-    result is multiplied by ``scale`` before it is rounded to ``x``'s
-    dtype."""
+def rope(x, theta, scale=1.0, rotary_dim=0):
+    """Rotary position embedding (Su et al., arXiv:2104.09864), the
+    half-split convention of the published modelling code: ``x [T, ...,
+    D]``, position = index along axis 0, over the whole head or, with
+    ``rotary_dim``, over the LAST ``rotary_dim`` columns of it (the
+    frequencies are those of a head ``rotary_dim`` wide; the columns before
+    pass through). The result is multiplied by ``scale`` before it is
+    rounded to ``x``'s dtype."""
     import jax.numpy as jnp
 
     t, d = x.shape[0], x.shape[-1]
-    half = d // 2
+    keep = d - rotary_dim if rotary_dim else 0
+    half = (d - keep) // 2
     inv = theta ** (-np.arange(half, dtype=np.float64) / half)
     ang = np.arange(t, dtype=np.float64)[:, None] * inv[None]
     shape = (t,) + (1,) * (x.ndim - 2) + (half,)
     cos = jnp.asarray(np.cos(ang), jnp.float32).reshape(shape)
     sin = jnp.asarray(np.sin(ang), jnp.float32).reshape(shape)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+    x1, x2 = xf[..., keep:keep + half], xf[..., keep + half:]
+    out = jnp.concatenate(([xf[..., :keep]] if keep else [])
+                          + [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
     return (out * scale if scale != 1.0 else out).astype(x.dtype)
 
@@ -145,7 +149,9 @@ class CausalAttention(Operator):
     """``softmax(q k^T / sqrt(D) + causal mask) v`` per head, ``num_heads``
     query heads sharing ``num_kv_heads`` key/value heads (Ainslie et al.,
     GQA, arXiv:2305.13245) without repeating keys. ``rotary`` applies
-    rotary positions to queries and keys first."""
+    rotary positions to queries and keys first: over the whole head, or
+    over its last ``rotary_dim`` columns (a head that is part content,
+    part position: latent attention's 192 + 64)."""
 
     name_hint = "causalattention"
     PARAMS = {
@@ -155,6 +161,8 @@ class CausalAttention(Operator):
         "seq_len": Param(int, REQUIRED),
         "rotary": Param(bool, True),
         "rope_theta": Param(float, 10000.0),
+        "rotary_dim": Param(int, 0, "rotate the last rotary_dim columns of "
+                            "each head only; 0: the whole head"),
     }
 
     def list_arguments(self):
@@ -168,6 +176,9 @@ class CausalAttention(Operator):
             raise MXNetError("CausalAttention: %d query heads do not share "
                              "%d key/value heads evenly"
                              % (self.num_heads, self.num_kv_heads))
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
+            raise MXNetError("CausalAttention: rotary_dim %d of a head of %d"
+                             % (self.rotary_dim, self.head_dim))
         if q[1] != self.num_heads * self.head_dim:
             raise MXNetError("CausalAttention: query width %d is not %d "
                              "heads of %d" % (q[1], self.num_heads,
@@ -210,8 +221,10 @@ class CausalAttention(Operator):
             # the rotation, before the one rounding to the compute dtype
             q = jax.vmap(functools.partial(
                 rope, theta=self.rope_theta,
-                scale=scale if splash else 1.0))(q)
-            k = jax.vmap(functools.partial(rope, theta=self.rope_theta))(k)
+                scale=scale if splash else 1.0,
+                rotary_dim=self.rotary_dim))(q)
+            k = jax.vmap(functools.partial(rope, theta=self.rope_theta,
+                                           rotary_dim=self.rotary_dim))(k)
         elif splash:
             q = (q.astype("float32") * scale).astype(q.dtype)
         if splash:

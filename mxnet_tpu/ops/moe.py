@@ -25,6 +25,13 @@ float32 at ``highest`` matmul precision whatever the compute dtype: the
 choice of experts is a discontinuous function of the scores. The expert
 products take inputs in the compute dtype and accumulate in float32.
 
+**Two expert bodies**, by ``gated``: ``W_down relu(W_up x)^2`` (the
+default; :func:`grouped_experts`) and the gated ``W_down (silu(W_gate x) *
+W_up x)`` with a third stacked weight (:func:`grouped_experts_gated`), each
+with both passes written out. Routing, layout, balancing and the counters
+are one; which body a traced node took is counted
+(``lower.experts_body.relu2`` / ``lower.experts_body.swiglu``).
+
 **The selection bias is a state, not a weight.** ``select_bias`` (float32,
 ``num_experts``) is added to the scores for the choice only and no gradient
 reaches it; the family balances its experts by moving it after every
@@ -55,9 +62,15 @@ from .registry import Operator, Param, REQUIRED, register_op
 BLOCK_ROWS = 512
 
 
-def block_rows(rows):
-    """Rows a block of the layout holds, for ``rows`` tokens."""
-    return min(BLOCK_ROWS, max(8, rows // 8))
+def block_rows(rows, top_k=0, num_experts=1):
+    """Rows a block of the layout holds, for ``rows`` tokens: an eighth of
+    them, up to ``BLOCK_ROWS`` or, where that is more, an evenly loaded
+    expert's share (``rows x top_k / num_experts``) and a quarter, in whole
+    128s. A balanced layer then runs ONE block an expert: at a cap of just
+    the even share (512 of 512, latent-attention cell, PR 32) every expert
+    that draws a row over the mean pads a second block."""
+    even = -(-5 * rows * top_k // (4 * num_experts * 128)) * 128
+    return min(max(BLOCK_ROWS, even), max(8, rows // 8))
 
 
 def layout_length(rows, top_k, num_held, block):
@@ -141,6 +154,15 @@ def plan(eid, wts, first_held, num_held, block):
             e_c[::block], pend[-1] // block, dropped)
 
 
+def _take_block(b, block, rows, weights, block_expert):
+    """Block ``b`` of the layout: its expert, its rows, their weights."""
+    import jax
+
+    r = jax.lax.dynamic_slice(rows, (b * block,), (block,))
+    w = jax.lax.dynamic_slice(weights, (b * block,), (block,))
+    return block_expert[b], r, w
+
+
 def _expert_block(xb, w_up_e, cd):
     import jax.numpy as jnp
 
@@ -165,15 +187,10 @@ def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
     cd = x.dtype
     block = rows.shape[0] // block_expert.shape[0]
 
-    def take(b, rows, weights, block_expert):
-        r = jax.lax.dynamic_slice(rows, (b * block,), (block,))
-        w = jax.lax.dynamic_slice(weights, (b * block,), (block,))
-        return block_expert[b], r, w
-
     def forward(x, w_up, w_down, wts, rows, weights, slot, block_expert,
                 nblocks):
         def body(b, out):
-            e, r, w = take(b, rows, weights, block_expert)
+            e, r, w = _take_block(b, block, rows, weights, block_expert)
             _, a = _expert_block(x[r], w_up[e], cd)
             o = jnp.dot(a, w_down[e], preferred_element_type=f32)
             return out.at[r].add(o * w[:, None])
@@ -193,7 +210,7 @@ def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
 
         def body(b, carry):
             dx, dwu, dwd, dwt = carry
-            e, r, w = take(b, rows, weights, block_expert)
+            e, r, w = _take_block(b, block, rows, weights, block_expert)
             xb, dyb = x[r], dy[r]
             relu, a = _expert_block(xb, w_up[e], cd)
             # d(result)/d(a), before the slot's weight
@@ -221,10 +238,90 @@ def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
              nblocks)
 
 
+def _swiglu_block(xb, w_gate_e, w_up_e, cd):
+    import jax
+    import jax.numpy as jnp
+
+    g = jnp.dot(xb, w_gate_e, preferred_element_type=jnp.float32)
+    u = jnp.dot(xb, w_up_e, preferred_element_type=jnp.float32)
+    return g, u, (jax.nn.silu(g) * u).astype(cd)
+
+
+def grouped_experts_gated(x, w_gate, w_up, w_down, wts, rows, weights, slot,
+                          block_expert, nblocks):
+    """:func:`grouped_experts` with the gated body: ``y[r] = sum_j wts[r, j]
+    * W_down[e] (silu(W_gate[e] x[r]) * W_up[e] x[r])``, ``w_gate`` stacked
+    like ``w_up``. Differentiable in ``x``, the three expert weights and
+    ``wts``."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    cd = x.dtype
+    block = rows.shape[0] // block_expert.shape[0]
+
+    def forward(x, w_gate, w_up, w_down, wts, rows, weights, slot,
+                block_expert, nblocks):
+        def body(b, out):
+            e, r, w = _take_block(b, block, rows, weights, block_expert)
+            _, _, a = _swiglu_block(x[r], w_gate[e], w_up[e], cd)
+            o = jnp.dot(a, w_down[e], preferred_element_type=f32)
+            return out.at[r].add(o * w[:, None])
+
+        return jax.lax.fori_loop(0, nblocks, body,
+                                 jnp.zeros(x.shape, f32)).astype(cd)
+
+    f = jax.custom_vjp(forward)
+
+    def f_fwd(*args):
+        return forward(*args), args
+
+    def f_bwd(res, dy):
+        (x, w_gate, w_up, w_down, wts, rows, weights, slot, block_expert,
+         nblocks) = res
+
+        def body(b, carry):
+            dx, dwg, dwu, dwd, dwt = carry
+            e, r, w = _take_block(b, block, rows, weights, block_expert)
+            xb, dyb = x[r], dy[r]
+            g, u, a = _swiglu_block(xb, w_gate[e], w_up[e], cd)
+            # d(result)/d(a), before the slot's weight
+            da = jnp.dot(dyb, w_down[e].T, preferred_element_type=f32)
+            dwt = jax.lax.dynamic_update_slice(
+                dwt, jnp.sum(a.astype(f32) * da, axis=1), (b * block,))
+            dyw = (dyb.astype(f32) * w[:, None]).astype(cd)
+            dwd = dwd.at[e].add(jnp.dot(a.T, dyw, preferred_element_type=f32))
+            daw = da * w[:, None]
+            sig = jax.nn.sigmoid(g)
+            # silu(g) = g sig(g); its slope sig (1 + g (1 - sig))
+            dg = (daw * u * sig * (1.0 + g * (1.0 - sig))).astype(cd)
+            du = (daw * g * sig).astype(cd)
+            dwg = dwg.at[e].add(jnp.dot(xb.T, dg, preferred_element_type=f32))
+            dwu = dwu.at[e].add(jnp.dot(xb.T, du, preferred_element_type=f32))
+            dxb = jnp.dot(dg, w_gate[e].T, preferred_element_type=f32) \
+                + jnp.dot(du, w_up[e].T, preferred_element_type=f32)
+            return dx.at[r].add(dxb), dwg, dwu, dwd, dwt
+
+        dx, dwg, dwu, dwd, dwt = jax.lax.fori_loop(
+            0, nblocks, body,
+            (jnp.zeros(x.shape, f32), jnp.zeros(w_gate.shape, f32),
+             jnp.zeros(w_up.shape, f32), jnp.zeros(w_down.shape, f32),
+             jnp.zeros(weights.shape, f32)))
+        dwts = jnp.take(dwt, slot, mode="fill", fill_value=0)
+        return (dx.astype(cd), dwg.astype(w_gate.dtype),
+                dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), dwts,
+                None, None, None, None, None)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(x, w_gate, w_up, w_down, wts, rows, weights, slot, block_expert,
+             nblocks)
+
+
 @register_op("RoutedExperts")
 class RoutedExperts(Operator):
     """The held experts' part of a routed-expert layer (see the module's
-    docstring). Experts are ``W_down relu(W_up x)^2``, no gate, no bias."""
+    docstring). Experts are ``W_down relu(W_up x)^2``, or with ``gated``
+    ``W_down (silu(W_gate x) * W_up x)``; no bias."""
 
     name_hint = "routedexperts"
     PARAMS = {
@@ -237,12 +334,15 @@ class RoutedExperts(Operator):
         "bias_update_rate": Param(float, 0.0, "what a training step moves "
                                   "each expert's selection bias by, "
                                   "against its load"),
+        "gated": Param(bool, False, "experts W_down (silu(W_gate x) * W_up "
+                       "x), a third stacked weight gate_weight"),
     }
     # arguments that reach the op in their own dtype under mixed precision
     full_precision_args = ("router_weight",)
 
     def list_arguments(self):
-        return ["data", "router_weight", "up_weight", "down_weight"]
+        gate = ["gate_weight"] if self.gated else []
+        return ["data", "router_weight"] + gate + ["up_weight", "down_weight"]
 
     def list_auxiliary_states(self):
         return ["expert_rows", "select_bias"]
@@ -258,7 +358,8 @@ class RoutedExperts(Operator):
                              % (self.first_held, self.first_held + held, e,
                                 self.top_k))
         h, f = data[1], self.num_hidden
-        return ([data, (h, e), (held, h, f), (held, f, h)], [data],
+        up = [(held, h, f)] * (2 if self.gated else 1)
+        return ([data, (h, e)] + up + [(held, f, h)], [data],
                 [(e + 1,), (e,)])
 
     def infer_type(self, in_types, out_types=None):
@@ -276,7 +377,7 @@ class RoutedExperts(Operator):
         import numpy as np
 
         rows, h = in_shapes[0]
-        block = block_rows(rows)
+        block = block_rows(rows, self.top_k, self.num_experts)
         length = layout_length(rows, self.top_k, self.num_held, block)
         # ids, weights and slots a pair; row and weight a slot; an expert
         # a block; the count of blocks
@@ -289,18 +390,24 @@ class RoutedExperts(Operator):
         import jax
         import jax.numpy as jnp
 
-        x, router, w_up, w_down = inputs
+        from .. import telemetry as _tel
+
+        x, router, *w_experts = inputs
         counted, bias = aux
         e = self.num_experts
         keep = functools.partial(ctx.keep, result="routing")
         eid, wts = route(x, router, bias, self.top_k, self.scale, keep)
         *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
-                                block_rows(x.shape[0]))
+                                block_rows(x.shape[0], self.top_k,
+                                           self.num_experts))
         wts, rows, weights, slot, block_expert, nblocks = keep(
             (wts, *layout))
-        y = grouped_experts(x, w_up, w_down, wts,
-                            rows, jax.lax.stop_gradient(weights), slot,
-                            block_expert, nblocks)
+        _tel.inc("lower.experts_body.%s"
+                 % ("swiglu" if self.gated else "relu2"))
+        body = grouped_experts_gated if self.gated else grouped_experts
+        y = body(x, *w_experts, wts,
+                 rows, jax.lax.stop_gradient(weights), slot,
+                 block_expert, nblocks)
         y = ctx.keep(y, "output")
         load = jnp.zeros((e,), jnp.int32).at[eid.reshape(-1)].add(1)
         if ctx.is_train and self.bias_update_rate:

@@ -227,10 +227,12 @@ object SymbolOpsGen {
       key: Symbol = null,
       value: Symbol = null,
       rotary: Boolean = true,
-      ropeTheta: Float = 10000.0f): Symbol = {
+      ropeTheta: Float = 10000.0f,
+      rotaryDim: Int = 0): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("rotary", rotary.toString))
     ps += (("rope_theta", ropeTheta.toString))
+    ps += (("rotary_dim", rotaryDim.toString))
     ps += (("num_heads", numHeads.toString))
     ps += (("num_kv_heads", numKvHeads.toString))
     ps += (("head_dim", headDim.toString))
@@ -821,11 +823,13 @@ object SymbolOpsGen {
       downWeight: Symbol = null,
       firstHeld: Int = 0,
       scale: Float = 1.0f,
-      biasUpdateRate: Float = 0.0f): Symbol = {
+      biasUpdateRate: Float = 0.0f,
+      gated: Boolean = false): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("first_held", firstHeld.toString))
     ps += (("scale", scale.toString))
     ps += (("bias_update_rate", biasUpdateRate.toString))
+    ps += (("gated", gated.toString))
     ps += (("num_experts", numExperts.toString))
     ps += (("num_held", numHeld.toString))
     ps += (("top_k", topK.toString))

@@ -1,0 +1,20 @@
+"""The third language model's rehearsals, run with every PR.
+
+`benchmark/tests/test_fit_lm_glm.py` under the driver's `pytest tests/`: the
+`fit_lm_ref` driver end to end at toy width against the `glm4_moe_lite`
+reference (latent attention, gated routed experts, the balanced start inside
+`init_params`), the runs `correct` must refuse, the controls, and the two new
+per-layer readers over one shared traced run. Each case shows under its own
+name. A file of its own, so that `--dist loadfile` gives it a worker beside
+the one that takes `test_glm4_moe_lite.py`.
+"""
+import os
+import sys
+
+from dist_util import REPO
+
+for _path in (REPO, os.path.join(REPO, "benchmark", "tests")):
+    if _path not in sys.path:
+        sys.path.insert(0, _path)
+
+from test_fit_lm_glm import *   # noqa: E402,F401,F403
