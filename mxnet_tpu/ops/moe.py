@@ -57,6 +57,7 @@ from __future__ import annotations
 import functools
 
 from ..base import MXNetError
+from .pallas_kernels import grouped_experts_applicable
 from .registry import Operator, Param, REQUIRED, register_op
 
 BLOCK_ROWS = 512
@@ -317,6 +318,44 @@ def grouped_experts_gated(x, w_gate, w_up, w_down, wts, rows, weights, slot,
              nblocks)
 
 
+def grouped_experts_kernel(x, w_experts, wts, rows, weights, slot,
+                           block_expert, nblocks, gated):
+    """:func:`grouped_experts` (``w_experts = (w_up, w_down)``) or
+    :func:`grouped_experts_gated` (``(w_gate, w_up, w_down)``) with each
+    pass three Pallas kernels over the filled blocks (rows gathered,
+    products, rows added back: ``pallas_kernels.grouped_experts_forward`` /
+    ``_backward``) where the loops run a block a step: the same products at
+    the same precisions, the weight gradients' float32 sums in VMEM an
+    expert at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import pallas_kernels as pk
+
+    def forward(x, w_experts, wts, rows, weights, slot, block_expert,
+                nblocks):
+        return pk.grouped_experts_forward(
+            x, w_experts, rows, weights, block_expert,
+            jnp.reshape(nblocks, (1,)), gated=gated)
+
+    f = jax.custom_vjp(forward)
+
+    def f_fwd(*args):
+        return forward(*args), args
+
+    def f_bwd(res, dy):
+        x, w_experts, wts, rows, weights, slot, block_expert, nblocks = res
+        dx, dws, dwt = pk.grouped_experts_backward(
+            x, w_experts, rows, weights, block_expert,
+            jnp.reshape(nblocks, (1,)), dy, gated=gated)
+        dwts = jnp.take(dwt, slot, mode="fill", fill_value=0)
+        return (dx, dws, dwts, None, None, None, None, None)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(x, tuple(w_experts), wts, rows, weights, slot, block_expert,
+             nblocks)
+
+
 @register_op("RoutedExperts")
 class RoutedExperts(Operator):
     """The held experts' part of a routed-expert layer (see the module's
@@ -397,17 +436,25 @@ class RoutedExperts(Operator):
         e = self.num_experts
         keep = functools.partial(ctx.keep, result="routing")
         eid, wts = route(x, router, bias, self.top_k, self.scale, keep)
+        block = block_rows(x.shape[0], self.top_k, self.num_experts)
         *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
-                                block_rows(x.shape[0], self.top_k,
-                                           self.num_experts))
+                                block)
         wts, rows, weights, slot, block_expert, nblocks = keep(
             (wts, *layout))
         _tel.inc("lower.experts_body.%s"
                  % ("swiglu" if self.gated else "relu2"))
-        body = grouped_experts_gated if self.gated else grouped_experts
-        y = body(x, *w_experts, wts,
-                 rows, jax.lax.stop_gradient(weights), slot,
-                 block_expert, nblocks)
+        layout = (wts, rows, jax.lax.stop_gradient(weights), slot,
+                  block_expert, nblocks)
+        # one Pallas kernel a pass where the shapes are whole tiles that
+        # fit VMEM, else a loop of XLA products, a block a step
+        if grouped_experts_applicable(x.shape[1], self.num_hidden, block,
+                                      x.dtype, self.gated, x.shape[0]):
+            _tel.inc("lower.experts_kernel.pallas_grouped")
+            y = grouped_experts_kernel(x, w_experts, *layout, self.gated)
+        else:
+            _tel.inc("lower.experts_kernel.xla_loop")
+            body = grouped_experts_gated if self.gated else grouped_experts
+            y = body(x, *w_experts, *layout)
         y = ctx.keep(y, "output")
         load = jnp.zeros((e,), jnp.int32).at[eid.reshape(-1)].add(1)
         if ctx.is_train and self.bias_update_rate:

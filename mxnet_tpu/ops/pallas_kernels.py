@@ -1472,3 +1472,621 @@ def delta_chunk_forward(*args, **static):
 def delta_chunk_backward(*args, **static):
     """:func:`_delta_chunk_backward` through its shared ``jax.jit``."""
     return _delta_jitted()[1](*args, **static)
+
+
+# ---------------------------------------------------------------------------
+# Grouped expert products (``ops/moe.py`` ``RoutedExperts``)
+# ---------------------------------------------------------------------------
+#
+# A pass over the blocks of ``moe.plan``'s layout is three kernel calls, none
+# of them a loop of XLA ops: the block's rows are GATHERED into layout order,
+# the PRODUCTS run over contiguous blocks, and the weighted results are
+# SCATTER-ADDED back onto their rows. A block is ``block`` slots of ONE
+# expert, the layout is sorted by expert, and only the first ``nblocks``
+# (traced) of the static ``L / block`` blocks are filled. Every grid has the
+# blocks as a sequential axis; a step at or past ``nblocks`` does nothing,
+# and its index maps are clamped to the last step that did, so it fetches
+# nothing new. ``block_expert``, ``nblocks`` and, in the row kernels, the
+# slots' rows are scalar-prefetched (SMEM).
+#
+# Rows in and out (``_gather_rows`` / ``_scatter_rows``). One DMA a row is
+# bound by the descriptors (60 ns a row on a v5e: two thirds of a forward
+# block's time, my chip runs, PR 33), so the rows move on the vector unit
+# instead: the gather holds the whole ``[S, w]`` table in VMEM (copied in
+# once a call) and copies a slot's row from it by the prefetched row; the
+# scatter holds the float32 ``[S, w]`` result in VMEM, adds each slot's row
+# onto it, and rounds it out once, at the end. Both take a tile of the width
+# a time where the whole does not fit. A dynamic row of a VMEM array is a
+# 32-bit affair (a bfloat16 row shares its sublane with its neighbour), so a
+# 16-bit ``x`` is handed over PACKED, two columns a word (``_pack_rows``),
+# and the product kernels unpack a block exactly (``_unpack_rows``). Within
+# a block a row occurs once; across blocks the grid is sequential and the
+# result never leaves VMEM between them, so a row two experts share is added
+# twice in order. A padding slot (row 0, weight 0) gathers row 0 and adds a
+# zero onto it.
+#
+# The products (``_experts_products_forward`` / ``_backward``): grid
+# (blocks, tiles of ``f``), the tiles innermost. An expert's weight tiles
+# come by index map (``block_expert[b]``), so with one tile consecutive
+# blocks of an expert fetch them once, and the transposed uses are
+# ``dot_general`` dimension numbers. The backward kernel keeps one float32
+# accumulator a weight in VMEM scratch, all of ``[h, f]``: it is overwritten
+# by a block whose expert differs from the block before and added to
+# otherwise, and every step rounds its tile into the output block, which the
+# pipeline writes back as its index moves on: the last block of an expert
+# writes last, and what it writes is the whole sum. An expert that draws no
+# row is never visited by a block: after the blocks the grid has a step an
+# expert and tile, which writes zeros for those experts and stays where it
+# was for the others. No ``[held, h, f]`` float32 array exists in HBM, and
+# none is filled with zeros first.
+#
+# A last tile of ``f`` that hangs over the edge (``f`` is the array's full
+# width where it is not whole lanes: 1856) holds no defined values there, so
+# the weight tiles are zeroed past ``f`` before any product reads them.
+#
+# Precision: every product takes operands in the compute dtype and
+# accumulates float32; the activation and its slopes are float32 before the
+# one rounding; accumulators, the results by slot, the summed rows and
+# ``dwt`` are float32.
+
+_EXPERTS_VMEM = 112 << 20       # asked of the compiler; a v5e has 128 MiB
+_ROW_UNROLL = 8                 # rows a step of the row kernels' loops
+
+
+def _lanes(width):
+    return -(-width // 128) * 128
+
+
+def _filled(b, n_ref):
+    """Block ``b``, or the last filled one for a step past them."""
+    import jax.numpy as jnp
+
+    return jnp.maximum(jnp.minimum(b, n_ref[0] - 1), 0)
+
+
+def _packed_width(h, itemsize):
+    """32-bit words of a row of ``h`` values as the row kernels move it."""
+    return h if itemsize == 4 else -(-h // 256) * 128
+
+
+def _pack_rows(x):
+    """``x [S, h]`` as 32-bit rows: itself where it is 32-bit, else word
+    ``j`` of a row holds columns ``j`` (low half) and ``j + w`` (high half,
+    zero past ``h``), ``w`` the packed width."""
+    import jax
+    import jax.numpy as jnp
+
+    if x.dtype.itemsize == 4:
+        return x
+    h = x.shape[1]
+    w = _packed_width(h, 2)
+
+    def bits(v):
+        return jax.lax.bitcast_convert_type(v, jnp.uint16).astype(jnp.uint32)
+
+    high = jnp.pad(x[:, w:], ((0, 0), (0, 2 * w - h)))
+    return bits(x[:, :w]) | (bits(high) << 16)
+
+
+def _unpack_rows(u, h, cd):
+    """The rows :func:`_pack_rows` packed, ``[n, h]`` in ``cd``, exactly."""
+    import jax
+    import jax.numpy as jnp
+
+    if u.dtype == cd:
+        return u
+    f32 = jnp.float32
+    low = jax.lax.bitcast_convert_type(u << 16, f32).astype(cd)
+    high = jax.lax.bitcast_convert_type(u & jnp.uint32(0xFFFF0000),
+                                        f32).astype(cd)
+    return jnp.concatenate([low, high], axis=1)[:, :h]
+
+
+def _row_tile(width, rows, bytes_each):
+    """The widest tile of whole lanes that divides ``width`` and whose
+    ``rows`` rows at ``bytes_each`` a value fit the row kernels' share of
+    VMEM; ``None`` where one lane tile does not."""
+    lanes = width // 128
+    for count in range(1, lanes + 1):
+        if lanes % count == 0 and \
+                rows * (width // count) * bytes_each <= _EXPERTS_VMEM * 2 // 3:
+            return width // count
+    return None
+
+
+def _experts_tiles(h, f, block, gated, itemsize):
+    """``(forward tile of f, backward tile of f, VMEM bytes reckoned)`` of
+    the product kernels: the widest tiles of whole lanes (or all of ``f``)
+    whose steps fit ``_EXPERTS_VMEM``, the larger need of the two; ``None``
+    where no tile does. Counted a step: the weight tiles twice (the
+    pipeline's two buffers), in the backward kernel also their output
+    blocks twice and the float32 accumulators of all of ``f``; the block's
+    rows in (packed, twice) and out (float32, twice) and unpacked; the
+    block's float32 intermediates a tile wide, which the compiler spills to
+    VMEM, and the products' float32 results."""
+    nw = 3 if gated else 2
+    hl = _lanes(h)
+    packed = 4 * block * _lanes(_packed_width(h, itemsize))
+    rows32 = 4 * block * hl
+
+    def need(ft, backward):
+        tile = hl * _lanes(ft)
+        mid = 4 * block * _lanes(ft)
+        if backward:
+            whole = tile * -(-f // ft)
+            return (nw * (4 * whole + 4 * tile * itemsize) + 4 * packed
+                    + 3 * rows32 + 2 * block * hl * itemsize + rows32
+                    + (10 if gated else 8) * mid + 2 * 4 * tile)
+        return (2 * nw * tile * itemsize + 2 * packed + 3 * rows32
+                + block * hl * itemsize + rows32 + (6 if gated else 4) * mid)
+
+    def widest(backward):
+        # all of f, then each count of tiles at its narrowest whole lanes
+        tiles = [f] + [t for t in range(_lanes(f) - 128, 0, -128)
+                       if -(-f // t) < -(-f // max(t - 128, 1)) or t == 128]
+        for ft in tiles:
+            if need(ft, backward) <= _EXPERTS_VMEM - (6 << 20):
+                return ft
+        return None
+
+    fwd, bwd = widest(False), widest(True)
+    if fwd is None or bwd is None:
+        return None
+    return fwd, bwd, max(need(fwd, False), need(bwd, True))
+
+
+def grouped_experts_applicable(h, f, block, dtype, gated, tokens) -> bool:
+    """Whether the grouped kernels take experts ``h -> f -> h`` over blocks
+    of ``block`` slots of ``tokens`` rows: a compute dtype the MXU takes, a
+    block of whole sublane tiles of it, ``h`` of whole lanes (a row's
+    width), ``f`` of whole lanes or, as the array's full width, whole
+    sublanes, a tiling of ``f`` whose step fits the VMEM a v5e may be asked
+    for, and a tile of the rows' width that the row kernels can hold."""
+    dtype = np.dtype(dtype) if str(dtype) != "bfloat16" else None
+    itemsize = 2 if dtype is None else dtype.itemsize
+    if dtype is not None and dtype != np.dtype("float32"):
+        return False
+    sublanes = 32 // itemsize
+    if block % sublanes or block % _ROW_UNROLL or h % 128 or f % sublanes \
+            or not pallas_available():
+        return False
+    if _row_tile(_packed_width(h, itemsize), tokens, 4) is None \
+            or _row_tile(h, tokens, 4 + 2 * itemsize) is None:
+        return False
+    return _experts_tiles(h, f, block, gated, itemsize) is not None
+
+
+def _experts_params():
+    """Two sequential grid axes, and the VMEM the rules above reckon by."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=_EXPERTS_VMEM)
+
+
+def _gather_rows(table, rows, nblocks, block):
+    """``table [S, w]`` (32-bit) -> ``[L, w]``: slot ``i`` of a filled block
+    holds ``table[rows[i]]``; the other blocks hold nothing defined."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, w = table.shape
+    nbmax = rows.shape[0] // block
+    wt = _row_tile(w, s, 4)
+
+    def kernel(rows_ref, n_ref, table_hbm, o_ref, held, sem):
+        t, b = pl.program_id(0), pl.program_id(1)
+
+        @pl.when((b == 0) & (n_ref[0] > 0))
+        def _():
+            at = table_hbm if wt == w else table_hbm.at[
+                :, pl.ds(pl.multiple_of(t * wt, 128), wt)]
+            copy = pltpu.make_async_copy(at, held, sem)
+            copy.start()
+            copy.wait()
+
+        @pl.when(b < n_ref[0])
+        def _():
+            def body(k, carry):
+                for u in range(_ROW_UNROLL):
+                    i = k * _ROW_UNROLL + u
+                    r = rows_ref[b * block + i]
+                    o_ref[pl.ds(i, 1), :] = held[pl.ds(r, 1), :]
+                return carry
+
+            jax.lax.fori_loop(0, block // _ROW_UNROLL, body, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(w // wt, nbmax),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((block, wt),
+                               lambda t, b, r, n: (_filled(b, n), t)),
+        scratch_shapes=[pltpu.VMEM((s, wt), table.dtype),
+                        pltpu.SemaphoreType.DMA(())])
+    return pallas_call(
+        kernel, rows, nblocks, table, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows.shape[0], w), table.dtype),
+        compiler_params=_experts_params(),
+        name="grouped_experts_gather")
+
+
+def _scatter_rows(vals, rows, nblocks, block, s, dtype):
+    """``vals [L, h]`` float32 -> ``[S, h]`` in ``dtype``: zeros and, for
+    every slot ``i`` of a filled block, ``vals[i]`` added onto row
+    ``rows[i]``; the sums float32, rounded once."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    h = vals.shape[1]
+    nbmax = rows.shape[0] // block
+    ht = _row_tile(h, s, 4 + 2 * np.dtype(dtype).itemsize)
+
+    def kernel(rows_ref, n_ref, v_ref, o_ref, acc):
+        b = pl.program_id(1)
+
+        @pl.when(b == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        @pl.when(b < n_ref[0])
+        def _():
+            def body(k, carry):
+                for u in range(_ROW_UNROLL):
+                    i = k * _ROW_UNROLL + u
+                    r = rows_ref[b * block + i]
+                    acc[pl.ds(r, 1), :] += v_ref[pl.ds(i, 1), :]
+                return carry
+
+            jax.lax.fori_loop(0, block // _ROW_UNROLL, body, 0)
+
+        @pl.when(b == nbmax - 1)
+        def _():
+            o_ref[...] = acc[...].astype(o_ref.dtype)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(h // ht, nbmax),
+        in_specs=[pl.BlockSpec((block, ht),
+                               lambda t, b, r, n: (_filled(b, n), t))],
+        out_specs=pl.BlockSpec((s, ht), lambda t, b, r, n: (0, t)),
+        scratch_shapes=[pltpu.VMEM((s, ht), jnp.float32)])
+    return pallas_call(
+        kernel, rows, nblocks, vals, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, h), dtype),
+        compiler_params=_experts_params(),
+        name="grouped_experts_scatter")
+
+
+def _experts_tools(ft, f, up_t):
+    """What the two product kernels' bodies share: the products, a skipped
+    step's tile, a hanging tile's mask, a block's inner products (``up_t``:
+    the gate and up weights ride ``[f, h]``, see :func:`_experts_layout`)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    nf = -(-f // ft)
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=f32)
+
+    nn = lambda a, b: dot(a, b, ((1,), (0,)))      # noqa: E731
+    nt = lambda a, b: dot(a, b, ((1,), (1,)))      # noqa: E731
+    tn = lambda a, b: dot(a, b, ((0,), (0,)))      # noqa: E731
+
+    def tile_of(b, j, n_ref):
+        # a skipped step keeps the last tile fetched
+        return jnp.where(b < n_ref[0], j, nf - 1)
+
+    def in_f(tile, j, axis):
+        """The weight tile with what hangs over ``f`` zeroed."""
+        if f % ft == 0:
+            return tile
+        at = jax.lax.broadcasted_iota(jnp.int32, tile.shape, axis)
+        return jnp.where(at < f - j * ft, tile, jnp.zeros_like(tile))
+
+    into_f = nt if up_t else nn
+
+    def forward_block(xb, ups, gated):
+        """The float32 inner products of a block and its activation: ``(g,
+        u, sig, a)`` gated, ``(None, relu, None, a)`` else; ``a`` float32."""
+        if gated:
+            g, u = into_f(xb, ups[0]), into_f(xb, ups[1])
+            sig = jax.nn.sigmoid(g)
+            return g, u, sig, g * sig * u
+        relu = jnp.maximum(into_f(xb, ups[0]), 0.0)
+        return None, relu, None, relu * relu
+
+    def weight_specs(h, nw, at):
+        """A block spec a weight; ``at(*grid and prefetched)`` gives the
+        expert and the tile of ``f``."""
+        from jax.experimental import pallas as pl
+
+        up = pl.BlockSpec((None, h, ft), lambda *a: (at(*a)[0], 0, at(*a)[1]))
+        down = pl.BlockSpec((None, ft, h),
+                            lambda *a: (at(*a)[0], at(*a)[1], 0))
+        return [down if up_t else up] * (nw - 1) + [down]
+
+    return nn, nt, tn, tile_of, in_f, forward_block, weight_specs
+
+
+def _experts_products_forward(xg, ws, weights, block_expert, nblocks, *,
+                              gated, up_t):
+    """The experts' weighted results by slot: ``xg [L, w]`` the blocks'
+    rows as :func:`_gather_rows` leaves them, ``ws`` the stacked weights
+    (``gate`` first where ``gated``, ``up [held, h, f]``, ``down [held, f,
+    h]``), ``weights [L]`` by slot, ``block_expert [L / block]``,
+    ``nblocks [1]``. Returns float32 ``[L, h]``, defined in the filled
+    blocks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    cd = ws[0].dtype
+    _, f, h = ws[-1].shape
+    length, w = xg.shape
+    nbmax = block_expert.shape[0]
+    block = length // nbmax
+    nw = len(ws)
+    ft = _experts_tiles(h, f, block, gated, cd.itemsize)[0]
+    nf = -(-f // ft)
+    nn, _, _, tile_of, in_f, forward_block, weight_specs = _experts_tools(
+        ft, f, up_t)
+
+    def kernel(be_ref, n_ref, x_ref, w_ref, *rest):
+        w_refs, o_ref, xb_ref = rest[:nw], rest[nw], rest[nw + 1]
+        b, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(b < n_ref[0])
+        def _():
+            @pl.when(j == 0)
+            def _():
+                xb_ref[...] = _unpack_rows(x_ref[...], h, cd)
+
+            ups = [in_f(r[...], j, 0 if up_t else 1) for r in w_refs[:-1]]
+            *_, a = forward_block(xb_ref[...], ups, gated)
+            o = nn(a.astype(cd), in_f(w_refs[-1][...], j, 0))
+            if nf == 1:
+                o_ref[...] = o * w_ref[...]
+            else:
+                @pl.when(j == 0)
+                def _():
+                    o_ref[...] = o
+
+                @pl.when(j > 0)
+                def _():
+                    o_ref[...] += o
+
+                @pl.when(j == nf - 1)
+                def _():
+                    o_ref[...] *= w_ref[...]
+
+    def at_block(b, j, be, n):
+        return _filled(b, n), 0
+
+    def tile(b, j, be, n):
+        return be[_filled(b, n)], tile_of(b, j, n)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(nbmax, nf),
+        in_specs=[pl.BlockSpec((block, w), at_block),
+                  pl.BlockSpec((block, 1), at_block)]
+        + weight_specs(h, nw, tile),
+        out_specs=pl.BlockSpec((block, h), at_block),
+        scratch_shapes=[pltpu.VMEM((block, h), cd)])
+    return pallas_call(
+        kernel, block_expert, nblocks, xg, weights.reshape(-1, 1), *ws,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((length, h), f32),
+        compiler_params=_experts_params(),
+        name="grouped_experts_forward")
+
+
+def _experts_products_backward(xg, dyg, ws, weights, block_expert, nblocks,
+                               *, gated, up_t):
+    """The mirror of :func:`_experts_products_forward`: ``dyg [L, w]`` the
+    blocks' rows of the result's gradient -> ``(dx by slot [L, h] float32,
+    the weights' gradients in the weights' shapes and dtypes, dwt [L]
+    float32 by slot)``, each block's inner products formed again."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    cd = ws[0].dtype
+    held, f, h = ws[-1].shape
+    length, w = xg.shape
+    nbmax = block_expert.shape[0]
+    block = length // nbmax
+    nw = len(ws)
+    ft = _experts_tiles(h, f, block, gated, cd.itemsize)[1]
+    nf = -(-f // ft)
+    nn, nt, tn, tile_of, in_f, forward_block, weight_specs = _experts_tools(
+        ft, f, up_t)
+    # the gradient of a gate or up weight, and the rows' through it
+    into_w = (lambda xb, d: tn(d, xb)) if up_t else tn
+    back = nn if up_t else nt
+
+    def kernel(be_ref, n_ref, idle_ref, x_ref, dy_ref, w_ref, *rest):
+        w_refs = rest[:nw]
+        dx_ref, dwt_ref = rest[nw], rest[nw + 1]
+        dw_refs = rest[nw + 2:2 * nw + 2]
+        xb_ref, dyb_ref = rest[2 * nw + 2:2 * nw + 4]
+        accs = rest[2 * nw + 4:]
+        b, j = pl.program_id(0), pl.program_id(1)
+        n = n_ref[0]
+
+        # after the blocks, a step an expert and tile: an expert that drew
+        # no row was never visited, and gets its zeros here
+        @pl.when((b >= nbmax)
+                 & (idle_ref[jnp.maximum(b - nbmax, 0)] == b - nbmax))
+        def _():
+            for ref in dw_refs:
+                ref[...] = jnp.zeros_like(ref)
+
+        @pl.when(b < n)
+        def _():
+            e = be_ref[b]
+            first = (b == 0) | (be_ref[jnp.maximum(b - 1, 0)] != e)
+
+            @pl.when(j == 0)
+            def _():
+                xb_ref[...] = _unpack_rows(x_ref[...], h, cd)
+                dyb_ref[...] = _unpack_rows(dy_ref[...], h, cd)
+
+            def accumulate(acc, ref, product):
+                @pl.when(first)
+                def _():
+                    acc[j] = product
+
+                @pl.when(jnp.logical_not(first))
+                def _():
+                    acc[j] += product
+
+                ref[...] = acc[j].astype(ref.dtype)
+
+            def add_over_tiles(ref, part):
+                @pl.when(j == 0)
+                def _():
+                    ref[...] = part
+
+                @pl.when(j > 0)
+                def _():
+                    ref[...] += part
+
+            wgt = w_ref[...]
+            xb, dyb = xb_ref[...], dyb_ref[...]
+            ups = [in_f(r[...], j, 0 if up_t else 1) for r in w_refs[:-1]]
+            down = in_f(w_refs[-1][...], j, 0)
+            g, u, sig, a = forward_block(xb, ups, gated)
+            a = a.astype(cd)
+            # d(result)/d(a), before the slot's weight
+            da = nt(dyb, down)
+            add_over_tiles(dwt_ref, jnp.sum(a.astype(f32) * da, axis=1,
+                                            keepdims=True))
+            accumulate(accs[-1], dw_refs[-1],
+                       tn(a, (dyb.astype(f32) * wgt).astype(cd)))
+            daw = da * wgt
+            if gated:
+                # silu(g) = g sig(g); its slope sig (1 + g (1 - sig))
+                dg = (daw * u * sig * (1.0 + g * (1.0 - sig))).astype(cd)
+                du = (daw * g * sig).astype(cd)
+                accumulate(accs[0], dw_refs[0], into_w(xb, dg))
+                accumulate(accs[1], dw_refs[1], into_w(xb, du))
+                dxb = back(dg, ups[0]) + back(du, ups[1])
+            else:
+                dh = (daw * 2.0 * u).astype(cd)
+                accumulate(accs[0], dw_refs[0], into_w(xb, dh))
+                dxb = back(dh, ups[0])
+            add_over_tiles(dx_ref, dxb)
+
+    def at_block(b, j, be, n, idle):
+        return _filled(b, n), 0
+
+    def tile(b, j, be, n, idle):
+        return be[_filled(b, n)], tile_of(b, j, n)
+
+    def grad_tile(b, j, be, n, idle):
+        # the blocks' steps follow the weights' tiles; a step of the sweep
+        # after them moves on only to an expert that drew no row
+        e = jnp.clip(b - nbmax, 0, held - 1)
+        to = jnp.where(idle[e] >= 0, idle[e], be[_filled(b, n)])
+        return (jnp.where(b < nbmax, be[_filled(b, n)], to),
+                jnp.where((b < nbmax) | (idle[e] != e), tile_of(b, j, n), j))
+
+    # the last expert at or before each that drew no row, -1 before the first
+    drew = jnp.zeros((held,), jnp.int32).at[block_expert].add(
+        (jnp.arange(nbmax) < nblocks[0]).astype(jnp.int32))
+    idle = jax.lax.cummax(jnp.where(drew == 0, jnp.arange(held), -1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(nbmax + held, nf),
+        in_specs=[pl.BlockSpec((block, w), at_block)] * 2
+        + [pl.BlockSpec((block, 1), at_block)] + weight_specs(h, nw, tile),
+        out_specs=[pl.BlockSpec((block, h), at_block),
+                   pl.BlockSpec((block, 1), at_block)]
+        + weight_specs(h, nw, grad_tile),
+        scratch_shapes=[pltpu.VMEM((block, h), cd)] * 2
+        + [pltpu.VMEM((nf, ft, h) if up_t else (nf, h, ft), f32)] * (nw - 1)
+        + [pltpu.VMEM((nf, ft, h), f32)])
+    dx, dwt, *dws = pallas_call(
+        kernel, block_expert, nblocks, idle.astype(jnp.int32), xg, dyg,
+        weights.reshape(-1, 1), *ws, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((length, h), f32),
+                   jax.ShapeDtypeStruct((length, 1), f32)]
+        + [jax.ShapeDtypeStruct(w_.shape, w_.dtype) for w_ in ws],
+        compiler_params=_experts_params(),
+        name="grouped_experts_backward")
+    return dx, tuple(dws), dwt[:, 0]
+
+
+def _experts_layout(ws):
+    """``(the weights as the product kernels take them, up_t)``: where ``f``
+    is not whole lanes (1856) XLA lays a ``[held, h, f]`` array out with
+    ``h`` innermost, and a kernel that asked for it row-major would have it
+    copied in, and its gradient copied back, every pass (80 MB each way in
+    the Nemotron cell; 12 ms a step, my chip runs, PR 33). So there the gate
+    and up weights ride TRANSPOSED, ``[held, f, h]``, which is that layout
+    under another name, and the kernels' products take them so."""
+    import jax.numpy as jnp
+
+    up_t = ws[-1].shape[1] % 128 != 0
+    if up_t:
+        ws = tuple(jnp.swapaxes(w, 1, 2) for w in ws[:-1]) + (ws[-1],)
+    return tuple(ws), up_t
+
+
+def _experts_forward(x, ws, rows, weights, block_expert, nblocks, *, gated):
+    """``y [S, h]`` in ``x``'s dtype: the grouped experts' weighted results
+    summed onto their rows (``moe.grouped_experts`` states the sum; the
+    layout is ``moe.plan``'s, ``nblocks [1]``)."""
+    block = rows.shape[0] // block_expert.shape[0]
+    ws, up_t = _experts_layout(ws)
+    xg = _gather_rows(_pack_rows(x), rows, nblocks, block)
+    og = _experts_products_forward(xg, ws, weights, block_expert, nblocks,
+                                   gated=gated, up_t=up_t)
+    return _scatter_rows(og, rows, nblocks, block, x.shape[0], x.dtype)
+
+
+def _experts_backward(x, ws, rows, weights, block_expert, nblocks, dy, *,
+                      gated):
+    """``dy [S, h]`` -> ``(dx [S, h]`` in ``x``'s dtype, the weights'
+    gradients, ``dwt [L]`` float32 by slot)``."""
+    import jax.numpy as jnp
+
+    block = rows.shape[0] // block_expert.shape[0]
+    ws, up_t = _experts_layout(ws)
+    xg = _gather_rows(_pack_rows(x), rows, nblocks, block)
+    dyg = _gather_rows(_pack_rows(dy.astype(x.dtype)), rows, nblocks, block)
+    dxg, dws, dwt = _experts_products_backward(
+        xg, dyg, ws, weights, block_expert, nblocks, gated=gated, up_t=up_t)
+    if up_t:
+        dws = tuple(jnp.swapaxes(d, 1, 2) for d in dws[:-1]) + (dws[-1],)
+    dx = _scatter_rows(dxg, rows, nblocks, block, x.shape[0], x.dtype)
+    return dx, dws, dwt
+
+
+@functools.lru_cache(None)
+def _experts_jitted():
+    """The two passes' callers as ``jax.jit`` functions, made once, as
+    :func:`_ssd_jitted` and for its reason."""
+    import jax
+
+    return (jax.jit(_experts_forward, static_argnames=("gated",)),
+            jax.jit(_experts_backward, static_argnames=("gated",)))
+
+
+def grouped_experts_forward(*args, **static):
+    """:func:`_experts_forward` through its shared ``jax.jit``."""
+    return _experts_jitted()[0](*args, **static)
+
+
+def grouped_experts_backward(*args, **static):
+    """:func:`_experts_backward` through its shared ``jax.jit``."""
+    return _experts_jitted()[1](*args, **static)

@@ -104,3 +104,88 @@ def test_gated_experts_compile_for_the_chip(one_chip):
         shape((rows, h)), shape((h, e), jnp.float32), shape((held, h, f)),
         shape((held, h, f)), shape((held, f, h))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+EXPERT_CELLS = {
+    # rows, h, f, held, top_k, experts, gated, dtype
+    "nemotron-cell": (8192, 2688, 1856, 8, 6, 128, False, "bfloat16"),
+    "glm-cell": (8192, 2048, 1536, 8, 4, 64, True, "bfloat16"),
+    "narrowest-float32": (64, 128, 8, 2, 2, 4, True, "float32"),
+    "narrowest-bfloat16": (128, 128, 16, 2, 2, 4, False, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_grouped_experts_kernels_compile_for_the_chip(one_chip, cell, pass_):
+    """Both cells' shapes (the widest the rule admits; the Nemotron cell's
+    ``f`` of 1856 hangs over its backward kernel's last tile, and its rows
+    of 21 lane tiles pack into 11 words' tiles) and the narrowest, at the
+    static bound of blocks, with the VMEM the rule reckons: Mosaic takes
+    the row kernels' dynamic rows and resident tables, the tiles and the
+    accumulators."""
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rows, h, f, held, k, e, gated, dtype = EXPERT_CELLS[cell]
+    dtype = jnp.dtype(dtype)
+    block = moe.block_rows(rows, k, e)
+    assert pk.grouped_experts_applicable(h, f, block, dtype, gated, rows)
+    assert pk._experts_tiles(h, f, block, gated, dtype.itemsize)[2] \
+        <= pk._EXPERTS_VMEM
+    length = moe.layout_length(rows, k, held, block)
+
+    def shape(dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    ws = (shape((held, h, f)),) * (2 if gated else 1) + (shape((held, f, h)),)
+    layout = (shape((length,), jnp.int32), shape((length,), jnp.float32),
+              shape((length // block,), jnp.int32), shape((1,), jnp.int32))
+    with jax.default_matmul_precision("default"):
+        if pass_ == "forward":
+            compiled = jax.jit(lambda x, ws, *lay: pk._experts_forward(
+                x, ws, *lay, gated=gated)).lower(
+                    shape((rows, h)), ws, *layout).compile()
+        else:
+            compiled = jax.jit(lambda x, ws, dy, *lay: pk._experts_backward(
+                x, ws, *lay, dy, gated=gated)).lower(
+                    shape((rows, h)), ws, shape((rows, h)), *layout).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # gather(s), products, scatter: no loop of XLA ops over the blocks
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == \
+        (3 if pass_ == "forward" else 4)
+    assert " while(" not in text
+
+
+@pytest.mark.parametrize("h,f,rows,why", [
+    (96, 128, 128, "a row of 96 is not whole lanes"),
+    (128, 20, 128, "an inner width of 20 is not whole sublanes"),
+    (128, 128, 72, "a block of 9 rows is no sublane tile")])
+def test_shapes_the_rule_refuses_take_the_xla_loop(h, f, rows, why):
+    """What ``grouped_experts_applicable`` refuses lowers as the loop of XLA
+    products, and the op counts which body it took."""
+    from mxnet_tpu import symbol as sym
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    block = moe.block_rows(rows, 2, 4)
+    assert not pk.grouped_experts_applicable(h, f, block, jnp.float32,
+                                             False, rows), why
+    net = sym.RoutedExperts(num_experts=4, num_held=2, top_k=2, num_hidden=f,
+                            data=sym.Variable("data"), name="x")
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        net.simple_bind(mx_cpu(), data=(rows, h)).forward(is_train=False)
+        assert telemetry.peek("lower.experts_kernel.xla_loop") == 1
+        assert not telemetry.peek("lower.experts_kernel.pallas_grouped")
+    finally:
+        telemetry.disable()
+
+
+def mx_cpu():
+    import mxnet_tpu as mx
+
+    return mx.cpu()

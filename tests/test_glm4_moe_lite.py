@@ -219,6 +219,8 @@ COUNTERS = ("step.dispatches", "step.fused_steps", "step.fused_fallback",
             "lower.attention_kernel.xla_blockwise",
             "lower.attention_kernel.pallas_splash",
             "lower.experts_body.swiglu", "lower.experts_body.relu2",
+            "lower.experts_kernel.xla_loop",
+            "lower.experts_kernel.pallas_grouped",
             "moe.rows_total", "moe.rows_here", "moe.dropped_rows",
             "remat.segments", "remat.segments_recomputed",
             "remat.kept_results")
@@ -269,6 +271,9 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch):
     assert not counters["lower.attention_kernel.pallas_splash"]
     assert counters["lower.experts_body.swiglu"] == 2
     assert not counters["lower.experts_body.relu2"]
+    # toy widths are no whole tiles: the loop of XLA products, once a layer
+    assert counters["lower.experts_kernel.xla_loop"] == 2
+    assert not counters["lower.experts_kernel.pallas_grouped"]
     # (row, expert) pairs: 2 expert layers x 3 steps x 48 rows x top-2
     assert counters["moe.rows_total"] == 2 * 3 * 48 * 2
     assert 0 < counters["moe.rows_here"] < counters["moe.rows_total"]
@@ -308,6 +313,49 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch):
     for name, norm in norms.items():
         assert abs(float(norm) - want["grad_norms"][name]) \
             <= 2e-3 * max(want["grad_norms"][name], 1e-3), name
+
+
+WHOLE_TILES = dict(TOY, hidden=128, expert_hidden=128, seq_len=64)
+
+
+def test_whole_tile_experts_take_the_grouped_kernel_and_match_the_loop(
+        monkeypatch):
+    """A row of whole lanes and blocks of 16 rows: each gated expert layer
+    takes the Pallas kernels (interpreted here), counted once a layer, and
+    two Adam steps read the loop's first moments and parameters, the same
+    rows counted, none dropped."""
+    from mxnet_tpu.ops import moe
+
+    batches = toy_batches(2, toy=WHOLE_TILES)
+    runs = {}
+    for body in ("pallas_grouped", "xla_loop"):
+        if body == "xla_loop":
+            monkeypatch.setattr(moe, "grouped_experts_applicable",
+                                lambda *a: False)
+        mod, _, counters = fit_toy(monkeypatch, batches, toy=WHOLE_TILES)
+        other = "xla_loop" if body == "pallas_grouped" else "pallas_grouped"
+        assert counters["lower.experts_kernel." + body] == 2
+        assert not counters["lower.experts_kernel." + other]
+        assert counters["lower.experts_body.swiglu"] == 2
+        assert counters["moe.dropped_rows"] == 0
+        assert counters["step.dispatches"] == 2
+        assert not counters["step.fused_fallback"]
+        params = {k: v.asnumpy() for part in mod.get_params()
+                  for k, v in part.items()}
+        moments = {name: mod._updater.states[i][0].asnumpy()
+                   for i, name in enumerate(mod._param_names)}
+        runs[body] = (counters["moe.rows_here"], params, moments)
+    (rows_k, params_k, moments_k), (rows_l, params_l, moments_l) = \
+        runs["pallas_grouped"], runs["xla_loop"]
+    assert rows_k == rows_l > 0
+    # Adam's step divides by the gradient's size: a gradient near zero
+    # turns a rounding into a step, so the parameters agree less closely
+    for k in params_l:
+        close(params_k[k], params_l[k], 2e-4)
+    for k in moments_l:
+        close(moments_k[k], moments_l[k], 2e-5)
+    assert any("experts_gate_weight" in k and np.abs(v).max() > 0
+               for k, v in moments_k.items())
 
 
 def test_model_loss_follows_the_reference(monkeypatch):
@@ -487,6 +535,9 @@ def _step_text(monkeypatch, net, params0, batches):
 PARENT_TEXT = {
     "nemotron_h": "6a340afd55b18bd0173950b183fd76d66d3db7573c4885e87ba68d5864fbff7b",
     "olmo_hybrid": "17840b26e9670a2a245172f2d13fe377fe4a188742e2f6880700bc3b94ce3e46",
+    # at commit a1b7200 (PR 32), the parent of the PR that gave the grouped
+    # products their Pallas kernels (PR 33): toy widths keep the XLA loop
+    "glm4_moe_lite": "836bd48c33b817989accd972afba9a6750609f9e1a3ffc91430949cf0abe4d2f",
 }
 
 
@@ -498,6 +549,8 @@ def test_the_models_that_share_the_operators_lower_as_they_did(monkeypatch,
     under recomputation, is the program text it was on the parent."""
     if model == "nemotron_h":
         toy, their, factory = NEMOTRON_TOY, nemotron_h, get_nemotron_h
+    elif model == "glm4_moe_lite":
+        toy, their, factory = TOY, ref, get_glm4_moe_lite
     else:
         toy, their, factory = OLMO_TOY, olmo_hybrid, get_olmo_hybrid
     params0 = {k: np.asarray(v) for k, v in their.init_params(

@@ -760,7 +760,9 @@ def fit_toy(monkeypatch, batches, compute_dtype=None, mirror=True,
             "step.dispatches", "step.fused_steps", "step.fused_fallback",
             "moe.rows_here", "moe.rows_total", "moe.dropped_rows",
             "lower.scan_kernel.xla_chunked",
-            "lower.attention_kernel.xla_blockwise")}
+            "lower.attention_kernel.xla_blockwise",
+            "lower.experts_kernel.xla_loop",
+            "lower.experts_kernel.pallas_grouped")}
         counters["load"] = telemetry.peek("moe.expert_load_max_over_mean",
                                           "gauge")
         counters["jit_entries"] = telemetry.peek("step.fused_jit_entries",
@@ -789,6 +791,9 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch,
     assert counters["moe.dropped_rows"] == 0
     assert counters["load"] >= 1.0
     assert counters["lower.scan_kernel.xla_chunked"] >= 4
+    # toy widths are no whole tiles: the loop of XLA products, once a layer
+    assert counters["lower.experts_kernel.xla_loop"] == 4
+    assert not counters["lower.experts_kernel.pallas_grouped"]
     want = ref.follow(toy, RECIPE, params0,
                       [(jnp.asarray(i), jnp.asarray(l)) for i, l in batches],
                       rows=np.arange(16).reshape(2, 8))
@@ -807,6 +812,51 @@ def test_model_fits_on_the_fused_step_like_the_reference(monkeypatch,
     for k, n in want["delta_norms"].items():
         if "[" in k:
             assert float(delta[k]) > 0.5 * n > 0
+
+
+WHOLE_TILES = dict(TOY, pattern="MEE", hidden=128, expert_hidden=136,
+                   seq_len=64)
+
+
+def test_whole_tile_experts_take_the_grouped_kernel_and_match_the_loop(
+        monkeypatch):
+    """A row of whole lanes, an inner width of whole sublanes, blocks of
+    16 rows: each expert layer takes the Pallas kernels (interpreted here),
+    counted once a layer, and two Adam steps read the loop's losses, first
+    moments and parameters, the same rows counted."""
+    from mxnet_tpu.ops import moe
+
+    ids = np.random.default_rng(13).integers(0, TOY["vocab"], (2, 2, 65))
+    batches = [(i[:, :-1].astype(np.int32), i[:, 1:].astype(np.int32))
+               for i in ids]
+    runs = {}
+    for body in ("pallas_grouped", "xla_loop"):
+        if body == "xla_loop":
+            monkeypatch.setattr(moe, "grouped_experts_applicable",
+                                lambda *a: False)
+        mod, _, counters = fit_toy(monkeypatch, batches, toy=WHOLE_TILES)
+        other = "xla_loop" if body == "pallas_grouped" else "pallas_grouped"
+        assert counters["lower.experts_kernel." + body] == 2
+        assert not counters["lower.experts_kernel." + other]
+        assert counters["moe.dropped_rows"] == 0
+        assert counters["step.dispatches"] == 2
+        assert not counters["step.fused_fallback"]
+        params = {k: v.asnumpy() for part in mod.get_params()
+                  for k, v in part.items()}
+        moments = {name: mod._updater.states[i][0].asnumpy()
+                   for i, name in enumerate(mod._param_names)}
+        runs[body] = (counters["moe.rows_here"], params, moments)
+    (rows_k, params_k, moments_k), (rows_l, params_l, moments_l) = \
+        runs["pallas_grouped"], runs["xla_loop"]
+    assert rows_k == rows_l > 0
+    # Adam's step divides by the gradient's size: a gradient near zero
+    # turns a rounding into a step, so the parameters agree less closely
+    for k in params_l:
+        close(params_k[k], params_l[k], 2e-4)
+    for k in moments_l:
+        close(moments_k[k], moments_l[k], 2e-5)
+    assert any("experts_up_weight" in k and np.abs(v).max() > 0
+               for k, v in moments_k.items())
 
 
 @pytest.mark.parametrize("aux_given", [True, False])
