@@ -404,7 +404,7 @@ def _registry_tools(site: str, build_fn: Callable[[dict], tuple]):
         dt = time.perf_counter() - t0
         rec = _xprof.record_compile("autotune.%s" % site, compiled, dt)
         compiled_cache[cand["name"]] = (compiled, args)
-        return {"flops": rec.flops, "peak_bytes": rec.peak_bytes,
+        return {"flops": rec.flops, "peak_bytes": rec.held_bytes,
                 "bytes_accessed": rec.bytes_accessed,
                 "compile_time_s": dt}
 

@@ -336,26 +336,21 @@ declare("MXNET_TPU_CRASH_DIR", str, "",
         "`$TMPDIR/mxnet_tpu_crash`).", section=_T)
 
 _X = "Device observability (xprof)"
-declare("MXNET_TPU_XPROF", bool, False,
-        "Route every step-path jit compile (fused step, executor "
-        "fwd+bwd, metric folds, kvstore reduce) through the compile "
-        "registry (`mxnet_tpu.xprof`): compile wall-time, "
-        "`cost_analysis` FLOPs/bytes, `memory_analysis` peak bytes and "
-        "the HLO op-category breakdown land in `compile.*` telemetry "
-        "and `xprof.records()`, and recompiles carry a retrace-cause diff "
-        "naming the changed argument avals. The wrapper dispatches "
-        "through the AOT executable it measured, so instrumentation "
-        "adds zero extra compiles or dispatches. `xprof.enable()` does "
-        "the same at runtime.", section=_X)
 declare("MXNET_TPU_XPROF_OPS", bool, True,
-        "Parse each recorded executable's optimized HLO into the "
+        "With telemetry on, every step-path jit compile (fused step, "
+        "executor fwd+bwd, metric folds, kvstore reduce) goes through "
+        "the compile registry (`mxnet_tpu.xprof`; no switch of its own) "
+        "and each recorded executable's optimized HLO is parsed into the "
         "conv/dot/fusion/collective/transpose/elementwise FLOP+bytes "
-        "breakdown (`trace_report.py --view ops`). Set to 0 to skip "
-        "the parse on very large modules; compile timing and memory "
-        "analysis still record.", section=_X)
+        "breakdown (`trace_report.py --view ops`) and, the fused "
+        "step's, into the census of its instructions by phase "
+        "(`compile.fused_step.census.*`). Set to 0 to skip "
+        "reading the text of very large modules; compile timing, "
+        "`cost_analysis` and `memory_analysis` still record.",
+        section=_X)
 declare("MXNET_TPU_XPROF_PREFLIGHT", bool, True,
         "Pre-flight OOM check: when the device reports an HBM limit, "
-        "a recorded executable whose `memory_analysis` peak cannot fit "
+        "a recorded executable whose `memory_analysis` footprint cannot fit "
         "raises before the first dispatch instead of OOM-ing minutes "
         "into a run. No-op where no limit is known (CPU).", section=_X)
 declare("MXNET_TPU_XPROF_RECORDS", int, 256,

@@ -716,8 +716,10 @@ class FusedTrainStep:
                          "aux", "opt_state", "hyper", "metric_acc",
                          "rng_key", "aug")
             donate_argnums = (0, 2, 3, 5)
+        # the one site that traces under the phases' scopes: its record
+        # carries the census of the program's instructions by phase
         return _xprof.jit(
-            step, site="fused_step", arg_names=arg_names,
+            step, site="fused_step", arg_names=arg_names, census=True,
             donate_argnums=donate_argnums if donate else ())
 
 

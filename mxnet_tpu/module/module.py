@@ -308,17 +308,30 @@ class Module(BaseModule):
         return self._exec_group.get_outputs()
 
     def publish_aux_counters(self):
-        """Turn auxiliary states that COUNT on the device (a routed-expert
-        layer's rows per expert) into telemetry. The step never fetches
-        them; this does, so call it at a fence only: ``fit`` does after
-        set-up (the baseline) and at each epoch's end, a harness at the
-        ends of its window. Counters get the increment since the last
-        call, summed over the layers; a gauge its largest value over the
-        layers. A no-op with telemetry off or nothing that counts."""
+        """What only a fence can read, into telemetry: the memory of the
+        step's first device, ONE ``memory_stats()`` call, as the gauges
+        ``device.hbm_in_use_bytes``, ``device.hbm_reserved_bytes`` and
+        ``device.hbm_limit_bytes``, set together (none of them where the
+        backend has no allocator to ask: the CPU), and the auxiliary
+        states that COUNT on the device (a routed-expert layer's rows
+        per expert). The step never fetches those; this does, so call it
+        at a fence only: ``fit`` does after set-up (the baseline) and at
+        each epoch's end, a harness at the ends of its window. Counters
+        get the increment since the last call, summed over the layers; a
+        gauge its largest value over the layers. A no-op with telemetry
+        off."""
         from .. import telemetry as _tel
+        from .. import xprof as _xprof
 
         if not _tel.enabled() or not self.binded:
             return
+        hbm = _xprof.hbm_stats(self._context[0].jax_device())
+        if hbm["source"] == "memory_stats":
+            for gauge, key in (("in_use", "live_bytes"),
+                               ("reserved", "reserved_bytes"),
+                               ("limit", "limit_bytes")):
+                if hbm[key] is not None:
+                    _tel.set_gauge("device.hbm_%s_bytes" % gauge, hbm[key])
         aux = self._exec_group.executor.aux_dict
         last = self.__dict__.setdefault("_aux_counter_last", {})
         gauges = {}

@@ -13,10 +13,13 @@ actually runs:
   through :func:`jit`, an AOT ``lower()``/``compile()`` wrapper that
   records compile wall-time, the argument-aval signature,
   ``cost_analysis()`` FLOPs / bytes-accessed and ``memory_analysis()``
-  argument/output/temp/peak bytes into the ``compile.*`` telemetry
-  namespace. A recompile carries a *retrace-cause diff* naming exactly
-  which avals changed vs the previous signature — "(64,3,224,224)f32
-  -> (32,3,224,224)f32 on batch.data" instead of "something retraced".
+  argument/output/alias/temp/code bytes, per site, as the gauges
+  ``compile.<site>.*`` (``held_bytes`` is what the program holds at
+  once by XLA's own account). A recompile carries a *retrace-cause
+  diff* naming exactly which avals changed vs the previous signature —
+  "(64,3,224,224)f32 -> (32,3,224,224)f32 on batch.data" instead of
+  "something retraced". It follows telemetry's one switch: on when
+  ``telemetry.enabled()`` at the moment a site is built.
 * **Op-category attribution** — :func:`hlo_op_breakdown` parses the
   compiled executable's optimized HLO into a conv / dot / fusion /
   collective / transpose / elementwise FLOP+bytes table whose category
@@ -25,12 +28,19 @@ actually runs:
   :func:`analyze` adds analytic MFU, arithmetic intensity and a
   compute- vs bandwidth-bound classification from the chip's peak
   FLOPs and HBM bandwidth.
-* **HBM accounting** — :class:`HbmWatermark` samples the live-buffer
-  watermark per step (``device.memory_stats()`` on TPU,
-  ``jax.live_arrays()`` fallback on CPU), feeds the
-  ``hbm.headroom_bytes`` gauge the MetricsServer exports, and
-  :func:`preflight_check` refuses a config whose ``memory_analysis``
-  peak cannot fit before a single step runs.
+* **Census by phase** — for a site that asks (``census=True``: the
+  fused step) the same parse groups the program's top-level
+  instructions by the SET of phases (``fwd`` / ``recompute`` / ``bwd``
+  / ``update`` / ``metric``) their members' ``op_name`` carry:
+  :func:`hlo_phase_census`, published as ``compile.<site>.census.*``.
+  A fusion that holds a weight-gradient product AND the optimizer's
+  update reads ``bwd+update``: what a device trace charges to one scope.
+* **HBM accounting** — :func:`hbm_stats` reads one device's
+  ``memory_stats()`` (``jax.live_arrays()`` on the CPU); a module
+  publishes an allocator's answer at a fence as ``device.hbm_*``
+  (``Module.publish_aux_counters``), and :func:`preflight_check`
+  refuses a config whose ``memory_analysis`` footprint cannot fit
+  before a single step runs.
 
 Everything except profiler trace capture works on CPU, so tier-1
 exercises the whole plane (``tests/test_xprof.py``).
@@ -59,10 +69,10 @@ _log = logging.getLogger(__name__)
 __all__ = [
     "enabled", "enable", "disable", "reset", "jit", "record_compile",
     "records", "summary", "last_retrace_cause", "hlo_op_breakdown",
-    "analyze", "chip_peaks", "chip_peak_tflops", "chip_hbm_gbps",
-    "CHIP_PEAKS", "hbm_stats",
-    "HbmWatermark", "preflight_check", "device_memory_limit",
-    "CompileRecord", "CATEGORIES",
+    "hlo_phase_census", "analyze", "chip_peaks", "chip_peak_tflops",
+    "chip_hbm_gbps", "CHIP_PEAKS", "hbm_stats",
+    "preflight_check", "device_memory_limit",
+    "CompileRecord", "CATEGORIES", "PHASES",
 ]
 
 # ---------------------------------------------------------------------------
@@ -73,10 +83,12 @@ _override: Optional[bool] = None
 
 
 def enabled() -> bool:
-    """Master switch: ``MXNET_TPU_XPROF`` or a runtime enable()."""
+    """Telemetry's switch is this one's: a site built while
+    ``telemetry.enabled()`` is instrumented. ``enable()`` / ``disable()``
+    override it either way (tests)."""
     if _override is not None:
         return _override
-    return bool(_env.get("MXNET_TPU_XPROF"))
+    return _tel.enabled()
 
 
 def enable():
@@ -98,8 +110,9 @@ class CompileRecord:
 
     __slots__ = ("site", "seq", "compile_time_s", "signature", "flops",
                  "bytes_accessed", "argument_bytes", "output_bytes",
-                 "temp_bytes", "peak_bytes", "generated_code_bytes",
-                 "op_breakdown", "retrace_cause", "num_devices", "ts")
+                 "alias_bytes", "temp_bytes", "generated_code_bytes",
+                 "held_bytes", "op_breakdown", "census", "matrix_flops",
+                 "census_loops_once", "retrace_cause", "num_devices", "ts")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -257,29 +270,51 @@ def _memory_dict(compiled) -> Optional[dict]:
                       ("generated_code_bytes",
                        "generated_code_size_in_bytes")):
         out[key] = int(getattr(m, attr, 0) or 0)
-    # aliased (donated) buffers are counted once: they are argument
-    # bytes XLA reuses for outputs, not extra live memory at peak
-    out["peak_bytes"] = max(0, out["argument_bytes"] + out["output_bytes"]
+    # what the program holds at once, by XLA's own account. Aliased
+    # (donated) buffers are counted once: they are argument bytes XLA
+    # reuses for outputs, not extra memory
+    out["held_bytes"] = max(0, out["argument_bytes"] + out["output_bytes"]
                             + out["temp_bytes"]
                             + out["generated_code_bytes"]
                             - out["alias_bytes"])
     return out
 
 
+def _read_program(site: str, compiled, census: bool):
+    """(op breakdown, census, matrix FLOPs by phase, loops counted once)
+    from the executable's optimised HLO text. A site that asks for the
+    census (the fused step: it traces under the phases' scopes) gets all
+    four, read under the span ``step.census`` so that what the parse
+    costs shows in its build; any other the op breakdown alone. Nones
+    with ``MXNET_TPU_XPROF_OPS`` off, and for a text the parser cannot
+    read: one warning, never a failed build."""
+    if not _env.get("MXNET_TPU_XPROF_OPS"):
+        return (None,) * 4
+    try:
+        if not census:
+            return (hlo_op_breakdown(compiled.as_text()),) + (None,) * 3
+        with _tel.span("step.census"):
+            return _analyze_hlo(compiled.as_text())
+    except Exception as e:          # the text's form is XLA's to change
+        _log.warning("xprof: %s: the optimised HLO was not read (%s: %s); "
+                     "no op breakdown and no census for this program",
+                     site, type(e).__name__, e)
+        return (None,) * 4
+
+
 def record_compile(site: str, compiled, compile_time_s: float,
-                   signature: Optional[tuple] = None) -> CompileRecord:
-    """Record one measured compile into the registry + ``compile.*``
-    telemetry; computes the retrace-cause diff against the site's
-    previous signature."""
+                   signature: Optional[tuple] = None,
+                   census: bool = False) -> CompileRecord:
+    """Record one measured compile into the registry and, with telemetry
+    on, into the gauges ``compile.<site>.*`` (one set a site, the latest
+    program's); computes the retrace-cause diff against the site's
+    previous signature. ``census``: group the program's instructions by
+    phase too (:func:`hlo_phase_census`)."""
     global _last_cause, _seq
     cost = _cost_dict(compiled)
     mem = _memory_dict(compiled) or {}
-    breakdown = None
-    if _env.get("MXNET_TPU_XPROF_OPS"):
-        try:
-            breakdown = hlo_op_breakdown(compiled.as_text())
-        except Exception:
-            breakdown = None
+    breakdown, by_set, matrix_flops, loops_once = _read_program(
+        site, compiled, census)
     flops = cost.get("flops")
     flops = float(flops) if flops else None
     if flops is None and breakdown:
@@ -296,14 +331,11 @@ def record_compile(site: str, compiled, compile_time_s: float,
             compile_time_s=round(float(compile_time_s), 6),
             signature=signature, flops=flops,
             bytes_accessed=float(ba) if ba else None,
-            argument_bytes=mem.get("argument_bytes"),
-            output_bytes=mem.get("output_bytes"),
-            temp_bytes=mem.get("temp_bytes"),
-            peak_bytes=mem.get("peak_bytes"),
-            generated_code_bytes=mem.get("generated_code_bytes"),
-            op_breakdown=breakdown, retrace_cause=cause,
+            op_breakdown=breakdown, census=by_set,
+            matrix_flops=matrix_flops, census_loops_once=loops_once,
+            retrace_cause=cause,
             num_devices=_device_count(compiled),
-            ts=round(time.time(), 6))
+            ts=round(time.time(), 6), **mem)
         st["compiles"] += 1
         st["time_s"] += float(compile_time_s)
         st["sig"] = signature
@@ -319,9 +351,32 @@ def record_compile(site: str, compiled, compile_time_s: float,
         _tel.observe("compile.time_ms", compile_time_s * 1e3)
         if flops:
             _tel.inc("compile.flops", int(flops))
-        if rec.peak_bytes:
-            _tel.set_gauge("compile.peak_bytes", rec.peak_bytes)
+        _publish(rec)
     return rec
+
+
+_SITE_GAUGES = ("argument_bytes", "output_bytes", "alias_bytes",
+                "temp_bytes", "generated_code_bytes", "held_bytes",
+                "flops", "bytes_accessed")
+
+
+def _publish(rec: CompileRecord):
+    """The record as gauges: ``compile.<site>.<field>`` for what XLA
+    reported, ``.build_s``, ``compile.<site>.census.<set>.ops`` /
+    ``.flops`` / ``.bytes`` for the sets of phases that occur and
+    ``.matrix_flops.<phase>`` for the products by their own phase, where
+    the site asked for the census."""
+    base = "compile.%s." % rec.site
+    for field in _SITE_GAUGES:
+        v = getattr(rec, field)
+        if v is not None:
+            _tel.set_gauge(base + field, v)
+    _tel.set_gauge(base + "build_s", rec.compile_time_s)
+    for name, row in (rec.census or {}).items():
+        for field, v in row.items():
+            _tel.set_gauge("%scensus.%s.%s" % (base, name, field), v)
+    for phase, v in (rec.matrix_flops or {}).items():
+        _tel.set_gauge("%smatrix_flops.%s" % (base, phase), v)
 
 
 def summary() -> dict:
@@ -335,11 +390,11 @@ def summary() -> dict:
                                     if st["last"] else None)}
         total_t = sum(st["time_s"] for st in _sites.values())
         total_n = sum(st["compiles"] for st in _sites.values())
-        peaks = [r.peak_bytes for r in _records if r.peak_bytes]
+        held = [r.held_bytes for r in _records if r.held_bytes]
     out = {"sites": sites,
            "totals": {"compiles": total_n,
                       "compile_time_s": round(total_t, 4),
-                      "peak_bytes_max": max(peaks) if peaks else 0}}
+                      "held_bytes_max": max(held) if held else 0}}
     try:
         out["hbm"] = hbm_stats()
     except Exception:
@@ -354,7 +409,7 @@ def summary() -> dict:
 _FALLBACK = object()
 
 
-def jit(fn, site: str, arg_names=None, **jit_kw):
+def jit(fn, site: str, arg_names=None, census=False, **jit_kw):
     """``jax.jit`` with the compile registry on the compile path.
 
     Disabled (the default): returns the plain ``jax.jit`` — zero added
@@ -363,21 +418,28 @@ def jit(fn, site: str, arg_names=None, **jit_kw):
     :class:`CompileRecord` and then dispatches through the measured AOT
     executable itself (same donation, same executable — no second
     compile, no extra dispatch). Positional calling only, which is all
-    the step-path sites use."""
+    the step-path sites use. ``census``: the site traces under the
+    phases' scopes (:data:`PHASES`) and wants its program's instructions
+    grouped by them, under the span ``step.census``."""
     import jax
 
     jfn = jax.jit(fn, **jit_kw)
     if not enabled():
         return jfn
-    return _InstrumentedJit(jfn, site, arg_names)
+    return _InstrumentedJit(jfn, site, arg_names, census)
 
 
 class _InstrumentedJit:
-    def __init__(self, jfn, site, arg_names):
+    def __init__(self, jfn, site, arg_names, census=False):
         self._jit = jfn
         self._site = site
         self._arg_names = arg_names
+        self._census = census
         self._cache: Dict[tuple, Any] = {}
+        # (signature, executable) of the last call: the steady state's
+        # whole lookup (the executable's own input check tells a new
+        # signature apart)
+        self._last = None
         self._lock = threading.Lock()
 
     def lower(self, *args, **kw):
@@ -393,7 +455,24 @@ class _InstrumentedJit:
         return aot + self._jit._cache_size()
 
     def __call__(self, *args):
-        sig = leaf_signature(args, self._arg_names)
+        last = self._last
+        if last is not None:
+            # no walk over the leaves in steady state: the executable
+            # checks its arguments' types (``TypeError``) and placement
+            # (``ValueError``) itself, before anything runs or is
+            # donated; whether that was another signature, the walk says
+            try:
+                return last[1](*args)
+            except (TypeError, ValueError) as e:
+                sig = leaf_signature(args, self._arg_names)
+                if sig == last[0]:
+                    # no new signature: an input check stricter than the
+                    # signature, or the call's own error, raised once
+                    if isinstance(e, TypeError):
+                        return self._refused(sig, args, e)
+                    raise
+        else:
+            sig = leaf_signature(args, self._arg_names)
         with self._lock:
             compiled = self._cache.get(sig)
             if compiled is None:
@@ -404,22 +483,29 @@ class _InstrumentedJit:
         if compiled is _FALLBACK:
             return self._jit(*args)
         try:
-            return compiled(*args)
+            res = compiled(*args)
         except TypeError as e:
-            # the AOT input check is stricter than jit dispatch (e.g. a
-            # committed-device mismatch). The plain jit still serves the
-            # call, but it COMPILES THE SITE A SECOND TIME and the
-            # registry's record no longer describes what runs — so this
-            # is counted and logged, never silent
-            _tel.inc("compile.aot_fallback")
-            _log.warning(
-                "xprof: %s: the measured AOT executable rejected its "
-                "arguments (%s); dispatching through jax.jit instead, "
-                "which compiles this site again", self._site,
-                str(e).splitlines()[0])
-            with self._lock:
-                self._cache[sig] = _FALLBACK
-            return self._jit(*args)
+            return self._refused(sig, args, e)
+        with self._lock:
+            self._last = (sig, compiled)
+        return res
+
+    def _refused(self, sig, args, e):
+        """The AOT input check is stricter than jit dispatch (e.g. a
+        committed-device mismatch). The plain jit still serves the call,
+        and this signature's later ones, but it COMPILES THE SITE A
+        SECOND TIME and the registry's record no longer describes what
+        runs — so this is counted and logged, never silent."""
+        _tel.inc("compile.aot_fallback")
+        _log.warning(
+            "xprof: %s: the measured AOT executable rejected its "
+            "arguments (%s); dispatching through jax.jit instead, "
+            "which compiles this site again", self._site,
+            str(e).splitlines()[0])
+        with self._lock:
+            self._cache[sig] = _FALLBACK
+            self._last = None
+        return self._jit(*args)
 
     def _compile(self, args, sig):
         t0 = time.perf_counter()
@@ -429,10 +515,11 @@ class _InstrumentedJit:
             self._cache[sig] = _FALLBACK
             return _FALLBACK
         rec = record_compile(self._site, compiled,
-                             time.perf_counter() - t0, signature=sig)
-        if _env.get("MXNET_TPU_XPROF_PREFLIGHT") and rec.peak_bytes:
+                             time.perf_counter() - t0, signature=sig,
+                             census=self._census)
+        if _env.get("MXNET_TPU_XPROF_PREFLIGHT") and rec.held_bytes:
             preflight_check(
-                rec.peak_bytes,
+                rec.held_bytes,
                 devices=compiled.runtime_executable().local_devices(),
                 what=self._site)
         self._cache[sig] = compiled
@@ -440,11 +527,15 @@ class _InstrumentedJit:
 
 
 # ---------------------------------------------------------------------------
-# HLO op-category attribution
+# HLO op-category attribution and the census by phase
 # ---------------------------------------------------------------------------
 
 CATEGORIES = ("conv", "dot", "fusion", "collective", "transpose",
               "elementwise", "other")
+#: the phases the fused step traces under (``jax.named_scope``), in the
+#: order a set's name joins them. ``recompute`` is what ``jax.checkpoint``
+#: runs again inside the backward pass
+PHASES = ("fwd", "recompute", "bwd", "update", "metric")
 
 _DTYPE_BYTES = {"pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
                 "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -453,25 +544,29 @@ _DTYPE_BYTES = {"pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
                 "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3b11fnuz": 1,
                 "token": 0, "opaque": 0}
 
-_SHAPE_RE = re.compile(r"([a-z]\w*)\[([\d,]*)\]")
+# ``dtype[dims]`` with its layout, where the text gives one
+_SHAPE_RE = re.compile(r"([a-z]\w*)\[([\d,]*)\](\{[^{}]*\})?")
+# a layout's memory space: S(1) and up are on the chip (VMEM, flags)
+_OFF_HBM_RE = re.compile(r"S\([1-9]\d*\)")
 _INSTR_RE = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+)\s=\s(.*)$")
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_PHASE_RE = re.compile(r"/(fwd|bwd|update|metric)(?:/|$)")
+_TRIPS_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 _COLLECTIVE = frozenset((
     "all-reduce", "all-gather", "all-to-all", "reduce-scatter",
-    "collective-permute", "collective-broadcast", "all-reduce-start",
-    "all-reduce-done", "all-gather-start", "all-gather-done",
-    "collective-permute-start", "collective-permute-done",
-    "partition-id", "replica-id", "send", "recv", "send-done",
-    "recv-done"))
+    "collective-permute", "collective-broadcast", "partition-id",
+    "replica-id", "send", "recv"))
 _DATA_MOVE = frozenset((
-    "transpose", "copy", "reshape", "bitcast", "bitcast-convert",
+    "transpose", "copy", "reshape", "bitcast-convert",
     "broadcast", "slice", "dynamic-slice", "dynamic-update-slice",
-    "concatenate", "gather", "scatter", "pad", "reverse", "copy-start",
-    "copy-done", "iota"))
+    "concatenate", "gather", "scatter", "pad", "reverse", "iota"))
+# what moves nothing and computes nothing
 _SKIP = frozenset((
     "parameter", "constant", "tuple", "get-tuple-element", "after-all",
-    "domain", "opt-barrier", "add-dependency", "partition-id"))
+    "domain", "opt-barrier", "add-dependency", "partition-id", "bitcast"))
 _REDUCES = frozenset(("reduce", "reduce-window", "select-and-scatter",
                       "sort"))
 # elementwise ops that actually do arithmetic (1 FLOP/elem model;
@@ -482,19 +577,37 @@ _ARITH = frozenset((
     "sqrt", "rsqrt", "cbrt", "tanh", "tan", "sine", "cosine", "atan2",
     "remainder", "negate", "abs", "erf", "sign", "floor", "ceil",
     "round-nearest-afz", "round-nearest-even", "clamp", "map"))
+_LOGIC = frozenset((
+    "compare", "select", "convert", "and", "or", "xor", "not",
+    "is-finite", "shift-left", "shift-right-logical",
+    "shift-right-arithmetic", "exponential-minus-one", "rng",
+    "rng-bit-generator", "reduce-precision", "real", "imag", "complex"))
+
+
+class _Instr:
+    """One parsed instruction: its name, result shapes, opcode, operand
+    text and everything after the operands (attributes, metadata)."""
+
+    __slots__ = ("name", "shapes", "opcode", "operands", "attrs")
+
+    def __init__(self, name, shapes, opcode, operands, attrs):
+        self.name, self.shapes, self.opcode = name, shapes, opcode
+        self.operands, self.attrs = operands, attrs
 
 
 def _dtype_bytes(dt: str) -> int:
     return _DTYPE_BYTES.get(dt, 4)
 
 
-def _shape_list(text: str) -> List[Tuple[str, Tuple[int, ...]]]:
-    """Every ``dtype[dims]`` token in ``text`` (operand lists carry the
-    operands' shapes inline in optimized-HLO text)."""
+def _shape_list(text: str) -> List[Tuple[str, Tuple[int, ...], bool]]:
+    """``(dtype, dims, in HBM)`` of every ``dtype[dims]`` token in
+    ``text``; a shape whose layout names a memory space on the chip
+    (``S(1)``: the compiler keeps it in VMEM) is no HBM traffic."""
     out = []
-    for dt, dims in _SHAPE_RE.findall(text):
+    for dt, dims, layout in _SHAPE_RE.findall(text):
         if dt in _DTYPE_BYTES or dt[0] in "sufc" or dt == "pred":
-            out.append((dt, tuple(int(d) for d in dims.split(",") if d)))
+            out.append((dt, tuple(int(d) for d in dims.split(",") if d),
+                        not _OFF_HBM_RE.search(layout)))
     return out
 
 
@@ -505,9 +618,12 @@ def _elems(dims) -> int:
     return n
 
 
-def _split_instr(rhs: str):
-    """(out_shapes, opcode, operand_text, attr_text) from an
-    instruction's right-hand side, or None."""
+def _nbytes(shapes, hbm_only=True) -> int:
+    return sum(_elems(d) * _dtype_bytes(dt) for dt, d, hbm in shapes
+               if hbm or not hbm_only)
+
+
+def _split_instr(name: str, rhs: str) -> Optional[_Instr]:
     rhs = rhs.strip()
     if rhs.startswith("("):            # tuple-shaped output
         depth, i = 0, 0
@@ -520,39 +636,89 @@ def _split_instr(rhs: str):
         m = _SHAPE_RE.match(rhs)
         if not m:
             return None
-        rest = rhs[m.end():]
-        if rest.startswith("{"):       # layout
-            rest = rest[rest.index("}") + 1:]
-        out_txt = rhs[:m.end()]
+        out_txt, rest = rhs[:m.end()], rhs[m.end():]
     rest = rest.strip()
     m = re.match(r"([\w\-]+)\(", rest)
     if not m:
         return None
-    opcode = m.group(1)
     depth, j = 0, m.end() - 1
     for j in range(m.end() - 1, len(rest)):
         depth += (rest[j] == "(") - (rest[j] == ")")
         if depth == 0:
             break
-    return (_shape_list(out_txt), opcode,
-            rest[m.end():j], rest[j + 1:])
+    return _Instr(name, _shape_list(out_txt), m.group(1),
+                  rest[m.end():j], rest[j + 1:])
 
 
-def _conv_flops(out_elems: int, op_shapes, attrs: str) -> int:
-    ksize = 1
-    m = re.search(r"size=([\dx]+)", attrs)
-    if m:
-        for d in m.group(1).split("x"):
-            ksize *= int(d)
-    cin = 1
-    m = re.search(r"dim_labels=[\w?]+_([\w?]+)->", attrs)
-    if m and len(op_shapes) >= 2:
-        rhs_labels, rhs_shape = m.group(1), op_shapes[1][1]
-        if "i" in rhs_labels and rhs_labels.index("i") < len(rhs_shape):
-            cin = rhs_shape[rhs_labels.index("i")]
-    m = re.search(r"feature_group_count=(\d+)", attrs)
-    groups = int(m.group(1)) if m else 1
-    return 2 * out_elems * ksize * cin // max(groups, 1)
+def _refs(operands: str) -> List[str]:
+    """The instruction names an operand list refers to."""
+    return [tok.split()[-1].lstrip("%")
+            for tok in _COMMENT_RE.sub("", operands).split(",")
+            if tok.strip()]
+
+
+def _attr(attrs: str, key: str) -> Optional[str]:
+    """The computation ``key=%name`` names (``calls``, ``body``, ...)."""
+    m = re.search(r"\b%s=%%?([\w.\-]+)" % key, attrs)
+    return m.group(1) if m else None
+
+
+def _phase(attrs: str) -> Optional[str]:
+    """The phase an instruction's ``op_name`` path carries, if any."""
+    m = _OP_NAME_RE.search(attrs)
+    ph = _PHASE_RE.search(m.group(1)) if m else None
+    if ph is None:
+        return None
+    if ph.group(1) == "bwd" and "rematted_computation" in m.group(1):
+        return "recompute"
+    return ph.group(1)
+
+
+def _window(attrs: str, key: str, rank: int, default: int):
+    """One field of ``window={...}`` as ``rank`` ints (``stride=2x2``;
+    of ``pad=1_2x1_2`` the low sides)."""
+    m = re.search(r"\b%s=([\d_x\-]+)" % key, attrs)
+    return [int(d.split("_")[0]) for d in m.group(1).split("x")] if m \
+        else [default] * rank
+
+
+def _conv_flops(out_shapes, op_shapes, attrs: str) -> int:
+    """2 a multiply-add that meets a real input element. The window's
+    taps that fall on padding or between the elements of a dilated
+    input are not work: the TPU compiler writes a 1x1 convolution's
+    gradient as a correlation over a window as large as the image,
+    padded by as much, and a strided layer's as one over a dilated
+    input, and 2 x outputs x window x channels would count those forty-
+    and fourfold."""
+    m = re.search(r"dim_labels=([\w?]+)_([\w?]+)->([\w?]+)", attrs)
+    if not m or len(op_shapes) < 2 or not out_shapes:
+        return 0
+    lhs_l, rhs_l, out_l = m.groups()
+    lhs, rhs, out = op_shapes[0][1], op_shapes[1][1], out_shapes[0][1]
+    if (len(lhs_l), len(rhs_l), len(out_l)) != (len(lhs), len(rhs),
+                                                len(out)):
+        return 0
+    spatial = [c for c in out_l if c.isdigit()]
+    rank = len(spatial)
+    stride = _window(attrs, "stride", rank, 1)
+    lhs_dil = _window(attrs, "lhs_dilate", rank, 1)
+    rhs_dil = _window(attrs, "rhs_dilate", rank, 1)
+    pad_lo = _window(attrs, "pad", rank, 0)
+    macs = _elems(out) * (rhs[rhs_l.index("i")] if "i" in rhs_l else 1)
+    for n, c in enumerate(sorted(spatial)):
+        # window fields are in the order of the spatial labels 0, 1, ...
+        size_in = lhs[lhs_l.index(c)]
+        size_k = rhs[rhs_l.index(c)]
+        size_out = out[out_l.index(c)]
+        last = (size_in - 1) * lhs_dil[n]
+        taps = 0
+        for o in range(size_out):
+            first = o * stride[n] - pad_lo[n]
+            for k in range(size_k):
+                pos = first + k * rhs_dil[n]
+                taps += 0 <= pos <= last and pos % lhs_dil[n] == 0
+        macs = macs * taps // max(size_out, 1)
+    return 2 * macs
 
 
 def _dot_flops(out_elems: int, op_shapes, attrs: str) -> int:
@@ -566,132 +732,258 @@ def _dot_flops(out_elems: int, op_shapes, attrs: str) -> int:
     return 2 * out_elems * k
 
 
-def hlo_op_breakdown(hlo_text: str) -> Dict[str, dict]:
-    """Parse optimized HLO text into ``{category: {"flops", "bytes",
-    "count"}}`` over the entry computation. FLOPs follow the standard
-    analytic model (2·N·K per dot/conv MAC, 1/elem for arithmetic,
-    in-elems per reduce); fused computations contribute their body's
-    conv/dot FLOPs to those categories and everything else to
-    ``fusion``, whose bytes are the fusion's interface traffic. The
-    per-category FLOPs sum to the reported total by construction —
-    cross-check against ``cost_analysis()['flops']`` lives in the
-    CompileRecord beside it."""
-    comps: Dict[str, list] = {}
+def _parse_hlo(hlo_text: str):
+    """``(computations, entry, shapes by instruction name)``."""
+    comps: Dict[str, List[_Instr]] = {}
+    defs: Dict[str, list] = {}
     entry = None
     cur: Optional[list] = None
+    last: Optional[_Instr] = None
     for line in hlo_text.splitlines():
         s = line.strip()
-        if s.endswith("{") and "=" not in s.split("(")[0]:
-            m = _COMP_RE.match(s)
-            if m:
-                cur = comps.setdefault(m.group(2), [])
-                if m.group(1):
-                    entry = m.group(2)
-            continue
-        if s.startswith("}"):
-            cur = None
-            continue
         if cur is None:
+            if s.endswith("{") and "=" not in s.split("(")[0]:
+                m = _COMP_RE.match(s)
+                if m:
+                    cur = comps.setdefault(m.group(2), [])
+                    last = None
+                    if m.group(1):
+                        entry = m.group(2)
+            continue
+        if s == "}":
+            cur = None
             continue
         m = _INSTR_RE.match(s)
         if m is None:
+            # a quoted attribute that spans lines (a Pallas kernel's
+            # metadata): what follows it, op_name too, is this line's
+            if last is not None:
+                last.attrs += " " + s
             continue
-        parsed = _split_instr(m.group(2))
-        if parsed is not None:
-            cur.append(parsed)
+        last = _split_instr(m.group(1), m.group(2))
+        if last is not None:
+            cur.append(last)
+            defs[last.name] = last.shapes
     if entry is None:          # single-computation module w/o ENTRY tag
         entry = next(iter(comps), None)
-    if entry is None:
-        return {}
+    return comps, entry, defs
 
-    def classify(parsed):
-        out_shapes, opcode, operands, attrs = parsed
-        op_shapes = _shape_list(operands)
-        out_elems = sum(_elems(d) for _dt, d in out_shapes)
-        out_bytes = sum(_elems(d) * _dtype_bytes(dt)
-                        for dt, d in out_shapes)
-        byts = out_bytes + sum(_elems(d) * _dtype_bytes(dt)
-                               for dt, d in op_shapes)
-        if opcode in _SKIP:
+
+def _analyze_hlo(hlo_text: str):
+    """One walk of the optimised HLO text, two tables.
+
+    Walked are the *top-level* instructions: the entry computation's
+    and, in its place, a ``while``'s body once a trip (the trip count
+    from ``known_trip_count`` or a condition ``i < constant``; where
+    neither says it the body counts once, and the third result counts
+    such loops). Each gives its FLOPs (2 N K a dot or convolution MAC,
+    those inside a fusion's body included; 1 an element for arithmetic,
+    the inputs' elements for a reduce) and its bytes: operands read +
+    results written, its least HBM traffic (what the compiler keeps on
+    the chip, memory space ``S(1)``, is none; an in-place update counts
+    its whole operand; an asynchronous pair counts once, at its
+    ``-done``; a custom call, a Pallas kernel, counts bytes and no
+    FLOPs).
+
+    Returns ``(by category, by set of phases, matrix FLOPs by phase,
+    loops counted once)``: ``{category: {"flops", "bytes", "count"}}``
+    as :func:`hlo_op_breakdown` documents, ``{set: {"ops", "flops",
+    "bytes"}}`` as :func:`hlo_phase_census` does, and ``{phase:
+    FLOPs}``: every convolution and dot under the phase of its OWN
+    ``op_name`` (a fusion's bytes cannot be told apart by phase; its
+    products can). The convolution and dot FLOPs of the three tables are
+    the same sum."""
+    comps, entry, defs = _parse_hlo(hlo_text)
+    if entry is None:
+        return {}, {}, {}, 0
+
+    def operand_shapes(ins):
+        inline = _shape_list(ins.operands)
+        if inline:
+            return inline
+        return [sh for ref in _refs(ins.operands)
+                for sh in defs.get(ref, ())]
+
+    def classify(ins):
+        opcode = ins.opcode
+        if opcode in _SKIP or opcode.endswith("-start") \
+                or 'custom_call_target="AllocateBuffer"' in ins.attrs:
             return None
+        out_elems = sum(_elems(d) for _dt, d, _hbm in ins.shapes)
+        if opcode.endswith("-done"):
+            # the pair's one transfer: one of its two sides is HBM
+            opcode = opcode[:-len("-done")]
+            op_shapes, byts = [], _nbytes(ins.shapes, hbm_only=False)
+        else:
+            op_shapes = operand_shapes(ins)
+            byts = _nbytes(ins.shapes) + _nbytes(op_shapes)
         if opcode == "convolution":
-            return "conv", _conv_flops(out_elems, op_shapes, attrs), byts
+            return "conv", _conv_flops(ins.shapes, op_shapes,
+                                       ins.attrs), byts
         if opcode in ("dot", "ragged-dot"):
-            return "dot", _dot_flops(out_elems, op_shapes, attrs), byts
+            return "dot", _dot_flops(out_elems, op_shapes, ins.attrs), byts
         if opcode in _COLLECTIVE:
             return "collective", 0, byts
         if opcode in _DATA_MOVE:
             return "transpose", 0, byts
         if opcode in _REDUCES:
-            in_elems = sum(_elems(d) for _dt, d in op_shapes) or out_elems
+            in_elems = sum(_elems(d) for _dt, d, _hbm in op_shapes) \
+                or out_elems
             return "elementwise", in_elems, byts
         if opcode == "fusion":
             return "fusion", 0, byts       # body folded in below
-        return ("elementwise", out_elems if opcode in _ARITH else 0,
-                byts) if opcode in _ARITH or opcode in (
-                    "compare", "select", "convert", "and", "or", "xor",
-                    "not", "is-finite", "shift-left",
-                    "shift-right-logical", "shift-right-arithmetic",
-                    "exponential-minus-one", "rng", "rng-bit-generator",
-                    "reduce-precision", "real", "imag", "complex",
-        ) else ("other", 0, byts)
+        if opcode in _ARITH:
+            return "elementwise", out_elems, byts
+        return ("elementwise" if opcode in _LOGIC else "other"), 0, byts
 
-    memo: Dict[str, Dict[str, int]] = {}
+    flops_memo: Dict[str, dict] = {}
 
     def body_flops(name, stack=()):
-        """Per-category FLOPs of a computation body (bytes inside a
-        fusion are not real memory traffic and are not counted)."""
-        if name in memo:
-            return memo[name]
+        """FLOPs of a computation body by category, a convolution's or a
+        dot's under ``(category, its own phase)`` (bytes inside a fusion
+        are not real memory traffic and are not counted)."""
+        if name in flops_memo:
+            return flops_memo[name]
         if name in stack or name not in comps:
             return {}
-        totals: Dict[str, int] = {}
-        for parsed in comps[name]:
-            cl = classify(parsed)
+        totals: dict = {}
+        for ins in comps[name]:
+            cl = classify(ins)
             if cl is None:
                 continue
             cat, fl, _by = cl
             if cat == "fusion":
-                m = re.search(r"calls=%?([\w.\-]+)", parsed[3])
-                if m:
-                    for c, f in body_flops(m.group(1),
-                                           stack + (name,)).items():
-                        c = c if c in ("conv", "dot") else "fusion"
-                        totals[c] = totals.get(c, 0) + f
+                for c, f in body_flops(_attr(ins.attrs, "calls"),
+                                       stack + (name,)).items():
+                    c = c if isinstance(c, tuple) else "fusion"
+                    totals[c] = totals.get(c, 0) + f
                 continue
+            if cat in ("conv", "dot"):
+                cat = (cat, _phase(ins.attrs) or "none")
             totals[cat] = totals.get(cat, 0) + fl
-        memo[name] = totals
+        flops_memo[name] = totals
         return totals
 
+    phase_memo: Dict[str, frozenset] = {}
+
+    def phases_of(ins, stack=()):
+        """The phases an instruction and, for a fusion, its members
+        (fusions inside it included) carry. What computes nothing has
+        no say: a constant keeps the scope that first made it."""
+        own = None if ins.opcode in _SKIP else _phase(ins.attrs)
+        found = {own} if own else set()
+        name = _attr(ins.attrs, "calls") if ins.opcode == "fusion" \
+            else None
+        if name in phase_memo:
+            return found | phase_memo[name]
+        if name is None or name in stack or name not in comps:
+            return found
+        inside = set()
+        for member in comps[name]:
+            inside |= phases_of(member, stack + (name,))
+        phase_memo[name] = frozenset(inside)
+        return found | inside
+
+    def trip_count(ins):
+        m = _TRIPS_RE.search(ins.attrs)
+        if m:
+            return int(m.group(1))
+        cond = comps.get(_attr(ins.attrs, "condition"), ())
+        consts = {i.name: i.operands.strip() for i in cond
+                  if i.opcode == "constant"}
+        for i in cond:
+            # counting up from zero by one, as ``lax.scan`` and
+            # ``fori_loop(0, n)`` lower
+            if i.opcode == "compare" and "direction=LT" in i.attrs:
+                for ref in _refs(i.operands):
+                    if consts.get(ref, "").isdigit():
+                        return int(consts[ref])
+        return None
+
     agg = {c: {"flops": 0, "bytes": 0, "count": 0} for c in CATEGORIES}
+    census: Dict[str, Dict[str, int]] = {}
     coll_ops: Dict[str, Dict[str, int]] = {}
-    for parsed in comps[entry]:
-        cl = classify(parsed)
-        if cl is None:
-            continue
-        cat, fl, by = cl
-        agg[cat]["bytes"] += by
-        agg[cat]["count"] += 1
-        if cat == "collective":
-            # per-opcode sub-buckets: an fsdp step's all-gather
-            # (param gather before forward) and reduce-scatter (grad
-            # shard-reduce) are distinguishable from the dp all-reduce
-            op = parsed[1]
-            sub = coll_ops.setdefault(op, {"bytes": 0, "count": 0})
-            sub["bytes"] += by
-            sub["count"] += 1
-        if cat == "fusion":
-            m = re.search(r"calls=%?([\w.\-]+)", parsed[3])
-            sub = body_flops(m.group(1), (entry,)) if m else {}
-            for c, f in sub.items():
-                c = c if c in ("conv", "dot") else "fusion"
-                agg[c]["flops"] += f
-        else:
-            agg[cat]["flops"] += fl
+    by_phase: Dict[str, int] = {}       # matrix FLOPs, product by product
+    once = [0]
+
+    def walk(comp, trips, stack):
+        for ins in comps[comp]:
+            if ins.opcode == "while":
+                body = _attr(ins.attrs, "body")
+                if body in comps and body not in stack:
+                    n = trip_count(ins)
+                    once[0] += n is None
+                    walk(body, trips * (n or 1), stack + (comp,))
+                continue
+            cl = classify(ins)
+            if cl is None:
+                continue
+            cat, fl, by = cl
+            agg[cat]["bytes"] += by * trips
+            agg[cat]["count"] += trips
+            matrix = 0                  # convolutions and dots inside
+            if cat == "collective":
+                # per-opcode sub-buckets: an fsdp step's all-gather
+                # (param gather before forward) and reduce-scatter (grad
+                # shard-reduce) are distinguishable from the dp all-reduce
+                sub = coll_ops.setdefault(ins.opcode,
+                                          {"bytes": 0, "count": 0})
+                sub["bytes"] += by * trips
+                sub["count"] += trips
+            if cat == "fusion":
+                for c, f in body_flops(_attr(ins.attrs, "calls"),
+                                       (comp,)).items():
+                    if isinstance(c, tuple):
+                        c, phase = c
+                        matrix += f
+                        by_phase[phase] = by_phase.get(phase, 0) + f * trips
+                    else:
+                        c = "fusion"
+                    agg[c]["flops"] += f * trips
+            else:
+                agg[cat]["flops"] += fl * trips
+                if cat in ("conv", "dot"):
+                    matrix = fl
+                    phase = _phase(ins.attrs) or "none"
+                    by_phase[phase] = by_phase.get(phase, 0) + fl * trips
+            found = phases_of(ins)
+            row = census.setdefault(
+                "+".join(p for p in PHASES if p in found) or "none",
+                {"ops": 0, "flops": 0, "bytes": 0})
+            row["ops"] += trips
+            row["flops"] += matrix * trips
+            row["bytes"] += by * trips
+
+    walk(entry, 1, ())
     if coll_ops:
         agg["collective"]["by_op"] = coll_ops
-    return {c: v for c, v in agg.items()
-            if v.get("count") or v.get("flops")}
+    return ({c: v for c, v in agg.items()
+             if v.get("count") or v.get("flops")}, census, by_phase,
+            once[0])
+
+
+def hlo_op_breakdown(hlo_text: str) -> Dict[str, dict]:
+    """Parse optimized HLO text into ``{category: {"flops", "bytes",
+    "count"}}`` over the program's top-level instructions
+    (:func:`_analyze_hlo` has the rules). Fused computations contribute
+    their body's conv/dot FLOPs to those categories and everything else
+    to ``fusion``, whose bytes are the fusion's interface traffic. The
+    per-category FLOPs sum to the reported total by construction —
+    cross-check against ``cost_analysis()['flops']`` lives in the
+    CompileRecord beside it."""
+    return _analyze_hlo(hlo_text)[0]
+
+
+def hlo_phase_census(hlo_text: str) -> Dict[str, dict]:
+    """``{set: {"ops", "flops", "bytes"}}``: the program's top-level
+    instructions grouped by the SET of phases they and, for a fusion,
+    their members carry in ``op_name`` (``fwd``, ``recompute``, ``bwd``,
+    ``update``, ``metric`` as the fused step scopes them; joined by
+    ``+`` in that order, ``none`` where no member names one: copies and
+    layout work the compiler added). ``flops`` are the convolutions' and
+    dots' inside, ``bytes`` the instruction's operands and results: the
+    least the chip moves for it, not a measured time."""
+    return _analyze_hlo(hlo_text)[1]
 
 
 # ---------------------------------------------------------------------------
@@ -775,7 +1067,9 @@ def analyze(flops, bytes_accessed, step_time_s=None,
 def hbm_stats(device=None) -> dict:
     """Live-buffer accounting FOR ONE DEVICE: ``device.memory_stats()``
     where the backend provides it (TPU), else ``jax.live_arrays()``
-    (CPU — no allocator limit, so ``limit_bytes`` is None). The
+    (CPU — no allocator, so ``limit_bytes`` and ``reserved_bytes``,
+    what the runtime holds for compiled programs' temporaries, are
+    None). One reading: in use and reserved are of the same moment. The
     live_arrays walk is per-device exact: a sharded array contributes
     only the bytes of its shards resident on ``device`` (an
     fsdp-sharded pack bills 1/fsdp per chip), never its GLOBAL
@@ -786,8 +1080,8 @@ def hbm_stats(device=None) -> dict:
     try:
         dev = device if device is not None else jax.devices()[0]
     except Exception:
-        return {"live_bytes": 0, "limit_bytes": None,
-                "peak_bytes": None, "source": "none"}
+        return {"live_bytes": 0, "limit_bytes": None, "peak_bytes": None,
+                "reserved_bytes": None, "source": "none"}
     ms = None
     try:
         ms = dev.memory_stats()
@@ -799,6 +1093,9 @@ def hbm_stats(device=None) -> dict:
                                 if ms.get("bytes_limit") else None),
                 "peak_bytes": (int(ms["peak_bytes_in_use"])
                                if ms.get("peak_bytes_in_use") else None),
+                "reserved_bytes": (int(ms["bytes_reserved"])
+                                   if ms.get("bytes_reserved") is not None
+                                   else None),
                 "source": "memory_stats"}
     live = 0
     for arr in jax.live_arrays():
@@ -812,40 +1109,8 @@ def hbm_stats(device=None) -> dict:
                 live += int(arr.nbytes)
         except Exception:
             pass
-    return {"live_bytes": live, "limit_bytes": None,
-            "peak_bytes": None, "source": "live_arrays"}
-
-
-class HbmWatermark:
-    """Per-step live-buffer watermark. ``sample()`` after each step;
-    ``peak`` is monotone over the run and the ``hbm.*`` gauges
-    (including ``hbm.headroom_bytes``, exported by the MetricsServer)
-    track the latest sample. ``limit_bytes`` overrides the device
-    limit where the backend reports none (CPU tests)."""
-
-    def __init__(self, device=None, limit_bytes: Optional[int] = None):
-        self.device = device
-        self.limit = limit_bytes
-        self.peak = 0
-        self.last = 0
-
-    def sample(self) -> int:
-        s = hbm_stats(self.device)
-        self.last = s["live_bytes"]
-        if self.limit is None:
-            self.limit = s["limit_bytes"]
-        self.peak = max(self.peak, self.last, s["peak_bytes"] or 0)
-        if _tel.enabled():
-            _tel.set_gauge("hbm.live_bytes", self.last)
-            _tel.set_gauge("hbm.peak_bytes", self.peak)
-            if self.limit:
-                _tel.set_gauge("hbm.headroom_bytes",
-                               self.limit - self.last)
-        return self.last
-
-    @property
-    def headroom_bytes(self) -> Optional[int]:
-        return self.limit - self.last if self.limit else None
+    return {"live_bytes": live, "limit_bytes": None, "peak_bytes": None,
+            "reserved_bytes": None, "source": "live_arrays"}
 
 
 def device_memory_limit(device=None) -> Optional[int]:

@@ -160,8 +160,11 @@ def test_jax_duration_events_become_spans_once():
 SETUP = {"fit.bind", "fit.init_params", "fit.init_optimizer",
          "fit.fused_build"}
 JAX = {"jax.trace", "jax.lower", "jax.backend_compile", "jax.cache_read"}
+# ``step.census``: with telemetry on the fused step's program is read
+# once, inside ``step.build``
 STEP = {True: {"fit.step", "fit.next", "step.marshal", "step.dispatch",
-               "step.write_back", "fit.callbacks", "step.build"},
+               "step.write_back", "fit.callbacks", "step.build",
+               "step.census"},
         False: {"fit.step", "fit.next", "fit.forward_backward",
                 "fit.update", "fit.update_metric", "fit.callbacks"}}
 
@@ -174,7 +177,7 @@ def test_fit_leaves_exactly_the_named_spans(monkeypatch, fused):
     count = Counter(s[0] for s in spans)
     assert set(count) - JAX == SETUP | STEP[fused]
     assert all(count[name] == 1 for name in SETUP)
-    per_step = STEP[fused] - {"fit.next", "step.build"}
+    per_step = STEP[fused] - {"fit.next", "step.build", "step.census"}
     assert all(count[name] == steps for name in per_step), count
     # the next() that found the epoch over is a span; its step is not
     assert count["fit.next"] == steps + 1
@@ -252,9 +255,11 @@ def _hlo_of_fused_step(monkeypatch, scopes):
         jfn = real_jit(fn, **kw)
         if getattr(fn, "__name__", "") == "step":
             class Spy:
-                def __call__(self, *args):
+                # telemetry is on, so the step's jit is xprof's wrapper
+                # around this one: it lowers, then runs what it built
+                def lower(self, *args):
                     lowered.append(jfn.lower(*args))
-                    return jfn(*args)
+                    return lowered[-1]
 
                 def _cache_size(self):
                     return jfn._cache_size()

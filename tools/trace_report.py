@@ -1037,14 +1037,14 @@ def render_compile(rec):
     if not sites:
         return "no xprof compile records\n"
     rows = [("site", "compiles", "total_s", "last_s", "flops",
-             "peak_bytes")]
+             "held_bytes")]
     for name, s in sorted(sites.items()):
         last = s.get("last") or {}
         rows.append((name, str(s.get("compiles", 0)),
                      "%.3f" % s.get("compile_time_s", 0.0),
                      "%.3f" % (last.get("compile_time_s") or 0.0),
                      "%.3g" % (last.get("flops") or 0),
-                     _fmt_bytes(last.get("peak_bytes") or 0)))
+                     _fmt_bytes(last.get("held_bytes") or 0)))
     out = ["compile registry (%d sites, %d compiles, %.3fs total):"
            % (len(sites), (xp.get("totals") or {}).get("compiles", 0),
               (xp.get("totals") or {}).get("compile_time_s", 0.0)), ""]
@@ -1097,14 +1097,14 @@ def render_memory(rec):
     sites = xp.get("sites") or {}
     out = []
     if sites:
-        rows = [("site", "arg", "out", "temp", "peak")]
+        rows = [("site", "arg", "out", "temp", "held")]
         for name, s in sorted(sites.items()):
             last = s.get("last") or {}
             rows.append((name,
                          _fmt_bytes(last.get("argument_bytes") or 0),
                          _fmt_bytes(last.get("output_bytes") or 0),
                          _fmt_bytes(last.get("temp_bytes") or 0),
-                         _fmt_bytes(last.get("peak_bytes") or 0)))
+                         _fmt_bytes(last.get("held_bytes") or 0)))
         out += ["memory analysis per executable:", ""] + _table(rows)
     hbm = xp.get("hbm") or {}
     peak = rec.get("peak_hbm_bytes")
