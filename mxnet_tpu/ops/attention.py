@@ -18,7 +18,24 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   skipped, and no ``[T, T]`` tensor reaches HBM in either pass. The XLA
   lowering below wrote its float32 scores out and read them back several
   times a pass: 577 of a 1,104 ms step at 8,192 tokens on the v5e
-  (PERF.md, PR 26).
+  (PERF.md, PR 26). The kernel wants ``[B, H, T, D]``, the graph holds
+  ``[B*T, H*D]``: each operand goes there, and the result and every
+  cotangent comes back, in ONE Pallas pass (``pallas_kernels.
+  attention_relayout``) that reads a head's tile of
+  rows where it lies and writes it where it belongs, row-major at both
+  ends, so nothing is transposed and XLA lays the producers' results
+  out for it. Rotary and the query's ``1/sqrt(D)`` ride that pass:
+  float32 from the operand's dtype in VMEM, ``x * C + partner(x) * S``
+  on the whole lanes that hold the turned columns, the scale, ONE
+  rounding to the compute dtype; the backward pass is the transposed
+  pass over the cotangent (the scale, then the rotation by the negative
+  angle). No float32 copy of an operand reaches HBM. Counted
+  ``lower.attention_layout.fused`` once a traced op: the one way there
+  is, for every shape the kernel takes (XLA's own rotation, slices,
+  concatenation and two-step transposes were 55 of the GLM cell's 379
+  ms a step, and written as one elementwise chain or met in the
+  kernel's ``SEQ_MINOR`` layout they compiled to MORE copies: PERF.md,
+  PR 37).
 * ``xla_blockwise``: everything else. Queries go in blocks of
   ``BLOCK_Q``; a block reads only the keys at or before its end, so the
   upper triangle is never computed, and each block runs under
@@ -48,29 +65,51 @@ SPLASH_BLOCK = 512
 _NEG = -1e30
 
 
-def rope(x, theta, scale=1.0, rotary_dim=0):
+@functools.lru_cache(None)
+def rope_tables(t, theta, half, lanes=1):
+    """``(C, S) [t, 2 * half]`` float32: ``C = [cos, cos]``, ``S = [-sin,
+    sin]`` of the half-split convention, position by row; widened on the
+    left with 1 and 0 to a whole number of ``lanes`` columns."""
+    inv = theta ** (-np.arange(half, dtype=np.float64) / half)
+    ang = np.arange(t, dtype=np.float64)[:, None] * inv[None]
+    cos = np.cos(ang).astype(np.float32)
+    sin = np.sin(ang).astype(np.float32)
+    pad = ((0, 0), (-2 * half % lanes, 0))
+    return (np.pad(np.concatenate([cos, cos], 1), pad, constant_values=1),
+            np.pad(np.concatenate([-sin, sin], 1), pad))
+
+
+def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0):
     """Rotary position embedding (Su et al., arXiv:2104.09864), the
     half-split convention of the published modelling code: ``x [T, ...,
-    D]``, position = index along axis 0, over the whole head or, with
+    D]``, position = index along ``pos_axis``, over the whole head or, with
     ``rotary_dim``, over the LAST ``rotary_dim`` columns of it (the
     frequencies are those of a head ``rotary_dim`` wide; the columns before
     pass through). The result is multiplied by ``scale`` before it is
-    rounded to ``x``'s dtype."""
+    rounded to ``x``'s dtype.
+
+    Written ``x * C + partner(x) * S`` over the turned columns (``C, S``:
+    :func:`rope_tables`; a column's partner is the one half the turned
+    width away, and ``a + (-b) * s`` is ``a - b * s`` exactly): float32
+    from ``x``'s dtype, one rounding. The pass that takes the splash
+    kernel its operands (``pallas_kernels.attention_relayout``) does the
+    same arithmetic on the tiles it moves."""
     import jax.numpy as jnp
 
-    t, d = x.shape[0], x.shape[-1]
+    d = x.shape[-1]
     keep = d - rotary_dim if rotary_dim else 0
     half = (d - keep) // 2
-    inv = theta ** (-np.arange(half, dtype=np.float64) / half)
-    ang = np.arange(t, dtype=np.float64)[:, None] * inv[None]
-    shape = (t,) + (1,) * (x.ndim - 2) + (half,)
-    cos = jnp.asarray(np.cos(ang), jnp.float32).reshape(shape)
-    sin = jnp.asarray(np.sin(ang), jnp.float32).reshape(shape)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., keep:keep + half], xf[..., keep + half:]
-    out = jnp.concatenate(([xf[..., :keep]] if keep else [])
-                          + [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                          axis=-1)
+    out = xf = x.astype(jnp.float32)
+    if half:
+        shape = [1] * x.ndim
+        shape[pos_axis], shape[-1] = x.shape[pos_axis], 2 * half
+        c, s = (a.reshape(shape)
+                for a in rope_tables(x.shape[pos_axis], theta, half))
+        r = xf[..., keep:]
+        partner = jnp.concatenate([r[..., half:], r[..., :half]], axis=-1)
+        out = r * c + partner * s
+        if keep:
+            out = jnp.concatenate([xf[..., :keep], out], axis=-1)
     return (out * scale if scale != 1.0 else out).astype(x.dtype)
 
 
@@ -144,6 +183,23 @@ def attend_blockwise(q, k, v, scale, block=BLOCK_Q):
     return jnp.concatenate(outs, axis=0)
 
 
+def _relaid(x, tables=(), back=False, **how):
+    """``x`` through ``pallas_kernels.attention_relayout``, whose backward
+    pass is the same pass over the cotangent, the other way."""
+    import jax
+
+    from .pallas_kernels import attention_relayout
+
+    @jax.custom_vjp
+    def f(x):
+        return attention_relayout(x, tables, back=back, **how)
+
+    f.defvjp(lambda x: (f(x), None),
+             lambda _, g: (attention_relayout(g, tables, back=not back,
+                                              **how),))
+    return f(x)
+
+
 @register_op("CausalAttention")
 class CausalAttention(Operator):
     """``softmax(q k^T / sqrt(D) + causal mask) v`` per head, ``num_heads``
@@ -212,32 +268,28 @@ class CausalAttention(Operator):
                          self.head_dim)
         b = q.shape[0] // t
         scale = 1.0 / float(np.sqrt(d))
-        q = q.reshape(b, t, hq, d)
-        k = k.reshape(b, t, hkv, d)
-        v = v.reshape(b, t, hkv, d)
-        splash = self._splash_applies() and pallas_kernels.pallas_available()
-        if self.rotary:
-            # the splash kernel takes queries already scaled: folded into
-            # the rotation, before the one rounding to the compute dtype
-            q = jax.vmap(functools.partial(
-                rope, theta=self.rope_theta,
-                scale=scale if splash else 1.0,
-                rotary_dim=self.rotary_dim))(q)
-            k = jax.vmap(functools.partial(rope, theta=self.rope_theta,
-                                           rotary_dim=self.rotary_dim))(k)
-        elif splash:
-            q = (q.astype("float32") * scale).astype(q.dtype)
-        if splash:
-            _tel.inc("lower.attention_kernel.pallas_splash")
-            qg = q.reshape(b, t, hkv, hq // hkv, d).transpose(0, 2, 3, 1, 4)
-            out = attend_splash(qg, k.transpose(0, 2, 1, 3),
-                                v.transpose(0, 2, 1, 3),
-                                ctx.kept.get("attention"))
-            out = out.transpose(0, 3, 1, 2, 4)
-            return [out.reshape(b * t, hq * d).astype(inputs[0].dtype)], []
-        _tel.inc("lower.attention_kernel.xla_blockwise")
-        qg = q.reshape(b, t, hkv, hq // hkv, d)
-        out = jax.lax.map(
-            lambda x: attend_blockwise(x[0], x[1], x[2], scale),
-            (qg, k, v))
-        return [ctx.keep(out.reshape(b * t, hq * d), "attention")], []
+        half = (self.rotary_dim or d) // 2 if self.rotary else 0
+        if not (self._splash_applies()
+                and pallas_kernels.pallas_available()):
+            _tel.inc("lower.attention_kernel.xla_blockwise")
+            q = q.reshape(b, t, hkv, hq // hkv, d)
+            k = k.reshape(b, t, hkv, d)
+            if half:
+                q, k = (rope(x, self.rope_theta, rotary_dim=2 * half,
+                             pos_axis=1) for x in (q, k))
+            out = jax.lax.map(
+                lambda x: attend_blockwise(x[0], x[1], x[2], scale),
+                (q, k, v.reshape(b, t, hkv, d)))
+            return [ctx.keep(out.reshape(b * t, hq * d), "attention")], []
+        _tel.inc("lower.attention_kernel.pallas_splash")
+        _tel.inc("lower.attention_layout.fused")
+        tables = rope_tables(t, self.rope_theta, half, 128) if half else ()
+        # the kernel takes queries already scaled: folded into the pass
+        # that turns them, before its one rounding to the compute dtype
+        q = _relaid(q, tables, batch=b, heads=hq, half=half, scale=scale)
+        k = _relaid(k, tables, batch=b, heads=hkv, half=half)
+        v = _relaid(v, batch=b, heads=hkv)
+        out = attend_splash(q.reshape(b, hkv, hq // hkv, t, d), k, v,
+                            ctx.kept.get("attention"))
+        return [_relaid(out.reshape(b, hq, t, d), back=True, batch=b,
+                        heads=hq)], []

@@ -33,7 +33,7 @@ __all__ = ["fused_linear", "flash_attention", "pallas_available",
            "norm_act_applicable", "ssd_chunk_applicable",
            "ssd_chunk_forward", "ssd_chunk_backward",
            "delta_chunk_applicable", "delta_chunk_forward",
-           "delta_chunk_backward"]
+           "delta_chunk_backward", "attention_relayout"]
 
 # float32 MXU-friendly tiles (sublane 8, lane 128)
 TILE_M = 128
@@ -2090,3 +2090,92 @@ def grouped_experts_forward(*args, **static):
 def grouped_experts_backward(*args, **static):
     """:func:`_experts_backward` through its shared ``jax.jit``."""
     return _experts_jitted()[1](*args, **static)
+
+
+# ---------------------------------------------------------------------------
+# Attention's operands between the graph's layout and the kernel's
+# (``ops/attention.py`` ``CausalAttention``, the ``pallas_splash`` path)
+# ---------------------------------------------------------------------------
+#
+# The graph holds ``[B*T, H*D]`` rows, the splash kernels take ``[B, H, T,
+# D]``. One pass an operand and direction: a grid step reads a ``[rows, D]``
+# tile of one head where it lies and writes it where it belongs (the
+# relayout is the two index maps, nothing is transposed in VMEM), and on the
+# way rotary positions turn the LAST columns of the head and ``scale``
+# multiplies all of it: float32 from the operand's dtype, ONE rounding to
+# it. ``y = x * C + partner(x) * S`` over the whole lanes that hold the
+# turned columns (``tables = (C, S) [T, w]``: 1 and 0 on lanes that pass
+# through, which are also copied unchanged; the sign of the rotation is in
+# ``S``; a lane's partner is half the turned width away: two rotations of
+# the lanes and a select). The pass back is its transpose: ``scale`` first,
+# then the rotation by the negative angle (``x * C - partner(x) * S``). No
+# accumulator; heads are the innermost grid axis, so a tile of the tables
+# is fetched once a block of positions.
+
+# rows a grid step: a 168 MB pass took 0.37 ms at 512, 0.32 at 1,024 and
+# 0.31 at 2,048 (my chip runs, PR 37): the steps' own time, 0.35 us each
+_RELAYOUT_ROWS = 1024
+
+
+def _turned(x, tables, half, scale, back, dtype):
+    """A ``[rows, D]`` tile turned and scaled (the section's comment)."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental.pallas import tpu as pltpu
+
+    x = x.astype(jnp.float32)
+    if back and scale != 1.0:
+        x = x * scale
+    if half:
+        c, s = tables
+        w = c.shape[-1]
+        start = x.shape[-1] - w
+        r = x[:, start:]
+        lane = lax.broadcasted_iota(jnp.int32, r.shape, 1)
+        ps = jnp.where(lane < w - half, pltpu.roll(r, w - half, 1),
+                       pltpu.roll(r, half, 1)) * s
+        y = jnp.where(lane < w - 2 * half, r,
+                      r * c - ps if back else r * c + ps)
+        x = jnp.concatenate([x[:, :start], y], axis=1) if start else y
+    if not back and scale != 1.0:
+        x = x * scale
+    return x.astype(dtype)
+
+
+def attention_relayout(x, tables=(), *, batch, heads, half=0, scale=1.0,
+                       back=False):
+    """``x [B*T, H*D]`` -> ``[B, H, T, D]``, the last ``2 * half`` columns
+    of every head turned by ``tables`` and all of it times ``scale``; with
+    ``back`` its transpose, ``[B, H, T, D]`` -> ``[B*T, H*D]``: the way of
+    the kernel's result and of every cotangent (the section's comment)."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if back:
+        t, d = x.shape[2:]
+    else:
+        t, d = x.shape[0] // batch, x.shape[1] // heads
+    bt = next((n for n in range(min(_RELAYOUT_ROWS, t), 0, -128)
+               if t % n == 0), t)
+    nt = t // bt
+
+    def kernel(x_ref, *refs):
+        o_ref = refs[-1]
+        o_ref[...] = _turned(x_ref[...], [r[...] for r in refs[:-1]], half,
+                             scale, back, o_ref.dtype)
+
+    rows = pl.BlockSpec((bt, d), lambda b, i, h: (b * nt + i, h))
+    by_head = pl.BlockSpec((None, None, bt, d), lambda b, i, h: (b, h, i, 0))
+    table = [pl.BlockSpec((bt, c.shape[1]), lambda b, i, h: (i, 0))
+             for c in tables]
+    shape = (batch * t, heads * d) if back else (batch, heads, t, d)
+    return pallas_call(
+        kernel, x, *tables, grid=(batch, nt, heads),
+        in_specs=[by_head if back else rows] + table,
+        out_specs=rows if back else by_head,
+        out_shape=jax.ShapeDtypeStruct(shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="attention_rows_from_heads" if back
+        else "attention_heads_from_rows")

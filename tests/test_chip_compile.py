@@ -80,6 +80,37 @@ def test_splash_attention_at_256_wide_heads_compiles_for_the_chip(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("batch,t,heads,d,turned,dtype", [
+    (1, 8192, 20, 256, 64, "bfloat16"),     # GLM's queries and keys
+    (1, 8192, 32, 128, 128, "bfloat16"),    # Nemotron's queries
+    (1, 8192, 15, 128, 0, "bfloat16"),      # Olmo's, and every value
+    (2, 1024, 3, 256, 256, "bfloat16"),     # a partner in the other tile
+    (1, 1024, 3, 256, 192, "bfloat16"),     # turned columns over two tiles
+    (3, 640, 2, 128, 2, "float32")],        # one pair; 128-row tiles
+    ids=["glm", "nemotron", "plain", "two-tiles", "tile-and-a-half",
+         "one-pair"])
+def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
+                                                        heads, d, turned,
+                                                        dtype):
+    """``CausalAttention``'s way to the splash kernel and back, at every
+    kind of shape ``_splash_applies`` admits: the lanes' rotation by half
+    the turned width and the select are Mosaic's to refuse."""
+    from mxnet_tpu.ops import attention, pallas_kernels as pk
+
+    half = turned // 2
+    tables = attention.rope_tables(t, 1e4, half, 128) if half else ()
+    how = dict(batch=batch, heads=heads, half=half, scale=0.125)
+    there = jax.jit(lambda x: pk.attention_relayout(x, tables, **how)).lower(
+        jax.ShapeDtypeStruct((batch * t, heads * d), dtype,
+                             sharding=one_chip)).compile()
+    back = jax.jit(lambda g: pk.attention_relayout(
+        g, tables, back=True, **how)).lower(
+            jax.ShapeDtypeStruct((batch, heads, t, d), dtype,
+                                 sharding=one_chip)).compile()
+    for compiled in (there, back):
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_gated_experts_compile_for_the_chip(one_chip):
     """The gated grouped products at the cell's sizes (8 held experts of
     2,048 x 1,536, 8,192 rows, top-4 of 64): both written passes."""

@@ -25,7 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import glm4_moe_lite as ref  # noqa: E402
 from benchmark.reference import nemotron_h, olmo_hybrid  # noqa: E402
 from test_nemotron_h import (Ring, against, aux_states, close,  # noqa: E402
-                             rng_inputs)
+                             rng_inputs, run_op)
 from test_nemotron_h import TOY as NEMOTRON_TOY  # noqa: E402
 from test_olmo_hybrid import TOY as OLMO_TOY  # noqa: E402
 from test_olmo_hybrid import toy_batches  # noqa: E402
@@ -105,6 +105,198 @@ def test_rotary_dim_of_the_whole_head_is_the_whole_head():
         sym.CausalAttention(query=sym.Variable("q"), num_heads=1,
                             num_kv_heads=1, head_dim=8, seq_len=4,
                             rotary_dim=5).infer_shape(q=(4, 8))
+
+
+def split_rope(x, theta, scale=1.0, rotary_dim=0):
+    """``ops.attention.rope`` as it was before the rotation was written
+    over the turned columns in one piece (PR 37): the two halves cut out,
+    turned apart and put back. The reference the new form has to equal."""
+    t, d = x.shape[0], x.shape[-1]
+    keep = d - rotary_dim if rotary_dim else 0
+    half = (d - keep) // 2
+    inv = theta ** (-np.arange(half, dtype=np.float64) / half)
+    ang = np.arange(t, dtype=np.float64)[:, None] * inv[None]
+    shape = (t,) + (1,) * (x.ndim - 2) + (half,)
+    cos = jnp.asarray(np.cos(ang), jnp.float32).reshape(shape)
+    sin = jnp.asarray(np.sin(ang), jnp.float32).reshape(shape)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., keep:keep + half], xf[..., keep + half:]
+    out = jnp.concatenate(([xf[..., :keep]] if keep else [])
+                          + [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1)
+    return (out * scale if scale != 1.0 else out).astype(x.dtype)
+
+
+@pytest.mark.parametrize("head_dim,rotary_dim,dtype", [
+    (256, 64, "bfloat16"), (128, 0, "bfloat16"), (16, 4, "float32"),
+    (16, 16, "float32")])
+def test_rope_is_the_split_form_bit_for_bit(head_dim, rotary_dim, dtype):
+    """``x * C + partner(x) * S`` over the turned columns is the split and
+    concatenated rotation to the bit, with and without the scale folded
+    in, and so is its cotangent (to float32 rounding at most)."""
+    from mxnet_tpu.ops import attention
+
+    x, g = (jnp.asarray(v, dtype) for v in rng_inputs(
+        2, x=(64, 3, head_dim), g=(64, 3, head_dim)).values())
+    for scale in (1.0, 1.0 / float(np.sqrt(head_dim))):
+        got, back = jax.vjp(lambda x: attention.rope(
+            x, 1e4, scale, rotary_dim), x)
+        want, want_back = jax.vjp(lambda x: split_rope(
+            x, 1e4, scale, rotary_dim), x)
+        assert got.dtype == want.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        np.testing.assert_allclose(
+            np.asarray(back(g)[0], np.float32),
+            np.asarray(want_back(g)[0], np.float32), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("batch,t,heads,head_dim,turned,dtype", [
+    (1, 256, 2, 256, 64, "bfloat16"), (2, 128, 3, 128, 128, "bfloat16"),
+    (1, 128, 2, 128, 0, "bfloat16"), (1, 256, 2, 256, 192, "float32"),
+    (3, 128, 1, 128, 2, "float32")],
+    ids=["glm", "nemotron", "plain", "tile-and-a-half", "one-pair"])
+def test_the_pass_to_the_kernel_is_rope_and_a_transpose(batch, t, heads,
+                                                        head_dim, turned,
+                                                        dtype):
+    """The Pallas pass that takes the splash kernel its operands (the
+    interpreter here) against ``rope`` with the scale folded in and a
+    transpose to ``[B, H, T, D]``, and its backward pass against theirs:
+    the way there equal to the bit in ``bfloat16`` (one rounding of the
+    same float32 arithmetic), everything else to a rounding."""
+    from mxnet_tpu.ops import attention
+
+    half, scale = turned // 2, 0.125
+    tables = attention.rope_tables(t, 1e4, half, 128) if half else ()
+    shape = (batch, t, heads, head_dim)
+    x = jnp.asarray(rng_inputs(3, x=(batch * t, heads * head_dim))["x"],
+                    dtype)
+    g = jnp.asarray(rng_inputs(4, g=(batch, heads, t, head_dim))["g"], dtype)
+    got, back = jax.vjp(lambda x: attention._relaid(
+        x, tables, batch=batch, heads=heads, half=half, scale=scale), x)
+    want, want_back = jax.vjp(lambda x: attention.rope(
+        x.reshape(shape), 1e4, scale, turned, pos_axis=1).transpose(
+            0, 2, 1, 3) if half else (x.reshape(shape).astype(
+                jnp.float32) * scale).astype(dtype).transpose(0, 2, 1, 3), x)
+    # autodiff of ``rope`` adds the cotangent's parts in another order: a
+    # float32 rounding apart, which a rounding to ``bfloat16`` can widen
+    # to one of its own
+    exact = dtype == "bfloat16"
+    for a, b, tol in ((got, want, 0 if exact else 1e-6),
+                      (back(g)[0], want_back(g)[0], 2 ** -7 if exact
+                       else 1e-6)):
+        assert a.dtype == b.dtype == x.dtype and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=tol,
+                                   atol=tol * 1e-2)
+    # the way back undoes the way there where nothing is turned or scaled
+    there = attention._relaid(x, batch=batch, heads=heads)
+    np.testing.assert_array_equal(
+        np.asarray(attention._relaid(there, back=True, batch=batch,
+                                     heads=heads), np.float32),
+        np.asarray(x, np.float32))
+
+
+def _attention_over(monkeypatch, splash, inputs, head, **op):
+    """One traced ``CausalAttention`` through bind / forward / backward on
+    the path asked for, and what the lowering counted."""
+    from mxnet_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "pallas_available", lambda: splash)
+    net = sym.CausalAttention(**op, **{k: sym.Variable(k) for k in inputs})
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        return run_op(net, inputs, head), {
+            n: telemetry.peek("lower." + n) for n in (
+                "attention_kernel.pallas_splash",
+                "attention_kernel.xla_blockwise", "attention_layout.fused",
+                "attention_layout.split")}
+    finally:
+        telemetry.disable()
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,rotary", [
+    (2, 2, 256, dict(rotary_dim=64, rope_theta=1e6)),
+    (4, 2, 128, dict(rope_theta=1e4)), (3, 3, 128, dict(rotary=False))],
+    ids=["glm", "nemotron", "olmo"])
+def test_the_splash_path_agrees_with_the_xla_path(monkeypatch, heads,
+                                                  kv_heads, head_dim,
+                                                  rotary):
+    """The three models' heads at 512 positions: operands turned and laid
+    out by the Pallas passes and attended by the splash kernel (all
+    interpreted here) against the XLA lowering: the output and the three
+    input gradients; and the passes are counted once a traced op."""
+    t = 512
+    inputs = rng_inputs(7, query=(t, heads * head_dim),
+                        key=(t, kv_heads * head_dim),
+                        value=(t, kv_heads * head_dim))
+    head = rng_inputs(8, h=(t, heads * head_dim))["h"]
+    op = dict(num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+              seq_len=t, **rotary)
+    (want, want_g), counted = _attention_over(monkeypatch, False, inputs,
+                                              head, **op)
+    assert counted["attention_kernel.xla_blockwise"] >= 1
+    assert not counted["attention_layout.fused"]
+    (got, got_g), counted = _attention_over(monkeypatch, True, inputs, head,
+                                            **op)
+    close(got, want, 2e-4)
+    for name in inputs:
+        close(got_g[name], want_g[name], 2e-4)
+    assert (counted["attention_layout.fused"]
+            == counted["attention_kernel.pallas_splash"] >= 1)
+    assert not counted["attention_layout.split"]
+    assert not counted["attention_kernel.xla_blockwise"]
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the programs its equations call,
+    a Pallas kernel's body apart: that runs on tiles in VMEM."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_splash_path_cuts_no_lanes_and_turns_no_float32_copy():
+    """What the chip's trace shows of the GLM cell's op (PR 37), kept here
+    where no chip is needed: at 20 heads of 256 with 64 turned columns the
+    traced forward and backward hold no ``slice`` or ``concatenate`` that
+    cuts the last axis inside a 128-lane tile, and no float32 ``[b, t, h,
+    d]`` copy of an operand goes through a ``transpose``."""
+    from mxnet_tpu.ops import attention
+
+    t, h, d = 1024, 20, 256
+    op = attention.CausalAttention(num_heads=h, num_kv_heads=h, head_dim=d,
+                                   seq_len=t, rope_theta=1e6, rotary_dim=64)
+
+    class Ctx:
+        kept = {}
+
+    def loss(q, k, v):
+        return op.apply(Ctx(), [q, k, v], [])[0][0].astype(
+            jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((t, h * d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    names = set()
+    for eqn in _equations(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        names.add(name)
+        widths = [v.aval.shape[-1] for v in eqn.invars + eqn.outvars
+                  if getattr(v.aval, "shape", ())]
+        # of an operand's columns (the wrapper of JAX's kernel cuts lane 0
+        # out of its log-sum-exp: not an operand, and not ours)
+        if name in ("slice", "concatenate", "dynamic_slice", "pad") and (
+                d in widths or h * d in widths):
+            assert all(w % 128 == 0 for w in widths), eqn
+        if name == "transpose":
+            aval = eqn.invars[0].aval
+            assert not (aval.dtype == jnp.float32
+                        and sorted(aval.shape) == sorted((1, t, h, d))), eqn
+    assert "pallas_call" in names
 
 
 def plain_gated_experts(data, router_weight, gate_weight, up_weight,
@@ -528,16 +720,18 @@ def _step_text(monkeypatch, net, params0, batches):
     return lowered[0]
 
 
-# sha256 of the step's text at commit 0c7ad87 (PR 31), the parent of the PR
-# that gave ``CausalAttention`` its ``rotary_dim`` and ``RoutedExperts`` its
-# gated body (PR 32), in this container's JAX. A PR that changes what these
-# programs compute on purpose reads the new ones off this test's failure.
+# sha256 of the step's text in this container's JAX, as PR 37 left it: that
+# PR wrote ``ops.attention.rope`` over the turned columns in one piece and
+# the op's XLA path around it (the toys' widths are no whole lanes, so all
+# three take that path; the rotation is the same to the bit,
+# ``test_rope_is_the_split_form_bit_for_bit``). Before it the three texts
+# were those of commits 0c7ad87 (PR 31) and a1b7200 (PR 32). A PR that
+# changes what these programs compute on purpose reads the new ones off
+# this test's failure.
 PARENT_TEXT = {
-    "nemotron_h": "6a340afd55b18bd0173950b183fd76d66d3db7573c4885e87ba68d5864fbff7b",
-    "olmo_hybrid": "17840b26e9670a2a245172f2d13fe377fe4a188742e2f6880700bc3b94ce3e46",
-    # at commit a1b7200 (PR 32), the parent of the PR that gave the grouped
-    # products their Pallas kernels (PR 33): toy widths keep the XLA loop
-    "glm4_moe_lite": "836bd48c33b817989accd972afba9a6750609f9e1a3ffc91430949cf0abe4d2f",
+    "nemotron_h": "1228aa239a8a81c1dbf1b8bd91b946948a53ae8199a5a85698dc61ab4bd9b7b0",
+    "olmo_hybrid": "01c68fef70c315c7ebbf2e1957e8e76b7ed51f9ba63815e15fdaf6d714b3330d",
+    "glm4_moe_lite": "c7dfb647853bf98c603616c0045b290b3a6542d22aecb4ce64ba1221a22cacb2",
 }
 
 
