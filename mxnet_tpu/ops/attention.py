@@ -10,7 +10,12 @@ is traced, and counted (``lower.attention_kernel.<name>``):
 
 * ``pallas_splash``: JAX's own Pallas TPU kernel (``jax.experimental.
   pallas.ops.tpu.splash_attention``) in its multi-query form, where it
-  applies: a head of whole 128-lanes and a sequence of whole
+  applies: a head of whole 128-lanes (128, 256: the Nemotron, Olmo and
+  GLM models) or of 64 columns, half a lane tile, with an even number of
+  query and of key/value heads (the LFM2 model's 32 over 8; the kernel
+  takes a ``[T, 64]`` tile as it is, the 64 lanes beside it idle in its
+  products, and the pass below moves the TWO heads that share a 128-lane
+  tile of the rows in one grid step), and a sequence of whole
   ``SPLASH_BLOCK`` blocks. One call a key/value head: its ``G`` query
   heads read that one head's keys, so nothing is repeated; forward and
   backward are blockwise in the kernel (scores and softmax float32 in
@@ -29,14 +34,16 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   on the whole lanes that hold the turned columns, the scale, ONE
   rounding to the compute dtype; the backward pass is the transposed
   pass over the cotangent (the scale, then the rotation by the negative
-  angle). No float32 copy of an operand reaches HBM. Counted
+  angle; a 64-wide head's partner is 32 columns away within its own half
+  of the tile). No float32 copy of an operand reaches HBM. Counted
   ``lower.attention_layout.fused`` once a traced op: the one way there
   is, for every shape the kernel takes (XLA's own rotation, slices,
   concatenation and two-step transposes were 55 of the GLM cell's 379
   ms a step, and written as one elementwise chain or met in the
   kernel's ``SEQ_MINOR`` layout they compiled to MORE copies: PERF.md,
   PR 37).
-* ``xla_blockwise``: everything else. Queries go in blocks of
+* ``xla_blockwise``: everything else (heads of any other width, toy
+  widths, an odd count of 64-wide heads). Queries go in blocks of
   ``BLOCK_Q``; a block reads only the keys at or before its end, so the
   upper triangle is never computed, and each block runs under
   ``jax.checkpoint``: the backward pass recomputes one block's scores at
@@ -77,6 +84,17 @@ def rope_tables(t, theta, half, lanes=1):
     pad = ((0, 0), (-2 * half % lanes, 0))
     return (np.pad(np.concatenate([cos, cos], 1), pad, constant_values=1),
             np.pad(np.concatenate([-sin, sin], 1), pad))
+
+
+def relayout_tables(t, theta, half, head_dim):
+    """:func:`rope_tables` as ``pallas_kernels.attention_relayout`` reads
+    them: over whole 128-lane tiles of a head, or for a head of 64 columns
+    one head's table beside the other's (two heads share a tile); nothing
+    where nothing is turned."""
+    if not half:
+        return ()
+    tables = rope_tables(t, theta, half, min(head_dim, 128))
+    return tuple(np.tile(a, (1, max(1, 128 // head_dim))) for a in tables)
 
 
 def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0):
@@ -244,9 +262,11 @@ class CausalAttention(Operator):
         return [q, kv, kv], [q], []
 
     def _splash_applies(self):
-        t = self.seq_len
-        return (self.head_dim % 128 == 0 and t % 128 == 0
-                and t % min(SPLASH_BLOCK, t) == 0)
+        t, d = self.seq_len, self.head_dim
+        # whole lanes, or two heads to a 128-lane tile of the rows
+        lanes = d % 128 == 0 or (
+            d == 64 and self.num_heads % 2 == self.num_kv_heads % 2 == 0)
+        return lanes and t % 128 == 0 and t % min(SPLASH_BLOCK, t) == 0
 
     def remat_results(self, in_shapes, in_types):
         """Kept always under recomputation: the kernel's output and, on
@@ -283,7 +303,7 @@ class CausalAttention(Operator):
             return [ctx.keep(out.reshape(b * t, hq * d), "attention")], []
         _tel.inc("lower.attention_kernel.pallas_splash")
         _tel.inc("lower.attention_layout.fused")
-        tables = rope_tables(t, self.rope_theta, half, 128) if half else ()
+        tables = relayout_tables(t, self.rope_theta, half, d)
         # the kernel takes queries already scaled: folded into the pass
         # that turns them, before its one rounding to the compute dtype
         q = _relaid(q, tables, batch=b, heads=hq, half=half, scale=scale)

@@ -7,7 +7,9 @@ and computes the part of the layer's result that its own experts give;
 rows routed to an absent expert add nothing here (in an expert-parallel
 layout the chip that holds that expert adds them; on one chip the layer
 runs without its exchange). What every chip computes alike, a shared
-expert, is not this op's: it is plain ``FullyConnected`` nodes beside it.
+expert, is not this op's: it is plain ``FullyConnected`` nodes beside it,
+and a family without one (``models/lfm2_moe.py``) takes the op's result as
+the layer's whole result.
 
 **No capacity, no dropped row.** The (row, expert) pairs that land here
 are sorted by expert and laid out in blocks of ``block`` rows, each
@@ -22,8 +24,12 @@ makes one expert's group long, never short of a row.
 
 Router scores (sigmoid, no softmax), selection and combine weights are
 float32 at ``highest`` matmul precision whatever the compute dtype: the
-choice of experts is a discontinuous function of the scores. The expert
-products take inputs in the compute dtype and accumulate in float32.
+choice of experts is a discontinuous function of the scores. The combine
+weights are the chosen scores over their sum plus ``norm_eps`` (the
+families differ: ``1e-20``, the default, in the DeepSeek line that the
+Nemotron and GLM models follow, ``1e-6`` in ``lfm2_moe``), times ``scale``.
+The expert products take inputs in the compute dtype and accumulate in
+float32.
 
 **Two expert bodies**, by ``gated``: ``W_down relu(W_up x)^2`` (the
 default; :func:`grouped_experts`) and the gated ``W_down (silu(W_gate x) *
@@ -81,13 +87,14 @@ def layout_length(rows, top_k, num_held, block):
              // block) * block
 
 
-def route(x, router, select_bias, top_k, scale, keep=lambda v: v):
+def route(x, router, select_bias, top_k, scale, keep=lambda v: v,
+          norm_eps=1e-20):
     """``x [S, h]`` -> (expert ids ``[S, k]`` int32, combine weights
     ``[S, k]`` float32): scores ``sigmoid(x W_r)``; the ``top_k`` largest
-    of ``scores + select_bias``; weights the chosen scores over their sum,
-    times ``scale``. ``keep`` marks the ids where they are made: the
-    weights' gradient reads them, and must read the marked ones for a
-    recomputation to skip the top-k."""
+    of ``scores + select_bias``; weights the chosen scores over their sum
+    plus ``norm_eps``, times ``scale``. ``keep`` marks the ids where they
+    are made: the weights' gradient reads them, and must read the marked
+    ones for a recomputation to skip the top-k."""
     import jax
     import jax.numpy as jnp
 
@@ -97,7 +104,8 @@ def route(x, router, select_bias, top_k, scale, keep=lambda v: v):
     _, eid = jax.lax.top_k(scores + select_bias.astype(f32), top_k)
     eid = keep(eid.astype(jnp.int32))
     chosen = jnp.take_along_axis(scores, eid, axis=1)
-    wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + 1e-20) * scale
+    wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + norm_eps) \
+        * scale
     return eid, wts
 
 
@@ -375,6 +383,8 @@ class RoutedExperts(Operator):
                                   "against its load"),
         "gated": Param(bool, False, "experts W_down (silu(W_gate x) * W_up "
                        "x), a third stacked weight gate_weight"),
+        "norm_eps": Param(float, 1e-20, "added to the chosen scores' sum "
+                          "before the combine weights are divided by it"),
     }
     # arguments that reach the op in their own dtype under mixed precision
     full_precision_args = ("router_weight",)
@@ -435,7 +445,8 @@ class RoutedExperts(Operator):
         counted, bias = aux
         e = self.num_experts
         keep = functools.partial(ctx.keep, result="routing")
-        eid, wts = route(x, router, bias, self.top_k, self.scale, keep)
+        eid, wts = route(x, router, bias, self.top_k, self.scale, keep,
+                         self.norm_eps)
         block = block_rows(x.shape[0], self.top_k, self.num_experts)
         *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
                                 block)

@@ -2117,8 +2117,10 @@ def grouped_experts_backward(*args, **static):
 _RELAYOUT_ROWS = 1024
 
 
-def _turned(x, tables, half, scale, back, dtype):
-    """A ``[rows, D]`` tile turned and scaled (the section's comment)."""
+def _turned(x, tables, half, scale, back, dtype, period=0):
+    """A ``[rows, D]`` tile turned and scaled (the section's comment); with
+    ``period`` a tile of heads ``period`` columns wide side by side, each
+    turned on its own."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental.pallas import tpu as pltpu
@@ -2132,9 +2134,12 @@ def _turned(x, tables, half, scale, back, dtype):
         start = x.shape[-1] - w
         r = x[:, start:]
         lane = lax.broadcasted_iota(jnp.int32, r.shape, 1)
-        ps = jnp.where(lane < w - half, pltpu.roll(r, w - half, 1),
+        if period:
+            lane = lane % period
+        own = period or w
+        ps = jnp.where(lane < own - half, pltpu.roll(r, w - half, 1),
                        pltpu.roll(r, half, 1)) * s
-        y = jnp.where(lane < w - 2 * half, r,
+        y = jnp.where(lane < own - 2 * half, r,
                       r * c - ps if back else r * c + ps)
         x = jnp.concatenate([x[:, :start], y], axis=1) if start else y
     if not back and scale != 1.0:
@@ -2147,8 +2152,16 @@ def attention_relayout(x, tables=(), *, batch, heads, half=0, scale=1.0,
     """``x [B*T, H*D]`` -> ``[B, H, T, D]``, the last ``2 * half`` columns
     of every head turned by ``tables`` and all of it times ``scale``; with
     ``back`` its transpose, ``[B, H, T, D]`` -> ``[B*T, H*D]``: the way of
-    the kernel's result and of every cotangent (the section's comment)."""
+    the kernel's result and of every cotangent (the section's comment).
+
+    A head narrower than the 128 lanes (64 columns: ``128 % D == 0``) has
+    no tile of its own among the rows, so a grid step moves the ``128 / D``
+    heads that share one: the ``[rows, 128]`` tile is turned as a whole
+    (``tables`` tiled to 128 columns, a lane's partner half the turned
+    width away WITHIN its head) and cut into the heads' ``[rows, D]``
+    tiles, or put together from them on the way back."""
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -2159,19 +2172,33 @@ def attention_relayout(x, tables=(), *, batch, heads, half=0, scale=1.0,
     bt = next((n for n in range(min(_RELAYOUT_ROWS, t), 0, -128)
                if t % n == 0), t)
     nt = t // bt
+    pack = TILE_N // d if d < TILE_N else 1     # heads a 128-lane tile
 
     def kernel(x_ref, *refs):
         o_ref = refs[-1]
-        o_ref[...] = _turned(x_ref[...], [r[...] for r in refs[:-1]], half,
-                             scale, back, o_ref.dtype)
+        cut = pack > 1 and not back     # the turned tile goes out by head
+        if pack > 1 and back:
+            tile = jnp.concatenate([x_ref[j].astype(jnp.float32)
+                                    for j in range(pack)], axis=1)
+        else:
+            tile = x_ref[...]
+        tile = _turned(tile, [r[...] for r in refs[:-1]], half, scale, back,
+                       jnp.float32 if cut else o_ref.dtype,
+                       d if pack > 1 else 0)
+        if cut:
+            for j in range(pack):
+                o_ref[j] = tile[:, j * d:(j + 1) * d].astype(o_ref.dtype)
+        else:
+            o_ref[...] = tile
 
-    rows = pl.BlockSpec((bt, d), lambda b, i, h: (b * nt + i, h))
-    by_head = pl.BlockSpec((None, None, bt, d), lambda b, i, h: (b, h, i, 0))
+    rows = pl.BlockSpec((bt, pack * d), lambda b, i, h: (b * nt + i, h))
+    by_head = pl.BlockSpec((None, pack if pack > 1 else None, bt, d),
+                           lambda b, i, h: (b, h, i, 0))
     table = [pl.BlockSpec((bt, c.shape[1]), lambda b, i, h: (i, 0))
              for c in tables]
     shape = (batch * t, heads * d) if back else (batch, heads, t, d)
     return pallas_call(
-        kernel, x, *tables, grid=(batch, nt, heads),
+        kernel, x, *tables, grid=(batch, nt, heads // pack),
         in_specs=[by_head if back else rows] + table,
         out_specs=rows if back else by_head,
         out_shape=jax.ShapeDtypeStruct(shape, x.dtype),

@@ -363,6 +363,62 @@ class CausalConv1D(Operator):
         return [out.reshape(rows, c).astype(x.dtype)], []
 
 
+@register_op("GatedShortConv")
+class GatedShortConv(Operator):
+    """The gated short convolution of the LFM2 family's ``conv`` mixers,
+    between its two projections: ``data [rows, 3C]`` holds ``[B; C; z]``,
+    three equal chunks in that order, and ``out = C * conv(B * z)``, ``conv``
+    the depthwise causal convolution of :class:`CausalConv1D` over the ``C``
+    channels (kernel ``kernel``, zeros before the sequence's start, no bias,
+    NO activation anywhere). One node, so that the two gates and the K
+    shifted multiply-adds are one float32 expression with one rounding that
+    XLA fuses into a pass over ``data`` (counted
+    ``lower.shortconv_body.xla_fused``); as plain ``slice_axis`` / ``_Mul``
+    / ``CausalConv1D`` nodes the gated product and the convolution's result
+    would each be rounded to the compute dtype and written out."""
+
+    name_hint = "gatedshortconv"
+    PARAMS = {
+        "kernel": Param(int, REQUIRED),
+        "seq_len": Param(int, REQUIRED),
+    }
+
+    def list_arguments(self):
+        return ["data", "weight"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            raise MXNetError("GatedShortConv: data shape unknown")
+        if data[1] % 3:
+            raise MXNetError("GatedShortConv: width %d is not three equal "
+                             "chunks" % data[1])
+        _sequences(data[0], self.seq_len, "GatedShortConv")
+        c = data[1] // 3
+        return [data, (c, self.kernel)], [(data[0], c)], []
+
+    def remat_results(self, in_shapes, in_types):
+        """The result: two gates and K multiply-adds a value to run again
+        against one activation to hold, so the last of a plan's choices."""
+        rows, c = in_shapes[0][0], in_shapes[0][1] // 3
+        return [("output", rows * c * np.dtype(in_types[0]).itemsize,
+                 (2 * self.kernel + 2) * rows * c)]
+
+    def apply(self, ctx, inputs, aux):
+        jnp = _jnp()
+        from .. import telemetry as _tel
+
+        _tel.inc("lower.shortconv_body.xla_fused")
+        x, w = inputs[0], inputs[1].astype(jnp.float32)
+        rows, c = x.shape[0], x.shape[1] // 3
+        t, k = self.seq_len, self.kernel
+        b, gate, z = (x[:, i * c:(i + 1) * c].astype(jnp.float32).reshape(
+            rows // t, t, c) for i in range(3))
+        vs = jnp.pad(b * z, ((0, 0), (k - 1, 0), (0, 0)))
+        out = gate * sum(vs[:, i:i + t] * w[:, i] for i in range(k))
+        return [ctx.keep(out.reshape(rows, c).astype(x.dtype), "output")], []
+
+
 def _ssd_chunks(x, dt, a_head, b_mat, c_mat, chunk):
     """What both passes of the XLA body share: the inputs cut into chunks
     ``[nc, L, ...]``, the cumulative decay inside each chunk, the masked

@@ -566,6 +566,20 @@ object SymbolOpsGen {
     mk("GatedDeltaRule", name, ps.toSeq, ins.toSeq)
   }
 
+  def GatedShortConv(data: Symbol,
+      kernel: Int,
+      seqLen: Int,
+      name: String = "",
+      weight: Symbol = null): Symbol = {
+    val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("kernel", kernel.toString))
+    ps += (("seq_len", seqLen.toString))
+    val ins = scala.collection.mutable.ArrayBuffer[(String, Symbol)]()
+    ins += (("data", data))
+    if (weight != null) ins += (("weight", weight))
+    mk("GatedShortConv", name, ps.toSeq, ins.toSeq)
+  }
+
   def IdentityAttachKLSparseReg(data: Symbol,
       name: String = "",
       sparsenessTarget: Float = 0.1f,
@@ -824,12 +838,14 @@ object SymbolOpsGen {
       firstHeld: Int = 0,
       scale: Float = 1.0f,
       biasUpdateRate: Float = 0.0f,
-      gated: Boolean = false): Symbol = {
+      gated: Boolean = false,
+      normEps: Float = 1e-20f): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("first_held", firstHeld.toString))
     ps += (("scale", scale.toString))
     ps += (("bias_update_rate", biasUpdateRate.toString))
     ps += (("gated", gated.toString))
+    ps += (("norm_eps", normEps.toString))
     ps += (("num_experts", numExperts.toString))
     ps += (("num_held", numHeld.toString))
     ps += (("top_k", topK.toString))

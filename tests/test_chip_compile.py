@@ -58,12 +58,15 @@ def test_delta_chunk_kernels_compile_for_the_chip(one_chip, heads, dk, dv,
         assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_splash_attention_at_256_wide_heads_compiles_for_the_chip(one_chip):
-    """Latent attention's kernel call at the benchmark's cell: 20 one-head
-    groups of 256 columns over 8,192 positions, forward and backward."""
+@pytest.mark.parametrize("h,group,d", [(20, 1, 256), (8, 4, 64)],
+                         ids=["256-wide", "64-wide-grouped"])
+def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d):
+    """The kernel call at two of the benchmark's cells: latent attention's
+    20 one-head groups of 256 columns, and LFM2's 8 groups of four heads of
+    64, half a lane tile, over 8,192 positions, forward and backward."""
     from mxnet_tpu.ops import attention
 
-    b, t, h, d = 1, 8192, 20, 256
+    b, t = 1, 8192
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
@@ -75,7 +78,7 @@ def test_splash_attention_at_256_wide_heads_compiles_for_the_chip(one_chip):
     # operands; a benchmark run leaves the default
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            shape(b, h, 1, t, d), shape(b, h, t, d),
+            shape(b, h, group, t, d), shape(b, h, t, d),
             shape(b, h, t, d)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
@@ -86,9 +89,12 @@ def test_splash_attention_at_256_wide_heads_compiles_for_the_chip(one_chip):
     (1, 8192, 15, 128, 0, "bfloat16"),      # Olmo's, and every value
     (2, 1024, 3, 256, 256, "bfloat16"),     # a partner in the other tile
     (1, 1024, 3, 256, 192, "bfloat16"),     # turned columns over two tiles
-    (3, 640, 2, 128, 2, "float32")],        # one pair; 128-row tiles
+    (3, 640, 2, 128, 2, "float32"),         # one pair; 128-row tiles
+    (1, 8192, 32, 64, 64, "bfloat16"),      # LFM2's queries: two heads a tile
+    (1, 8192, 8, 64, 0, "bfloat16"),        # LFM2's values
+    (2, 1024, 2, 64, 16, "float32")],       # a part of a 64-wide head
     ids=["glm", "nemotron", "plain", "two-tiles", "tile-and-a-half",
-         "one-pair"])
+         "one-pair", "lfm2", "lfm2-plain", "half-lanes-part"])
 def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
                                                         heads, d, turned,
                                                         dtype):
@@ -98,7 +104,7 @@ def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
     from mxnet_tpu.ops import attention, pallas_kernels as pk
 
     half = turned // 2
-    tables = attention.rope_tables(t, 1e4, half, 128) if half else ()
+    tables = attention.relayout_tables(t, 1e4, half, d)
     how = dict(batch=batch, heads=heads, half=half, scale=0.125)
     there = jax.jit(lambda x: pk.attention_relayout(x, tables, **how)).lower(
         jax.ShapeDtypeStruct((batch * t, heads * d), dtype,
