@@ -259,6 +259,30 @@ def _plan_kept(plans, arg_index, arg_list, budget):
     return kept
 
 
+def _weight_grad_flops(symbol, arg_shapes):
+    """``{argument: operations}`` of the products that form each
+    argument's gradient, as the nodes that read it state them
+    (:meth:`Operator.weight_grad_flops`) from the shapes this walk infers
+    as binding does; summed where several nodes read one argument. An
+    argument that any reader says nothing about is left out."""
+    shapes, flops, silent = {}, {}, set()
+    for n in symbol._topo():
+        if n.is_variable:
+            shapes[n.uid] = [tuple(arg_shapes[n.name])]
+            continue
+        in_shapes = [shapes[src.uid][i] for src, i in n.inputs]
+        shapes[n.uid] = n.op.infer_shape(in_shapes)[1]
+        stated = n.op.weight_grad_flops(in_shapes)
+        for slot, (src, _) in enumerate(n.inputs):
+            if not src.is_variable:
+                continue
+            if slot in stated:
+                flops[src.name] = flops.get(src.name, 0) + stated[slot]
+            else:
+                silent.add(src.name)
+    return {name: f for name, f in flops.items() if name not in silent}
+
+
 def make_graph_eval(symbol, node_device=None, remat=False,
                     remat_budget=None):
     """Build the pure graph-eval function for a symbol.

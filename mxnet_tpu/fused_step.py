@@ -50,16 +50,23 @@ batch on both paths (the benchmark reads it as
 ``fit_dispatches_per_step``: 1.0 fused);
 ``step.fused_recompiles`` counts fresh trace signatures (a shape-driven
 recompile storm trips the tracing RecompileDetector);
-``step.fused_fallback`` counts requested-but-refused configurations.
+``step.fused_fallback`` counts requested-but-refused configurations;
+``step.update_seam.apart`` / ``.riding`` count, once a traced program, the
+parameters whose update follows its weight-gradient product as a pass of
+its own and those whose update rides it (:func:`_plan_update_seam`), gauge
+``step.update_seam.apart_bytes`` what crosses between them.
 """
 from __future__ import annotations
+
+import math
 
 from . import telemetry as _tel
 from . import env as _env
 from . import xprof as _xprof
 from .analysis import sanitizers as _san
+from .base import MXNetError
 from .engine import get_engine
-from .executor import zero_cotangent
+from .executor import _weight_grad_flops, zero_cotangent
 
 __all__ = ["enabled", "make_fused_step", "FusedTrainStep",
            "make_fused_infer", "FusedInfer"]
@@ -174,6 +181,56 @@ def make_fused_step(module, eval_metric):
     return FusedTrainStep(module, eval_metric)
 
 
+# The seam between a weight-gradient product and the optimizer's update.
+# Left alone XLA fuses a parameter's update onto the product of its
+# gradient as an epilogue: three float32 operands read and three results
+# written per output tile, beside the product's own. For a small
+# parameter that saves the gradient's trip through HBM; for a large dense
+# weight whose product the MXU bounds, the epilogue's streams cost the
+# product more than the trip. The two constants were measured on the v5e
+# alone, in the four language-model cells stepped with every gradient
+# apart and with none (PR 39; PERF.md section 6 has the runs): a class of
+# weights gained where the product apart saved more than the 0.028-0.037
+# ms a million parameters that the update's own pass costs. On another
+# chip of ``xprof.CHIP_PEAKS`` the margin follows that chip's peaks and
+# the floor is unverified.
+#: the product's time at the peak over the update's bytes' time at the HBM
+#: rate, from which a product counts as bound by the MXU (an Adam'd dense
+#: weight reaches 1.0 at ~2,900 rows contracted, 2.0 at ~5,800; the cells'
+#: dense weights sit at 2.84, their experts at 0.13-0.18: the chip has
+#: said nothing between, the 2.0 is the TPU compiler's own estimate's)
+_SEAM_MXU_MARGIN = 2.0
+#: bytes an update moves (weight and states, read and written) from which
+#: every class of weights measured gained or broke even apart (four, at
+#: 951-1,156 MB); of the thirteen classes at 72-665 MB six gained and
+#: seven lost by nothing the step can observe and explain: they ride
+_SEAM_FLOOR_BYTES = 768 << 20
+
+
+def _plan_update_seam(flops, update_bytes, peaks, force=None):
+    """Positions of the parameters whose update FOLLOWS their
+    weight-gradient product as an elementwise pass of its own, the
+    gradient written once between them; every other update RIDES the
+    product, as the compiler fuses it. From what can be observed, no
+    knob: ``flops[pos]`` the operations of the products that form the
+    gradient (``None`` where a reader of the parameter states nothing:
+    rides), ``update_bytes[pos]`` what its update reads and writes,
+    ``peaks`` the device's ``(TFLOP/s, GB/s)`` (``None`` where it reports
+    none, the CPU: everything rides). Apart goes a parameter whose
+    product the MXU bounds by :data:`_SEAM_MXU_MARGIN` and whose update
+    moves at least :data:`_SEAM_FLOOR_BYTES`. ``force`` is the tests':
+    ``"apart"`` / ``"riding"`` for every parameter."""
+    if force is not None:
+        return frozenset(range(len(flops)) if force == "apart" else ())
+    if peaks is None:
+        return frozenset()
+    tflops, gbps = peaks
+    return frozenset(
+        pos for pos, (f, b) in enumerate(zip(flops, update_bytes))
+        if f is not None and b >= _SEAM_FLOOR_BYTES
+        and f / (tflops * 1e12) >= _SEAM_MXU_MARGIN * b / (gbps * 1e9))
+
+
 class FusedTrainStep:
     """One-dispatch training step bound to a Module's executor group.
 
@@ -227,6 +284,7 @@ class FusedTrainStep:
                                                  ex.arg_arrays[arg_i])
 
         self._jit_cache = {}
+        self._seam_plan = None      # _update_seam's, once a bind
         self._seen_sigs = set()
         self._retrace_san = (_san.RetraceSanitizer()
                              if _san.enabled("retrace") else None)
@@ -517,6 +575,40 @@ class FusedTrainStep:
         return _do, [nd._var for nd in o_nds], mut
 
     # ------------------------------------------------------------------
+    def _update_seam(self, specs):
+        """The parameters' positions whose update follows its product
+        (:func:`_plan_update_seam`), from the bound shapes, reckoned once
+        a bind: the products' operations as the graph's nodes state them,
+        a device's share of them under a mesh, against the bytes that
+        device's shard of the weight and of each optimizer state is read
+        and written with."""
+        if self._seam_plan is not None:
+            return self._seam_plan
+        ex = self._executor
+        mesh = getattr(self._group, "_mesh", None)
+        device = (ex._ctx.jax_device() if mesh is None
+                  else mesh.devices.flat[0])
+        try:
+            peaks = _xprof.chip_peaks(device.device_kind)
+        except MXNetError:
+            peaks = None        # no published peak: as where there is none
+        stated = _weight_grad_flops(
+            ex._symbol, {n: a.shape for n, a in zip(ex.arg_names,
+                                                    ex.arg_arrays)})
+        n_states = {pos: n for _, n, positions in specs
+                    for pos in positions}
+        flops, update_bytes = [], []
+        for pos, i in enumerate(self._p_arg_idx):
+            w = ex.arg_arrays[i]._data
+            f = stated.get(ex.arg_names[i])
+            flops.append(None if f is None
+                         else f / (1 if mesh is None else mesh.size))
+            update_bytes.append(
+                math.prod(w.sharding.shard_shape(w.shape))
+                * w.dtype.itemsize * (1 + n_states[pos]) * 2)
+        self._seam_plan = _plan_update_seam(flops, update_bytes, peaks)
+        return self._seam_plan
+
     def _build(self, specs, clipped, donate, fold, feed=None, watch=None):
         """Trace+compile the whole-batch step for one (structure,
         donation, fold, feed) configuration. With ``feed`` set the data
@@ -569,6 +661,7 @@ class FusedTrainStep:
         leaves = self._fold_leaves or ()
         math_fns = {(kind, n): _update_math(kind, n, clipped)
                     for kind, n, _ in specs}
+        apart = self._update_seam(specs)
 
         _tel.inc("executor.jit_build")
 
@@ -613,6 +706,19 @@ class FusedTrainStep:
                 if grad_shardings is not None:
                     grads = [jax.lax.with_sharding_constraint(g, s)
                              for g, s in zip(grads, grad_shardings)]
+                # the seam: a gradient that goes apart is written once,
+                # as the float32 the update would have read anyway (what
+                # the collective summed, under a mesh), and the compiler
+                # may not fuse through. One barrier a gradient: one over
+                # all of them would hold every gradient at once
+                grads = [jax.lax.optimization_barrier(g) if pos in apart
+                         else g for pos, g in enumerate(grads)]
+                _tel.inc("step.update_seam.apart", len(apart))
+                _tel.inc("step.update_seam.riding",
+                         len(grads) - len(apart))
+                _tel.set_gauge("step.update_seam.apart_bytes", sum(
+                    grads[pos].size * grads[pos].dtype.itemsize
+                    for pos in apart))
             new_p = list(p_vals)
             new_st = []
             with jax.named_scope("update"):

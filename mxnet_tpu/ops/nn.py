@@ -61,6 +61,11 @@ class FullyConnected(Operator):
                  rows * self.num_hidden * np.dtype(in_types[0]).itemsize,
                  2 * rows * k * self.num_hidden)]
 
+    def weight_grad_flops(self, in_shapes):
+        """``x^T dy``: ``2 x rows x K x N``, the rows contracted."""
+        rows, k = in_shapes[0][0], int(np.prod(in_shapes[0][1:]))
+        return {1: 2 * rows * k * self.num_hidden}
+
     def apply(self, ctx, inputs, aux):
         # XLA is the measured fast path: the Pallas fused_linear kernel
         # benched 0.1-1.0x of the XLA dot on a v5e across 256..8192 sizes

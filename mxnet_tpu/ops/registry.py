@@ -180,6 +180,16 @@ class Operator:
         list is recomputed inside its segment."""
         return []
 
+    def weight_grad_flops(self, in_shapes):
+        """``{input slot: operations}`` of the products that form the
+        gradient of each input this node reads as a weight, from the input
+        shapes: what the fused step holds against the optimizer's bytes
+        when it places the seam between a parameter's weight-gradient
+        product and its update (``fused_step._plan_update_seam``). A slot
+        an op does not list states nothing, and its parameter's update
+        rides whatever produces the gradient."""
+        return {}
+
     def apply(self, ctx: OpContext, inputs: Sequence[Any], aux: Sequence[Any]):
         """Pure function over jnp arrays -> (outputs, new_aux)."""
         raise NotImplementedError

@@ -273,3 +273,178 @@ def test_trace_report_shows_dispatch_columns():
                    "deltas": {"dispatches": 1, "fused_recompiles": 1}}])
     header = out.splitlines()[2]
     assert "dispatch" in header and "fused_rc" in header
+
+
+# ---------------------------------------------------------------------------
+# the seam between a weight-gradient product and the optimizer's update
+# ---------------------------------------------------------------------------
+
+V5E = (197, 819)            # TFLOP/s in bfloat16, GB/s: xprof.CHIP_PEAKS
+
+
+@pytest.mark.parametrize("rows, shape, n_states, peaks, apart", [
+    # a dense feed-forward weight at 8,192 rows under Adam: the MXU binds
+    # its product 2.84 times over, 1.01 GB of update
+    (8192, (11008, 3840), 2, V5E, True),
+    # a head of 16,384 x 2,688: 1.06 GB; no width's divisors are asked
+    (8192, (16384, 2688), 2, V5E, True),
+    # the feed-forward weight where half the rows are contracted: 1.42,
+    # under the margin
+    (4096, (11008, 3840), 2, V5E, False),
+    # projections of 2,048 x 2,048 (101 MB) and 10,304 x 2,688 (665 MB)
+    # at 8,192 rows: under the floor
+    (8192, (2048, 2048), 2, V5E, False),
+    (8192, (10304, 2688), 2, V5E, False),
+    # an expert's weight sees 512 rows: the update's bytes bind
+    (512, (1536, 2048), 2, V5E, False),
+    # a 3x3 convolution 512 -> 512 at 256 x 14 x 14 positions under SGD
+    # with momentum, were it to state its product: the MXU binds it too,
+    # but its update moves 38 MB
+    (256 * 14 * 14, (512, 512 * 9), 1, V5E, False),
+    # a router at 8,192 rows: bound by the MXU, 3 MB of update
+    (8192, (64, 2048), 2, V5E, False),
+    # a bias, a norm's scale, an embedding: no reader states a product
+    (None, (11008,), 2, V5E, False),
+    (None, (3840,), 2, V5E, False),
+    (None, (12544, 3840), 2, V5E, False),
+    # the dense weight where the device reports no peak
+    (8192, (11008, 3840), 2, None, False),
+], ids=["dense_8192_rows_adam", "head_8192_rows_adam", "dense_4096_rows_adam",
+        "dense_101mb_under_floor", "dense_665mb_under_floor",
+        "expert_512_rows", "conv3x3_512_sgd_momentum", "router", "bias",
+        "norm_scale", "embedding", "no_peak"])
+def test_update_seam_rule_on_shapes(rows, shape, n_states, peaks, apart):
+    """The rule, on shapes alone: ``rows`` contracted by the product that
+    forms the gradient of a float32 weight of ``shape`` (``None``: no
+    reader states one) under an optimizer with ``n_states`` states."""
+    import math
+
+    from mxnet_tpu.fused_step import _plan_update_seam
+
+    flops = None if rows is None else 2 * rows * math.prod(shape)
+    # weight and states in float32, each read and written
+    update_bytes = math.prod(shape) * 4 * (1 + n_states) * 2
+    plan = _plan_update_seam([flops], [update_bytes], peaks)
+    assert plan == (frozenset([0]) if apart else frozenset())
+    # the tests' own argument takes either side whatever the shapes say
+    assert _plan_update_seam([flops], [update_bytes], peaks,
+                             force="apart") == frozenset([0])
+    assert _plan_update_seam([flops], [update_bytes], peaks,
+                             force="riding") == frozenset()
+
+
+def test_weight_grad_flops_are_stated_by_the_readers():
+    """``FullyConnected`` states ``2 x rows x K x N`` for its weight; a
+    bias, a convolution's weight and anything a silent node reads are
+    left out, and one argument read twice adds up."""
+    from mxnet_tpu.executor import _weight_grad_flops
+
+    net = sym.Variable("data")
+    net = sym.Convolution(net, num_filter=4, kernel=(3, 3), name="conv")
+    net = sym.Flatten(net)
+    shared = sym.Variable("shared_weight")
+    net = sym.FullyConnected(data=net, weight=shared, num_hidden=36,
+                             no_bias=True, name="fc1")
+    net = sym.FullyConnected(data=net, weight=shared, num_hidden=36,
+                             no_bias=True, name="fc2")
+    net = sym.FullyConnected(net, num_hidden=CLASSES, name="fc3")
+    net = sym.SoftmaxOutput(net, name="softmax")
+    shapes, _, _ = net.infer_shape(data=(BATCH, 1, 5, 5),
+                                   softmax_label=(BATCH,))
+    stated = _weight_grad_flops(net, dict(zip(net.list_arguments(), shapes)))
+    assert stated == {"shared_weight": 2 * (2 * BATCH * 36 * 36),
+                      "fc3_weight": 2 * BATCH * 36 * CLASSES}
+
+
+def _conv_sym():
+    net = sym.Variable("data")
+    net = sym.Convolution(net, num_filter=4, kernel=(3, 3), pad=(1, 1),
+                          name="conv1")
+    net = sym.BatchNorm(net, name="bn1")
+    net = sym.Activation(net, act_type="relu")
+    net = sym.Flatten(net)
+    net = sym.FullyConnected(net, num_hidden=CLASSES, name="fc")
+    return sym.SoftmaxOutput(net, name="softmax")
+
+
+def _seam_fit(net, data_shape, optimizer, optimizer_params, force,
+              monkeypatch):
+    """Three fused steps from seeded parameters with the seam forced
+    (``"apart"`` / ``"riding"``) or by the rule (``None``): parameters,
+    auxiliary states and the optimizer's states as numpy."""
+    import functools
+
+    from mxnet_tpu import fused_step
+
+    monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
+    if force is not None:
+        monkeypatch.setattr(
+            fused_step, "_plan_update_seam",
+            functools.partial(fused_step._plan_update_seam, force=force))
+    rng = np.random.RandomState(7)
+    X = rng.randn(BATCH * 3, *data_shape).astype(np.float32)
+    y = rng.randint(0, CLASSES, BATCH * 3).astype(np.float32)
+    arg_shapes, _, _ = net.infer_shape(data=(BATCH,) + data_shape,
+                                       softmax_label=(BATCH,))
+    prng = np.random.RandomState(3)
+    params = {n: mx.nd.array((prng.randn(*s) * 0.1).astype(np.float32))
+              for n, s in zip(net.list_arguments(), arg_shapes)
+              if n not in ("data", "softmax_label")}
+    mod = Module(net, context=mx.cpu())
+    mod.fit(mx.io.NDArrayIter(X, y, batch_size=BATCH), num_epoch=1,
+            optimizer=optimizer, optimizer_params=optimizer_params,
+            arg_params=params, initializer=None)
+    assert mod._fused_step_active
+    args, aux = mod.get_params()
+    out = {"arg." + k: v.asnumpy() for k, v in args.items()}
+    out.update(("aux." + k, v.asnumpy()) for k, v in aux.items())
+    for i, st in mod._updater.states.items():
+        for j, s in enumerate(st if isinstance(st, (tuple, list)) else [st]):
+            out["state.%d.%d" % (i, j)] = s.asnumpy()
+    return out
+
+
+@pytest.mark.parametrize("optimizer, optimizer_params", [
+    ("adam", {"learning_rate": 0.01}),
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 1e-4}),
+], ids=["adam", "sgd_momentum"])
+@pytest.mark.parametrize("net, data_shape, numwatch, compute_dtype", [
+    (_mlp_sym, (DIM,), True, None), (_conv_sym, (1, 5, 5), False, None),
+    (_mlp_sym, (DIM,), False, "bfloat16"),
+], ids=["mlp_numwatch", "conv", "mlp_bfloat16"])
+def test_update_seam_same_results_either_side(tel, monkeypatch, net,
+                                              data_shape, numwatch,
+                                              compute_dtype, optimizer,
+                                              optimizer_params):
+    """Apart, riding or by the rule, three steps leave the same
+    parameters, auxiliary states and optimizer states, to the bit: the
+    seam moves where a gradient is written, not what is computed. Under a
+    compute dtype too: the gradient crosses after the cast's transpose,
+    as the float32 the update reads, so whatever rounding the compiler
+    keeps or drops riding it keeps or drops apart."""
+    if numwatch:
+        monkeypatch.setenv("MXNET_TPU_NUMWATCH", "1")
+    if compute_dtype:
+        monkeypatch.setenv("MXNET_COMPUTE_DTYPE", compute_dtype)
+    got = {}
+    for force in ("apart", "riding", None):
+        with monkeypatch.context() as m:
+            telemetry.reset()
+            got[force] = _seam_fit(net(), data_shape, optimizer,
+                                   dict(optimizer_params), force, m)
+            n = len(net().list_arguments()) - 2
+            apart = telemetry.peek("step.update_seam.apart")
+            assert apart == (n if force == "apart" else 0)
+            assert telemetry.peek("step.update_seam.riding") == n - apart
+            assert telemetry.peek("step.dispatches") == 3
+            if force == "apart":
+                elements = sum(v.size for k, v in got[force].items()
+                               if k.startswith("arg."))
+                assert telemetry.peek(
+                    "step.update_seam.apart_bytes", kind="gauge") \
+                    == elements * 4
+    assert got["apart"].keys() == got["riding"].keys() == got[None].keys()
+    assert any(k.startswith("state.") for k in got[None])
+    for k, want in got["riding"].items():
+        np.testing.assert_array_equal(got[None][k], want, err_msg=k)
+        np.testing.assert_array_equal(got["apart"][k], want, err_msg=k)

@@ -162,6 +162,55 @@ def test_sharded_fused_no_retrace_across_batches(tel, monkeypatch):
 
 
 @pytest.mark.multichip
+def test_sharded_fused_seam_apart_follows_the_pinned_collective(
+        tel, monkeypatch):
+    """The update's seam under the mesh, forced apart: every gradient
+    crosses its barrier as the value the sharding constraint pinned (the
+    collective stays between backward and update), the step is one jit
+    entry, and the parameters are the riding step's, bit for bit."""
+    import functools
+
+    import jax
+
+    from mxnet_tpu import fused_step
+
+    riding = _fit_dp(8, nbatches=4, num_epoch=1, monkeypatch=monkeypatch,
+                     linear=True)
+    pinned, crossed = [], []
+    pin, barrier = jax.lax.with_sharding_constraint, \
+        jax.lax.optimization_barrier
+
+    def spy_pin(x, s):
+        pinned.append(pin(x, s))
+        return pinned[-1]
+
+    def spy_barrier(x):
+        crossed.append(x)
+        return barrier(x)
+
+    monkeypatch.setattr(jax.lax, "with_sharding_constraint", spy_pin)
+    monkeypatch.setattr(jax.lax, "optimization_barrier", spy_barrier)
+    monkeypatch.setattr(
+        fused_step, "_plan_update_seam",
+        functools.partial(fused_step._plan_update_seam, force="apart"))
+    telemetry.reset()
+    apart = _fit_dp(8, nbatches=4, num_epoch=1, monkeypatch=monkeypatch,
+                    linear=True)
+    n_params = len(apart._param_names)
+    assert telemetry.peek("step.update_seam.apart") == n_params
+    assert telemetry.peek("step.update_seam.riding") == 0
+    assert len(crossed) == n_params
+    assert all(any(c is p for p in pinned) for c in crossed)
+    assert telemetry.peek("step.fused_jit_entries", kind="gauge") == 1
+    assert telemetry.peek("step.fused_recompiles") == 1
+    args_r, _ = riding.get_params()
+    args_a, _ = apart.get_params()
+    for name in sorted(args_r):
+        assert np.array_equal(args_r[name].asnumpy(),
+                              args_a[name].asnumpy()), name
+
+
+@pytest.mark.multichip
 def test_sharded_fused_donation_safety(monkeypatch):
     """Donated params/opt-state buffers stay safe under NamedSharding
     across many steps — a use-after-donate raises inside jax, and the

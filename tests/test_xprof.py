@@ -695,3 +695,59 @@ def test_disabled_xprof_records_nothing():
     finally:
         xprof._override = prev
         xprof.reset()
+
+
+# ---------------------------------------------------------------------------
+# the seam between a weight-gradient product and the update (fused_step)
+# ---------------------------------------------------------------------------
+
+def _step_text_and_census(monkeypatch, net, force):
+    """The compiled step's text, its census and the seam's counters for
+    ``net`` under Adam with the seam forced (``None``: by the rule)."""
+    import functools
+
+    from mxnet_tpu import fused_step
+
+    texts = []
+    analyze = xprof._analyze_hlo
+    with monkeypatch.context() as m:
+        m.setenv("MXNET_TPU_FUSED_STEP", "1")
+        m.setattr(xprof, "_analyze_hlo",
+                  lambda text: texts.append(text) or analyze(text))
+        if force is not None:
+            m.setattr(fused_step, "_plan_update_seam", functools.partial(
+                fused_step._plan_update_seam, force=force))
+        telemetry.reset()
+        xprof.reset()
+        _fit(net)
+        counts = (telemetry.peek("step.update_seam.apart"),
+                  telemetry.peek("step.update_seam.riding"),
+                  telemetry.peek("step.update_seam.apart_bytes",
+                                 kind="gauge"))
+        assert telemetry.peek("step.dispatches") == 4
+        assert len(texts) == 1          # one program, read once
+        return texts[0], _census(), counts
+
+
+def test_update_seam_apart_keeps_bwd_and_update_in_sets_of_their_own(
+        tel, monkeypatch):
+    """Forced apart, no instruction of the compiled step holds both the
+    backward pass and the update; the counters are counted once a traced
+    program (four steps, one trace); with everything riding, and by the
+    rule on a device with no peak, the program's text is one and holds no
+    barrier: the parent's."""
+    net = _deep_sym(layers=2)
+    text, census, counts = _step_text_and_census(monkeypatch, net, "apart")
+    n_params = 6                        # three layers' weight and bias
+    elements = DIM * 32 + 32 + 32 * 32 + 32 + 32 * CLASSES + CLASSES
+    assert counts == (n_params, 0, 4 * elements)
+    mixed = [name for name in census
+             if {"bwd", "update"} <= set(name.split("+"))]
+    assert not mixed, mixed
+    assert "update" in census and census["update"]["ops"] >= 1
+    riding, _, counts = _step_text_and_census(monkeypatch, net, "riding")
+    assert counts == (0, n_params, 0)
+    ruled, _, counts = _step_text_and_census(monkeypatch, net, None)
+    assert counts == (0, n_params, 0)
+    assert ruled == riding != text
+    assert "opt-barrier" not in riding
