@@ -231,6 +231,48 @@ def _plan_update_seam(flops, update_bytes, peaks, force=None):
         and f / (tflops * 1e12) >= _SEAM_MXU_MARGIN * b / (gbps * 1e9))
 
 
+# The step's outputs and the update. The parameters are donated: an
+# update is written where its parameter stood. Short of memory, XLA's
+# rematerialisation defers a large output to the program's end and
+# recomputes it there from whatever it still finds, and what it finds of
+# a donated parameter is the UPDATED one: in the Ling cell (PR 40) the
+# float32 probabilities ``[8192, 19648]`` came out of the head's product
+# run again AFTER the head's Adam update (``fusion.2840.remat`` reading
+# ``lm_head_weight`` 925 instructions behind the ``divide_subtract_fusion``
+# that had overwritten it), and the loss the metric folds from them read
+# 0.57% low at a rate of 3e-5, by the rate (gradients and parameters were
+# right: they read the forward pass's own probabilities). The compiler is
+# told what it may not assume: the parameters that the outputs' nearest
+# nodes read go to their update through ONE optimization barrier together
+# with the outputs, so those updates wait for outputs that are complete.
+#: bytes of outputs from which they are tied: what rematerialisation
+#: defers is what frees memory worth a product run again (644 MB there);
+#: the image cells' 1 MB of probabilities and every toy program stay as
+#: they were
+_OUTPUTS_FLOOR_BYTES = 64 << 20
+
+
+def _outputs_nearest_parameters(symbol, params):
+    """The names among ``params`` that the outputs' nearest nodes read:
+    from every output back along its inputs, each path as far as the first
+    node that reads any of ``params`` (a language model's head; a
+    classifier's last ``FullyConnected``: weight and bias)."""
+    found, seen = set(), set()
+    stack = [node for node, _ in symbol._outputs]
+    while stack:
+        node = stack.pop()
+        if node.uid in seen or node.is_variable:
+            continue
+        seen.add(node.uid)
+        read = {src.name for src, _ in node.inputs
+                if src.is_variable and src.name in params}
+        if read:
+            found |= read
+        else:
+            stack.extend(src for src, _ in node.inputs)
+    return found
+
+
 class FusedTrainStep:
     """One-dispatch training step bound to a Module's executor group.
 
@@ -662,6 +704,10 @@ class FusedTrainStep:
         math_fns = {(kind, n): _update_math(kind, n, clipped)
                     for kind, n, _ in specs}
         apart = self._update_seam(specs)
+        near = _outputs_nearest_parameters(
+            ex._symbol, {ex.arg_names[i] for i in p_idx})
+        tied = [pos for pos, i in enumerate(p_idx)
+                if ex.arg_names[i] in near]
 
         _tel.inc("executor.jit_build")
 
@@ -721,6 +767,15 @@ class FusedTrainStep:
                     for pos in apart))
             new_p = list(p_vals)
             new_st = []
+            if tied and sum(o.size * o.dtype.itemsize
+                            for o in outs) >= _OUTPUTS_FLOOR_BYTES:
+                # the outputs are complete before what they were computed
+                # from is overwritten (the comment at _OUTPUTS_FLOOR_BYTES)
+                held, outs = jax.lax.optimization_barrier(
+                    ([new_p[pos] for pos in tied], outs))
+                for pos, value in zip(tied, held):
+                    new_p[pos] = value
+                _tel.inc("step.outputs_before_update", len(tied))
             with jax.named_scope("update"):
                 for gi, (kind, n_states, positions) in enumerate(specs):
                     math_fn = math_fns[(kind, n_states)]

@@ -16,7 +16,16 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   takes a ``[T, 64]`` tile as it is, the 64 lanes beside it idle in its
   products, and the pass below moves the TWO heads that share a 128-lane
   tile of the rows in one grid step), and a sequence of whole
-  ``SPLASH_BLOCK`` blocks. One call a key/value head: its ``G`` query
+  ``SPLASH_BLOCK`` blocks. A head of one and a half lane tiles (192: the
+  Ling model's latent layers, 128 content + 64 rotary columns) goes as a
+  head of 256: 64 zero columns are put BEFORE each query's and key's own
+  (rotary turns the last columns, and a zero column adds nothing to a
+  score, so the result is exact; the scale stays ``192^-1/2``); the
+  kernel's score products then run at 256, a third more than they need,
+  and no other 192-wide path has to exist. Values may be narrower than
+  keys (``value_dim``; whole lanes): the kernel takes ``v`` at its own
+  width as it stands in JAX, and the result is ``value_dim`` wide. One
+  call a key/value head: its ``G`` query
   heads read that one head's keys, so nothing is repeated; forward and
   backward are blockwise in the kernel (scores and softmax float32 in
   VMEM, products in the compute dtype), blocks above the diagonal are
@@ -43,7 +52,8 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   kernel's ``SEQ_MINOR`` layout they compiled to MORE copies: PERF.md,
   PR 37).
 * ``xla_blockwise``: everything else (heads of any other width, toy
-  widths, an odd count of 64-wide heads). Queries go in blocks of
+  widths, an odd count of 64-wide heads), values of any width. Queries go
+  in blocks of
   ``BLOCK_Q``; a block reads only the keys at or before its end, so the
   upper triangle is never computed, and each block runs under
   ``jax.checkpoint``: the backward pass recomputes one block's scores at
@@ -225,7 +235,10 @@ class CausalAttention(Operator):
     GQA, arXiv:2305.13245) without repeating keys. ``rotary`` applies
     rotary positions to queries and keys first: over the whole head, or
     over its last ``rotary_dim`` columns (a head that is part content,
-    part position: latent attention's 192 + 64)."""
+    part position: latent attention's 192 + 64). ``value_dim`` gives the
+    values, and the result, a width of their own (latent attention whose
+    values are narrower than its keys: 128 beside 128 + 64); 0, the
+    default, is ``head_dim``."""
 
     name_hint = "causalattention"
     PARAMS = {
@@ -237,6 +250,8 @@ class CausalAttention(Operator):
         "rope_theta": Param(float, 10000.0),
         "rotary_dim": Param(int, 0, "rotate the last rotary_dim columns of "
                             "each head only; 0: the whole head"),
+        "value_dim": Param(int, 0, "a head's value (and result) width; 0: "
+                           "head_dim"),
     }
 
     def list_arguments(self):
@@ -257,15 +272,24 @@ class CausalAttention(Operator):
             raise MXNetError("CausalAttention: query width %d is not %d "
                              "heads of %d" % (q[1], self.num_heads,
                                               self.head_dim))
+        if self.value_dim < 0:
+            raise MXNetError("CausalAttention: value_dim %d"
+                             % self.value_dim)
         _sequences(q[0], self.seq_len, "CausalAttention")
-        kv = (q[0], self.num_kv_heads * self.head_dim)
-        return [q, kv, kv], [q], []
+        vd = self.value_dim or self.head_dim
+        return ([q, (q[0], self.num_kv_heads * self.head_dim),
+                 (q[0], self.num_kv_heads * vd)],
+                [(q[0], self.num_heads * vd)], [])
 
     def _splash_applies(self):
-        t, d = self.seq_len, self.head_dim
+        t, d, vd = self.seq_len, self.head_dim, self.value_dim
         # whole lanes, or two heads to a 128-lane tile of the rows
         lanes = d % 128 == 0 or (
             d == 64 and self.num_heads % 2 == self.num_kv_heads % 2 == 0)
+        if vd and vd != d:
+            # values of their own width in whole lanes; a key of a tile
+            # and a half is widened to two with zero columns
+            lanes = vd % 128 == 0 and (d % 128 == 0 or d == 192)
         return lanes and t % 128 == 0 and t % min(SPLASH_BLOCK, t) == 0
 
     def remat_results(self, in_shapes, in_types):
@@ -274,11 +298,14 @@ class CausalAttention(Operator):
         backward kernels' residuals: a step runs the forward kernel once.
         Projections and rotary are recomputed."""
         rows, width = in_shapes[0]
+        if self.value_dim:
+            width = self.num_heads * self.value_dim
         return [("attention", rows * width * np.dtype(in_types[0]).itemsize
                  + 4 * rows * self.num_heads, None)]
 
     def apply(self, ctx, inputs, aux):
         import jax
+        import jax.numpy as jnp
 
         from .. import telemetry as _tel
         from . import pallas_kernels
@@ -286,6 +313,7 @@ class CausalAttention(Operator):
         q, k, v = inputs
         t, hq, hkv, d = (self.seq_len, self.num_heads, self.num_kv_heads,
                          self.head_dim)
+        vd = self.value_dim or d
         b = q.shape[0] // t
         scale = 1.0 / float(np.sqrt(d))
         half = (self.rotary_dim or d) // 2 if self.rotary else 0
@@ -299,10 +327,18 @@ class CausalAttention(Operator):
                              pos_axis=1) for x in (q, k))
             out = jax.lax.map(
                 lambda x: attend_blockwise(x[0], x[1], x[2], scale),
-                (q, k, v.reshape(b, t, hkv, d)))
-            return [ctx.keep(out.reshape(b * t, hq * d), "attention")], []
+                (q, k, v.reshape(b, t, hkv, vd)))
+            return [ctx.keep(out.reshape(b * t, hq * vd), "attention")], []
         _tel.inc("lower.attention_kernel.pallas_splash")
         _tel.inc("lower.attention_layout.fused")
+        if d == 192:
+            # a tile and a half: zero columns before each head's own make
+            # it two (the docstring); the scale above is the true width's
+            def widened(x, heads):
+                x = jnp.pad(x.reshape(-1, heads, d), ((0, 0), (0, 0), (64, 0)))
+                return x.reshape(-1, heads * (d + 64))
+
+            q, k, d = widened(q, hq), widened(k, hkv), d + 64
         tables = relayout_tables(t, self.rope_theta, half, d)
         # the kernel takes queries already scaled: folded into the pass
         # that turns them, before its one rounding to the compute dtype
@@ -311,5 +347,5 @@ class CausalAttention(Operator):
         v = _relaid(v, batch=b, heads=hkv)
         out = attend_splash(q.reshape(b, hkv, hq // hkv, t, d), k, v,
                             ctx.kept.get("attention"))
-        return [_relaid(out.reshape(b, hq, t, d), back=True, batch=b,
+        return [_relaid(out.reshape(b, hq, t, vd), back=True, batch=b,
                         heads=hq)], []

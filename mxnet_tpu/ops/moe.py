@@ -31,6 +31,18 @@ Nemotron and GLM models follow, ``1e-6`` in ``lfm2_moe``), times ``scale``.
 The expert products take inputs in the compute dtype and accumulate in
 float32.
 
+**Group-limited choice** (``n_group`` > 1; DeepSeek-V3, arXiv:2412.19437,
+section 2.1.2; the Ling model's 512 experts in 8 groups): the experts are
+``n_group`` runs of consecutive ids; a group's score is the sum of its TWO
+largest ``scores + select_bias``, the ``topk_group`` best groups are kept
+and the ``top_k`` experts are the largest inside them, so a token's experts
+lie on at most ``topk_group`` groups' chips. The mask is float32 beside
+the scores and touches the choice alone: the combine weights are the
+chosen experts' unbiased scores as ever. ``n_group = topk_group = 1`` (the
+default) is no limit and the program the other models trace. What the op
+does NOT cover: softmax scoring, and an ``ep`` mesh axis with its exchange
+(ROADMAP Reach A2).
+
 **Two expert bodies**, by ``gated``: ``W_down relu(W_up x)^2`` (the
 default; :func:`grouped_experts`) and the gated ``W_down (silu(W_gate x) *
 W_up x)`` with a third stacked weight (:func:`grouped_experts_gated`), each
@@ -88,20 +100,29 @@ def layout_length(rows, top_k, num_held, block):
 
 
 def route(x, router, select_bias, top_k, scale, keep=lambda v: v,
-          norm_eps=1e-20):
+          norm_eps=1e-20, n_group=1, topk_group=1):
     """``x [S, h]`` -> (expert ids ``[S, k]`` int32, combine weights
     ``[S, k]`` float32): scores ``sigmoid(x W_r)``; the ``top_k`` largest
-    of ``scores + select_bias``; weights the chosen scores over their sum
-    plus ``norm_eps``, times ``scale``. ``keep`` marks the ids where they
-    are made: the weights' gradient reads them, and must read the marked
-    ones for a recomputation to skip the top-k."""
+    of ``scores + select_bias``, with ``n_group`` > 1 inside the
+    ``topk_group`` groups whose two largest sum highest; weights the chosen
+    scores over their sum plus ``norm_eps``, times ``scale``. ``keep``
+    marks the ids where they are made: the weights' gradient reads them,
+    and must read the marked ones for a recomputation to skip the top-k."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
     scores = jax.nn.sigmoid(jnp.dot(x.astype(f32), router.astype(f32),
                                     precision=jax.lax.Precision.HIGHEST))
-    _, eid = jax.lax.top_k(scores + select_bias.astype(f32), top_k)
+    choice = scores + select_bias.astype(f32)
+    if n_group > 1:
+        groups = choice.reshape(choice.shape[0], n_group, -1)
+        _, best = jax.lax.top_k(jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1),
+                                topk_group)
+        kept = jnp.sum(jax.nn.one_hot(best, n_group, dtype=f32), axis=1)
+        choice = jnp.where(kept[:, :, None] > 0, groups,
+                           -jnp.inf).reshape(choice.shape)
+    _, eid = jax.lax.top_k(choice, top_k)
     eid = keep(eid.astype(jnp.int32))
     chosen = jnp.take_along_axis(scores, eid, axis=1)
     wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + norm_eps) \
@@ -385,6 +406,9 @@ class RoutedExperts(Operator):
                        "x), a third stacked weight gate_weight"),
         "norm_eps": Param(float, 1e-20, "added to the chosen scores' sum "
                           "before the combine weights are divided by it"),
+        "n_group": Param(int, 1, "groups of consecutive experts the choice "
+                         "is limited by; 1: no limit"),
+        "topk_group": Param(int, 1, "groups a row's experts may lie in"),
     }
     # arguments that reach the op in their own dtype under mixed precision
     full_precision_args = ("router_weight",)
@@ -406,6 +430,11 @@ class RoutedExperts(Operator):
             raise MXNetError("RoutedExperts: experts %d..%d of %d, top_k %d"
                              % (self.first_held, self.first_held + held, e,
                                 self.top_k))
+        g, kept = self.n_group, self.topk_group
+        if g < 1 or e % g or not 1 <= kept <= g or (
+                g > 1 and (e // g < 2 or kept * (e // g) < self.top_k)):
+            raise MXNetError("RoutedExperts: top_k %d inside %d of %d groups "
+                             "of %d experts" % (self.top_k, kept, g, e))
         h, f = data[1], self.num_hidden
         up = [(held, h, f)] * (2 if self.gated else 1)
         return ([data, (h, e)] + up + [(held, f, h)], [data],
@@ -446,7 +475,7 @@ class RoutedExperts(Operator):
         e = self.num_experts
         keep = functools.partial(ctx.keep, result="routing")
         eid, wts = route(x, router, bias, self.top_k, self.scale, keep,
-                         self.norm_eps)
+                         self.norm_eps, self.n_group, self.topk_group)
         block = block_rows(x.shape[0], self.top_k, self.num_experts)
         *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
                                 block)

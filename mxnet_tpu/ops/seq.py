@@ -800,11 +800,176 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     return jnp.moveaxis(o, 2, 1).reshape(t, h, dv), starts
 
 
+SUB_CHUNK = 16      # positions a sub-chunk of the per-channel body
+
+
+def gated_delta_chunked_channel(q, k, v, g, beta, chunk, sub=SUB_CHUNK):
+    """:func:`gated_delta_chunked` with one decay a KEY CHANNEL (Kimi Delta
+    Attention; Kimi Linear, arXiv:2510.26692): ``S_t = (I - b_t k_t k_t^T)
+    Diag(exp(g_t)) S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T q_t``, ``g [T,
+    H, K]`` the log-decays (<= 0). The chunk's WY form stands as it is, with
+    ``G_t = Diag(exp(c_t))`` (``c`` the decays summed from the chunk's start)
+    where the scalar was: ``A[t, i] = b_t sum_d k_t[d] k_i[d] exp(c_t[d] -
+    c_i[d])`` below the diagonal, ``(I + A) U = B V - B (K * exp(c)) S_0``,
+    ``O = (Q * exp(c)) S_0 + P U`` with ``P[t, i] = sum_d q_t[d] k_i[d]
+    exp(c_t[d] - c_i[d])`` on and below it, ``S_end = Diag(exp(c_L)) S_0 +
+    (K * exp(c_L - c))^T U``.
+
+    What changes is how ``A`` and ``P`` are formed: the decay no longer
+    factors out of the inner product, and written ``(K * exp(c)) (K *
+    exp(-c))^T`` the second factor overflows float32 (``exp(320)`` over 64
+    positions at a log-decay of -5). So the chunk is cut into sub-chunks of
+    ``sub`` positions and every exponent is referred to a sub-chunk's own
+    MIDDLE: block row ``s`` is ``(X_s * exp(c_t - r_s)) (K * exp(r_s -
+    c_i))^T`` (``r_s`` the sum up to the middle of sub-chunk ``s``), both
+    factors within ``exp(+-sub/2 * |g|)`` inside ``s``'s own sub-chunk
+    (``exp(+-40)`` at 16 x 5), the right one in (0, 1] before it, where
+    an underflow loses what the true product has lost already; columns
+    after sub-chunk ``s`` are above the diagonal and not formed. (Referred
+    to the sub-chunk's START, as the published kernels' description has
+    it, the left factor sinks to ``exp(-80)`` = 1.8e-35: inside float32,
+    but the low-order parts of the six-pass ``HIGHEST`` product, 2^-16 of
+    that, are subnormal and flushed, and the last positions of a sub-chunk
+    came out at bfloat16's precision: 1e-3 against the recurrence at the
+    bound, 3e-7 so.) Every other exponent (``c``, ``c_L - c``, ``c_L``) is
+    <= 0.
+
+    Float32 at ``HIGHEST`` and the solve by substitution, as the scalar
+    body. ``q``, ``k [T, H, K]``, ``v [T, H, V]``, ``g [T, H, K]``, ``beta
+    [T, H]``; T whole chunks of whole sub-chunks. Returns ``o [T, H, V]``
+    and the chunk-start states ``[T/chunk, H, K, V]``."""
+    jax, jnp = _jax(), _jnp()
+    from jax.scipy.linalg import solve_triangular
+
+    hi = jax.lax.Precision.HIGHEST
+    t, h, dk = k.shape
+    dv = v.shape[-1]
+    nc, ns = t // chunk, chunk // sub
+
+    def cut(x):                         # [T, H, ...] -> [nc, H, L, ...]
+        x = x.reshape((nc, chunk) + x.shape[1:])
+        return jnp.moveaxis(x, 1, 2)
+
+    def subs(x):                        # [nc, H, L, K] -> [nc, H, ns, sub, K]
+        return x.reshape(nc, h, ns, sub, dk)
+
+    q, k, v, g, beta = (cut(x) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=2)                          # [nc, H, L, K]
+
+    @jax.checkpoint
+    def scores(q, k, gc, beta):
+        """``A`` and ``P [nc, H, L, L]``. Recomputed in the backward pass:
+        the scaled copies of q and k (the columns' FOUR times a key's
+        bytes) are elementwise and cheap, and kept as residuals they were
+        1.4 of the body's 3.0 GB at 8,192 x 32 x 128, with which the cell's
+        step did not load (PR 40)."""
+        # r_s: the decays summed up to the middle of sub-chunk s
+        ref = gc[:, :, sub // 2 - 1::sub]               # [nc, H, ns, K]
+        e_row = jnp.exp(subs(gc) - ref[:, :, :, None])
+        # columns i against block row s: those of sub-chunks <= s
+        seen = jnp.arange(chunk)[None, :] \
+            < (jnp.arange(ns)[:, None] + 1) * sub
+        seen = seen[None, None, :, :, None]             # [1, 1, ns, L, 1]
+        e_col = jnp.where(seen, jnp.exp(jnp.where(
+            seen, ref[:, :, :, None] - gc[:, :, None], 0.0)), 0.0)
+        k_col = k[:, :, None] * e_col                   # [nc, H, ns, L, K]
+        kk = jnp.einsum("nhstd,nhsid->nhsti", subs(k) * e_row, k_col,
+                        precision=hi).reshape(nc, h, chunk, chunk)
+        qk = jnp.einsum("nhstd,nhsid->nhsti", subs(q) * e_row, k_col,
+                        precision=hi).reshape(nc, h, chunk, chunk)
+        lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+        return (jnp.where(jnp.tril(lower, -1), beta[..., None] * kk, 0.0),
+                jnp.where(lower, qk, 0.0))
+
+    a_mat, p_mat = scores(q, k, gc, beta)
+    rhs = jnp.concatenate([beta[..., None] * v,
+                           beta[..., None] * k * jnp.exp(gc)], axis=-1)
+    sol = solve_triangular(a_mat, rhs, lower=True, unit_diagonal=True)
+    u0, w = sol[..., :dv], sol[..., dv:]
+    k_end = k * jnp.exp(gc[:, :, -1:] - gc)             # k_i G_L / G_i
+    m = jnp.exp(gc[:, :, -1])[..., None] * jnp.eye(dk, dtype=k.dtype) \
+        - jnp.einsum("nhld,nhle->nhde", k_end, w, precision=hi)
+    n = jnp.einsum("nhld,nhle->nhde", k_end, u0, precision=hi)
+
+    def carry(s, mn):
+        return jnp.einsum("hde,hev->hdv", mn[0], s, precision=hi) + mn[1], s
+
+    _, starts = jax.lax.scan(carry, jnp.zeros((h, dk, dv), k.dtype), (m, n))
+    u = u0 - jnp.einsum("nhld,nhdv->nhlv", w, starts, precision=hi)
+    o = jnp.einsum("nhld,nhdv->nhlv", q * jnp.exp(gc), starts, precision=hi) \
+        + jnp.einsum("nhti,nhiv->nhtv", p_mat, u, precision=hi)
+    return jnp.moveaxis(o, 2, 1).reshape(t, h, dv), starts
+
+
+# float32 bytes of the per-channel body's intermediates that one run of it
+# may hold: with all 32 heads of an 8,192-position sequence at 128 x 128 at
+# once the body's forward and backward held 3.0 GB, and the cell's step was
+# 0.2 GB over the chip (PR 40); 8 heads a run hold 0.8
+_CHANNEL_RUN_BYTES = 1 << 30
+
+
+def _channel_scan(seq_ops, head_ops, chunk, prepare, out_dtype):
+    """:func:`gated_delta_chunked_channel` over sequences, a GROUP OF HEADS
+    at a time where all of them together would hold more than
+    ``_CHANNEL_RUN_BYTES`` of float32 intermediates (reckoned from the
+    shapes: a position and head holds about ``2 ns K`` for the columns'
+    scaled keys, ``10 K + 4 V`` for the other scaled copies, right-hand
+    sides and solutions, ``4 L`` for the chunk's square matrices).
+    ``seq_ops`` are ``[B, T, H, ...]``, ``head_ops`` ``[H, ...]``;
+    ``prepare(*seq_ops, *head_ops)`` of one sequence's group (``[T, hg,
+    ...]``, ``[hg, ...]``) gives the body's float32 ``q, k, v, g, beta``,
+    so that what is float32 exists a group at a time too; the result is
+    cut to ``T`` and cast to ``out_dtype`` inside. The heads are
+    independent, so the result is the same; each group runs under
+    ``jax.checkpoint``, so that the backward pass holds one group's
+    intermediates and not all groups' residuals (at the price of the
+    group's forward pass once more). One group: the plain body."""
+    jax, jnp = _jax(), _jnp()
+
+    b, t, h = seq_ops[0].shape[:3]
+    dk, dv = seq_ops[1].shape[-1], seq_ops[2].shape[-1]
+    pad = -t % chunk
+    a_head = 4 * (t + pad) * (2 * (chunk // SUB_CHUNK) * dk + 10 * dk
+                              + 4 * dv + 4 * chunk)
+    hg = max(d for d in range(1, h + 1)
+             if h % d == 0 and (d == 1 or d * a_head <= _CHANNEL_RUN_BYTES))
+
+    def one(x):
+        ops = prepare(*x[0], *x[1])
+        if pad:
+            # past the end: decay 1, beta 0, no key: the state stands still
+            ops = [jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                   for v in ops]
+        return gated_delta_chunked_channel(*ops, chunk=chunk)[0][:t].astype(
+            out_dtype)
+
+    if hg == h:
+        return jax.lax.map(lambda s: one((s, head_ops)), tuple(seq_ops))
+
+    def groups(x):      # [B, T, H, ...] -> [B * H/hg, T, hg, ...]
+        x = x.reshape((b, t, h // hg, hg) + x.shape[3:])
+        return jnp.moveaxis(x, 2, 1).reshape((-1, t, hg) + x.shape[4:])
+
+    def head_groups(x):     # [H, ...] -> [B * H/hg, hg, ...]
+        x = x.reshape((1, h // hg, hg) + x.shape[1:])
+        return jnp.broadcast_to(x, (b,) + x.shape[1:]).reshape(
+            (-1, hg) + x.shape[3:])
+
+    o = jax.lax.map(jax.checkpoint(one),
+                    (tuple(groups(x) for x in seq_ops),
+                     tuple(head_groups(x) for x in head_ops)))
+    return jnp.moveaxis(o.reshape(b, h // hg, t, hg, dv), 1, 2).reshape(
+        b, t, h, dv)
+
+
 def gated_delta_scan(q, k, v, g, beta, chunk, kernel):
     """The gated delta rule over sequences of whole chunks as ONE
     differentiable function, beside :func:`ssd_scan`: ``q``, ``k [B, T, H,
     K]``, ``v [B, T, H, V]``, ``g``, ``beta [B, T, H]``, float32; returns
-    ``o [B, T, H, V]``. ``kernel`` picks the body: the Pallas chunk kernels
+    ``o [B, T, H, V]``. With ``g [B, T, H, K]``, a decay a key channel, the
+    body is :func:`gated_delta_chunked_channel` under autodiff, a group of
+    heads at a time (:func:`_channel_scan`; no kernel takes it yet).
+    ``kernel`` picks the scalar body: the Pallas chunk kernels
     (``pallas_kernels.delta_chunk_forward`` / ``_backward``: the solve, the
     chunk's matrices and the carried state stay in VMEM) with the backward
     pass written out, its residuals the five inputs and the float32
@@ -813,6 +978,9 @@ def gated_delta_scan(q, k, v, g, beta, chunk, kernel):
     jax = _jax()
     from . import pallas_kernels
 
+    if g.ndim == 4:
+        return _channel_scan((q, k, v, g, beta), (), chunk,
+                             lambda *ops: ops, q.dtype)
     if not kernel:
         return jax.lax.map(
             lambda x: gated_delta_chunked(*x, chunk=chunk)[0],
@@ -840,16 +1008,32 @@ class GatedDeltaRule(Operator):
     """Linear attention by the gated delta rule over whole sequences,
     chunked (:func:`gated_delta_chunked`), beside ``SSMScan``. Per position
     and head, from the mixer's convolved and activated projections
-    ``query``, ``key`` ``[rows, H*K]``, ``value`` ``[rows, H*V]`` and the
-    two per-head gates ``a``, ``b`` ``[rows, H]`` before their
+    ``query``, ``key`` ``[rows, H*K]``, ``value`` ``[rows, H*V]``, the step
+    gate ``b`` ``[rows, H]`` and the decay gate ``a``, both before their
     nonlinearities:
 
     ``q = query / |query|_2 / sqrt(K)``, ``k = key / |key|_2``;
     ``beta = sigmoid(b)``, doubled under ``neg_eigval`` (the factor ``I -
     beta k k^T`` may then reflect: eigenvalues in (-1, 1));
-    ``alpha = exp(-exp(A_log) softplus(a + dt_bias))``;
+    ``alpha = exp(g)``, ``g`` the log-decay (below);
     ``S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T``,
     ``o_t = S_t^T q_t``, ``S_0 = 0`` at each sequence's start.
+
+    **What the op covers.** The decay's SHAPE is read off ``a``: ``[rows,
+    H]`` is one decay a head (Gated DeltaNet, arXiv:2412.06464; ``dt_bias
+    [H]``), ``[rows, H*K]`` one a KEY CHANNEL (Kimi Delta Attention,
+    arXiv:2510.26692; ``dt_bias [H*K]``; ``alpha_t`` is then
+    ``Diag(exp(g_t))`` on the state's key axis: ``S_t = (I - beta_t k_t
+    k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``). ``A_log`` is ``[H]``
+    in both. The gate's FORM is ``gate_floor``: 0 (the default) is ``g =
+    -exp(A_log) softplus(a + dt_bias)``, unbounded below; a negative floor
+    is the bounded gate ``g = gate_floor * sigmoid(exp(A_log) (a +
+    dt_bias))``, every log-decay in ``(gate_floor, 0)``, which is what
+    keeps the per-channel chunk's intermediates inside float32
+    (:func:`gated_delta_chunked_channel`: -5 x a 16-position sub-chunk =
+    -80). Either form goes with either shape; a per-channel gate whose
+    channels are equal computes the per-head op. Counted
+    ``lower.delta_rule_gate.head`` / ``.channel`` a traced node.
 
     The normalisation, the doubling and the decay are inside the op, in
     float32 like the state and the solve, whatever the compute dtype; the
@@ -862,6 +1046,8 @@ class GatedDeltaRule(Operator):
     values (``lower.delta_rule_kernel.pallas_chunked``; 64 x 96 x 192 at 15
     heads is such a shape), else the same chunked algorithm in
     ``jax.numpy`` under autodiff (``lower.delta_rule_kernel.xla_chunked``).
+    A decay a channel always takes the ``jax.numpy`` body, chunks cut into
+    sub-chunks of 16 (no kernel forms its chunk yet: ROADMAP Speed).
     In both, every operand and product is float32 (products at ``HIGHEST``:
     six bfloat16 passes, never one), as are the decays, the solve, the
     carried state, its gradient and every accumulator. The kernels' backward
@@ -878,6 +1064,9 @@ class GatedDeltaRule(Operator):
         "chunk": Param(int, 64),
         "seq_len": Param(int, REQUIRED),
         "neg_eigval": Param(bool, False, "beta in (0, 2), not (0, 1)"),
+        "gate_floor": Param(float, 0.0, "0: log-decay -exp(A_log) softplus(a "
+                            "+ dt_bias); negative: gate_floor * sigmoid("
+                            "exp(A_log) (a + dt_bias)), bounded below"),
     }
     # the decay compounds over a sequence: its parameters stay float32
     full_precision_args = ("A_log", "dt_bias")
@@ -887,17 +1076,26 @@ class GatedDeltaRule(Operator):
         return ["query", "key", "value", "a", "b", "A_log", "dt_bias"]
 
     def infer_shape(self, in_shapes):
-        q = in_shapes[0]
+        q, a = in_shapes[0], in_shapes[3]
         if q is None:
             raise MXNetError("GatedDeltaRule: query shape unknown")
         h = self.num_heads
         if q[1] != h * self.key_dim:
             raise MXNetError("GatedDeltaRule: query width %d is not %d heads "
                              "of %d" % (q[1], h, self.key_dim))
+        if self.gate_floor > 0:
+            raise MXNetError("GatedDeltaRule: gate_floor %g is above 0"
+                             % self.gate_floor)
         _sequences(q[0], self.seq_len, "GatedDeltaRule")
         rows = q[0]
-        return ([q, q, (rows, h * self.value_dim), (rows, h), (rows, h),
-                 (h,), (h,)], [(rows, h * self.value_dim)], [])
+        # a decay a head unless ``a`` comes a key channel wide
+        gate = h if a is None else a[1]
+        if gate not in (h, h * self.key_dim):
+            raise MXNetError("GatedDeltaRule: a of width %d is neither a "
+                             "decay a head (%d) nor a key channel (%d)"
+                             % (gate, h, h * self.key_dim))
+        return ([q, q, (rows, h * self.value_dim), (rows, gate), (rows, h),
+                 (h,), (gate,)], [(rows, h * self.value_dim)], [])
 
     def remat_results(self, in_shapes, in_types):
         """Kept always under recomputation: the result, one activation of
@@ -924,9 +1122,41 @@ class GatedDeltaRule(Operator):
                 jnp.sum(jnp.square(x), axis=-1, keepdims=True)
                 + self.NORM_EPS)
 
+        def gate(a, a_log, dt_bias):
+            if self.gate_floor:
+                return self.gate_floor * jax.nn.sigmoid(jnp.exp(a_log)
+                                                        * (a + dt_bias))
+            return -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+
+        channel = a.shape[1] != h
+        _tel.inc("lower.delta_rule_gate.%s"
+                 % ("channel" if channel else "head"))
+        if channel:
+            if self.chunk % SUB_CHUNK:
+                raise MXNetError("GatedDeltaRule: a decay a channel wants "
+                                 "chunks of whole %d-position sub-chunks, "
+                                 "not %d" % (SUB_CHUNK, self.chunk))
+            _tel.inc("lower.delta_rule_kernel.xla_chunked")
+
+            def prepare(q, k, v, a, b, a_log, dt_bias):
+                """One sequence's group of heads, as it comes (``[T, hg,
+                .]`` in the compute dtype; ``a_log [hg]``, ``dt_bias [hg,
+                K]``) -> the body's float32 operands."""
+                q, k, v, a, b = (x.astype(f32) for x in (q, k, v, a, b))
+                beta = jax.nn.sigmoid(b)
+                return (unit(q) * (self.key_dim ** -0.5), unit(k), v,
+                        gate(a, a_log[:, None], dt_bias),
+                        2.0 * beta if self.neg_eigval else beta)
+
+            raw = inputs[:5]
+            o = _channel_scan(
+                [heads(x) for x in raw[:4]] + [raw[4].reshape(n, t, h)],
+                (a_log, dt_bias.reshape(h, self.key_dim)), self.chunk,
+                prepare, raw[0].dtype)
+            return [ctx.keep(o.reshape(n * t, -1), "output")], []
         q = unit(heads(q)) * (self.key_dim ** -0.5)
         k, v = unit(heads(k)), heads(v)
-        g = (-jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)).reshape(n, t, h)
+        g = gate(a, a_log, dt_bias).reshape(n, t, h)
         beta = jax.nn.sigmoid(b).reshape(n, t, h)
         if self.neg_eigval:
             beta = 2.0 * beta

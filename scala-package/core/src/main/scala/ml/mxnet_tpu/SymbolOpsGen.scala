@@ -228,11 +228,13 @@ object SymbolOpsGen {
       value: Symbol = null,
       rotary: Boolean = true,
       ropeTheta: Float = 10000.0f,
-      rotaryDim: Int = 0): Symbol = {
+      rotaryDim: Int = 0,
+      valueDim: Int = 0): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("rotary", rotary.toString))
     ps += (("rope_theta", ropeTheta.toString))
     ps += (("rotary_dim", rotaryDim.toString))
+    ps += (("value_dim", valueDim.toString))
     ps += (("num_heads", numHeads.toString))
     ps += (("num_kv_heads", numKvHeads.toString))
     ps += (("head_dim", headDim.toString))
@@ -547,10 +549,12 @@ object SymbolOpsGen {
       ALog: Symbol = null,
       dtBias: Symbol = null,
       chunk: Int = 64,
-      negEigval: Boolean = false): Symbol = {
+      negEigval: Boolean = false,
+      gateFloor: Float = 0.0f): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("chunk", chunk.toString))
     ps += (("neg_eigval", negEigval.toString))
+    ps += (("gate_floor", gateFloor.toString))
     ps += (("num_heads", numHeads.toString))
     ps += (("key_dim", keyDim.toString))
     ps += (("value_dim", valueDim.toString))
@@ -839,13 +843,17 @@ object SymbolOpsGen {
       scale: Float = 1.0f,
       biasUpdateRate: Float = 0.0f,
       gated: Boolean = false,
-      normEps: Float = 1e-20f): Symbol = {
+      normEps: Float = 1e-20f,
+      nGroup: Int = 1,
+      topkGroup: Int = 1): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("first_held", firstHeld.toString))
     ps += (("scale", scale.toString))
     ps += (("bias_update_rate", biasUpdateRate.toString))
     ps += (("gated", gated.toString))
     ps += (("norm_eps", normEps.toString))
+    ps += (("n_group", nGroup.toString))
+    ps += (("topk_group", topkGroup.toString))
     ps += (("num_experts", numExperts.toString))
     ps += (("num_held", numHeld.toString))
     ps += (("top_k", topK.toString))

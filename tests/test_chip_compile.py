@@ -58,12 +58,15 @@ def test_delta_chunk_kernels_compile_for_the_chip(one_chip, heads, dk, dv,
         assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("h,group,d", [(20, 1, 256), (8, 4, 64)],
-                         ids=["256-wide", "64-wide-grouped"])
-def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d):
-    """The kernel call at two of the benchmark's cells: latent attention's
-    20 one-head groups of 256 columns, and LFM2's 8 groups of four heads of
-    64, half a lane tile, over 8,192 positions, forward and backward."""
+@pytest.mark.parametrize("h,group,d,dv", [
+    (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128)],
+    ids=["256-wide", "64-wide-grouped", "256-wide-keys-128-wide-values"])
+def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
+    """The kernel call at three of the benchmark's cells: latent attention's
+    20 one-head groups of 256 columns, LFM2's 8 groups of four heads of
+    64, half a lane tile, and Ling's 32 heads whose 192-wide keys go
+    widened to 256 beside values of 128, over 8,192 positions, forward and
+    backward."""
     from mxnet_tpu.ops import attention
 
     b, t = 1, 8192
@@ -79,7 +82,7 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d):
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             shape(b, h, group, t, d), shape(b, h, t, d),
-            shape(b, h, t, d)).compile()
+            shape(b, h, t, dv)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
@@ -92,9 +95,10 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d):
     (3, 640, 2, 128, 2, "float32"),         # one pair; 128-row tiles
     (1, 8192, 32, 64, 64, "bfloat16"),      # LFM2's queries: two heads a tile
     (1, 8192, 8, 64, 0, "bfloat16"),        # LFM2's values
-    (2, 1024, 2, 64, 16, "float32")],       # a part of a 64-wide head
+    (2, 1024, 2, 64, 16, "float32"),        # a part of a 64-wide head
+    (1, 8192, 32, 256, 64, "bfloat16")],    # Ling's keys, widened from 192
     ids=["glm", "nemotron", "plain", "two-tiles", "tile-and-a-half",
-         "one-pair", "lfm2", "lfm2-plain", "half-lanes-part"])
+         "one-pair", "lfm2", "lfm2-plain", "half-lanes-part", "ling"])
 def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
                                                         heads, d, turned,
                                                         dtype):
@@ -147,6 +151,7 @@ EXPERT_CELLS = {
     # rows, h, f, held, top_k, experts, gated, dtype
     "nemotron-cell": (8192, 2688, 1856, 8, 6, 128, False, "bfloat16"),
     "glm-cell": (8192, 2048, 1536, 8, 4, 64, True, "bfloat16"),
+    "ling-cell": (8192, 2560, 768, 8, 8, 512, True, "bfloat16"),
     "narrowest-float32": (64, 128, 8, 2, 2, 4, True, "float32"),
     "narrowest-bfloat16": (128, 128, 16, 2, 2, 4, False, "bfloat16"),
 }

@@ -448,3 +448,69 @@ def test_update_seam_same_results_either_side(tel, monkeypatch, net,
     for k, want in got["riding"].items():
         np.testing.assert_array_equal(got[None][k], want, err_msg=k)
         np.testing.assert_array_equal(got["apart"][k], want, err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# the step's outputs are complete before the update that overwrites what
+# they were computed from
+# ---------------------------------------------------------------------------
+
+def test_outputs_nearest_parameters_by_graph():
+    """From each output back to the first node that reads a trained
+    parameter: a classifier's last ``FullyConnected`` (weight and bias,
+    past the loss node that reads only the label), a language model's head
+    (past the label's reshape), a weight shared with an earlier node
+    once."""
+    from mxnet_tpu.fused_step import _outputs_nearest_parameters
+
+    net = _mlp_sym()
+    params = set(net.list_arguments()) - {"data", "softmax_label"}
+    assert _outputs_nearest_parameters(net, params) \
+        == {"fc2_weight", "fc2_bias"}
+    x = sym.Embedding(data=sym.Variable("data"), input_dim=32,
+                      output_dim=8, name="embed")
+    x = sym.Reshape(data=x, shape=(-1, 8))
+    shared = sym.Variable("embed_weight")
+    x = sym.FullyConnected(data=sym.RMSNorm(data=x, name="norm"),
+                           weight=shared, num_hidden=32, no_bias=True,
+                           name="head")
+    lm = sym.SoftmaxOutput(
+        data=x, label=sym.Reshape(data=sym.Variable("softmax_label"),
+                                  shape=(-1,)), name="softmax")
+    params = set(lm.list_arguments()) - {"data", "softmax_label"}
+    assert _outputs_nearest_parameters(lm, params) == {"embed_weight"}
+    # a parameter nobody trains stops no path
+    assert _outputs_nearest_parameters(lm, {"norm_gamma"}) == {"norm_gamma"}
+
+
+@pytest.mark.parametrize("net, data_shape, numwatch, compute_dtype", [
+    (_mlp_sym, (DIM,), True, None), (_conv_sym, (1, 5, 5), False, None),
+    (_mlp_sym, (DIM,), False, "bfloat16"),
+], ids=["mlp_numwatch", "conv", "mlp_bfloat16"])
+def test_outputs_before_update_same_results(tel, monkeypatch, net,
+                                            data_shape, numwatch,
+                                            compute_dtype):
+    """Tied to the outputs or not, three steps leave the same parameters,
+    auxiliary states and optimizer states, to the bit: the barrier says
+    when the last layer's update may begin, not what it computes. Below
+    the floor (every program of these tests) nothing is tied."""
+    from mxnet_tpu import fused_step
+
+    if numwatch:
+        monkeypatch.setenv("MXNET_TPU_NUMWATCH", "1")
+    if compute_dtype:
+        monkeypatch.setenv("MXNET_COMPUTE_DTYPE", compute_dtype)
+    got = {}
+    for floor in (0, None):
+        with monkeypatch.context() as m:
+            telemetry.reset()
+            if floor is not None:
+                m.setattr(fused_step, "_OUTPUTS_FLOOR_BYTES", floor)
+            got[floor] = _seam_fit(net(), data_shape, "adam",
+                                   {"learning_rate": 0.01}, None, m)
+            assert telemetry.peek("step.outputs_before_update") \
+                == (2 if floor == 0 else None)
+            assert telemetry.peek("step.dispatches") == 3
+    assert got[0].keys() == got[None].keys()
+    for k, want in got[None].items():
+        np.testing.assert_array_equal(got[0][k], want, err_msg=k)
