@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import types
 from typing import Optional
 
 import numpy as np
@@ -32,8 +33,9 @@ __all__ = ["fused_linear", "flash_attention", "pallas_available",
            "conv_backward_applicable", "fused_norm_act",
            "norm_act_applicable", "ssd_chunk_applicable",
            "ssd_chunk_forward", "ssd_chunk_backward",
-           "delta_chunk_applicable", "delta_chunk_forward",
-           "delta_chunk_backward", "attention_relayout"]
+           "delta_chunk_applicable", "delta_channel_applicable",
+           "delta_chunk_forward", "delta_chunk_backward",
+           "attention_relayout"]
 
 # float32 MXU-friendly tiles (sublane 8, lane 128)
 TILE_M = 128
@@ -1146,23 +1148,57 @@ def ssd_chunk_backward(*args, **static):
 # A`` inside the chunk, summed along its rows (at t) and its columns (at i,
 # negative). The caller rounds the output to the compute dtype once,
 # outside.
+#
+# One decay a KEY CHANNEL (``g [B, T, H, K]``; ``seq.
+# gated_delta_chunked_channel`` has the algebra) runs through the same two
+# kernels, the branch taken in Python when the call is traced
+# (``channel_parts`` / ``head_channel``; the scalar bodies are untouched by
+# it). What differs: the gate rides the keys' layout and its gradient
+# leaves in it; the cumulative sum ``c [L, K]`` is a product with a
+# triangle of ones (exact: the six passes hold a float32 times 1); the
+# decay does not factor out of ``K K^T``, so ``A`` and ``P`` are formed a
+# block row a 16-position sub-chunk, ``([Q_s; K_s] * exp(c - r_s)) (K *
+# exp(r_s - c))^T`` over the columns of sub-chunks ``<= s`` (the rest
+# scaled by 0), every exponent referred to the sub-chunk's MIDDLE so that
+# both factors stay within ``exp(+-40)`` at a log-decay of -5 (referred to
+# its start the six-pass product's low-order parts go subnormal and Mosaic
+# flushes them); ``exp(c)``, ``exp(c_L - c)`` are ``[L, K]`` and scale q
+# and k, ``exp(c_L)`` ``[K, 1]`` scales the state's key axis, all with
+# exponents <= 0. The decay's gradient has no one-matrix form a channel;
+# ``c`` enters only as a factor ``exp(+-c)`` of a row of q or k, and for an
+# operand ``X * exp(c)``, ``dc = X * dX``: ``dc = q * dq + k * (dk_row -
+# dk_col)``, k's gradient split by the side k stood on, plus ``c_L``'s
+# terms at the last position, then the reversed cumulative sum as a
+# product with the same triangle. The scores' gradients go back the way
+# the scores came: a block row ``[dP_s; dA_s b] (K * exp(r_s - c))`` for q
+# and k by rows, its transpose against ``[Q_s; K_s] * exp(c - r_s)`` for k
+# by columns.
 
 # heads a grid step takes at most (three or five measure alike, one is a
 # tenth slower), and the float32 words it may hold by the count below: five
 # heads at 64 x (96 + 192) fit the 16 MB of scoped VMEM, fifteen do not
 _DELTA_HEADS = 5
 _DELTA_STEP_WORDS = 420_000
+# positions a sub-chunk of the chunk with a decay a key channel
+# (``seq.gated_delta_chunked_channel``, whose ``SUB_CHUNK`` this is)
+DELTA_SUB_CHUNK = 16
 
 
-def _delta_heads(heads, dk, dv, chunk):
+def _delta_heads(heads, dk, dv, chunk, channel=False):
     """Heads a grid step takes: the most that divide ``heads`` evenly and
     fit VMEM together, a head counted as its rows of q/k and v, four of the
-    chunk's square matrices and its state, each padded to whole lanes."""
+    chunk's square matrices and its state, each padded to whole lanes; with
+    a decay a key ``channel`` also the gate's block and ``exp(c - r)`` (the
+    scaled copies of k a sub-chunk come and go one at a time): four heads a
+    step at 64 x 128 x 128, which measured 4-8% under two and 12-18% under
+    one a pass of a layer alone (PR 41)."""
     def lanes(width):
         return -(-width // 128) * 128
 
     words = chunk * (lanes(dk) + lanes(dv) + 4 * lanes(chunk)) \
         + dk * lanes(dv)
+    if channel:
+        words += 2 * chunk * lanes(dk)
     most = min(_DELTA_HEADS, max(1, _DELTA_STEP_WORDS // words))
     return max(d for d in range(1, most + 1) if heads % d == 0)
 
@@ -1181,8 +1217,28 @@ def delta_chunk_applicable(dims, chunk, dtype) -> bool:
             and pallas_available())
 
 
+def delta_channel_applicable(dims, chunk, dtype) -> bool:
+    """Whether the chunk kernels take ``dims = (H, K, V)`` with one decay a
+    KEY CHANNEL (``g [B, T, H, K]``): what :func:`delta_chunk_applicable`
+    admits, of that a key width of whole lane tiles (128: the gate and the
+    scaled keys are ``[rows, K]`` tiles with K along the lanes; what the
+    published models of this family have) and a chunk of whole
+    ``DELTA_SUB_CHUNK``-position sub-chunks (two sublane tiles each) up to
+    64 positions, four sub-chunks."""
+    return (delta_chunk_applicable(dims, chunk, dtype) and dims[1] % 128 == 0
+            and chunk % DELTA_SUB_CHUNK == 0 and chunk <= 64)
+
+
 _DeltaChunk = collections.namedtuple(
     "_DeltaChunk", "b_col e_col w_col e_last decay p kkd a t_inv qs ks z u")
+# the same with a decay a key channel: ``e_col``, ``w_col [L, K]`` and
+# ``e_last [K, 1]`` (beside it as the row ``e_last_row [1, K]``), the block
+# rows' factors ``e_row [L, K]`` and ``col_exp`` (a sub-chunk's ``[L, K]``
+# each) where ``decay [L, L]`` was, ``qe``, ``ke`` = q, k ``* e_col`` and
+# ``qs``, ``ks`` their products with the state
+_DeltaChannelChunk = collections.namedtuple(
+    "_DeltaChannelChunk", "b_col e_col w_col e_last e_last_row e_row col_exp "
+    "p kkd a t_inv qe ke qs ks z u")
 
 
 def _delta_chunk_tools(chunk):
@@ -1276,18 +1332,92 @@ def _delta_chunk_tools(chunk):
                                    nn(t_inv, b_col * z)))
         return out
 
-    return nn, nt, tn, both, tn_sum, masks, column, row, parts
+    sub = DELTA_SUB_CHUNK
+    ns = chunk // sub
+
+    def by_sub(x, s):
+        """Sub-chunk ``s``'s rows of ``x [L, .]``."""
+        return x[s * sub:(s + 1) * sub]
+
+    def square_eye(n):
+        return jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) \
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+
+    def triangle(lower):
+        """Ones where ``lower``: ``triangle @ x`` sums x's rows from the
+        chunk's start, ``triangle^T @ x`` to its end; exactly (the six
+        passes hold a float32 times 1), in float32."""
+        return jnp.where(lower, 1.0, 0.0).astype(f32)
+
+    def channel_parts(heads, masks3):
+        """:func:`parts` with a decay a key channel: ``g [L, K]`` where
+        ``g_row`` was. ``A`` and ``P`` are formed a block row a sub-chunk,
+        ``([Q_s; K_s] * exp(c - r_s)) (K * exp(r_s - c))^T`` over the
+        columns of sub-chunks ``<= s`` (the others' factor is 0), ``r_s``
+        the decays summed to the sub-chunk's MIDDLE: both factors within
+        ``exp(+-40)`` at a log-decay of -5, as
+        ``seq.gated_delta_chunked_channel`` has it and for its reason."""
+        lower, strict, eye = masks3
+        dk = heads[0][0].shape[-1]
+        upto, eye_k = triangle(lower), square_eye(dk)
+        at = jax.lax.broadcasted_iota(jnp.int32, (chunk, dk), 0)
+        first = []
+        for q, k, _, g, b_row, _ in heads:
+            # c [L, K]: the log-decays summed from the chunk's start
+            c = nn(upto, g)
+            mids = [c[s * sub + sub // 2 - 1:s * sub + sub // 2]
+                    for s in range(ns)]
+            e_row = jnp.exp(c - jnp.concatenate(
+                [jnp.broadcast_to(m, (sub, dk)) for m in mids], axis=0))
+            col_exp = [jnp.exp(jnp.where(at < (s + 1) * sub, m - c,
+                                         _SSD_MASKED))
+                       for s, m in enumerate(mids)]
+            qr, kr = q * e_row, k * e_row
+            blocks = [nt(jnp.concatenate([by_sub(qr, s), by_sub(kr, s)],
+                                         axis=0), k * e)
+                      for s, e in enumerate(col_exp)]
+            p = jnp.where(lower, jnp.concatenate(
+                [blk[:sub] for blk in blocks], axis=0), 0.0)
+            kkd = jnp.where(strict, jnp.concatenate(
+                [blk[sub:] for blk in blocks], axis=0), 0.0)
+            b_col = column(b_row, eye)
+            last = c[chunk - 1:chunk, :]
+            e_last_row = jnp.exp(last)
+            first.append((b_col, jnp.exp(c), jnp.exp(last - c),
+                          column(e_last_row, eye_k), e_last_row, e_row,
+                          col_exp, p, kkd, b_col * kkd))
+        inverses = unit_lower_inverses([f[-1] for f in first], eye)
+        out = []
+        for (q, k, v, _, _, s), f, t_inv in zip(heads, first, inverses):
+            b_col, e_col = f[0], f[1]
+            qe, ke = q * e_col, k * e_col
+            qs, ks = both(nn, qe, ke, s)
+            z = v - ks
+            out.append(_DeltaChannelChunk(*f, t_inv, qe, ke, qs, ks, z,
+                                          nn(t_inv, b_col * z)))
+        return out
+
+    return types.SimpleNamespace(
+        nn=nn, nt=nt, tn=tn, both=both, tn_sum=tn_sum, masks=masks,
+        column=column, row=row, parts=parts, channel_parts=channel_parts,
+        by_sub=by_sub, square_eye=square_eye, triangle=triangle)
 
 
-def _delta_layout(q, v, chunk):
+def _delta_layout(q, v, chunk, channel):
     """The kernels' view of the op's arrays: heads before positions, the
     scalars ``[B, H / hb, T / chunk, hb, chunk]``; and the block specs by
-    (sequence, head group, chunk) given the chunk order."""
+    (sequence, head group, chunk) given the chunk order. A decay a key
+    ``channel`` goes as the keys do. (Read where they lie, ``[B, T, H *
+    K]`` in blocks of ``hb`` heads' lanes, the per-channel cell's arrays
+    cost MORE: the kernels ran as fast and the transposes left, but XLA
+    then turned ``[B, T, H, K]`` into ``[B, T, H * K]`` in passes of their
+    own, a tile of 8 heads x 128 lanes against one of 8 positions x 128:
+    ``step_device_ms`` 498 against 483, PR 41.)"""
     from jax.experimental import pallas as pl
 
     b, t, h, dk = q.shape
     dv = v.shape[-1]
-    hb, nc = _delta_heads(h, dk, dv, chunk), t // chunk
+    hb, nc = _delta_heads(h, dk, dv, chunk, channel), t // chunk
 
     def wide(x):
         return x.transpose(0, 2, 1, 3)
@@ -1312,19 +1442,22 @@ def _delta_layout(q, v, chunk):
 def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
     """The gated delta rule over sequences of whole chunks, one kernel call
     (see the section's comment). ``q``, ``k [B, T, H, K]``, ``v [B, T, H,
-    V]``, ``g``, ``beta [B, T, H]``, all float32. Returns ``o [B, T, H,
-    V]`` and, ``with_states``, the float32 state at each chunk's start
-    ``[B, T/chunk, H, K, V]`` (else ``None``). Scratch: the carried state,
-    float32 ``[heads a step, K, V]``."""
+    V]``, ``beta [B, T, H]`` and ``g [B, T, H]``, one decay a head, or ``[B,
+    T, H, K]``, one a key channel; all float32. Returns ``o [B, T, H, V]``
+    and, ``with_states``, the float32 state at each chunk's start ``[B,
+    T/chunk, H, K, V]`` (else ``None``). Scratch: the carried state, float32
+    ``[heads a step, K, V]``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
+    channel = g.ndim == 4
     (b, t, h, dk, dv, hb, nc), wide, small, specs = _delta_layout(
-        q, v, chunk)
-    nn, _, tn, _, _, masks, _, _, parts = _delta_chunk_tools(chunk)
+        q, v, chunk, channel)
+    tools = _delta_chunk_tools(chunk)
+    nn, tn = tools.nn, tools.tn
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
         st_ref = rest[-1]
@@ -1333,15 +1466,18 @@ def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
         def _():
             st_ref[...] = jnp.zeros_like(st_ref)
 
-        masks3 = masks()
         # unrolled over the step's heads
-        heads = [(q_ref[i], k_ref[i], v_ref[i], g_ref[i:i + 1, :],
+        heads = [(q_ref[i], k_ref[i], v_ref[i],
+                  g_ref[i] if channel else g_ref[i:i + 1, :],
                   b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
-        for i, (head, c) in enumerate(zip(heads, parts(heads, masks3))):
-            kh, s = head[1], head[5]
+        parts = tools.channel_parts if channel else tools.parts
+        for i, (ops, c) in enumerate(zip(heads, parts(heads, tools.masks()))):
+            kh, s = ops[1], ops[5]
             if with_states:
                 rest[0][i] = s
-            o_ref[i] = c.e_col * c.qs + nn(c.p, c.u)
+            # a channel's exp(c) scales q before its product with the state
+            from_start = c.qs if channel else c.e_col * c.qs
+            o_ref[i] = from_start + nn(c.p, c.u)
             st_ref[i] = c.e_last * s + tn(kh * c.w_col, c.u)
 
     keys, values, scalars, states = specs(lambda ci: ci)
@@ -1351,9 +1487,10 @@ def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
         out_shape.append(jax.ShapeDtypeStruct((b, nc, h, dk, dv), f32))
         out_specs.append(states)
     out = pallas_call(
-        kernel, wide(q), wide(k), wide(v), small(g), small(beta),
+        kernel, wide(q), wide(k), wide(v),
+        wide(g) if channel else small(g), small(beta),
         grid=(b, h // hb, nc),
-        in_specs=[keys, keys, values, scalars, scalars],
+        in_specs=[keys, keys, values, keys if channel else scalars, scalars],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
         compiler_params=_ssd_params(), name="delta_chunk_forward")
@@ -1365,7 +1502,7 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
     each chunk's matrices are formed again in VMEM from the inputs and the
     chunk-start state, and the state's gradient rides a float32 scratch
     ``[heads a step, K, V]``. ``do [B, T, H, V]``; returns ``dq``, ``dk``,
-    ``dv`` in the inputs' shapes and ``dg``, ``dbeta [B, T, H]``, all
+    ``dv``, ``dg`` in the inputs' shapes and ``dbeta [B, T, H]``, all
     float32."""
     import jax
     import jax.numpy as jnp
@@ -1373,10 +1510,14 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
+    channel = g.ndim == 4
     (b, t, h, dk, dv, hb, nc), wide, small, specs = _delta_layout(
-        q, v, chunk)
-    nn, nt, tn, both, tn_sum, masks, column, row, parts = \
-        _delta_chunk_tools(chunk)
+        q, v, chunk, channel)
+    tools = _delta_chunk_tools(chunk)
+    nn, nt, tn, both, tn_sum, column, row, by_sub = (
+        tools.nn, tools.nt, tools.tn, tools.both, tools.tn_sum, tools.column,
+        tools.row, tools.by_sub)
+    sub = DELTA_SUB_CHUNK
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
@@ -1384,18 +1525,18 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
         def _():
             ds_ref[...] = jnp.zeros_like(ds_ref)
 
-        masks3 = lower, strict, eye = masks()
+        masks3 = lower, strict, eye = tools.masks()
         at_last = jax.lax.broadcasted_iota(
             jnp.int32, (chunk, 1), 0) == chunk - 1
 
         def rows(x):
             return jnp.sum(x, axis=1, keepdims=True)
 
-        # unrolled over the step's heads
-        heads = [(q_ref[i], k_ref[i], v_ref[i], g_ref[i:i + 1, :],
-                  b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
-        for i, (head, c) in enumerate(zip(heads, parts(heads, masks3))):
-            qh, kh, s, ds, dy = head[0], head[1], head[5], ds_ref[i], \
+        if channel:
+            upto, eye_k = tools.triangle(lower), tools.square_eye(dk)
+
+        def head_scalar(i, ops, c):
+            qh, kh, s, ds, dy = ops[0], ops[1], ops[5], ds_ref[i], \
                 do_ref[i]
             (b_col, e_col, w_col, e_last, decay, p, kkd, a, t_inv, qs, ks, z,
              u) = c
@@ -1431,25 +1572,86 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
                                          keepdims=True)
             ds_ref[i] = e_last * ds + tn_sum(kh, dks, qh, dyw)
 
+        def head_channel(i, ops, c):
+            """A decay a key channel. ``c [L, K]`` enters only as a factor
+            ``exp(+-c)`` of a row of q or k, and for an operand ``X *
+            exp(c)``, ``dc = X * dX``: the channel's gradient is ``q * dq``
+            and ``k * dk``, the latter signed by the side k stood on (times
+            ``exp(c)``: a block row of ``A`` or ``P``, ``(K * exp(c)) S``;
+            times ``exp(-c)``: the columns, ``K * exp(c_L - c)``), plus at
+            the last position what ``c_L`` carries."""
+            qh, kh, s, ds, dy = ops[0], ops[1], ops[5], ds_ref[i], \
+                do_ref[i]
+            (b_col, e_col, w_col, e_last, e_last_row, e_row, col_exp, p, kkd,
+             _, t_inv, qe, ke, _, _, z, u) = c
+            # o = (Q * E) S + P u, S_end = Diag(e_L) S + (w k)^T u
+            du = tn(p, dy) + nn(kh * w_col, ds)
+            dr = tn(t_inv, du)                  # u = T R: dR = T^T du
+            da, dp = both(nt, dr, dy, u)        # dA = -dR u^T, below
+            da = -da
+            dz = b_col * dr                     # R = b (v - (K * E) S)
+            g_kk = jnp.where(strict, da * b_col, 0.0)
+            g_qk = jnp.where(lower, dp, 0.0)
+            # the scores a block row: d [Q_s; K_s] by rows, d K by columns
+            qr, kr = qh * e_row, kh * e_row
+            at_q, at_k, at_col = [], [], None
+            for j, e in enumerate(col_exp):
+                top = jnp.concatenate([by_sub(g_qk, j), by_sub(g_kk, j)],
+                                      axis=0)
+                at_rows = nn(top, kh * e)
+                at_q.append(at_rows[:sub])
+                at_k.append(at_rows[sub:])
+                mine = e * tn(top, jnp.concatenate(
+                    [by_sub(qr, j), by_sub(kr, j)], axis=0))
+                at_col = mine if at_col is None else at_col + mine
+            of_q, of_k = both(nt, dy, -dz, s)   # d (Q * E), d (K * E)
+            gave = w_col * nt(u, ds)            # d (w k), times w
+            dq = e_row * jnp.concatenate(at_q, axis=0) + e_col * of_q
+            dk_row = e_row * jnp.concatenate(at_k, axis=0) + e_col * of_k
+            dk_col = at_col + gave
+            dq_ref[i] = dq
+            dk_ref[i] = dk_row + dk_col
+            dv_ref[i] = dz
+            db_ref[i:i + 1, :] = row(rows(dr * z) + rows(da * kkd), eye)
+            # c_L: in every w_t = exp(c_L - c_t), and in Diag(exp(c_L)) S
+            ends = jnp.sum(kh * gave, axis=0, keepdims=True) \
+                + e_last_row * row(rows(ds * s), eye_k)
+            dc = qh * dq + kh * (dk_row - dk_col) \
+                + jnp.where(at_last, ends, 0.0)
+            # g_j is in c_t for every t >= j of the chunk
+            dg_ref[i] = tn(upto, dc)
+            ds_ref[i] = e_last * ds + tn_sum(ke, -dz, qe, dy)
+
+        # unrolled over the step's heads
+        heads = [(q_ref[i], k_ref[i], v_ref[i],
+                  g_ref[i] if channel else g_ref[i:i + 1, :],
+                  b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
+        parts, one = (tools.channel_parts, head_channel) if channel \
+            else (tools.parts, head_scalar)
+        for i, (ops, c) in enumerate(zip(heads, parts(heads, masks3))):
+            one(i, ops, c)
+
     keys, values, scalars, states = specs(lambda ci: nc - 1 - ci)
     small_out = jax.ShapeDtypeStruct((b, h // hb, nc, hb, chunk), f32)
+    keys_out = jax.ShapeDtypeStruct((b, h, t, dk), f32)
     dq, dk_, dv_, dg, db = pallas_call(
-        kernel, wide(q), wide(k), wide(v), small(g), small(beta), starts,
-        wide(do),
+        kernel, wide(q), wide(k), wide(v),
+        wide(g) if channel else small(g), small(beta), starts, wide(do),
         grid=(b, h // hb, nc),
-        in_specs=[keys, keys, values, scalars, scalars, states, values],
-        out_specs=[keys, keys, values, scalars, scalars],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, dk), f32),
-                   jax.ShapeDtypeStruct((b, h, t, dk), f32),
+        in_specs=[keys, keys, values, keys if channel else scalars, scalars,
+                  states, values],
+        out_specs=[keys, keys, values, keys if channel else scalars, scalars],
+        out_shape=[keys_out, keys_out,
                    jax.ShapeDtypeStruct((b, h, t, dv), f32),
-                   small_out, small_out],
+                   keys_out if channel else small_out, small_out],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
         compiler_params=_ssd_params(), name="delta_chunk_backward")
 
     def by_position(x):
         return x.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
 
-    return wide(dq), wide(dk_), wide(dv_), by_position(dg), by_position(db)
+    return (wide(dq), wide(dk_), wide(dv_),
+            wide(dg) if channel else by_position(dg), by_position(db))
 
 
 @functools.lru_cache(None)

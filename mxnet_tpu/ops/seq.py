@@ -18,6 +18,7 @@ from typing import List, Tuple
 import numpy as np
 
 from ..base import MXNetError
+from .pallas_kernels import DELTA_SUB_CHUNK
 from .registry import Operator, Param, REQUIRED, register_op
 
 
@@ -800,7 +801,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     return jnp.moveaxis(o, 2, 1).reshape(t, h, dv), starts
 
 
-SUB_CHUNK = 16      # positions a sub-chunk of the per-channel body
+# positions a sub-chunk of the per-channel body, here and in the kernels
+SUB_CHUNK = DELTA_SUB_CHUNK
 
 
 def gated_delta_chunked_channel(q, k, v, g, beta, chunk, sub=SUB_CHUNK):
@@ -965,23 +967,25 @@ def _channel_scan(seq_ops, head_ops, chunk, prepare, out_dtype):
 def gated_delta_scan(q, k, v, g, beta, chunk, kernel):
     """The gated delta rule over sequences of whole chunks as ONE
     differentiable function, beside :func:`ssd_scan`: ``q``, ``k [B, T, H,
-    K]``, ``v [B, T, H, V]``, ``g``, ``beta [B, T, H]``, float32; returns
-    ``o [B, T, H, V]``. With ``g [B, T, H, K]``, a decay a key channel, the
-    body is :func:`gated_delta_chunked_channel` under autodiff, a group of
-    heads at a time (:func:`_channel_scan`; no kernel takes it yet).
-    ``kernel`` picks the scalar body: the Pallas chunk kernels
+    K]``, ``v [B, T, H, V]``, ``beta [B, T, H]`` and ``g [B, T, H]``, one
+    decay a head, or ``[B, T, H, K]``, one a key channel; float32; returns
+    ``o [B, T, H, V]``. ``kernel`` picks the body: the Pallas chunk kernels
     (``pallas_kernels.delta_chunk_forward`` / ``_backward``: the solve, the
     chunk's matrices and the carried state stay in VMEM) with the backward
     pass written out, its residuals the five inputs and the float32
-    chunk-start states ``[B, T/chunk, H, K, V]``; or
-    :func:`gated_delta_chunked` a sequence at a time under autodiff."""
+    chunk-start states ``[B, T/chunk, H, K, V]``; or the same chunked
+    algorithm in ``jax.numpy`` under autodiff, :func:`gated_delta_chunked`
+    a sequence at a time or, a decay a channel,
+    :func:`gated_delta_chunked_channel` a group of heads at a time
+    (:func:`_channel_scan`)."""
     jax = _jax()
     from . import pallas_kernels
 
-    if g.ndim == 4:
+    if not kernel and g.ndim == 4:
         return _channel_scan((q, k, v, g, beta), (), chunk,
                              lambda *ops: ops, q.dtype)
     if not kernel:
+        # one decay a head
         return jax.lax.map(
             lambda x: gated_delta_chunked(*x, chunk=chunk)[0],
             (q, k, v, g, beta))
@@ -1046,8 +1050,13 @@ class GatedDeltaRule(Operator):
     values (``lower.delta_rule_kernel.pallas_chunked``; 64 x 96 x 192 at 15
     heads is such a shape), else the same chunked algorithm in
     ``jax.numpy`` under autodiff (``lower.delta_rule_kernel.xla_chunked``).
-    A decay a channel always takes the ``jax.numpy`` body, chunks cut into
-    sub-chunks of 16 (no kernel forms its chunk yet: ROADMAP Speed).
+    A decay a channel takes the same kernels where besides the keys are
+    whole lane tiles (128 a head) and the chunk whole 16-position
+    sub-chunks, at most four (``pallas_kernels.delta_channel_applicable``;
+    64 x 128 x 128 at 32 heads is such a shape), the chunk's ``A`` and
+    ``P`` formed a block row a sub-chunk as the ``jax.numpy`` body forms
+    them and the decay's gradient ``[rows, H*K]`` wide; else that body
+    (:func:`gated_delta_chunked_channel`), a group of heads at a time.
     In both, every operand and product is float32 (products at ``HIGHEST``:
     six bfloat16 passes, never one), as are the decays, the solve, the
     carried state, its gradient and every accumulator. The kernels' backward
@@ -1131,13 +1140,20 @@ class GatedDeltaRule(Operator):
         channel = a.shape[1] != h
         _tel.inc("lower.delta_rule_gate.%s"
                  % ("channel" if channel else "head"))
+        dims = (h, self.key_dim, self.value_dim)
         if channel:
             if self.chunk % SUB_CHUNK:
                 raise MXNetError("GatedDeltaRule: a decay a channel wants "
                                  "chunks of whole %d-position sub-chunks, "
                                  "not %d" % (SUB_CHUNK, self.chunk))
-            _tel.inc("lower.delta_rule_kernel.xla_chunked")
-
+            kernel = pallas_kernels.delta_channel_applicable(
+                dims, self.chunk, q.dtype)
+        else:
+            kernel = pallas_kernels.delta_chunk_applicable(
+                dims, self.chunk, q.dtype)
+        _tel.inc("lower.delta_rule_kernel.pallas_chunked" if kernel
+                 else "lower.delta_rule_kernel.xla_chunked")
+        if channel and not kernel:
             def prepare(q, k, v, a, b, a_log, dt_bias):
                 """One sequence's group of heads, as it comes (``[T, hg,
                 .]`` in the compute dtype; ``a_log [hg]``, ``dt_bias [hg,
@@ -1156,7 +1172,11 @@ class GatedDeltaRule(Operator):
             return [ctx.keep(o.reshape(n * t, -1), "output")], []
         q = unit(heads(q)) * (self.key_dim ** -0.5)
         k, v = unit(heads(k)), heads(v)
-        g = gate(a, a_log, dt_bias).reshape(n, t, h)
+        if channel:
+            g = gate(heads(a), a_log[:, None],
+                     dt_bias.reshape(h, self.key_dim))
+        else:
+            g = gate(a, a_log, dt_bias).reshape(n, t, h)
         beta = jax.nn.sigmoid(b).reshape(n, t, h)
         if self.neg_eigval:
             beta = 2.0 * beta
@@ -1166,10 +1186,6 @@ class GatedDeltaRule(Operator):
             q, k, v, g, beta = (
                 jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
                 for x in (q, k, v, g, beta))
-        kernel = pallas_kernels.delta_chunk_applicable(
-            (h, self.key_dim, self.value_dim), self.chunk, q.dtype)
-        _tel.inc("lower.delta_rule_kernel.pallas_chunked" if kernel
-                 else "lower.delta_rule_kernel.xla_chunked")
         o = gated_delta_scan(q, k, v, g, beta, self.chunk, kernel)
         o = o[:, :t].reshape(n * t, -1).astype(inputs[0].dtype)
         return [ctx.keep(o, "output")], []

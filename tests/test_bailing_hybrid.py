@@ -131,6 +131,68 @@ def test_heads_in_groups_compute_what_all_heads_do(monkeypatch):
         close(a, b, 1e-5)
 
 
+def channel_gates(decay, shape, seed):
+    """Log-decays ``[B, T, H, K]``: every channel at the bound -5, every
+    channel near 0, or both kinds and everything between inside one head
+    (a channel keeps its kind along the sequence, its size moves)."""
+    rng = np.random.default_rng(seed)
+    if decay == "at_the_bound":
+        return jnp.full(shape, -5.0, jnp.float32)
+    if decay == "near_zero":
+        return jnp.asarray(-1e-3 * rng.uniform(size=shape), jnp.float32)
+    kind = rng.integers(0, 3, size=(1, 1) + shape[2:])
+    g = np.where(kind == 0, -5.0 + 1e-3 * rng.uniform(size=shape),
+                 np.where(kind == 1, -1e-3 * rng.uniform(size=shape),
+                          -5.0 * rng.uniform(size=shape)))
+    return jnp.asarray(g, jnp.float32)
+
+
+@pytest.mark.parametrize("decay,h,chunks,dv", [
+    ("at_the_bound", 2, 2, 128), ("near_zero", 3, 3, 128),
+    ("mixed_in_a_head", 4, 4, 128), ("mixed_in_a_head", 2, 2, 256),
+    ("mixed_in_a_head", 2, 3, 64)],
+    ids=["at_the_bound", "near_zero", "mixed_in_a_head", "values_256",
+         "values_64"])
+def test_channel_kernels_against_the_xla_body(decay, h, chunks, dv):
+    """The Pallas chunk kernels with a decay a key channel (interpreted
+    here) at 128 keys a head, chunks of 64, against
+    ``seq.gated_delta_chunked_channel`` and its autodiff: the output, the
+    chunk-start states, and dq, dk, dv, dg A CHANNEL and dbeta. (At the
+    bound dg is what the cumulative sums' cancellation leaves: 1e-3 where
+    dq is 13, so it is held at 1e-4 of its own largest.)"""
+    from mxnet_tpu.ops import pallas_kernels
+
+    t, dk = 64 * chunks, 128
+    q, k, v, beta = delta_inputs(7, batch=2, t=t, h=h, dk=dk, dv=dv)
+    beta = 2.0 * beta
+    g = channel_gates(decay, q.shape, 8)
+    assert pallas_kernels.delta_channel_applicable((h, dk, dv), 64, q.dtype)
+    o, starts = pallas_kernels.delta_chunk_forward(q, k, v, g, beta, chunk=64,
+                                                   with_states=True)
+    assert starts.dtype == jnp.float32
+    assert starts.shape == (2, chunks, h, dk, dv)
+    assert not np.asarray(starts[:, 0]).any()
+    for i in range(2):
+        want_o, want = seq.gated_delta_chunked_channel(
+            q[i], k[i], v[i], g[i], beta[i], 64)
+        close(o[i], want_o, 2e-5)
+        close(starts[i], want, 2e-5)
+    assert pallas_kernels.delta_chunk_forward(
+        q, k, v, g, beta, chunk=64, with_states=False)[1] is None
+    head = jnp.asarray(np.random.default_rng(9).normal(size=o.shape),
+                       jnp.float32)
+
+    def grads(kernel):
+        return jax.grad(lambda *a: jnp.sum(seq.gated_delta_scan(
+            *a, 64, kernel) * head), argnums=range(5))(q, k, v, g, beta)
+
+    for name, got, want in zip(("dq", "dk", "dv", "dg", "dbeta"),
+                               grads(True), grads(False)):
+        assert got.shape == want.shape and bool(jnp.all(jnp.isfinite(got)))
+        close(got, want, 1e-4 if (name, decay) == ("dg", "at_the_bound")
+              else 3e-5)
+
+
 def _delta_op(inputs, head, **op):
     net = sym.GatedDeltaRule(**op, **{k: sym.Variable(k) for k in inputs})
     telemetry.reset()
@@ -178,12 +240,16 @@ def test_equal_channels_and_the_softplus_form_are_the_per_head_op(dk, dv):
     close(got_g["dt_bias"].reshape(h, dk).sum(-1), want_g["dt_bias"], 5e-5)
 
 
-@pytest.mark.parametrize("shape", ["head", "channel"])
+@pytest.mark.parametrize("shape", ["head", "channel", "channel_kernel"])
 def test_bounded_gate_against_plain(shape):
     """``gate_floor`` -5: ``g = -5 sigmoid(exp(A_log) (a + dt_bias))``, with
-    either shape of ``a``, against the recurrence written out."""
-    t, h, dk, dv = 64, 2, 8, 8
-    wide = h * dk if shape == "channel" else h
+    either shape of ``a``, against the recurrence written out; a decay a
+    channel at toy widths on the XLA body, at 128 keys a head and chunks of
+    64 (a sequence of two and a half) on the Pallas chunk kernels, each
+    counted once."""
+    t, h, dk, dv, chunk = (160, 2, 128, 128, 64) if shape == "channel_kernel" \
+        else (64, 2, 8, 8, 32)
+    wide = h if shape == "head" else h * dk
     inputs = rng_inputs(7, query=(t, h * dk), key=(t, h * dk),
                         value=(t, h * dv), a=(t, wide), b=(t, h),
                         A_log=(h,), dt_bias=(wide,))
@@ -202,10 +268,21 @@ def test_bounded_gate_against_plain(shape):
                        jax.nn.sigmoid(b).reshape(1, t, h))
         return o.reshape(t, h * dv)
 
-    net = sym.GatedDeltaRule(num_heads=h, key_dim=dk, value_dim=dv, chunk=32,
-                             seq_len=t, gate_floor=-5.0,
+    net = sym.GatedDeltaRule(num_heads=h, key_dim=dk, value_dim=dv,
+                             chunk=chunk, seq_len=t, gate_floor=-5.0,
                              **{k: sym.Variable(k) for k in inputs})
-    against(plain, net, inputs, tol=5e-5)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        against(plain, net, inputs, tol=5e-5)
+        counted = [telemetry.peek("lower.delta_rule_kernel." + k) or 0
+                   for k in ("pallas_chunked", "xla_chunked")]
+    finally:
+        telemetry.disable()
+    # 8 keys a head are whole sublanes: the scalar kernels' shape, and no
+    # whole lane tile for a decay a channel
+    assert counted == {"head": [1, 0], "channel": [0, 1],
+                       "channel_kernel": [1, 0]}[shape]
 
 
 def test_bad_gates_are_refused():

@@ -27,27 +27,33 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads,dk,dv,chunk", [
-    (15, 96, 192, 64),      # the benchmark's cell: odd heads, part lanes
-    (4, 128, 256, 128),     # the widest the rule admits
-    (5, 8, 8, 128),         # the narrowest widths at the longest chunk
-    (16, 64, 64, 64)],
-    ids=["cell", "widest", "narrowest", "half-lanes"])
+@pytest.mark.parametrize("heads,dk,dv,chunk,channel", [
+    (15, 96, 192, 64, False),   # the benchmark's cell: odd heads, part lanes
+    (4, 128, 256, 128, False),  # the widest the rule admits
+    (5, 8, 8, 128, False),      # the narrowest widths at the longest chunk
+    (16, 64, 64, 64, False),
+    (32, 128, 128, 64, True),   # the Ling cell: a decay a key channel
+    (4, 128, 256, 64, True)],   # ... at the widest values
+    ids=["cell", "widest", "narrowest", "half-lanes", "channel-cell",
+         "channel-widest"])
 def test_delta_chunk_kernels_compile_for_the_chip(one_chip, heads, dk, dv,
-                                                  chunk):
-    """Every shape ``delta_chunk_applicable`` admits has to fit the 16 MB
-    of scoped VMEM with the heads a step ``_delta_heads`` gives it."""
+                                                  chunk, channel):
+    """Every shape ``delta_chunk_applicable`` (a decay a key ``channel``:
+    ``delta_channel_applicable``) admits has to fit the 16 MB of scoped
+    VMEM with the heads a step ``_delta_heads`` gives it."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    assert pk.delta_chunk_applicable((heads, dk, dv), chunk,
-                                     jnp.dtype("float32"))
+    rule = pk.delta_channel_applicable if channel \
+        else pk.delta_chunk_applicable
+    assert rule((heads, dk, dv), chunk, jnp.dtype("float32"))
     b, t = 1, 4 * chunk
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
+    gate = (b, t, heads, dk) if channel else (b, t, heads)
     args = (shape(b, t, heads, dk), shape(b, t, heads, dk),
-            shape(b, t, heads, dv), shape(b, t, heads), shape(b, t, heads))
+            shape(b, t, heads, dv), shape(*gate), shape(b, t, heads))
     forward = jax.jit(lambda *a: pk._delta_chunk_forward(
         *a, chunk=chunk, with_states=True)).lower(*args).compile()
     backward = jax.jit(lambda *a: pk._delta_chunk_backward(

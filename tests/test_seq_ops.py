@@ -332,16 +332,63 @@ def delta_scan_args(seed, b, t, h, dk, dv):
             2.0 * jax.nn.sigmoid(normal(b, t, h)))
 
 
-def test_gated_delta_scan_traces_two_kernels_and_no_scan():
+@pytest.mark.parametrize("dims,chunk,dtype,takes", [
+    ((32, 128, 128), 64, "float32", True),      # the cell's own shapes
+    ((4, 128, 256), 32, "float32", True),
+    ((2, 128, 8), 16, "float32", True),         # values as the scalar rule
+    ((32, 128, 128), 64, "bfloat16", False),    # another result
+    ((4, 8, 8), 32, "float32", False),          # the toys: no whole lane tile
+    ((15, 96, 192), 64, "float32", False),      # the scalar kernels' cell
+    ((32, 128, 128), 24, "float32", False),     # no whole sub-chunks
+    ((32, 128, 128), 40, "float32", False),
+    ((32, 128, 128), 128, "float32", False)],   # past four sub-chunks
+    ids=["cell", "values256", "values8", "bfloat16", "keys8", "keys96",
+         "chunk24", "chunk40", "chunk128"])
+def test_delta_channel_applicable(dims, chunk, dtype, takes):
+    """The rule beside ``delta_chunk_applicable`` for a decay a key
+    channel: keys of whole lane tiles, chunks of whole 16-position
+    sub-chunks; everything it refuses keeps the XLA body."""
+    from mxnet_tpu.ops import pallas_kernels
+
+    assert pallas_kernels.delta_channel_applicable(
+        dims, chunk, jnp.dtype(dtype)) is takes
+    if takes:
+        assert pallas_kernels.delta_chunk_applicable(
+            dims, chunk, jnp.dtype(dtype))
+
+
+def test_delta_heads_a_step_count_the_channel_decays_tiles():
+    """A head with a decay a key channel holds the gate's block and ``exp(c
+    - r)`` beside what the scalar rule counts: four heads a grid step at
+    the Ling cell's shape, two at values of 256; the Olmo cell's five
+    stand. The sub-chunk is one number in the kernels and in the XLA
+    body."""
+    from mxnet_tpu.ops import pallas_kernels as pk, seq
+
+    assert pk._delta_heads(32, 128, 128, 64) == 4
+    assert pk._delta_heads(32, 128, 128, 64, channel=True) == 4
+    assert pk._delta_heads(4, 128, 256, 64, channel=True) == 2
+    assert pk._delta_heads(15, 96, 192, 64) == 5
+    assert seq.SUB_CHUNK == pk.DELTA_SUB_CHUNK == 16
+
+
+@pytest.mark.parametrize("decay", ["head", "channel"])
+def test_gated_delta_scan_traces_two_kernels_and_no_scan(decay):
     """``jax.grad`` through the kernel body holds exactly the forward and
     the backward ``pallas_call`` (each twice: the interpreter's and
     Mosaic's branch), every VMEM scratch float32, and neither a ``scan``
-    nor a ``triangular_solve``; the XLA body holds both and no kernel."""
+    nor a ``triangular_solve``; the XLA body holds both and no kernel. With
+    one decay a head and with one a key channel (``g [B, T, H, K]`` at 128
+    keys a head)."""
     from test_nemotron_h import _sub_jaxprs
 
     from mxnet_tpu.ops import seq
 
-    args = delta_scan_args(0, 1, 128, 2, 8, 16)
+    if decay == "head":
+        args = delta_scan_args(0, 1, 128, 2, 8, 16)
+    else:
+        q, k, v, g, beta = delta_scan_args(0, 1, 128, 2, 128, 128)
+        args = (q, k, v, g[..., None] * jnp.linspace(0.1, 1.0, 128), beta)
     for kernel in (True, False):
         jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
             seq.gated_delta_scan(*a, 64, kernel)), argnums=range(5)))(*args)
@@ -358,7 +405,8 @@ def test_gated_delta_scan_traces_two_kernels_and_no_scan():
                 + ["delta_chunk_forward"] * 2, names
             assert len(scratch) == 4 and all(
                 d == jnp.float32 for d in scratch), scratch
-            assert not prims & {"scan", "triangular_solve", "while"}, prims
+            assert not prims & {"scan", "triangular_solve", "while",
+                                "checkpoint", "remat"}, prims
         else:
             assert not names and {"scan", "triangular_solve"} <= prims
 
