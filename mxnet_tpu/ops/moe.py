@@ -12,15 +12,26 @@ and a family without one (``models/lfm2_moe.py``) takes the op's result as
 the layer's whole result.
 
 **No capacity, no dropped row.** The (row, expert) pairs that land here
-are sorted by expert and laid out in blocks of ``block`` rows, each
-block one expert's (:func:`plan`); a group's last block is padded, with
-weight 0. The layout's length is static, ``rows x min(top_k, num_held)``
-plus one block an expert, but the products run in a loop over the blocks
-really filled (a dynamic trip count), each block's results scattered back
-weighted onto its rows, so work follows the rows really routed:
-:func:`grouped_experts`, forward and backward loops written out because a
-loop of unknown length has no reverse-mode autodiff. An imbalanced router
-makes one expert's group long, never short of a row.
+are laid out by expert in blocks of ``block`` rows, each block one
+expert's (:func:`plan`); a group's last block is padded, with weight 0.
+``top_k`` picks distinct experts, so a held expert draws a row at most
+once: which rows it draws is a dense compare over ``[held, k, rows]``, one
+``lax.sort`` a held expert's column brings them to the front in row order
+with their weights riding, and the columns are written end to end, each at
+its expert's block-aligned start. The weights' gradient comes back from
+the slots to the pairs by one more sort (:func:`pairs_from_slots`), the
+loads and the chosen scores by a compare against every expert: nothing in
+either pass is a scalar scatter, gather or search whose length is the
+pairs or the slots (on the chip those cost 7-30 ns an element, a sort of
+the same length under 1 ns a key; PERF.md section 5). The layout's
+length is static, ``rows x min(top_k, num_held)`` plus one block an
+expert, but the products run in a loop over the blocks really filled (a
+dynamic trip count), each block's results scattered back weighted onto
+its rows, so work follows the rows really routed: :func:`grouped_experts`,
+forward and backward loops written out because a loop of unknown length
+has no reverse-mode autodiff. An imbalanced router makes one expert's
+group long, never short of a row (an expert may draw every row: its column
+is a row long).
 
 Router scores (sigmoid, no softmax), selection and combine weights are
 float32 at ``highest`` matmul precision whatever the compute dtype: the
@@ -48,7 +59,9 @@ default; :func:`grouped_experts`) and the gated ``W_down (silu(W_gate x) *
 W_up x)`` with a third stacked weight (:func:`grouped_experts_gated`), each
 with both passes written out. Routing, layout, balancing and the counters
 are one; which body a traced node took is counted
-(``lower.experts_body.relu2`` / ``lower.experts_body.swiglu``).
+(``lower.experts_body.relu2`` / ``lower.experts_body.swiglu``), beside how
+its products lower (``lower.experts_kernel.*``) and the layout's form
+(``lower.experts_plan.column_sort``, the one there is).
 
 **The selection bias is a state, not a weight.** ``select_bias`` (float32,
 ``num_experts``) is added to the scores for the choice only and no gradient
@@ -124,7 +137,11 @@ def route(x, router, select_bias, top_k, scale, keep=lambda v: v,
                            -jnp.inf).reshape(choice.shape)
     _, eid = jax.lax.top_k(choice, top_k)
     eid = keep(eid.astype(jnp.int32))
-    chosen = jnp.take_along_axis(scores, eid, axis=1)
+    # the chosen scores by a compare against every expert, not a gather a
+    # pair (nor, backward, a scatter-add): one value and zeros, the sum exact
+    expert = jnp.arange(scores.shape[1], dtype=jnp.int32)
+    chosen = jnp.sum(jnp.where(eid[:, :, None] == expert, scores[:, None, :],
+                               0.0), axis=2)
     wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + norm_eps) \
         * scale
     return eid, wts
@@ -141,47 +158,109 @@ def balance_step(bias, load, rate):
         + rate * jnp.sign(jnp.mean(load) - load)
 
 
+def expert_load(eid, num_experts):
+    """Rows each of ``num_experts`` experts drew, ``[E]`` int32: a compare
+    of every pair against every expert and a sum, no scatter-add."""
+    import jax.numpy as jnp
+
+    expert = jnp.arange(num_experts, dtype=jnp.int32)
+    return jnp.sum((eid.reshape(1, -1) == expert[:, None])
+                   .astype(jnp.int32), axis=1)
+
+
 def plan(eid, wts, first_held, num_held, block):
     """Lay the pairs that land on the held experts out in blocks.
 
-    Returns ``(rows [L], weights [L], slot [S, k], block_expert [L /
-    block], nblocks, dropped)``: slot i of the layout computes row
-    ``rows[i]`` through the expert of its block, and that row adds the
-    result times ``weights[i]``; padding slots read row 0 with weight 0.
-    ``slot[r, j]`` is where pair (r, j) sits in the layout, ``L`` for a
-    pair that does not land here. ``nblocks`` (traced) is how many blocks
-    are filled, ``dropped`` the pairs that landed here and got no slot."""
+    Returns ``(rows [L], weights [L], slot [S, k], order [held, S],
+    block_expert [L / block], nblocks, dropped)``: slot i of the layout
+    computes row ``rows[i]`` through the expert of its block, and that row
+    adds the result times ``weights[i]`` (``wts`` by slot, no gradient
+    through them); padding slots read row 0 with weight 0. ``slot[r, j]``
+    is where pair (r, j) sits in the layout, ``L`` for a pair that does not
+    land here. ``order[e]`` is the rows sorted for
+    held expert ``e``: those it drew first, in row order (its slots in the
+    layout's order), then the others; :func:`pairs_from_slots` carries the
+    weights' gradient back along it. ``nblocks`` (traced) is how many blocks
+    are filled, ``dropped`` the pairs that landed here and got no slot.
+
+    Dense compares over ``[held, k, S]`` and one sort a held expert's
+    column: no scatter, gather or search whose length is the pairs or the
+    slots (a scalar one costs the chip 7-30 ns an element, PERF.md
+    section 5)."""
+    import jax
     import jax.numpy as jnp
 
     s, k = eid.shape
-    pairs = s * k
     length = layout_length(s, k, num_held, block)
-    local = eid.reshape(-1) - first_held
-    here = (local >= 0) & (local < num_held)
-    key = jnp.where(here, local, num_held)
-    order = jnp.argsort(key, stable=True)
-    counts = jnp.zeros((num_held + 1,), jnp.int32).at[key].add(1)[:num_held]
+    # [held, k, S], the rows innermost: pair (r, j) chose held expert e;
+    # at most one j a row and expert, so the masked sums hold one value
+    held = first_held + jnp.arange(num_held, dtype=jnp.int32)
+    match = eid.T[None] == held[:, None, None]
+    hit = jnp.any(match, axis=1)
+    w = jnp.sum(jnp.where(match, jax.lax.stop_gradient(wts).T[None], 0.0),
+                axis=1)
+    hits = hit.astype(jnp.int32)
+    counts = jnp.sum(hits, axis=1)
     padded = (counts + block - 1) // block * block
     pend = jnp.cumsum(padded)
     pstart = pend - padded
-    sstart = jnp.cumsum(counts) - counts
-    pos = jnp.arange(length, dtype=jnp.int32)
-    e_of = jnp.searchsorted(pend, pos, side="right").astype(jnp.int32)
-    e_c = jnp.minimum(e_of, num_held - 1)
-    j = pos - pstart[e_c]
-    valid = (e_of < num_held) & (j < counts[e_c])
-    pair = order[jnp.clip(sstart[e_c] + j, 0, pairs - 1)]
-    rows = jnp.where(valid, pair // k, 0).astype(jnp.int32)
-    weights = jnp.where(valid, wts.reshape(-1)[pair], 0.0)
-    # a pair's slot: its place among its expert's pairs, from the sort
-    rank = jnp.zeros((pairs,), jnp.int32).at[order].set(
-        jnp.arange(pairs, dtype=jnp.int32))
-    key_c = jnp.minimum(key, num_held - 1)
-    slot = jnp.where(here, pstart[key_c] + rank - sstart[key_c], length)
-    dropped = jnp.sum(here.astype(jnp.int32)) \
-        - jnp.sum(valid.astype(jnp.int32))
-    return (rows, weights, slot.reshape(s, k).astype(jnp.int32),
-            e_c[::block], pend[-1] // block, dropped)
+    row = jnp.arange(s, dtype=jnp.int32)
+    # an expert's rows to the front of its column, their weights beside
+    key, w = jax.lax.sort((jnp.where(hit, row, row + s), w), dimension=1,
+                          num_keys=1)
+    order = jnp.where(key < s, key, key - s)
+    drew = row < counts[:, None]
+    # columns into the layout at their experts' starts, in order: what a
+    # column holds past its expert's rows reads as padding, and the next
+    # expert's column overwrites it (row + 1, so that 0 is an empty slot)
+    rows = jnp.zeros((length + s,), jnp.int32)
+    weights = jnp.zeros((length + s,), w.dtype)
+    for e in range(num_held):
+        rows = jax.lax.dynamic_update_slice(
+            rows, jnp.where(drew[e], order[e] + 1, 0), (pstart[e],))
+        weights = jax.lax.dynamic_update_slice(
+            weights, jnp.where(drew[e], w[e], 0.0), (pstart[e],))
+    rows, weights = rows[:length], weights[:length]
+    # a pair's slot: its row's place among its expert's rows
+    at = pstart[:, None] + jnp.cumsum(hits, axis=1) - 1
+    slot = jnp.where(jnp.any(match, axis=0),
+                     jnp.sum(jnp.where(match, at[:, None], 0), axis=0), length)
+    first = jnp.arange(length // block, dtype=jnp.int32) * block
+    block_expert = jnp.minimum(
+        jnp.sum((pend[None] <= first[:, None]).astype(jnp.int32), axis=1),
+        num_held - 1)
+    dropped = jnp.sum(counts) - jnp.sum((rows > 0).astype(jnp.int32))
+    return (jnp.maximum(rows - 1, 0), weights, slot.T.astype(jnp.int32),
+            order, block_expert, pend[-1] // block, dropped)
+
+
+def pairs_from_slots(dwt, slot, order, block_expert, nblocks):
+    """``dwt [L]`` by slot of :func:`plan`'s layout -> ``[S, k]`` by pair,
+    0 for a pair that does not land here: each held expert's run of slots
+    cut out where it starts, carried from the expert's order back to the
+    rows' by one sort, and spread onto the pairs by a compare (what
+    ``jnp.take(dwt, slot)`` gives, without a gather the pairs long)."""
+    import jax
+    import jax.numpy as jnp
+
+    held, s = order.shape
+    nbmax = block_expert.shape[0]
+    block = dwt.shape[0] // nbmax
+    expert = jnp.arange(held, dtype=jnp.int32)
+    filled = jnp.arange(nbmax, dtype=jnp.int32) < nblocks
+    blocks = jnp.sum(((block_expert[None] == expert[:, None])
+                      & filled[None]).astype(jnp.int32), axis=1)
+    pend = jnp.cumsum(blocks) * block
+    pstart = pend - blocks * block
+    run = jnp.concatenate([dwt, jnp.zeros((s,), dwt.dtype)])
+    by_place = jnp.stack([jax.lax.dynamic_slice(run, (pstart[e],), (s,))
+                          for e in range(held)])
+    _, by_row = jax.lax.sort((order, by_place), dimension=1, num_keys=1)
+    # a pair's expert by where its slot lies; ``held`` for none
+    of = jnp.sum((slot.T[None] >= pend[:, None, None]).astype(jnp.int32),
+                 axis=0)
+    return jnp.sum(jnp.where(of[None] == expert[:, None, None],
+                             by_row[:, None], 0.0), axis=0).T
 
 
 def _take_block(b, block, rows, weights, block_expert):
@@ -201,15 +280,16 @@ def _expert_block(xb, w_up_e, cd):
     return r, (r * r).astype(cd)
 
 
-def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
-                    nblocks):
+def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, order,
+                    block_expert, nblocks):
     """``y[r] = sum_j wts[r, j] * W_down[e] relu(W_up[e] x[r])^2`` over the
     pairs (r, j) that land here: ``x [S, h]``, ``w_up [held, h, f]``,
     ``w_down [held, f, h]``, ``wts [S, k]``, the layout of :func:`plan`
     (``weights`` is ``wts`` by slot). One block a loop step, ``nblocks``
     steps, each adding its rows' weighted results onto the float32 result
     (a padding slot adds zero to row 0). Differentiable in ``x``, the
-    expert weights and ``wts`` (whose gradient comes back by ``slot``)."""
+    expert weights and ``wts`` (whose gradient comes back by ``slot`` and
+    ``order``: :func:`pairs_from_slots`)."""
     import jax
     import jax.numpy as jnp
 
@@ -217,8 +297,8 @@ def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
     cd = x.dtype
     block = rows.shape[0] // block_expert.shape[0]
 
-    def forward(x, w_up, w_down, wts, rows, weights, slot, block_expert,
-                nblocks):
+    def forward(x, w_up, w_down, wts, rows, weights, slot, order,
+                block_expert, nblocks):
         def body(b, out):
             e, r, w = _take_block(b, block, rows, weights, block_expert)
             _, a = _expert_block(x[r], w_up[e], cd)
@@ -236,7 +316,8 @@ def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
         return forward(*args), args
 
     def f_bwd(res, dy):
-        x, w_up, w_down, wts, rows, weights, slot, block_expert, nblocks = res
+        (x, w_up, w_down, wts, rows, weights, slot, order, block_expert,
+         nblocks) = res
 
         def body(b, carry):
             dx, dwu, dwd, dwt = carry
@@ -259,12 +340,12 @@ def grouped_experts(x, w_up, w_down, wts, rows, weights, slot, block_expert,
             (jnp.zeros(x.shape, f32),
              jnp.zeros(w_up.shape, f32), jnp.zeros(w_down.shape, f32),
              jnp.zeros(weights.shape, f32)))
-        dwts = jnp.take(dwt, slot, mode="fill", fill_value=0)
+        dwts = pairs_from_slots(dwt, slot, order, block_expert, nblocks)
         return (dx.astype(cd), dwu.astype(w_up.dtype),
-                dwd.astype(w_down.dtype), dwts, None, None, None, None, None)
+                dwd.astype(w_down.dtype), dwts) + (None,) * 6
 
     f.defvjp(f_fwd, f_bwd)
-    return f(x, w_up, w_down, wts, rows, weights, slot, block_expert,
+    return f(x, w_up, w_down, wts, rows, weights, slot, order, block_expert,
              nblocks)
 
 
@@ -278,7 +359,7 @@ def _swiglu_block(xb, w_gate_e, w_up_e, cd):
 
 
 def grouped_experts_gated(x, w_gate, w_up, w_down, wts, rows, weights, slot,
-                          block_expert, nblocks):
+                          order, block_expert, nblocks):
     """:func:`grouped_experts` with the gated body: ``y[r] = sum_j wts[r, j]
     * W_down[e] (silu(W_gate[e] x[r]) * W_up[e] x[r])``, ``w_gate`` stacked
     like ``w_up``. Differentiable in ``x``, the three expert weights and
@@ -290,7 +371,7 @@ def grouped_experts_gated(x, w_gate, w_up, w_down, wts, rows, weights, slot,
     cd = x.dtype
     block = rows.shape[0] // block_expert.shape[0]
 
-    def forward(x, w_gate, w_up, w_down, wts, rows, weights, slot,
+    def forward(x, w_gate, w_up, w_down, wts, rows, weights, slot, order,
                 block_expert, nblocks):
         def body(b, out):
             e, r, w = _take_block(b, block, rows, weights, block_expert)
@@ -307,8 +388,8 @@ def grouped_experts_gated(x, w_gate, w_up, w_down, wts, rows, weights, slot,
         return forward(*args), args
 
     def f_bwd(res, dy):
-        (x, w_gate, w_up, w_down, wts, rows, weights, slot, block_expert,
-         nblocks) = res
+        (x, w_gate, w_up, w_down, wts, rows, weights, slot, order,
+         block_expert, nblocks) = res
 
         def body(b, carry):
             dx, dwg, dwu, dwd, dwt = carry
@@ -337,17 +418,17 @@ def grouped_experts_gated(x, w_gate, w_up, w_down, wts, rows, weights, slot,
             (jnp.zeros(x.shape, f32), jnp.zeros(w_gate.shape, f32),
              jnp.zeros(w_up.shape, f32), jnp.zeros(w_down.shape, f32),
              jnp.zeros(weights.shape, f32)))
-        dwts = jnp.take(dwt, slot, mode="fill", fill_value=0)
+        dwts = pairs_from_slots(dwt, slot, order, block_expert, nblocks)
         return (dx.astype(cd), dwg.astype(w_gate.dtype),
-                dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), dwts,
-                None, None, None, None, None)
+                dwu.astype(w_up.dtype), dwd.astype(w_down.dtype), dwts) \
+            + (None,) * 6
 
     f.defvjp(f_fwd, f_bwd)
-    return f(x, w_gate, w_up, w_down, wts, rows, weights, slot, block_expert,
-             nblocks)
+    return f(x, w_gate, w_up, w_down, wts, rows, weights, slot, order,
+             block_expert, nblocks)
 
 
-def grouped_experts_kernel(x, w_experts, wts, rows, weights, slot,
+def grouped_experts_kernel(x, w_experts, wts, rows, weights, slot, order,
                            block_expert, nblocks, gated):
     """:func:`grouped_experts` (``w_experts = (w_up, w_down)``) or
     :func:`grouped_experts_gated` (``(w_gate, w_up, w_down)``) with each
@@ -361,7 +442,7 @@ def grouped_experts_kernel(x, w_experts, wts, rows, weights, slot,
 
     from . import pallas_kernels as pk
 
-    def forward(x, w_experts, wts, rows, weights, slot, block_expert,
+    def forward(x, w_experts, wts, rows, weights, slot, order, block_expert,
                 nblocks):
         return pk.grouped_experts_forward(
             x, w_experts, rows, weights, block_expert,
@@ -373,16 +454,17 @@ def grouped_experts_kernel(x, w_experts, wts, rows, weights, slot,
         return forward(*args), args
 
     def f_bwd(res, dy):
-        x, w_experts, wts, rows, weights, slot, block_expert, nblocks = res
+        (x, w_experts, wts, rows, weights, slot, order, block_expert,
+         nblocks) = res
         dx, dws, dwt = pk.grouped_experts_backward(
             x, w_experts, rows, weights, block_expert,
             jnp.reshape(nblocks, (1,)), dy, gated=gated)
-        dwts = jnp.take(dwt, slot, mode="fill", fill_value=0)
-        return (dx, dws, dwts, None, None, None, None, None)
+        dwts = pairs_from_slots(dwt, slot, order, block_expert, nblocks)
+        return (dx, dws, dwts) + (None,) * 6
 
     f.defvjp(f_fwd, f_bwd)
-    return f(x, tuple(w_experts), wts, rows, weights, slot, block_expert,
-             nblocks)
+    return f(x, tuple(w_experts), wts, rows, weights, slot, order,
+             block_expert, nblocks)
 
 
 @register_op("RoutedExperts")
@@ -457,15 +539,14 @@ class RoutedExperts(Operator):
         rows, h = in_shapes[0]
         block = block_rows(rows, self.top_k, self.num_experts)
         length = layout_length(rows, self.top_k, self.num_held, block)
-        # ids, weights and slots a pair; row and weight a slot; an expert
-        # a block; the count of blocks
-        routing = 4 * (3 * rows * self.top_k + 2 * length
-                       + length // block + 1)
+        # ids, weights and slots a pair; a row an expert and row; row and
+        # weight a slot; an expert a block; the count of blocks
+        routing = 4 * (3 * rows * self.top_k + self.num_held * rows
+                       + 2 * length + length // block + 1)
         return [("output", rows * h * np.dtype(in_types[0]).itemsize, None),
                 ("routing", routing, None)]
 
     def apply(self, ctx, inputs, aux):
-        import jax
         import jax.numpy as jnp
 
         from .. import telemetry as _tel
@@ -479,12 +560,12 @@ class RoutedExperts(Operator):
         block = block_rows(x.shape[0], self.top_k, self.num_experts)
         *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
                                 block)
-        wts, rows, weights, slot, block_expert, nblocks = keep(
+        wts, rows, weights, slot, order, block_expert, nblocks = keep(
             (wts, *layout))
+        _tel.inc("lower.experts_plan.column_sort")
         _tel.inc("lower.experts_body.%s"
                  % ("swiglu" if self.gated else "relu2"))
-        layout = (wts, rows, jax.lax.stop_gradient(weights), slot,
-                  block_expert, nblocks)
+        layout = (wts, rows, weights, slot, order, block_expert, nblocks)
         # one Pallas kernel a pass where the shapes are whole tiles that
         # fit VMEM, else a loop of XLA products, a block a step
         if grouped_experts_applicable(x.shape[1], self.num_hidden, block,
@@ -496,7 +577,7 @@ class RoutedExperts(Operator):
             body = grouped_experts_gated if self.gated else grouped_experts
             y = body(x, *w_experts, *layout)
         y = ctx.keep(y, "output")
-        load = jnp.zeros((e,), jnp.int32).at[eid.reshape(-1)].add(1)
+        load = expert_load(eid, e)
         if ctx.is_train and self.bias_update_rate:
             bias = balance_step(bias, load, self.bias_update_rate)
         seen = jnp.concatenate([load, dropped[None].astype(jnp.int32)])

@@ -141,10 +141,10 @@ def test_gated_experts_compile_for_the_chip(one_chip):
         eid, wts = moe.route(x, router, jnp.zeros((e,), jnp.float32), k, 1.8)
         *layout, _ = moe.plan(eid, wts, 0, held,
                               moe.block_rows(rows, k, e))
-        rows_, weights, slot, block_expert, nblocks = layout
+        rows_, weights, *rest = layout
         y = moe.grouped_experts_gated(
             x, w_gate, w_up, w_down, wts, rows_,
-            jax.lax.stop_gradient(weights), slot, block_expert, nblocks)
+            jax.lax.stop_gradient(weights), *rest)
         return y.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(

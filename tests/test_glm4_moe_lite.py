@@ -720,18 +720,19 @@ def _step_text(monkeypatch, net, params0, batches):
     return lowered[0]
 
 
-# sha256 of the step's text in this container's JAX, as PR 37 left it: that
-# PR wrote ``ops.attention.rope`` over the turned columns in one piece and
-# the op's XLA path around it (the toys' widths are no whole lanes, so all
-# three take that path; the rotation is the same to the bit,
-# ``test_rope_is_the_split_form_bit_for_bit``). Before it the three texts
-# were those of commits 0c7ad87 (PR 31) and a1b7200 (PR 32). A PR that
-# changes what these programs compute on purpose reads the new ones off
-# this test's failure.
+# sha256 of the step's text in this container's JAX. The Olmo toy's is as PR
+# 37 left it (that PR wrote ``ops.attention.rope`` over the turned columns in
+# one piece and the op's XLA path around it; before it the three texts were
+# those of commits 0c7ad87 (PR 31) and a1b7200 (PR 32)). The two toys with a
+# ``RoutedExperts`` node are as PR 42 left them: ``moe.plan``'s layout from
+# sorts and dense compares, the same arrays to the bit
+# (``tests/test_moe_kernel.py``); until then they were 1228aa23... and
+# c7dfb647... A PR that changes what these programs compute on purpose reads
+# the new ones off this test's failure.
 PARENT_TEXT = {
-    "nemotron_h": "1228aa239a8a81c1dbf1b8bd91b946948a53ae8199a5a85698dc61ab4bd9b7b0",
+    "nemotron_h": "2d7da3f55448359f22199f4cd4d54782c91a0105c57def880cb408541d94be81",
     "olmo_hybrid": "01c68fef70c315c7ebbf2e1957e8e76b7ed51f9ba63815e15fdaf6d714b3330d",
-    "glm4_moe_lite": "c7dfb647853bf98c603616c0045b290b3a6542d22aecb4ce64ba1221a22cacb2",
+    "glm4_moe_lite": "ac10154ec8d09085a5802619e71d14043909fdab3d84d22eacee0eff9b6ab543",
 }
 
 

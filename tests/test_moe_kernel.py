@@ -38,13 +38,27 @@ def pairs_of(name, rows):
         return np.stack([r % 8, (r % 8 + 1) % 8], 1), 8, 2, 4
     if name == "nothing_lands_here":
         return np.stack([np.full(rows, 6), np.full(rows, 7)], 1), 8, 0, 4
+    if name == "one_expert_draws_every_row":
+        # no capacity would hold them: expert 2 (the second held) takes
+        # every row, the other choices spread over all eight
+        second = np.where(r % 8 == 2, 7, r % 8)
+        return np.stack([second, np.full(rows, 2)], 1), 8, 1, 3
+    if name == "ids_of_no_expert":
+        # what a capacity laid under the op hands down (10 ** 6): such a
+        # pair lands nowhere, beside pairs that do and a row with none left
+        ids = np.stack([r % 4, 3 - r % 4 + 4 * (r % 2)], 1)
+        ids[r % 3 == 0, 0] = 10 ** 6
+        ids[r % 5 == 0, 1] = 10 ** 6
+        return ids, 8, 0, 4
     raise KeyError(name)
 
 
 LAYOUTS = {"three_blocks_and_an_empty_expert": 64,
            "every_row_shared_to_the_bound": 33,
            "held_from_the_third": 48,
-           "nothing_lands_here": 24}
+           "nothing_lands_here": 24,
+           "one_expert_draws_every_row": 40,
+           "ids_of_no_expert": 45}
 
 
 def problem(name, gated, dtype, seed=0, h=H, f=F, block=BLOCK):
@@ -67,11 +81,11 @@ def problem(name, gated, dtype, seed=0, h=H, f=F, block=BLOCK):
 
 def both_bodies(x, ws, wts, layout, head, gated):
     """``{body: (y, (dx, dws, dwts))}`` of the loop and the kernel."""
-    rows, weights, slot, block_expert, nblocks = layout
+    rows, weights, slot, order, block_expert, nblocks = layout
 
     def run(body):
         def loss(x, ws, wts):
-            lay = (wts, rows, jax.lax.stop_gradient(weights), slot,
+            lay = (wts, rows, jax.lax.stop_gradient(weights), slot, order,
                    block_expert, nblocks)
             if body == "loop":
                 fn = moe.grouped_experts_gated if gated \
@@ -135,6 +149,133 @@ def test_kernel_matches_the_loop(name, gated, dtype):
         assert np.asarray(dws1[0][1], np.float32).any()
 
 
+def stated_layout(eid, wts, first, held, block):
+    """:func:`moe.plan`'s layout stated a pair at a time: ``(rows, weights,
+    slot, the filled blocks' experts)``."""
+    s, k = eid.shape
+    length = moe.layout_length(s, k, held, block)
+    rows, weights = np.zeros(length, np.int32), np.zeros(length, np.float32)
+    slot, experts, at = np.full((s, k), length, np.int32), [], 0
+    for e in range(held):
+        r, j = np.nonzero(eid == first + e)         # in row order
+        rows[at:at + len(r)], weights[at:at + len(r)] = r, wts[r, j]
+        slot[r, j] = at + np.arange(len(r))
+        experts += [e] * -(-len(r) // block)
+        at = len(experts) * block
+    return rows, weights, slot, experts
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_the_layout_is_the_stated_one(name):
+    """Every landing pair one slot, each block one expert's, padding of
+    weight 0, no pair dropped whatever the imbalance, ``nblocks`` the
+    blocks filled; ``order`` an expert's rows first; and a value a slot
+    comes back to the pairs as ``jnp.take`` brings it, whatever lies in the
+    slots no pair has (the kernels define nothing past the filled blocks)."""
+    rows_in = LAYOUTS[name]
+    eid, _, first, held = pairs_of(name, rows_in)
+    wts = np.asarray(jax.random.uniform(jax.random.PRNGKey(5), eid.shape,
+                                        jnp.float32, 0.2, 1.0))
+    rows, weights, slot, order, block_expert, nblocks, dropped = (
+        np.asarray(a) for a in jax.jit(
+            moe.plan, static_argnums=(2, 3, 4))(
+                jnp.asarray(eid, jnp.int32), wts, first, held, BLOCK))
+    want_rows, want_weights, want_slot, experts = stated_layout(
+        eid, wts, first, held, BLOCK)
+    length = len(want_rows)
+    assert int(dropped) == 0 and int(nblocks) == len(experts)
+    assert rows.dtype == slot.dtype == block_expert.dtype == np.int32
+    assert weights.dtype == np.float32
+    assert (rows == want_rows).all() and (weights == want_weights).all()
+    assert (slot == want_slot).all()
+    assert len(block_expert) == length // BLOCK
+    assert list(block_expert[:len(experts)]) == experts
+    assert ((0 <= block_expert) & (block_expert < held)).all()
+    here = (eid >= first) & (eid < first + held)
+    assert int((weights != 0).sum()) == int(here.sum())
+    for e in range(held):
+        drew = np.nonzero((eid == first + e).any(axis=1))[0]
+        assert sorted(order[e]) == list(range(rows_in))
+        assert list(order[e][:len(drew)]) == list(drew)
+    dwt = np.full(length, np.nan, np.float32)
+    dwt[slot[here]] = np.arange(1, here.sum() + 1)
+    back = np.asarray(jax.jit(moe.pairs_from_slots)(
+        dwt, slot, order, block_expert, nblocks))
+    assert back.dtype == np.float32
+    assert (back == np.asarray(jnp.take(jnp.asarray(dwt), slot, mode="fill",
+                                        fill_value=0))).all()
+    if name == "one_expert_draws_every_row":
+        assert (eid == 2).any(axis=1).all() and experts.count(1) == 3
+    if name == "ids_of_no_expert":
+        assert (eid == 10 ** 6).any() and (~here).all(axis=1).any()
+        assert 0 < here.sum() < (eid != 10 ** 6).sum()
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "swiglu"])
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_results_are_those_of_a_gather_by_slot(monkeypatch, name, gated):
+    """``y``, ``dx``, the weights' gradients and ``dwts`` of both bodies
+    with the weights' gradient brought back by ``jnp.take(dwt, slot)``, as
+    it was before the sorts: the same values, to the bit."""
+    x, ws, wts, layout, head, _ = problem(name, gated, jnp.float32)
+    got = both_bodies(x, ws, wts, layout, head, gated)
+    monkeypatch.setattr(
+        moe, "pairs_from_slots", lambda dwt, slot, *_: jnp.take(
+            dwt, slot, mode="fill", fill_value=0))
+    want = both_bodies(x, ws, wts, layout, head, gated)
+    for body in ("loop", "kernel"):
+        a, b = jax.tree_util.tree_leaves(got[body]), \
+            jax.tree_util.tree_leaves(want[body])
+        assert len(a) == len(b) == 3 + len(ws)
+        for u, v in zip(a, b):
+            assert (np.asarray(u) == np.asarray(v)).all()
+
+
+def test_the_routing_lowers_without_a_scalar_scatter_or_gather():
+    """At the Ling cell's shapes (8,192 rows, 8 of 512, 8 held): the
+    layout, the load count and the weights' gradient's way back to the
+    pairs hold no ``scatter``, no ``gather`` and no ``while`` (a scalar one
+    costs the chip 7-30 ns an element; the sorts ride the vector unit).
+    Lowered, not compiled or run."""
+    s, k, e, held = 8192, 8, 512, 8
+    block = moe.block_rows(s, k, e)
+    length = moe.layout_length(s, k, held, block)
+    i32, f32 = jnp.int32, jnp.float32
+    sd = jax.ShapeDtypeStruct
+    texts = {
+        "plan": jax.jit(lambda eid, wts: moe.plan(
+            eid, wts, 0, held, block)).lower(sd((s, k), i32),
+                                             sd((s, k), f32)),
+        "load": jax.jit(lambda eid: moe.expert_load(eid, e)).lower(
+            sd((s, k), i32)),
+        "back": jax.jit(moe.pairs_from_slots).lower(
+            sd((length,), f32), sd((s, k), i32), sd((held, s), i32),
+            sd((length // block,), i32), sd((), i32))}
+    for what, lowered in texts.items():
+        text = lowered.as_text()
+        assert "stablehlo.sort" in text or what == "load"
+        for op in ("scatter", "gather", "while"):
+            assert "stablehlo.%s" % op not in text \
+                and "\"%s\"" % op not in text, (what, op)
+
+
+def test_a_traced_node_counts_its_layout_once():
+    from mxnet_tpu import symbol as sym
+    from mxnet_tpu import telemetry
+    import mxnet_tpu as mx
+
+    net = sym.RoutedExperts(num_experts=4, num_held=2, top_k=2,
+                            num_hidden=F, data=sym.Variable("data"), name="x")
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        net.simple_bind(mx.cpu(), data=(64, H)).forward(is_train=False)
+        assert telemetry.peek("lower.experts_plan.column_sort") == 1
+        assert telemetry.peek("lower.experts_kernel.pallas_grouped") == 1
+    finally:
+        telemetry.disable()
+
+
 @pytest.mark.parametrize("gated", [False, True], ids=["relu2", "swiglu"])
 def test_a_tile_of_f_that_hangs_over_the_edge(monkeypatch, gated):
     """``f`` = 192 in tiles of 128: the last tile's upper half lies past
@@ -175,12 +316,12 @@ def test_weight_gradients_are_summed_in_float32_across_an_experts_blocks():
         .astype(bf16)
     eid = jnp.zeros((rows, 1), jnp.int32)
     wts = jnp.ones((rows, 1), jnp.float32)
-    rows_, weights, slot, block_expert, nblocks, _ = moe.plan(
+    rows_, weights, slot, order_, block_expert, nblocks, _ = moe.plan(
         eid, wts, 0, 1, block)
     assert int(nblocks) == 6
     _, vjp = jax.vjp(lambda up, down: moe.grouped_experts_kernel(
-        x, (up, down), wts, rows_, weights, slot, block_expert, nblocks,
-        False), w_up, w_down)
+        x, (up, down), wts, rows_, weights, slot, order_, block_expert,
+        nblocks, False), w_up, w_down)
     dwu, dwd = (np.asarray(g[0], f64) for g in vjp(dy))
 
     def rounded(v):
@@ -223,10 +364,8 @@ def test_every_product_and_every_sum_is_float32(gated):
     32-bit words and nothing else."""
     x, ws, wts, layout, head, _ = problem(
         "three_blocks_and_an_empty_expert", gated, jnp.bfloat16)
-    rows, weights, slot, block_expert, nblocks = layout
     jaxpr = jax.make_jaxpr(jax.grad(lambda x, ws, wts: jnp.sum(
-        moe.grouped_experts_kernel(x, ws, wts, rows, weights, slot,
-                                   block_expert, nblocks, gated)
+        moe.grouped_experts_kernel(x, ws, wts, *layout, gated)
         .astype(jnp.float32) * head), argnums=(0, 1, 2)))(x, ws, wts)
     calls = [eqn for sub in _sub_jaxprs(jaxpr.jaxpr) for eqn in sub.eqns
              if eqn.primitive.name == "pallas_call"]

@@ -538,7 +538,7 @@ def test_routed_experts_against_loop_under_a_skewed_router():
                              jnp.asarray(inputs["router_weight"]),
                              jnp.asarray(bias), top_k, 2.5)
     assert int((np.asarray(eid) == 2).any(axis=1).sum()) > 32
-    rows, weights, slot, block_expert, nblocks, dropped = moe_ops.plan(
+    rows, weights, slot, _, block_expert, nblocks, dropped = moe_ops.plan(
         eid, wts, first, held, 8)
     assert int(dropped) == 0
     here = (np.asarray(eid) >= first) & (np.asarray(eid) < first + held)
@@ -1071,7 +1071,9 @@ def test_attention_kernel_and_routing_run_once_a_step(monkeypatch):
     log-sum-exp and the experts' routing are kept: the forward kernel is
     called as often as each backward kernel (once a step; the parent's
     plan called it twice), and ``top_k`` and the layout's ``sort`` appear
-    once a ``RoutedExperts`` node. Counted in the traced step, and as
+    once a ``RoutedExperts`` node (a second ``sort`` is the backward
+    pass's: it brings the combine weights' gradient from the slots back to
+    the rows, ``moe.pairs_from_slots``). Counted in the traced step, and as
     calls of the kernel's jitted caller (forward, backward) in the step
     lowered for the TPU."""
     import re
@@ -1089,7 +1091,7 @@ def test_attention_kernel_and_routing_run_once_a_step(monkeypatch):
     assert kernels("splash_mqa_fwd") == kernels("splash_mqa_dq") \
         == kernels("splash_mqa_dkv") == 2
     assert _count(jaxpr, lambda e: e.primitive.name == "top_k") == 1
-    assert _count(jaxpr, lambda e: e.primitive.name == "sort") == 1
+    assert _count(jaxpr, lambda e: e.primitive.name == "sort") == 2
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     assert 'kernel_name = "splash_mqa_fwd_residuals"' in text
     assert len(re.findall(r"call @_splash_attention(_\d+)?\(", text)) == 2
