@@ -10,8 +10,9 @@ points a user calls, at the full width of ResNet-50 (1000 classes,
 2. the trained module re-bound for inference behind
    ``serving.InferenceServer``, single-row requests checked against
    ``mod.predict``;
-3. every shipped Pallas kernel, forward and backward, compiled by Mosaic
-   and compared with its XLA reference.
+3. the Pallas kernels a cell's train step takes (and ``rtc``'s entry),
+   forward and backward, compiled by Mosaic and compared with the
+   ``jax.numpy`` body of the same operator.
 
 Each phase asserts; nothing is retried and nothing falls back to the CPU.
 Without a TPU the script exits non-zero before any phase and prints no
@@ -231,99 +232,163 @@ def _fwd_bwd(fn, args):
     return (out,) + tuple(grads)
 
 
-def kernel_phase(device, small=False):
-    """Compile and run each shipped Pallas kernel once on ``device``,
-    forward and backward, against its XLA reference (computed at highest
-    matmul precision). On a TPU the kernels lower through Mosaic; on the
-    CPU the same calls lower to the Pallas interpreter."""
+#: the kernel phase's cases: ``rtc``'s entry point and the four families of
+#: Pallas kernels the language cells run (``ops/pallas_kernels.py``)
+KERNEL_CASES = ("rtc axpy", "ssd_scan", "gated_delta_scan head",
+                "gated_delta_scan channel", "grouped_experts relu2",
+                "grouped_experts swiglu", "attention_relayout")
+
+
+def _kernel_case(name, device, small):
+    """``(kernel fn, jax.numpy body, float32 arguments, tolerance)`` of one
+    case: both functions are differentiable in every argument, the kernel's
+    backward pass its own kernel. Shapes are a language cell's widths over
+    1,024 positions, or with ``small`` the least the kernel's rule admits
+    over two chunks (a carried state crosses one boundary).
+
+    The tolerances are three times the ``reldiff`` (sum |a - b| / (sum
+    |a| + sum |b|), the largest over the result and every gradient) read
+    on the v5e at the cells' widths (PR 43, one run of this phase; PERF.md
+    section 6): 1.93e-3 for the scan and 2.16e-3 / 2.26e-3 for the
+    experts, whose float32 products pass the MXU at the default precision
+    inside the kernel where the body's run at ``highest``; 3.9e-7 / 4.5e-7
+    for the delta rule, whose kernels ask for ``HIGHEST`` themselves; 0
+    for the relayout pass, which has no product."""
     import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu import rtc
+    from mxnet_tpu.ops import attention, moe, seq
     from mxnet_tpu.ops import pallas_kernels as pk
-    from mxnet_tpu.parallel.ring_attention import reference_attention
 
     rng = np.random.RandomState(5)
+    f32 = jnp.dtype("float32")
 
-    def put(*shape, dtype=np.float32, scale=1.0):
+    def put(*shape, scale=1.0, shift=0.0):
         return jax.device_put(
-            (rng.randn(*shape) * scale).astype(np.float32), device
-        ).astype(dtype)
+            (rng.randn(*shape) * scale + shift).astype(np.float32), device)
 
-    m, k, n = (128, 128, 128) if small else (512, 1024, 1024)
-    b, t, h, d = (1, 128, 1, 128) if small else (2, 512, 4, 128)
-    nb, ch, hw = (2, 128, 8) if small else (8, 128, 28)
-    qkv = (put(b, t, h, d), put(b, t, h, d), put(b, t, h, d))
-    linear_args = (put(m, k), put(n, k, scale=k ** -0.5), put(n))
+    def unit(x):
+        return x / jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
-    def ref_norm(x, sc, sh):
-        y = x.astype(jnp.float32) * sc + sh
-        return jnp.maximum(y, 0.0).astype(x.dtype)
+    if name == "ssd_scan":
+        # the Nemotron cell's mixer: 64 heads of 64 in 8 groups, state 128
+        dims, chunk, t = ((2, 64, 1, 128), 128, 256) if small \
+            else ((64, 64, 8, 128), 128, 1024)
+        h, p, g, n = dims
+        assert pk.ssd_chunk_applicable(dims, chunk, f32)
+        args = (put(1, t, h * p + 2 * g * n),
+                jax.nn.softplus(put(1, t, h, shift=-2.0)),    # dt
+                -jnp.exp(put(h, scale=0.5)), put(h))          # A, D
+        return (lambda *a: seq.ssd_scan(*a, dims, chunk, True),
+                lambda *a: seq.ssd_scan(*a, dims, chunk, False), args, 6e-3)
+    if name.startswith("gated_delta_scan"):
+        channel = name.endswith("channel")
+        if channel:
+            # the Ling cell's mixer (8 of its 32 heads): 128 keys, 128
+            # values, chunks of 64, one decay a key channel
+            h, dk, dv, chunk, t = (1, 128, 8, 16, 32) if small \
+                else (8, 128, 128, 64, 1024)
+            assert pk.delta_channel_applicable((h, dk, dv), chunk, f32)
+        else:
+            # the Olmo cell's mixer: 15 heads of 96 keys and 192 values
+            h, dk, dv, chunk, t = (2, 8, 16, 16, 32) if small \
+                else (15, 96, 192, 64, 1024)
+            assert pk.delta_chunk_applicable((h, dk, dv), chunk, f32)
+        gate = put(1, t, h, dk, shift=-2.0) if channel \
+            else put(1, t, h, shift=-2.0)
+        args = (unit(put(1, t, h, dk)) * dk ** -0.5, unit(put(1, t, h, dk)),
+                put(1, t, h, dv), -jax.nn.softplus(gate),
+                jax.nn.sigmoid(put(1, t, h)))
+        return (lambda *a: seq.gated_delta_scan(*a, chunk, True),
+                lambda *a: seq.gated_delta_scan(*a, chunk, False), args,
+                2e-6)
+    if name.startswith("grouped_experts"):
+        gated = name.endswith("swiglu")
+        # 8 held experts of 16 drawn two a row: the Nemotron cell's
+        # ``relu2`` experts (2,688 -> 1,856), the GLM cell's gated ones
+        # (2,048 -> 1,536)
+        rows, hid, f, held = (64, 128, 256, 4) if small \
+            else (2048,) + ((2048, 1536) if gated else (2688, 1856)) + (8,)
+        top_k, total = 2, 2 * held
+        block = 16 if small else moe.block_rows(rows, top_k, total)
+        assert pk.grouped_experts_applicable(hid, f, block, f32, gated, rows)
+        eid = np.stack([rng.permutation(total)[:top_k]
+                        for _ in range(rows)]).astype(np.int32)
+        wts = jax.nn.sigmoid(put(rows, top_k))
+        *layout, dropped = moe.plan(jax.device_put(eid, device), wts, 0,
+                                    held, block)
+        assert int(dropped) == 0
+        args = (put(rows, hid), wts) + tuple(
+            put(held, i, o, scale=i ** -0.5)
+            for i, o in [(hid, f)] * (2 if gated else 1) + [(f, hid)])
+        loop = moe.grouped_experts_gated if gated else moe.grouped_experts
+        # ``wts`` by pair carries the gradient; the layout's copy by slot
+        # (``layout[1]``) is a constant, as ``RoutedExperts`` hands it over
+        return (lambda x, wts, *ws: moe.grouped_experts_kernel(
+            x, ws, wts, *layout, gated),
+            lambda x, wts, *ws: loop(x, *ws, wts, *layout), args, 7e-3)
+    assert name == "attention_relayout"
+    # the GLM cell's queries: 20 heads of 256, the last 64 columns turned;
+    # small: two 64-wide heads in one 128-lane tile, turned whole
+    batch, t, heads, d, turned = (1, 128, 2, 64, 64) if small \
+        else (1, 1024, 20, 256, 64)
+    theta, scale = 1e6, d ** -0.5
+    tables = tuple(jax.device_put(a, device) for a in
+                   attention.relayout_tables(t, theta, turned // 2, d))
+    return (lambda x: attention._relaid(
+        x, tables, batch=batch, heads=heads, half=turned // 2, scale=scale),
+        lambda x: attention.rope(
+            x.reshape(batch, t, heads, d), theta, scale, turned,
+            pos_axis=1).transpose(0, 2, 1, 3),
+        (put(batch * t, heads * d),), 1e-6)
 
-    # (name, kernel fn, reference fn, args, reldiff tolerance)
-    cases = [
-        # relu is the epilogue the callers use. Its mask flips where a
-        # pre-activation sits within bf16-pass rounding of zero (~0.05%
-        # of elements at this shape), and each flip moves a whole row of
-        # the data gradient: reldiff 1.0e-2 on the v5e (PR 21), nearly
-        # all of it flips. tanh has no mask and shows the matmuls' own
-        # error, 1.6e-3
-        ("fused_linear %s" % act,
-         lambda x, w, b, act=act: pk.fused_linear(x, w, b, act=act),
-         lambda x, w, b, ep=ep: ep(x @ w.T + b), linear_args, 2e-2)
-        for act, ep in (("relu", jax.nn.relu), ("tanh", jnp.tanh))
-    ] + [
-        ("flash_attention", pk.flash_attention, reference_attention,
-         qkv, 2e-2),
-        ("flash_attention_causal",
-         lambda q, kk, v: pk.flash_attention(q, kk, v, causal=True),
-         lambda q, kk, v: reference_attention(q, kk, v, causal=True),
-         qkv, 2e-2),
-        ("conv2d (conv_dgrad + conv_wgrad)",
-         lambda x, w: pk.conv2d(x, w, stride=(1, 1), pad=(1, 1)),
-         lambda x, w: jax.lax.conv_general_dilated(
-             x, w, (1, 1), [(1, 1), (1, 1)],
-             dimension_numbers=("NCHW", "OIHW", "NCHW")),
-         (put(nb, ch, hw, hw), put(ch, ch, 3, 3, scale=0.05)), 2e-2),
-    ] + [
-        ("fused_norm_act %s" % jnp.dtype(dtype).name,
-         lambda x, sc, sh: pk.fused_norm_act(x, sc, sh, act="relu"),
-         ref_norm,
-         (put(nb, hw, hw, ch, dtype=dtype), put(ch), put(ch)), tol)
-        for dtype, tol in ((jnp.float32, 1e-4), (jnp.bfloat16, 2e-2))]
+
+def _rtc_case(device):
+    """One runtime-compiled (rtc) kernel through its NDArray entry point."""
+    from mxnet_tpu import rtc
+
+    rng = np.random.RandomState(5)
+    ctx = mx.Context("cpu" if device.platform == "cpu" else "tpu",
+                     device.id)
+    x = mx.nd.array(rng.randn(256, 128).astype(np.float32), ctx=ctx)
+    y = mx.nd.array(rng.randn(256, 128).astype(np.float32), ctx=ctx)
+    out = mx.nd.zeros((256, 128), ctx=ctx)
+    axpy = rtc.Rtc("axpy", [("x", x), ("y", y)], [("out", out)],
+                   "out_ref[:] = 2.0 * x_ref[:] + y_ref[:]")
+    axpy.push([x, y], [out])
+    assert out.handle.devices() == {device}
+    return assert_almost_equal(
+        out.asnumpy(), 2.0 * x.asnumpy() + y.asnumpy(), 1e-6, "rtc axpy")
+
+
+def kernel_phase(device, small=False, only=KERNEL_CASES):
+    """Compile and run each Pallas kernel a cell's train step takes once
+    on ``device``, forward and backward, against the ``jax.numpy`` body
+    the same operator keeps for shapes the kernel does not admit (float32,
+    computed at highest matmul precision). On a TPU the kernels lower
+    through Mosaic: the one place a compiled kernel meets its body
+    directly (a cell's ``correct`` lets a carried state rounded to bfloat16
+    through). On the CPU the same calls lower to the Pallas interpreter."""
+    import jax
 
     results, failures = {}, []
-    with jax.default_matmul_precision("highest"):
-        refs = [_fwd_bwd(ref_fn, args) for _, _, ref_fn, args, _ in cases]
-    for (name, kernel_fn, _, args, tol), ref in zip(cases, refs):
+    for name in only:
         try:
+            if name == "rtc axpy":
+                results[name] = _rtc_case(device)
+                continue
+            kernel_fn, body_fn, args, tol = _kernel_case(name, device, small)
+            with jax.default_matmul_precision("highest"):
+                want = _fwd_bwd(body_fn, args)
             got = _fwd_bwd(kernel_fn, args)
             results[name] = max(
                 assert_almost_equal(np.asarray(g, np.float32),
-                                    np.asarray(r, np.float32), tol,
+                                    np.asarray(w, np.float32), tol,
                                     "%s[%d]" % (name, i))
-                for i, (g, r) in enumerate(zip(got, ref)))
+                for i, (g, w) in enumerate(zip(got, want)))
         except Exception as e:   # collect every kernel's verdict, then fail
             failures.append("%s: %s: %s" % (
                 name, type(e).__name__, str(e).splitlines()[0][:300]))
-
-    # one runtime-compiled (rtc) kernel through its NDArray entry point
-    try:
-        ctx = mx.Context("cpu" if device.platform == "cpu" else "tpu",
-                         device.id)
-        x = mx.nd.array(rng.randn(256, 128).astype(np.float32), ctx=ctx)
-        y = mx.nd.array(rng.randn(256, 128).astype(np.float32), ctx=ctx)
-        out = mx.nd.zeros((256, 128), ctx=ctx)
-        axpy = rtc.Rtc("axpy", [("x", x), ("y", y)], [("out", out)],
-                       "out_ref[:] = 2.0 * x_ref[:] + y_ref[:]")
-        axpy.push([x, y], [out])
-        results["rtc axpy"] = assert_almost_equal(
-            out.asnumpy(), 2.0 * x.asnumpy() + y.asnumpy(), 1e-6,
-            "rtc axpy")
-        assert out.handle.devices() == {device}
-    except Exception as e:
-        failures.append("rtc axpy: %s: %s" % (
-            type(e).__name__, str(e).splitlines()[0][:300]))
 
     print("chip_smoke kernels %s" % json.dumps(
         {"ok": sorted(results), "failed": failures,

@@ -592,31 +592,9 @@ declare("MXNET_TPU_NO_NATIVE", bool, False,
         "Disable the C++ runtime library (pure-Python recordio + engines "
         "only).", section="Native library / Pallas")
 declare("MXNET_TPU_NO_PALLAS", bool, False,
-        "Hard-disable all Pallas usage. (The former `MXNET_TPU_PALLAS` "
-        "fast-path gate is retired: on-chip measurement showed XLA wins "
-        "at every size, see docs/pallas.md; the kernels remain available "
-        "explicitly via `ops.pallas_kernels`, `rtc`, ring/Ulysses "
-        "attention.)", section="Native library / Pallas")
-declare("MXNET_TPU_PALLAS_CONV", bool, False,
-        "Force the Pallas conv-backward kernels (dgrad/wgrad as tiled "
-        "MXU matmuls, `ops.pallas_kernels.conv2d`) for every applicable "
-        "Convolution, bypassing the autotune cache — the pin/override "
-        "for a chip window (docs/performance.md). Misaligned shapes "
-        "still fall back to XLA per-layer.",
+        "Hard-disable all Pallas usage: every operator that chooses a "
+        "kernel from its shapes keeps its XLA body.",
         section="Native library / Pallas")
-
-_AT = "Autotuning"
-declare("MXNET_TPU_AUTOTUNE", bool, False,
-        "Consult the autotuner's best-config cache "
-        "(`.autotune_cache.json`, written by "
-        "`mxnet_tpu.autotune.run_smoke(path)`, which you call yourself) at "
-        "trace time: tuned kernel/tile choices apply to `ops/nn.py` and "
-        "the fused step with zero extra dispatches. Off: every site "
-        "keeps its measured default.", section=_AT)
-declare("MXNET_TPU_AUTOTUNE_BUDGET_S", float, 60.0,
-        "Wall-clock budget (seconds) for one `mxnet_tpu.autotune` "
-        "search; candidates past the budget are recorded as pruned "
-        "(`budget exhausted`), never silently skipped.", section=_AT)
 
 _OW = "Obswatch / fleet federation"
 declare("MXNET_TPU_OBSWATCH_INTERVAL_MS", float, 1000.0,

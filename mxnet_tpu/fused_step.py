@@ -848,12 +848,6 @@ class FusedTrainStep:
         # "batch.data" / "params.fc1_weight" instead of "arg1[0]"
         names = [ex.arg_names[i] for i in self._p_arg_idx]
         batch_names = [ex.arg_names[i] for i in self._o_arg_idx]
-        # consult the autotuner's best-config cache once per build:
-        # tuned kernel choices were already applied while tracing the
-        # ops above (ops/nn.py reads the same cache), this records the
-        # consultation for observability — nothing runs per dispatch
-        from . import autotune as _autotune
-        _autotune.note_build("fused_step")
         if watch is not None:
             # the stats pack joins the donated set (argnum 6)
             def step(p_vals, o_vals, aux, st, sv_mats, accs, stats, key,

@@ -563,9 +563,10 @@ class _Waiter:
     """Reply waiter for one mid (the fleet ``_PendingWaiter`` shape,
     with wire-taxonomy errors)."""
 
-    __slots__ = ("_done", "_frame", "_error", "t0", "_on_cancel")
+    __slots__ = ("_done", "_frame", "_error", "t0", "_on_cancel", "conn")
 
     def __init__(self):
+        self.conn = None    # the connection the request rides
         self._done = threading.Event()
         self._frame: Optional[Frame] = None
         self._error: Optional[BaseException] = None
@@ -649,10 +650,7 @@ class _PooledConn:
         w = _Waiter()
         w._on_cancel = lambda: self._forget(mid)
         with self._lock:
-            try:
-                conn = self._ensure_conn()
-            except WireError:
-                raise
+            conn = w.conn = self._ensure_conn()
             self._pending[mid] = w
         try:
             conn.send_frame(bufs)
@@ -690,12 +688,16 @@ class _PooledConn:
         self._fail_pending(conn)
 
     def _fail_pending(self, conn: WireConn):
+        """``conn`` is lost: fail the requests that ride it, and only
+        those. Its reader notices the loss up to a receive timeout after
+        the sender did, and by then the slot may have redialled and hold
+        a newer connection's waiters."""
         conn.close()
         with self._lock:
             if self._conn is conn:
                 self._conn = None
-            pending = list(self._pending.values())
-            self._pending.clear()
+            lost = [m for m, w in self._pending.items() if w.conn is conn]
+            pending = [self._pending.pop(m) for m in lost]
         for w in pending:
             w.fail(WirePeerLost("connection to %s lost mid-request"
                                 % self._client.peer))

@@ -61,11 +61,6 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   and accumulation are float32, the two products take inputs in the
   compute dtype. The ``G`` query heads that share a key/value head ride
   one einsum against it.
-
-The repo's own :func:`pallas_kernels.flash_attention` is not among them:
-it has no grouped form (repeating keys is what this op exists to avoid)
-and its backward pass recomputes the full ``[T, T]`` scores, so at the
-shapes a language model brings it cannot apply (ROADMAP Reach A1).
 """
 from __future__ import annotations
 

@@ -67,11 +67,6 @@ class FullyConnected(Operator):
         return {1: 2 * rows * k * self.num_hidden}
 
     def apply(self, ctx, inputs, aux):
-        # XLA is the measured fast path: the Pallas fused_linear kernel
-        # benched 0.1-1.0x of the XLA dot on a v5e across 256..8192 sizes
-        # (tools/bench_pallas.py, table in docs/pallas.md), so the former
-        # MXNET_TPU_PALLAS gate was retired. The kernels remain available
-        # explicitly via ops.pallas_kernels / rtc.
         jnp = _jnp()
         data = inputs[0]
         w = inputs[1]
@@ -258,10 +253,6 @@ class Convolution(_ConvBase):
         nd = len(kernel)
         spatial = _spatial_letters(nd)
         nhwc = self._is_nhwc()
-        if nd == 2 and self.num_group == 1:
-            out = self._pallas_conv(inputs, stride, pad, dilate, nhwc)
-            if out is not None:
-                return [out], []
         # weight stays OIHW in BOTH layouts (checkpoint-canonical); XLA
         # re-lays it out at compile time, so NHWC costs no transposes at
         # runtime on TPU
@@ -282,28 +273,6 @@ class Convolution(_ConvBase):
                 else (1, -1) + (1,) * nd
             out = out + inputs[2].reshape(bshape)
         return [out], []
-
-    def _pallas_conv(self, inputs, stride, pad, dilate, nhwc):
-        """Trace-time routing of the conv *backward* through the Pallas
-        dgrad/wgrad kernels: taken when `MXNET_TPU_PALLAS_CONV` pins it
-        or the autotune cache holds a measured win for this chip. The
-        forward stays `conv_general_dilated` either way (docs/pallas.md:
-        XLA's forward conv already wins); `pallas_kernels.conv2d`
-        returns None for any shape its tiles cannot cover, keeping the
-        XLA path per-layer. All decisions happen while tracing — zero
-        per-dispatch cost."""
-        from .. import autotune as _autotune
-        from . import pallas_kernels as _pk
-
-        x = inputs[0]
-        sig = _autotune.aval_sig(x.shape, x.dtype)
-        if not _autotune.conv_kernel_enabled(sig):
-            return None
-        return _pk.conv2d(
-            x, inputs[1], bias=None if self.no_bias else inputs[2],
-            stride=stride, pad=pad, dilate=dilate,
-            num_group=self.num_group, nhwc=nhwc,
-            tiles=_autotune.conv_tiles(sig))
 
 
 @register_op("Deconvolution")
@@ -510,29 +479,8 @@ class BatchNorm(Operator):
         scale = (gamma.astype(inv.dtype) * inv).astype(x.dtype)
         shift = (beta.astype(inv.dtype) - mean * gamma.astype(inv.dtype)
                  * inv).astype(x.dtype)
-        out = None
-        if caxis == x.ndim - 1:
-            out = self._fused_norm(x, scale, shift)
-        if out is None:
-            out = x * scale.reshape(bshape) + shift.reshape(bshape)
+        out = x * scale.reshape(bshape) + shift.reshape(bshape)
         return [out], new_aux
-
-    def _fused_norm(self, x, scale, shift):
-        """Trace-time: the one-pass Pallas scale/shift kernel (forward
-        and backward each a single VMEM pass, f32 math) when the
-        autotune cache holds a measured `block_rows` win for this chip.
-        None -> the XLA elementwise path. The kernel's scale/shift
-        cotangents chain through the traced batch statistics, so
-        training gradients are unchanged."""
-        from .. import autotune as _autotune
-        from . import pallas_kernels as _pk
-
-        br = _autotune.norm_block_rows(
-            _autotune.aval_sig(x.shape, x.dtype))
-        if not br:
-            return None
-        return _pk.fused_norm_act(x, scale, shift, act="none",
-                                  block_rows=br)
 
 
 # ---------------------------------------------------------------------------

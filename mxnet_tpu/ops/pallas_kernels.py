@@ -1,46 +1,42 @@
-"""Pallas TPU kernels — the cuDNN-class fast path.
+"""Pallas TPU kernels: what the train path's operators lower to where
+their shapes are whole tiles.
 
-The reference reached peak GPU throughput with hand-tuned cuDNN kernels
-(``src/operator/cudnn_*-inl.h``); on TPU the analogue is Pallas: kernels
-that tile HBM->VMEM explicitly and feed the MXU. This module provides the
-first such kernel — a fused linear layer (tiled matmul + bias + activation
-in one VMEM-resident pass) used by FullyConnected when shapes are
-tile-aligned — plus the availability plumbing shared by future kernels
-(conv/pool/attention).
-
-Gradients route through ``jax.custom_vjp``: the backward matmuls are plain
-XLA (already MXU-optimal); only the fused forward is hand-written.
-
-Every kernel goes through :func:`pallas_call`, which decides interpreter
-vs Mosaic per LOWERING: a computation lowered for the CPU gets the Pallas
-interpreter (so the whole path is testable without hardware), one lowered
+:func:`pallas_call` is the one way in. It decides interpreter or Mosaic
+per LOWERING: a computation lowered for the CPU gets the Pallas
+interpreter (so every kernel is testable without hardware), one lowered
 for a TPU gets the compiled kernel, and nothing in the process can flip
-that.
+that. ``rtc`` compiles a user's kernel source through it.
+
+Four families of kernels follow, each written from a trace of the
+benchmark cell it aimed at and each with a line in the ledger there
+(``docs/pallas.md``): the state-space scan's chunk kernels (``SSMScan``),
+the gated delta rule's with a decay a head or a key channel
+(``GatedDeltaRule``), the routed experts' grouped products
+(``RoutedExperts``) and the pass that takes the splash attention kernel
+its operands (``CausalAttention``). Each has both passes written out (the
+operator joins them under one ``jax.custom_vjp``) and an ``*_applicable``
+rule over shapes; the operator chooses the kernel or its ``jax.numpy``
+body when the node is traced and counts the choice (``lower.*``).
 """
 from __future__ import annotations
 
 import collections
 import functools
 import types
-from typing import Optional
 
 import numpy as np
 
 from .. import env as _env
 
-__all__ = ["fused_linear", "flash_attention", "pallas_available",
-           "pallas_call", "conv2d", "conv_dgrad", "conv_wgrad",
-           "conv_backward_applicable", "fused_norm_act",
-           "norm_act_applicable", "ssd_chunk_applicable",
+__all__ = ["pallas_available", "pallas_call", "ssd_chunk_applicable",
            "ssd_chunk_forward", "ssd_chunk_backward",
            "delta_chunk_applicable", "delta_channel_applicable",
            "delta_chunk_forward", "delta_chunk_backward",
-           "attention_relayout"]
+           "grouped_experts_applicable", "grouped_experts_forward",
+           "grouped_experts_backward", "attention_relayout"]
 
-# float32 MXU-friendly tiles (sublane 8, lane 128)
-TILE_M = 128
+# lanes of a tile
 TILE_N = 128
-TILE_K = 128
 
 
 @functools.lru_cache(None)
@@ -72,654 +68,6 @@ def pallas_call(kernel, *operands, **kw):
 
     return jax.lax.platform_dependent(*operands, cpu=lowered(True),
                                       default=lowered(False))
-
-
-def _linear_call(x, w_t, bias, act: str):
-    """Tiled (M,K)x(K,N) matmul with fused bias+activation epilogue."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    m, k = x.shape
-    _, n = w_t.shape
-    grid = (m // TILE_M, n // TILE_N, k // TILE_K)
-    nk = grid[2]
-
-    def kernel(x_ref, w_ref, b_ref, o_ref):
-        kk = pl.program_id(2)
-
-        @pl.when(kk == 0)
-        def _():
-            o_ref[:] = jnp.zeros_like(o_ref)
-        o_ref[:] += jnp.dot(x_ref[:], w_ref[:],
-                            preferred_element_type=jnp.float32)
-
-        @pl.when(kk == nk - 1)
-        def _():
-            acc = o_ref[:] + b_ref[:]
-            if act == "relu":
-                acc = jnp.maximum(acc, 0.0)
-            elif act == "tanh":
-                acc = jnp.tanh(acc)
-            elif act == "sigmoid":
-                acc = jax.nn.sigmoid(acc)
-            o_ref[:] = acc
-
-    return pallas_call(
-        kernel, x, w_t, bias,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_M, TILE_K), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((TILE_K, TILE_N), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, TILE_N), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((TILE_M, TILE_N), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-    )
-
-
-def fused_linear(x, weight, bias=None, act: str = "none") -> Optional[object]:
-    """out = act(x @ weight.T + bias) via the Pallas kernel.
-
-    ``weight`` uses the framework layout (num_hidden, in_dim). Returns None
-    when the kernel does not apply (shape misalignment / pallas missing) —
-    callers fall back to the XLA path.
-    """
-    if not pallas_available():
-        return None
-    import jax
-    import jax.numpy as jnp
-
-    m, k = x.shape
-    n = weight.shape[0]
-    if (m % TILE_M or k % TILE_K or n % TILE_N
-            or x.dtype != jnp.float32 or weight.dtype != jnp.float32):
-        return None
-    b = bias if bias is not None else jnp.zeros((n,), jnp.float32)
-
-    @jax.custom_vjp
-    def f(x, w, b):
-        return _linear_call(x, w.T, b.reshape(1, n), act)
-
-    def f_fwd(x, w, b):
-        out = f(x, w, b)
-        return out, (x, w, b, out)
-
-    def f_bwd(res, g):
-        x, w, b, out = res
-        if act == "relu":
-            g = jnp.where(out > 0, g, 0.0)
-        elif act == "tanh":
-            g = g * (1.0 - out * out)
-        elif act == "sigmoid":
-            g = g * out * (1.0 - out)
-        gx = jnp.dot(g, w)
-        gw = jnp.dot(g.T, x)
-        gb = jnp.sum(g, axis=0)
-        return gx, gw, gb
-
-    f.defvjp(f_fwd, f_bwd)
-    return f(x, weight, b)
-
-
-# ---------------------------------------------------------------------------
-# Flash attention
-# ---------------------------------------------------------------------------
-
-BLOCK_Q = 128
-BLOCK_K = 128
-_NEG_INF = -1e30
-
-
-def _flash_call(q, k, v, scale: float, causal: bool):
-    """Online-softmax tiled attention. q/k/v: (BH, T, D) float32.
-
-    The cuDNN-class fused kernel of this framework (the reference's GPU
-    fast path was cudnn_*-inl.h): one pass over K/V blocks per Q block,
-    carrying running max / normalizer / weighted accumulator in VMEM
-    scratch, so the (T, T) score matrix never materializes in HBM.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bh, t, d = q.shape
-    grid = (bh, t // BLOCK_Q, t // BLOCK_K)
-    nk = grid[2]
-
-    def kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
-        ik = pl.program_id(2)
-
-        @pl.when(ik == 0)
-        def _():
-            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        iq = pl.program_id(1)
-
-        def body():
-            s = jax.lax.dot_general(
-                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (BQ, BK)
-            if causal:
-                row = iq * BLOCK_Q + jax.lax.broadcasted_iota(
-                    jnp.int32, (BLOCK_Q, BLOCK_K), 0)
-                col = ik * BLOCK_K + jax.lax.broadcasted_iota(
-                    jnp.int32, (BLOCK_Q, BLOCK_K), 1)
-                s = jnp.where(row >= col, s, _NEG_INF)
-
-            m_prev = m_ref[:, :1]                          # (BQ, 1)
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)                         # (BQ, BK)
-            alpha = jnp.exp(m_prev - m_new)                # (BQ, 1)
-            l_ref[:, :1] = (l_ref[:, :1] * alpha
-                            + p.sum(axis=-1, keepdims=True))
-            m_ref[:, :1] = m_new
-            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-                p, v_ref[0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        if causal:
-            # blocks fully above the diagonal contribute nothing; skip
-            # their MXU work (their DMA is already pipelined by pallas)
-            @pl.when(iq * BLOCK_Q // BLOCK_K >= ik)
-            def _():
-                body()
-        else:
-            body()
-
-        @pl.when(ik == nk - 1)
-        def _():
-            o_ref[0] = acc_ref[:] / l_ref[:, :1]
-
-    return pallas_call(
-        kernel, q, k, v,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda b, iq, ik: (b, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK_Q, d), lambda b, iq, ik: (b, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((BLOCK_Q, 128), jnp.float32),   # running max
-            pltpu.VMEM((BLOCK_Q, 128), jnp.float32),   # running normalizer
-            pltpu.VMEM((BLOCK_Q, d), jnp.float32),     # weighted accumulator
-        ],
-    )
-
-
-def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None) -> Optional[object]:
-    """Fused attention over (B, T, H, D) inputs (layout shared with
-    :mod:`mxnet_tpu.parallel.ring_attention`).
-
-    Returns None when the kernel does not apply (seq len not a multiple
-    of the 128 block, non-f32, pallas unavailable) — callers fall back to
-    the XLA reference path. Backward recomputes through the reference
-    attention (rematerialization: the O(T^2) probs never hit HBM in fwd).
-    """
-    if not pallas_available():
-        return None
-    import jax
-    import jax.numpy as jnp
-
-    b, t, h, d = q.shape
-    if (t % BLOCK_Q or t % BLOCK_K or q.dtype != jnp.float32
-            or d > 256):
-        return None
-    if scale is None:
-        scale = 1.0 / float(np.sqrt(d))
-
-    def _pack(x):   # (B, T, H, D) -> (B*H, T, D)
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-
-    def _unpack(x):
-        return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-
-    @jax.custom_vjp
-    def f(q, k, v):
-        return _unpack(_flash_call(_pack(q), _pack(k), _pack(v),
-                                   scale, causal))
-
-    def _ref(q, k, v):
-        # recompute path shares the single attention oracle, pinned to
-        # the kernel's scale and finite mask value
-        from ..parallel.ring_attention import reference_attention
-
-        return reference_attention(q, k, v, causal=causal, scale=scale,
-                                   mask_value=_NEG_INF)
-
-    def f_fwd(q, k, v):
-        return f(q, k, v), (q, k, v)
-
-    def f_bwd(res, g):
-        q, k, v = res
-        _, vjp = jax.vjp(_ref, q, k, v)
-        return vjp(g)
-
-    f.defvjp(f_fwd, f_bwd)
-    return f(q, k, v)
-
-
-# ---------------------------------------------------------------------------
-# conv backward: dgrad + wgrad as MXU-shaped matmuls over im2col tiles
-# ---------------------------------------------------------------------------
-#
-# xprof's op-category breakdown pins the fused ResNet step on the conv
-# backward (ROADMAP item 1), which XLA lowers as transposed convs. Here
-# both halves become plain tiled matmuls — the shape the MXU actually
-# is — over im2col patches:
-#
-#   wgrad:  gw = patches(x)^T @ g      (K*K*C, N*HO*WO) x (N*HO*WO, O)
-#   dgrad:  dx = patches(g~) @ w~      (N*H*W, K*K*O)   x (K*K*O, C)
-#
-# where g~ is g stride-dilated + edge-padded and w~ the spatially
-# flipped, O<->C-swapped kernel (the standard transposed-conv algebra).
-# Patch extraction is a handful of strided slices XLA fuses into the
-# operand feed; the MXU work runs in the Pallas kernels below with
-# bf16-or-f32 inputs and f32 accumulation. Tile sizes are parameters —
-# the autotuner (mxnet_tpu/autotune.py) measures candidates per chip.
-
-_DEF_TILES = (128, 128, 128)
-
-
-def _tiles_ok(tiles) -> bool:
-    # both matmul kernels place every tile dimension on either the MXU
-    # lane axis (128) or a sublane axis fed from one; 128-multiples
-    # everywhere keep one rule valid for f32 and bf16 operand tiles
-    return (len(tiles) == 3
-            and all(t > 0 and t % 128 == 0 for t in tiles))
-
-
-def _matmul(a, b, tiles, transpose_a=False):
-    """Tiled matmul with f32 accumulation: ``a @ b`` or ``a.T @ b``.
-
-    ``transpose_a`` contracts on ``a``'s FIRST axis without ever
-    materializing the transpose — the wgrad shape (patches^T @ g) — so
-    the only data movement is the tile feed itself. Inputs may be bf16
-    (MXU-native) or f32; the accumulator and output are f32.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    tm, tn, tk = tiles
-    if transpose_a:
-        k, m = a.shape
-    else:
-        m, k = a.shape
-    _, n = b.shape
-    grid = (m // tm, n // tn, k // tk)
-    nk = grid[2]
-
-    def kernel(a_ref, b_ref, o_ref):
-        kk = pl.program_id(2)
-
-        @pl.when(kk == 0)
-        def _():
-            o_ref[:] = jnp.zeros_like(o_ref)
-        if transpose_a:
-            o_ref[:] += jax.lax.dot_general(
-                a_ref[:], b_ref[:], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            o_ref[:] += jnp.dot(a_ref[:], b_ref[:],
-                                preferred_element_type=jnp.float32)
-
-    a_spec = (pl.BlockSpec((tk, tm), lambda i, j, kk: (kk, i))
-              if transpose_a
-              else pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)))
-    return pallas_call(
-        kernel, a, b,
-        grid=grid,
-        in_specs=[a_spec,
-                  pl.BlockSpec((tk, tn), lambda i, j, kk: (kk, j))],
-        out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-    )
-
-
-def _patches(x, kh, kw, stride):
-    """im2col over an already-padded NHWC tensor: (N, Hp, Wp, C) ->
-    (N*HO*WO, KH*KW*C), minor order (kh, kw, c) — the flattening of
-    ``w.transpose(2, 3, 1, 0)`` so the matmul contracts correctly."""
-    import jax
-    import jax.numpy as jnp
-
-    n, hp, wp, c = x.shape
-    sh, sw = stride
-    ho = (hp - kh) // sh + 1
-    wo = (wp - kw) // sw + 1
-    cols = []
-    for i in range(kh):
-        for j in range(kw):
-            cols.append(jax.lax.slice(
-                x, (0, i, j, 0),
-                (n, i + (ho - 1) * sh + 1, j + (wo - 1) * sw + 1, c),
-                (1, sh, sw, 1)))
-    p = jnp.stack(cols, axis=3)          # (N, HO, WO, KH*KW, C)
-    return p.reshape(n * ho * wo, kh * kw * c)
-
-
-def _cast_in(x, compute_dtype):
-    import jax.numpy as jnp
-
-    return x.astype(compute_dtype) if compute_dtype is not None \
-        and x.dtype != compute_dtype else x
-
-
-def conv_backward_applicable(x_shape, w_shape, stride, pad, dilate,
-                             num_group, tiles=_DEF_TILES) -> bool:
-    """Static (trace-time) applicability of the Pallas conv-backward
-    pair for a 2D conv. Every condition is a shape/param fact, so the
-    decision costs nothing per dispatch. ``x_shape`` is NHWC."""
-    if not pallas_available() or not _tiles_ok(tiles):
-        return False
-    if len(x_shape) != 4 or len(w_shape) != 4 or num_group != 1:
-        return False
-    if tuple(dilate) != (1, 1):
-        return False
-    n, h, w, c = x_shape
-    o, ci, kh, kw = w_shape
-    sh, sw = stride
-    ph, pw = pad
-    if ci != c or ph > kh - 1 or pw > kw - 1:
-        return False
-    if (h + 2 * ph - kh) % sh or (w + 2 * pw - kw) % sw:
-        return False   # dgrad's dilate+pad inversion is only exact here
-    ho = (h + 2 * ph - kh) // sh + 1
-    wo = (w + 2 * pw - kw) // sw + 1
-    tm, tn, tk = tiles
-    return not (n * h * w % tm or c % tn or kh * kw * o % tk      # dgrad
-                or kh * kw * c % tm or o % tn or n * ho * wo % tk  # wgrad
-                )
-
-
-def conv_dgrad(w, g, x_shape, stride, pad, tiles=_DEF_TILES,
-               compute_dtype=None):
-    """Input gradient of a 2D conv as one tiled matmul.
-
-    ``w`` OIHW, ``g`` NHWC output cotangent, ``x_shape`` the NHWC primal
-    shape. Returns dx (NHWC, primal dtype) or None when the shapes don't
-    tile. ``compute_dtype`` (e.g. bf16) casts the matmul operands; the
-    accumulator stays f32 either way.
-    """
-    if not pallas_available():
-        return None
-    import jax.numpy as jnp
-
-    n, h, wd, c = x_shape
-    o, _, kh, kw = w.shape
-    sh, sw = stride
-    ph, pw = pad
-    if not conv_backward_applicable(x_shape, w.shape, stride, pad,
-                                    (1, 1), 1, tiles):
-        return None
-    ho = (h + 2 * ph - kh) // sh + 1
-    wo = (wd + 2 * pw - kw) // sw + 1
-    if (sh, sw) != (1, 1):
-        gd = jnp.zeros((n, (ho - 1) * sh + 1, (wo - 1) * sw + 1, o),
-                       g.dtype)
-        gd = gd.at[:, ::sh, ::sw, :].set(g)
-    else:
-        gd = g
-    gp = jnp.pad(gd, ((0, 0), (kh - 1 - ph,) * 2, (kw - 1 - pw,) * 2,
-                      (0, 0)))
-    pat = _patches(gp, kh, kw, (1, 1))            # (N*H*W, KH*KW*O)
-    wt = w[:, :, ::-1, ::-1].transpose(2, 3, 0, 1).reshape(kh * kw * o, c)
-    dx = _matmul(_cast_in(pat, compute_dtype),
-                 _cast_in(wt, compute_dtype), tiles)
-    return dx.reshape(n, h, wd, c).astype(g.dtype)
-
-
-def conv_wgrad(x, g, w_shape, stride, pad, tiles=_DEF_TILES,
-               compute_dtype=None):
-    """Weight gradient of a 2D conv as one tiled ``patches^T @ g``
-    matmul (the transpose is folded into the kernel's tile feed, never
-    materialized). ``x``/``g`` NHWC, returns gw in OIHW, or None."""
-    if not pallas_available():
-        return None
-    import jax.numpy as jnp
-
-    o, c, kh, kw = w_shape
-    ph, pw = pad
-    if not conv_backward_applicable(x.shape, w_shape, stride, pad,
-                                    (1, 1), 1, tiles):
-        return None
-    xp = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-    pat = _patches(xp, kh, kw, stride)            # (N*HO*WO, KH*KW*C)
-    gm = g.reshape(-1, o)
-    gw = _matmul(_cast_in(pat, compute_dtype),
-                 _cast_in(gm, compute_dtype), tiles, transpose_a=True)
-    return gw.reshape(kh, kw, c, o).transpose(3, 2, 0, 1).astype(g.dtype)
-
-
-def conv2d(x, w, bias=None, stride=(1, 1), pad=(0, 0), dilate=(1, 1),
-           num_group=1, nhwc=False, tiles=_DEF_TILES,
-           compute_dtype=None):
-    """2D convolution whose *backward* runs the Pallas dgrad/wgrad
-    kernels. The forward stays ``lax.conv_general_dilated`` — XLA's
-    forward conv already saturates the MXU (docs/pallas.md policy); it
-    is the backward, which XLA lowers as transposed convs, that the
-    profile blames. Returns None when the kernels do not apply (shape
-    misalignment, groups, dilation) — callers keep the XLA path.
-    """
-    if not pallas_available():
-        return None
-    import jax
-    import jax.numpy as jnp
-
-    x_nhwc_shape = x.shape if nhwc \
-        else (x.shape[0], x.shape[2], x.shape[3], x.shape[1])
-    if not conv_backward_applicable(x_nhwc_shape, w.shape, stride, pad,
-                                    dilate, num_group, tiles):
-        return None
-
-    dn = ("NHWC", "OIHW", "NHWC") if nhwc else ("NCHW", "OIHW", "NCHW")
-    pads = [(p, p) for p in pad]
-
-    def _fwd_conv(x, w):
-        return jax.lax.conv_general_dilated(
-            x, w, window_strides=stride, padding=pads,
-            dimension_numbers=dn,
-            preferred_element_type=x.dtype
-            if x.dtype == jnp.float32 else None)
-
-    @jax.custom_vjp
-    def f(x, w):
-        return _fwd_conv(x, w)
-
-    def f_fwd(x, w):
-        return f(x, w), (x, w)
-
-    def f_bwd(res, g):
-        x, w = res
-        xh = x if nhwc else x.transpose(0, 2, 3, 1)
-        gh = g if nhwc else g.transpose(0, 2, 3, 1)
-        dx = conv_dgrad(w, gh, xh.shape, stride, pad, tiles,
-                        compute_dtype)
-        gw = conv_wgrad(xh, gh, w.shape, stride, pad, tiles,
-                        compute_dtype)
-        if dx is None or gw is None:  # pragma: no cover - pre-checked
-            _, vjp = jax.vjp(_fwd_conv, x, w)
-            return vjp(g)
-        if not nhwc:
-            dx = dx.transpose(0, 3, 1, 2)
-        return dx.astype(x.dtype), gw.astype(w.dtype)
-
-    f.defvjp(f_fwd, f_bwd)
-    out = f(x, w)
-    if bias is not None:
-        bshape = (1, 1, 1, -1) if nhwc else (1, -1, 1, 1)
-        out = out + bias.reshape(bshape)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# fused norm + activation (BN scale/shift + ReLU, forward and backward)
-# ---------------------------------------------------------------------------
-#
-# BatchNorm's apply step is a per-channel scale/shift (the statistics
-# are folded beforehand, ops/nn.py); its backward in XLA re-reads the
-# activations twice (dx, then the per-channel reductions). Both
-# directions here are one VMEM pass each: forward computes
-# act(x*scale+shift) in f32; backward recomputes the pre-activation
-# (cheaper than storing the mask), masks the cotangent, and emits dx
-# plus the per-channel dscale/dshift partial sums in the same pass.
-
-NORM_BLOCK_ROWS = 128
-_NORM_BLOCK_C = 128
-
-
-def norm_act_applicable(shape, dtype, block_rows=NORM_BLOCK_ROWS) -> bool:
-    """Static applicability: channels-last tensor whose row count tiles
-    ``block_rows`` and whose channel count tiles the 128 lane axis."""
-    if not pallas_available():
-        return False
-    import jax.numpy as jnp
-
-    # the row tile must fill whole sublane tiles: 8 rows of f32, 16 of
-    # bf16 (which packs two rows per 32-bit sublane)
-    sublane = {jnp.dtype(jnp.float32): 8,
-               jnp.dtype(jnp.bfloat16): 16}.get(jnp.dtype(dtype))
-    if sublane is None or len(shape) < 2 or block_rows <= 0 \
-            or block_rows % sublane:
-        return False
-    c = shape[-1]
-    rows = 1
-    for d in shape[:-1]:
-        rows *= d
-    return not (rows % block_rows or c % _NORM_BLOCK_C)
-
-
-def _norm_act_fwd_call(x2, scale, shift, act, block_rows):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    r, c = x2.shape
-    grid = (r // block_rows, c // _NORM_BLOCK_C)
-
-    def kernel(x_ref, sc_ref, sh_ref, o_ref):
-        y = (x_ref[:].astype(jnp.float32) * sc_ref[:]
-             + sh_ref[:])
-        if act == "relu":
-            y = jnp.maximum(y, 0.0)
-        o_ref[:] = y.astype(o_ref.dtype)
-
-    return pallas_call(
-        kernel, x2, scale, shift,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, _NORM_BLOCK_C),
-                         lambda i, j: (i, j)),
-            pl.BlockSpec((1, _NORM_BLOCK_C), lambda i, j: (0, j)),
-            pl.BlockSpec((1, _NORM_BLOCK_C), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, _NORM_BLOCK_C),
-                               lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, c), x2.dtype),
-    )
-
-
-def _norm_act_bwd_call(x2, scale, shift, g2, act, block_rows):
-    """One pass: dx + per-channel dscale/dshift partials. The row-tile
-    axis is the LAST grid dimension so the (1, C) reduction outputs
-    accumulate sequentially across row tiles (same revisit rule as the
-    matmul K axis)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    r, c = x2.shape
-    grid = (c // _NORM_BLOCK_C, r // block_rows)
-
-    def kernel(x_ref, sc_ref, sh_ref, g_ref, dx_ref, dsc_ref, dsh_ref):
-        i = pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _():
-            dsc_ref[:] = jnp.zeros_like(dsc_ref)
-            dsh_ref[:] = jnp.zeros_like(dsh_ref)
-        x = x_ref[:].astype(jnp.float32)
-        ge = g_ref[:].astype(jnp.float32)
-        if act == "relu":
-            pre = x * sc_ref[:] + sh_ref[:]
-            ge = jnp.where(pre > 0.0, ge, 0.0)
-        dx_ref[:] = (ge * sc_ref[:]).astype(dx_ref.dtype)
-        dsc_ref[:] += jnp.sum(ge * x, axis=0, keepdims=True)
-        dsh_ref[:] += jnp.sum(ge, axis=0, keepdims=True)
-
-    return pallas_call(
-        kernel, x2, scale, shift, g2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, _NORM_BLOCK_C),
-                         lambda j, i: (i, j)),
-            pl.BlockSpec((1, _NORM_BLOCK_C), lambda j, i: (0, j)),
-            pl.BlockSpec((1, _NORM_BLOCK_C), lambda j, i: (0, j)),
-            pl.BlockSpec((block_rows, _NORM_BLOCK_C),
-                         lambda j, i: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, _NORM_BLOCK_C),
-                         lambda j, i: (i, j)),
-            pl.BlockSpec((1, _NORM_BLOCK_C), lambda j, i: (0, j)),
-            pl.BlockSpec((1, _NORM_BLOCK_C), lambda j, i: (0, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, c), g2.dtype),
-            jax.ShapeDtypeStruct((1, c), jnp.float32),
-            jax.ShapeDtypeStruct((1, c), jnp.float32),
-        ],
-    )
-
-
-def fused_norm_act(x, scale, shift, act: str = "none",
-                   block_rows: int = NORM_BLOCK_ROWS):
-    """``act(x * scale + shift)`` with per-channel scale/shift over the
-    last (channels) axis, forward and backward each one fused kernel.
-
-    ``x`` is any-rank channels-last (bf16 or f32); ``scale``/``shift``
-    are per-channel vectors. Math runs in f32 regardless of input dtype
-    (bf16 compute, f32 accumulate); the output is cast back to
-    ``x.dtype``. Returns None when the kernel does not apply — callers
-    fall back to the XLA elementwise path. ``block_rows`` is the tuned
-    row-tile knob (site ``norm_act`` in mxnet_tpu/autotune.py).
-    """
-    if act not in ("none", "relu"):
-        return None
-    if not norm_act_applicable(x.shape, x.dtype, block_rows):
-        return None
-    import jax
-    import jax.numpy as jnp
-
-    c = x.shape[-1]
-    sc = scale.astype(jnp.float32).reshape(1, c)
-    sh = shift.astype(jnp.float32).reshape(1, c)
-
-    @jax.custom_vjp
-    def f(x, sc, sh):
-        return _norm_act_fwd_call(x.reshape(-1, c), sc, sh, act,
-                                  block_rows).reshape(x.shape)
-
-    def f_fwd(x, sc, sh):
-        return f(x, sc, sh), (x, sc, sh)
-
-    def f_bwd(res, g):
-        x, sc, sh = res
-        dx, dsc, dsh = _norm_act_bwd_call(
-            x.reshape(-1, c), sc, sh, g.reshape(-1, c), act, block_rows)
-        return (dx.reshape(x.shape).astype(x.dtype),
-                dsc.reshape(sc.shape), dsh.reshape(sh.shape))
-
-    f.defvjp(f_fwd, f_bwd)
-    out = f(x, sc, sh)
-    return out
 
 
 # ---------------------------------------------------------------------------

@@ -32,20 +32,17 @@ __all__ = ["ring_attention", "ulysses_attention", "reference_attention",
            "make_ring_attention"]
 
 
-def reference_attention(q, k, v, causal: bool = False, scale=None,
-                        mask_value=-np.inf):
-    """Plain full attention (B, T, H, D) — the correctness oracle (also
-    the recompute path for the Pallas flash kernel's VJP, which passes
-    its own scale and finite mask_value)."""
+def reference_attention(q, k, v, causal: bool = False):
+    """Plain full attention (B, T, H, D) — the correctness oracle, and
+    Ulysses' local attention."""
     import jax.numpy as jnp
 
-    if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         t_q, t_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((t_q, t_k), dtype=bool))
-        logits = jnp.where(mask, logits, mask_value)
+        logits = jnp.where(mask, logits, -np.inf)
     probs = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -129,14 +126,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False):
                               tiled=True)
 
     qf, kf, vf = scatter_heads(q), scatter_heads(k), scatter_heads(v)
-    # local full-sequence attention: Pallas flash kernel when the shapes
-    # tile, XLA reference otherwise
-    from ..ops.pallas_kernels import flash_attention
-
-    out = flash_attention(qf, kf, vf, causal=causal)
-    if out is None:
-        out = reference_attention(qf, kf, vf, causal=causal)
-    return gather_heads(out)
+    return gather_heads(reference_attention(qf, kf, vf, causal=causal))
 
 
 def make_ring_attention(mesh, axis_name: str = "sp", causal: bool = False,

@@ -24,6 +24,8 @@ from mxnet_tpu.ops import attention, moe, seq
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import bailing_hybrid as ref  # noqa: E402
+from test_hlo_gates import (check_products_are_bfloat16,  # noqa: E402
+                            check_state_is_donated, lower_language_toy)
 from test_glm4_moe_lite import _attention_over  # noqa: E402
 from test_nemotron_h import (Ring, against, aux_states, close,  # noqa: E402
                              rng_inputs, run_op)
@@ -672,3 +674,32 @@ def test_reference_imports_nothing_of_the_program():
 
     src = inspect.getsource(ref)
     assert "mxnet_tpu" not in src.replace("mxnet_tpu/optimizer.py", "")
+
+
+# ---------------------------------------------------------------------------
+# the toy preset's fused step, from its lowering (tests/test_hlo_gates.py)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def toy_step():
+    return lower_language_toy("ling3_flash_l6_e8of512_bf16.json",
+                              get_bailing_hybrid(**TOY),
+                              *toy_batches(1, toy=TOY)[0])
+
+
+def test_the_toy_step_donates_every_master_moment_and_state(toy_step):
+    check_state_is_donated(*toy_step)
+
+
+def test_the_toy_step_takes_bfloat16_products_but_where_named(toy_step):
+    check_products_are_bfloat16(*toy_step[:2], {
+        # ``GatedDeltaRule`` computes in float32 whatever the compute
+        # dtype (a bfloat16 operand is another result: ``ops/seq.py``)
+        "seq": 94,
+        # the router's scores, float32 from the normed rows (a choice of
+        # experts is discontinuous: ``moe.route``): forward, recomputed,
+        # and the two gradients, a layer of experts
+        "RoutedExperts": 12,
+        # toy widths take ``attend_blockwise``, whose backward pass takes
+        # the float32 scores' cotangent against operands widened to it; the
+        # cells' heads take the splash kernel (tests/test_cell_lowering.py)
+        "attention": 4})

@@ -80,12 +80,25 @@ def test_train_phase_fails_when_the_loss_does_not_fall():
                                steps=2, fused=True, lr=0.0)
 
 
-def test_kernel_phase_through_the_interpreter():
-    results = chip_smoke.kernel_phase(jax.devices()[0], small=True)
-    assert {"fused_linear relu", "fused_linear tanh", "flash_attention",
-            "flash_attention_causal", "conv2d (conv_dgrad + conv_wgrad)",
-            "fused_norm_act float32", "fused_norm_act bfloat16",
-            "rtc axpy"} <= set(results)
+@pytest.mark.parametrize("name", chip_smoke.KERNEL_CASES)
+def test_kernel_phase_through_the_interpreter(name):
+    """A case a kernel, at the least shapes its rule admits: the kernel
+    (interpreted here) against the ``jax.numpy`` body, forward and every
+    gradient."""
+    results = chip_smoke.kernel_phase(jax.devices()[0], small=True,
+                                      only=(name,))
+    assert set(results) == {name}
+
+
+def test_kernel_phase_names_the_case_that_fails(monkeypatch):
+    from mxnet_tpu.ops import seq
+
+    real = seq.ssd_scan
+    monkeypatch.setattr(seq, "ssd_scan", lambda *a: real(*a) * (
+        1.1 if a[-1] else 1.0))
+    with pytest.raises(AssertionError, match="ssd_scan: AssertionError"):
+        chip_smoke.kernel_phase(jax.devices()[0], small=True,
+                                only=("rtc axpy", "ssd_scan"))
 
 
 @pytest.mark.multichip

@@ -99,36 +99,106 @@ def _lowered_text(fn, args, platform):
         lowering_platforms=(platform,)).as_text()
 
 
-@pytest.mark.parametrize("name", ["fused_norm_act", "fused_linear", "rtc"])
+def _kernel_case(name):
+    """``(fn, args)`` of one shipped kernel at the smallest shapes its
+    ``*_applicable`` rule admits (float32 ones; a case that the rule
+    stops admitting fails here, not on the chip)."""
+    f32 = jnp.dtype("float32")
+
+    def ones(*shape):
+        return jnp.ones(shape, f32)
+
+    if name == "rtc":
+        def body(x_ref, o_ref):
+            o_ref[:] = x_ref[:] * 2.0
+        return (lambda x: pk.pallas_call(
+            body, x, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype)),
+            (ones(128, 128),))
+    if name.startswith("ssd_chunk"):
+        # two 64-wide heads share a 128-lane unit; one group, one chunk
+        dims, chunk = (2, 64, 1, 128), 128
+        assert pk.ssd_chunk_applicable(dims, chunk, f32)
+        assert not pk.ssd_chunk_applicable((1, 64, 1, 128), chunk, f32)
+        xbc, dt = ones(1, chunk, 2 * 64 + 2 * 128), ones(1, chunk, 2) * 0.1
+        a_head, d_skip = -ones(2), ones(2)
+        if name == "ssd_chunk_forward":
+            return (lambda *a: pk.ssd_chunk_forward(
+                *a, dims=dims, chunk=chunk, with_states=True),
+                (xbc, dt, a_head, d_skip))
+        starts = jnp.zeros((1, 1, 1, 128, 128), f32)
+        return (lambda *a: pk.ssd_chunk_backward(*a, dims=dims, chunk=chunk),
+                (xbc, dt, a_head, d_skip, starts, ones(1, chunk, 128)))
+    if name.startswith("delta_chunk"):
+        channel = name.endswith("channel")
+        # a decay a head: 8 keys, 8 values, a chunk of one sublane tile; a
+        # decay a channel: 128 keys, a chunk of one 16-position sub-chunk
+        dk, dv, chunk = (128, 8, 16) if channel else (8, 8, 8)
+        rule = pk.delta_channel_applicable if channel \
+            else pk.delta_chunk_applicable
+        assert rule((1, dk, dv), chunk, f32)
+        assert not rule((1, dk, dv), chunk // 2, f32)
+        q = ones(1, chunk, 1, dk) * dk ** -0.5
+        g = -ones(1, chunk, 1, dk) * 0.1 if channel \
+            else -ones(1, chunk, 1) * 0.1
+        args = (q, q, ones(1, chunk, 1, dv), g, ones(1, chunk, 1) * 0.5)
+        if "forward" in name:
+            return (lambda *a: pk.delta_chunk_forward(
+                *a, chunk=chunk, with_states=True), args)
+        return (lambda *a: pk.delta_chunk_backward(*a, chunk=chunk),
+                args + (jnp.zeros((1, 1, 1, dk, dv), f32),
+                        ones(1, chunk, 1, dv)))
+    if name.startswith("grouped_experts"):
+        # one expert of 128 -> 8 -> 128 over one block of 8 slots
+        gated = name.endswith("gated")
+        h, f, block = 128, 8, 8
+        assert pk.grouped_experts_applicable(h, f, block, f32, gated, block)
+        assert not pk.grouped_experts_applicable(h, f, block // 2, f32,
+                                                 gated, block)
+        ws = (ones(1, h, f) * 0.1,) * (2 if gated else 1) \
+            + (ones(1, f, h) * 0.1,)
+        layout = (jnp.arange(block, dtype=jnp.int32), ones(block),
+                  jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32))
+        if "forward" in name:
+            return (lambda x, *ws: pk.grouped_experts_forward(
+                x, ws, *layout, gated=gated), (ones(block, h),) + ws)
+        return (lambda x, dy, *ws: pk.grouped_experts_backward(
+            x, ws, *layout, dy, gated=gated),
+            (ones(block, h), ones(block, h)) + ws)
+    assert name.startswith("attention_relayout")
+    from mxnet_tpu.ops import attention
+
+    # two 64-wide heads, one 128-lane tile, turned whole; ``back``: the
+    # way of the kernel's result and of every cotangent
+    t, heads, d = 128, 2, 64
+    tables = tuple(jnp.asarray(a) for a in
+                   attention.relayout_tables(t, 1e4, d // 2, d))
+    back = name.endswith("back")
+    return (lambda x: pk.attention_relayout(
+        x, tables, batch=1, heads=heads, half=d // 2, scale=0.125,
+        back=back),
+        (ones(1, heads, t, d) if back else ones(t, heads * d),))
+
+
+@pytest.mark.parametrize("name", [
+    "rtc", "ssd_chunk_forward", "ssd_chunk_backward", "delta_chunk_forward",
+    "delta_chunk_forward_channel", "delta_chunk_backward",
+    "delta_chunk_backward_channel", "grouped_experts_forward",
+    "grouped_experts_forward_gated", "grouped_experts_backward",
+    "attention_relayout", "attention_relayout_back"])
 def test_kernels_lower_to_mosaic_for_tpu_and_the_interpreter_for_cpu(name):
     """The interpret decision is taken per lowering: the same traced call
     becomes a Mosaic custom call when lowered for a TPU and interpreter
-    HLO when lowered for the CPU — no process-wide answer to flip."""
-    x = jnp.ones((128, 128), jnp.float32)
-    v = jnp.ones((128,), jnp.float32)
-    if name == "fused_norm_act":
-        fn, args = (lambda x, s, b: pk.fused_norm_act(x, s, b, act="relu"),
-                    (x, v, v))
-    elif name == "fused_linear":
-        fn, args = (lambda x, w, b: pk.fused_linear(x, w, b)), (x, x, v)
-    else:
-        def body(x_ref, o_ref):
-            o_ref[:] = x_ref[:] * 2.0
-        fn, args = (lambda x: pk.pallas_call(
-            body, x, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))), (x,)
+    HLO when lowered for the CPU — no process-wide answer to flip. A case
+    a kernel the train path ships (the four families a cell has timed and
+    ``rtc``'s entry), at the smallest shapes its rule admits."""
+    fn, args = _kernel_case(name)
     tpu = _lowered_text(fn, args, "tpu")
     cpu = _lowered_text(fn, args, "cpu")
     assert "tpu_custom_call" in tpu
     assert "tpu_custom_call" not in cpu
     # and the CPU lowering really computes
-    out = jax.jit(fn)(*args)
-    assert np.isfinite(np.asarray(out)).all()
-
-
-def test_bf16_norm_act_needs_sixteen_row_tiles():
-    assert pk.norm_act_applicable((64, 128), jnp.float32, 8)
-    assert not pk.norm_act_applicable((64, 128), jnp.bfloat16, 8)
-    assert pk.norm_act_applicable((64, 128), jnp.bfloat16, 16)
+    for out in jax.tree_util.tree_leaves(jax.jit(fn)(*args)):
+        assert np.isfinite(np.asarray(out, np.float32)).all()
 
 
 def test_native_library_older_than_its_sources_is_rebuilt(caplog,

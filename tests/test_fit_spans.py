@@ -45,6 +45,11 @@ def _iter(steps, batch):
 
 
 def _fit(monkeypatch, fused, steps=6, batch=4, mod=None):
+    from mxnet_tpu import xprof
+
+    # telemetry's switch decides: an ``xprof.disable()`` that a test of
+    # another file left behind on this worker does not
+    monkeypatch.setattr(xprof, "_override", None)
     if fused:
         monkeypatch.setenv("MXNET_TPU_FUSED_STEP", "1")
     else:

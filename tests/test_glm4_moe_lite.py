@@ -24,6 +24,8 @@ from mxnet_tpu.models import (get_glm4_moe_lite, get_nemotron_h,
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import glm4_moe_lite as ref  # noqa: E402
 from benchmark.reference import nemotron_h, olmo_hybrid  # noqa: E402
+from test_hlo_gates import (check_products_are_bfloat16,  # noqa: E402
+                            check_state_is_donated, lower_language_toy)
 from test_nemotron_h import (Ring, against, aux_states, close,  # noqa: E402
                              rng_inputs, run_op)
 from test_nemotron_h import TOY as NEMOTRON_TOY  # noqa: E402
@@ -753,3 +755,29 @@ def test_the_models_that_share_the_operators_lower_as_they_did(monkeypatch,
     text = _step_text(monkeypatch, factory(**toy), params0,
                       toy_batches(1, toy=toy))
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TEXT[model]
+
+
+# ---------------------------------------------------------------------------
+# the toy preset's fused step, from its lowering (tests/test_hlo_gates.py)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def toy_step():
+    return lower_language_toy("glm47_flash_l6_e8of64_bf16.json",
+                              get_glm4_moe_lite(**TOY),
+                              *toy_batches(1, toy=TOY)[0])
+
+
+def test_the_toy_step_donates_every_master_moment_and_state(toy_step):
+    check_state_is_donated(*toy_step)
+
+
+def test_the_toy_step_takes_bfloat16_products_but_where_named(toy_step):
+    check_products_are_bfloat16(*toy_step[:2], {
+        # the router's scores, float32 from the normed rows (a choice of
+        # experts is discontinuous: ``moe.route``): forward, recomputed,
+        # and the two gradients, a layer of experts
+        "RoutedExperts": 8,
+        # toy widths take ``attend_blockwise``, whose backward pass takes
+        # the float32 scores' cotangent against operands widened to it; the
+        # cells' heads take the splash kernel (tests/test_cell_lowering.py)
+        "attention": 12})

@@ -21,6 +21,8 @@ from mxnet_tpu.models import get_lfm2_moe
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import lfm2_moe as ref  # noqa: E402
+from test_hlo_gates import (check_products_are_bfloat16,  # noqa: E402
+                            check_state_is_donated, lower_language_toy)
 from test_glm4_moe_lite import _attention_over  # noqa: E402
 from test_nemotron_h import (Ring, against, aux_states, close,  # noqa: E402
                              rng_inputs, run_op)
@@ -580,3 +582,29 @@ def test_step_cost_counts_the_configurations_parts():
     assert part("update", "", "") == "optimizer"
     assert part("fwd", "FullyConnected", "lm_head") == "lm_head_loss"
     assert part("fwd", "Embedding", "embed") == "other:Embedding"
+
+
+# ---------------------------------------------------------------------------
+# the toy preset's fused step, from its lowering (tests/test_hlo_gates.py)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def toy_step():
+    return lower_language_toy("lfm2_24b_a2b_e8of64_bf16.json",
+                              get_lfm2_moe(**TOY),
+                              *toy_batches(1, toy=TOY)[0])
+
+
+def test_the_toy_step_donates_every_master_moment_and_state(toy_step):
+    check_state_is_donated(*toy_step)
+
+
+def test_the_toy_step_takes_bfloat16_products_but_where_named(toy_step):
+    check_products_are_bfloat16(*toy_step[:2], {
+        # the router's scores, float32 from the normed rows (a choice of
+        # experts is discontinuous: ``moe.route``): forward, recomputed,
+        # and the two gradients, a layer of experts
+        "RoutedExperts": 11,
+        # toy widths take ``attend_blockwise``, whose backward pass takes
+        # the float32 scores' cotangent against operands widened to it; the
+        # cells' heads take the splash kernel (tests/test_cell_lowering.py)
+        "attention": 4})

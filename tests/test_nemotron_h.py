@@ -19,6 +19,8 @@ from mxnet_tpu.ops import moe as moe_ops
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import nemotron_h as ref  # noqa: E402
+from test_hlo_gates import (check_products_are_bfloat16,  # noqa: E402
+                            check_state_is_donated, lower_language_toy)
 
 TOY = dict(pattern="MEMEMEM*E", hidden=32, vocab=96, experts_total=16,
            experts_held=4, first_expert=4, seq_len=24, mamba_heads=4,
@@ -1173,3 +1175,28 @@ def test_recomputation_plan_keeps_by_budget_and_matches_plain(budget):
     assert 9 <= seen["remat.kept_results"] <= 29
     if budget == 40000:
         assert 9 < seen["remat.kept_results"] < 29
+
+
+# ---------------------------------------------------------------------------
+# the toy preset's fused step, from its lowering (tests/test_hlo_gates.py)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def toy_step():
+    return lower_language_toy("nemotron3_nano_l9_e8of128_bf16.json",
+                              get_nemotron_h(**TOY), *toy_batches(1)[0])
+
+
+def test_the_toy_step_donates_every_master_moment_and_state(toy_step):
+    check_state_is_donated(*toy_step)
+
+
+def test_the_toy_step_takes_bfloat16_products_but_where_named(toy_step):
+    check_products_are_bfloat16(*toy_step[:2], {
+        # the router's scores, float32 from the normed rows (a choice of
+        # experts is discontinuous: ``moe.route``): forward, recomputed,
+        # and the two gradients, a layer of experts
+        "RoutedExperts": 16,
+        # toy widths take ``attend_blockwise``, whose backward pass takes
+        # the float32 scores' cotangent against operands widened to it; the
+        # cells' heads take the splash kernel (tests/test_cell_lowering.py)
+        "attention": 4})

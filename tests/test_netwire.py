@@ -297,6 +297,36 @@ def test_net_partition_fails_fast_then_reconnects(tel, no_faults):
         srv.close()
 
 
+def test_a_lost_connections_late_reader_spares_the_redialled_ones_requests(
+        no_faults):
+    """A connection's reader notices its loss up to a receive timeout
+    after the sender did: by then the slot has redialled and holds the
+    next request, which is not the old connection's to fail (on a loaded
+    worker the partition test above lost its second request this way)."""
+    srv = _echo_server()
+    client = WireClient(srv.host, srv.port, peer="echo", pool=1)
+    try:
+        assert client.call("infer", timeout_s=10.0).op == "ok"
+        slot = client._conns[0]
+        old = slot._conn
+        faults.configure("net_partition")
+        with pytest.raises(WirePeerLost):
+            client.request("infer")
+        faults.configure("net_drop")       # the next request stays pending
+        w = client.request("infer")
+        faults.configure(None)
+        assert slot._conn is not old and w.conn is slot._conn
+        slot._fail_pending(old)             # the old reader, arriving late
+        assert not w.done() and client.pending_count() == 1
+        slot._fail_pending(slot._conn)      # its own connection's loss
+        with pytest.raises(WirePeerLost):
+            w.wait(1.0)
+    finally:
+        faults.configure(None)
+        client.close()
+        srv.close()
+
+
 def test_net_drop_times_out_without_leaking_pending(no_faults):
     srv = _echo_server()
     client = WireClient(srv.host, srv.port, peer="echo", pool=1)

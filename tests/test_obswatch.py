@@ -338,8 +338,12 @@ def test_obswatch_survives_dead_replica(tmp_path):
 def test_obswatch_over_real_inproc_fleet(tmp_path):
     """The scraper against a real router + InProc replicas: served
     counters federate and the router-view latency headline exists."""
+    # no attempt is given up while its replica compiles its first batch
+    # (500 ms by default: a loaded worker retried four of the eight, and
+    # both attempts were served)
     router = fleet.FleetRouter(fleet.in_process(fleet.demo_server_factory),
-                               2, health_interval_s=0.02)
+                               2, health_interval_s=0.02,
+                               attempt_timeout_ms=30e3, deadline_ms=60e3)
     try:
         import numpy as np
         x = np.zeros((1, 8), dtype=np.float32)

@@ -20,6 +20,8 @@ from mxnet_tpu.models import olmo_hybrid as model
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import olmo_hybrid as ref  # noqa: E402
 from test_nemotron_h import Ring, against, close, rng_inputs  # noqa: E402
+from test_hlo_gates import (check_products_are_bfloat16,  # noqa: E402
+                            check_state_is_donated, lower_language_toy)
 
 TOY = dict(layer_types=["linear_attention", "linear_attention",
                         "linear_attention", "full_attention"],
@@ -389,3 +391,28 @@ def test_cost_of_the_cut_by_hand():
     assert cost["flops"] == 3 * fwd and cost["recompute_flops"] == fwd
     assert cost["state_bytes"] == cost["params"] * 30
     assert 33e12 < cost["flops"] < 38e12
+
+
+# ---------------------------------------------------------------------------
+# the toy preset's fused step, from its lowering (tests/test_hlo_gates.py)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def toy_step():
+    return lower_language_toy("olmo_hybrid_l4_headshare_bf16.json",
+                              get_olmo_hybrid(**TOY),
+                              *toy_batches(1, toy=TOY)[0])
+
+
+def test_the_toy_step_donates_every_master_moment_and_state(toy_step):
+    check_state_is_donated(*toy_step)
+
+
+def test_the_toy_step_takes_bfloat16_products_but_where_named(toy_step):
+    check_products_are_bfloat16(*toy_step[:2], {
+        # ``GatedDeltaRule`` computes in float32 whatever the compute
+        # dtype (a bfloat16 operand is another result: ``ops/seq.py``)
+        "seq": 94,
+        # toy widths take ``attend_blockwise``, whose backward pass takes
+        # the float32 scores' cotangent against operands widened to it; the
+        # cells' heads take the splash kernel (tests/test_cell_lowering.py)
+        "attention": 4})

@@ -1,82 +1,14 @@
-"""Pallas kernel + RTC tests (interpret mode on CPU; the same code paths
-compile natively on TPU)."""
-import os
-
-import jax.numpy as jnp
-
+"""RTC tests: a kernel compiled at run time from its source through
+``pallas_kernels.pallas_call`` (the interpreter on the CPU; the same
+code compiles through Mosaic on a TPU)."""
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import symbol as sym
-from mxnet_tpu.ops.pallas_kernels import fused_linear, pallas_available
+from mxnet_tpu.ops.pallas_kernels import pallas_available
 
 pytestmark = pytest.mark.skipif(not pallas_available(),
                                 reason="pallas unavailable")
-
-
-def test_fused_linear_matches_xla():
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(128, 256).astype(np.float32))
-    w = jnp.asarray(rng.randn(128, 256).astype(np.float32))
-    b = jnp.asarray(rng.randn(128).astype(np.float32))
-    out = fused_linear(x, w, b)
-    assert out is not None
-    expected = np.asarray(x) @ np.asarray(w).T + np.asarray(b)
-    np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-4,
-                               atol=1e-4)
-    # fused relu epilogue
-    out_relu = fused_linear(x, w, b, act="relu")
-    np.testing.assert_allclose(np.asarray(out_relu),
-                               np.maximum(expected, 0), rtol=1e-4, atol=1e-4)
-
-
-def test_fused_linear_gradients():
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.randn(128, 128).astype(np.float32))
-    w = jnp.asarray(rng.randn(128, 128).astype(np.float32))
-    b = jnp.asarray(rng.randn(128).astype(np.float32))
-
-    def loss_pallas(x, w, b):
-        return jnp.sum(fused_linear(x, w, b, act="relu") ** 2)
-
-    def loss_ref(x, w, b):
-        return jnp.sum(jnp.maximum(x @ w.T + b, 0) ** 2)
-
-    g1 = jax.grad(loss_pallas, argnums=(0, 1, 2))(x, w, b)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(x, w, b)
-    for a, e in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e), rtol=1e-3,
-                                   atol=1e-3)
-
-
-def test_fused_linear_misaligned_falls_back():
-    import jax.numpy as jnp
-
-    x = jnp.zeros((5, 7), jnp.float32)
-    w = jnp.zeros((3, 7), jnp.float32)
-    assert fused_linear(x, w) is None
-
-
-def test_fused_linear_matches_fc():
-    """fused_linear stays correct even though the FC hot path is XLA
-    (the MXNET_TPU_PALLAS gate was retired on measured data —
-    docs/pallas.md)."""
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(0)
-    x = rng.randn(128, 256).astype(np.float32)
-    w = rng.randn(128, 256).astype(np.float32)
-    b = rng.randn(128).astype(np.float32)
-    out = fused_linear(jnp.asarray(x), jnp.asarray(w), jnp.asarray(b))
-    assert out is not None
-    np.testing.assert_allclose(np.asarray(out), x @ w.T + b, rtol=1e-4,
-                               atol=1e-3)
 
 
 def test_rtc_kernel():
@@ -114,48 +46,3 @@ def test_rtc_bad_source():
     out = mx.nd.zeros((4, 4))
     with pytest.raises(Exception):
         Rtc("bad", [("x", x)], [("out", out)], "this is not python !!!")
-
-
-def test_flash_attention_matches_reference():
-    from mxnet_tpu.ops import pallas_kernels as pk
-    from mxnet_tpu.parallel.ring_attention import reference_attention
-
-    rng = np.random.RandomState(0)
-    B, T, H, D = 2, 256, 2, 64
-    q = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-    k = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-    for causal in (False, True):
-        out = pk.flash_attention(q, k, v, causal=causal)
-        assert out is not None
-        ref = reference_attention(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-5)
-
-
-def test_flash_attention_grads():
-    import jax
-    from mxnet_tpu.ops import pallas_kernels as pk
-    from mxnet_tpu.parallel.ring_attention import reference_attention
-
-    rng = np.random.RandomState(1)
-    B, T, H, D = 1, 128, 2, 32
-    q = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-    k = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
-    g = jax.grad(lambda q, k, v: (pk.flash_attention(q, k, v, causal=True)
-                                  ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: (reference_attention(q, k, v, causal=True)
-                                   ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-4)
-
-
-def test_flash_attention_fallback():
-    from mxnet_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.RandomState(2)
-    # T not a multiple of the block -> caller must fall back
-    q = jnp.asarray(rng.randn(1, 100, 2, 32).astype(np.float32))
-    assert pk.flash_attention(q, q, q) is None

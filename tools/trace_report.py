@@ -260,82 +260,6 @@ def _table(rows):
     return out
 
 
-def _strike(s):
-    """Strike-through via the unicode combining long stroke: invalid
-    rows stay visible in the table (the fence's whole point is that
-    bad measurements are shown refuted, not silently dropped)."""
-    return "".join(ch + "̶" for ch in s)
-
-
-def load_tune_rows(path):
-    """Autotuner rows from the .jsonl `autotune.record()` appends to
-    (``experiment: autotune:<site>:<cand>``).
-    Unparseable lines are skipped, same contract as load_records."""
-    rows = []
-    try:
-        with open(path) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                if isinstance(rec, dict) and rec.get("site") \
-                        and str(rec.get("experiment",
-                                        "")).startswith("autotune:"):
-                    rows.append(rec)
-    except OSError:
-        pass
-    return rows
-
-
-def render_tune(rows):
-    """Winners/losers table per autotune site: candidate, config,
-    measured step time, analytic MFU, and the status column (BEST /
-    prune reason). Rows the validate() gate rejects render
-    struck-through with the reason — never dropped."""
-    if not rows:
-        return ("no autotune rows (`mxnet_tpu.autotune.run_smoke(path)` "
-                "writes them)\n")
-    try:
-        sys.path.insert(0, _repo_root())
-        from mxnet_tpu.autotune import validate
-    except Exception:   # a box without the package: trust the stored tags
-        def validate(row):
-            return None
-    out = []
-    for site in sorted({r["site"] for r in rows}):
-        srows = [r for r in rows if r["site"] == site]
-        out.append("site %s (%d candidates)" % (site, len(srows)))
-        table = [("candidate", "config", "step_ms", "mfu_pct", "status")]
-        for r in srows:
-            step = ("%.3f" % r["step_time_ms"]
-                    if r.get("step_time_ms") is not None else "-")
-            mfu = ("%.2f" % r["analytic_mfu_pct"]
-                   if r.get("analytic_mfu_pct") is not None else "-")
-            if r.get("pruned"):
-                status = "pruned: %s" % r["pruned"]
-            elif r.get("best"):
-                status = "BEST"
-            else:
-                status = ""
-            cells = (str(r.get("candidate", "?")),
-                     json.dumps(r.get("config", {}), sort_keys=True),
-                     step, mfu, status)
-            reason = validate(r)
-            if reason is None and r.get("valid") is False:
-                reason = r.get("invalid_reason") or "tagged invalid"
-            if reason:
-                cells = tuple(_strike(c) for c in cells[:4]) \
-                    + ("INVALID: %s" % reason,)
-            table.append(cells)
-        out.extend(_table(table))
-        out.append("")
-    return "\n".join(out) + "\n"
-
-
 def render_bench_summary(rec):
     """The one-line "analytic vs measured MFU, gap attributed to
     <category>" headline for the top of the bench report."""
@@ -1213,7 +1137,7 @@ def main(argv=None):
     p.add_argument("--view", default="steps",
                    choices=("steps", "compile", "ops", "memory", "bench",
                             "serve", "fleet", "fleet-health", "wire",
-                            "tune", "waterfall", "numerics"),
+                            "waterfall", "numerics"),
                    help="steps (default): slowest-step trace table; "
                         "compile/ops/memory/bench: xprof views over a "
                         "file of records that carry an xprof summary; "
@@ -1223,8 +1147,7 @@ def main(argv=None):
                         "transport per-peer table + netfeed epoch over "
                         "a fleet record; fleet-health: federated rollup "
                         "table + burn-rate verdict over an obswatch "
-                        "artifact; tune: autotuner winners/losers per "
-                        "site from the .jsonl autotune.record() wrote; "
+                        "artifact; "
                         "waterfall: one kept distributed trace as an "
                         "indented span tree (path = a chrome-trace "
                         "file, or a trace id resolved against "
@@ -1265,10 +1188,6 @@ def main(argv=None):
         return 0
     if a.path is None:
         p.error("path is required")
-    if a.view == "tune":
-        rows = load_tune_rows(a.path)
-        sys.stdout.write(render_tune(rows))
-        return 0 if rows else 1
     if a.view == "serve":
         rec = latest_serve_record(load_bench_records(a.path))
         if rec is None:
