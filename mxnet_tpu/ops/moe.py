@@ -33,14 +33,32 @@ has no reverse-mode autodiff. An imbalanced router makes one expert's
 group long, never short of a row (an expert may draw every row: its column
 is a row long).
 
-Router scores (sigmoid, no softmax), selection and combine weights are
-float32 at ``highest`` matmul precision whatever the compute dtype: the
-choice of experts is a discontinuous function of the scores. The combine
-weights are the chosen scores over their sum plus ``norm_eps`` (the
+Router scores, selection and combine weights are float32 at ``highest``
+matmul precision whatever the compute dtype: the choice of experts is a
+discontinuous function of the scores. ``score_func`` names the scores:
+``"sigmoid"`` (the default: one sigmoid an expert, the DeepSeek line's) or
+``"softmax"`` over ALL the experts' logits (Qwen3-Next's 512; the chosen
+probabilities over their sum are then its ``norm_topk_prob`` weights). The
+combine weights are the chosen scores over their sum plus ``norm_eps`` (the
 families differ: ``1e-20``, the default, in the DeepSeek line that the
-Nemotron and GLM models follow, ``1e-6`` in ``lfm2_moe``), times ``scale``.
-The expert products take inputs in the compute dtype and accumulate in
-float32.
+Nemotron and GLM models follow, ``1e-6`` in ``lfm2_moe``; under a softmax
+the sum is at least ``top_k / num_experts`` and ``1e-20`` is below float32's
+last bit of it), times ``scale``. The expert products take inputs in the
+compute dtype and accumulate in float32.
+
+**The auxiliary load-balancing loss** (``aux_loss_coef`` c > 0; the
+families that route by softmax balance by it and have no selection bias):
+the step's loss is ``CE + c sum_layers L_aux``, ``L_aux = E sum_e f_e P_e``
+(:func:`aux_loss`: ``f_e`` the share of the rows that chose expert ``e``,
+no gradient through it, ``P_e`` the mean score; over ALL ``E`` experts, held
+or not, and over the rows this node sees: in an expert-parallel layout the
+loads and the means would be summed over the chips first, and on one chip's
+share they are its own rows', as the selection bias's loads are). It enters
+through the backward pass alone (:func:`aux_loss_gradient`, an identity on
+the scores whose cotangent gains ``c E load_e / S^2`` in every row), the way
+the families' training code adds it: no second head, no output, nothing
+fetched, the metric stays the cross-entropy; with c = 0 (the default) the
+node traces the program it always did.
 
 **Group-limited choice** (``n_group`` > 1; DeepSeek-V3, arXiv:2412.19437,
 section 2.1.2; the Ling model's 512 experts in 8 groups): the experts are
@@ -51,8 +69,7 @@ lie on at most ``topk_group`` groups' chips. The mask is float32 beside
 the scores and touches the choice alone: the combine weights are the
 chosen experts' unbiased scores as ever. ``n_group = topk_group = 1`` (the
 default) is no limit and the program the other models trace. What the op
-does NOT cover: softmax scoring, and an ``ep`` mesh axis with its exchange
-(ROADMAP Reach A2).
+does NOT cover: an ``ep`` mesh axis with its exchange (ROADMAP Reach A2).
 
 **Two expert bodies**, by ``gated``: ``W_down relu(W_up x)^2`` (the
 default; :func:`grouped_experts`) and the gated ``W_down (silu(W_gate x) *
@@ -60,8 +77,9 @@ W_up x)`` with a third stacked weight (:func:`grouped_experts_gated`), each
 with both passes written out. Routing, layout, balancing and the counters
 are one; which body a traced node took is counted
 (``lower.experts_body.relu2`` / ``lower.experts_body.swiglu``), beside how
-its products lower (``lower.experts_kernel.*``) and the layout's form
-(``lower.experts_plan.column_sort``, the one there is).
+its products lower (``lower.experts_kernel.*``), the layout's form
+(``lower.experts_plan.column_sort``, the one there is) and the scores
+(``lower.experts_score.sigmoid`` / ``.softmax``).
 
 **The selection bias is a state, not a weight.** ``select_bias`` (float32,
 ``num_experts``) is added to the scores for the choice only and no gradient
@@ -113,20 +131,26 @@ def layout_length(rows, top_k, num_held, block):
 
 
 def route(x, router, select_bias, top_k, scale, keep=lambda v: v,
-          norm_eps=1e-20, n_group=1, topk_group=1):
+          norm_eps=1e-20, n_group=1, topk_group=1, score_func="sigmoid",
+          aux_loss_coef=0.0):
     """``x [S, h]`` -> (expert ids ``[S, k]`` int32, combine weights
-    ``[S, k]`` float32): scores ``sigmoid(x W_r)``; the ``top_k`` largest
-    of ``scores + select_bias``, with ``n_group`` > 1 inside the
+    ``[S, k]`` float32): scores ``sigmoid(x W_r)`` or, ``score_func``
+    ``"softmax"``, ``softmax(x W_r)`` over all the experts; the ``top_k``
+    largest of ``scores + select_bias``, with ``n_group`` > 1 inside the
     ``topk_group`` groups whose two largest sum highest; weights the chosen
     scores over their sum plus ``norm_eps``, times ``scale``. ``keep``
     marks the ids where they are made: the weights' gradient reads them,
-    and must read the marked ones for a recomputation to skip the top-k."""
+    and must read the marked ones for a recomputation to skip the top-k.
+    ``aux_loss_coef`` > 0 adds the load-balancing loss's gradient to the
+    scores' (:func:`aux_loss_gradient`)."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(f32), router.astype(f32),
-                                    precision=jax.lax.Precision.HIGHEST))
+    logits = jnp.dot(x.astype(f32), router.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
+        else jax.nn.sigmoid(logits)
     choice = scores + select_bias.astype(f32)
     if n_group > 1:
         groups = choice.reshape(choice.shape[0], n_group, -1)
@@ -137,6 +161,9 @@ def route(x, router, select_bias, top_k, scale, keep=lambda v: v,
                            -jnp.inf).reshape(choice.shape)
     _, eid = jax.lax.top_k(choice, top_k)
     eid = keep(eid.astype(jnp.int32))
+    if aux_loss_coef:
+        scores = aux_loss_gradient(
+            scores, expert_load(eid, scores.shape[1]), aux_loss_coef)
     # the chosen scores by a compare against every expert, not a gather a
     # pair (nor, backward, a scatter-add): one value and zeros, the sum exact
     expert = jnp.arange(scores.shape[1], dtype=jnp.int32)
@@ -145,6 +172,50 @@ def route(x, router, select_bias, top_k, scale, keep=lambda v: v,
     wts = chosen / (jnp.sum(chosen, axis=1, keepdims=True) + norm_eps) \
         * scale
     return eid, wts
+
+
+def aux_loss(scores, load):
+    """The router's load-balancing loss of one layer (Fedus et al., Switch
+    Transformers, arXiv:2101.03961, equation 4, as the published modelling
+    code of the softmax-routed families writes it): ``E sum_e f_e P_e``,
+    ``f_e`` the rows that chose expert ``e`` over the rows (``load [E] /
+    S``: they sum to ``top_k``), ``P_e`` the mean of ``scores [S, E]``
+    over the rows; over ALL experts, held or not. 1 x ``top_k`` where both
+    are even."""
+    import jax.numpy as jnp
+
+    s, e = scores.shape
+    return e * jnp.sum(load.astype(jnp.float32) / s
+                       * jnp.mean(scores, axis=0))
+
+
+def aux_loss_gradient(scores, load, coef):
+    """``scores``, unchanged, whose gradient gains ``coef`` times
+    :func:`aux_loss`'s: the loss ``L = CE + coef sum_layers L_aux`` reaches
+    the router through the backward pass alone, as the families' training
+    code adds it (no second head, nothing fetched, the metric stays the
+    cross-entropy). ``f`` carries no gradient, so ``dL_aux / dscores[r, e]
+    = E load_e / S^2``: one row for all rows. The step's head gives the
+    gradient of the MEAN loss over tokens (``SoftmaxOutput(normalization=
+    "valid")``), which is the scale the sum is written in."""
+    import jax
+    import jax.numpy as jnp
+
+    s, e = scores.shape
+
+    @jax.custom_vjp
+    def f(scores, load):
+        return scores
+
+    def f_fwd(scores, load):
+        return scores, load
+
+    def f_bwd(load, d):
+        row = (coef * e / (s * s)) * load.astype(jnp.float32)
+        return d + row[None].astype(d.dtype), None
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(scores, load)
 
 
 def balance_step(bias, load, rate):
@@ -491,6 +562,11 @@ class RoutedExperts(Operator):
         "n_group": Param(int, 1, "groups of consecutive experts the choice "
                          "is limited by; 1: no limit"),
         "topk_group": Param(int, 1, "groups a row's experts may lie in"),
+        "score_func": Param(str, "sigmoid", "the router's scores: sigmoid "
+                            "an expert, or softmax over all of them"),
+        "aux_loss_coef": Param(float, 0.0, "weight of the load-balancing "
+                               "loss whose gradient the backward pass adds "
+                               "to the router's"),
     }
     # arguments that reach the op in their own dtype under mixed precision
     full_precision_args = ("router_weight",)
@@ -512,6 +588,12 @@ class RoutedExperts(Operator):
             raise MXNetError("RoutedExperts: experts %d..%d of %d, top_k %d"
                              % (self.first_held, self.first_held + held, e,
                                 self.top_k))
+        if self.score_func not in ("sigmoid", "softmax"):
+            raise MXNetError("RoutedExperts: score_func %r is neither "
+                             "'sigmoid' nor 'softmax'" % self.score_func)
+        if self.aux_loss_coef < 0:
+            raise MXNetError("RoutedExperts: aux_loss_coef %g is below 0"
+                             % self.aux_loss_coef)
         g, kept = self.n_group, self.topk_group
         if g < 1 or e % g or not 1 <= kept <= g or (
                 g > 1 and (e // g < 2 or kept * (e // g) < self.top_k)):
@@ -556,13 +638,15 @@ class RoutedExperts(Operator):
         e = self.num_experts
         keep = functools.partial(ctx.keep, result="routing")
         eid, wts = route(x, router, bias, self.top_k, self.scale, keep,
-                         self.norm_eps, self.n_group, self.topk_group)
+                         self.norm_eps, self.n_group, self.topk_group,
+                         self.score_func, self.aux_loss_coef)
         block = block_rows(x.shape[0], self.top_k, self.num_experts)
         *layout, dropped = plan(eid, wts, self.first_held, self.num_held,
                                 block)
         wts, rows, weights, slot, order, block_expert, nblocks = keep(
             (wts, *layout))
         _tel.inc("lower.experts_plan.column_sort")
+        _tel.inc("lower.experts_score.%s" % self.score_func)
         _tel.inc("lower.experts_body.%s"
                  % ("swiglu" if self.gated else "relu2"))
         layout = (wts, rows, weights, slot, order, block_expert, nblocks)

@@ -1012,7 +1012,7 @@ class GatedDeltaRule(Operator):
     """Linear attention by the gated delta rule over whole sequences,
     chunked (:func:`gated_delta_chunked`), beside ``SSMScan``. Per position
     and head, from the mixer's convolved and activated projections
-    ``query``, ``key`` ``[rows, H*K]``, ``value`` ``[rows, H*V]``, the step
+    ``query``, ``key`` ``[rows, Hk*K]``, ``value`` ``[rows, H*V]``, the step
     gate ``b`` ``[rows, H]`` and the decay gate ``a``, both before their
     nonlinearities:
 
@@ -1022,6 +1022,19 @@ class GatedDeltaRule(Operator):
     ``alpha = exp(g)``, ``g`` the log-decay (below);
     ``S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T``,
     ``o_t = S_t^T q_t``, ``S_0 = 0`` at each sequence's start.
+
+    **Key heads.** ``num_key_heads`` ``Hk`` (0, the default: ``H``) query/key
+    heads under ``H = num_heads`` value heads: value head ``j`` reads key head
+    ``j // (H / Hk)`` with its OWN decay and step gate, so the recurrence
+    runs ``H`` states of ``K x V`` (Qwen3-Next: 16 under 32). ``q`` and ``k``
+    are normalised once a key head and then REPEATED to the value heads
+    (``jnp.repeat`` on the float32 ``[B, T, Hk, K]``; the bodies and the
+    kernels see ``H`` heads as ever, and autodiff sums each pair's ``dq``,
+    ``dk``); with ``Hk = H`` nothing is repeated and the traced program is
+    the older models'. Counted ``lower.delta_rule_heads.grouped`` /
+    ``.equal`` a traced node. What is left: index maps that read key head
+    ``h // ratio`` inside the kernels, so that the repeated copies never
+    exist (ROADMAP Reach A3).
 
     **What the op covers.** The decay's SHAPE is read off ``a``: ``[rows,
     H]`` is one decay a head (Gated DeltaNet, arXiv:2412.06464; ``dt_bias
@@ -1068,6 +1081,9 @@ class GatedDeltaRule(Operator):
     name_hint = "gateddeltarule"
     PARAMS = {
         "num_heads": Param(int, REQUIRED),
+        "num_key_heads": Param(int, 0, "query/key heads, each read by "
+                               "num_heads / num_key_heads value heads; 0: "
+                               "num_heads"),
         "key_dim": Param(int, REQUIRED, "a head's query/key width"),
         "value_dim": Param(int, REQUIRED, "a head's value width"),
         "chunk": Param(int, 64),
@@ -1088,10 +1104,13 @@ class GatedDeltaRule(Operator):
         q, a = in_shapes[0], in_shapes[3]
         if q is None:
             raise MXNetError("GatedDeltaRule: query shape unknown")
-        h = self.num_heads
-        if q[1] != h * self.key_dim:
+        h, hk = self.num_heads, self.num_key_heads or self.num_heads
+        if hk < 1 or h % hk:
+            raise MXNetError("GatedDeltaRule: %d value heads do not share %d "
+                             "key heads evenly" % (h, hk))
+        if q[1] != hk * self.key_dim:
             raise MXNetError("GatedDeltaRule: query width %d is not %d heads "
-                             "of %d" % (q[1], h, self.key_dim))
+                             "of %d" % (q[1], hk, self.key_dim))
         if self.gate_floor > 0:
             raise MXNetError("GatedDeltaRule: gate_floor %g is above 0"
                              % self.gate_floor)
@@ -1122,9 +1141,20 @@ class GatedDeltaRule(Operator):
         q, k, v, a, b, a_log, dt_bias = (x.astype(f32) for x in inputs)
         t, h = self.seq_len, self.num_heads
         n = q.shape[0] // t
+        ratio = h // (self.num_key_heads or h)
+        _tel.inc("lower.delta_rule_heads.%s"
+                 % ("grouped" if ratio > 1 else "equal"))
 
         def heads(x):
             return x.reshape(n, t, h, -1)
+
+        def shared(x):
+            """``[B, T, Hk, K]`` -> ``[B, T, H, K]``: a key head once a
+            value head that reads it."""
+            return x if ratio == 1 else jnp.repeat(x, ratio, axis=2)
+
+        def key_heads(x):
+            return x.reshape(n, t, h // ratio, -1)
 
         def unit(x):
             return x * jax.lax.rsqrt(
@@ -1166,12 +1196,13 @@ class GatedDeltaRule(Operator):
 
             raw = inputs[:5]
             o = _channel_scan(
-                [heads(x) for x in raw[:4]] + [raw[4].reshape(n, t, h)],
+                [shared(key_heads(x)) for x in raw[:2]]
+                + [heads(x) for x in raw[2:4]] + [raw[4].reshape(n, t, h)],
                 (a_log, dt_bias.reshape(h, self.key_dim)), self.chunk,
                 prepare, raw[0].dtype)
             return [ctx.keep(o.reshape(n * t, -1), "output")], []
-        q = unit(heads(q)) * (self.key_dim ** -0.5)
-        k, v = unit(heads(k)), heads(v)
+        q = shared(unit(key_heads(q)) * (self.key_dim ** -0.5))
+        k, v = shared(unit(key_heads(k))), heads(v)
         if channel:
             g = gate(heads(a), a_log[:, None],
                      dt_bias.reshape(h, self.key_dim))

@@ -548,10 +548,12 @@ object SymbolOpsGen {
       b: Symbol = null,
       ALog: Symbol = null,
       dtBias: Symbol = null,
+      numKeyHeads: Int = 0,
       chunk: Int = 64,
       negEigval: Boolean = false,
       gateFloor: Float = 0.0f): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
+    ps += (("num_key_heads", numKeyHeads.toString))
     ps += (("chunk", chunk.toString))
     ps += (("neg_eigval", negEigval.toString))
     ps += (("gate_floor", gateFloor.toString))
@@ -845,7 +847,9 @@ object SymbolOpsGen {
       gated: Boolean = false,
       normEps: Float = 1e-20f,
       nGroup: Int = 1,
-      topkGroup: Int = 1): Symbol = {
+      topkGroup: Int = 1,
+      scoreFunc: String = "sigmoid",
+      auxLossCoef: Float = 0.0f): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("first_held", firstHeld.toString))
     ps += (("scale", scale.toString))
@@ -854,6 +858,8 @@ object SymbolOpsGen {
     ps += (("norm_eps", normEps.toString))
     ps += (("n_group", nGroup.toString))
     ps += (("topk_group", topkGroup.toString))
+    ps += (("score_func", scoreFunc.toString))
+    ps += (("aux_loss_coef", auxLossCoef.toString))
     ps += (("num_experts", numExperts.toString))
     ps += (("num_held", numHeld.toString))
     ps += (("top_k", topK.toString))

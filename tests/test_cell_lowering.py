@@ -51,6 +51,8 @@ CELLS = {
         "GatedShortConv": 7, "CausalAttention": 2, "RoutedExperts": 8},
     "ling3_flash_l6_e8of512_bf16": {
         "GatedDeltaRule": 5, "RoutedExperts": 5, "CausalAttention": 1},
+    "qwen3_next_l4_e32of512_bf16": {
+        "GatedDeltaRule": 3, "RoutedExperts": 4, "CausalAttention": 1},
 }
 
 
@@ -109,7 +111,8 @@ def test_a_cells_nodes_take_their_kernels_at_its_own_sizes(config):
     finally:
         telemetry.reset()
         telemetry.disable()
-    assert outs[0].shape[0] in (8192, cell["tokens"]["batch"])
+    assert outs[0].shape[0] in (8192 * cell["tokens"]["batch"],
+                                cell["tokens"]["batch"])
     assert counted == {name: held.get(op, 0)
                        for op, names in KERNEL.items() for name in names}
     assert fallen == dict.fromkeys(FALLBACKS, 0)

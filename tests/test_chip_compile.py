@@ -33,9 +33,10 @@ def one_chip():
     (5, 8, 8, 128, False),      # the narrowest widths at the longest chunk
     (16, 64, 64, 64, False),
     (32, 128, 128, 64, True),   # the Ling cell: a decay a key channel
-    (4, 128, 256, 64, True)],   # ... at the widest values
+    (4, 128, 256, 64, True),    # ... at the widest values
+    (32, 128, 128, 64, False)],  # the Qwen3-Next cell: 16 key heads repeated
     ids=["cell", "widest", "narrowest", "half-lanes", "channel-cell",
-         "channel-widest"])
+         "channel-widest", "grouped-heads-cell"])
 def test_delta_chunk_kernels_compile_for_the_chip(one_chip, heads, dk, dv,
                                                   chunk, channel):
     """Every shape ``delta_chunk_applicable`` (a decay a key ``channel``:
@@ -65,14 +66,15 @@ def test_delta_chunk_kernels_compile_for_the_chip(one_chip, heads, dk, dv,
 
 
 @pytest.mark.parametrize("h,group,d,dv", [
-    (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128)],
-    ids=["256-wide", "64-wide-grouped", "256-wide-keys-128-wide-values"])
+    (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128), (2, 8, 256, 256)],
+    ids=["256-wide", "64-wide-grouped", "256-wide-keys-128-wide-values",
+         "256-wide-grouped"])
 def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
     """The kernel call at three of the benchmark's cells: latent attention's
     20 one-head groups of 256 columns, LFM2's 8 groups of four heads of
-    64, half a lane tile, and Ling's 32 heads whose 192-wide keys go
-    widened to 256 beside values of 128, over 8,192 positions, forward and
-    backward."""
+    64, half a lane tile, Ling's 32 heads whose 192-wide keys go widened
+    to 256 beside values of 128, and Qwen3-Next's 2 groups of eight heads of
+    256, over 8,192 positions, forward and backward."""
     from mxnet_tpu.ops import attention
 
     b, t = 1, 8192
@@ -102,9 +104,11 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
     (1, 8192, 32, 64, 64, "bfloat16"),      # LFM2's queries: two heads a tile
     (1, 8192, 8, 64, 0, "bfloat16"),        # LFM2's values
     (2, 1024, 2, 64, 16, "float32"),        # a part of a 64-wide head
-    (1, 8192, 32, 256, 64, "bfloat16")],    # Ling's keys, widened from 192
+    (1, 8192, 32, 256, 64, "bfloat16"),     # Ling's keys, widened from 192
+    (1, 8192, 2, 256, 64, "bfloat16")],     # Qwen3-Next's keys: 2 heads
     ids=["glm", "nemotron", "plain", "two-tiles", "tile-and-a-half",
-         "one-pair", "lfm2", "lfm2-plain", "half-lanes-part", "ling"])
+         "one-pair", "lfm2", "lfm2-plain", "half-lanes-part", "ling",
+         "qwen3-next"])
 def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
                                                         heads, d, turned,
                                                         dtype):
@@ -158,6 +162,7 @@ EXPERT_CELLS = {
     "nemotron-cell": (8192, 2688, 1856, 8, 6, 128, False, "bfloat16"),
     "glm-cell": (8192, 2048, 1536, 8, 4, 64, True, "bfloat16"),
     "ling-cell": (8192, 2560, 768, 8, 8, 512, True, "bfloat16"),
+    "qwen3-next-cell": (8192, 2048, 512, 32, 10, 512, True, "bfloat16"),
     "narrowest-float32": (64, 128, 8, 2, 2, 4, True, "float32"),
     "narrowest-bfloat16": (128, 128, 16, 2, 2, 4, False, "bfloat16"),
 }
