@@ -235,7 +235,8 @@ def _fwd_bwd(fn, args):
 #: the kernel phase's cases: ``rtc``'s entry point and the four families of
 #: Pallas kernels the language cells run (``ops/pallas_kernels.py``)
 KERNEL_CASES = ("rtc axpy", "ssd_scan", "gated_delta_scan head",
-                "gated_delta_scan channel", "grouped_experts relu2",
+                "gated_delta_scan channel", "gated_delta_rows head",
+                "gated_delta_rows channel", "grouped_experts relu2",
                 "grouped_experts swiglu", "attention_relayout")
 
 
@@ -252,8 +253,11 @@ def _kernel_case(name, device, small):
     section 6): 1.93e-3 for the scan and 2.16e-3 / 2.26e-3 for the
     experts, whose float32 products pass the MXU at the default precision
     inside the kernel where the body's run at ``highest``; 3.9e-7 / 4.5e-7
-    for the delta rule, whose kernels ask for ``HIGHEST`` themselves; 0
-    for the relayout pass, which has no product."""
+    for the delta rule, whose kernels ask for ``HIGHEST`` themselves
+    (its row-major entry: 4.75e-6, PR 45, the largest over the result and
+    seven gradients: ``dt_bias``'s, a float32 sum over every row taken a
+    chunk at a time in the kernel); 0 for the relayout pass, which has no
+    product."""
     import jax
     import jax.numpy as jnp
 
@@ -302,6 +306,39 @@ def _kernel_case(name, device, small):
         return (lambda *a: seq.gated_delta_scan(*a, chunk, True),
                 lambda *a: seq.gated_delta_scan(*a, chunk, False), args,
                 2e-6)
+    if name.startswith("gated_delta_rows"):
+        # the row-major entry, from the op's rows before their
+        # normalisation: the Qwen3-Next cell's mixer (8 of its 32 value
+        # heads, two a key head, one decay a head) and the Ling cell's (8
+        # of 32, one decay a key channel through the bounded gate formed in
+        # the kernel), 128 keys and values, chunks of 64
+        channel = name.endswith("channel")
+        h, dk, dv, chunk, t = (2, 128, 128, 16, 32) if small \
+            else (8, 128, 128, 64, 1024)
+        hk, floor = (h, -5.0) if channel else (h // 2, 0.0)
+        spec = pk.DeltaRows(t, h, hk, dk, dv, chunk, floor, 1e-6)
+        assert pk.delta_rows_applicable((h, dk, dv), hk, chunk, t, channel)
+        args = (put(t, hk * dk), put(t, hk * dk), put(t, h * dv))
+        if channel:
+            args += (put(t, h * dk), jax.nn.sigmoid(put(1, t, h)),
+                     jnp.exp(put(1, h * dk, scale=0.5)),
+                     put(1, h * dk, shift=-1.0))
+        else:
+            args += (-jax.nn.softplus(put(1, t, h, shift=-2.0)),
+                     jax.nn.sigmoid(put(1, t, h)))
+
+        def body(query, key, value, gate, beta, scale=None, bias=None):
+            q, k = (jnp.repeat(unit(x.reshape(1, t, hk, dk)), h // hk, axis=2)
+                    for x in (query, key))
+            if channel:
+                gate = floor * jax.nn.sigmoid(scale * (gate + bias))
+                gate = gate.reshape(1, t, h, dk)
+            return seq.gated_delta_scan(
+                q * dk ** -0.5, k, value.reshape(1, t, h, dv), gate, beta,
+                chunk, False).reshape(t, h * dv)
+
+        return (lambda *a: seq.gated_delta_rows(
+            *a, *(None,) * (7 - len(a)), spec), body, args, 1.5e-5)
     if name.startswith("grouped_experts"):
         gated = name.endswith("swiglu")
         # 8 held experts of 16 drawn two a row: the Nemotron cell's

@@ -32,6 +32,8 @@ __all__ = ["pallas_available", "pallas_call", "ssd_chunk_applicable",
            "ssd_chunk_forward", "ssd_chunk_backward",
            "delta_chunk_applicable", "delta_channel_applicable",
            "delta_chunk_forward", "delta_chunk_backward",
+           "DeltaRows", "delta_rows_applicable", "delta_rows_forward",
+           "delta_rows_backward",
            "grouped_experts_applicable", "grouped_experts_forward",
            "grouped_experts_backward", "attention_relayout"]
 
@@ -461,10 +463,34 @@ def ssd_chunk_backward(*args, **static):
 #
 # One grid step is one chunk of a few heads of one sequence, the chunk axis
 # innermost and sequential; see ``seq.gated_delta_chunked`` for the chunk's
-# WY form. The arrays are read head-major, ``[B, H, T, K]`` (a block's last
-# dimension is the array's own, so a head's width need not be whole 128-lane
-# tiles: 96 and 192 are not), the per-position scalars with the positions
-# along the lanes. Inside a step, all in VMEM and per head: the cumulative
+# WY form. The kernels have TWO ENTRIES over one chunk body
+# (``_delta_chunk_tools``: ``forward_steps`` / ``backward_steps`` over
+# ``parts`` / ``channel_parts`` and ``head_scalar`` / ``head_channel``);
+# only where a head's rows come from and go to differs:
+#
+# - ROW-MAJOR (``_delta_rows_forward`` / ``_backward``; ``seq.
+#   gated_delta_rows``; ``delta_rows_applicable``: heads of whole lane
+#   tiles, 128 keys and 128 or 256 values, sequences of whole chunks): the
+#   op's own arrays ``[rows, Hk * K]`` / ``[rows, H * V]`` IN THE COMPUTE
+#   DTYPE, as the projections leave them, in blocks of ``(chunk, a step's
+#   heads x a head's lanes)``; a head is a static lane-aligned slice of
+#   the block. In VMEM, before the body: the cast to float32, ``q / |q| /
+#   sqrt(K)`` and ``k / |k|`` a KEY head (value head ``i`` reads key head
+#   ``i // ratio`` of the step's block, so shared key heads are never
+#   repeated in HBM), a channel's gate from ``a``, ``exp(A_log)`` and
+#   ``dt_bias`` in both forms of ``gate_floor``. After the backward body: a
+#   key head's ``dq``, ``dk`` summed over its value heads in float32, the
+#   normalisation's backward pass, the gate's, the stores. No float32 or
+#   head-major copy of a wide array exists on either side of the call.
+# - HEAD-MAJOR (``_delta_chunk_forward`` / ``_backward``; ``seq.
+#   gated_delta_scan``): float32 ``[B, H, T, K]`` arrays that the op casts,
+#   normalises, gates, repeats and transposes in XLA (a block's last
+#   dimension is the array's own, so a head's width need not be whole
+#   128-lane tiles: the Olmo cell's 96 and 192 are not).
+#
+# In both, the per-position scalars (the step gate and a head's decay, 1 MB
+# arrays) go with the positions along the lanes (``small``), formed in XLA.
+# Inside a step, all in VMEM and per head: the cumulative
 # log-decay and the masked decay matrix, ``K K^T`` and ``Q K^T``, ``A``, the
 # chunk's explicit ``T = (I + A)^-1``, ``u = T b (v - G K S)``, the output
 # ``G Q S + ((Q K^T) * decay) u`` and the state's update. Because the state
@@ -481,11 +507,14 @@ def ssd_chunk_backward(*args, **static):
 # for 64 (a twentieth of the backward kernel's time, none of the forward's:
 # at six passes a product the kernels are bound by the rows they stream).
 #
-# The differentiable function over them is ``seq.gated_delta_scan``. Its
-# residuals are the five float32 inputs and the float32 chunk-start states
-# ``[B, T/chunk, H, K, V]`` the forward kernel writes when asked; the
-# backward kernel forms everything else again. Under segment recomputation
-# the op so runs forward (no states), forward (with states), backward.
+# The differentiable function over them is ``seq.gated_delta_rows``
+# (row-major; residuals: the op's inputs as they came, which the segment
+# holds or recomputes anyway, and the float32 chunk-start states ``[B,
+# T/chunk, H, K, V]`` the forward kernel writes when asked) or ``seq.
+# gated_delta_scan`` (head-major; residuals: the five float32 head-major
+# inputs and the same states); the backward kernel forms everything else
+# again. Under segment recomputation the op so runs forward (no states),
+# forward (with states), backward.
 #
 # Precision (both kernels): everything is float32, as in the XLA body. Every
 # ``dot`` takes float32 operands at ``Precision.HIGHEST`` (Mosaic's
@@ -494,8 +523,17 @@ def ssd_chunk_backward(*args, **static):
 # substitution, the carried state, its gradient and every accumulator are
 # float32. The decay's gradient is ONE float32 matrix ``E = dP * P + dA *
 # A`` inside the chunk, summed along its rows (at t) and its columns (at i,
-# negative). The caller rounds the output to the compute dtype once,
-# outside.
+# negative). ROUNDINGS: head-major none (the caller rounds the output to
+# the compute dtype once, outside, and autodiff rounds the wide gradients
+# where it transposes the casts); row-major the same ones, at the stores:
+# ``o``, ``dquery``, ``dkey``, ``dvalue`` and a channel's ``da`` leave in the
+# compute dtype, each rounded once from its float32 value after the
+# normalisation's and the gate's backward passes; the casts from the
+# compute dtype are exact. ``A_log``'s and ``dt_bias``'s gradients are
+# float32 sums over every row: a channel's accumulate in float32 in VMEM
+# along the chunk axis (an output block resident across it) and are summed
+# over the sequences in XLA, never rounded; a head's are autodiff's over
+# the float32 ``dg [B, T, H]``, as ever.
 #
 # One decay a KEY CHANNEL (``g [B, T, H, K]``; ``seq.
 # gated_delta_chunked_channel`` has the algebra) runs through the same two
@@ -745,46 +783,203 @@ def _delta_chunk_tools(chunk):
                                           nn(t_inv, b_col * z)))
         return out
 
-    return types.SimpleNamespace(
-        nn=nn, nt=nt, tn=tn, both=both, tn_sum=tn_sum, masks=masks,
-        column=column, row=row, parts=parts, channel_parts=channel_parts,
-        by_sub=by_sub, square_eye=square_eye, triangle=triangle)
+    def forward_steps(hb, head_ops, channel, st_ref, keep_start, put_o):
+        """A grid step of the forward kernel, unrolled over its ``hb`` heads:
+        ``head_ops(i)`` gives head ``i``'s ``q, k, v, g, b_row, s`` (float32,
+        q and k normalised), ``put_o(i, o)`` takes its output and
+        ``keep_start(i, s)``, where given, its chunk-start state; the carried
+        state ``st_ref [hb, K, V]`` moves on. Where a head's rows come from
+        and go to is the caller's: both entries run this."""
+        heads = [head_ops(i) for i in range(hb)]
+        part = channel_parts if channel else parts
+        for i, (ops, c) in enumerate(zip(heads, part(heads, masks()))):
+            kh, s = ops[1], ops[5]
+            if keep_start is not None:
+                keep_start(i, s)
+            # a channel's exp(c) scales q before its product with the state
+            from_start = c.qs if channel else c.e_col * c.qs
+            put_o(i, from_start + nn(c.p, c.u))
+            st_ref[i] = c.e_last * s + tn(kh * c.w_col, c.u)
+
+    def backward_steps(hb, dk, head_ops, channel, ds_ref, io):
+        """A grid step of the backward kernel, unrolled over its ``hb``
+        heads, the mirror of :func:`forward_steps`: ``io.dy(i)`` is head
+        ``i``'s output cotangent ``[L, V]`` and ``io.dq`` / ``dk`` / ``dv``
+        ``(i, x)`` take the gradients of its (normalised) q, k and v,
+        ``io.db`` / ``io.dg`` those of its step gate and log-decay (rows
+        ``[1, L]``; a decay a key channel ``[L, K]``), all float32; the
+        state's gradient rides ``ds_ref [hb, K, V]``."""
+        masks3 = lower, strict, eye = masks()
+        at_last = jax.lax.broadcasted_iota(
+            jnp.int32, (chunk, 1), 0) == chunk - 1
+
+        def rows(x):
+            return jnp.sum(x, axis=1, keepdims=True)
+
+        if channel:
+            upto, eye_k = triangle(lower), square_eye(dk)
+
+        def head_scalar(i, ops, c):
+            qh, kh, s, ds, dy = ops[0], ops[1], ops[5], ds_ref[i], io.dy(i)
+            (b_col, e_col, w_col, e_last, decay, p, kkd, a, t_inv, qs, ks, z,
+             u) = c
+            # o = G Q S + P u, S_end = g_L S + (w k)^T u
+            du = tn(p, dy) + nn(kh * w_col, ds)
+            dr = tn(t_inv, du)                  # u = T R: dR = T^T du
+            da, dp = both(nt, dr, dy, u)        # dA = -dR u^T, below
+            da = -da
+            dz = b_col * dr                     # R = b (v - G K S)
+            dks = -e_col * dz
+            dyw = e_col * dy
+            uds = nt(u, ds)                     # d (w k)
+            g_kk = jnp.where(strict, da * b_col * decay, 0.0)
+            g_qk = dp * decay
+            at_q, at_k = both(nn, g_qk, g_kk, kh)
+            of_q, of_k = both(nt, dyw, dks, s)
+            io.dq(i, at_q + of_q)
+            io.dk(i, at_k + tn_sum(g_kk, kh, g_qk, qh) + of_k + w_col * uds)
+            io.dv(i, dz)
+            io.db(i, row(rows(dr * z) + rows(da * kkd), eye))
+            # the decay's gradient: ONE matrix by rows and by columns, then
+            # what the chunk's own scalings carry
+            e = dp * p + da * a
+            took = rows(dy * qs) * e_col + rows(dks * ks)   # d G, times G
+            gave = rows(uds * kh) * w_col                   # d w, times w
+            ends = jnp.sum(gave, axis=0, keepdims=True) \
+                + e_last * jnp.sum(rows(ds * s), axis=0, keepdims=True)
+            dc = rows(e) - column(jnp.sum(e, axis=0, keepdims=True), eye) \
+                + took - gave + jnp.where(at_last, ends, 0.0)
+            # g_j is in c_t for every t >= j of the chunk
+            io.dg(i, jnp.sum(jnp.where(lower, dc, 0.0), axis=0,
+                             keepdims=True))
+            ds_ref[i] = e_last * ds + tn_sum(kh, dks, qh, dyw)
+
+        def head_channel(i, ops, c):
+            """A decay a key channel. ``c [L, K]`` enters only as a factor
+            ``exp(+-c)`` of a row of q or k, and for an operand ``X *
+            exp(c)``, ``dc = X * dX``: the channel's gradient is ``q * dq``
+            and ``k * dk``, the latter signed by the side k stood on (times
+            ``exp(c)``: a block row of ``A`` or ``P``, ``(K * exp(c)) S``;
+            times ``exp(-c)``: the columns, ``K * exp(c_L - c)``), plus at
+            the last position what ``c_L`` carries."""
+            qh, kh, s, ds, dy = ops[0], ops[1], ops[5], ds_ref[i], io.dy(i)
+            (b_col, e_col, w_col, e_last, e_last_row, e_row, col_exp, p, kkd,
+             _, t_inv, qe, ke, _, _, z, u) = c
+            # o = (Q * E) S + P u, S_end = Diag(e_L) S + (w k)^T u
+            du = tn(p, dy) + nn(kh * w_col, ds)
+            dr = tn(t_inv, du)                  # u = T R: dR = T^T du
+            da, dp = both(nt, dr, dy, u)        # dA = -dR u^T, below
+            da = -da
+            dz = b_col * dr                     # R = b (v - (K * E) S)
+            g_kk = jnp.where(strict, da * b_col, 0.0)
+            g_qk = jnp.where(lower, dp, 0.0)
+            # the scores a block row: d [Q_s; K_s] by rows, d K by columns
+            qr, kr = qh * e_row, kh * e_row
+            at_q, at_k, at_col = [], [], None
+            for j, e in enumerate(col_exp):
+                top = jnp.concatenate([by_sub(g_qk, j), by_sub(g_kk, j)],
+                                      axis=0)
+                at_rows = nn(top, kh * e)
+                at_q.append(at_rows[:sub])
+                at_k.append(at_rows[sub:])
+                mine = e * tn(top, jnp.concatenate(
+                    [by_sub(qr, j), by_sub(kr, j)], axis=0))
+                at_col = mine if at_col is None else at_col + mine
+            of_q, of_k = both(nt, dy, -dz, s)   # d (Q * E), d (K * E)
+            gave = w_col * nt(u, ds)            # d (w k), times w
+            dq = e_row * jnp.concatenate(at_q, axis=0) + e_col * of_q
+            dk_row = e_row * jnp.concatenate(at_k, axis=0) + e_col * of_k
+            dk_col = at_col + gave
+            io.dq(i, dq)
+            io.dk(i, dk_row + dk_col)
+            io.dv(i, dz)
+            io.db(i, row(rows(dr * z) + rows(da * kkd), eye))
+            # c_L: in every w_t = exp(c_L - c_t), and in Diag(exp(c_L)) S
+            ends = jnp.sum(kh * gave, axis=0, keepdims=True) \
+                + e_last_row * row(rows(ds * s), eye_k)
+            dc = qh * dq + kh * (dk_row - dk_col) \
+                + jnp.where(at_last, ends, 0.0)
+            # g_j is in c_t for every t >= j of the chunk
+            io.dg(i, tn(upto, dc))
+            ds_ref[i] = e_last * ds + tn_sum(ke, -dz, qe, dy)
+
+        heads = [head_ops(i) for i in range(hb)]
+        part, one = (channel_parts, head_channel) if channel \
+            else (parts, head_scalar)
+        for i, (ops, c) in enumerate(zip(heads, part(heads, masks3))):
+            one(i, ops, c)
+
+    return types.SimpleNamespace(forward_steps=forward_steps,
+                                 backward_steps=backward_steps)
+
+
+def _scalars_row(ref):
+    """``put(i, x)`` that stores head ``i``'s row ``x [1, L]`` of a block of
+    per-position scalars ``[hb, L]``."""
+    return lambda i, x: ref.__setitem__((slice(i, i + 1), slice(None)), x)
+
+
+def _delta_scalars(b, t, h, hb, chunk, dk, dv):
+    """What both entries share of their layouts: the per-position scalars
+    ``[B, T, H]`` sent as ``[B, H / hb, T / chunk, hb, chunk]`` (``small``;
+    positions along the lanes) and brought back (``by_position``), and the
+    block specs of those and of the chunk-start states given the chunk
+    order."""
+    from jax.experimental import pallas as pl
+
+    nc = t // chunk
+
+    def small(x):
+        return x.reshape(b, nc, chunk, h // hb, hb).transpose(0, 3, 1, 4, 2)
+
+    def by_position(x):
+        return x.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
+
+    def specs(order):
+        return (pl.BlockSpec((None, None, None, hb, chunk),
+                             lambda bi, hi, ci: (bi, hi, order(ci), 0, 0)),
+                pl.BlockSpec((None, None, hb, dk, dv),
+                             lambda bi, hi, ci: (bi, order(ci), hi, 0, 0)))
+
+    return small, by_position, specs
 
 
 def _delta_layout(q, v, chunk, channel):
-    """The kernels' view of the op's arrays: heads before positions, the
-    scalars ``[B, H / hb, T / chunk, hb, chunk]``; and the block specs by
-    (sequence, head group, chunk) given the chunk order. A decay a key
-    ``channel`` goes as the keys do. (Read where they lie, ``[B, T, H *
-    K]`` in blocks of ``hb`` heads' lanes, the per-channel cell's arrays
-    cost MORE: the kernels ran as fast and the transposes left, but XLA
-    then turned ``[B, T, H, K]`` into ``[B, T, H * K]`` in passes of their
-    own, a tile of 8 heads x 128 lanes against one of 8 positions x 128:
-    ``step_device_ms`` 498 against 483, PR 41.)"""
+    """The HEAD-MAJOR entry's view of the op's arrays: heads before
+    positions, the scalars ``[B, H / hb, T / chunk, hb, chunk]``; and the
+    block specs by (sequence, head group, chunk) given the chunk order. A
+    decay a key ``channel`` goes as the keys do. What keeps this entry:
+    heads that are not whole lane tiles (96 x 192) and sequences of part
+    chunks. Whole lane tiles take :func:`_delta_rows_layout`, which reads
+    the rows where they lie. (Two attempts at that, and why they differ.
+    PR 41 kept the op's XLA prologue and changed only these block specs to
+    ``[B, T, H * K]``: the kernels ran as fast and the transposes left, but
+    the prologue had already made float32 ``[B, T, H, K]`` arrays, tiles of 8
+    heads x 128 lanes, and XLA relaid them as ``[B, T, H * K]`` in passes of
+    its own: ``step_device_ms`` 498 against 483. PR 45 deleted the
+    prologue: the kernels take the op's INPUTS, 2-D in the compute dtype,
+    so no 4-D float32 array exists for XLA to relay: the Ling cell's
+    ``step_device_ms`` 418.1 against 453.7 head-major, the scope's XLA
+    share 0.8 ms a step against 40.1, ``copy`` 5.9 against 28.6.)"""
     from jax.experimental import pallas as pl
 
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     hb, nc = _delta_heads(h, dk, dv, chunk, channel), t // chunk
+    small, by_position, small_specs = _delta_scalars(b, t, h, hb, chunk, dk,
+                                                     dv)
 
     def wide(x):
         return x.transpose(0, 2, 1, 3)
-
-    def small(x):
-        return x.reshape(b, nc, chunk, h // hb, hb).transpose(0, 3, 1, 4, 2)
 
     def specs(order):
         def block(width):
             return pl.BlockSpec((None, hb, chunk, width),
                                 lambda bi, hi, ci: (bi, hi, order(ci), 0))
 
-        scalars = pl.BlockSpec((None, None, None, hb, chunk),
-                               lambda bi, hi, ci: (bi, hi, order(ci), 0, 0))
-        states = pl.BlockSpec((None, None, hb, dk, dv),
-                              lambda bi, hi, ci: (bi, order(ci), hi, 0, 0))
-        return block(dk), block(dv), scalars, states
+        return (block(dk), block(dv)) + small_specs(order)
 
-    return (b, t, h, dk, dv, hb, nc), wide, small, specs
+    return (b, t, h, dk, dv, hb, nc), wide, small, by_position, specs
 
 
 def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
@@ -802,10 +997,9 @@ def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
 
     f32 = jnp.float32
     channel = g.ndim == 4
-    (b, t, h, dk, dv, hb, nc), wide, small, specs = _delta_layout(
+    (b, t, h, dk, dv, hb, nc), wide, small, _, specs = _delta_layout(
         q, v, chunk, channel)
     tools = _delta_chunk_tools(chunk)
-    nn, tn = tools.nn, tools.tn
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
         st_ref = rest[-1]
@@ -814,19 +1008,14 @@ def _delta_chunk_forward(q, k, v, g, beta, *, chunk, with_states):
         def _():
             st_ref[...] = jnp.zeros_like(st_ref)
 
-        # unrolled over the step's heads
-        heads = [(q_ref[i], k_ref[i], v_ref[i],
-                  g_ref[i] if channel else g_ref[i:i + 1, :],
-                  b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
-        parts = tools.channel_parts if channel else tools.parts
-        for i, (ops, c) in enumerate(zip(heads, parts(heads, tools.masks()))):
-            kh, s = ops[1], ops[5]
-            if with_states:
-                rest[0][i] = s
-            # a channel's exp(c) scales q before its product with the state
-            from_start = c.qs if channel else c.e_col * c.qs
-            o_ref[i] = from_start + nn(c.p, c.u)
-            st_ref[i] = c.e_last * s + tn(kh * c.w_col, c.u)
+        def head_ops(i):
+            return (q_ref[i], k_ref[i], v_ref[i],
+                    g_ref[i] if channel else g_ref[i:i + 1, :],
+                    b_ref[i:i + 1, :], st_ref[i])
+
+        tools.forward_steps(
+            hb, head_ops, channel, st_ref,
+            rest[0].__setitem__ if with_states else None, o_ref.__setitem__)
 
     keys, values, scalars, states = specs(lambda ci: ci)
     out_shape = [jax.ShapeDtypeStruct((b, h, t, dv), f32)]
@@ -859,13 +1048,9 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
 
     f32 = jnp.float32
     channel = g.ndim == 4
-    (b, t, h, dk, dv, hb, nc), wide, small, specs = _delta_layout(
-        q, v, chunk, channel)
+    (b, t, h, dk, dv, hb, nc), wide, small, by_position, specs = \
+        _delta_layout(q, v, chunk, channel)
     tools = _delta_chunk_tools(chunk)
-    nn, nt, tn, both, tn_sum, column, row, by_sub = (
-        tools.nn, tools.nt, tools.tn, tools.both, tools.tn_sum, tools.column,
-        tools.row, tools.by_sub)
-    sub = DELTA_SUB_CHUNK
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref,
                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref):
@@ -873,111 +1058,17 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
         def _():
             ds_ref[...] = jnp.zeros_like(ds_ref)
 
-        masks3 = lower, strict, eye = tools.masks()
-        at_last = jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, 1), 0) == chunk - 1
+        def head_ops(i):
+            return (q_ref[i], k_ref[i], v_ref[i],
+                    g_ref[i] if channel else g_ref[i:i + 1, :],
+                    b_ref[i:i + 1, :], st_ref[i])
 
-        def rows(x):
-            return jnp.sum(x, axis=1, keepdims=True)
-
-        if channel:
-            upto, eye_k = tools.triangle(lower), tools.square_eye(dk)
-
-        def head_scalar(i, ops, c):
-            qh, kh, s, ds, dy = ops[0], ops[1], ops[5], ds_ref[i], \
-                do_ref[i]
-            (b_col, e_col, w_col, e_last, decay, p, kkd, a, t_inv, qs, ks, z,
-             u) = c
-            # o = G Q S + P u, S_end = g_L S + (w k)^T u
-            du = tn(p, dy) + nn(kh * w_col, ds)
-            dr = tn(t_inv, du)                  # u = T R: dR = T^T du
-            da, dp = both(nt, dr, dy, u)        # dA = -dR u^T, below
-            da = -da
-            dz = b_col * dr                     # R = b (v - G K S)
-            dks = -e_col * dz
-            dyw = e_col * dy
-            uds = nt(u, ds)                     # d (w k)
-            g_kk = jnp.where(strict, da * b_col * decay, 0.0)
-            g_qk = dp * decay
-            at_q, at_k = both(nn, g_qk, g_kk, kh)
-            of_q, of_k = both(nt, dyw, dks, s)
-            dq_ref[i] = at_q + of_q
-            dk_ref[i] = at_k + tn_sum(g_kk, kh, g_qk, qh) + of_k \
-                + w_col * uds
-            dv_ref[i] = dz
-            db_ref[i:i + 1, :] = row(rows(dr * z) + rows(da * kkd), eye)
-            # the decay's gradient: ONE matrix by rows and by columns, then
-            # what the chunk's own scalings carry
-            e = dp * p + da * a
-            took = rows(dy * qs) * e_col + rows(dks * ks)   # d G, times G
-            gave = rows(uds * kh) * w_col                   # d w, times w
-            ends = jnp.sum(gave, axis=0, keepdims=True) \
-                + e_last * jnp.sum(rows(ds * s), axis=0, keepdims=True)
-            dc = rows(e) - column(jnp.sum(e, axis=0, keepdims=True), eye) \
-                + took - gave + jnp.where(at_last, ends, 0.0)
-            # g_j is in c_t for every t >= j of the chunk
-            dg_ref[i:i + 1, :] = jnp.sum(jnp.where(lower, dc, 0.0), axis=0,
-                                         keepdims=True)
-            ds_ref[i] = e_last * ds + tn_sum(kh, dks, qh, dyw)
-
-        def head_channel(i, ops, c):
-            """A decay a key channel. ``c [L, K]`` enters only as a factor
-            ``exp(+-c)`` of a row of q or k, and for an operand ``X *
-            exp(c)``, ``dc = X * dX``: the channel's gradient is ``q * dq``
-            and ``k * dk``, the latter signed by the side k stood on (times
-            ``exp(c)``: a block row of ``A`` or ``P``, ``(K * exp(c)) S``;
-            times ``exp(-c)``: the columns, ``K * exp(c_L - c)``), plus at
-            the last position what ``c_L`` carries."""
-            qh, kh, s, ds, dy = ops[0], ops[1], ops[5], ds_ref[i], \
-                do_ref[i]
-            (b_col, e_col, w_col, e_last, e_last_row, e_row, col_exp, p, kkd,
-             _, t_inv, qe, ke, _, _, z, u) = c
-            # o = (Q * E) S + P u, S_end = Diag(e_L) S + (w k)^T u
-            du = tn(p, dy) + nn(kh * w_col, ds)
-            dr = tn(t_inv, du)                  # u = T R: dR = T^T du
-            da, dp = both(nt, dr, dy, u)        # dA = -dR u^T, below
-            da = -da
-            dz = b_col * dr                     # R = b (v - (K * E) S)
-            g_kk = jnp.where(strict, da * b_col, 0.0)
-            g_qk = jnp.where(lower, dp, 0.0)
-            # the scores a block row: d [Q_s; K_s] by rows, d K by columns
-            qr, kr = qh * e_row, kh * e_row
-            at_q, at_k, at_col = [], [], None
-            for j, e in enumerate(col_exp):
-                top = jnp.concatenate([by_sub(g_qk, j), by_sub(g_kk, j)],
-                                      axis=0)
-                at_rows = nn(top, kh * e)
-                at_q.append(at_rows[:sub])
-                at_k.append(at_rows[sub:])
-                mine = e * tn(top, jnp.concatenate(
-                    [by_sub(qr, j), by_sub(kr, j)], axis=0))
-                at_col = mine if at_col is None else at_col + mine
-            of_q, of_k = both(nt, dy, -dz, s)   # d (Q * E), d (K * E)
-            gave = w_col * nt(u, ds)            # d (w k), times w
-            dq = e_row * jnp.concatenate(at_q, axis=0) + e_col * of_q
-            dk_row = e_row * jnp.concatenate(at_k, axis=0) + e_col * of_k
-            dk_col = at_col + gave
-            dq_ref[i] = dq
-            dk_ref[i] = dk_row + dk_col
-            dv_ref[i] = dz
-            db_ref[i:i + 1, :] = row(rows(dr * z) + rows(da * kkd), eye)
-            # c_L: in every w_t = exp(c_L - c_t), and in Diag(exp(c_L)) S
-            ends = jnp.sum(kh * gave, axis=0, keepdims=True) \
-                + e_last_row * row(rows(ds * s), eye_k)
-            dc = qh * dq + kh * (dk_row - dk_col) \
-                + jnp.where(at_last, ends, 0.0)
-            # g_j is in c_t for every t >= j of the chunk
-            dg_ref[i] = tn(upto, dc)
-            ds_ref[i] = e_last * ds + tn_sum(ke, -dz, qe, dy)
-
-        # unrolled over the step's heads
-        heads = [(q_ref[i], k_ref[i], v_ref[i],
-                  g_ref[i] if channel else g_ref[i:i + 1, :],
-                  b_ref[i:i + 1, :], st_ref[i]) for i in range(hb)]
-        parts, one = (tools.channel_parts, head_channel) if channel \
-            else (tools.parts, head_scalar)
-        for i, (ops, c) in enumerate(zip(heads, parts(heads, masks3))):
-            one(i, ops, c)
+        tools.backward_steps(hb, dk, head_ops, channel, ds_ref,
+                             types.SimpleNamespace(
+            dy=do_ref.__getitem__, dq=dq_ref.__setitem__,
+            dk=dk_ref.__setitem__, dv=dv_ref.__setitem__,
+            db=_scalars_row(db_ref),
+            dg=dg_ref.__setitem__ if channel else _scalars_row(dg_ref)))
 
     keys, values, scalars, states = specs(lambda ci: nc - 1 - ci)
     small_out = jax.ShapeDtypeStruct((b, h // hb, nc, hb, chunk), f32)
@@ -994,24 +1085,327 @@ def _delta_chunk_backward(q, k, v, g, beta, starts, do, *, chunk):
                    keys_out if channel else small_out, small_out],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
         compiler_params=_ssd_params(), name="delta_chunk_backward")
-
-    def by_position(x):
-        return x.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
-
     return (wide(dq), wide(dk_), wide(dv_),
             wide(dg) if channel else by_position(dg), by_position(db))
 
 
+# what the row-major entry needs beside its arrays: the op's own shape
+# (``heads`` value heads reading ``key_heads`` query/key heads), the gate's
+# form where the gate is formed in the kernel (a decay a key channel) and the
+# normalisation's epsilon; hashable, so one ``jax.jit`` entry a shape
+DeltaRows = collections.namedtuple(
+    "DeltaRows", "seq_len heads key_heads key_dim value_dim chunk gate_floor "
+    "eps")
+
+
+def delta_rows_applicable(dims, key_heads, chunk, seq_len, channel) -> bool:
+    """Whether the chunk kernels, where they take ``dims = (H, K, V)`` at all
+    (:func:`delta_chunk_applicable` / :func:`delta_channel_applicable`), can
+    read and write the op's wide arrays ROW-MAJOR where the projections
+    leave them (:func:`_delta_rows_forward`): a head is whole lane tiles of
+    keys and of values (a static lane-aligned slice of a block), the
+    sequences are whole chunks of whole bfloat16 sublane tiles (16 rows),
+    and a step's value heads cover whole key heads. 64 x 128 x 128 at 32 / 32
+    and at 32 / 16 heads are such shapes; 96 x 192 is not and keeps the
+    head-major entry."""
+    h, dk, dv = dims
+    return (dk % TILE_N == 0 and dv % TILE_N == 0 and chunk % 16 == 0
+            and seq_len % chunk == 0 and h % key_heads == 0
+            and _delta_heads(h, dk, dv, chunk, channel)
+            % (h // key_heads) == 0)
+
+
+def _delta_rows_layout(query, value, gate, spec):
+    """The row-major entry's view: nothing of the wide arrays moves. The
+    blocks are ``(chunk, a step's heads x a head's lanes)`` of ``[rows, Hk *
+    K]`` / ``[rows, H * V]`` by (sequence, head group, chunk); the per-head
+    scalars go as :func:`_delta_layout` sends them (``small``: 1 MB arrays),
+    the gate's two parameter rows ``[1, H * K]`` and the sums of their
+    gradients ``[B, 1, H * K]`` a step's lanes at a time, the latter
+    resident across the chunk axis."""
+    from jax.experimental import pallas as pl
+
+    t, h, dk, dv, chunk = (spec.seq_len, spec.heads, spec.key_dim,
+                           spec.value_dim, spec.chunk)
+    b, nc, ratio = query.shape[0] // t, t // chunk, h // spec.key_heads
+    channel = gate.ndim == 2
+    hb = _delta_heads(h, dk, dv, chunk, channel)
+    small, by_position, small_specs = _delta_scalars(b, t, h, hb, chunk, dk,
+                                                     dv)
+
+    def specs(order):
+        def rows(width):
+            return pl.BlockSpec(
+                (chunk, width), lambda bi, hi, ci: (bi * nc + order(ci), hi))
+
+        scalars, states = small_specs(order)
+        return types.SimpleNamespace(
+            keys=rows(hb // ratio * dk), values=rows(hb * dv),
+            gate=rows(hb * dk) if channel else scalars, scalars=scalars,
+            states=states,
+            gate_row=pl.BlockSpec((1, hb * dk), lambda bi, hi, ci: (0, hi)),
+            gate_sum=pl.BlockSpec((None, 1, hb * dk),
+                                  lambda bi, hi, ci: (bi, 0, hi)))
+
+    return (b, nc, hb, ratio, channel), small, by_position, specs
+
+
+def _delta_rows_tools(spec):
+    """What happens in VMEM between the op's rows and the chunk's matrices
+    (float32 throughout): the normalisation of a key head's q and k and a
+    channel's gate, and their backward passes, as ``GatedDeltaRule`` states
+    them and autodiff differentiates them."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    floor, q_scale = spec.gate_floor, spec.key_dim ** -0.5
+
+    def lanes(i, width):
+        return slice(None), slice(i * width, (i + 1) * width)
+
+    def unit(ref, j):
+        """Key head ``j``'s rows, float32, and ``1 / |x|`` a row."""
+        x = ref[lanes(j, spec.key_dim)].astype(f32)
+        return x, jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + spec.eps)
+
+    def unit_back(x, r, dy):
+        """``d x`` of ``y = x r``, ``r = rsqrt(sum(x^2) + eps)``."""
+        return r * dy - x * (r * r * r
+                             * jnp.sum(x * dy, axis=-1, keepdims=True))
+
+    def softplus(y):
+        return jnp.maximum(y, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(y)))
+
+    def gate(a, scale, bias):
+        """A channel's log-decay ``[L, K]`` from ``a`` as it comes and the
+        rows ``scale = exp(A_log)``, ``bias = dt_bias``: both forms of
+        ``gate_floor``. Returns it with what its backward pass reads."""
+        y = a.astype(f32) + bias
+        if floor:
+            sig = jax.nn.sigmoid(scale * y)
+            return floor * sig, (y, sig)
+        sp = softplus(y)
+        return -scale * sp, (y, sp)
+
+    def gate_back(dg, scale, kept):
+        """``d a [L, K]`` and the sums over the chunk's rows ``d scale``,
+        ``d bias [1, K]``."""
+        y, other = kept
+        if floor:
+            dz = dg * (floor * other * (1.0 - other))   # other: the sigmoid
+            da, dscale = dz * scale, dz * y
+        else:
+            da, dscale = -scale * dg * jax.nn.sigmoid(y), -dg * other
+        return (da, jnp.sum(dscale, axis=0, keepdims=True),
+                jnp.sum(da, axis=0, keepdims=True))
+
+    def reader(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, gate_rows):
+        """``head_ops(i)`` of a step over its blocks (what the shared steps
+        ask: ``q, k, v, g, b_row, s`` of value head ``i``, float32, q and k
+        normalised), with what the backward pass reads again: ``key_heads[j]
+        = (x, 1 / |x|)`` of key head ``j``'s q and of its k, formed once a
+        key head, and a channel's ``gates[i]``. ``gate_rows``: the refs of
+        ``scale`` and ``bias``, a decay a key channel; else ``()`` and
+        ``g_ref`` holds the heads' log-decays."""
+        ratio = spec.heads // spec.key_heads
+        key_heads, normed, gates = {}, {}, {}
+
+        def head_ops(i):
+            j = i // ratio
+            if j not in key_heads:
+                key_heads[j] = (q, qr), (k, kr) = [
+                    unit(ref, j) for ref in (q_ref, k_ref)]
+                normed[j] = q * qr * q_scale, k * kr
+            if gate_rows:
+                at = lanes(i, spec.key_dim)
+                g, gates[i] = gate(g_ref[at], *(r[at] for r in gate_rows))
+            else:
+                g = g_ref[i:i + 1, :]
+            return (*normed[j], v_ref[lanes(i, spec.value_dim)].astype(f32),
+                    g, b_ref[i:i + 1, :], st_ref[i])
+
+        return head_ops, key_heads, gates
+
+    return types.SimpleNamespace(lanes=lanes, unit_back=unit_back,
+                                 gate_back=gate_back, q_scale=q_scale,
+                                 reader=reader)
+
+
+def _delta_rows_forward(query, key, value, gate, beta, scale=None, bias=None,
+                        *, spec, with_states):
+    """:func:`_delta_chunk_forward` over the op's arrays AS THEY LIE, for
+    shapes :func:`delta_rows_applicable` admits: ``query``, ``key [rows, Hk *
+    K]``, ``value [rows, H * V]`` in the compute dtype, before their
+    normalisation; ``beta [B, T, H]`` float32; ``gate`` either the log-decay
+    a head ``[B, T, H]`` float32 or, a decay a key channel, the op's ``a
+    [rows, H * K]`` in the compute dtype with the float32 rows ``scale =
+    exp(A_log)`` (a head's value on each of its channels) and ``bias =
+    dt_bias``, ``[1, H * K]``. In VMEM, a step: the casts to float32, ``q / |q|
+    / sqrt(K)`` and ``k / |k|`` a KEY head (value head ``i`` reads key head
+    ``i // ratio`` of the step's block: no repeated copy exists), the
+    channel's gate, then the chunk body both entries share. Returns ``o
+    [rows, H * V]`` ROUNDED TO THE COMPUTE DTYPE at the store (its one
+    rounding) and, ``with_states``, the float32 chunk-start states ``[B,
+    T/chunk, H, K, V]``. Scratch: the carried state, float32."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    (b, nc, hb, ratio, channel), small, _, specs = _delta_rows_layout(
+        query, value, gate, spec)
+    h, dk, dv = spec.heads, spec.key_dim, spec.value_dim
+    tools, rows = _delta_chunk_tools(spec.chunk), _delta_rows_tools(spec)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest):
+        gate_rows, (o_ref, *rest) = rest[:2 * channel], rest[2 * channel:]
+        st_ref = rest[-1]
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            st_ref[...] = jnp.zeros_like(st_ref)
+
+        head_ops, _, _ = rows.reader(q_ref, k_ref, v_ref, g_ref, b_ref,
+                                     st_ref, gate_rows)
+
+        def put_o(i, o):
+            o_ref[rows.lanes(i, dv)] = o.astype(o_ref.dtype)
+
+        tools.forward_steps(
+            hb, head_ops, channel, st_ref,
+            rest[0].__setitem__ if with_states else None, put_o)
+
+    at = specs(lambda ci: ci)
+    out_shape = [jax.ShapeDtypeStruct(value.shape, value.dtype)]
+    out_specs = [at.values]
+    if with_states:
+        out_shape.append(jax.ShapeDtypeStruct((b, nc, h, dk, dv), f32))
+        out_specs.append(at.states)
+    out = pallas_call(
+        kernel, query, key, value, gate if channel else small(gate),
+        small(beta), *((scale, bias) if channel else ()),
+        grid=(b, h // hb, nc),
+        in_specs=[at.keys, at.keys, at.values, at.gate, at.scalars]
+        + [at.gate_row] * (2 * channel),
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
+        compiler_params=_ssd_params(), name="delta_chunk_forward")
+    return out[0], out[1] if with_states else None
+
+
+def _delta_rows_backward(query, key, value, gate, beta, scale, bias, starts,
+                         do, *, spec):
+    """The mirror of :func:`_delta_rows_forward` over the chunks reversed,
+    as :func:`_delta_chunk_backward` mirrors its forward: the rows are read
+    as they lie and normalised and gated again in VMEM, the shared body
+    gives the gradients of the normalised q and k a VALUE head, a key head's
+    are their float32 sum, and the normalisation's backward pass (``dx = r dy
+    - x r^3 sum(x dy)``) and the gate's run before the stores. Returns
+    ``dquery``, ``dkey``, ``dvalue`` (and a decay a key channel ``da``) in the
+    inputs' shapes ROUNDED TO THE COMPUTE DTYPE at the store, where the
+    cast's own backward pass rounded them; ``dbeta`` and a head's ``dg [B,
+    T, H]`` float32; and a channel's ``dscale``, ``dbias [1, H * K]``:
+    float32 sums over every row, accumulated in VMEM along the chunk axis
+    in float32 and summed over the sequences outside, never rounded."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    (b, nc, hb, ratio, channel), small, by_position, specs = \
+        _delta_rows_layout(query, value, gate, spec)
+    h, dk, dv = spec.heads, spec.key_dim, spec.value_dim
+    tools, rows = _delta_chunk_tools(spec.chunk), _delta_rows_tools(spec)
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest):
+        gate_rows, rest = rest[:2 * channel], rest[2 * channel:]
+        st_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref = rest[:7]
+        gate_sums, ds_ref = rest[7:-1], rest[-1]
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            ds_ref[...] = jnp.zeros_like(ds_ref)
+            for ref in gate_sums:
+                ref[...] = jnp.zeros_like(ref)
+
+        head_ops, key_heads, gates = rows.reader(
+            q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, gate_rows)
+
+        def key_gradient(ref, which, scale):
+            """The sum over a key head's value heads, through the
+            normalisation, to the store."""
+            sums = {}
+
+            def put(i, x):
+                j = i // ratio
+                sums[j] = x if i % ratio == 0 else sums[j] + x
+                if i % ratio == ratio - 1:
+                    x0, r = key_heads[j][which]
+                    ref[rows.lanes(j, dk)] = rows.unit_back(
+                        x0, r, sums.pop(j) * scale).astype(ref.dtype)
+
+            return put
+
+        def put_dv(i, x):
+            dv_ref[rows.lanes(i, dv)] = x.astype(dv_ref.dtype)
+
+        def put_da(i, dg):
+            at = rows.lanes(i, dk)
+            da, dscale, dbias = rows.gate_back(dg, gate_rows[0][at],
+                                               gates.pop(i))
+            dg_ref[at] = da.astype(dg_ref.dtype)
+            for ref, x in zip(gate_sums, (dscale, dbias)):
+                ref[at] += x
+
+        tools.backward_steps(
+            hb, dk, head_ops, channel, ds_ref, types.SimpleNamespace(
+                dy=lambda i: do_ref[rows.lanes(i, dv)].astype(f32),
+                dq=key_gradient(dq_ref, 0, rows.q_scale),
+                dk=key_gradient(dk_ref, 1, 1.0), dv=put_dv,
+                db=_scalars_row(db_ref),
+                dg=put_da if channel else _scalars_row(dg_ref)))
+
+    at = specs(lambda ci: nc - 1 - ci)
+    small_out = jax.ShapeDtypeStruct((b, h // hb, nc, hb, spec.chunk), f32)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)     # noqa: E731
+    gate_sum = jax.ShapeDtypeStruct((b, 1, h * dk), f32)
+    dq, dk_, dv_, dg, db, *sums = pallas_call(
+        kernel, query, key, value, gate if channel else small(gate),
+        small(beta), *((scale, bias) if channel else ()), starts, do,
+        grid=(b, h // hb, nc),
+        in_specs=[at.keys, at.keys, at.values, at.gate, at.scalars]
+        + [at.gate_row] * (2 * channel) + [at.states, at.values],
+        out_specs=[at.keys, at.keys, at.values, at.gate, at.scalars]
+        + [at.gate_sum] * (2 * channel),
+        out_shape=[like(query), like(key), like(value),
+                   like(gate) if channel else small_out, small_out]
+        + [gate_sum] * (2 * channel),
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
+        compiler_params=_ssd_params(), name="delta_chunk_backward")
+    dscale, dbias = (jnp.sum(x, axis=0) for x in sums) if channel \
+        else (None, None)
+    return (dq, dk_, dv_, dg if channel else by_position(dg),
+            by_position(db), dscale, dbias)
+
+
 @functools.lru_cache(None)
 def _delta_jitted():
-    """The two kernels' callers as ``jax.jit`` functions, made once, as
+    """The kernels' callers (head-major forward and backward, row-major
+    forward and backward) as ``jax.jit`` functions, made once, as
     :func:`_ssd_jitted` and for its reason: a model's layers share shapes,
     so a step traces and lowers each unrolled body once."""
     import jax
 
     return (jax.jit(_delta_chunk_forward,
                     static_argnames=("chunk", "with_states")),
-            jax.jit(_delta_chunk_backward, static_argnames=("chunk",)))
+            jax.jit(_delta_chunk_backward, static_argnames=("chunk",)),
+            jax.jit(_delta_rows_forward,
+                    static_argnames=("spec", "with_states")),
+            jax.jit(_delta_rows_backward, static_argnames=("spec",)))
 
 
 def delta_chunk_forward(*args, **static):
@@ -1022,6 +1416,16 @@ def delta_chunk_forward(*args, **static):
 def delta_chunk_backward(*args, **static):
     """:func:`_delta_chunk_backward` through its shared ``jax.jit``."""
     return _delta_jitted()[1](*args, **static)
+
+
+def delta_rows_forward(*args, **static):
+    """:func:`_delta_rows_forward` through its shared ``jax.jit``."""
+    return _delta_jitted()[2](*args, **static)
+
+
+def delta_rows_backward(*args, **static):
+    """:func:`_delta_rows_backward` through its shared ``jax.jit``."""
+    return _delta_jitted()[3](*args, **static)
 
 
 # ---------------------------------------------------------------------------

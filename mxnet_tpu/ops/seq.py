@@ -969,11 +969,14 @@ def gated_delta_scan(q, k, v, g, beta, chunk, kernel):
     differentiable function, beside :func:`ssd_scan`: ``q``, ``k [B, T, H,
     K]``, ``v [B, T, H, V]``, ``beta [B, T, H]`` and ``g [B, T, H]``, one
     decay a head, or ``[B, T, H, K]``, one a key channel; float32; returns
-    ``o [B, T, H, V]``. ``kernel`` picks the body: the Pallas chunk kernels
-    (``pallas_kernels.delta_chunk_forward`` / ``_backward``: the solve, the
-    chunk's matrices and the carried state stay in VMEM) with the backward
-    pass written out, its residuals the five inputs and the float32
-    chunk-start states ``[B, T/chunk, H, K, V]``; or the same chunked
+    ``o [B, T, H, V]``, float32: the caller rounds it. ``kernel`` picks the
+    body: the Pallas chunk kernels' HEAD-MAJOR entry (``pallas_kernels.
+    delta_chunk_forward`` / ``_backward``: the solve, the chunk's matrices
+    and the carried state stay in VMEM; they read float32 ``[B, H, T, .]``
+    transposes of these arguments; :func:`gated_delta_rows` is the entry
+    that reads the op's own rows) with the backward pass written out, its
+    residuals the five inputs and the float32 chunk-start states ``[B,
+    T/chunk, H, K, V]``; or the same chunked
     algorithm in ``jax.numpy`` under autodiff, :func:`gated_delta_chunked`
     a sequence at a time or, a decay a channel,
     :func:`gated_delta_chunked_channel` a group of heads at a time
@@ -990,21 +993,56 @@ def gated_delta_scan(q, k, v, g, beta, chunk, kernel):
             lambda x: gated_delta_chunked(*x, chunk=chunk)[0],
             (q, k, v, g, beta))
 
+    return _chunk_kernels(pallas_kernels.delta_chunk_forward,
+                          pallas_kernels.delta_chunk_backward,
+                          chunk=chunk)(q, k, v, g, beta)
+
+
+def _chunk_kernels(forward, backward, **static):
+    """One differentiable function over a forward and a backward chunk
+    kernel: the value is ``forward(*args, with_states=False)``'s; under
+    differentiation the forward kernel also writes the float32 chunk-start
+    states, and ``backward(*args, states, do)`` reads them back beside the
+    arguments, which are the residuals."""
+    jax = _jax()
+
     @jax.custom_vjp
     def f(*args):
-        return pallas_kernels.delta_chunk_forward(
-            *args, chunk=chunk, with_states=False)[0]
+        return forward(*args, with_states=False, **static)[0]
 
     def f_fwd(*args):
-        o, starts = pallas_kernels.delta_chunk_forward(
-            *args, chunk=chunk, with_states=True)
+        o, starts = forward(*args, with_states=True, **static)
         return o, args + (starts,)
 
     def f_bwd(res, do):
-        return pallas_kernels.delta_chunk_backward(*res, do, chunk=chunk)
+        return backward(*res, do, **static)
 
     f.defvjp(f_fwd, f_bwd)
-    return f(q, k, v, g, beta)
+    return f
+
+
+def gated_delta_rows(query, key, value, gate, beta, scale, bias, spec):
+    """:func:`gated_delta_scan`'s kernel body over the op's arrays AS THE
+    PROJECTIONS LEAVE THEM, for shapes ``pallas_kernels.
+    delta_rows_applicable`` admits (``spec``: a ``pallas_kernels.
+    DeltaRows``): ``query``, ``key [rows, Hk * K]`` and ``value [rows, H *
+    V]`` in the compute dtype, NOT normalised; ``beta [B, T, H]`` float32;
+    ``gate`` the log-decay a head ``[B, T, H]`` float32 (``scale``, ``bias``
+    ``None``) or, a decay a key channel, the op's ``a [rows, H * K]`` in the
+    compute dtype with the float32 rows ``scale = exp(A_log)`` a channel and
+    ``bias = dt_bias``, ``[1, H * K]``. The casts, the two normalisations, a
+    channel's gate and the shared key heads happen in VMEM
+    (``pallas_kernels.delta_rows_forward`` / ``_backward``), so no float32
+    or head-major copy of a wide array exists on either side of the calls.
+    Returns ``o [rows, H * V]`` in the compute dtype. The residuals are the
+    inputs as they came and the float32 chunk-start states ``[B, T/chunk,
+    H, K, V]``; the gradients of ``scale`` and ``bias`` are float32 sums
+    over every row, never rounded."""
+    from . import pallas_kernels
+
+    return _chunk_kernels(
+        pallas_kernels.delta_rows_forward, pallas_kernels.delta_rows_backward,
+        spec=spec)(query, key, value, gate, beta, scale, bias)
 
 
 @register_op("GatedDeltaRule")
@@ -1029,12 +1067,13 @@ class GatedDeltaRule(Operator):
     runs ``H`` states of ``K x V`` (Qwen3-Next: 16 under 32). ``q`` and ``k``
     are normalised once a key head and then REPEATED to the value heads
     (``jnp.repeat`` on the float32 ``[B, T, Hk, K]``; the bodies and the
-    kernels see ``H`` heads as ever, and autodiff sums each pair's ``dq``,
-    ``dk``); with ``Hk = H`` nothing is repeated and the traced program is
-    the older models'. Counted ``lower.delta_rule_heads.grouped`` /
-    ``.equal`` a traced node. What is left: index maps that read key head
-    ``h // ratio`` inside the kernels, so that the repeated copies never
-    exist (ROADMAP Reach A3).
+    head-major kernels see ``H`` heads as ever, and autodiff sums each
+    pair's ``dq``, ``dk``); with ``Hk = H`` nothing is repeated. On the
+    kernels' ROW-MAJOR entry (below) no copy is repeated at all: value head
+    ``i`` of a grid step reads key head ``i // ratio`` of the step's block,
+    and a key head's ``dq``, ``dk`` are summed over its value heads in
+    float32 in VMEM. Counted ``lower.delta_rule_heads.grouped`` / ``.equal``
+    a traced node.
 
     **What the op covers.** The decay's SHAPE is read off ``a``: ``[rows,
     H]`` is one decay a head (Gated DeltaNet, arXiv:2412.06464; ``dt_bias
@@ -1054,7 +1093,10 @@ class GatedDeltaRule(Operator):
 
     The normalisation, the doubling and the decay are inside the op, in
     float32 like the state and the solve, whatever the compute dtype; the
-    result is rounded to it once.
+    result is rounded to it once, and so is each wide gradient (``query``,
+    ``key``, ``value``, a channel's ``a``), after the float32 backward
+    passes of the normalisation and the gate. ``A_log``'s and ``dt_bias``'s
+    gradients are float32 sums over every row, never rounded.
 
     The recurrence's body (:func:`gated_delta_scan`) is chosen from the
     shapes when the node is traced, as ``SSMScan`` and ``CausalAttention``
@@ -1073,10 +1115,29 @@ class GatedDeltaRule(Operator):
     In both, every operand and product is float32 (products at ``HIGHEST``:
     six bfloat16 passes, never one), as are the decays, the solve, the
     carried state, its gradient and every accumulator. The kernels' backward
-    pass is written out: it keeps the five float32 inputs and the float32
-    chunk-start states ``[B, T/chunk, H, K, V]`` and forms each chunk's
-    matrices again in VMEM, so under segment recomputation the op runs
-    forward, forward, backward."""
+    pass is written out: it keeps its inputs and the float32 chunk-start
+    states ``[B, T/chunk, H, K, V]`` and forms each chunk's matrices again
+    in VMEM, so under segment recomputation the op runs forward, forward,
+    backward.
+
+    **Where the kernels read the rows** (``lower.delta_rule_layout.rows`` /
+    ``.heads`` a traced node that takes the kernels; chosen from the shapes
+    alone, ``pallas_kernels.delta_rows_applicable``). At heads of whole lane
+    tiles (128 keys, 128 or 256 values) over sequences of whole chunks, a
+    step's value heads covering whole key heads (32 heads of 128 x 128 a
+    decay a channel, and 32 over 16 a decay a head, are such shapes), the
+    kernels take THIS OP'S INPUTS as the projections leave them
+    (:func:`gated_delta_rows`): ``query``, ``key``, ``value`` and a
+    channel's ``a`` row-major in the compute dtype, blocks of a step's
+    heads' lanes; the casts, the two normalisations, a channel's gate and
+    the shared key heads happen in VMEM, the result and the wide gradients
+    are rounded at the kernels' stores, and the residuals are the inputs as
+    they came (which the segment holds anyway) and the chunk-start states.
+    Only what is a head wide is formed here in XLA: ``beta``, a head's
+    decay, ``exp(A_log)`` a channel. Everywhere else (96 x 192; a sequence
+    of part chunks) the op casts, normalises, gates, repeats and transposes
+    in XLA and the kernels read float32 head-major copies
+    (:func:`gated_delta_scan`), whose five are then the residuals."""
 
     name_hint = "gateddeltarule"
     PARAMS = {
@@ -1138,9 +1199,8 @@ class GatedDeltaRule(Operator):
         from . import pallas_kernels
 
         f32 = jnp.float32
-        q, k, v, a, b, a_log, dt_bias = (x.astype(f32) for x in inputs)
         t, h = self.seq_len, self.num_heads
-        n = q.shape[0] // t
+        n = inputs[0].shape[0] // t
         ratio = h // (self.num_key_heads or h)
         _tel.inc("lower.delta_rule_heads.%s"
                  % ("grouped" if ratio > 1 else "equal"))
@@ -1167,7 +1227,11 @@ class GatedDeltaRule(Operator):
                                                         * (a + dt_bias))
             return -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
 
-        channel = a.shape[1] != h
+        def step_gate(b):
+            beta = jax.nn.sigmoid(b).reshape(n, t, h)
+            return 2.0 * beta if self.neg_eigval else beta
+
+        channel = inputs[3].shape[1] != h
         _tel.inc("lower.delta_rule_gate.%s"
                  % ("channel" if channel else "head"))
         dims = (h, self.key_dim, self.value_dim)
@@ -1177,12 +1241,35 @@ class GatedDeltaRule(Operator):
                                  "chunks of whole %d-position sub-chunks, "
                                  "not %d" % (SUB_CHUNK, self.chunk))
             kernel = pallas_kernels.delta_channel_applicable(
-                dims, self.chunk, q.dtype)
+                dims, self.chunk, jnp.dtype(f32))
         else:
             kernel = pallas_kernels.delta_chunk_applicable(
-                dims, self.chunk, q.dtype)
+                dims, self.chunk, jnp.dtype(f32))
         _tel.inc("lower.delta_rule_kernel.pallas_chunked" if kernel
                  else "lower.delta_rule_kernel.xla_chunked")
+        rows = kernel and pallas_kernels.delta_rows_applicable(
+            dims, h // ratio, self.chunk, t, channel)
+        if kernel:
+            _tel.inc("lower.delta_rule_layout.%s"
+                     % ("rows" if rows else "heads"))
+        if rows:
+            # the kernels read the wide arrays where they lie, as they come;
+            # what is a head wide is formed here, in float32
+            b, a_log, dt_bias = (x.astype(f32) for x in inputs[4:])
+            if channel:
+                g, gate_rows = inputs[3], (
+                    jnp.repeat(jnp.exp(a_log), self.key_dim)[None],
+                    dt_bias[None])
+            else:
+                g, gate_rows = gate(inputs[3].astype(f32), a_log,
+                                    dt_bias).reshape(n, t, h), (None, None)
+            o = gated_delta_rows(
+                *inputs[:3], g, step_gate(b), *gate_rows,
+                pallas_kernels.DeltaRows(
+                    t, h, h // ratio, self.key_dim, self.value_dim,
+                    self.chunk, self.gate_floor, self.NORM_EPS))
+            return [ctx.keep(o, "output")], []
+        q, k, v, a, b, a_log, dt_bias = (x.astype(f32) for x in inputs)
         if channel and not kernel:
             def prepare(q, k, v, a, b, a_log, dt_bias):
                 """One sequence's group of heads, as it comes (``[T, hg,
@@ -1208,9 +1295,7 @@ class GatedDeltaRule(Operator):
                      dt_bias.reshape(h, self.key_dim))
         else:
             g = gate(a, a_log, dt_bias).reshape(n, t, h)
-        beta = jax.nn.sigmoid(b).reshape(n, t, h)
-        if self.neg_eigval:
-            beta = 2.0 * beta
+        beta = step_gate(b)
         pad = -t % self.chunk
         if pad:
             # past the end: decay 1, beta 0, no key: the state stands still

@@ -64,6 +64,21 @@ def _arm_transfer_sanitizer(request, monkeypatch):
 
 
 @pytest.fixture(autouse=True)
+def _xprof_override_ends_with_its_test():
+    """``xprof.enable()`` / ``disable()`` override telemetry's switch for
+    the PROCESS. A test that leaves ``disable()`` behind turns the compile
+    registry off for every later file on its worker (which files share a
+    worker is timing: ``tests/test_benchmark_rehearsals.py``'s traced run
+    then printed no ``step_program_*`` metric, once in a whole run). The
+    override a test set is taken back when it ends."""
+    from mxnet_tpu import xprof
+
+    before = xprof._override
+    yield
+    xprof._override = before
+
+
+@pytest.fixture(autouse=True)
 def _no_thread_or_process_leaks(request):
     """Every test must clean up after itself on the concurrency plane:
     no new non-daemon threads and no live child processes may survive a

@@ -7,11 +7,14 @@ the arguments an operator does not keep whole (``jax.eval_shape`` over
 nothing allocated). Every operator that chooses a body from its shapes
 counts the choice once a traced node (``lower.*``), so the counters read
 the Pallas body for every scan, delta-rule, attention and experts node and
-nothing for any XLA fallback. The toy presets of the models' own test
-files count the fallbacks: a change that drops a cell off its kernel passes
-them all, and costs 10-50% of the cell's step on the chip (ledger, PRs 27,
-31, 33, 37, 41). Only the configuration files are read; nothing of the
-benchmark is imported."""
+nothing for any XLA fallback; a delta-rule node also counts WHERE its
+kernels read the op's wide arrays (``delta_rule_layout.rows``: as the
+projections leave them, at heads of whole lane tiles; ``.heads``: float32
+head-major copies, the Olmo cell's 96 x 192). The toy presets of the
+models' own test files count the fallbacks: a change that drops a cell off
+its kernel passes them all, and costs 10-50% of the cell's step on the chip
+(ledger, PRs 27, 31, 33, 37, 41). Only the configuration files are read;
+nothing of the benchmark is imported."""
 import importlib
 import json
 import os
@@ -36,6 +39,12 @@ KERNEL = {
     "RoutedExperts": ["experts_kernel.pallas_grouped",
                       "experts_plan.column_sort"],
     "GatedShortConv": ["shortconv_body.xla_fused"],
+}
+# the entry each cell's ``GatedDeltaRule`` nodes take, all of them
+DELTA_LAYOUT = {
+    "olmo_hybrid_l4_headshare_bf16": "heads",
+    "ling3_flash_l6_e8of512_bf16": "rows",
+    "qwen3_next_l4_e32of512_bf16": "rows",
 }
 FALLBACKS = ["scan_kernel.xla_chunked", "delta_rule_kernel.xla_chunked",
              "attention_kernel.xla_blockwise", "attention_layout.split",
@@ -108,6 +117,8 @@ def test_a_cells_nodes_take_their_kernels_at_its_own_sizes(config):
                    for names in KERNEL.values() for name in names}
         fallen = {name: telemetry.peek("lower." + name) or 0
                   for name in FALLBACKS}
+        layout = {name: telemetry.peek("lower.delta_rule_layout." + name)
+                  or 0 for name in ("rows", "heads")}
     finally:
         telemetry.reset()
         telemetry.disable()
@@ -116,3 +127,6 @@ def test_a_cells_nodes_take_their_kernels_at_its_own_sizes(config):
     assert counted == {name: held.get(op, 0)
                        for op, names in KERNEL.items() for name in names}
     assert fallen == dict.fromkeys(FALLBACKS, 0)
+    assert layout == dict({"rows": 0, "heads": 0}, **{
+        DELTA_LAYOUT[config]: held["GatedDeltaRule"]}
+        if config in DELTA_LAYOUT else {})

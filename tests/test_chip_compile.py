@@ -65,6 +65,48 @@ def test_delta_chunk_kernels_compile_for_the_chip(one_chip, heads, dk, dv,
         assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("heads,key_heads,dv,chunk,channel,floor,dtype", [
+    (32, 32, 128, 64, True, -5.0, "bfloat16"),  # the Ling cell
+    (32, 16, 128, 64, False, 0.0, "bfloat16"),  # the Qwen3-Next cell
+    (4, 4, 256, 64, True, 0.0, "float32"),      # the softplus gate, widest
+    (8, 2, 128, 16, False, 0.0, "bfloat16")],   # four value heads a key head
+    ids=["ling-cell", "qwen3-next-cell", "softplus-gate-values-256",
+         "four-a-key-head-chunk-16"])
+def test_delta_rows_kernels_compile_for_the_chip(one_chip, heads, key_heads,
+                                                 dv, chunk, channel, floor,
+                                                 dtype):
+    """The chunk kernels' row-major entry (``delta_rows_applicable``): blocks
+    of a step's heads' lanes off the op's ``[rows, H * K]`` arrays in the
+    compute dtype, the casts, the normalisations and a channel's gate in
+    VMEM, within the 16 MB of scoped VMEM at the heads a step
+    ``_delta_heads`` gives."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    dk, t = 128, 4 * chunk
+    assert pk.delta_rows_applicable((heads, dk, dv), key_heads, chunk, t,
+                                    channel)
+    spec = pk.DeltaRows(t, heads, key_heads, dk, dv, chunk, floor, 1e-6)
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def rows(width):
+        return shape(2 * t, width, dtype=jnp.dtype(dtype))
+
+    scalars, gate_row = shape(2, t, heads), shape(1, heads * dk)
+    args = (rows(key_heads * dk), rows(key_heads * dk), rows(heads * dv)) + (
+        (rows(heads * dk), scalars, gate_row, gate_row) if channel
+        else (scalars, scalars, None, None))
+    forward = jax.jit(lambda *a: pk._delta_rows_forward(
+        *a, spec=spec, with_states=True)).lower(*args).compile()
+    backward = jax.jit(lambda *a: pk._delta_rows_backward(
+        *a, spec=spec)).lower(
+            *args, shape(2, t // chunk, heads, dk, dv),
+            rows(heads * dv)).compile()
+    for compiled in (forward, backward):
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("h,group,d,dv", [
     (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128), (2, 8, 256, 256)],
     ids=["256-wide", "64-wide-grouped", "256-wide-keys-128-wide-values",

@@ -147,6 +147,28 @@ def _kernel_case(name):
         return (lambda *a: pk.delta_chunk_backward(*a, chunk=chunk),
                 args + (jnp.zeros((1, 1, 1, dk, dv), f32),
                         ones(1, chunk, 1, dv)))
+    if name.startswith("delta_rows"):
+        channel = name.endswith("channel")
+        # the row-major entry: one head of 128 keys and values, a chunk of
+        # one bfloat16 sublane tile, the rows as the projections leave them
+        dk, dv, chunk = 128, 128, 16
+        assert pk.delta_rows_applicable((1, dk, dv), 1, chunk, chunk, channel)
+        assert not pk.delta_rows_applicable((1, dk, dv), 1, chunk, chunk + 8,
+                                            channel)
+        spec = pk.DeltaRows(chunk, 1, 1, dk, dv, chunk,
+                            -5.0 if channel else 0.0, 1e-6)
+        rows = jnp.ones((chunk, dk), jnp.bfloat16)
+        gate = (rows, ones(1, chunk, 1) * 0.5, ones(1, dk), -ones(1, dk)) \
+            if channel else (-ones(1, chunk, 1) * 0.1, ones(1, chunk, 1) * 0.5)
+        fill = (None,) * (4 - len(gate))
+        if "forward" in name:
+            return (lambda *a: pk.delta_rows_forward(
+                *a, *fill, spec=spec, with_states=True),
+                (rows, rows, rows) + gate)
+        return (lambda q, k, v, *a: pk.delta_rows_backward(
+            q, k, v, *a[:-2], *fill, *a[-2:], spec=spec),
+            (rows, rows, rows) + gate
+            + (jnp.zeros((1, 1, 1, dk, dv), f32), rows))
     if name.startswith("grouped_experts"):
         # one expert of 128 -> 8 -> 128 over one block of 8 slots
         gated = name.endswith("gated")
@@ -182,7 +204,9 @@ def _kernel_case(name):
 @pytest.mark.parametrize("name", [
     "rtc", "ssd_chunk_forward", "ssd_chunk_backward", "delta_chunk_forward",
     "delta_chunk_forward_channel", "delta_chunk_backward",
-    "delta_chunk_backward_channel", "grouped_experts_forward",
+    "delta_chunk_backward_channel", "delta_rows_forward",
+    "delta_rows_forward_channel", "delta_rows_backward",
+    "delta_rows_backward_channel", "grouped_experts_forward",
     "grouped_experts_forward_gated", "grouped_experts_backward",
     "attention_relayout", "attention_relayout_back"])
 def test_kernels_lower_to_mosaic_for_tpu_and_the_interpreter_for_cpu(name):

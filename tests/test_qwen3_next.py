@@ -145,11 +145,14 @@ def test_bad_key_heads_are_refused():
 # sha256 of the operators' traced programs at the older cells' shapes, as
 # ``python tests/op_program_text.py`` printed them on the parent of the PR
 # that added the arguments (commit bdd296d). A PR that changes what these
-# nodes compute on purpose reads the new ones off this test's failure.
+# nodes compute on purpose reads the new ones off this test's failure:
+# ``ling.delta`` is PR 45's (the kernels read the node's rows where they lie,
+# ``lower.delta_rule_layout.rows``); ``olmo.delta``, heads of 96 x 192, keeps
+# the head-major entry and the text of bdd296d.
 PARENT_PROGRAMS = {
     "glm.experts": "54bf1fac1c4a93f5be2dd88bee0bff4d8f7a00a15f7032092a9338e13a941aff",
     "lfm2.experts": "e80ff383d43456a3dc0ff0c51bf9e2d05dbda7a77d2c689944ef552d94bb30a2",
-    "ling.delta": "4de744fbc524e1ac61d925d9512e0e9673bbf0deb072e6a7a6f709d4a18f670a",
+    "ling.delta": "e3130547eb3872b48f798501af196d1738fc989f7f8137d7b3ea52853791794a",
     "ling.experts": "ced652c76c75dc37b0baca02e22814fad6f364adb3490af0acdd07d3bde6ded9",
     "nemotron.experts": "a3dbd7ae9d8c11d1c07eebe0614a5865db811e466afa5ebf8b3778972f2b4158",
     "olmo.delta": "e3af893438200de39a473a75fc465b74578f0dd4ac449cefd7281c05722f0a6c",
@@ -168,7 +171,8 @@ def test_the_older_cells_nodes_trace_the_parents_program(traced_programs,
     sigmoid scores, no auxiliary loss) the Olmo and Ling cells'
     ``GatedDeltaRule`` and the four older expert cells' ``RoutedExperts``,
     at their published shapes and 8,192 positions, trace the value and the
-    gradients the parent traced, to the character."""
+    gradients the parent traced, to the character (the Ling cell's delta
+    rule: the text PR 45 gave it, see above)."""
     assert traced_programs[node] == PARENT_PROGRAMS[node]
 
 

@@ -372,27 +372,194 @@ def test_delta_heads_a_step_count_the_channel_decays_tiles():
     assert seq.SUB_CHUNK == pk.DELTA_SUB_CHUNK == 16
 
 
-@pytest.mark.parametrize("decay", ["head", "channel"])
+@pytest.mark.parametrize("dims,key_heads,chunk,t,channel,takes", [
+    ((32, 128, 128), 32, 64, 8192, True, True),     # the Ling cell
+    ((32, 128, 128), 16, 64, 8192, False, True),    # the Qwen3-Next cell
+    ((15, 96, 192), 15, 64, 8192, False, False),    # the Olmo cell: part lanes
+    ((4, 128, 256), 4, 32, 64, True, True),         # values of two lane tiles
+    ((2, 128, 8), 2, 16, 32, True, False),          # values of part of one
+    ((32, 128, 128), 32, 64, 8192 + 32, True, False),  # a part chunk
+    ((32, 128, 128), 32, 24, 8184, False, False),  # bfloat16 tiles: 16 rows
+    ((8, 128, 128), 1, 64, 128, False, False)],     # 4 heads a step, 8 a key
+    ids=["ling", "qwen3_next", "olmo", "values256", "values8", "part_chunk",
+         "chunk24", "steps_split_a_key_head"])
+def test_delta_rows_applicable(dims, key_heads, chunk, t, channel, takes):
+    """Where the chunk kernels read the op's rows as the projections leave
+    them: heads of whole lane tiles, sequences of whole chunks of whole
+    bfloat16 sublane tiles, a step's value heads covering whole key heads.
+    What it refuses keeps the head-major entry."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    assert pk.delta_rows_applicable(dims, key_heads, chunk, t,
+                                    channel) is takes
+
+
+ROWS_CASES = {
+    # value heads, key heads, a decay a key channel, gate_floor, neg_eigval
+    "two_value_heads_a_key_head": (2, 1, False, 0.0, False),
+    "channel_bounded_gate": (2, 2, True, -5.0, True),
+    "channel_softplus_gate": (2, 2, True, 0.0, False),
+}
+
+
+def rows_case(case, dtype, dk=128, dv=128, t=32, chunk=16, n=2):
+    """The op at 128 keys and values a head over two sequences of two
+    chunks, its inputs in ``dtype`` (``A_log`` and ``dt_bias`` float32, as
+    the executor hands them), a cotangent in ``dtype``, and the same
+    function through ``gated_delta_chunked`` / ``_channel`` and autodiff."""
+    from mxnet_tpu.ops import seq
+    from mxnet_tpu.ops.registry import OpContext, create_operator
+
+    h, hk, channel, floor, neg = ROWS_CASES[case]
+    rng = np.random.default_rng(4)
+    rows, wide = n * t, h * dk if channel else h
+
+    def normal(*shape):
+        return rng.standard_normal(shape).astype(np.float32)
+
+    inputs = [jnp.asarray(x, dtype) for x in (
+        normal(rows, hk * dk), normal(rows, hk * dk), normal(rows, h * dv),
+        normal(rows, wide), normal(rows, h))] + [
+        jnp.asarray(np.log(rng.uniform(1.0, 4.0, h)), jnp.float32),
+        jnp.asarray(normal(wide) - 2.0)]
+    head = jnp.asarray(normal(rows, h * dv), dtype).astype(jnp.float32)
+    op = create_operator("GatedDeltaRule", num_heads=h, num_key_heads=hk,
+                         key_dim=dk, value_dim=dv, chunk=chunk, seq_len=t,
+                         gate_floor=floor, neg_eigval=neg)
+
+    def through_op(inputs):
+        o = op.apply(OpContext(True), list(inputs), [])[0][0]
+        return jnp.sum(o.astype(jnp.float32) * head), o
+
+    def through_bodies(inputs):
+        q, k, v, a, b, a_log, dt_bias = (x.astype(jnp.float32)
+                                         for x in inputs)
+
+        def unit(x):
+            x = x.reshape(n, t, hk, dk)
+            return jnp.repeat(x * jax.lax.rsqrt(
+                jnp.sum(x * x, -1, keepdims=True) + op.NORM_EPS), h // hk, 2)
+
+        rate = jnp.repeat(jnp.exp(a_log), wide // h)
+        g = floor * jax.nn.sigmoid(rate * (a + dt_bias)) if floor \
+            else -rate * jax.nn.softplus(a + dt_bias)
+        g = g.reshape((n, t, h, dk) if channel else (n, t, h))
+        beta = jax.nn.sigmoid(b).reshape(n, t, h) * (2.0 if neg else 1.0)
+        body = seq.gated_delta_chunked_channel if channel \
+            else seq.gated_delta_chunked
+        q, k, v = unit(q) * dk ** -0.5, unit(k), v.reshape(n, t, h, dv)
+        o = jnp.stack([body(q[i], k[i], v[i], g[i], beta[i], chunk)[0]
+                       for i in range(n)]).reshape(rows, h * dv)
+        # the op's one rounding of the result
+        o = o.astype(dtype)
+        return jnp.sum(o.astype(jnp.float32) * head), o
+
+    return inputs, through_op, through_bodies
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+def test_row_major_entry_against_the_chunked_bodies(case, dtype):
+    """The chunk kernels reading the op's rows where they lie (interpreted
+    here): the casts, ``q / |q| / sqrt(K)`` and ``k / |k|`` a KEY head, a
+    channel's gate in both forms and the value heads' shared key head all
+    happen in VMEM. Against the same function through the XLA bodies and
+    autodiff: the output and all seven gradients, from float32 inputs
+    (nothing is rounded: the float32 tolerance) and from bfloat16 ones (the
+    result and each wide gradient rounded once, to bfloat16, at the store;
+    ``A_log``'s and ``dt_bias``'s gradients are float32 sums over every row
+    and hold far inside a bfloat16 step). Counted
+    ``lower.delta_rule_layout.rows``."""
+    inputs, through_op, through_bodies = rows_case(case, jnp.dtype(dtype))
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        (_, got_o), got = jax.value_and_grad(through_op, has_aux=True)(inputs)
+        assert telemetry.peek("lower.delta_rule_layout.rows") == 1
+        assert not telemetry.peek("lower.delta_rule_layout.heads")
+        assert delta_counters() == (1, 0)
+    finally:
+        telemetry.disable()
+    (_, want_o), want = jax.value_and_grad(through_bodies, has_aux=True)(
+        inputs)
+    assert got_o.dtype == want_o.dtype == jnp.dtype(dtype)
+    rounded = dtype == "bfloat16"
+    close(got_o.astype(jnp.float32), want_o.astype(jnp.float32),
+          8e-3 if rounded else 1e-5)
+    for name, g, w in zip(("query", "key", "value", "a", "b", "A_log",
+                           "dt_bias"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))), name
+        whole = name in ("A_log", "dt_bias")
+        close(g.astype(jnp.float32), w.astype(jnp.float32),
+              (1e-3 if whole else 8e-3) if rounded else 2e-5)
+
+
+def test_heads_of_part_lane_tiles_keep_the_head_major_entry():
+    """15 heads of 96 keys and 192 values (the Olmo cell's) are no whole
+    lane tiles: the node counts ``lower.delta_rule_layout.heads`` and
+    traces the program it did before the row-major entry came, float32
+    ``[B, H, T, K]`` operands through transposes (its text is held to the
+    parent's by hash in ``tests/test_qwen3_next.py``, ``olmo.delta``)."""
+    from test_nemotron_h import _sub_jaxprs
+
+    from mxnet_tpu.ops.registry import OpContext, create_operator
+
+    t, h, dk, dv = 128, 15, 96, 192
+    op = create_operator("GatedDeltaRule", num_heads=h, key_dim=dk,
+                         value_dim=dv, chunk=64, seq_len=t, neg_eigval=True)
+    shapes = op.infer_shape([(t, h * dk)] + [None] * 6)[0]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32 if i > 4 else jnp.bfloat16)
+            for i, s in enumerate(shapes)]
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        jaxpr = jax.make_jaxpr(
+            lambda *a: op.apply(OpContext(True), list(a), [])[0][0])(*args)
+        assert telemetry.peek("lower.delta_rule_layout.heads") == 1
+        assert not telemetry.peek("lower.delta_rule_layout.rows")
+        assert delta_counters() == (1, 0)
+    finally:
+        telemetry.disable()
+    calls = [eqn for sub in _sub_jaxprs(jaxpr.jaxpr) for eqn in sub.eqns
+             if eqn.primitive.name == "pallas_call"]
+    assert calls and all(
+        v.aval.dtype == jnp.float32 for eqn in calls for v in eqn.invars)
+    assert {tuple(v.aval.shape) for v in calls[0].invars[:3]} == {
+        (1, h, t, dk), (1, h, t, dv)}
+    assert "transpose" in {eqn.primitive.name
+                           for sub in _sub_jaxprs(jaxpr.jaxpr)
+                           for eqn in sub.eqns}
+
+
+@pytest.mark.parametrize("decay", ["head", "channel", "rows_head",
+                                   "rows_channel"])
 def test_gated_delta_scan_traces_two_kernels_and_no_scan(decay):
     """``jax.grad`` through the kernel body holds exactly the forward and
     the backward ``pallas_call`` (each twice: the interpreter's and
     Mosaic's branch), every VMEM scratch float32, and neither a ``scan``
     nor a ``triangular_solve``; the XLA body holds both and no kernel. With
     one decay a head and with one a key channel (``g [B, T, H, K]`` at 128
-    keys a head)."""
+    keys a head). ``rows_*``: the same through the row-major entry
+    (``seq.gated_delta_rows`` from bfloat16 rows, two value heads a key
+    head; a channel's gate formed in the kernel), where besides nothing a
+    row wide is left beside the kernels: no ``transpose`` of more than the
+    per-head scalars, no ``broadcast_in_dim`` of a key head, no float32
+    array of a position's keys or values."""
     from test_nemotron_h import _sub_jaxprs
 
-    from mxnet_tpu.ops import seq
+    from mxnet_tpu.ops import pallas_kernels as pk, seq
 
-    if decay == "head":
-        args = delta_scan_args(0, 1, 128, 2, 8, 16)
-    else:
-        q, k, v, g, beta = delta_scan_args(0, 1, 128, 2, 128, 128)
-        args = (q, k, v, g[..., None] * jnp.linspace(0.1, 1.0, 128), beta)
-    for kernel in (True, False):
-        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
-            seq.gated_delta_scan(*a, 64, kernel)), argnums=range(5)))(*args)
-        names, scratch, prims = [], [], set()
+    def traced(fn, args):
+        """The value and every gradient of ``fn``: ``jax.vjp`` under a
+        cotangent of the result's own type, so that no cast of the result
+        stands beside the kernels."""
+        def both(do, *a):
+            o, back = jax.vjp(fn, *a)
+            return o, back(do)
+
+        jaxpr = jax.make_jaxpr(both)(jax.eval_shape(fn, *args), *args)
+        names, scratch, prims, outside = [], [], set(), []
         for sub in _sub_jaxprs(jaxpr.jaxpr):
             for eqn in sub.eqns:
                 prims.add(eqn.primitive.name)
@@ -400,13 +567,63 @@ def test_gated_delta_scan_traces_two_kernels_and_no_scan(decay):
                     names.append(eqn.params["name"])
                     scratch += [a.dtype for a in
                                 eqn.params["grid_mapping"].scratch_avals]
+        for eqn in jaxpr.jaxpr.eqns:
+            # the kernels' callers are jitted: look inside them, not inside
+            # the kernels
+            inner = eqn.params["jaxpr"].jaxpr.eqns \
+                if eqn.primitive.name in ("pjit", "jit") else [eqn]
+            outside += [e for e in inner if e.primitive.name != "pallas_call"]
+        return names, scratch, prims, outside
+
+    def kernels_alone(names, scratch, prims):
+        assert sorted(names) == ["delta_chunk_backward"] * 2 \
+            + ["delta_chunk_forward"] * 2, names
+        assert len(scratch) == 4 and all(
+            d == jnp.float32 for d in scratch), scratch
+        assert not prims & {"scan", "triangular_solve", "while",
+                            "checkpoint", "remat"}, prims
+
+    if decay.startswith("rows"):
+        channel = decay == "rows_channel"
+        t, h, hk, dk, dv = 128, 4, 4 if channel else 2, 128, 128
+        rng = np.random.default_rng(0)
+
+        def normal(*shape, dtype=jnp.bfloat16):
+            return jnp.asarray(rng.standard_normal(shape), dtype)
+
+        f32 = jnp.float32
+        args = (normal(t, hk * dk), normal(t, hk * dk), normal(t, h * dv)) + (
+            (normal(t, h * dk), jax.nn.sigmoid(normal(1, t, h, dtype=f32)),
+             jnp.ones((1, h * dk), f32), -jnp.ones((1, h * dk), f32))
+            if channel else
+            (-jax.nn.softplus(normal(1, t, h, dtype=f32)),
+             jax.nn.sigmoid(normal(1, t, h, dtype=f32)), None, None))
+        spec = pk.DeltaRows(t, h, hk, dk, dv, 64, -5.0 if channel else 0.0,
+                            1e-6)
+        args = tuple(a for a in args if a is not None)
+        names, scratch, prims, outside = traced(
+            lambda *a: seq.gated_delta_rows(
+                *a, *(None,) * (7 - len(a)), spec), args)
+        kernels_alone(names, scratch, prims)
+        for eqn in outside:
+            for v in list(eqn.invars) + list(eqn.outvars):
+                shape = getattr(v.aval, "shape", ())
+                wide = int(np.prod(shape)) >= t * hk * min(dk, dv)
+                assert not (wide and v.aval.dtype == f32 and len(shape) != 5
+                            ), eqn      # 5: the chunk-start states
+                assert not (wide and eqn.primitive.name in (
+                    "transpose", "broadcast_in_dim")), eqn
+        return
+    if decay == "head":
+        args = delta_scan_args(0, 1, 128, 2, 8, 16)
+    else:
+        q, k, v, g, beta = delta_scan_args(0, 1, 128, 2, 128, 128)
+        args = (q, k, v, g[..., None] * jnp.linspace(0.1, 1.0, 128), beta)
+    for kernel in (True, False):
+        names, scratch, prims, _ = traced(
+            lambda *a: seq.gated_delta_scan(*a, 64, kernel), args)
         if kernel:
-            assert sorted(names) == ["delta_chunk_backward"] * 2 \
-                + ["delta_chunk_forward"] * 2, names
-            assert len(scratch) == 4 and all(
-                d == jnp.float32 for d in scratch), scratch
-            assert not prims & {"scan", "triangular_solve", "while",
-                                "checkpoint", "remat"}, prims
+            kernels_alone(names, scratch, prims)
         else:
             assert not names and {"scan", "triangular_solve"} <= prims
 
