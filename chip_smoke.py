@@ -232,12 +232,13 @@ def _fwd_bwd(fn, args):
     return (out,) + tuple(grads)
 
 
-#: the kernel phase's cases: ``rtc``'s entry point and the four families of
+#: the kernel phase's cases: ``rtc``'s entry point and the five families of
 #: Pallas kernels the language cells run (``ops/pallas_kernels.py``)
 KERNEL_CASES = ("rtc axpy", "ssd_scan", "gated_delta_scan head",
                 "gated_delta_scan channel", "gated_delta_rows head",
                 "gated_delta_rows channel", "grouped_experts relu2",
-                "grouped_experts swiglu", "attention_relayout")
+                "grouped_experts swiglu", "attention_relayout",
+                "attention_backward float32", "attention_backward bfloat16")
 
 
 def _kernel_case(name, device, small):
@@ -257,7 +258,11 @@ def _kernel_case(name, device, small):
     (its row-major entry: 4.75e-6, PR 45, the largest over the result and
     seven gradients: ``dt_bias``'s, a float32 sum over every row taken a
     chunk at a time in the kernel); 0 for the relayout pass, which has no
-    product."""
+    product; 2.03e-3 / 1.18e-3 for attention's backward pass with float32 /
+    bfloat16 operands (PR 46; the float32 case's products pass the MXU at
+    the default precision as the experts' do; the bfloat16 case hands both
+    sides bfloat16 operands and the body casts them up: what is read is the
+    rounding of ``p``, ``ds`` and the results, 1.22e-3 on the interpreter)."""
     import jax
     import jax.numpy as jnp
 
@@ -364,6 +369,29 @@ def _kernel_case(name, device, small):
         return (lambda x, wts, *ws: moe.grouped_experts_kernel(
             x, ws, wts, *layout, gated),
             lambda x, wts, *ws: loop(x, *ws, wts, *layout), args, 7e-3)
+    if name.startswith("attention_backward"):
+        # JAX's splash forward kernel and this repo's one backward kernel
+        # against the blockwise body: the Ling cell's kind of head (keys of
+        # 256 beside values of 128; 4 groups of two heads here) over four
+        # blocks of 512, ten pairs of them; small: a group of two 64-wide
+        # heads over two blocks
+        dtype = jnp.dtype(name.split()[1])
+        b, hkv, g, t, d, dv = (1, 1, 2, 1024, 64, 64) if small \
+            else (1, 4, 2, 2048, 256, 128)
+        assert pk.attention_backward_applicable(t, d, dv, dtype)
+        args = tuple(x.astype(dtype) for x in (
+            put(b, hkv, g, t, d, scale=d ** -0.5), put(b, hkv, t, d),
+            put(b, hkv, t, dv)))
+
+        def body(q, k, v):
+            q, k, v = (x.astype(f32) for x in (q, k, v))
+            out = jax.vmap(lambda q, k, v: attention.attend_blockwise(
+                q.transpose(2, 0, 1, 3), k.transpose(1, 0, 2),
+                v.transpose(1, 0, 2), 1.0))(q, k, v)
+            return out.transpose(0, 2, 3, 1, 4)
+
+        return (attention.attend_splash, body, args,
+                7e-3 if dtype == f32 else 4e-3)
     assert name == "attention_relayout"
     # the GLM cell's queries: 20 heads of 256, the last 64 columns turned;
     # small: two 64-wide heads in one 128-lane tile, turned whole

@@ -26,9 +26,16 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   keys (``value_dim``; whole lanes): the kernel takes ``v`` at its own
   width as it stands in JAX, and the result is ``value_dim`` wide. One
   call a key/value head: its ``G`` query
-  heads read that one head's keys, so nothing is repeated; forward and
-  backward are blockwise in the kernel (scores and softmax float32 in
-  VMEM, products in the compute dtype), blocks above the diagonal are
+  heads read that one head's keys, so nothing is repeated. The forward
+  pass is JAX's kernel; the backward pass is this repo's ONE kernel
+  (``pallas_kernels.attention_backward``, counted ``lower.
+  attention_backward.fused``: a pair of blocks' scores, ``exp`` and
+  ``do v^T`` formed once for ``dq``, ``dk`` and ``dv``, five products where
+  JAX's ``dq`` and ``dkv`` kernels run seven) wherever ``pallas_kernels.
+  attention_backward_applicable`` takes the shape, and JAX's two kernels
+  (``.split``) where it does not. Both passes are blockwise in their kernel
+  (scores and softmax float32 in VMEM, products in the compute dtype,
+  accumulators float32), blocks above the diagonal are
   skipped, and no ``[T, T]`` tensor reaches HBM in either pass. The XLA
   lowering below wrote its float32 scores out and read them back several
   times a pass: 577 of a 1,104 ms step at 8,192 tokens on the v5e
@@ -137,7 +144,13 @@ def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0):
 
 
 @functools.lru_cache(None)
-def _splash_kernel(t, group, block, interpret, keep_name):
+def _splash_kernel(t, group, block, interpret, keep_name, residuals=False,
+                   backward=True):
+    """JAX's multi-query splash kernel over ``group`` causal heads of ``t``
+    positions. With ``backward`` it differentiates itself, through JAX's
+    ``dq`` and ``dkv`` kernels; without, it is the forward kernel alone,
+    and with ``residuals`` that returns ``out, (log-sum-exp,)``, float32 a
+    (head, position): what ``attend_splash``'s own backward pass reads."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
 
@@ -145,36 +158,77 @@ def _splash_kernel(t, group, block, interpret, keep_name):
 
     mask = masks.MultiHeadMask([masks.CausalMask((t, t))
                                 for _ in range(group)])
-    sizes = kernel.BlockSizes(
-        block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        block_q_dq=block, block_kv_dq=block)
+    sizes = dict(block_q=block, block_kv=block, block_kv_compute=block)
+    if backward:
+        sizes.update(
+            block_q_dkv=block, block_kv_dkv=block,
+            block_kv_dkv_compute=block, block_q_dq=block, block_kv_dq=block)
     # the kernel's block tables are arrays made here: outside any trace,
     # or a cached kernel would carry one program's tracers into the next
     with jax.ensure_compile_time_eval():
         return kernel.make_splash_mqa_single_device(
-            mask, block_sizes=sizes, interpret=interpret,
+            mask, block_sizes=kernel.BlockSizes(**sizes),
+            interpret=interpret, save_residuals=residuals,
             residual_checkpoint_name=keep_name)
 
 
-def attend_splash(q, k, v, keep_name=None):
-    """``q [B, Hkv, G, T, D]`` (already scaled by 1/sqrt(D)), ``k, v [B,
-    Hkv, T, D]`` -> ``[B, Hkv, G, T, D]``: one multi-query kernel call a
-    (sequence, key/value head). The interpreter on ``cpu``, the Mosaic
-    kernel elsewhere (``pallas_kernels.pallas_call``'s rule). Under
-    ``keep_name`` the kernel marks its output and log-sum-exp, the
-    backward kernels' residuals, for a recomputation to keep."""
+def attend_splash(q, k, v, keep_name=None, fused=True):
+    """``q [B, Hkv, G, T, D]`` (already scaled by 1/sqrt(D)), ``k [B, Hkv,
+    T, D]``, ``v [B, Hkv, T, Dv]`` -> ``[B, Hkv, G, T, Dv]``: one
+    multi-query kernel call a (sequence, key/value head). The interpreter
+    on ``cpu``, the Mosaic kernel elsewhere (``pallas_kernels.
+    pallas_call``'s rule). Under ``keep_name`` the forward kernel marks its
+    output and log-sum-exp, the backward pass's residuals, for a
+    recomputation to keep.
+
+    ``fused`` (``pallas_kernels.attention_backward_applicable``): the
+    backward pass is ONE kernel of five products, ``pallas_kernels.
+    attention_backward``, under a ``custom_vjp`` of this function's; where
+    the rule refuses a shape JAX's kernel differentiates itself (its ``dq``
+    and ``dkv`` kernels: seven products, the same result in another order
+    of the float32 sums)."""
     import jax
+    import jax.numpy as jnp
+
+    from .pallas_kernels import attention_backward
 
     t, group = q.shape[3], q.shape[2]
     block = min(SPLASH_BLOCK, t)
 
-    def run(interpret):
-        one = _splash_kernel(t, group, block, interpret, keep_name)
-        return lambda q, k, v: jax.vmap(jax.vmap(one))(q, k, v)
+    def forward(**how):
+        def run(interpret):
+            one = _splash_kernel(t, group, block, interpret,
+                                 None if fused else keep_name, **how)
+            return lambda q, k, v: jax.vmap(jax.vmap(one))(q, k, v)
 
-    return jax.lax.platform_dependent(q, k, v, cpu=run(True),
-                                      default=run(False))
+        return lambda q, k, v: jax.lax.platform_dependent(
+            q, k, v, cpu=run(True), default=run(False))
+
+    if not fused:
+        return forward()(q, k, v)
+
+    attend = jax.custom_vjp(forward(backward=False))
+
+    def attend_fwd(q, k, v):
+        out, (lse,) = forward(residuals=True, backward=False)(q, k, v)
+        if keep_name is not None:
+            # named HERE: a name inside JAX's own ``custom_vjp``, which
+            # this rule calls and does not differentiate, is out of a
+            # recomputation's sight, and the kernel would run twice a step
+            out, lse = (jax.ad_checkpoint.checkpoint_name(x, keep_name)
+                        for x in (out, lse))
+        return out, (q, k, v, out, lse)
+
+    def attend_bwd(kept, do):
+        q, k, v, out, lse = kept
+        # a float32 sum over the head's columns: XLA reads both in the
+        # compute dtype and writes one number a (head, position)
+        di = jnp.einsum("bhgtd,bhgtd->bhgt", out.astype(jnp.float32),
+                        do.astype(jnp.float32))
+        return attention_backward(q, k, v, do, lse, di)
+
+    attend.defvjp(attend_fwd, attend_bwd)
+    return attend(q, k, v)
 
 
 def _block(q, k, v, start, scale):
@@ -340,7 +394,11 @@ class CausalAttention(Operator):
         q = _relaid(q, tables, batch=b, heads=hq, half=half, scale=scale)
         k = _relaid(k, tables, batch=b, heads=hkv, half=half)
         v = _relaid(v, batch=b, heads=hkv)
+        fused = pallas_kernels.attention_backward_applicable(t, d, vd,
+                                                             q.dtype)
+        _tel.inc("lower.attention_backward.%s"
+                 % ("fused" if fused else "split"))
         out = attend_splash(q.reshape(b, hkv, hq // hkv, t, d), k, v,
-                            ctx.kept.get("attention"))
+                            ctx.kept.get("attention"), fused)
         return [_relaid(out.reshape(b, hq, t, vd), back=True, batch=b,
                         heads=hq)], []

@@ -7,16 +7,18 @@ interpreter (so every kernel is testable without hardware), one lowered
 for a TPU gets the compiled kernel, and nothing in the process can flip
 that. ``rtc`` compiles a user's kernel source through it.
 
-Four families of kernels follow, each written from a trace of the
+Five families of kernels follow, each written from a trace of the
 benchmark cell it aimed at and each with a line in the ledger there
 (``docs/pallas.md``): the state-space scan's chunk kernels (``SSMScan``),
 the gated delta rule's with a decay a head or a key channel
 (``GatedDeltaRule``), the routed experts' grouped products
-(``RoutedExperts``) and the pass that takes the splash attention kernel
-its operands (``CausalAttention``). Each has both passes written out (the
-operator joins them under one ``jax.custom_vjp``) and an ``*_applicable``
-rule over shapes; the operator chooses the kernel or its ``jax.numpy``
-body when the node is traced and counts the choice (``lower.*``).
+(``RoutedExperts``), the pass that takes the splash attention kernel
+its operands and the backward pass of that attention as one kernel
+(``CausalAttention``; its forward product is JAX's). Each has both passes
+written out (the operator joins them under one ``jax.custom_vjp``) and an
+``*_applicable`` rule over shapes; the operator chooses the kernel or its
+``jax.numpy`` body when the node is traced and counts the choice
+(``lower.*``).
 """
 from __future__ import annotations
 
@@ -35,7 +37,8 @@ __all__ = ["pallas_available", "pallas_call", "ssd_chunk_applicable",
            "DeltaRows", "delta_rows_applicable", "delta_rows_forward",
            "delta_rows_backward",
            "grouped_experts_applicable", "grouped_experts_forward",
-           "grouped_experts_backward", "attention_relayout"]
+           "grouped_experts_backward", "attention_relayout",
+           "attention_backward_applicable", "attention_backward"]
 
 # lanes of a tile
 TILE_N = 128
@@ -2160,3 +2163,187 @@ def attention_relayout(x, tables=(), *, batch, heads, half=0, scale=1.0,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="attention_rows_from_heads" if back
         else "attention_heads_from_rows")
+
+
+# ---------------------------------------------------------------------------
+# Causal attention's backward pass as one kernel
+# (``ops/attention.py`` ``attend_splash``, the ``pallas_splash`` path)
+# ---------------------------------------------------------------------------
+#
+# The forward product is JAX's splash kernel; it leaves the output and the
+# float32 log-sum-exp a (head, position). JAX's backward pass is two kernels,
+# ``dq`` (three products) and ``dkv`` (four), and each forms the scores, the
+# ``exp`` and ``do v^T`` for itself. Here one grid step is one (query block,
+# key block) pair of the causal half, of one query head of one key/value
+# head of one sequence, and forms them ONCE for all three gradients, five
+# products where seven ran:
+#
+#   s^T = k q^T                      [bkv, bq]   (keys by rows: ``lse`` and
+#   p^T = exp(s^T - lse)                          ``di`` are rows ``[1, bq]``
+#   dv += p^T do                                  and no score is transposed
+#   dp^T = v do^T                                 for ``dv`` and ``dk``)
+#   ds^T = p^T (dp^T - di)
+#   dk += ds^T q
+#   dq += ds k                       (the one transposed operand a pair)
+#
+# Query blocks are the outer loop of a head and key blocks the inner: only
+# the pairs at or under the diagonal are grid steps, from a table that is
+# prefetched into SMEM (a sequence of 16 x 16 blocks has 136), and only the
+# blocks ON the diagonal pay for a mask. ``dq`` of the current query block
+# is a float32 scratch across its key blocks; ``dk`` and ``dv`` of the WHOLE
+# key/value head are float32 scratch ``[T, D]`` / ``[T, Dv]`` across the
+# head's steps, the group's query heads adding into the same two (16 MiB at
+# 8,192 positions of 256 + 256 columns: above Mosaic's default, hence
+# ``vmem_limit_bytes``; a v5e core has 128 MiB).
+#
+# Precision: scores, ``exp``, ``dp``, ``ds`` and all three accumulators are
+# float32; the five products take operands in the compute dtype, ``p`` and
+# ``ds`` rounded to it where JAX's two kernels round them; ``dq``, ``dk``,
+# ``dv`` are rounded to the compute dtype once, when complete. No ``[T, T]``
+# array, no float32 copy of an operand and no partial sum reaches HBM.
+
+ATTENTION_BACKWARD_BLOCK = 512
+_ATTENTION_VMEM = 100 << 20     # asked of the compiler; a v5e has 128 MiB
+_ATTENTION_MASKED = -0.7 * float(np.finfo(np.float32).max)   # as splash's
+
+
+def _attention_backward_vmem(t, d, dv, itemsize, block):
+    """Bytes of VMEM the kernel holds at once: the head's two float32
+    accumulators, their results in the compute dtype (an output block is
+    double-buffered), the pair's operands, and its float32 scores."""
+    wide = _lanes(d) + _lanes(dv)
+    return (t * wide * (4 + 2 * itemsize)
+            + block * (2 * _lanes(d) + _lanes(dv)) * (4 * itemsize + 4)
+            + 8 * block * block * 4)
+
+
+def attention_backward_applicable(t, d, dv, dtype) -> bool:
+    """Whether the one-kernel backward pass takes key/value heads of ``t``
+    positions of ``d`` key and ``dv`` value columns (a head's group of query
+    heads adds into the same accumulators, whatever its size): the splash
+    path's own shapes (heads of whole lanes or of 64 columns, whole
+    blocks), a compute dtype the MXU takes, and the head's resident
+    accumulators within the VMEM a v5e may be asked for, 8 MiB left to
+    Mosaic's own."""
+    name = "bfloat16" if str(dtype) == "bfloat16" else np.dtype(dtype).name
+    if name not in ("bfloat16", "float32") or not pallas_available():
+        return False
+    block = min(ATTENTION_BACKWARD_BLOCK, t)
+    if t % block or block % 128 or any(w % 128 and w != 64 for w in (d, dv)):
+        return False
+    return _attention_backward_vmem(t, d, dv, 2 if name == "bfloat16" else 4,
+                                    block) <= _ATTENTION_VMEM - (8 << 20)
+
+
+@functools.lru_cache(None)
+def _causal_pairs(blocks):
+    """The (query block, key block) pairs at or under the diagonal of
+    ``blocks`` x ``blocks``, a query block's pairs together and in key
+    order, the one ON the diagonal last: ``(q, k) [pairs]`` int32."""
+    qs, ks = np.tril_indices(blocks)
+    return qs.astype(np.int32), ks.astype(np.int32)
+
+
+def attention_backward(q, k, v, do, lse, di):
+    """``dq, dk, dv`` of causal attention ``o = softmax(q k^T) v`` from the
+    output's cotangent: ``q [B, Hkv, G, T, D]`` (already scaled), ``k [B,
+    Hkv, T, D]``, ``v [B, Hkv, T, Dv]``, ``do [B, Hkv, G, T, Dv]``, the
+    forward kernel's float32 log-sum-exp ``lse`` and ``di = sum(o * do)``
+    ``[B, Hkv, G, T]`` (the section's comment)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hkv, group, t, d = q.shape
+    dv_ = v.shape[-1]
+    cd = q.dtype
+    block = min(ATTENTION_BACKWARD_BLOCK, t)
+    pairs = _causal_pairs(t // block)
+    npairs = len(pairs[0])
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))       # x y^T
+
+    def kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+               dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+        g, step = pl.program_id(2), pl.program_id(3)
+        qi, ki = qi_ref[step], ki_ref[step]
+        rows = pl.ds(pl.multiple_of(ki * block, block), block)
+
+        @pl.when((g == 0) & (step == 0))
+        def _():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
+
+        @pl.when(ki == 0)
+        def _():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        def pair(diagonal):
+            qb, kb, vb, dob = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+            st = lax.dot_general(kb, qb, nt, preferred_element_type=f32)
+            if diagonal:    # the same block of positions both ways
+                st = jnp.where(
+                    lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                    <= lax.broadcasted_iota(jnp.int32, st.shape, 1),
+                    st, _ATTENTION_MASKED)
+            pt = jnp.exp(st - lse_ref[...])
+            dv_acc[rows, :] += jnp.dot(pt.astype(cd), dob,
+                                       preferred_element_type=f32)
+            dpt = lax.dot_general(vb, dob, nt, preferred_element_type=f32)
+            dst = (dpt - di_ref[...]) * pt
+            dk_acc[rows, :] += jnp.dot(dst.astype(cd), qb,
+                                       preferred_element_type=f32)
+            # transposed in float32, then rounded: the form JAX's own fused
+            # ``dkv`` kernel runs on the chip
+            dq_acc[...] += jnp.dot(dst.T.astype(cd), kb,
+                                   preferred_element_type=f32)
+
+        @pl.when(ki < qi)
+        def _():
+            pair(False)
+
+        @pl.when(ki == qi)
+        def _():
+            pair(True)
+            dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+        @pl.when((g == group - 1) & (step == npairs - 1))
+        def _():
+            dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    def by_query(width):
+        return pl.BlockSpec((None, None, None, block, width),
+                            lambda b, h, g, p, qi, ki: (b, h, g, qi[p], 0))
+
+    def by_key(width):
+        return pl.BlockSpec((None, None, block, width),
+                            lambda b, h, g, p, qi, ki: (b, h, ki[p], 0))
+
+    def whole(width):
+        return pl.BlockSpec((None, None, t, width),
+                            lambda b, h, g, p, qi, ki: (b, h, 0, 0))
+
+    # one number a position: a row ``[1, block]`` of ``[.., 1, T]``
+    row = pl.BlockSpec((None, None, None, 1, block),
+                       lambda b, h, g, p, qi, ki: (b, h, g, 0, qi[p]))
+    return pallas_call(
+        kernel, *pairs, q, k, v, do, lse[:, :, :, None], di[:, :, :, None],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, hkv, group, npairs),
+            in_specs=[by_query(d), by_key(d), by_key(dv_), by_query(dv_),
+                      row, row],
+            out_specs=[by_query(d), whole(d), whole(dv_)],
+            scratch_shapes=[pltpu.VMEM((block, d), f32),
+                            pltpu.VMEM((t, d), f32),
+                            pltpu.VMEM((t, dv_), f32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, cd),
+                   jax.ShapeDtypeStruct(k.shape, cd),
+                   jax.ShapeDtypeStruct(v.shape, cd)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_ATTENTION_VMEM),
+        name="causal_attention_backward")

@@ -7,7 +7,9 @@ the arguments an operator does not keep whole (``jax.eval_shape`` over
 nothing allocated). Every operator that chooses a body from its shapes
 counts the choice once a traced node (``lower.*``), so the counters read
 the Pallas body for every scan, delta-rule, attention and experts node and
-nothing for any XLA fallback; a delta-rule node also counts WHERE its
+nothing for any XLA fallback (an attention node also counts its backward
+pass: ``attention_backward.fused``, this repo's one kernel of five products,
+or ``.split``, JAX's two of seven); a delta-rule node also counts WHERE its
 kernels read the op's wide arrays (``delta_rule_layout.rows``: as the
 projections leave them, at heads of whole lane tiles; ``.heads``: float32
 head-major copies, the Olmo cell's 96 x 192). The toy presets of the
@@ -35,7 +37,8 @@ KERNEL = {
     "SSMScan": ["scan_kernel.pallas_chunked"],
     "GatedDeltaRule": ["delta_rule_kernel.pallas_chunked"],
     "CausalAttention": ["attention_kernel.pallas_splash",
-                        "attention_layout.fused"],
+                        "attention_layout.fused",
+                        "attention_backward.fused"],
     "RoutedExperts": ["experts_kernel.pallas_grouped",
                       "experts_plan.column_sort"],
     "GatedShortConv": ["shortconv_body.xla_fused"],
@@ -48,7 +51,7 @@ DELTA_LAYOUT = {
 }
 FALLBACKS = ["scan_kernel.xla_chunked", "delta_rule_kernel.xla_chunked",
              "attention_kernel.xla_blockwise", "attention_layout.split",
-             "experts_kernel.xla_loop"]
+             "attention_backward.split", "experts_kernel.xla_loop"]
 # the operators each cell's cut of its model holds, by node
 CELLS = {
     "nemotron3_nano_l9_e8of128_bf16": {

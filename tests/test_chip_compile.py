@@ -112,11 +112,13 @@ def test_delta_rows_kernels_compile_for_the_chip(one_chip, heads, key_heads,
     ids=["256-wide", "64-wide-grouped", "256-wide-keys-128-wide-values",
          "256-wide-grouped"])
 def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
-    """The kernel call at three of the benchmark's cells: latent attention's
+    """The kernel call at four of the benchmark's cells: latent attention's
     20 one-head groups of 256 columns, LFM2's 8 groups of four heads of
     64, half a lane tile, Ling's 32 heads whose 192-wide keys go widened
     to 256 beside values of 128, and Qwen3-Next's 2 groups of eight heads of
-    256, over 8,192 positions, forward and backward."""
+    256, over 8,192 positions: JAX's forward kernel and this repo's one
+    backward kernel, two custom calls where JAX's own backward pass made
+    three."""
     from mxnet_tpu.ops import attention
 
     b, t = 1, 8192
@@ -133,7 +135,35 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             shape(b, h, group, t, d), shape(b, h, t, d),
             shape(b, h, t, dv)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "causal_attention_backward" in text
+
+
+@pytest.mark.parametrize("h,group,d,dv", [
+    (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128), (2, 8, 256, 256),
+    (2, 16, 128, 128), (15, 1, 128, 128)],
+    ids=["glm", "lfm2", "ling", "qwen3-next", "nemotron", "olmo"])
+def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv):
+    """The one-kernel backward pass at the six language cells' shapes over
+    8,192 positions: the head's float32 ``dk`` and ``dv`` resident in VMEM
+    (16 MiB at 256 + 256 columns, above Mosaic's default limit), the
+    contraction over a pair's key rows for ``dq``, the dynamic row slices of
+    the accumulators and half-lane heads are Mosaic's to refuse."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    b, t = 1, 8192
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    assert pk.attention_backward_applicable(t, d, dv, jnp.bfloat16)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(pk.attention_backward).lower(
+            shape(b, h, group, t, d), shape(b, h, t, d), shape(b, h, t, dv),
+            shape(b, h, group, t, dv), shape(b, h, group, t, dtype="float32"),
+            shape(b, h, group, t, dtype="float32")).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("batch,t,heads,d,turned,dtype", [

@@ -186,6 +186,15 @@ def _kernel_case(name):
         return (lambda x, dy, *ws: pk.grouped_experts_backward(
             x, ws, *layout, dy, gated=gated),
             (ones(block, h), ones(block, h)) + ws)
+    if name == "attention_backward":
+        # a group of two 64-wide heads over one block of 128 positions
+        t, d, group = 128, 64, 2
+        assert pk.attention_backward_applicable(t, d, d, f32)
+        assert not pk.attention_backward_applicable(t + 64, d, d, f32)
+        return (pk.attention_backward,
+                (ones(1, 1, group, t, d) * 0.1, ones(1, 1, t, d),
+                 ones(1, 1, t, d), ones(1, 1, group, t, d),
+                 ones(1, 1, group, t) * 4.0, ones(1, 1, group, t)))
     assert name.startswith("attention_relayout")
     from mxnet_tpu.ops import attention
 
@@ -208,12 +217,12 @@ def _kernel_case(name):
     "delta_rows_forward_channel", "delta_rows_backward",
     "delta_rows_backward_channel", "grouped_experts_forward",
     "grouped_experts_forward_gated", "grouped_experts_backward",
-    "attention_relayout", "attention_relayout_back"])
+    "attention_relayout", "attention_relayout_back", "attention_backward"])
 def test_kernels_lower_to_mosaic_for_tpu_and_the_interpreter_for_cpu(name):
     """The interpret decision is taken per lowering: the same traced call
     becomes a Mosaic custom call when lowered for a TPU and interpreter
     HLO when lowered for the CPU — no process-wide answer to flip. A case
-    a kernel the train path ships (the four families a cell has timed and
+    a kernel the train path ships (the five families a cell has timed and
     ``rtc``'s entry), at the smallest shapes its rule admits."""
     fn, args = _kernel_case(name)
     tpu = _lowered_text(fn, args, "tpu")
