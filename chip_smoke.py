@@ -238,7 +238,8 @@ KERNEL_CASES = ("rtc axpy", "ssd_scan", "gated_delta_scan head",
                 "gated_delta_scan channel", "gated_delta_rows head",
                 "gated_delta_rows channel", "grouped_experts relu2",
                 "grouped_experts swiglu", "attention_relayout",
-                "attention_backward float32", "attention_backward bfloat16")
+                "attention_backward float32", "attention_backward bfloat16",
+                "attention_backward bfloat16 window")
 
 
 def _kernel_case(name, device, small):
@@ -374,8 +375,11 @@ def _kernel_case(name, device, small):
         # against the blockwise body: the Ling cell's kind of head (keys of
         # 256 beside values of 128; 4 groups of two heads here) over four
         # blocks of 512, ten pairs of them; small: a group of two 64-wide
-        # heads over two blocks
+        # heads over two blocks. ``window``: the same under a band of 640
+        # keys (a block and a quarter: the edge crosses both pairs behind
+        # the diagonal; small: 384, inside the diagonal block too)
         dtype = jnp.dtype(name.split()[1])
+        window = 0 if "window" not in name else 384 if small else 640
         b, hkv, g, t, d, dv = (1, 1, 2, 1024, 64, 64) if small \
             else (1, 4, 2, 2048, 256, 128)
         assert pk.attention_backward_applicable(t, d, dv, dtype)
@@ -387,11 +391,12 @@ def _kernel_case(name, device, small):
             q, k, v = (x.astype(f32) for x in (q, k, v))
             out = jax.vmap(lambda q, k, v: attention.attend_blockwise(
                 q.transpose(2, 0, 1, 3), k.transpose(1, 0, 2),
-                v.transpose(1, 0, 2), 1.0))(q, k, v)
+                v.transpose(1, 0, 2), 1.0, window=window))(q, k, v)
             return out.transpose(0, 2, 3, 1, 4)
 
-        return (attention.attend_splash, body, args,
-                7e-3 if dtype == f32 else 4e-3)
+        return (lambda q, k, v: attention.attend_splash(q, k, v,
+                                                        window=window),
+                body, args, 7e-3 if dtype == f32 else 4e-3)
     assert name == "attention_relayout"
     # the GLM cell's queries: 20 heads of 256, the last 64 columns turned;
     # small: two 64-wide heads in one 128-lane tile, turned whole

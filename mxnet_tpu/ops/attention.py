@@ -1,9 +1,34 @@
-"""Causal grouped-head attention with rotary positions.
+"""Causal grouped-head attention with rotary positions, over every earlier
+key or over a sliding window of them.
 
 Beyond the reference (2016 MXNet has no attention operator); the op the
 ``*`` layers of a hybrid language model lower to. Layout as the other
 language-model ops (``ops/seq.py``): ``[rows, width]`` activations whose
 rows are whole sequences of ``seq_len`` positions.
+
+**The mask** (counted ``lower.attention_mask.causal`` / ``.window`` once a
+traced op): position ``i`` reads the keys ``j <= i``, or with ``window`` =
+``w`` the band ``i - w < j <= i``: ``w`` keys, its own among them. A window
+that holds the whole sequence (``w >= seq_len``) IS the causal op: the same
+program. Under a band both lowerings below skip what it empties: the splash
+forward kernel takes ``LocalMask`` (left ``w - 1``, right 0) and visits only
+the blocks that hold a score; the backward kernel's grid is the band's pairs
+of blocks, ``qi - ceil((w - 1) / block) <= ki <= qi``
+(``pallas_kernels.attention_block_pairs``; counted ``lower.attention_
+window.block_pairs`` beside what the causal half of the same blocks holds,
+``.block_pairs_causal``: 31 and 136 at 8,192 positions, blocks of 512 and a
+window of 512), the pair on the diagonal masked above it, the pairs the
+band's lower edge crosses masked below it (with ``w`` = a block, half of the
+one off-diagonal pair: the kernels then run at about half the causal ones'
+share of their roofline, PERF.md section 6, PR 47), the pairs between
+unmasked; the XLA body slices a query block's keys from the first its first
+query reaches.
+
+**Rotary frequencies** are the plain ``theta^(-2i/r)`` or, with
+``rope_factor`` > 1, YaRN's blend of them and their ``1 / factor``
+(:func:`rope_frequencies`), cos and sin times ``rope_attention_factor``;
+either way they are float32 tables made once on the host from float64
+angles, and ride the one relayout pass below.
 
 Which implementation a program takes is decided from the shapes when it
 is traced, and counted (``lower.attention_kernel.<name>``):
@@ -84,39 +109,74 @@ SPLASH_BLOCK = 512
 _NEG = -1e30
 
 
+def rope_frequencies(theta, half, scaling=None):
+    """The ``half`` rotary frequencies of a turned width ``2 * half``,
+    float64, and what cos and sin are multiplied by. ``scaling`` is ``None``
+    (the plain ``theta^(-i / half)``, factor 1) or YaRN's ``(factor,
+    original_positions, beta_fast, beta_slow, attention_factor)`` (Peng et
+    al., arXiv:2309.00071, as the published ``_compute_yarn_parameters``
+    writes it): a frequency that turns more than ``beta_fast`` times over
+    the original context stays, one that turns less than ``beta_slow`` times
+    is divided by ``factor``, and between the two columns where that happens
+    (``floor`` / ``ceil``, within the turned width) the two are blended by a
+    linear ramp; ``attention_factor`` 0 is ``0.1 ln(factor) + 1``."""
+    plain = theta ** (-np.arange(half, dtype=np.float64) / half)
+    if scaling is None:
+        return plain, 1.0
+    factor, original, beta_fast, beta_slow, attention_factor = scaling
+    if not attention_factor:
+        attention_factor = 0.1 * np.log(factor) + 1.0 if factor > 1 else 1.0
+    if factor == 1:
+        return plain, float(attention_factor)
+
+    def column(turns):
+        return 2 * half * np.log(original / (turns * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    lo = max(np.floor(column(beta_fast)), 0)
+    hi = min(np.ceil(column(beta_slow)), 2 * half - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - lo) / (hi - lo), 0, 1)
+    return plain * (1 - ramp) + plain / factor * ramp, float(attention_factor)
+
+
 @functools.lru_cache(None)
-def rope_tables(t, theta, half, lanes=1):
+def rope_tables(t, theta, half, lanes=1, scaling=None):
     """``(C, S) [t, 2 * half]`` float32: ``C = [cos, cos]``, ``S = [-sin,
     sin]`` of the half-split convention, position by row; widened on the
-    left with 1 and 0 to a whole number of ``lanes`` columns."""
-    inv = theta ** (-np.arange(half, dtype=np.float64) / half)
+    left with 1 and 0 to a whole number of ``lanes`` columns. ``scaling``:
+    :func:`rope_frequencies`' (its attention factor is in both tables)."""
+    inv, factor = rope_frequencies(theta, half, scaling)
     ang = np.arange(t, dtype=np.float64)[:, None] * inv[None]
-    cos = np.cos(ang).astype(np.float32)
-    sin = np.sin(ang).astype(np.float32)
+    # float64 times 1.0 is itself: factor 1 gives the plain tables exactly
+    cos = (np.cos(ang) * factor).astype(np.float32)
+    sin = (np.sin(ang) * factor).astype(np.float32)
     pad = ((0, 0), (-2 * half % lanes, 0))
     return (np.pad(np.concatenate([cos, cos], 1), pad, constant_values=1),
             np.pad(np.concatenate([-sin, sin], 1), pad))
 
 
-def relayout_tables(t, theta, half, head_dim):
+def relayout_tables(t, theta, half, head_dim, scaling=None):
     """:func:`rope_tables` as ``pallas_kernels.attention_relayout`` reads
     them: over whole 128-lane tiles of a head, or for a head of 64 columns
     one head's table beside the other's (two heads share a tile); nothing
     where nothing is turned."""
     if not half:
         return ()
-    tables = rope_tables(t, theta, half, min(head_dim, 128))
+    tables = rope_tables(t, theta, half, min(head_dim, 128), scaling)
     return tuple(np.tile(a, (1, max(1, 128 // head_dim))) for a in tables)
 
 
-def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0):
+def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0, scaling=None):
     """Rotary position embedding (Su et al., arXiv:2104.09864), the
     half-split convention of the published modelling code: ``x [T, ...,
     D]``, position = index along ``pos_axis``, over the whole head or, with
     ``rotary_dim``, over the LAST ``rotary_dim`` columns of it (the
-    frequencies are those of a head ``rotary_dim`` wide; the columns before
-    pass through). The result is multiplied by ``scale`` before it is
-    rounded to ``x``'s dtype.
+    frequencies are those of a head ``rotary_dim`` wide, plain or under
+    ``scaling``: :func:`rope_frequencies`; the columns before pass through).
+    The result is multiplied by ``scale`` before it is rounded to ``x``'s
+    dtype.
 
     Written ``x * C + partner(x) * S`` over the turned columns (``C, S``:
     :func:`rope_tables`; a column's partner is the one half the turned
@@ -134,7 +194,8 @@ def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0):
         shape = [1] * x.ndim
         shape[pos_axis], shape[-1] = x.shape[pos_axis], 2 * half
         c, s = (a.reshape(shape)
-                for a in rope_tables(x.shape[pos_axis], theta, half))
+                for a in rope_tables(x.shape[pos_axis], theta, half,
+                                     scaling=scaling))
         r = xf[..., keep:]
         partner = jnp.concatenate([r[..., half:], r[..., :half]], axis=-1)
         out = r * c + partner * s
@@ -145,9 +206,12 @@ def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0):
 
 @functools.lru_cache(None)
 def _splash_kernel(t, group, block, interpret, keep_name, residuals=False,
-                   backward=True):
+                   backward=True, window=0):
     """JAX's multi-query splash kernel over ``group`` causal heads of ``t``
-    positions. With ``backward`` it differentiates itself, through JAX's
+    positions, each reading every earlier key or, with ``window``, the last
+    ``window`` (its own among them: ``LocalMask``, whose empty blocks the
+    kernel skips as it skips those above the diagonal). With ``backward`` it
+    differentiates itself, through JAX's
     ``dq`` and ``dkv`` kernels; without, it is the forward kernel alone,
     and with ``residuals`` that returns ``out, (log-sum-exp,)``, float32 a
     (head, position): what ``attend_splash``'s own backward pass reads."""
@@ -156,8 +220,9 @@ def _splash_kernel(t, group, block, interpret, keep_name, residuals=False,
 
     import jax
 
-    mask = masks.MultiHeadMask([masks.CausalMask((t, t))
-                                for _ in range(group)])
+    mask = masks.MultiHeadMask([
+        masks.LocalMask((t, t), (window - 1, 0), 0) if window
+        else masks.CausalMask((t, t)) for _ in range(group)])
     sizes = dict(block_q=block, block_kv=block, block_kv_compute=block)
     if backward:
         sizes.update(
@@ -172,14 +237,15 @@ def _splash_kernel(t, group, block, interpret, keep_name, residuals=False,
             residual_checkpoint_name=keep_name)
 
 
-def attend_splash(q, k, v, keep_name=None, fused=True):
+def attend_splash(q, k, v, keep_name=None, fused=True, window=0):
     """``q [B, Hkv, G, T, D]`` (already scaled by 1/sqrt(D)), ``k [B, Hkv,
     T, D]``, ``v [B, Hkv, T, Dv]`` -> ``[B, Hkv, G, T, Dv]``: one
     multi-query kernel call a (sequence, key/value head). The interpreter
     on ``cpu``, the Mosaic kernel elsewhere (``pallas_kernels.
     pallas_call``'s rule). Under ``keep_name`` the forward kernel marks its
     output and log-sum-exp, the backward pass's residuals, for a
-    recomputation to keep.
+    recomputation to keep. ``window``: 0, every key at or before the
+    query's position; ``w`` (below ``T``), the last ``w`` of them.
 
     ``fused`` (``pallas_kernels.attention_backward_applicable``): the
     backward pass is ONE kernel of five products, ``pallas_kernels.
@@ -198,7 +264,8 @@ def attend_splash(q, k, v, keep_name=None, fused=True):
     def forward(**how):
         def run(interpret):
             one = _splash_kernel(t, group, block, interpret,
-                                 None if fused else keep_name, **how)
+                                 None if fused else keep_name,
+                                 window=window, **how)
             return lambda q, k, v: jax.vmap(jax.vmap(one))(q, k, v)
 
         return lambda q, k, v: jax.lax.platform_dependent(
@@ -225,29 +292,37 @@ def attend_splash(q, k, v, keep_name=None, fused=True):
         # compute dtype and writes one number a (head, position)
         di = jnp.einsum("bhgtd,bhgtd->bhgt", out.astype(jnp.float32),
                         do.astype(jnp.float32))
-        return attention_backward(q, k, v, do, lse, di)
+        return attention_backward(q, k, v, do, lse, di, window)
 
     attend.defvjp(attend_fwd, attend_bwd)
     return attend(q, k, v)
 
 
-def _block(q, k, v, start, scale):
+def _block(q, k, v, start, scale, first=0, window=0):
     """Queries ``q [nq, Hkv, G, D]`` at positions ``start..`` against the
-    keys ``k, v [nk, Hkv, D]`` at positions ``0..nk``."""
+    keys ``k, v [nk, Hkv, D]`` at positions ``first..first + nk``; with
+    ``window`` a query reads the last ``window`` keys at or before it."""
     import jax
     import jax.numpy as jnp
 
     s = jnp.einsum("qhgd,khd->hgqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     qpos = start + jnp.arange(q.shape[0])
-    mask = jnp.arange(k.shape[0])[None, :] <= qpos[:, None]
+    kpos = jnp.arange(k.shape[0])[None, :]
+    if first:
+        kpos = first + kpos
+    mask = kpos <= qpos[:, None]
+    if window:
+        mask = mask & (kpos > qpos[:, None] - window)
     p = jax.nn.softmax(jnp.where(mask, s, _NEG), axis=-1)
     return jnp.einsum("hgqk,khd->qhgd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def attend_blockwise(q, k, v, scale, block=BLOCK_Q):
-    """One sequence: ``q [T, Hkv, G, D]``, ``k, v [T, Hkv, D]``."""
+def attend_blockwise(q, k, v, scale, block=BLOCK_Q, window=0):
+    """One sequence: ``q [T, Hkv, G, D]``, ``k, v [T, Hkv, D]``. A block of
+    queries reads the keys up to its end, under ``window`` from the first
+    key its first query reaches."""
     import jax
     import jax.numpy as jnp
 
@@ -255,8 +330,10 @@ def attend_blockwise(q, k, v, scale, block=BLOCK_Q):
     outs = []
     for i in range(0, t, block):
         n = min(i + block, t)
-        fn = jax.checkpoint(functools.partial(_block, start=i, scale=scale))
-        outs.append(fn(q[i:n], k[:n], v[:n]))
+        first = max(0, i - window + 1) if window else 0
+        fn = jax.checkpoint(functools.partial(
+            _block, start=i, scale=scale, first=first, window=window))
+        outs.append(fn(q[i:n], k[first:n], v[first:n]))
     return jnp.concatenate(outs, axis=0)
 
 
@@ -287,7 +364,13 @@ class CausalAttention(Operator):
     part position: latent attention's 192 + 64). ``value_dim`` gives the
     values, and the result, a width of their own (latent attention whose
     values are narrower than its keys: 128 beside 128 + 64); 0, the
-    default, is ``head_dim``."""
+    default, is ``head_dim``. ``window`` ``w`` makes it sliding-window
+    attention: position ``i`` reads the keys ``i - w < j <= i``, its own
+    among the ``w``; 0, the default, and any ``w >= seq_len`` are plain
+    causal attention, the same program. ``rope_factor`` above 1 scales the
+    rotary frequencies by length as YaRN does (:func:`rope_frequencies`:
+    ``rope_original_positions``, ``rope_beta_fast``, ``rope_beta_slow``,
+    and ``rope_attention_factor`` on cos and sin)."""
 
     name_hint = "causalattention"
     PARAMS = {
@@ -301,6 +384,18 @@ class CausalAttention(Operator):
                             "each head only; 0: the whole head"),
         "value_dim": Param(int, 0, "a head's value (and result) width; 0: "
                            "head_dim"),
+        "window": Param(int, 0, "a position reads the last window keys, its "
+                        "own among them; 0: every earlier key"),
+        "rope_factor": Param(float, 1.0, "YaRN: the length the frequencies "
+                             "are scaled by; 1: the plain ones"),
+        "rope_original_positions": Param(int, 0, "YaRN: the context the "
+                                         "plain frequencies were trained "
+                                         "at"),
+        "rope_beta_fast": Param(float, 32.0),
+        "rope_beta_slow": Param(float, 1.0),
+        "rope_attention_factor": Param(float, 0.0, "YaRN: what cos and sin "
+                                       "are multiplied by; 0: 0.1 "
+                                       "ln(rope_factor) + 1"),
     }
 
     def list_arguments(self):
@@ -324,6 +419,13 @@ class CausalAttention(Operator):
         if self.value_dim < 0:
             raise MXNetError("CausalAttention: value_dim %d"
                              % self.value_dim)
+        if self.window < 0:
+            raise MXNetError("CausalAttention: window %d" % self.window)
+        if self.rope_factor < 1 or (self.rope_factor > 1
+                                    and self.rope_original_positions < 1):
+            raise MXNetError(
+                "CausalAttention: rope_factor %g over %d original positions"
+                % (self.rope_factor, self.rope_original_positions))
         _sequences(q[0], self.seq_len, "CausalAttention")
         vd = self.value_dim or self.head_dim
         return ([q, (q[0], self.num_kv_heads * self.head_dim),
@@ -340,6 +442,15 @@ class CausalAttention(Operator):
             # and a half is widened to two with zero columns
             lanes = vd % 128 == 0 and (d % 128 == 0 or d == 192)
         return lanes and t % 128 == 0 and t % min(SPLASH_BLOCK, t) == 0
+
+    def _rope_scaling(self):
+        """:func:`rope_frequencies`' ``scaling``; ``None``: the plain
+        tables."""
+        if self.rope_factor == 1 and self.rope_attention_factor in (0, 1):
+            return None
+        return (self.rope_factor, self.rope_original_positions,
+                self.rope_beta_fast, self.rope_beta_slow,
+                self.rope_attention_factor)
 
     def remat_results(self, in_shapes, in_types):
         """Kept always under recomputation: the kernel's output and, on
@@ -366,6 +477,11 @@ class CausalAttention(Operator):
         b = q.shape[0] // t
         scale = 1.0 / float(np.sqrt(d))
         half = (self.rotary_dim or d) // 2 if self.rotary else 0
+        scaling = self._rope_scaling() if half else None
+        # a window that holds the whole sequence is no window
+        window = self.window if self.window < t else 0
+        _tel.inc("lower.attention_mask.%s" % ("window" if window
+                                              else "causal"))
         if not (self._splash_applies()
                 and pallas_kernels.pallas_available()):
             _tel.inc("lower.attention_kernel.xla_blockwise")
@@ -373,13 +489,24 @@ class CausalAttention(Operator):
             k = k.reshape(b, t, hkv, d)
             if half:
                 q, k = (rope(x, self.rope_theta, rotary_dim=2 * half,
-                             pos_axis=1) for x in (q, k))
+                             pos_axis=1, scaling=scaling) for x in (q, k))
             out = jax.lax.map(
-                lambda x: attend_blockwise(x[0], x[1], x[2], scale),
+                lambda x: attend_blockwise(x[0], x[1], x[2], scale,
+                                           window=window),
                 (q, k, v.reshape(b, t, hkv, vd)))
             return [ctx.keep(out.reshape(b * t, hq * vd), "attention")], []
         _tel.inc("lower.attention_kernel.pallas_splash")
         _tel.inc("lower.attention_layout.fused")
+        if window:
+            # the pairs of blocks the backward pass walks under the band,
+            # and what the causal half of the same blocks would hold
+            block = min(pallas_kernels.ATTENTION_BACKWARD_BLOCK, t)
+            blocks = t // block
+            _tel.inc("lower.attention_window.block_pairs", len(
+                pallas_kernels.attention_block_pairs(blocks, window,
+                                                     block)[0]))
+            _tel.inc("lower.attention_window.block_pairs_causal",
+                     blocks * (blocks + 1) // 2)
         if d == 192:
             # a tile and a half: zero columns before each head's own make
             # it two (the docstring); the scale above is the true width's
@@ -388,7 +515,7 @@ class CausalAttention(Operator):
                 return x.reshape(-1, heads * (d + 64))
 
             q, k, d = widened(q, hq), widened(k, hkv), d + 64
-        tables = relayout_tables(t, self.rope_theta, half, d)
+        tables = relayout_tables(t, self.rope_theta, half, d, scaling)
         # the kernel takes queries already scaled: folded into the pass
         # that turns them, before its one rounding to the compute dtype
         q = _relaid(q, tables, batch=b, heads=hq, half=half, scale=scale)
@@ -399,6 +526,6 @@ class CausalAttention(Operator):
         _tel.inc("lower.attention_backward.%s"
                  % ("fused" if fused else "split"))
         out = attend_splash(q.reshape(b, hkv, hq // hkv, t, d), k, v,
-                            ctx.kept.get("attention"), fused)
+                            ctx.kept.get("attention"), fused, window)
         return [_relaid(out.reshape(b, hq, t, vd), back=True, batch=b,
                         heads=hq)], []

@@ -2235,21 +2235,35 @@ def attention_backward_applicable(t, d, dv, dtype) -> bool:
                                     block) <= _ATTENTION_VMEM - (8 << 20)
 
 
+def _blocks_behind(window, block):
+    """Key blocks before a query block's own that a window of ``window``
+    keys reaches: ``ceil((window - 1) / block)``."""
+    return -(-(window - 1) // block)
+
+
 @functools.lru_cache(None)
-def _causal_pairs(blocks):
-    """The (query block, key block) pairs at or under the diagonal of
-    ``blocks`` x ``blocks``, a query block's pairs together and in key
-    order, the one ON the diagonal last: ``(q, k) [pairs]`` int32."""
+def attention_block_pairs(blocks, window=0, block=ATTENTION_BACKWARD_BLOCK):
+    """The (query block, key block) pairs of ``blocks`` x ``blocks`` that
+    hold a score: those at or under the diagonal, or with ``window`` (a
+    position reads the last ``window`` keys, its own among them) the band's,
+    ``qi - ceil((window - 1) / block) <= ki <= qi``. A query block's pairs
+    together and in key order, the one ON the diagonal last: ``(q, k)
+    [pairs]`` int32."""
     qs, ks = np.tril_indices(blocks)
+    if window:
+        band = qs - ks <= _blocks_behind(window, block)
+        qs, ks = qs[band], ks[band]
     return qs.astype(np.int32), ks.astype(np.int32)
 
 
-def attention_backward(q, k, v, do, lse, di):
+def attention_backward(q, k, v, do, lse, di, window=0):
     """``dq, dk, dv`` of causal attention ``o = softmax(q k^T) v`` from the
     output's cotangent: ``q [B, Hkv, G, T, D]`` (already scaled), ``k [B,
     Hkv, T, D]``, ``v [B, Hkv, T, Dv]``, ``do [B, Hkv, G, T, Dv]``, the
     forward kernel's float32 log-sum-exp ``lse`` and ``di = sum(o * do)``
-    ``[B, Hkv, G, T]`` (the section's comment)."""
+    ``[B, Hkv, G, T]`` (the section's comment). ``window`` (below ``T``): a
+    position reads the last ``window`` keys, and the grid is the band's
+    pairs of blocks."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -2260,8 +2274,12 @@ def attention_backward(q, k, v, do, lse, di):
     dv_ = v.shape[-1]
     cd = q.dtype
     block = min(ATTENTION_BACKWARD_BLOCK, t)
-    pairs = _causal_pairs(t // block)
+    pairs = attention_block_pairs(t // block, window, block)
     npairs = len(pairs[0])
+    # key blocks a query block reads before its own, and the nearest of
+    # them that the band's lower edge crosses (``behind + 1``: none)
+    behind = _blocks_behind(window, block) if window else 0
+    edge_from = max(1, -(-(window - block + 1) // block)) if window else 0
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))       # x y^T
 
@@ -2276,11 +2294,12 @@ def attention_backward(q, k, v, do, lse, di):
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
-        @pl.when(ki == 0)
+        # a query block's first pair
+        @pl.when(ki == jnp.maximum(qi - behind, 0) if window else ki == 0)
         def _():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        def pair(diagonal):
+        def pair(diagonal, edge=False):
             qb, kb, vb, dob = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
             st = lax.dot_general(kb, qb, nt, preferred_element_type=f32)
             if diagonal:    # the same block of positions both ways
@@ -2288,6 +2307,11 @@ def attention_backward(q, k, v, do, lse, di):
                     lax.broadcasted_iota(jnp.int32, st.shape, 0)
                     <= lax.broadcasted_iota(jnp.int32, st.shape, 1),
                     st, _ATTENTION_MASKED)
+            if edge:        # the band's lower edge: key > query - window
+                st = jnp.where(
+                    lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                    - lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                    < window - (qi - ki) * block, st, _ATTENTION_MASKED)
             pt = jnp.exp(st - lse_ref[...])
             dv_acc[rows, :] += jnp.dot(pt.astype(cd), dob,
                                        preferred_element_type=f32)
@@ -2300,13 +2324,25 @@ def attention_backward(q, k, v, do, lse, di):
             dq_acc[...] += jnp.dot(dst.T.astype(cd), kb,
                                    preferred_element_type=f32)
 
-        @pl.when(ki < qi)
-        def _():
-            pair(False)
+        if not window:
+            @pl.when(ki < qi)
+            def _():
+                pair(False)
+        else:
+            # only the pairs the edge crosses pay for its mask
+            if edge_from > 1:
+                @pl.when((ki < qi) & (qi - ki < edge_from))
+                def _():
+                    pair(False)
+
+            if edge_from <= behind:
+                @pl.when(qi - ki >= edge_from)
+                def _():
+                    pair(False, edge=True)
 
         @pl.when(ki == qi)
         def _():
-            pair(True)
+            pair(True, edge=0 < window < block)
             dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
         @pl.when((g == group - 1) & (step == npairs - 1))
@@ -2346,4 +2382,5 @@ def attention_backward(q, k, v, do, lse, di):
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=_ATTENTION_VMEM),
-        name="causal_attention_backward")
+        name="window_attention_backward" if window
+        else "causal_attention_backward")

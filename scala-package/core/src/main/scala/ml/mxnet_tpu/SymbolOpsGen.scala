@@ -229,12 +229,24 @@ object SymbolOpsGen {
       rotary: Boolean = true,
       ropeTheta: Float = 10000.0f,
       rotaryDim: Int = 0,
-      valueDim: Int = 0): Symbol = {
+      valueDim: Int = 0,
+      window: Int = 0,
+      ropeFactor: Float = 1.0f,
+      ropeOriginalPositions: Int = 0,
+      ropeBetaFast: Float = 32.0f,
+      ropeBetaSlow: Float = 1.0f,
+      ropeAttentionFactor: Float = 0.0f): Symbol = {
     val ps = scala.collection.mutable.ArrayBuffer[(String, String)]()
     ps += (("rotary", rotary.toString))
     ps += (("rope_theta", ropeTheta.toString))
     ps += (("rotary_dim", rotaryDim.toString))
     ps += (("value_dim", valueDim.toString))
+    ps += (("window", window.toString))
+    ps += (("rope_factor", ropeFactor.toString))
+    ps += (("rope_original_positions", ropeOriginalPositions.toString))
+    ps += (("rope_beta_fast", ropeBetaFast.toString))
+    ps += (("rope_beta_slow", ropeBetaSlow.toString))
+    ps += (("rope_attention_factor", ropeAttentionFactor.toString))
     ps += (("num_heads", numHeads.toString))
     ps += (("num_kv_heads", numKvHeads.toString))
     ps += (("head_dim", headDim.toString))

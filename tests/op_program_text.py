@@ -1,11 +1,12 @@
 """The traced programs (``jax.make_jaxpr`` of the value and the gradients) of
-``GatedDeltaRule`` and ``RoutedExperts`` at the shapes of the benchmark's
+``GatedDeltaRule``, ``RoutedExperts`` and ``CausalAttention`` at the shapes of the benchmark's
 older language cells, each node as its model's factory builds it (no
 argument this repo added after them is given), as sha256 of the text with
 the addresses in it taken out. ``tests/test_qwen3_next.py`` holds them
 against the hashes this file gave on the parent of the PR that added
-``num_key_heads``, ``score_func`` and ``aux_loss_coef``: under those
-arguments' defaults the older cells' operators trace the program they did,
+``num_key_heads``, ``score_func`` and ``aux_loss_coef``, and
+``tests/test_laguna.py`` the attentions against the parent of the PR that
+added ``window`` and ``rope_factor``: under those arguments' defaults the older cells' operators trace the program they did,
 to the character. Run it against another tree to read that tree's::
 
     PYTHONPATH=<tree> python tests/op_program_text.py
@@ -41,6 +42,27 @@ NODES = {
         num_experts=512, num_held=8, first_held=0, top_k=8, scale=2.5,
         num_hidden=768, gated=True, n_group=8, topk_group=4,
         bias_update_rate=0.01), 2560),
+    # every older language cell's ``CausalAttention`` (the hint: the query's
+    # width), held by ``tests/test_laguna.py`` against the parent of the PR
+    # that added ``window`` and the scaled rotary frequencies
+    "nemotron.attention": ("CausalAttention", dict(
+        num_heads=32, num_kv_heads=2, head_dim=128, seq_len=ROWS,
+        rope_theta=10000.0), 32 * 128),
+    "olmo.attention": ("CausalAttention", dict(
+        num_heads=15, num_kv_heads=15, head_dim=128, seq_len=ROWS,
+        rotary=False), 15 * 128),
+    "glm.attention": ("CausalAttention", dict(
+        num_heads=20, num_kv_heads=20, head_dim=256, seq_len=ROWS,
+        rope_theta=1000000.0, rotary_dim=64), 20 * 256),
+    "lfm2.attention": ("CausalAttention", dict(
+        num_heads=32, num_kv_heads=8, head_dim=64, seq_len=ROWS,
+        rope_theta=1000000.0), 32 * 64),
+    "ling.attention": ("CausalAttention", dict(
+        num_heads=32, num_kv_heads=32, head_dim=192, seq_len=ROWS,
+        rope_theta=6000000.0, rotary_dim=64, value_dim=128), 32 * 192),
+    "qwen3_next.attention": ("CausalAttention", dict(
+        num_heads=16, num_kv_heads=2, head_dim=256, seq_len=ROWS,
+        rope_theta=10000000.0, rotary_dim=64), 16 * 256),
 }
 
 
@@ -81,9 +103,9 @@ def program_text(name):
     return re.sub(r"0x[0-9a-f]+", "0x", text)
 
 
-def program_hashes():
+def program_hashes(names=None):
     return {name: hashlib.sha256(program_text(name).encode()).hexdigest()
-            for name in sorted(NODES)}
+            for name in sorted(names or NODES)}
 
 
 if __name__ == "__main__":

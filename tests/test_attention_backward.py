@@ -131,7 +131,7 @@ def test_the_pairs_of_blocks_are_the_causal_half(blocks):
     and no other, a query block's pairs together and in key order, so that
     its first pair is key block 0 (``dq`` starts) and its last the one ON
     the diagonal (the mask is paid, ``dq`` is written)."""
-    qs, ks = pk._causal_pairs(blocks)
+    qs, ks = pk.attention_block_pairs(blocks)
     assert list(zip(qs.tolist(), ks.tolist())) == [
         (i, j) for i in range(blocks) for j in range(i + 1)]
     assert qs.dtype == ks.dtype == np.int32

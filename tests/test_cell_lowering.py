@@ -9,7 +9,9 @@ counts the choice once a traced node (``lower.*``), so the counters read
 the Pallas body for every scan, delta-rule, attention and experts node and
 nothing for any XLA fallback (an attention node also counts its backward
 pass: ``attention_backward.fused``, this repo's one kernel of five products,
-or ``.split``, JAX's two of seven); a delta-rule node also counts WHERE its
+or ``.split``, JAX's two of seven; and its mask, ``attention_mask.causal``
+or ``.window``, a windowed node the pairs of blocks its band holds:
+``attention_window.block_pairs``); a delta-rule node also counts WHERE its
 kernels read the op's wide arrays (``delta_rule_layout.rows``: as the
 projections leave them, at heads of whole lane tiles; ``.heads``: float32
 head-major copies, the Olmo cell's 96 x 192). The toy presets of the
@@ -65,7 +67,11 @@ CELLS = {
         "GatedDeltaRule": 5, "RoutedExperts": 5, "CausalAttention": 1},
     "qwen3_next_l4_e32of512_bf16": {
         "GatedDeltaRule": 3, "RoutedExperts": 4, "CausalAttention": 1},
+    "laguna_xs2_l5_e32of256_bf16": {"CausalAttention": 5, "RoutedExperts": 4},
 }
+# the cells whose attentions are not all causal: (windowed nodes, the pairs
+# of 512-blocks the band holds a node, of the causal half's 136)
+WINDOWED = {"laguna_xs2_l5_e32of256_bf16": (3, 31)}
 
 
 def abstract_arguments(net, shape):
@@ -122,6 +128,10 @@ def test_a_cells_nodes_take_their_kernels_at_its_own_sizes(config):
                   for name in FALLBACKS}
         layout = {name: telemetry.peek("lower.delta_rule_layout." + name)
                   or 0 for name in ("rows", "heads")}
+        mask = {name: telemetry.peek("lower." + name) or 0 for name in (
+            "attention_mask.causal", "attention_mask.window",
+            "attention_window.block_pairs",
+            "attention_window.block_pairs_causal")}
     finally:
         telemetry.reset()
         telemetry.disable()
@@ -133,3 +143,9 @@ def test_a_cells_nodes_take_their_kernels_at_its_own_sizes(config):
     assert layout == dict({"rows": 0, "heads": 0}, **{
         DELTA_LAYOUT[config]: held["GatedDeltaRule"]}
         if config in DELTA_LAYOUT else {})
+    windowed, pairs = WINDOWED.get(config, (0, 0))
+    assert mask == {
+        "attention_mask.causal": held.get("CausalAttention", 0) - windowed,
+        "attention_mask.window": windowed,
+        "attention_window.block_pairs": windowed * pairs,
+        "attention_window.block_pairs_causal": windowed * 136}

@@ -107,18 +107,23 @@ def test_delta_rows_kernels_compile_for_the_chip(one_chip, heads, key_heads,
         assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("h,group,d,dv", [
-    (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128), (2, 8, 256, 256)],
+@pytest.mark.parametrize("h,group,d,dv,window", [
+    (20, 1, 256, 256, 0), (8, 4, 64, 64, 0), (32, 1, 256, 128, 0),
+    (2, 8, 256, 256, 0), (8, 6, 128, 128, 0), (8, 8, 128, 128, 512)],
     ids=["256-wide", "64-wide-grouped", "256-wide-keys-128-wide-values",
-         "256-wide-grouped"])
-def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
-    """The kernel call at four of the benchmark's cells: latent attention's
-    20 one-head groups of 256 columns, LFM2's 8 groups of four heads of
-    64, half a lane tile, Ling's 32 heads whose 192-wide keys go widened
-    to 256 beside values of 128, and Qwen3-Next's 2 groups of eight heads of
-    256, over 8,192 positions: JAX's forward kernel and this repo's one
-    backward kernel, two custom calls where JAX's own backward pass made
-    three."""
+         "256-wide-grouped", "128-wide-groups-of-6",
+         "128-wide-groups-of-8-window-512"])
+def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv,
+                                                window):
+    """The kernel call at six of the benchmark's cells' layers: latent
+    attention's 20 one-head groups of 256 columns, LFM2's 8 groups of four
+    heads of 64, half a lane tile, Ling's 32 heads whose 192-wide keys go
+    widened to 256 beside values of 128, Qwen3-Next's 2 groups of eight heads
+    of 256, and Laguna's 8 groups of six (full layers) and of eight under a
+    window of 512 (``LocalMask``; the backward pass over the band's 31 pairs
+    of blocks), over 8,192 positions: JAX's forward kernel and this repo's
+    one backward kernel, two custom calls where JAX's own backward pass made
+    three, and no ``[T, T]`` array in either pass."""
     from mxnet_tpu.ops import attention
 
     b, t = 1, 8192
@@ -127,7 +132,8 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
         return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
-        return attention.attend_splash(q, k, v).astype(jnp.float32).sum()
+        return attention.attend_splash(q, k, v, window=window).astype(
+            jnp.float32).sum()
 
     # tests/conftest.py asks for "highest", which Mosaic refuses of bfloat16
     # operands; a benchmark run leaves the default
@@ -137,16 +143,21 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv):
             shape(b, h, t, dv)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
-    assert "causal_attention_backward" in text
+    assert ("window" if window else "causal") + "_attention_backward" in text
+    assert "8192,8192" not in text
 
 
-@pytest.mark.parametrize("h,group,d,dv", [
-    (20, 1, 256, 256), (8, 4, 64, 64), (32, 1, 256, 128), (2, 8, 256, 256),
-    (2, 16, 128, 128), (15, 1, 128, 128)],
-    ids=["glm", "lfm2", "ling", "qwen3-next", "nemotron", "olmo"])
-def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv):
-    """The one-kernel backward pass at the six language cells' shapes over
-    8,192 positions: the head's float32 ``dk`` and ``dv`` resident in VMEM
+@pytest.mark.parametrize("h,group,d,dv,window", [
+    (20, 1, 256, 256, 0), (8, 4, 64, 64, 0), (32, 1, 256, 128, 0),
+    (2, 8, 256, 256, 0), (2, 16, 128, 128, 0), (15, 1, 128, 128, 0),
+    (8, 6, 128, 128, 0), (8, 8, 128, 128, 512), (8, 8, 128, 128, 700)],
+    ids=["glm", "lfm2", "ling", "qwen3-next", "nemotron", "olmo",
+         "laguna-full", "laguna-window", "window-of-a-block-and-a-third"])
+def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv,
+                                                  window):
+    """The one-kernel backward pass at the seven language cells' shapes
+    (Laguna's two kinds of layer; under a window the grid is the band's
+    pairs, the edge's mask takes a scalar from SMEM) over 8,192 positions: the head's float32 ``dk`` and ``dv`` resident in VMEM
     (16 MiB at 256 + 256 columns, above Mosaic's default limit), the
     contraction over a pair's key rows for ``dq``, the dynamic row slices of
     the accumulators and half-lane heads are Mosaic's to refuse."""
@@ -159,7 +170,8 @@ def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv):
 
     assert pk.attention_backward_applicable(t, d, dv, jnp.bfloat16)
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(pk.attention_backward).lower(
+        compiled = jax.jit(
+            lambda *a: pk.attention_backward(*a, window=window)).lower(
             shape(b, h, group, t, d), shape(b, h, t, d), shape(b, h, t, dv),
             shape(b, h, group, t, dv), shape(b, h, group, t, dtype="float32"),
             shape(b, h, group, t, dtype="float32")).compile()
@@ -177,10 +189,11 @@ def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv):
     (1, 8192, 8, 64, 0, "bfloat16"),        # LFM2's values
     (2, 1024, 2, 64, 16, "float32"),        # a part of a 64-wide head
     (1, 8192, 32, 256, 64, "bfloat16"),     # Ling's keys, widened from 192
-    (1, 8192, 2, 256, 64, "bfloat16")],     # Qwen3-Next's keys: 2 heads
+    (1, 8192, 2, 256, 64, "bfloat16"),      # Qwen3-Next's keys: 2 heads
+    (1, 8192, 48, 128, 64, "bfloat16")],    # Laguna's full layers' queries
     ids=["glm", "nemotron", "plain", "two-tiles", "tile-and-a-half",
          "one-pair", "lfm2", "lfm2-plain", "half-lanes-part", "ling",
-         "qwen3-next"])
+         "qwen3-next", "laguna-yarn"])
 def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
                                                         heads, d, turned,
                                                         dtype):
@@ -190,7 +203,10 @@ def test_attention_relayout_passes_compile_for_the_chip(one_chip, batch, t,
     from mxnet_tpu.ops import attention, pallas_kernels as pk
 
     half = turned // 2
-    tables = attention.relayout_tables(t, 1e4, half, d)
+    # Laguna's: half of a 128-wide head under YaRN's tables
+    scaling = (64.0, 4096, 64.0, 1.0, 0.0) if (heads, d) == (48, 128) \
+        else None
+    tables = attention.relayout_tables(t, 1e4, half, d, scaling)
     how = dict(batch=batch, heads=heads, half=half, scale=0.125)
     there = jax.jit(lambda x: pk.attention_relayout(x, tables, **how)).lower(
         jax.ShapeDtypeStruct((batch * t, heads * d), dtype,
@@ -235,6 +251,7 @@ EXPERT_CELLS = {
     "glm-cell": (8192, 2048, 1536, 8, 4, 64, True, "bfloat16"),
     "ling-cell": (8192, 2560, 768, 8, 8, 512, True, "bfloat16"),
     "qwen3-next-cell": (8192, 2048, 512, 32, 10, 512, True, "bfloat16"),
+    "laguna-cell": (8192, 2048, 512, 32, 8, 256, True, "bfloat16"),
     "narrowest-float32": (64, 128, 8, 2, 2, 4, True, "float32"),
     "narrowest-bfloat16": (128, 128, 16, 2, 2, 4, False, "bfloat16"),
 }

@@ -25,7 +25,7 @@ from mxnet_tpu.ops import moe
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import qwen3_next as ref  # noqa: E402
-from op_program_text import NODES, program_hashes  # noqa: E402
+from op_program_text import program_hashes  # noqa: E402
 from test_hlo_gates import (check_products_are_bfloat16,  # noqa: E402
                             check_state_is_donated, lower_language_toy)
 from test_nemotron_h import (Ring, against, aux_states, close,  # noqa: E402
@@ -161,10 +161,10 @@ PARENT_PROGRAMS = {
 
 @pytest.fixture(scope="module")
 def traced_programs():
-    return program_hashes()
+    return program_hashes(sorted(PARENT_PROGRAMS))
 
 
-@pytest.mark.parametrize("node", sorted(NODES))
+@pytest.mark.parametrize("node", sorted(PARENT_PROGRAMS))
 def test_the_older_cells_nodes_trace_the_parents_program(traced_programs,
                                                          node):
     """Under the new arguments' defaults (as many key heads as value heads;
