@@ -233,13 +233,18 @@ def _fwd_bwd(fn, args):
 
 
 #: the kernel phase's cases: ``rtc``'s entry point and the five families of
-#: Pallas kernels the language cells run (``ops/pallas_kernels.py``)
+#: Pallas kernels the language cells run (``ops/pallas_kernels.py``); the
+#: ``attention_backward`` cases differentiate through both attention kernels,
+#: the ``attention_forward`` ones read the forward kernel's two results
 KERNEL_CASES = ("rtc axpy", "ssd_scan", "gated_delta_scan head",
                 "gated_delta_scan channel", "gated_delta_rows head",
                 "gated_delta_rows channel", "grouped_experts relu2",
                 "grouped_experts swiglu", "attention_relayout",
                 "attention_backward float32", "attention_backward bfloat16",
-                "attention_backward bfloat16 window")
+                "attention_backward bfloat16 window",
+                "attention_forward bfloat16", "attention_forward bfloat16 window",
+                "attention_forward bfloat16 narrow",
+                "attention_forward bfloat16 wide")
 
 
 def _kernel_case(name, device, small):
@@ -263,7 +268,12 @@ def _kernel_case(name, device, small):
     bfloat16 operands (PR 46; the float32 case's products pass the MXU at
     the default precision as the experts' do; the bfloat16 case hands both
     sides bfloat16 operands and the body casts them up: what is read is the
-    rounding of ``p``, ``ds`` and the results, 1.22e-3 on the interpreter)."""
+    rounding of ``p``, ``ds`` and the results, 1.22e-3 on the interpreter);
+    1.05e-3 for each of the forward kernel's four cases (PR 48, the last
+    of its chip calls, which read the backward cases as PR 46 did: the
+    largest over ``out`` and ``lse``; it is ``out``'s one rounding and
+    ``p``'s, the log-sum-exp reads under 1e-6; 1.04e-3 on the
+    interpreter)."""
     import jax
     import jax.numpy as jnp
 
@@ -370,8 +380,38 @@ def _kernel_case(name, device, small):
         return (lambda x, wts, *ws: moe.grouped_experts_kernel(
             x, ws, wts, *layout, gated),
             lambda x, wts, *ws: loop(x, *ws, wts, *layout), args, 7e-3)
+    if name.startswith("attention_forward"):
+        # this repo's forward kernel alone, its output AND its log-sum-exp
+        # (the backward kernel's residual, which no gradient case reads
+        # apart from the output) against the float32 product over the whole
+        # ``[T, T]`` scores, bfloat16 operands on both sides: the Ling
+        # cell's kind of head (keys of 256 beside values of 128) causal and
+        # under a band of 640 keys, LFM2's (a group of four 64-wide heads)
+        # and GLM's (256 beside 256), over four blocks of 512; small: two
+        # blocks. Not differentiated: ``attention_backward``'s cases are
+        # the gradients THROUGH this kernel
+        window = 0 if "window" not in name else 384 if small else 640
+        hkv, g, d, dv = {"narrow": (2, 4, 64, 64), "wide": (4, 1, 256, 256)
+                         }.get(name.split()[-1], (4, 2, 256, 128))
+        t = 1024 if small else 2048
+        assert pk.attention_applicable(t, d, dv, jnp.bfloat16)
+        args = tuple(x.astype(jnp.bfloat16) for x in (
+            put(1, hkv, g, t, d, scale=d ** -0.5), put(1, hkv, t, d),
+            put(1, hkv, t, dv)))
+
+        def body(q, k, v):
+            q, k, v = (x.astype(f32) for x in (q, k, v))
+            s = jnp.einsum("bhgtd,bhsd->bhgts", q, k)
+            i, j = jnp.arange(t)[:, None], jnp.arange(t)[None]
+            s = jnp.where((j <= i) & (j > i - (window or t)), s, -jnp.inf)
+            lse = jax.nn.logsumexp(s, axis=-1)
+            return jnp.einsum("bhgts,bhsd->bhgtd",
+                              jnp.exp(s - lse[..., None]), v), lse
+
+        return (lambda q, k, v: pk.attention_forward(q, k, v, window), body,
+                args, 4e-3)
     if name.startswith("attention_backward"):
-        # JAX's splash forward kernel and this repo's one backward kernel
+        # this repo's forward kernel and its one backward kernel
         # against the blockwise body: the Ling cell's kind of head (keys of
         # 256 beside values of 128; 4 groups of two heads here) over four
         # blocks of 512, ten pairs of them; small: a group of two 64-wide
@@ -382,7 +422,7 @@ def _kernel_case(name, device, small):
         window = 0 if "window" not in name else 384 if small else 640
         b, hkv, g, t, d, dv = (1, 1, 2, 1024, 64, 64) if small \
             else (1, 4, 2, 2048, 256, 128)
-        assert pk.attention_backward_applicable(t, d, dv, dtype)
+        assert pk.attention_applicable(t, d, dv, dtype)
         args = tuple(x.astype(dtype) for x in (
             put(b, hkv, g, t, d, scale=d ** -0.5), put(b, hkv, t, d),
             put(b, hkv, t, dv)))
@@ -448,9 +488,12 @@ def kernel_phase(device, small=False, only=KERNEL_CASES):
                 results[name] = _rtc_case(device)
                 continue
             kernel_fn, body_fn, args, tol = _kernel_case(name, device, small)
+            # a forward kernel alone gives several results and no gradient
+            run = (lambda fn, args: jax.jit(fn)(*args)) \
+                if name.startswith("attention_forward") else _fwd_bwd
             with jax.default_matmul_precision("highest"):
-                want = _fwd_bwd(body_fn, args)
-            got = _fwd_bwd(kernel_fn, args)
+                want = run(body_fn, args)
+            got = run(kernel_fn, args)
             results[name] = max(
                 assert_almost_equal(np.asarray(g, np.float32),
                                     np.asarray(w, np.float32), tol,

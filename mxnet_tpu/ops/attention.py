@@ -10,14 +10,15 @@ rows are whole sequences of ``seq_len`` positions.
 traced op): position ``i`` reads the keys ``j <= i``, or with ``window`` =
 ``w`` the band ``i - w < j <= i``: ``w`` keys, its own among them. A window
 that holds the whole sequence (``w >= seq_len``) IS the causal op: the same
-program. Under a band both lowerings below skip what it empties: the splash
-forward kernel takes ``LocalMask`` (left ``w - 1``, right 0) and visits only
-the blocks that hold a score; the backward kernel's grid is the band's pairs
-of blocks, ``qi - ceil((w - 1) / block) <= ki <= qi``
-(``pallas_kernels.attention_block_pairs``; counted ``lower.attention_
-window.block_pairs`` beside what the causal half of the same blocks holds,
-``.block_pairs_causal``: 31 and 136 at 8,192 positions, blocks of 512 and a
-window of 512), the pair on the diagonal masked above it, the pairs the
+program. Under a band both lowerings below skip what it empties: the two
+kernels visit the band's pairs of blocks, ``qi - ceil((w - 1) / block) <= ki
+<= qi`` (the backward kernel's grid is the table of them,
+``pallas_kernels.attention_block_pairs``; the forward kernel's is the query
+blocks, a block's key blocks a loop inside its step; counted
+``lower.attention_window.block_pairs`` beside what the causal half of the
+same blocks holds, ``.block_pairs_causal``: 31 and 136 at 8,192 positions,
+blocks of 512 and a window of 512), the pair on the diagonal masked above it,
+the pairs the
 band's lower edge crosses masked below it (with ``w`` = a block, half of the
 one off-diagonal pair: the kernels then run at about half the causal ones'
 share of their roofline, PERF.md section 6, PR 47), the pairs between
@@ -33,12 +34,13 @@ angles, and ride the one relayout pass below.
 Which implementation a program takes is decided from the shapes when it
 is traced, and counted (``lower.attention_kernel.<name>``):
 
-* ``pallas_splash``: JAX's own Pallas TPU kernel (``jax.experimental.
-  pallas.ops.tpu.splash_attention``) in its multi-query form, where it
-  applies: a head of whole 128-lanes (128, 256: the Nemotron, Olmo and
+* ``pallas_splash``: the Pallas lowering, one kernel a pass in the layout
+  JAX's splash attention (``jax.experimental.pallas.ops.tpu.
+  splash_attention``, its multi-query form) takes, where that applies: a
+  head of whole 128-lanes (128, 256: the Nemotron, Olmo, Laguna and
   GLM models) or of 64 columns, half a lane tile, with an even number of
-  query and of key/value heads (the LFM2 model's 32 over 8; the kernel
-  takes a ``[T, 64]`` tile as it is, the 64 lanes beside it idle in its
+  query and of key/value heads (the LFM2 model's 32 over 8; the kernels
+  take a ``[T, 64]`` tile as it is, the 64 lanes beside it idle in their
   products, and the pass below moves the TWO heads that share a 128-lane
   tile of the rows in one grid step), and a sequence of whole
   ``SPLASH_BLOCK`` blocks. A head of one and a half lane tiles (192: the
@@ -46,19 +48,28 @@ is traced, and counted (``lower.attention_kernel.<name>``):
   head of 256: 64 zero columns are put BEFORE each query's and key's own
   (rotary turns the last columns, and a zero column adds nothing to a
   score, so the result is exact; the scale stays ``192^-1/2``); the
-  kernel's score products then run at 256, a third more than they need,
+  kernels' score products then run at 256, a third more than they need,
   and no other 192-wide path has to exist. Values may be narrower than
-  keys (``value_dim``; whole lanes): the kernel takes ``v`` at its own
-  width as it stands in JAX, and the result is ``value_dim`` wide. One
-  call a key/value head: its ``G`` query
-  heads read that one head's keys, so nothing is repeated. The forward
-  pass is JAX's kernel; the backward pass is this repo's ONE kernel
-  (``pallas_kernels.attention_backward``, counted ``lower.
-  attention_backward.fused``: a pair of blocks' scores, ``exp`` and
-  ``do v^T`` formed once for ``dq``, ``dk`` and ``dv``, five products where
-  JAX's ``dq`` and ``dkv`` kernels run seven) wherever ``pallas_kernels.
-  attention_backward_applicable`` takes the shape, and JAX's two kernels
-  (``.split``) where it does not. Both passes are blockwise in their kernel
+  keys (``value_dim``; whole lanes): the kernels take ``v`` at its own
+  width, and the result is ``value_dim`` wide. A key/value head's ``G``
+  query heads read that one head's keys, so nothing is repeated.
+  **Which pass is whose**: both are this repo's wherever ONE rule,
+  ``pallas_kernels.attention_applicable``, takes the shape, which is every
+  shape the benchmark's cells trace, and both are JAX's splash kernels
+  where it refuses (a sequence too long for a head's keys, values and
+  gradient accumulators to stay in VMEM). The forward pass is
+  ``pallas_kernels.attention_forward`` (counted ``lower.attention_forward.
+  fused``, JAX's ``.splash``; device op ``causal_attention_forward`` /
+  ``window_attention_forward``): the online softmax with a grid over the
+  query blocks, a head's keys and values resident in VMEM and the band's
+  key blocks a loop inside a query block's step, scores by key rows so that
+  a position's running max, sum and log-sum-exp are one float32 number
+  each, ``p`` rounded once to the compute dtype for ``p v``. The backward
+  pass is ONE kernel over the table of block pairs (``pallas_kernels.
+  attention_backward``, counted ``lower.attention_backward.fused``, JAX's
+  two ``.split``: a pair of blocks' scores, ``exp`` and ``do v^T`` formed
+  once for ``dq``, ``dk`` and ``dv``, five products where JAX's ``dq`` and
+  ``dkv`` kernels run seven). Both passes are blockwise in their kernel
   (scores and softmax float32 in VMEM, products in the compute dtype,
   accumulators float32), blocks above the diagonal are
   skipped, and no ``[T, T]`` tensor reaches HBM in either pass. The XLA
@@ -205,16 +216,12 @@ def rope(x, theta, scale=1.0, rotary_dim=0, pos_axis=0, scaling=None):
 
 
 @functools.lru_cache(None)
-def _splash_kernel(t, group, block, interpret, keep_name, residuals=False,
-                   backward=True, window=0):
+def _splash_kernel(t, group, block, interpret, keep_name, window=0):
     """JAX's multi-query splash kernel over ``group`` causal heads of ``t``
     positions, each reading every earlier key or, with ``window``, the last
     ``window`` (its own among them: ``LocalMask``, whose empty blocks the
-    kernel skips as it skips those above the diagonal). With ``backward`` it
-    differentiates itself, through JAX's
-    ``dq`` and ``dkv`` kernels; without, it is the forward kernel alone,
-    and with ``residuals`` that returns ``out, (log-sum-exp,)``, float32 a
-    (head, position): what ``attend_splash``'s own backward pass reads."""
+    kernel skips as it skips those above the diagonal). It differentiates
+    itself, through JAX's ``dq`` and ``dkv`` kernels."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
 
@@ -223,67 +230,62 @@ def _splash_kernel(t, group, block, interpret, keep_name, residuals=False,
     mask = masks.MultiHeadMask([
         masks.LocalMask((t, t), (window - 1, 0), 0) if window
         else masks.CausalMask((t, t)) for _ in range(group)])
-    sizes = dict(block_q=block, block_kv=block, block_kv_compute=block)
-    if backward:
-        sizes.update(
-            block_q_dkv=block, block_kv_dkv=block,
-            block_kv_dkv_compute=block, block_q_dq=block, block_kv_dq=block)
     # the kernel's block tables are arrays made here: outside any trace,
     # or a cached kernel would carry one program's tracers into the next
     with jax.ensure_compile_time_eval():
         return kernel.make_splash_mqa_single_device(
-            mask, block_sizes=kernel.BlockSizes(**sizes),
-            interpret=interpret, save_residuals=residuals,
-            residual_checkpoint_name=keep_name)
+            mask, block_sizes=kernel.BlockSizes(
+                block_q=block, block_kv=block, block_kv_compute=block,
+                block_q_dkv=block, block_kv_dkv=block,
+                block_kv_dkv_compute=block, block_q_dq=block,
+                block_kv_dq=block),
+            interpret=interpret, residual_checkpoint_name=keep_name)
 
 
 def attend_splash(q, k, v, keep_name=None, fused=True, window=0):
     """``q [B, Hkv, G, T, D]`` (already scaled by 1/sqrt(D)), ``k [B, Hkv,
-    T, D]``, ``v [B, Hkv, T, Dv]`` -> ``[B, Hkv, G, T, Dv]``: one
-    multi-query kernel call a (sequence, key/value head). The interpreter
+    T, D]``, ``v [B, Hkv, T, Dv]`` -> ``[B, Hkv, G, T, Dv]``: a key/value
+    head's ``G`` query heads against that one head's keys. The interpreter
     on ``cpu``, the Mosaic kernel elsewhere (``pallas_kernels.
-    pallas_call``'s rule). Under ``keep_name`` the forward kernel marks its
-    output and log-sum-exp, the backward pass's residuals, for a
+    pallas_call``'s rule). Under ``keep_name`` the forward kernel's
+    output and log-sum-exp, the backward pass's residuals, are marked for a
     recomputation to keep. ``window``: 0, every key at or before the
     query's position; ``w`` (below ``T``), the last ``w`` of them.
 
-    ``fused`` (``pallas_kernels.attention_backward_applicable``): the
-    backward pass is ONE kernel of five products, ``pallas_kernels.
-    attention_backward``, under a ``custom_vjp`` of this function's; where
-    the rule refuses a shape JAX's kernel differentiates itself (its ``dq``
-    and ``dkv`` kernels: seven products, the same result in another order
-    of the float32 sums)."""
+    ``fused`` (``pallas_kernels.attention_applicable``): both passes are
+    this repo's, ``pallas_kernels.attention_forward`` and the ONE backward
+    kernel of five products, ``pallas_kernels.attention_backward``, under a
+    ``custom_vjp`` of this function's. Where the rule refuses a shape JAX's
+    splash kernel runs, one call a (sequence, key/value head), and
+    differentiates itself (its ``dq`` and ``dkv`` kernels: seven products,
+    the same result in another order of the float32 sums)."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
-    from .pallas_kernels import attention_backward
-
-    t, group = q.shape[3], q.shape[2]
-    block = min(SPLASH_BLOCK, t)
-
-    def forward(**how):
-        def run(interpret):
-            one = _splash_kernel(t, group, block, interpret,
-                                 None if fused else keep_name,
-                                 window=window, **how)
-            return lambda q, k, v: jax.vmap(jax.vmap(one))(q, k, v)
-
-        return lambda q, k, v: jax.lax.platform_dependent(
-            q, k, v, cpu=run(True), default=run(False))
+    from . import pallas_kernels
 
     if not fused:
-        return forward()(q, k, v)
+        t, group = q.shape[3], q.shape[2]
 
-    attend = jax.custom_vjp(forward(backward=False))
+        def run(interpret):
+            one = _splash_kernel(t, group, min(SPLASH_BLOCK, t), interpret,
+                                 keep_name, window)
+            return lambda q, k, v: jax.vmap(jax.vmap(one))(q, k, v)
+
+        return jax.lax.platform_dependent(q, k, v, cpu=run(True),
+                                          default=run(False))
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return pallas_kernels.attention_forward(q, k, v, window)[0]
 
     def attend_fwd(q, k, v):
-        out, (lse,) = forward(residuals=True, backward=False)(q, k, v)
+        out, lse = pallas_kernels.attention_forward(q, k, v, window)
         if keep_name is not None:
-            # named HERE: a name inside JAX's own ``custom_vjp``, which
-            # this rule calls and does not differentiate, is out of a
-            # recomputation's sight, and the kernel would run twice a step
-            out, lse = (jax.ad_checkpoint.checkpoint_name(x, keep_name)
-                        for x in (out, lse))
+            # named HERE, in this rule's own forward pass, where a
+            # recomputation's plan sees them: the kernel runs once a step
+            out, lse = (checkpoint_name(x, keep_name) for x in (out, lse))
         return out, (q, k, v, out, lse)
 
     def attend_bwd(kept, do):
@@ -292,7 +294,8 @@ def attend_splash(q, k, v, keep_name=None, fused=True, window=0):
         # compute dtype and writes one number a (head, position)
         di = jnp.einsum("bhgtd,bhgtd->bhgt", out.astype(jnp.float32),
                         do.astype(jnp.float32))
-        return attention_backward(q, k, v, do, lse, di, window)
+        return pallas_kernels.attention_backward(q, k, v, do, lse, di,
+                                                 window)
 
     attend.defvjp(attend_fwd, attend_bwd)
     return attend(q, k, v)
@@ -521,8 +524,11 @@ class CausalAttention(Operator):
         q = _relaid(q, tables, batch=b, heads=hq, half=half, scale=scale)
         k = _relaid(k, tables, batch=b, heads=hkv, half=half)
         v = _relaid(v, batch=b, heads=hkv)
-        fused = pallas_kernels.attention_backward_applicable(t, d, vd,
-                                                             q.dtype)
+        # one rule, two kernels: this repo's forward and backward pass, or
+        # JAX's splash kernel and its own two
+        fused = pallas_kernels.attention_applicable(t, d, vd, q.dtype)
+        _tel.inc("lower.attention_forward.%s"
+                 % ("fused" if fused else "splash"))
         _tel.inc("lower.attention_backward.%s"
                  % ("fused" if fused else "split"))
         out = attend_splash(q.reshape(b, hkv, hq // hkv, t, d), k, v,

@@ -12,9 +12,9 @@ benchmark cell it aimed at and each with a line in the ledger there
 (``docs/pallas.md``): the state-space scan's chunk kernels (``SSMScan``),
 the gated delta rule's with a decay a head or a key channel
 (``GatedDeltaRule``), the routed experts' grouped products
-(``RoutedExperts``), the pass that takes the splash attention kernel
-its operands and the backward pass of that attention as one kernel
-(``CausalAttention``; its forward product is JAX's). Each has both passes
+(``RoutedExperts``), the pass that takes the attention kernels their
+operands and both passes of that attention, one kernel each
+(``CausalAttention``). Each has both passes
 written out (the operator joins them under one ``jax.custom_vjp``) and an
 ``*_applicable`` rule over shapes; the operator chooses the kernel or its
 ``jax.numpy`` body when the node is traced and counts the choice
@@ -38,7 +38,8 @@ __all__ = ["pallas_available", "pallas_call", "ssd_chunk_applicable",
            "delta_rows_backward",
            "grouped_experts_applicable", "grouped_experts_forward",
            "grouped_experts_backward", "attention_relayout",
-           "attention_backward_applicable", "attention_backward"]
+           "attention_applicable", "attention_backward",
+           "attention_forward"]
 
 # lanes of a tile
 TILE_N = 128
@@ -2170,8 +2171,8 @@ def attention_relayout(x, tables=(), *, batch, heads, half=0, scale=1.0,
 # (``ops/attention.py`` ``attend_splash``, the ``pallas_splash`` path)
 # ---------------------------------------------------------------------------
 #
-# The forward product is JAX's splash kernel; it leaves the output and the
-# float32 log-sum-exp a (head, position). JAX's backward pass is two kernels,
+# The forward kernel (below) leaves the output and the float32 log-sum-exp
+# a (head, position). JAX's backward pass is two kernels,
 # ``dq`` (three products) and ``dkv`` (four), and each forms the scores, the
 # ``exp`` and ``do v^T`` for itself. Here one grid step is one (query block,
 # key block) pair of the causal half, of one query head of one key/value
@@ -2217,28 +2218,47 @@ def _attention_backward_vmem(t, d, dv, itemsize, block):
             + 8 * block * block * 4)
 
 
-def attention_backward_applicable(t, d, dv, dtype) -> bool:
-    """Whether the one-kernel backward pass takes key/value heads of ``t``
-    positions of ``d`` key and ``dv`` value columns (a head's group of query
-    heads adds into the same accumulators, whatever its size): the splash
-    path's own shapes (heads of whole lanes or of 64 columns, whole
-    blocks), a compute dtype the MXU takes, and the head's resident
-    accumulators within the VMEM a v5e may be asked for, 8 MiB left to
-    Mosaic's own."""
+def attention_applicable(t, d, dv, dtype) -> bool:
+    """Whether this repo's two attention kernels (the forward pass below,
+    the one-kernel backward pass) take key/value heads of ``t`` positions of
+    ``d`` key and ``dv`` value columns, whatever the size of a head's group
+    of query heads: ONE rule, since ``attend_splash`` joins the two under
+    one ``custom_vjp`` and the backward kernel reads the forward kernel's
+    rows of log-sum-exp. The splash path's own shapes (heads of whole lanes
+    or of 64 columns, whole blocks of whole lane tiles), a compute dtype the
+    MXU takes, and what each kernel holds resident (the backward pass a
+    head's float32 accumulators, the forward pass its keys and values: the
+    smaller of the two while the two blocks are one size) within the VMEM a
+    v5e may be asked for, 8 MiB left to Mosaic's own."""
     name = "bfloat16" if str(dtype) == "bfloat16" else np.dtype(dtype).name
     if name not in ("bfloat16", "float32") or not pallas_available():
         return False
-    block = min(ATTENTION_BACKWARD_BLOCK, t)
-    if t % block or block % 128 or any(w % 128 and w != 64 for w in (d, dv)):
+    if any(w % 128 and w != 64 for w in (d, dv)):
         return False
-    return _attention_backward_vmem(t, d, dv, 2 if name == "bfloat16" else 4,
-                                    block) <= _ATTENTION_VMEM - (8 << 20)
+    itemsize = 2 if name == "bfloat16" else 4
+    backward, forward = (min(block, t) for block in (
+        ATTENTION_BACKWARD_BLOCK, ATTENTION_FORWARD_BLOCK))
+    if any(t % block or block % 128 for block in (backward, forward)):
+        return False
+    return max(_attention_backward_vmem(t, d, dv, itemsize, backward),
+               _attention_forward_vmem(1, t, d, dv, itemsize, forward)
+               ) <= _ATTENTION_VMEM - (8 << 20)
 
 
 def _blocks_behind(window, block):
     """Key blocks before a query block's own that a window of ``window``
     keys reaches: ``ceil((window - 1) / block)``."""
     return -(-(window - 1) // block)
+
+
+def _band(window, block):
+    """``(behind, edge_from)``: the key blocks a query block reads before its
+    own, and the nearest of them that the band's lower edge crosses
+    (``behind + 1``: none); 0, 0 without a window."""
+    if not window:
+        return 0, 0
+    return (_blocks_behind(window, block),
+            max(1, -(-(window - block + 1) // block)))
 
 
 @functools.lru_cache(None)
@@ -2276,10 +2296,7 @@ def attention_backward(q, k, v, do, lse, di, window=0):
     block = min(ATTENTION_BACKWARD_BLOCK, t)
     pairs = attention_block_pairs(t // block, window, block)
     npairs = len(pairs[0])
-    # key blocks a query block reads before its own, and the nearest of
-    # them that the band's lower edge crosses (``behind + 1``: none)
-    behind = _blocks_behind(window, block) if window else 0
-    edge_from = max(1, -(-(window - block + 1) // block)) if window else 0
+    behind, edge_from = _band(window, block)
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))       # x y^T
 
@@ -2384,3 +2401,203 @@ def attention_backward(q, k, v, do, lse, di, window=0):
             vmem_limit_bytes=_ATTENTION_VMEM),
         name="window_attention_backward" if window
         else "causal_attention_backward")
+
+
+# ---------------------------------------------------------------------------
+# Causal attention's forward pass as one kernel
+# (``ops/attention.py`` ``attend_splash``, the ``pallas_splash`` path)
+# ---------------------------------------------------------------------------
+#
+# The online softmax over the (query block, key block) pairs the backward
+# kernel's table lists, but not as a grid over that table: a grid step
+# is one QUERY block of one key/value head of one sequence, for ``heads``
+# query heads of its group; the head's keys and values are whole in VMEM
+# (fetched once a head: their block does not move with the query block) and
+# the key blocks at or under the diagonal, or inside the band, are a loop IN
+# the step: the blocks the band's lower edge crosses (their distance from
+# the diagonal is static, one ``pl.when`` a distance), the unmasked ones
+# between (a ``fori_loop`` over a dynamic range), the one ON the diagonal.
+# The step opens by clearing its statistics and closes by writing ``out``
+# and the log-sum-exp. As in the backward kernel the scores are formed by key
+# rows:
+#
+#   s^T = k q^T                       [bkv, bq]
+#   m' = max(m, max over keys s^T)    [1, bq]   (a position's running max,
+#   p^T = exp(s^T - m')                          sum and log-sum-exp are ONE
+#   l = exp(m - m') l + sum over keys p^T        number each: rows, where
+#   o^T = exp(m - m') o^T + v^T p^T   [Dv, bq]   splash keeps 128 lanes, and
+#                                                the reductions run down
+#                                                sublanes, not along lanes)
+#
+# ``v^T`` is the pair's value block transposed once in VMEM for the step's
+# heads; ``o^T`` is transposed back once a query block, when ``out = (o^T /
+# l)^T`` is written. One head's pair is a CHAIN: product, maximum, ``exp``,
+# product, each waiting on the one before, so the step runs its heads'
+# chains unrolled side by side for the scheduler to interleave (one head's
+# MXU work under another's vector work). Under a window a chain is a column
+# PART of the query block (a position's statistics do not meet another's),
+# and a part of a masked pair reads only the key rows its queries can see,
+# in whole tiles of 128 rows: above the diagonal and below the band's edge
+# nothing is computed; the ``iota`` mask cuts the rest.
+#
+# Precision: scores, maxima, ``exp``, sums, the rescale and the ``[Dv, bq]``
+# accumulator are float32; the two products take operands in the compute
+# dtype (``v`` as it lies, ``p`` rounded ONCE) and accumulate in float32;
+# ``out`` is rounded once, ``lse = m + log(l)`` leaves as float32 rows ``[..,
+# 1, T]``, the form the backward kernel reads.
+
+ATTENTION_FORWARD_BLOCK = 512
+
+
+def _attention_forward_vmem(heads, t, d, dv, itemsize, block):
+    """Bytes of VMEM a step of ``heads`` query heads holds at once: the
+    head's keys and values, the heads' query and output blocks (each
+    double-buffered), their float32 accumulators, and the chains' float32
+    scores."""
+    wide = _lanes(d) + _lanes(dv)
+    return (2 * itemsize * wide * (t + heads * block)
+            + 4 * heads * block * _lanes(dv) + 6 * block * block * 4)
+
+
+def _attention_forward_heads(group, t, d, dv, itemsize, block):
+    """Query heads of a group a grid step runs side by side: the most that
+    divide the group, up to eight, within the VMEM asked for."""
+    return max(n for n in range(1, min(group, 8) + 1) if group % n == 0 and (
+        n == 1 or _attention_forward_vmem(n, t, d, dv, itemsize, block)
+        <= _ATTENTION_VMEM - (8 << 20)))
+
+
+def _attention_forward(q, k, v, *, window=0):
+    """``out [B, Hkv, G, T, Dv]`` and the float32 log-sum-exp ``[B, Hkv, G,
+    T]`` of causal attention ``softmax(q k^T) v``: ``q [B, Hkv, G, T, D]``
+    (already scaled), ``k [B, Hkv, T, D]``, ``v [B, Hkv, T, Dv]`` (the
+    section's comment). ``window`` (below ``T``): a position reads the last
+    ``window`` keys, and a query block's loop is the band's key blocks."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hkv, group, t, d = q.shape
+    dv_ = v.shape[-1]
+    cd = q.dtype
+    block = min(ATTENTION_FORWARD_BLOCK, t)
+    behind, edge_from = _band(window, block)
+    gs = _attention_forward_heads(group, t, d, dv_, cd.itemsize, block)
+    # column parts of a query block: under a window most pairs are masked
+    # ones, and a part skips the key rows none of its queries sees (heads of
+    # whole lane tiles: at 64 columns two parts lost, docs/pallas.md)
+    parts = 2 if window and min(d, dv_, block // 2) >= TILE_N else 1
+    sub = block // parts
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))       # x y^T
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_acc, l_acc, o_acc):
+        qi = pl.program_id(3)
+        m_acc[...] = jnp.full_like(m_acc, _ATTENTION_MASKED)
+        l_acc[...] = jnp.zeros_like(l_acc)
+        o_acc[...] = jnp.zeros_like(o_acc)
+
+        def pair(ki, behind_by=None):
+            """Key block ``ki`` against the step's chains; ``behind_by``:
+            its distance from the diagonal where it is masked (0: ON it),
+            static."""
+            rows = pl.ds(pl.multiple_of(ki * block, block), block)
+            kb, vt = k_ref[rows, :], v_ref[rows, :].T
+            diagonal = behind_by == 0
+            # a query at column c of the block sees the key rows above
+            # ``c - reach``: the band's lower edge, where it crosses the pair
+            reach = window - behind_by * block \
+                if window and behind_by is not None else None
+            if reach is not None and reach >= block:
+                reach = None
+            for g in range(gs):
+                for part in range(parts):
+                    cols = slice(part * sub, (part + 1) * sub)
+                    # the key rows this part's queries can see, in whole
+                    # lane tiles of ``v^T``
+                    last = (part + 1) * sub if diagonal else block
+                    first = 0 if reach is None else max(
+                        0, (part * sub - reach + 1) // TILE_N * TILE_N)
+                    if first >= last:
+                        continue
+                    s = lax.dot_general(kb[first:last], q_ref[g, cols, :],
+                                        nt, preferred_element_type=f32)
+                    key = lax.broadcasted_iota(jnp.int32, s.shape, 0) + first
+                    query = lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+                        + part * sub
+                    if diagonal:
+                        s = jnp.where(key <= query, s, _ATTENTION_MASKED)
+                    if reach is not None:
+                        s = jnp.where(query - key < reach, s,
+                                      _ATTENTION_MASKED)
+                    m_prev = m_acc[g, :, cols]
+                    m_next = jnp.maximum(m_prev,
+                                         s.max(axis=0, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_next)
+                    p = jnp.exp(s - m_next)
+                    l_acc[g, :, cols] = alpha * l_acc[g, :, cols] \
+                        + p.sum(axis=0, keepdims=True)
+                    m_acc[g, :, cols] = m_next
+                    o_acc[g, :, cols] = alpha * o_acc[g, :, cols] + jnp.dot(
+                        vt[:, first:last], p.astype(cd),
+                        preferred_element_type=f32)
+
+        # in key order: the pairs the band's edge crosses (their distance is
+        # one of at most two), the unmasked ones between, the diagonal's
+        for behind_by in range(behind, edge_from - 1, -1) if window else ():
+            @pl.when(qi >= behind_by)
+            def _(behind_by=behind_by):
+                pair(qi - behind_by, behind_by)
+
+        lax.fori_loop(jnp.maximum(qi - edge_from + 1, 0) if window else 0,
+                      qi, lambda ki, _: pair(ki), None)
+        pair(qi, 0)
+        for g in range(gs):
+            l = l_acc[g]
+            o_ref[g] = (o_acc[g] / l).T.astype(o_ref.dtype)
+            lse_ref[g] = m_acc[g] + jnp.log(l)
+
+    def by_query(width):
+        return pl.BlockSpec((None, None, gs, block, width),
+                            lambda b, h, g, i: (b, h, g, i, 0))
+
+    def whole(width):
+        return pl.BlockSpec((None, None, t, width),
+                            lambda b, h, g, i: (b, h, 0, 0))
+
+    # one number a position: a row ``[1, block]`` of ``[.., 1, T]``
+    row = pl.BlockSpec((None, None, gs, 1, block),
+                       lambda b, h, g, i: (b, h, g, 0, i))
+    out, lse = pallas_call(
+        kernel, q, k, v, grid=(b, hkv, group // gs, t // block),
+        in_specs=[by_query(d), whole(d), whole(dv_)],
+        out_specs=[by_query(dv_), row],
+        scratch_shapes=[pltpu.VMEM((gs, 1, block), f32),
+                        pltpu.VMEM((gs, 1, block), f32),
+                        pltpu.VMEM((gs, dv_, block), f32)],
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, group, t, dv_), cd),
+                   jax.ShapeDtypeStruct((b, hkv, group, 1, t), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_ATTENTION_VMEM),
+        name="window_attention_forward" if window
+        else "causal_attention_forward")
+    return out, lse[:, :, :, 0]
+
+
+@functools.lru_cache(None)
+def _attention_jitted():
+    """The forward kernel's caller as a ``jax.jit`` function, made once, as
+    :func:`_ssd_jitted` and for its reason: a model's attention layers share
+    shapes, so a step traces and lowers the kernel once a shape."""
+    import jax
+
+    return jax.jit(_attention_forward, static_argnames=("window",))
+
+
+def attention_forward(q, k, v, window=0):
+    """:func:`_attention_forward` through its shared ``jax.jit``."""
+    return _attention_jitted()(q, k, v, window=window)

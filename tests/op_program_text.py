@@ -4,10 +4,12 @@ older language cells, each node as its model's factory builds it (no
 argument this repo added after them is given), as sha256 of the text with
 the addresses in it taken out. ``tests/test_qwen3_next.py`` holds them
 against the hashes this file gave on the parent of the PR that added
-``num_key_heads``, ``score_func`` and ``aux_loss_coef``, and
-``tests/test_laguna.py`` the attentions against the parent of the PR that
-added ``window`` and ``rope_factor``: under those arguments' defaults the older cells' operators trace the program they did,
-to the character. Run it against another tree to read that tree's::
+``num_key_heads``, ``score_func`` and ``aux_loss_coef``: under those
+arguments' defaults the older cells' operators trace the program they did,
+to the character; ``tests/test_attention_window.py`` holds the attentions
+against what this file gave on the tree of the PR that last changed their
+text on purpose (48: the forward pass a kernel of this repo's). Run it
+against another tree to read that tree's::
 
     PYTHONPATH=<tree> python tests/op_program_text.py
 """

@@ -64,7 +64,7 @@ def test_one_kernel_backward_agrees_with_the_product_and_the_split_kernels(
     blockwise body, and against JAX's ``dq`` and ``dkv`` kernels, which form
     the same five products' operands in seven."""
     monkeypatch.setattr(pk, "ATTENTION_BACKWARD_BLOCK", 128)
-    assert pk.attention_backward_applicable(t, d, dv, dtype)
+    assert pk.attention_applicable(t, d, dv, dtype)
     q, k, v, do = operands(b, hkv, group, t, d, dv, dtype)
     fused = gradients(attention.attend_splash, q, k, v, do)
     split = gradients(lambda *a: attention.attend_splash(*a, fused=False),
@@ -154,7 +154,7 @@ def test_the_pairs_of_blocks_are_the_causal_half(blocks):
          "one-block", "float16", "too-long", "part-blocks", "part-lanes"])
 def test_the_rule_takes_the_cells_shapes_and_refuses_what_does_not_fit(
         t, d, dv, dtype, takes):
-    assert pk.attention_backward_applicable(t, d, dv, dtype) is takes
+    assert pk.attention_applicable(t, d, dv, dtype) is takes
 
 
 @pytest.mark.parametrize("fits", [True, False], ids=["fused", "split"])
@@ -166,7 +166,7 @@ def test_the_operator_counts_which_backward_pass_it_takes(monkeypatch, fits):
     from mxnet_tpu.ops.registry import OpContext, create_operator
 
     if not fits:
-        monkeypatch.setattr(pk, "attention_backward_applicable",
+        monkeypatch.setattr(pk, "attention_applicable",
                             lambda *a: False)
     t, heads, kv, d = 256, 4, 2, 128
     rng = np.random.default_rng(1)

@@ -7,9 +7,10 @@ the arguments an operator does not keep whole (``jax.eval_shape`` over
 nothing allocated). Every operator that chooses a body from its shapes
 counts the choice once a traced node (``lower.*``), so the counters read
 the Pallas body for every scan, delta-rule, attention and experts node and
-nothing for any XLA fallback (an attention node also counts its backward
-pass: ``attention_backward.fused``, this repo's one kernel of five products,
-or ``.split``, JAX's two of seven; and its mask, ``attention_mask.causal``
+nothing for any XLA fallback (an attention node also counts its two
+passes: ``attention_forward.fused``, this repo's forward kernel, or
+``.splash``, JAX's; ``attention_backward.fused``, this repo's one kernel of
+five products, or ``.split``, JAX's two of seven; and its mask, ``attention_mask.causal``
 or ``.window``, a windowed node the pairs of blocks its band holds:
 ``attention_window.block_pairs``); a delta-rule node also counts WHERE its
 kernels read the op's wide arrays (``delta_rule_layout.rows``: as the
@@ -40,6 +41,7 @@ KERNEL = {
     "GatedDeltaRule": ["delta_rule_kernel.pallas_chunked"],
     "CausalAttention": ["attention_kernel.pallas_splash",
                         "attention_layout.fused",
+                        "attention_forward.fused",
                         "attention_backward.fused"],
     "RoutedExperts": ["experts_kernel.pallas_grouped",
                       "experts_plan.column_sort"],
@@ -53,7 +55,8 @@ DELTA_LAYOUT = {
 }
 FALLBACKS = ["scan_kernel.xla_chunked", "delta_rule_kernel.xla_chunked",
              "attention_kernel.xla_blockwise", "attention_layout.split",
-             "attention_backward.split", "experts_kernel.xla_loop"]
+             "attention_forward.splash", "attention_backward.split",
+             "experts_kernel.xla_loop"]
 # the operators each cell's cut of its model holds, by node
 CELLS = {
     "nemotron3_nano_l9_e8of128_bf16": {

@@ -7,6 +7,7 @@ one process may load the TPU's library, and only a test that has started
 may ask for it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,10 +121,10 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv,
     heads of 64, half a lane tile, Ling's 32 heads whose 192-wide keys go
     widened to 256 beside values of 128, Qwen3-Next's 2 groups of eight heads
     of 256, and Laguna's 8 groups of six (full layers) and of eight under a
-    window of 512 (``LocalMask``; the backward pass over the band's 31 pairs
-    of blocks), over 8,192 positions: JAX's forward kernel and this repo's
-    one backward kernel, two custom calls where JAX's own backward pass made
-    three, and no ``[T, T]`` array in either pass."""
+    window of 512 (both passes over the band's 31 pairs of blocks), over
+    8,192 positions: this repo's forward kernel and its one backward kernel,
+    two custom calls where JAX's own three passes made three, and no ``[T,
+    T]`` array in either pass."""
     from mxnet_tpu.ops import attention
 
     b, t = 1, 8192
@@ -143,7 +144,10 @@ def test_splash_attention_compiles_for_the_chip(one_chip, h, group, d, dv,
             shape(b, h, t, dv)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
-    assert ("window" if window else "causal") + "_attention_backward" in text
+    for which in ("forward", "backward"):
+        assert "%s_attention_%s" % ("window" if window else "causal",
+                                    which) in text
+    assert "splash_mqa" not in text
     assert "8192,8192" not in text
 
 
@@ -168,7 +172,7 @@ def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv,
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    assert pk.attention_backward_applicable(t, d, dv, jnp.bfloat16)
+    assert pk.attention_applicable(t, d, dv, jnp.bfloat16)
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(
             lambda *a: pk.attention_backward(*a, window=window)).lower(
@@ -176,6 +180,45 @@ def test_attention_backward_compiles_for_the_chip(one_chip, h, group, d, dv,
             shape(b, h, group, t, dv), shape(b, h, group, t, dtype="float32"),
             shape(b, h, group, t, dtype="float32")).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("h,group,d,dv,window", [
+    (20, 1, 256, 256, 0), (8, 4, 64, 64, 0), (32, 1, 256, 128, 0),
+    (2, 8, 256, 256, 0), (2, 16, 128, 128, 0), (15, 1, 128, 128, 0),
+    (8, 6, 128, 128, 0), (8, 8, 128, 128, 512), (8, 8, 128, 128, 700)],
+    ids=["glm", "lfm2", "ling", "qwen3-next", "nemotron", "olmo",
+         "laguna-full", "laguna-window", "window-of-a-block-and-a-third"])
+def test_attention_forward_compiles_for_the_chip(one_chip, h, group, d, dv,
+                                                 window):
+    """The forward kernel at the seven language cells' shapes over 8,192
+    positions: a head's keys and values whole in VMEM (8 MiB at 256 + 256
+    columns, double-buffered: above Mosaic's default limit) and the key
+    blocks a loop of dynamic length inside a query block's step; scores by
+    key rows, so a position's running max, sum and log-sum-exp are one
+    number of a ``[1, 512]`` row; the group's query heads a step as
+    ``_attention_forward_heads`` gives them (eight of Nemotron's sixteen),
+    their float32 ``[Dv, 512]`` accumulators in VMEM; the transposed ``v``
+    block and the accumulator's one transpose a query block, half-lane
+    heads and the column parts' slices under a window are Mosaic's to
+    refuse. One custom call; the log-sum-exp leaves as ``[.., 1, T]`` rows,
+    so no lane is cut out of a ``[.., T, 128]`` array after it."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    b, t = 1, 8192
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    assert pk.attention_applicable(t, d, dv, jnp.bfloat16)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda *a: pk.attention_forward(*a, window=window)).lower(
+            shape(b, h, group, t, d), shape(b, h, t, d),
+            shape(b, h, t, dv)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert ("window" if window else "causal") + "_attention_forward" in text
+    assert not re.search(r"f32\[[0-9,]*8192,128\]", text)
 
 
 @pytest.mark.parametrize("batch,t,heads,d,turned,dtype", [

@@ -189,8 +189,8 @@ def _kernel_case(name):
     if name == "attention_backward":
         # a group of two 64-wide heads over one block of 128 positions
         t, d, group = 128, 64, 2
-        assert pk.attention_backward_applicable(t, d, d, f32)
-        assert not pk.attention_backward_applicable(t + 64, d, d, f32)
+        assert pk.attention_applicable(t, d, d, f32)
+        assert not pk.attention_applicable(t + 64, d, d, f32)
         return (pk.attention_backward,
                 (ones(1, 1, group, t, d) * 0.1, ones(1, 1, t, d),
                  ones(1, 1, t, d), ones(1, 1, group, t, d),

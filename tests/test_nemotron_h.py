@@ -1069,11 +1069,11 @@ def _count(jaxpr, pick):
 
 
 def test_attention_kernel_and_routing_run_once_a_step(monkeypatch):
-    """Under segment recomputation the splash kernel's output and
+    """Under segment recomputation the attention kernel's output and
     log-sum-exp and the experts' routing are kept: the forward kernel is
     called as often as the one backward kernel (once a step:
     ``attend_splash`` names both in its own forward rule, where the plan
-    sees them), JAX's ``dq`` and ``dkv`` kernels are not called, and
+    sees them), none of JAX's three splash kernels is called, and
     ``top_k`` and the layout's ``sort`` appear once a ``RoutedExperts``
     node (a second ``sort`` is the backward
     pass's: it brings the combine weights' gradient from the slots back to
@@ -1092,15 +1092,17 @@ def test_attention_kernel_and_routing_run_once_a_step(monkeypatch):
                       and name in str(e.params.get("name")))
 
     # one call a branch of ``platform_dependent`` (interpreter, Mosaic)
-    assert kernels("splash_mqa_fwd") \
+    assert kernels("causal_attention_forward") \
         == kernels("causal_attention_backward") == 2
-    assert kernels("splash_mqa_dq") == kernels("splash_mqa_dkv") == 0
+    assert kernels("splash_mqa") == 0
     assert _count(jaxpr, lambda e: e.primitive.name == "top_k") == 1
     assert _count(jaxpr, lambda e: e.primitive.name == "sort") == 2
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
-    assert 'kernel_name = "splash_mqa_fwd_residuals"' in text
-    assert len(re.findall(r"call @_splash_attention(_\d+)?\(", text)) == 1
-    assert text.count('kernel_name = "causal_attention_backward"') == 1
+    assert "splash" not in text
+    assert len(re.findall(r"call @_attention_forward(_\d+)?\(", text)) == 1
+    for which in ("forward", "backward"):
+        assert text.count(
+            'kernel_name = "causal_attention_%s"' % which) == 1
 
 
 @functools.lru_cache(None)
