@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import os
 import types
 
 import numpy as np
@@ -58,15 +59,69 @@ def pallas_available() -> bool:
         return False
 
 
+# the kernels this module's ``pallas_call`` has traced, by the name their
+# Mosaic module goes under: they are serialized without their locations
+_NO_LOCATIONS = set()
+
+
+@functools.lru_cache(None)
+def _strip_locations():
+    """Once a process, when the first kernel is traced: the kernels NAMED
+    in ``_NO_LOCATIONS`` reach XLA without their operations' locations. A
+    Pallas kernel is an opaque payload to XLA (the serialized Mosaic
+    module), and JAX writes into it the file paths and names of the ten
+    innermost Python frames of every operation, which reach past the jit
+    into WHOEVER CALLED IT: the same step then asks the persistent cache
+    for another key from ``xprof``'s wrapper than from a plain ``jax.jit``
+    call, from another caller, and from another checkout directory (PR 49:
+    every traced run of a language cell built its step again, 40-70 s).
+    JAX captures the frames at each operation's trace and serializes the
+    module itself, with no option and no hook of the kernel's author's, so
+    the one seam is its serializer: ``strip-debuginfo`` runs over the
+    module first. Nothing else JAX lowers changes, and no option of JAX's
+    is set. With ``JAX_TRACEBACK_IN_LOCATIONS_LIMIT`` in the environment
+    (a kernel's author asking JAX for frames: a Mosaic error names them)
+    nothing is stripped (``docs/pallas.md``)."""
+    if "JAX_TRACEBACK_IN_LOCATIONS_LIMIT" in os.environ:
+        return
+    try:
+        from jax._src import tpu_custom_call
+        from jax._src.lib.mlir import passmanager
+
+        serialize = tpu_custom_call._lower_mosaic_module_to_asm
+    except (ImportError, AttributeError):
+        # a JAX that serializes elsewhere: the kernels run as they are,
+        # and ``tests/test_build_identity.py`` says what was lost
+        return
+
+    @functools.wraps(serialize)
+    def without_locations(module, **kw):
+        # the module was made for this one serialization: in place
+        attrs = module.operation.attributes
+        if "sym_name" in attrs and attrs["sym_name"].value in _NO_LOCATIONS:
+            with module.context:
+                passmanager.PassManager.parse(
+                    "builtin.module(strip-debuginfo)").run(module.operation)
+        return serialize(module, **kw)
+
+    tpu_custom_call._lower_mosaic_module_to_asm = without_locations
+
+
 def pallas_call(kernel, *operands, **kw):
     """``pl.pallas_call(kernel, **kw)(*operands)`` with interpret mode
     chosen by the platform the enclosing computation is LOWERED for
     (``jax.lax.platform_dependent``): the interpreter on ``cpu``, the
     Mosaic-compiled kernel everywhere else. A process-wide "what is the
     default backend" answer is wrong as soon as one process holds two
-    backends, which every process on a TPU host does."""
+    backends, which every process on a TPU host does. A kernel given a
+    ``name`` lowers to the same bytes whoever calls it
+    (:func:`_strip_locations`)."""
     import jax
     from jax.experimental import pallas as pl
+
+    if kw.get("name"):
+        _NO_LOCATIONS.add(kw["name"])
+        _strip_locations()
 
     def lowered(interpret):
         return lambda *ops: pl.pallas_call(kernel, interpret=interpret,

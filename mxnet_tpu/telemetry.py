@@ -39,6 +39,7 @@ JSONL schema.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import copy
 import json
 import os
@@ -93,30 +94,72 @@ _JAX_EVENT_SPANS = {
 }
 
 
+# JAX's plain events about its persistent cache -> what a build that saw
+# one reads as its ``cache``: a hit is counted when the cache answers, a
+# miss when JAX WRITES what XLA built (a build that saw neither ran with
+# no cache directory, or under JAX's thresholds of a second and a size)
+_JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_misses": "built",
+    "/jax/compilation_cache/cache_hits": "read",
+}
+
+
 def enabled() -> bool:
     return _ENABLED
 
 
 def _on_jax_duration(event, duration_secs, **_kw):
-    if not _ENABLED:
-        return
     name = _JAX_EVENT_SPANS.get(event)
     # a trace that ends inside another (the jitted helpers a traced
     # function calls, thousands in one train step) is left out: the
     # enclosing jax.trace covers its time, and the ring is bounded
-    if name is not None and (_outermost_trace is None
-                             or _outermost_trace()):
+    if name is None or not (_outermost_trace is None
+                            or _outermost_trace()):
+        return
+    build = getattr(_tls, "build", None)
+    if build is not None:
+        build[name] += duration_secs
+    if _ENABLED:
         stack = getattr(_tls, "stack", None)
         _spans.append((name, threading.get_ident(),
                        time.perf_counter() - duration_secs, duration_secs,
                        stack[-1].name if stack else None, _span_step))
 
 
+def _on_jax_event(event, **_kw):
+    answer = _JAX_CACHE_EVENTS.get(event)
+    build = getattr(_tls, "build", None)
+    if answer is not None and build is not None:
+        build["cache"] = answer
+
+
+@contextlib.contextmanager
+def jax_build():
+    """What JAX reports ON THIS THREAD until the block ends, as one dict
+    a build: the seconds of its four duration events under their span
+    names (``jax.trace``, ``jax.lower``, ``jax.cache_read``,
+    ``jax.backend_compile``; outermost traces only, and the last ENCLOSES
+    the cache read) and ``cache``: ``"read"`` if the persistent cache
+    answered, ``"built"`` if XLA built the program and the cache took it,
+    ``"off"`` if the cache had no part (no directory; a program under
+    JAX's thresholds). Collected whether telemetry is on or not; a build
+    opened inside another takes its events alone."""
+    _hook_jax()
+    outer = getattr(_tls, "build", None)
+    build = _tls.build = dict.fromkeys(_JAX_EVENT_SPANS.values(), 0.0)
+    build["cache"] = "off"
+    try:
+        yield build
+    finally:
+        _tls.build = outer
+
+
 def _hook_jax():
-    """Once per process: JAX's ``TraceAnnotation`` for the spans, and a
-    listener through which every program JAX traces, lowers, builds or
+    """Once per process: JAX's ``TraceAnnotation`` for the spans, and the
+    listeners through which every program JAX traces, lowers, builds or
     reads from its persistent cache while telemetry is on leaves a
-    ``jax.*`` span, whichever call site asked for it."""
+    ``jax.*`` span, whichever call site asked for it, and an open
+    :func:`jax_build` its seconds and the cache's answer."""
     global _TraceAnnotation, _outermost_trace
     with _reg_lock:
         if _TraceAnnotation is not None:
@@ -129,6 +172,7 @@ def _hook_jax():
     except (ImportError, AttributeError):
         pass                 # a JAX without it: every trace is recorded
     monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    monitoring.register_event_listener(_on_jax_event)
 
 
 def enable():

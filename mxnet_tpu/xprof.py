@@ -20,6 +20,14 @@ actually runs:
   "(64,3,224,224)f32 -> (32,3,224,224)f32 on batch.data" instead of
   "something retraced". It follows telemetry's one switch: on when
   ``telemetry.enabled()`` at the moment a site is built.
+* **A build names itself** — the record also says WHAT was built
+  (:func:`program_identity`: a hash of the lowered module's text and one
+  of each Pallas kernel's payload, apart), whether the persistent cache
+  answered and the build's seconds by JAX's own phases;
+  :func:`diff_builds` tells two records, of two processes if need be,
+  apart by the part that differs. Every site lowers to the same bytes
+  however it is reached: this repo's kernels carry no Python frame
+  (``ops/pallas_kernels.py``: ``_strip_locations``).
 * **Op-category attribution** — :func:`hlo_op_breakdown` parses the
   compiled executable's optimized HLO into a conv / dot / fusion /
   collective / transpose / elementwise FLOP+bytes table whose category
@@ -54,6 +62,8 @@ compiles and zero extra dispatches (regression-tested against
 """
 from __future__ import annotations
 
+import contextlib
+import hashlib
 import logging
 import re
 import threading
@@ -68,7 +78,8 @@ _log = logging.getLogger(__name__)
 
 __all__ = [
     "enabled", "enable", "disable", "reset", "jit", "record_compile",
-    "records", "summary", "last_retrace_cause", "hlo_op_breakdown",
+    "records", "summary", "last_retrace_cause", "program_identity",
+    "diff_builds", "hlo_op_breakdown",
     "hlo_phase_census", "analyze", "chip_peaks", "chip_peak_tflops",
     "chip_hbm_gbps", "CHIP_PEAKS", "hbm_stats",
     "preflight_check", "device_memory_limit",
@@ -106,13 +117,24 @@ def disable():
 # ---------------------------------------------------------------------------
 
 class CompileRecord:
-    """One measured ``lower()``/``compile()`` of a step-path site."""
+    """One measured ``lower()``/``compile()`` of a step-path site.
+    ``module_sha`` / ``kernels`` say what was built
+    (:func:`program_identity`); ``cache`` whether JAX's persistent cache
+    answered (``"read"``), took what XLA built (``"built"``) or had no
+    part (``"off"``: no directory, or a program under JAX's thresholds of
+    a second and a size); ``trace_s``, ``lower_s``,
+    ``cache_read_s`` and ``backend_compile_s`` are ``compile_time_s`` by
+    JAX's own phases, the last LESS the cache read it encloses. None on a
+    record made from an executable alone (:func:`record_compile` without
+    ``identity`` / ``build``)."""
 
     __slots__ = ("site", "seq", "compile_time_s", "signature", "flops",
                  "bytes_accessed", "argument_bytes", "output_bytes",
                  "alias_bytes", "temp_bytes", "generated_code_bytes",
                  "held_bytes", "op_breakdown", "census", "matrix_flops",
-                 "census_loops_once", "retrace_cause", "num_devices", "ts")
+                 "census_loops_once", "retrace_cause", "num_devices", "ts",
+                 "module_sha", "kernels", "cache", "trace_s", "lower_s",
+                 "cache_read_s", "backend_compile_s")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -120,9 +142,9 @@ class CompileRecord:
 
     def to_dict(self) -> dict:
         d = {k: getattr(self, k) for k in self.__slots__}
-        d["signature"] = [[n, list(a[0]), a[1]]
-                          + ([a[3]] if len(a) > 3 and a[3] else [])
-                          for n, a in (self.signature or ())]
+        d["signature"] = _signature_rows(self.signature)
+        if self.kernels is not None:
+            d["kernels"] = [list(k) for k in self.kernels]
         return d
 
 
@@ -217,6 +239,13 @@ def leaf_signature(args, arg_names=None) -> tuple:
     return tuple(specs)
 
 
+def _signature_rows(signature) -> list:
+    """A signature as ``to_dict()`` writes it: ``[name, shape, dtype]``
+    with the placement behind where there is one."""
+    return [[n, list(a[0]), a[1]] + ([a[3]] if len(a) > 3 and a[3] else [])
+            for n, a in (signature or ())]
+
+
 def diff_signatures(prev, cur) -> Optional[str]:
     """Human-readable retrace cause: which leaves' avals changed."""
     if prev is None or prev == cur:
@@ -232,6 +261,81 @@ def diff_signatures(prev, cur) -> Optional[str]:
     if len(changes) > 3:
         head += " (+%d more)" % (len(changes) - 3)
     return head
+
+
+# -- what was built ---------------------------------------------------------
+
+# a Pallas kernel in a lowered module's text: the custom call, its payload
+# (``backend_config``: the serialized Mosaic module, in which MLIR writes a
+# quote as ``\22``, so the first bare quote ends it) and, behind it on the
+# same line, the name the kernel was given
+_PAYLOAD_RE = re.compile(
+    rb'@tpu_custom_call\([^\n]*?backend_config = "([^"]*)"')
+_KERNEL_NAME_RE = re.compile(rb'kernel_name = "([^"]*)"')
+
+
+def program_identity(text: str):
+    """``(module_sha, kernels)`` of a lowered module's text
+    (``lowered.as_text()``): the sha256 of the text with every
+    ``tpu_custom_call``'s payload cut out, and ``[(kernel's name, sha256
+    of its payload)]`` in program order. JAX strips locations from the
+    module it hashes for the persistent cache; it cannot look inside a
+    payload, so two programs that miss each other's entry differ in one
+    of these parts, and the parts say in which."""
+    # one copy of a text that runs to hundreds of MB (a language step's
+    # tables are constants in it), hashed in place
+    data = text.encode()
+    view = memoryview(data)
+    module, kernels, pos = hashlib.sha256(), [], 0
+    for m in _PAYLOAD_RE.finditer(data):
+        module.update(view[pos:m.start(1)])
+        pos = m.end(1)
+        eol = data.find(b"\n", pos)
+        name = _KERNEL_NAME_RE.search(data, pos,
+                                      eol if eol >= 0 else len(data))
+        kernels.append((name.group(1).decode() if name
+                        else "tpu_custom_call",
+                        hashlib.sha256(view[m.start(1):pos]).hexdigest()))
+    module.update(view[pos:])
+    return module.hexdigest(), kernels
+
+
+def diff_builds(a, b) -> Optional[str]:
+    """Which part of two builds differs, in :func:`diff_signatures`'
+    manner, or None: ``arguments: ...`` (the signature diff), ``module
+    text outside the kernels``, ``kernel <name> (#i): payload`` by the
+    kernels' places in the program, ``kernels: 10 against 9``. Each side
+    a :class:`CompileRecord` or its ``to_dict()`` (a site's ``last`` in a
+    saved :func:`summary`), so that two PROCESSES' builds of "the same"
+    step compare: a miss of the persistent cache on a step nobody changed
+    is one call to read."""
+    a, b = (x.to_dict() if isinstance(x, CompileRecord) else x
+            for x in (a, b))
+
+    def signature(build):
+        return tuple((r[0], (tuple(r[1]), r[2], False,
+                             r[3] if len(r) > 3 else None))
+                     for r in build.get("signature") or ())
+
+    found = []
+    arguments = diff_signatures(signature(a), signature(b))
+    if arguments:
+        found.append("arguments: " + arguments)
+    if a.get("module_sha") != b.get("module_sha"):
+        found.append("module text outside the kernels")
+    ka, kb = a.get("kernels") or [], b.get("kernels") or []
+    if len(ka) != len(kb):
+        found.append("kernels: %d against %d" % (len(ka), len(kb)))
+    else:
+        places: Dict[str, list] = {}
+        for i, ((na, sha_a), (nb, sha_b)) in enumerate(zip(ka, kb)):
+            if na != nb:
+                found.append("kernel %s against %s (#%d)" % (na, nb, i))
+            elif sha_a != sha_b:
+                places.setdefault(na, []).append("#%d" % i)
+        found += ["kernel %s (%s): payload" % (name, ", ".join(at))
+                  for name, at in places.items()]
+    return "; ".join(found) or None
 
 
 # -- executable analysis ----------------------------------------------------
@@ -304,13 +408,29 @@ def _read_program(site: str, compiled, census: bool):
 
 def record_compile(site: str, compiled, compile_time_s: float,
                    signature: Optional[tuple] = None,
-                   census: bool = False) -> CompileRecord:
+                   census: bool = False, identity=None,
+                   build: Optional[dict] = None) -> CompileRecord:
     """Record one measured compile into the registry and, with telemetry
     on, into the gauges ``compile.<site>.*`` (one set a site, the latest
     program's); computes the retrace-cause diff against the site's
     previous signature. ``census``: group the program's instructions by
-    phase too (:func:`hlo_phase_census`)."""
+    phase too (:func:`hlo_phase_census`). ``identity``: what
+    :func:`program_identity` made of the lowered text; ``build``: what
+    ``telemetry.jax_build`` collected while the program was lowered and
+    compiled."""
     global _last_cause, _seq
+    named = {}
+    if identity is not None:
+        named["module_sha"], named["kernels"] = identity
+    if build is not None:
+        read = build["jax.cache_read"]
+        named.update(
+            cache=build["cache"], trace_s=round(build["jax.trace"], 6),
+            lower_s=round(build["jax.lower"], 6),
+            cache_read_s=round(read, 6),
+            # JAX times the backend's compile around the cache read
+            backend_compile_s=round(
+                max(0.0, build["jax.backend_compile"] - read), 6))
     cost = _cost_dict(compiled)
     mem = _memory_dict(compiled) or {}
     breakdown, by_set, matrix_flops, loops_once = _read_program(
@@ -335,7 +455,7 @@ def record_compile(site: str, compiled, compile_time_s: float,
             matrix_flops=matrix_flops, census_loops_once=loops_once,
             retrace_cause=cause,
             num_devices=_device_count(compiled),
-            ts=round(time.time(), 6), **mem)
+            ts=round(time.time(), 6), **mem, **named)
         st["compiles"] += 1
         st["time_s"] += float(compile_time_s)
         st["sig"] = signature
@@ -349,8 +469,6 @@ def record_compile(site: str, compiled, compile_time_s: float,
     if _tel.enabled():
         _tel.inc("compile.count")
         _tel.observe("compile.time_ms", compile_time_s * 1e3)
-        if flops:
-            _tel.inc("compile.flops", int(flops))
         _publish(rec)
     return rec
 
@@ -365,13 +483,18 @@ def _publish(rec: CompileRecord):
     reported, ``.build_s``, ``compile.<site>.census.<set>.ops`` /
     ``.flops`` / ``.bytes`` for the sets of phases that occur and
     ``.matrix_flops.<phase>`` for the products by their own phase, where
-    the site asked for the census."""
+    the site asked for the census; of a build that was watched
+    ``.cache_read`` (1: the persistent cache answered; 0: XLA built the
+    program, or the cache is off). The build's other parts are the
+    record's (:func:`summary`, ``trace_report --view compile``)."""
     base = "compile.%s." % rec.site
     for field in _SITE_GAUGES:
         v = getattr(rec, field)
         if v is not None:
             _tel.set_gauge(base + field, v)
     _tel.set_gauge(base + "build_s", rec.compile_time_s)
+    if rec.cache is not None:
+        _tel.set_gauge(base + "cache_read", int(rec.cache == "read"))
     for name, row in (rec.census or {}).items():
         for field, v in row.items():
             _tel.set_gauge("%scensus.%s.%s" % (base, name, field), v)
@@ -420,7 +543,8 @@ def jit(fn, site: str, arg_names=None, census=False, **jit_kw):
     compile, no extra dispatch). Positional calling only, which is all
     the step-path sites use. ``census``: the site traces under the
     phases' scopes (:data:`PHASES`) and wants its program's instructions
-    grouped by them, under the span ``step.census``."""
+    grouped by them, under the span ``step.census`` (and its identity
+    hashed under ``step.identity``)."""
     import jax
 
     jfn = jax.jit(fn, **jit_kw)
@@ -510,13 +634,21 @@ class _InstrumentedJit:
     def _compile(self, args, sig):
         t0 = time.perf_counter()
         try:
-            compiled = self._jit.lower(*args).compile()
+            with _tel.jax_build() as build:
+                lowered = self._jit.lower(*args)
+                compiled = lowered.compile()
         except NotImplementedError:
             self._cache[sig] = _FALLBACK
             return _FALLBACK
-        rec = record_compile(self._site, compiled,
-                             time.perf_counter() - t0, signature=sig,
-                             census=self._census)
+        build_s = time.perf_counter() - t0
+        # as the census: what the hashing costs shows in the build of the
+        # site that asked (the fused step's ``step.build``)
+        with _tel.span("step.identity") if self._census \
+                else contextlib.nullcontext():
+            identity = program_identity(lowered.as_text())
+        rec = record_compile(self._site, compiled, build_s, signature=sig,
+                             census=self._census, identity=identity,
+                             build=build)
         if _env.get("MXNET_TPU_XPROF_PREFLIGHT") and rec.held_bytes:
             preflight_check(
                 rec.held_bytes,

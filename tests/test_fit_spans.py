@@ -165,11 +165,11 @@ def test_jax_duration_events_become_spans_once():
 SETUP = {"fit.bind", "fit.init_params", "fit.init_optimizer",
          "fit.fused_build"}
 JAX = {"jax.trace", "jax.lower", "jax.backend_compile", "jax.cache_read"}
-# ``step.census``: with telemetry on the fused step's program is read
-# once, inside ``step.build``
+# ``step.census`` / ``step.identity``: with telemetry on the fused step's
+# program is read and its lowered text hashed once, inside ``step.build``
 STEP = {True: {"fit.step", "fit.next", "step.marshal", "step.dispatch",
                "step.write_back", "fit.callbacks", "step.build",
-               "step.census"},
+               "step.census", "step.identity"},
         False: {"fit.step", "fit.next", "fit.forward_backward",
                 "fit.update", "fit.update_metric", "fit.callbacks"}}
 
@@ -182,12 +182,16 @@ def test_fit_leaves_exactly_the_named_spans(monkeypatch, fused):
     count = Counter(s[0] for s in spans)
     assert set(count) - JAX == SETUP | STEP[fused]
     assert all(count[name] == 1 for name in SETUP)
-    per_step = STEP[fused] - {"fit.next", "step.build", "step.census"}
+    per_step = STEP[fused] - {"fit.next", "step.build", "step.census",
+                              "step.identity"}
     assert all(count[name] == steps for name in per_step), count
     # the next() that found the epoch over is a span; its step is not
     assert count["fit.next"] == steps + 1
     if fused:
         assert count["step.build"] == 2      # _build, and the first call
+        assert count["step.census"] == count["step.identity"] == 1
+        assert {s[4] for s in spans if s[0] == "step.identity"} \
+            == {"step.dispatch"}             # inside the first call's build
     by_step = {}
     for name, _tid, _t0, _dur, parent, step in spans:
         if name in SETUP:

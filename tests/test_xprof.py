@@ -76,6 +76,201 @@ def test_retrace_cause_names_changed_aval(xp):
     assert "batch.data" in (xprof.last_retrace_cause() or "")
 
 
+# -- a build names itself ---------------------------------------------------
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent cache in a directory of the test's, writing every
+    program however small and quick (the tests run on the CPU without
+    one); the process's settings come back afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    want = {"jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0,
+            "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {name: getattr(jax.config, name) for name in want}
+    for name, value in want.items():
+        jax.config.update(name, value)
+    cc.reset_cache()
+    yield tmp_path
+    for name, value in before.items():
+        jax.config.update(name, value)
+    cc.reset_cache()
+
+
+def _built_again(site):
+    """A new jit of the same function: the same module, no executable."""
+    def poly(a):
+        return jnp.sum(a * a * 3.0 + a)
+
+    f = xprof.jit(poly, site=site, arg_names=("a",))
+    f(np.ones((16, 4), np.float32))
+    return [r for r in xprof.records() if r.site == site][-1]
+
+
+def test_record_of_a_built_and_of_a_read_back_program(xp, persistent_cache):
+    built = _built_again("t.cache")
+    assert built.cache == "built" and built.cache_read_s == 0
+    assert built.backend_compile_s > 0
+    assert list(persistent_cache.iterdir())          # written
+    assert telemetry.peek("compile.t.cache.cache_read", kind="gauge") == 0
+    read = _built_again("t.cache")
+    assert read.cache == "read" and read.cache_read_s > 0
+    # JAX times the backend around the read: what is left of it is small
+    assert read.backend_compile_s < read.cache_read_s + 0.05
+    for rec in (built, read):
+        assert rec.trace_s > 0 and rec.lower_s > 0
+        assert rec.trace_s + rec.lower_s + rec.cache_read_s \
+            + rec.backend_compile_s <= rec.compile_time_s
+        assert rec.kernels == []             # no Pallas kernel: no payload
+    assert read.module_sha == built.module_sha and len(read.module_sha) == 64
+    assert xprof.diff_builds(built, read) is None
+    # of the build's parts the gauges carry the answer alone (a reader's);
+    # the rest is the record's, and the summary's below
+    gauges = telemetry.snapshot()["compile"]["t"]["cache"]
+    assert gauges["cache_read"] == 1
+    assert gauges["build_s"] == read.compile_time_s
+    assert not {"cache_read_s", "backend_compile_s", "kernels"} & set(gauges)
+    assert telemetry.peek("compile.flops") is None   # went in PR 49
+    last = xprof.summary()["sites"]["t.cache"]["last"]
+    assert (last["cache"], last["module_sha"]) == ("read", read.module_sha)
+
+
+def test_without_a_persistent_cache_a_build_reads_off(xp):
+    rec = _built_again("t.nocache")
+    assert rec.cache == "off" and rec.cache_read_s == 0
+    assert telemetry.peek("compile.t.nocache.cache_read", kind="gauge") == 0
+
+
+def test_a_build_inside_a_build_takes_its_own_events():
+    with telemetry.jax_build() as outer:
+        with telemetry.jax_build() as inner:
+            telemetry._on_jax_event("/jax/compilation_cache/cache_hits")
+            telemetry._on_jax_duration(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 2.0)
+        telemetry._on_jax_event(
+            "/jax/compilation_cache/compile_requests_use_cache")
+        assert outer["cache"] == "off"       # asked is not answered
+        telemetry._on_jax_event("/jax/compilation_cache/cache_misses")
+        telemetry._on_jax_duration(
+            "/jax/core/compile/backend_compile_duration", 30.0)
+        telemetry._on_jax_duration("/some/other/event", 1.0)
+    assert (inner["cache"], inner["jax.cache_read"]) == ("read", 2.0)
+    assert (outer["cache"], outer["jax.cache_read"],
+            outer["jax.backend_compile"]) == ("built", 0.0, 30.0)
+    telemetry._on_jax_event("/jax/compilation_cache/cache_hits")  # no build
+
+
+_TWO_KERNELS = """module @jit_step {
+  func.func public @main(%arg0: tensor<8xf32>) -> tensor<8xf32> {
+    %0 = stablehlo.custom_call @tpu_custom_call(%arg0) {backend_config = \
+"{\\22body\\22: \\22FIRST\\22}", kernel_name = "scan_forward", \
+operand_layouts = []} : (tensor<8xf32>) -> tensor<8xf32>
+    %1 = stablehlo.add %0, %0 : tensor<8xf32>
+    %2 = stablehlo.custom_call @tpu_custom_call(%1) {backend_config = \
+"{\\22body\\22: \\22SECOND\\22}"} : (tensor<8xf32>) -> tensor<8xf32>
+    %3 = stablehlo.custom_call @Sharding(%2) {backend_config = "other"} \
+: (tensor<8xf32>) -> tensor<8xf32>
+    return %3 : tensor<8xf32>
+  }
+}
+"""
+
+
+def test_program_identity_cuts_the_payloads_out_of_the_text():
+    import hashlib
+
+    a = _TWO_KERNELS.replace("FIRST", "QUJD").replace("SECOND", "REVG")
+    b = a.replace("REVG", "R0hJ")           # the second payload differs
+    sha_a, kernels_a = xprof.program_identity(a)
+    sha_b, kernels_b = xprof.program_identity(b)
+    assert sha_a == sha_b
+    assert [k[0] for k in kernels_a] == ["scan_forward", "tpu_custom_call"]
+    assert kernels_a[0] == kernels_b[0] and kernels_a[1] != kernels_b[1]
+    assert kernels_a[0][1] == hashlib.sha256(
+        b'{\\22body\\22: \\22QUJD\\22}').hexdigest()
+    # another custom call's configuration is module text
+    assert xprof.program_identity(a.replace('"other"', '"else"'))[0] != sha_a
+    assert xprof.program_identity(a.replace("add", "multiply"))[0] != sha_a
+    assert xprof.program_identity("module @jit_f {}") == (
+        hashlib.sha256(b"module @jit_f {}").hexdigest(), [])
+
+
+def _build(**kw):
+    base = {"signature": [["params.w", [4, 8], "float32"],
+                          ["batch.data", [2, 8], "float32", "dev(0)"]],
+            "module_sha": "m0",
+            "kernels": [["scan_forward", "a"], ["attention", "b"],
+                        ["scan_forward", "c"], ["scan_forward", "d"]]}
+    return dict(base, **kw)
+
+
+def test_diff_builds_names_the_part_that_differs():
+    assert xprof.diff_builds(_build(), _build()) is None
+    assert xprof.diff_builds(_build(), _build(module_sha="m1")) \
+        == "module text outside the kernels"
+    other = _build(kernels=[["scan_forward", "a"], ["attention", "B"],
+                            ["scan_forward", "C"], ["scan_forward", "D"]])
+    assert xprof.diff_builds(_build(), other) == (
+        "kernel attention (#1): payload; "
+        "kernel scan_forward (#2, #3): payload")
+    assert xprof.diff_builds(_build(), _build(kernels=_build()["kernels"][:3])) \
+        == "kernels: 4 against 3"
+    renamed = _build(kernels=[["scan_forward", "a"], ["attention_v2", "b"]]
+                     + _build()["kernels"][2:])
+    assert xprof.diff_builds(_build(), renamed) \
+        == "kernel attention against attention_v2 (#1)"
+    moved = _build(signature=[["params.w", [4, 8], "float32"],
+                              ["batch.data", [4, 8], "float32", "dev(0)"]],
+                   module_sha="m1")
+    assert xprof.diff_builds(_build(), moved) == (
+        "arguments: (2,8)float32@dev(0) -> (4,8)float32@dev(0) on "
+        "batch.data; module text outside the kernels")
+    # records of an older program name nothing they do not hold
+    assert xprof.diff_builds({}, {}) is None
+
+
+def test_diff_builds_takes_records_and_what_a_summary_saved(xp):
+    import json
+
+    f = xprof.jit(lambda a: jnp.sum(a * a), site="t.diff",
+                  arg_names=("batch.data",))
+    f(np.ones((8, 6), np.float32))
+    f(np.ones((4, 6), np.float32))
+    first, second = [r for r in xprof.records() if r.site == "t.diff"]
+    assert first.module_sha != second.module_sha
+    want = ("arguments: (8,6)float32 -> (4,6)float32 on batch.data; "
+            "module text outside the kernels")
+    assert xprof.diff_builds(first, second) == want
+    saved = json.loads(json.dumps(xprof.summary()))["sites"]["t.diff"]["last"]
+    assert xprof.diff_builds(first.to_dict(), saved) == want
+    assert xprof.diff_builds(second, saved) is None
+
+
+def test_compile_view_shows_what_was_built_and_which_cache_answered(xp):
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    from trace_report import render_compile
+
+    rec = _built_again("t.view")
+    out = render_compile({"xprof": xprof.summary()})
+    header, row = [line.split() for line in out.splitlines()
+                   if line.split()[:1] in (["site"], ["t.view"])]
+    cell = dict(zip(header, row))
+    assert cell["cache"] == "off" and cell["kernels"] == "0"
+    assert cell["module"] == rec.module_sha[:12]
+    assert float(cell["trace_s"]) > 0 and float(cell["lower_s"]) > 0
+    # a record saved by an older program has none of the fields
+    old = {"xprof": {"sites": {"fused_step": {
+        "compiles": 1, "compile_time_s": 2.0,
+        "last": {"compile_time_s": 2.0, "flops": 10.0}}}}}
+    assert " - " in render_compile(old)
+
+
 def test_recompile_detector_event_carries_cause(xp):
     from mxnet_tpu import tracing
 

@@ -955,18 +955,34 @@ def render_trace_summary(trees, top=3):
 
 
 def render_compile(rec):
-    """Per-site compile registry table."""
+    """Per-site compile registry table: each site's newest build by
+    what it is (the module text's hash, its Pallas kernels), which cache
+    answered and its seconds by phase (``xprof.diff_builds`` tells two
+    files' records of one site apart)."""
     xp = rec.get("xprof") or {}
     sites = xp.get("sites") or {}
     if not sites:
         return "no xprof compile records\n"
-    rows = [("site", "compiles", "total_s", "last_s", "flops",
+    rows = [("site", "compiles", "total_s", "last_s", "cache", "trace_s",
+             "lower_s", "read_s", "backend_s", "module", "kernels", "flops",
              "held_bytes")]
+
+    def seconds(last, field):
+        v = last.get(field)
+        return "-" if v is None else "%.3f" % v
+
     for name, s in sorted(sites.items()):
         last = s.get("last") or {}
+        kernels = last.get("kernels")
         rows.append((name, str(s.get("compiles", 0)),
                      "%.3f" % s.get("compile_time_s", 0.0),
                      "%.3f" % (last.get("compile_time_s") or 0.0),
+                     last.get("cache") or "-",
+                     seconds(last, "trace_s"), seconds(last, "lower_s"),
+                     seconds(last, "cache_read_s"),
+                     seconds(last, "backend_compile_s"),
+                     (last.get("module_sha") or "-")[:12],
+                     "-" if kernels is None else str(len(kernels)),
                      "%.3g" % (last.get("flops") or 0),
                      _fmt_bytes(last.get("held_bytes") or 0)))
     out = ["compile registry (%d sites, %d compiles, %.3fs total):"
